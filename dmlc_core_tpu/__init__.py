@@ -53,10 +53,9 @@ import os as _os
 _force_n = _os.environ.get("DMLC_TPU_FORCE_CPU", "").strip()
 if _force_n and _force_n != "0":
     # opt-in env hook: pin jax to N virtual CPU devices BEFORE anything
-    # touches a backend.  Lets examples/tools run safely on TPU
-    # terminals (where the platform plugin overrides JAX_PLATFORMS)
-    # without per-script code — CI smoke-runs every example this way.
-    # "0"/empty = disabled; anything else must be a device count.
+    # touches a backend.  Lets examples/tools run on an N-device CPU
+    # mesh without per-script code — CI smoke-runs every example this
+    # way.  "0"/empty = disabled; anything else must be a device count.
     if not _force_n.isdigit():
         raise ValueError(
             f"DMLC_TPU_FORCE_CPU={_force_n!r}: expected a device count "

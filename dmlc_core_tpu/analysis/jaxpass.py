@@ -16,12 +16,10 @@ the dynamic tracer :mod:`dmlc_core_tpu.base.jitcheck`:
   reads inside ``*cache_key*`` functions (a mid-run env mutation flips
   the key and recompiles — route through ``base/knobs.py``).
 
-* ``donation-discipline`` — ``base/compat.py`` disables donation on
-  legacy jax because of a real use-after-donate corruption; every
-  ``donate_argnums=`` must therefore be the compat gate's return value,
-  never a literal, and an argument passed at a donated position is DEAD
-  after the call: any later read of that name (before a rebinding
-  store) is flagged.
+* ``donation-discipline`` — an argument passed at a donated position
+  is DEAD after the call (XLA reuses the buffer the moment the call
+  dispatches): any later read of that name (before a rebinding store)
+  is flagged.
 
 * ``transfer-discipline`` — host↔device traffic belongs at ingest and
   result boundaries, not inside traced code or round loops.  Flagged:
@@ -87,19 +85,14 @@ EXPLAIN = {
     },
     "donation-discipline": {
         "doc": "Donated buffers are freed for reuse by XLA the moment "
-               "the call dispatches — base/compat.py gates donation off "
-               "on legacy jax because a real use-after-donate corrupted "
-               "results.  Two contracts: every donate_argnums= value "
-               "must be the compat gate's return (donate_argnums(0), "
-               "never the literal (0,)), and a name passed at a donated "
+               "the call dispatches: a name passed at a donated "
                "position must not be read again before it is rebound.",
         "flagged": (
-            "step = jax.jit(update, donate_argnums=(0,))  # ungated\n"
+            "step = jax.jit(update, donate_argnums=(0,))\n"
             "new = step(state, grads)\n"
             "log(state.mean())      # read after donation: garbage\n"),
         "clean": (
-            "from dmlc_core_tpu.base.compat import donate_argnums\n"
-            "step = jax.jit(update, donate_argnums=donate_argnums(0))\n"
+            "step = jax.jit(update, donate_argnums=(0,))\n"
             "state = step(state, grads)   # rebinding kills the name\n"),
     },
     "transfer-discipline": {
@@ -138,9 +131,8 @@ def _call_name(func: ast.expr) -> str:
 
 def _const_nums(node: Optional[ast.expr]) -> Optional[Tuple[int, ...]]:
     """donate/static argnums as a tuple of ints when statically known:
-    a literal int, a literal tuple of ints, or the compat gate call
-    ``donate_argnums(0, 1)`` (whose runtime value is the nums or ());
-    None when unknowable (a variable, ...)."""
+    a literal int or a literal tuple of ints; None when unknowable (a
+    variable, ...)."""
     if node is None:
         return None
     if isinstance(node, ast.Constant) and isinstance(node.value, int) \
@@ -154,33 +146,7 @@ def _const_nums(node: Optional[ast.expr]) -> Optional[Tuple[int, ...]]:
                 return None
             out.append(elt.value)
         return tuple(out)
-    if (isinstance(node, ast.Call)
-            and _call_name(node.func) == "donate_argnums"):
-        out = []
-        for a in node.args:
-            if not (isinstance(a, ast.Constant)
-                    and isinstance(a.value, int)):
-                return None
-            out.append(a.value)
-        return tuple(out)
     return None
-
-
-def _is_compat_gated(node: Optional[ast.expr]) -> bool:
-    """True when the donate_argnums= value goes through the
-    base/compat.py gate (or is a variable we cannot prove literal)."""
-    if node is None:
-        return True
-    if isinstance(node, ast.Call):
-        return _call_name(node.func) == "donate_argnums"
-    if isinstance(node, (ast.Constant, ast.Tuple, ast.List)):
-        # () / (0,) / 0 literals bypass the gate
-        if isinstance(node, ast.Constant) and node.value in ((), None):
-            return True                    # empty donation is a no-op
-        if isinstance(node, (ast.Tuple, ast.List)) and not node.elts:
-            return True
-        return False
-    return True                            # Name/Attribute: resolved upstream
 
 
 def _kwarg(call: ast.Call, name: str) -> Optional[ast.expr]:
@@ -192,18 +158,12 @@ def _kwarg(call: ast.Call, name: str) -> Optional[ast.expr]:
 
 def _jit_call_info(call: ast.Call) -> Optional[Dict[str, object]]:
     """For ``jax.jit(f, ...)`` / ``partial(jax.jit, ...)`` calls: the
-    statically-known donate/static argnums and the gate verdict."""
-    if _is_jit_expr(call.func):
-        donate = _kwarg(call, "donate_argnums")
-    elif _partial_jit(call):
-        donate = _kwarg(call, "donate_argnums")
-    else:
+    statically-known donate/static argnums."""
+    if not (_is_jit_expr(call.func) or _partial_jit(call)):
         return None
     return {
-        "donate_kw": donate,
-        "donate": _const_nums(donate),
+        "donate": _const_nums(_kwarg(call, "donate_argnums")),
         "static": _const_nums(_kwarg(call, "static_argnums")),
-        "gated": _is_compat_gated(donate),
     }
 
 
@@ -248,16 +208,14 @@ class _Module:
                             self.jitted[node.name] = info
                     elif _is_jit_expr(dec):
                         self.jitted[node.name] = {
-                            "donate_kw": None, "donate": None,
-                            "static": None, "gated": True}
+                            "donate": None, "static": None}
             elif isinstance(node, ast.Assign) and len(node.targets) == 1:
                 v = node.value
                 if not isinstance(v, ast.Call):
                     continue
                 info = _jit_call_info(v)
                 if info is None and _compile_chain(v):
-                    info = {"donate_kw": None, "donate": None,
-                            "static": None, "gated": True}
+                    info = {"donate": None, "static": None}
                 if info is None:
                     continue
                 t = node.targets[0]
@@ -396,19 +354,7 @@ def _name_events(fn: ast.AST, name: str) -> List[Tuple[int, str, int]]:
 
 def _check_donation(ctx: AnalysisContext, pf: ParsedFile,
                     mod: _Module) -> None:
-    # (a) donate_argnums literals bypassing the base/compat gate
-    for node in ast.walk(pf.tree):
-        if not isinstance(node, ast.Call):
-            continue
-        info = _jit_call_info(node)
-        if info is not None and not info["gated"]:
-            ctx.add(pf, node.lineno, "donation-discipline",
-                    "donate_argnums passed as a literal — donation must "
-                    "go through the base/compat.py gate "
-                    "(donate_argnums(...)), which turns it off on jax "
-                    "versions with the use-after-donate bug",
-                    key=f"ungated:L-{_call_name(node.func) or 'jit'}")
-    # (b) donated argument read after the call
+    """Donated argument read after the call."""
     for fn in _enclosing_functions(pf.tree):
         for node in ast.walk(fn):
             if not isinstance(node, ast.Call):
@@ -437,8 +383,6 @@ def _check_donation(ctx: AnalysisContext, pf: ParsedFile,
                             "donating",
                             key=f"{fn.name}:use-after-donate:{arg.id}")
                     break
-    # decorated defs with ungated literal donate (partial form caught
-    # above via the decorator Call walk — nothing extra needed)
 
 
 # -- transfer-discipline -----------------------------------------------------

@@ -1,21 +1,26 @@
 """Persistent XLA compilation cache wiring + cold-start instrumentation.
 
-BENCH_r05 measured `warmup_seconds: 31.0` against `seconds: 12.4` of
-actual training on the north-star config — the XLA compiles that
-dominate that half minute are re-paid by every ``bench.py`` run, every
-elastic-recovery relaunch, and every serve restart, even though the
-programs are byte-identical each time.  JAX ships a persistent
-compilation cache (serialized executables keyed on the HLO + device
-topology) that turns a repeat compile into a disk read; this module is
-the ONE place that wires it, so every engine (in-core / external /
-sparse GBT, serve runners, bench) gets warm-start behavior through a
-single pair of env knobs:
+The XLA compiles of a cold start (tens of seconds on the north-star
+config) are re-paid by every ``bench.py`` run, every elastic-recovery
+relaunch, and every serve restart, even though the programs are
+byte-identical each time.  JAX ships a persistent compilation cache
+(serialized executables keyed on the HLO + device topology) that turns
+a repeat compile into a disk read; this module is the ONE place that
+wires it, so every engine (in-core / external / sparse GBT, serve
+runners, bench, chip_smoke) gets warm-start behavior.
 
-* ``DMLC_COMPILE_CACHE`` — default on; ``0`` disables (no jax config is
-  touched at all);
-* ``DMLC_COMPILE_CACHE_DIR`` — cache directory.  Unset: an already-
-  configured jax cache dir (e.g. the test harness's) is adopted as-is,
-  else ``~/.cache/dmlc_core_tpu/xla_compile_cache``.
+Where the cache lives is decided from OUTSIDE the program:
+
+* ``JAX_COMPILATION_CACHE_DIR`` set — jax has adopted that directory at
+  import and nothing here ever points it elsewhere;
+* unset — ONE fixed, git-ignored directory in the checkout
+  (``<repo>/.compile_cache``), the same for the library, the tests, the
+  smoke, the bench and every child process.  The path is part of what
+  makes a later process find the entries, so it carries no pid, temp
+  name or timestamp.
+
+``DMLC_COMPILE_CACHE=0`` disables the wiring (no jax config is touched
+at all).
 
 When enabled, the write thresholds are opened up
 (``jax_persistent_cache_min_compile_time_secs=0``, no minimum entry
@@ -57,10 +62,11 @@ __all__ = [
     "configure", "enabled", "set_cache_dir", "stats",
 ]
 
-#: default on-disk location when neither ``DMLC_COMPILE_CACHE_DIR`` nor
-#: an existing jax cache dir says otherwise
-_DEFAULT_DIR = os.path.join(os.path.expanduser("~"), ".cache",
-                            "dmlc_core_tpu", "xla_compile_cache")
+#: on-disk location when ``JAX_COMPILATION_CACHE_DIR`` is not set: one
+#: fixed directory at the root of the checkout (listed in .gitignore)
+_DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".compile_cache")
 
 _lock = threading.Lock()
 #: process-local event counts (kept even when base.metrics is disabled
@@ -143,28 +149,28 @@ def cache_dir() -> Optional[str]:
 
 
 def configure() -> bool:
-    """Idempotently wire jax's persistent compilation cache from env.
+    """Idempotently wire jax's persistent compilation cache.
 
     Safe to call before every compile site (each engine does).  Returns
     True when the cache is active.  ``DMLC_COMPILE_CACHE=0`` is a
-    strict no-op: nothing in jax.config is touched.  A cache dir the
-    process already configured (e.g. tests/conftest.py) is adopted
-    unless ``DMLC_COMPILE_CACHE_DIR`` explicitly overrides it.
+    strict no-op: nothing in jax.config is touched.  A directory jax
+    already holds — from ``JAX_COMPILATION_CACHE_DIR``, or the
+    :func:`set_cache_dir` test hook — is never replaced; only when
+    there is none does the fixed in-checkout directory go in.
     """
     if not enabled():
         return False
-    env_dir = get_env("DMLC_COMPILE_CACHE_DIR", "")
-    current = jax.config.jax_compilation_cache_dir
-    target = env_dir or current or _DEFAULT_DIR
-    if target != current:
-        set_cache_dir(target)
+    if jax.config.jax_compilation_cache_dir is None:
+        set_cache_dir(_DEFAULT_DIR)
     else:
         _open_thresholds()
     return True
 
 
 def set_cache_dir(path: str) -> None:
-    """Point the persistent cache at ``path`` (created lazily by jax).
+    """Point the persistent cache at ``path`` (created lazily by jax) —
+    :func:`configure`'s own setter and the hook tests use to isolate a
+    cache.
 
     Also resets jax's sticky cache handle so a redirect AFTER a compile
     has happened takes effect — without the reset the first-initialized
@@ -223,12 +229,13 @@ class BackgroundCompiler:
     does ingest work, and :meth:`join` blocks only for whatever compile
     time the ingest did not already cover.
 
-    Failures never propagate: a thunk that raises is logged once and
-    simply missing from the results — callers fall back to the inline
-    jit path, which recompiles (and usually hits the just-written
-    persistent cache).  ``compile_seconds`` after join is the longest
-    single worker wall (the critical path; workers run concurrently),
-    ``join_wait_seconds`` the non-overlapped residue the caller paid.
+    A thunk that raises — the compiler refusing a program — is
+    re-raised by :meth:`join` on the caller's thread: a program that
+    does not compile must stop the run with the compiler's message, not
+    be retried down another path.  ``compile_seconds`` after join is the
+    longest single worker wall (the critical path; workers run
+    concurrently), ``join_wait_seconds`` the non-overlapped residue the
+    caller paid.
     """
 
     def __init__(self, jobs: Dict[str, Callable[[], Any]],
@@ -238,7 +245,6 @@ class BackgroundCompiler:
         configure()
         self._what = what
         self._results: Dict[str, Any] = {}
-        self._errors: Dict[str, BaseException] = {}
         self._walls: Dict[str, float] = {}
         self._mark = marker()
         self._joined = False
@@ -255,8 +261,6 @@ class BackgroundCompiler:
             t0 = get_time()
             try:
                 self._results[name] = thunk()
-            except BaseException as e:  # noqa: BLE001 — surfaced at join
-                self._errors[name] = e
             finally:
                 self._walls[name] = get_time() - t0
                 if _metrics.enabled():
@@ -265,18 +269,13 @@ class BackgroundCompiler:
         return run
 
     def join(self) -> Dict[str, Any]:
-        """Wait for every worker; returns name → compiled result
-        (failed thunks are absent — see class docstring)."""
-        if self._joined:
-            return self._results
+        """Wait for every worker; returns name → compiled result, or
+        raises the first thunk's exception (see class docstring)."""
         t0 = get_time()
-        self._grp.join_all()
-        self._joined = True
-        self.join_wait_seconds = get_time() - t0
-        self.compile_seconds = max(self._walls.values(), default=0.0)
-        self.cache_verdict = verdict(self._mark)
-        for name, err in self._errors.items():
-            LOG("WARNING", "background compile %r failed "
-                "(%s: %s) — falling back to inline jit compile",
-                name, type(err).__name__, err)
+        self._grp.join_all()         # re-raises what a thunk raised
+        if not self._joined:
+            self._joined = True
+            self.join_wait_seconds = get_time() - t0
+            self.compile_seconds = max(self._walls.values(), default=0.0)
+            self.cache_verdict = verdict(self._mark)
         return self._results

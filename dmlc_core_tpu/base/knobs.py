@@ -227,8 +227,7 @@ declare("DMLC_WARMUP_EXEC", "auto",
         "compiling them: 'auto' executes on TPU only (first dispatch "
         "pays real staging there), '1' forces execution everywhere, "
         "'0' compiles/AOT-warms only — on CPU an exec-warmup just runs "
-        "the whole first dispatch chunk twice (the BENCH_r06 98s "
-        "warm_dispatch).", "gbt")
+        "the whole first dispatch chunk twice.", "gbt")
 declare("DMLC_FEATURE_BUNDLE", "0",
         "1 fuses mutually-exclusive (near-one-hot) feature blocks into "
         "one multi-bin storage feature (LightGBM's EFB with the "
@@ -241,9 +240,6 @@ declare("DMLC_FEATURE_BUNDLE", "0",
 declare("DMLC_COMPILE_CACHE", "1",
         "0 disables the persistent compilation cache "
         "(base/compile_cache).", "compile-cache")
-declare("DMLC_COMPILE_CACHE_DIR", "",
-        "Cache directory; empty adopts an already-configured dir or the "
-        "default location.", "compile-cache")
 declare("DMLC_COMPILE_CACHE_EXPECT", "",
         "CI drill only: scripts/check_compile_cache.py asserts this "
         "outcome ('miss' or 'hit').", "compile-cache")

@@ -27,7 +27,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from dmlc_core_tpu.base.compat import donate_argnums, shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dmlc_core_tpu.base.logging import CHECK, CHECK_EQ
@@ -168,7 +168,7 @@ class FM:
             in_specs=(P(), {"m": P(), "s": P(), "t": P()},
                       P("data", None), P("data"), P("data")),
             out_specs=(P(), {"m": P(), "s": P(), "t": P()}, P()),
-            check_vma=False), donate_argnums=donate_argnums(0, 1))
+            check_vma=False), donate_argnums=(0, 1))
         _STEP_FN_CACHE[cache_key] = self._step_fn
 
     # -- training -------------------------------------------------------

@@ -60,9 +60,9 @@ def _host_bin_requested() -> bool:
     binning (unset/empty = bin where the data lives).  Any other value
     is fatal — historically this knob named a jax backend, and silently
     routing e.g. ``tpu`` (or a typo) to the single-core host loop would
-    invert the operator's intent.  Through a remote-device tunnel, host
-    binning uploads the 4×-smaller uint8 matrix instead of f32
-    features; see the call sites for the measured trade-offs."""
+    invert the operator's intent.  Host binning uploads the 4×-smaller
+    uint8 matrix instead of f32 features, at the price of host-side
+    searchsorted."""
     from dmlc_core_tpu.base.parameter import get_env
 
     backend = get_env("DMLC_TPU_BIN_BACKEND", "", str)

@@ -31,7 +31,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 import flax.linen as nn
 import optax
 
-from dmlc_core_tpu.base.compat import donate_argnums
+
 from dmlc_core_tpu.base.logging import CHECK, LOG
 from dmlc_core_tpu.base.parameter import Parameter, field
 from dmlc_core_tpu.base.timer import get_time
@@ -208,7 +208,7 @@ class ResNetTrainer:
             step,
             in_shardings=(None, img_sh, lbl_sh),
             out_shardings=(None, rep, rep),
-            donate_argnums=donate_argnums(0),
+            donate_argnums=(0,),
         )
 
     # -- training ------------------------------------------------------

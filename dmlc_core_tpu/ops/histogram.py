@@ -8,13 +8,17 @@ there.  XLA formulations, selected by ``method``:
   ``(node, feature, bin)`` index, run separately for grad and hess.
   Lowers to XLA scatter-add: fast on CPU, slow on TPU (scatter
   serializes); the CPU default.
-* ``"matmul"`` — MXU formulation, the TPU default: scan over row blocks;
+* ``"matmul"`` — MXU formulation in plain XLA: scan over row blocks;
   per block the LHS ``[R, 2N]`` holds the node one-hot scaled by g (then
   h) and the RHS ``[R, F·B]`` is the bin one-hot, so ONE bf16 matmul
   with f32 accumulation (``preferred_element_type``) yields the whole
   block's contribution.  Blocking bounds the one-hot materialization to
   ~100MB regardless of n.
-* ``"auto"`` — picks by backend platform (tpu → matmul, else segment).
+* ``"pallas"`` — the factored one-hot Pallas kernel (``_hist_pallas``).
+* ``"auto"`` — :func:`resolve_hist_method`: on TPU ``pallas`` where the
+  shape passes the kernel's VMEM gate, else ``matmul``; ``segment`` on
+  every other backend.  An EXPLICIT method is never rewritten: asking
+  for ``pallas`` at a shape the gate rejects raises.
 
 TPU layout note: the result is ``[2, n_nodes, F, n_bins]`` with the
 grad/hess plane LEADING.  A trailing axis of size 2 is catastrophic under
@@ -43,6 +47,7 @@ from dmlc_core_tpu.ops import binlayout as _bl
 
 __all__ = ["build_histogram", "fused_descend_histogram", "fused_round",
            "select_feature_bins", "histogram_methods",
+           "resolve_hist_method", "pallas_interpret",
            "reference_histogram", "hist_psum_bytes_per_round",
            "bins_bytes_per_round", "leaves_built_per_round",
            "quantize_hist_partial", "dequantize_hist_sum"]
@@ -218,6 +223,43 @@ def _pallas_ok(n_bins: int, n_features: int, n_nodes: int = 1,
     return acc <= 24 << 20 and tile_stack <= 15 << 20
 
 
+def pallas_interpret() -> bool:
+    """Whether this module's ``pallas_call``s run in the Pallas
+    INTERPRETER instead of being compiled by Mosaic: everywhere but on a
+    TPU backend.  The one place that decision is made — the CPU test
+    suite exercises kernel logic through it, and ``chip_smoke.py``
+    requires it to be False."""
+    return jax.default_backend() != "tpu"
+
+
+def resolve_hist_method(method: str, n_bins: int, n_rows: int,
+                        n_nodes: int = 1, bins_itemsize: int = 1) -> str:
+    """The histogram engine ``method`` stands for at this shape
+    (``n_rows`` = rows of the feature-major matrix the kernel reads:
+    features, or a layout's physical rows).  ``auto`` chooses from what
+    it can observe — backend and the kernel's VMEM gate; an explicit
+    method is returned as is, except that ``pallas`` at a shape
+    :func:`_pallas_ok` rejects is an error, never a quiet ``matmul``.
+    ``models.histgbt`` calls this per tree level up front, so the
+    choice is on record (``HistGBT.round_plan``) before anything
+    traces."""
+    if method == "auto":
+        if jax.default_backend() != "tpu":
+            return "segment"
+        return ("pallas" if _pallas_ok(n_bins, n_rows, n_nodes,
+                                       bins_itemsize) else "matmul")
+    if method == "pallas" and not _pallas_ok(n_bins, n_rows, n_nodes,
+                                            bins_itemsize):
+        log_fatal(f"build_histogram: method='pallas' was requested but "
+                  f"the kernel's VMEM gate rejects n_bins={n_bins}, "
+                  f"rows={n_rows}, n_nodes={n_nodes}, "
+                  f"itemsize={bins_itemsize} at tile_rows={_TILE_ROWS} — "
+                  f"use 'auto' or 'matmul'")
+    if method not in ("segment", "matmul", "pallas"):
+        log_fatal(f"build_histogram: unknown method {method!r}")
+    return method
+
+
 def build_histogram(
     bins: jax.Array,        # [n, F] uint8/int32 — binned feature matrix
     node_id: jax.Array,     # [n] int32 — tree-node assignment of each row
@@ -253,16 +295,8 @@ def build_histogram(
     if layout is not None:
         CHECK(transposed, "layout= requires the transposed [F, n] matrix")
         n_bins = layout.sync_bins
-        if method == "auto":
-            if jax.default_backend() == "tpu":
-                method = ("pallas" if _pallas_ok(n_bins, layout.phys_rows,
-                                                 n_nodes, 1)
-                          else "matmul")
-            else:
-                method = "segment"
-        if method == "pallas" and not _pallas_ok(n_bins, layout.phys_rows,
-                                                 n_nodes, 1):
-            method = "matmul"
+        method = resolve_hist_method(method, n_bins, layout.phys_rows,
+                                     n_nodes, 1)
         if method == "pallas":
             if layout.pairs:
                 return _hist_pallas(bins, node_id, grad, hess, n_nodes,
@@ -277,25 +311,16 @@ def build_histogram(
         return _hist_matmul(storage.T, node_id, grad, hess,
                             n_nodes, n_bins)
     F = bins.shape[0] if transposed else bins.shape[1]
-    itemsize = jnp.dtype(bins.dtype).itemsize
-    if method == "auto":
-        if jax.default_backend() == "tpu":
-            method = ("pallas" if _pallas_ok(n_bins, F, n_nodes, itemsize)
-                      else "matmul")
-        else:
-            method = "segment"
-    if method == "pallas" and not _pallas_ok(n_bins, F, n_nodes, itemsize):
-        method = "matmul"  # shapes the kernel can't tile — use the XLA path
+    method = resolve_hist_method(method, n_bins, F, n_nodes,
+                                 jnp.dtype(bins.dtype).itemsize)
     if method == "segment":
         return _hist_segment(bins.T if transposed else bins,
                              node_id, grad, hess, n_nodes, n_bins)
     if method == "matmul":
         return _hist_matmul(bins.T if transposed else bins,
                             node_id, grad, hess, n_nodes, n_bins)
-    if method == "pallas":
-        return _hist_pallas(bins, node_id, grad, hess, n_nodes, n_bins,
-                            transposed=transposed)
-    log_fatal(f"build_histogram: unknown method {method!r}")
+    return _hist_pallas(bins, node_id, grad, hess, n_nodes, n_bins,
+                        transposed=transposed)
 
 
 @partial(jax.jit, static_argnums=(4, 5))
@@ -752,7 +777,7 @@ def fused_round(
             pl.BlockSpec((L, S * A, lo), lambda i: (0, 0, 0)),
             pl.BlockSpec((1, tile_rows), lambda i: (0, i)),
         ),
-        interpret=jax.default_backend() != "tpu",
+        interpret=pallas_interpret(),
     )(*operands)
 
     def canon(slab):
@@ -849,7 +874,7 @@ def _hist_pallas(bins, node_id, grad, hess, n_nodes, n_bins,
             pl.BlockSpec((1, tile_rows), lambda i: (0, i)),
         ],
         out_specs=pl.BlockSpec((L, S * A, lo), lambda i: (0, 0, 0)),
-        interpret=jax.default_backend() != "tpu",
+        interpret=pallas_interpret(),
     )(bins_t, node_id.reshape(1, n_pad), grad.reshape(1, n_pad),
       hess.reshape(1, n_pad))
     if layout is not None:
@@ -908,7 +933,7 @@ def _fused_pallas(bins_t, node_id, feat_sel, thr_sel, grad, hess,
             pl.BlockSpec((Fp, S * A, lo), lambda i: (0, 0, 0)),
             pl.BlockSpec((1, tile_rows), lambda i: (0, i)),
         ),
-        interpret=jax.default_backend() != "tpu",
+        interpret=pallas_interpret(),
     )(bins_p, node_id.reshape(1, n_pad), feat_sel.reshape(1, n_pad),
       thr_sel.reshape(1, n_pad), grad.reshape(1, n_pad),
       hess.reshape(1, n_pad))
@@ -945,16 +970,21 @@ def fused_descend_histogram(
     standalone descend pass), so the default is the two-pass form; the
     fused kernel is kept for parts where HBM bandwidth, not VPU issue
     rate, binds."""
-    F = bins_t.shape[0]
-    itemsize = jnp.dtype(bins_t.dtype).itemsize
-    use_pallas = (fuse and dir_sel is None and layout is None
-                  and method in ("auto", "pallas")
-                  and jax.default_backend() == "tpu"
-                  and _pallas_ok(n_bins, F, n_prev, itemsize))
-    if use_pallas:
+    if fuse:
+        # an explicit request: run the fused kernel or say why not
+        CHECK(dir_sel is None and layout is None
+              and method in ("auto", "pallas"),
+              "fused descend+histogram (DMLC_TPU_FUSED_DESCEND=1) "
+              "supports neither missing mode, a packed/bundled bin "
+              f"layout, nor hist_method={method!r}")
+        CHECK(_pallas_ok(n_bins, bins_t.shape[0], n_prev,
+                         jnp.dtype(bins_t.dtype).itemsize),
+              "fused descend+histogram: shape outside the kernel's VMEM "
+              f"gate (n_bins={n_bins}, features={bins_t.shape[0]}, "
+              f"n_prev={n_prev})")
         return _fused_pallas(bins_t, node_id, feat_sel, thr_sel,
                              grad, hess, n_prev, n_bins)
-    # unfused fallback: XLA descend, then the regular histogram
+    # two-pass form: XLA descend, then the regular histogram
     valid = node_id >= 0
     row_bin = select_feature_bins(bins_t, feat_sel, layout=layout)
     go_right = row_bin > thr_sel
@@ -977,7 +1007,7 @@ def select_feature_bins(bins_t: jax.Array, feat_sel: jax.Array,
     dimension serializes badly on TPU, so the selected feature's bin is
     extracted by compare-and-sum over the F rows (one [F, n] VPU pass).
     Shared by the tree descend in HistGBT (in-core and external-memory)
-    and the unfused fused_descend_histogram fallback.  With ``layout``
+    and the two-pass form of fused_descend_histogram.  With ``layout``
     the matrix is physical (packed/bundled) and ``feat_sel`` indexes
     ORIGINAL features — ``binlayout.select_bins`` decodes nibbles and
     bundle segments after the same compare-and-sum pass.

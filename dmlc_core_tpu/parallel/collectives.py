@@ -37,7 +37,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from dmlc_core_tpu.base.compat import shard_map
+from jax import shard_map
 
 from dmlc_core_tpu.base import metrics as _metrics
 from dmlc_core_tpu.base.logging import CHECK, LOG, log_fatal

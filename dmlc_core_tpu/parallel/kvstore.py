@@ -56,7 +56,7 @@ def _fused_mesh_reducer(mesh, axis):
     within it jax.jit caches per bucket composition (shapes tuple)."""
     from functools import partial
 
-    from dmlc_core_tpu.base.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     @jax.jit
@@ -101,10 +101,10 @@ def _fused_mesh_updater(mesh, axis, lr):
     (tests/test_ps.py asserts it)."""
     from functools import partial
 
-    from dmlc_core_tpu.base.compat import donate_argnums, shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    @partial(jax.jit, donate_argnums=donate_argnums(0))
+    @partial(jax.jit, donate_argnums=(0,))
     @partial(shard_map, mesh=mesh,
              in_specs=(P(axis), P(axis), P()), out_specs=P(),
              check_vma=False)

@@ -33,7 +33,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from dmlc_core_tpu.base.compat import axis_size
+from jax.lax import axis_size
 
 __all__ = ["moe_ffn", "reference_moe_ffn"]
 

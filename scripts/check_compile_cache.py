@@ -2,8 +2,8 @@
 """Compile-cache wiring check (scripts/ci.sh stage).
 
 Runs one tiny in-core GBT fit with the persistent XLA compile cache
-pointed at ``DMLC_COMPILE_CACHE_DIR`` and prints the cache evidence as
-one JSON line.  ``DMLC_COMPILE_CACHE_EXPECT`` asserts the outcome:
+placed from outside by ``JAX_COMPILATION_CACHE_DIR`` and prints the
+cache evidence as one JSON line.  ``DMLC_COMPILE_CACHE_EXPECT`` asserts the outcome:
 
 * ``miss`` — fresh dir: something must have been compiled AND written;
 * ``hit``  — second process against the same dir: at least one program
@@ -30,9 +30,9 @@ import numpy as np  # noqa: E402
 
 def main() -> int:
     expect = os.environ.get("DMLC_COMPILE_CACHE_EXPECT", "")
-    cache_dir = os.environ.get("DMLC_COMPILE_CACHE_DIR", "")
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
     if not cache_dir:
-        print("DMLC_COMPILE_CACHE_DIR must be set", file=sys.stderr)
+        print("JAX_COMPILATION_CACHE_DIR must be set", file=sys.stderr)
         return 2
 
     from dmlc_core_tpu.base import compile_cache as cc

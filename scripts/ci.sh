@@ -73,18 +73,15 @@ if ! git diff --exit-code -- doc/api doc/configuration.md \
 fi
 
 echo "== compile cache pre-seed (one warm dir for lanes + bench) =="
-# Persistent cache dir shared by BOTH pytest lanes (conftest honors the
-# env var), the multichip stage, and any later bench.py on this image:
+# One persistent cache directory serves BOTH pytest lanes, the
+# multichip stage, and any later bench.py: JAX_COMPILATION_CACHE_DIR
+# when the environment sets it, else the fixed <repo>/.compile_cache
+# (base/compile_cache.py) — no stage here names a directory of its own.
 # scripts/warm_compile_cache.py AOT-compiles the flagship round ladder
 # at the bench config's exact shapes into it (ShapeDtypeStructs — no
-# data), so bench warmup_seconds collapses from the 23-31 s of
-# BENCH_r04/r05 toward the <5 s ROADMAP target and the bench JSON says
-# compile_cache: hit.  Idempotent: a warm rerun joins in cache-read time.
-# The dir MUST default to the library default (~/.cache/...): a bench
-# launched later in a fresh shell carries no env var, so pre-seeding a
-# /tmp dir warms a cache nobody reads (the BENCH_r05 31 s warmup bug).
-export DMLC_COMPILE_CACHE_DIR="${DMLC_COMPILE_CACHE_DIR:-$HOME/.cache/dmlc_core_tpu/xla_compile_cache}"
-mkdir -p "$DMLC_COMPILE_CACHE_DIR"
+# data), so a later bench reads the round program instead of compiling
+# it and its JSON says compile_cache: hit.  Idempotent: a warm rerun
+# joins in cache-read time.
 python scripts/warm_compile_cache.py
 
 echo "== multichip dryrun (sharded-ingest parity + scaling report) =="
@@ -103,9 +100,9 @@ echo "== compile cache (cold -> warm wiring) =="
 # contract of doc/performance.md.
 CC_DIR="$(mktemp -d)"
 trap 'rm -rf "$CC_DIR"' EXIT
-env JAX_PLATFORMS=cpu DMLC_COMPILE_CACHE_DIR="$CC_DIR" \
+env JAX_PLATFORMS=cpu JAX_COMPILATION_CACHE_DIR="$CC_DIR" \
     DMLC_COMPILE_CACHE_EXPECT=miss python scripts/check_compile_cache.py
-env JAX_PLATFORMS=cpu DMLC_COMPILE_CACHE_DIR="$CC_DIR" \
+env JAX_PLATFORMS=cpu JAX_COMPILATION_CACHE_DIR="$CC_DIR" \
     DMLC_COMPILE_CACHE_EXPECT=hit python scripts/check_compile_cache.py
 
 echo "== stream smoke (append -> tail -> boost -> publish -> serve) =="

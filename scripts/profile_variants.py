@@ -9,7 +9,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import numpy as np
 import jax
 import jax.numpy as jnp
-from dmlc_core_tpu.base.compat import donate_argnums, shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from dmlc_core_tpu.models.histgbt import _make_best_split
@@ -85,7 +85,7 @@ def make_round(with_hist=True, with_split=True, with_descend=True,
     mapped = shard_map(round_body, mesh=mesh,
                        in_specs=(P("data", None), P("data"), P("data"), P("data")),
                        out_specs=P("data"), check_vma=False)
-    return jax.jit(mapped, donate_argnums=donate_argnums(3))
+    return jax.jit(mapped, donate_argnums=(3,))
 
 
 def timed(label, fn):

@@ -3,13 +3,13 @@
 
 AOT-compiles the flagship boosting-round ladder — the K-rounds-per-
 dispatch program (and remainder, when ``rounds % K != 0``) at the bench
-config's exact shapes — into ``DMLC_COMPILE_CACHE_DIR``, WITHOUT
-materializing any data: ``lower().compile()`` works on
-ShapeDtypeStructs, so warming the 10M-row program costs compile time
-only.  A later ``bench.py`` (or any fit at the same config) on the same
-image then deserializes instead of compiling: ``warmup_seconds`` drops
-from the 23-31 s BENCH_r04/r05 measured toward the <5 s ROADMAP target,
-and the bench JSON reports ``compile_cache: hit``.
+config's exact shapes — into the persistent compile cache
+(``JAX_COMPILATION_CACHE_DIR`` if set, else ``<repo>/.compile_cache``;
+see ``base/compile_cache.py``), WITHOUT materializing any data:
+``lower().compile()`` works on ShapeDtypeStructs, so warming the
+10M-row program costs compile time only.  A later ``bench.py`` (or any
+fit at the same config) against the same directory then deserializes
+instead of compiling, and the bench JSON reports ``compile_cache: hit``.
 
 Idempotent and cheap when warm: a second run joins in cache-read time.
 Config mirrors bench.py's env (``BENCH_ROWS``/``BENCH_FEATURES``/

@@ -2,8 +2,8 @@
 the process dies (VERDICT r3 #1 — two consecutive rounds produced an
 empty/blind official capture).
 
-Each test launches bench.py as a real subprocess (BENCH_FORCE_CPU pins
-it off any TPU plugin), kills it at a chosen point, and asserts the LAST
+Each test launches bench.py as a real subprocess (BENCH_FORCE_CPU asks
+for the CPU self-test run), kills it at a chosen point, and asserts the LAST
 stdout line — the driver's parse target — is a complete JSON record with
 a usable rate.
 """
@@ -107,13 +107,10 @@ class TestBenchSurvivesKill:
         assert rec_seen["chunks_so_far"]
 
     def test_budget_exhaustion_flushes_and_exits_zero(self):
-        # budget expires mid-fit; the watchdog thread must flush and
-        # exit 0 well before the outer 240s cap.  BENCH_NO_FALLBACK pins
-        # the 2000-round config — otherwise _pick_config would shrink
-        # rounds to fit the budget and a fast machine could finish
-        # cleanly before the watchdog fires
-        proc = _spawn(BENCH_ROUNDS=2000, BENCH_TIME_BUDGET=30,
-                      BENCH_NO_FALLBACK=1)
+        # budget expires mid-fit (the config is never shrunk to fit):
+        # the watchdog thread must flush and exit 0 well before the
+        # outer 240s cap
+        proc = _spawn(BENCH_ROUNDS=2000, BENCH_TIME_BUDGET=30)
         try:
             out, _ = proc.communicate(timeout=240)
         except subprocess.TimeoutExpired:
@@ -132,7 +129,19 @@ class TestBenchSurvivesKill:
         assert rec["phase"] == "done"
         assert rec["value"] > 0
         assert rec["anomaly"] is False
-        # configs 2/4 smoke fields present (value or explicit null)
-        assert "infeed_stall_frac" in rec
-        assert "kvstore_sync_ms" in rec
+        assert rec["platform"] == "cpu" and rec["mfu"] is None
         assert proc.returncode == 0
+
+    def test_default_run_without_a_tpu_exits_nonzero(self):
+        # no BENCH_FORCE_CPU: a CPU host must not produce a record that
+        # reads like a benchmark
+        env = _env()
+        del env["BENCH_FORCE_CPU"]
+        env["JAX_PLATFORMS"] = "cpu"
+        proc = subprocess.Popen(
+            [sys.executable, _BENCH], env=env, stdout=subprocess.PIPE,
+            stderr=subprocess.DEVNULL, text=True, cwd=_REPO)
+        out, _ = proc.communicate(timeout=240)
+        rec = _last_record(out)
+        assert proc.returncode == 2
+        assert "no TPU" in rec["error"] and rec["value"] == 0.0

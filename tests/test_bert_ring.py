@@ -11,7 +11,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from dmlc_core_tpu.base.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dmlc_core_tpu.models.bert import BERT
@@ -84,7 +84,7 @@ class TestBERT:
     @pytest.mark.slow
     def test_fit_chunked_matches_per_step(self):
         """The scan-chunked multi-step program (fit_chunked, the
-        remote-tunnel bench path) must reproduce the per-step train_step
+        bench path) must reproduce the per-step train_step
         trajectory exactly: same batch, same 4 steps, same final loss."""
         tokens, labels, mask = _batch(seed=9)
         mesh = create_mesh(MeshSpec(data=2, model=2, seq=2))
@@ -236,7 +236,7 @@ class TestUlysses:
     def test_matches_full_softmax(self, causal, rng):
         from functools import partial
 
-        from dmlc_core_tpu.base.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from dmlc_core_tpu.parallel.mesh import MeshSpec, create_mesh
@@ -263,7 +263,7 @@ class TestUlysses:
     def test_head_divisibility_rejected(self, rng):
         from functools import partial
 
-        from dmlc_core_tpu.base.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from dmlc_core_tpu.parallel.mesh import MeshSpec, create_mesh
@@ -285,7 +285,7 @@ class TestUlysses:
         """Both SP formulations must agree on the same sharded inputs."""
         from functools import partial
 
-        from dmlc_core_tpu.base.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from dmlc_core_tpu.parallel.mesh import MeshSpec, create_mesh

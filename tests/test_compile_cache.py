@@ -6,7 +6,10 @@ machinery is an optimization layered on the inline jit path, so any
 divergence in trees, margins or eval curves is a bug, not noise.
 """
 
+import json
 import os
+import subprocess
+import sys
 
 import numpy as np
 import pytest
@@ -91,12 +94,89 @@ class TestCompileCache:
     def test_verdict_classification(self):
         assert cc.verdict(cc.marker()) is None   # no traffic since mark
 
-    def test_configure_adopts_existing_dir(self, monkeypatch):
-        # no env override → the already-configured dir survives
-        monkeypatch.delenv("DMLC_COMPILE_CACHE_DIR", raising=False)
-        before = jax.config.jax_compilation_cache_dir
+    def test_background_compile_failure_raises_at_join(self):
+        # a program the compiler refuses stops the run with the
+        # compiler's message — it is not retried down another path
+        def refused():
+            raise RuntimeError("Mosaic said no")
+
+        bg = cc.BackgroundCompiler({"ok": lambda: 1, "bad": refused})
+        with pytest.raises(RuntimeError, match="Mosaic said no"):
+            bg.join()
+
+    def test_configure_never_replaces_a_dir_jax_holds(self, tmp_cache):
+        # the set_cache_dir test hook (like JAX_COMPILATION_CACHE_DIR)
+        # put a directory in jax.config: configure() must keep it
         assert cc.configure() is True
-        assert jax.config.jax_compilation_cache_dir == before
+        assert jax.config.jax_compilation_cache_dir == tmp_cache
+
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+_PLACEMENT_PROBE = """
+import json, sys
+sys.path.insert(0, %r)
+import jax
+writes = []
+_update = jax.config.update
+def update(name, value):
+    writes.append([name, value])
+    return _update(name, value)
+jax.config.update = update
+from dmlc_core_tpu.base import compile_cache as cc
+cc.configure()
+print(json.dumps({"dir": cc.cache_dir(), "writes": writes}))
+""" % _REPO
+
+
+def _placement(env_dir):
+    """Run configure() in a fresh process; returns the directory in
+    effect and every (name, value) it wrote to jax.config."""
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["JAX_PLATFORMS"] = "cpu"
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+    out = subprocess.run([sys.executable, "-c", _PLACEMENT_PROBE],
+                         env=env, capture_output=True, text=True,
+                         timeout=120, check=True).stdout
+    return json.loads(out.strip().splitlines()[-1])
+
+
+class TestCachePlacement:
+    def test_env_dir_is_the_only_dir(self, tmp_path):
+        want = str(tmp_path / "from_outside")
+        got = _placement(want)
+        assert got["dir"] == want
+        # nothing wrote a directory into jax.config: jax adopted the
+        # environment's, the library only opened the size/time floors
+        assert [w for w in got["writes"]
+                if w[0] == "jax_compilation_cache_dir"] == []
+
+    def test_unset_is_one_fixed_dir_in_the_checkout(self):
+        a, b = _placement(None), _placement(None)
+        want = os.path.join(_REPO, ".compile_cache")
+        assert a["dir"] == b["dir"] == want
+        with open(os.path.join(_REPO, ".gitignore")) as f:
+            assert ".compile_cache/" in f.read().split()
+
+    def test_old_knob_is_read_nowhere(self):
+        from dmlc_core_tpu.base import knobs
+
+        needle = "DMLC_COMPILE_" + "CACHE_DIR"
+        assert needle not in knobs.names()
+        hits = []
+        for top in ("dmlc_core_tpu", "scripts", "tests", "doc", "examples",
+                    "bench.py", "chip_smoke.py", "README.md"):
+            path = os.path.join(_REPO, top)
+            files = ([path] if os.path.isfile(path) else
+                     [os.path.join(d, f) for d, _, fs in os.walk(path)
+                      for f in fs if f.endswith((".py", ".md", ".sh"))])
+            for fp in files:
+                with open(fp, errors="replace") as f:
+                    if needle in f.read():
+                        hits.append(os.path.relpath(fp, _REPO))
+        assert hits == []
 
 
 class TestOverlapParity:

@@ -1115,7 +1115,7 @@ _DONATION_BAD = """
     def update(state, grads):
         return state
 
-    step = jax.jit(update, donate_argnums=(0,))   # ungated literal
+    step = jax.jit(update, donate_argnums=(0,))
 
     def train(state, grads):
         new = step(state, grads)
@@ -1125,12 +1125,11 @@ _DONATION_BAD = """
 
 _DONATION_GOOD = """
     import jax
-    from dmlc_core_tpu.base.compat import donate_argnums
 
     def update(state, grads):
         return state
 
-    step = jax.jit(update, donate_argnums=donate_argnums(0))
+    step = jax.jit(update, donate_argnums=(0,))
 
     def train(state, grads):
         state = step(state, grads)     # rebinding kills the old name
@@ -1138,16 +1137,16 @@ _DONATION_GOOD = """
 """
 
 
-def test_donation_discipline_flags_ungated_and_use_after(tmp_path):
+def test_donation_discipline_flags_use_after(tmp_path):
     ctx = analyze(_mini_repo(tmp_path,
                              {"dmlc_core_tpu/mod.py": _DONATION_BAD}),
                   rules=["donation-discipline"])
     msgs = [f.message for f in _findings(ctx, "donation-discipline")]
-    assert any("base/compat.py gate" in m for m in msgs), msgs
-    assert any("reads 'state' after donating" in m for m in msgs), msgs
+    assert len(msgs) == 1, msgs
+    assert "reads 'state' after donating" in msgs[0], msgs
 
 
-def test_donation_discipline_clean_gated_and_rebound(tmp_path):
+def test_donation_discipline_clean_rebound(tmp_path):
     ctx = analyze(_mini_repo(tmp_path,
                              {"dmlc_core_tpu/mod.py": _DONATION_GOOD}),
                   rules=["donation-discipline"])

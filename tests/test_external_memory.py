@@ -469,8 +469,8 @@ def test_external_memory_multiclass(tmp_path):
 
 def test_host_pinned_passes_match_default(tmp_path, monkeypatch):
     """DMLC_TPU_SKETCH_BACKEND / DMLC_TPU_BIN_BACKEND pin the streaming
-    passes to the host backend (the remote-tunnel mode bench_external
-    uses).  Same cuts, same trees as the default path."""
+    passes to the host backend.  Same cuts, same trees as the default
+    path."""
     from dmlc_core_tpu.data.iter import RowBlockIter
     from dmlc_core_tpu.models import HistGBT
 

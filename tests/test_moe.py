@@ -12,7 +12,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 from jax import lax
-from dmlc_core_tpu.base.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dmlc_core_tpu.parallel.moe import moe_ffn, reference_moe_ffn

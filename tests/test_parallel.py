@@ -1003,7 +1003,7 @@ class TestZeroAdam:
         replicated Adam on the globally-summed gradients."""
 
         import jax
-        from dmlc_core_tpu.base.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from dmlc_core_tpu.parallel.mesh import local_mesh
@@ -1050,7 +1050,7 @@ class TestZeroAdam:
 
     def test_state_is_sharded(self):
         import jax
-        from dmlc_core_tpu.base.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from dmlc_core_tpu.parallel.mesh import local_mesh
@@ -1073,7 +1073,7 @@ class TestZeroAdam:
 
     def test_nested_pytree_params(self):
         import jax
-        from dmlc_core_tpu.base.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from dmlc_core_tpu.parallel.mesh import local_mesh

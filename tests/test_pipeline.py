@@ -12,7 +12,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from dmlc_core_tpu.base.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dmlc_core_tpu.parallel.pipeline import PipelineLM, pipeline_apply
@@ -130,7 +130,7 @@ class TestPipelineLM:
 
     @pytest.mark.slow
     def test_fit_chunked_matches_per_step(self, rng):
-        """The scan-chunked program (tunnel bench path) must reproduce
+        """The scan-chunked program (bench path) must reproduce
         the per-step trajectory exactly on the pipelined mesh."""
         tokens, labels, mask = self._data(rng)
         mesh = _mesh(2, 2)
