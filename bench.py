@@ -2123,8 +2123,9 @@ def main() -> None:
     # everything from here runs off the device-resident handle; the host
     # copies (~1.2 GB at 10M×28) would otherwise sit in RAM to the end
     del X, y, margin
-    # cold-start evidence: the quantize+stage wall (the round-program
-    # compile overlaps it — see the per-run warmup breakdown)
+    # cold-start evidence: the host wall of the staging calls up to
+    # their last enqueue (the round-program compile overlaps it — see
+    # the per-run warmup breakdown)
     EV["config"] = {**EV["config"],
                     "bin_seconds": round(model.last_bin_seconds or 0.0, 3)}
 

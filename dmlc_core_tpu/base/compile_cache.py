@@ -56,6 +56,7 @@ from dmlc_core_tpu.base import metrics as _metrics
 from dmlc_core_tpu.base.logging import LOG
 from dmlc_core_tpu.base.parameter import get_env
 from dmlc_core_tpu.base.timer import get_time
+from dmlc_core_tpu.utils.profiler import current_op, span
 
 __all__ = [
     "BackgroundCompiler", "cache_dir", "compile_cache_metrics",
@@ -163,7 +164,7 @@ def configure() -> bool:
     if jax.config.jax_compilation_cache_dir is None:
         set_cache_dir(_DEFAULT_DIR)
     else:
-        _open_thresholds()
+        _set_cache_options()
     return True
 
 
@@ -179,17 +180,31 @@ def set_cache_dir(path: str) -> None:
     from jax.experimental.compilation_cache import compilation_cache as cc
 
     jax.config.update("jax_compilation_cache_dir", path)
-    _open_thresholds()
+    _set_cache_options()
     cc.reset_cache()
     LOG("DEBUG", "compile_cache: persistent XLA cache at %s", path)
 
 
-def _open_thresholds() -> None:
+def _set_cache_options() -> None:
     """Cache EVERY program: the default 1 s compile-time floor would
     skip most CPU-backend programs and every small serve bucket — the
-    exact compiles a warm restart must not re-pay."""
+    exact compiles a warm restart must not re-pay.
+
+    And key every program on its metadata too.  The ``jax.named_scope``
+    marks of the device phases (``dmlc.bin``, ``dmlc.round.L3.hist`` —
+    doc/observability.md) live in the HLO's ``op_name`` metadata, which
+    jax strips before hashing a program: a program that differs from a
+    cached one only in its scopes would be "hit" and come back WITHOUT
+    them, and the device trace would lose the phase.  With the metadata
+    in the key such a program compiles once more instead.  Locations
+    are cut to their innermost frame, so the key follows where in the
+    library an operation is written and under which scope, not who
+    called the library (``jax_include_full_tracebacks_in_locations=
+    False`` would do the same and drop the scopes from ``op_name``)."""
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_traceback_in_locations_limit", 1)
 
 
 def stats() -> Dict[str, Any]:
@@ -251,6 +266,8 @@ class BackgroundCompiler:
         self.compile_seconds = 0.0
         self.join_wait_seconds = 0.0
         self.cache_verdict: Optional[str] = None
+        # the workers' spans join the operation that starts them
+        self._op = current_op()
         self._grp = ThreadGroup()
         for name, thunk in jobs.items():
             self._grp.create(f"compile-{name}",
@@ -259,13 +276,20 @@ class BackgroundCompiler:
     def _runner(self, name: str, thunk: Callable[[], Any]):
         def run(_shutdown) -> None:
             t0 = get_time()
-            try:
-                self._results[name] = thunk()
-            finally:
-                self._walls[name] = get_time() - t0
-                if _metrics.enabled():
-                    compile_cache_metrics()["compile"].observe(
-                        self._walls[name], what=f"{self._what}:{name}")
+            mark = marker()
+            with span("dmlc.compile", op=self._op, what=self._what,
+                      program=name) as sp:
+                try:
+                    self._results[name] = thunk()
+                finally:
+                    # process-wide counts: two programs compiling at
+                    # once see each other's cache traffic
+                    sp.set(cache=verdict(mark) or "none")
+                    self._walls[name] = get_time() - t0
+                    if _metrics.enabled():
+                        compile_cache_metrics()["compile"].observe(
+                            self._walls[name],
+                            what=f"{self._what}:{name}")
         return run
 
     def join(self) -> Dict[str, Any]:

@@ -46,8 +46,10 @@ def gbt_metrics():
                                labels=("engine",)),
             "phase": r.histogram(
                 "gbt_phase_seconds",
-                "per-phase wall time: bin (quantize+stage), round "
-                "(boost), warmup (compile), predict (score batch); with "
+                "per-phase wall time: bin (host wall of the staging "
+                "calls up to their last enqueue, not their completion), "
+                "round (boost), warmup (compile), predict (score batch); "
+                "with "
                 "DMLC_METRICS_GBT_PHASES=1 the external engine adds "
                 "hist/split/leaf/apply via block_until_ready",
                 labels=("engine", "phase")),

@@ -48,7 +48,7 @@ from dmlc_core_tpu.base import metrics as _metrics
 from dmlc_core_tpu.base.logging import CHECK, CHECK_EQ, LOG, log_fatal
 from dmlc_core_tpu.base.parameter import Parameter, field, get_env
 from dmlc_core_tpu.base.timer import get_time
-from dmlc_core_tpu.utils.profiler import global_tracer, tracing_enabled
+from dmlc_core_tpu.utils.profiler import span
 from dmlc_core_tpu.data.device_feed import assemble_row_sharded
 from dmlc_core_tpu.data.iter import slab_shard_slices
 from dmlc_core_tpu.ops import binlayout as _bl
@@ -219,6 +219,12 @@ def _warmup_exec_mode() -> str:
     return v
 
 
+#: device phase of the transposes and concats that put binned slabs into
+#: the round program's feature-major layout (doc/observability.md)
+_LAYOUT_SCOPE = jax.named_scope("dmlc.ingest.layout")
+_to_feature_major = _LAYOUT_SCOPE(lambda b: b.T)
+
+
 @lru_cache(maxsize=32)
 def _pack_matrix_fn(mesh: Mesh, layout: "_bl.BinLayout"):
     """Jitted bin-matrix packing for one (mesh, layout): [F, n] uint8 →
@@ -248,7 +254,7 @@ def _bin_chunk_fn(mesh: Mesh, missing: bool, miss_bin: int):
     def f(xc, cuts):
         b = (apply_bins_missing(xc, cuts, miss_bin) if missing
              else apply_bins(xc, cuts))
-        return b.T
+        return _to_feature_major(b)
     return jax.jit(f, out_shardings=NamedSharding(mesh, P(None, "data")))
 
 
@@ -262,7 +268,7 @@ def _bin_piece_fn(missing: bool, miss_bin: int):
     def f(xp, cuts):
         b = (apply_bins_missing(xp, cuts, miss_bin) if missing
              else apply_bins(xp, cuts))
-        return b.T
+        return _to_feature_major(b)
     return jax.jit(f)
 
 
@@ -272,7 +278,7 @@ def _concat_pieces_fn(n_pieces: int):
     committed inputs keep it on the owning chip (sharded-ingest
     assembly; peak per-chip HBM ~2× that chip's uint8 slice)."""
     del n_pieces  # part of the key: one program per piece count
-    return jax.jit(lambda *ps: jnp.concatenate(ps, axis=1))
+    return jax.jit(_LAYOUT_SCOPE(lambda *ps: jnp.concatenate(ps, axis=1)))
 
 
 @lru_cache(maxsize=64)
@@ -281,7 +287,7 @@ def _concat_feature_major_fn(mesh: Mesh, n_pieces: int):
     — peak HBM is ~2× the uint8 matrix, vs the whole-matrix path's
     f32-plus-uint8 (~5×)."""
     del n_pieces  # part of the key: one program per piece count
-    return jax.jit(lambda *ps: jnp.concatenate(ps, axis=1),
+    return jax.jit(_LAYOUT_SCOPE(lambda *ps: jnp.concatenate(ps, axis=1)),
                    out_shardings=NamedSharding(mesh, P(None, "data")))
 
 
@@ -392,7 +398,7 @@ def _transpose_to_feature_major_fn(mesh: Mesh):
     """Shared jitted ``[n, F] → [F, n]`` resharding transpose (per mesh —
     a fresh per-fit lambda would recompile every call)."""
     return jax.jit(
-        lambda b: b.T,
+        _to_feature_major,
         out_shardings=NamedSharding(mesh, P(None, "data")))
 
 
@@ -505,6 +511,11 @@ class HistGBT(_ExternalMemoryEngine):
                   f"with objective {self.param.objective!r} "
                   f"(allowed: {sorted(allowed)})")
         self._obj = OBJECTIVES[self.param.objective]
+        # before this model's first program compiles (the cut programs
+        # run ahead of the round-program warmup that used to be the
+        # first to wire the cache): see _set_cache_options on why the
+        # scopes in the programs depend on it
+        _cc.configure()
         self.cuts: Optional[jax.Array] = None          # [F, n_bins-1]
         #: NaN-as-missing mode (XGBoost learned default direction),
         #: auto-detected from the training data: bin n_bins-1 is
@@ -524,7 +535,9 @@ class HistGBT(_ExternalMemoryEngine):
         self.last_chunk_times: List[Tuple[int, float]] = []
         self.last_warmup_seconds: Optional[float] = None
         #: cold-start breakdown of the last fit (doc/performance.md):
-        #: bin = quantize + stage wall (make_device_data);
+        #: bin = host wall of make_device_data's staging calls up to
+        #: their last enqueue (the call is asynchronous: NOT when the
+        #: binned matrix is ready);
         #: compile = round-program compile critical path (overlapped
         #: with bin when the warmup handle ran; None on the inline
         #: path, where compile hides inside the warm dispatch);
@@ -820,6 +833,15 @@ class HistGBT(_ExternalMemoryEngine):
         ``after_chunk(done, preds, trees_k) -> stop?`` hooks validation/
         early-stopping between dispatches.
         """
+        with span("dmlc.fit", rounds=self.param.n_trees):
+            return self._boost_rounds(
+                bins_t, y_d, w_d, preds, n_features, eval_every,
+                warmup_rounds, after_chunk, chunk_callback, round_offset)
+
+    def _boost_rounds(self, bins_t, y_d, w_d, preds, n_features,
+                      eval_every, warmup_rounds, after_chunk,
+                      chunk_callback, round_offset):
+        """:meth:`_boost_binned`'s work, inside its ``dmlc.fit`` span."""
         p = self.param
         # rounds per dispatch (_rounds_schedule): 25 amortizes
         # per-dispatch latency while keeping ≥2 evidence chunks at the
@@ -861,7 +883,8 @@ class HistGBT(_ExternalMemoryEngine):
             and y_d.sharding == row_sh and w_d.sharding == row_sh
             and preds.sharding == margin_sh)
         if warm is not None:
-            execs = warm.join()              # never leave workers behind
+            with span("dmlc.fit.join_warmup"):
+                execs = warm.join()          # never leave workers behind
             if shardings_ok and warm.matches(
                     self._round_fn_cache_key, n_features,
                     int(bins_t.shape[1]), K, rem):
@@ -900,36 +923,37 @@ class HistGBT(_ExternalMemoryEngine):
             device_s += get_time() - t_v
 
         t_w = get_time()
-        if warmup_rounds > 0 and not using_aot:
-            # first-dispatch tracing + compilation pulled out of the
-            # round loop: lower the exact programs against the LIVE
-            # buffers (lowering never executes or donates) and compile —
-            # a warm persistent cache collapses that to a disk read.
-            # The executables are adopted exactly like the overlapped
-            # warmup path's, and published for later fits only when the
-            # buffers carry the canonical shardings they key on.
-            t_tr = get_time()
-            aot_args = (bins_t, y_d, w_d, preds) + (
-                (jax.random.fold_in(base_key, round_offset),)
-                if sampling else ())
-            kfn = kfn_jit.lower(*aot_args).compile()
-            if rem:
-                rem_fn = rem_jit.lower(*aot_args).compile()
-            if shardings_ok:
-                n_padded = int(bins_t.shape[1])
-                _AOT_EXEC_CACHE[(self._round_fn_cache_key(
-                    n_features, K), n_features, n_padded)] = kfn
+        with span("dmlc.fit.warm_dispatch"):
+            if warmup_rounds > 0 and not using_aot:
+                # first-dispatch tracing + compilation pulled out of the
+                # round loop: lower the exact programs against the LIVE
+                # buffers (lowering never executes or donates) and compile —
+                # a warm persistent cache collapses that to a disk read.
+                # The executables are adopted exactly like the overlapped
+                # warmup path's, and published for later fits only when the
+                # buffers carry the canonical shardings they key on.
+                t_tr = get_time()
+                aot_args = (bins_t, y_d, w_d, preds) + (
+                    (jax.random.fold_in(base_key, round_offset),)
+                    if sampling else ())
+                kfn = kfn_jit.lower(*aot_args).compile()
                 if rem:
+                    rem_fn = rem_jit.lower(*aot_args).compile()
+                if shardings_ok:
+                    n_padded = int(bins_t.shape[1])
                     _AOT_EXEC_CACHE[(self._round_fn_cache_key(
-                        n_features, rem), n_features, n_padded)] = rem_fn
-            using_aot = True
-            trace_s = get_time() - t_tr
-        exec_mode = _warmup_exec_mode()
-        if warmup_rounds > 0 and (
-                exec_mode == "1" or (exec_mode == "auto"
-                                     and jax.default_backend() == "tpu")):
-            warm_dispatch(kfn, rem_fn)
-        np.asarray(preds[:1])
+                        n_features, K), n_features, n_padded)] = kfn
+                    if rem:
+                        _AOT_EXEC_CACHE[(self._round_fn_cache_key(
+                            n_features, rem), n_features, n_padded)] = rem_fn
+                using_aot = True
+                trace_s = get_time() - t_tr
+            exec_mode = _warmup_exec_mode()
+            if warmup_rounds > 0 and (
+                    exec_mode == "1" or (exec_mode == "auto"
+                                         and jax.default_backend() == "tpu")):
+                warm_dispatch(kfn, rem_fn)
+            np.asarray(preds[:1])
         self.last_dispatch = "aot" if using_aot else "jit"
         self.last_warm_dispatch_seconds = get_time() - t_w
         self.last_warmup_seconds = join_wait + \
@@ -962,9 +986,12 @@ class HistGBT(_ExternalMemoryEngine):
         done = 0
         while done < p.n_trees:
             fn = kfn if p.n_trees - done >= K else rem_fn
-            preds, trees_k = run(fn, preds, done)
+            k_now = K if fn is kfn else rem
+            with span("dmlc.fit.dispatch", rounds=k_now,
+                      first_round=round_offset + done):
+                preds, trees_k = run(fn, preds, done)
             chunks.append(trees_k)        # stacked [k, ...] device arrays
-            done += K if fn is kfn else rem
+            done += k_now
             if eval_every and done % eval_every == 0:
                 loss = float(self._obj.metric(preds, y_d))
                 LOG("INFO", "round %d: loss=%.5f", done, loss)
@@ -977,12 +1004,9 @@ class HistGBT(_ExternalMemoryEngine):
             # later chunks keep computing — so these in-order arrival
             # timestamps give per-chunk durations for free (see
             # ``last_chunk_times`` doc in __init__).
-            if tracing_enabled():
-                with global_tracer().scope("gbt.fetch_chunk"):
-                    t_np = jax.tree.map(np.asarray, trees_k)
-            else:
+            k = int(trees_k["leaf"].shape[0])
+            with span("dmlc.fit.fetch_chunk", trees=k):
                 t_np = jax.tree.map(np.asarray, trees_k)
-            k = t_np["leaf"].shape[0]
             fetched += k
             prev_t = (self.last_chunk_times[-1][1]
                       if self.last_chunk_times else 0.0)
@@ -1004,7 +1028,8 @@ class HistGBT(_ExternalMemoryEngine):
                 chunk_callback(*self.last_chunk_times[-1])
             self.trees.extend(
                 {key: t_np[key][i] for key in t_np} for i in range(k))
-        np.asarray(preds[:1])             # real sync before stopping timer
+        with span("dmlc.fit.sync"):
+            np.asarray(preds[:1])         # real sync before stopping timer
         self.last_fit_seconds = get_time() - t0
         return preds
 
@@ -1170,27 +1195,41 @@ class HistGBT(_ExternalMemoryEngine):
         ndev = device_count(self.mesh)
         chunk = _ingest_chunk_rows(ndev)
         if chunk <= 0 or n <= chunk:
-            bins = self._bin_matrix(jax.device_put(X, mat_sharding))
-            # feature-major for the round program (see the host-bin
-            # branch comment in make_device_data); drop the row-major
-            # copy right away
-            bins_t = _transpose_to_feature_major_fn(self.mesh)(bins)
-            bins.delete()
-            del bins
+            with span("dmlc.ingest.stream", slabs=1):
+                with span("dmlc.ingest.put", bytes=X.nbytes):
+                    x_d = jax.device_put(X, mat_sharding)
+                with span("dmlc.ingest.bin_dispatch"):
+                    bins = self._bin_matrix(x_d)
+                    del x_d
+                    # feature-major for the round program (see the
+                    # host-bin branch comment in make_device_data); drop
+                    # the row-major copy right away
+                    bins_t = _transpose_to_feature_major_fn(self.mesh)(bins)
+                    bins.delete()
+                    del bins
             return bins_t
         fn = _bin_chunk_fn(self.mesh, self._missing, self._miss_bin())
         pieces: List[jax.Array] = []
         inflight: deque = deque()
-        for lo in range(0, n, chunk):
-            inflight.append(
-                jax.device_put(X[lo:lo + chunk], mat_sharding))
-            if len(inflight) >= 2:       # keep one H2D copy in flight
+
+        def bin_oldest():
+            with span("dmlc.ingest.bin_dispatch"):
                 pieces.append(fn(inflight.popleft(), self.cuts))
-        while inflight:
-            pieces.append(fn(inflight.popleft(), self.cuts))
-        if len(pieces) == 1:
-            return pieces[0]
-        return _concat_feature_major_fn(self.mesh, len(pieces))(*pieces)
+
+        with span("dmlc.ingest.stream", slabs=-(-n // chunk)):
+            for lo in range(0, n, chunk):
+                slab = X[lo:lo + chunk]
+                with span("dmlc.ingest.put", bytes=slab.nbytes):
+                    inflight.append(jax.device_put(slab, mat_sharding))
+                if len(inflight) >= 2:   # keep one H2D copy in flight
+                    bin_oldest()
+            while inflight:
+                bin_oldest()
+            if len(pieces) == 1:
+                return pieces[0]
+            with span("dmlc.ingest.concat"):
+                return _concat_feature_major_fn(
+                    self.mesh, len(pieces))(*pieces)
 
     def _bin_eval_chunked(self, Xv: np.ndarray) -> jax.Array:
         """Validation-set binning through the chunked ingest path: the
@@ -1259,35 +1298,47 @@ class HistGBT(_ExternalMemoryEngine):
         pieces: List[List[Any]] = [[] for _ in range(ndev)]
         counts = [0] * ndev
         inflight: deque = deque()
-        lo = 0
-        for X_slab in slabs:
-            L = X_slab.shape[1] if binned else len(X_slab)
-            CHECK(lo + L <= n_real,
-                  f"slab stream produced more than the declared "
-                  f"{n_real} rows")
-            if host_bin:
-                b_slab = (np.asarray(X_slab) if binned else _host_bin_t(
-                    np.ascontiguousarray(X_slab, np.float32), cuts_np,
-                    missing=self._missing))                   # [F, L]
-                for k, s_lo, s_hi, _dst in slab_shard_slices(lo, L, S):
-                    pieces[k].append(jax.device_put(
-                        np.ascontiguousarray(b_slab[:, s_lo:s_hi]),
-                        devs[k]))
-                    counts[k] += s_hi - s_lo
-            else:
-                for k, s_lo, s_hi, _dst in slab_shard_slices(lo, L, S):
-                    xp = jax.device_put(np.ascontiguousarray(
-                        X_slab[s_lo:s_hi], dtype=np.float32), devs[k])
-                    inflight.append((k, xp))
-                    counts[k] += s_hi - s_lo
-                    if len(inflight) >= 2:   # keep one H2D put in flight
-                        kq, xq = inflight.popleft()
-                        pieces[kq].append(bin_fn(xq, cuts_dev))
-            lo += L
-        CHECK_EQ(lo, n_real, "slab stream ended before the declared rows")
-        while inflight:
+
+        def put(piece: np.ndarray, k: int):
+            with span("dmlc.ingest.put", bytes=piece.nbytes):
+                return jax.device_put(piece, devs[k])
+
+        def bin_oldest():
             kq, xq = inflight.popleft()
-            pieces[kq].append(bin_fn(xq, cuts_dev))
+            with span("dmlc.ingest.bin_dispatch"):
+                pieces[kq].append(bin_fn(xq, cuts_dev))
+
+        lo = 0
+        with span("dmlc.ingest.stream") as sp:
+            n_slabs = 0
+            for X_slab in slabs:
+                n_slabs += 1
+                L = X_slab.shape[1] if binned else len(X_slab)
+                CHECK(lo + L <= n_real,
+                      f"slab stream produced more than the declared "
+                      f"{n_real} rows")
+                if host_bin:
+                    b_slab = (np.asarray(X_slab) if binned
+                              else _host_bin_t(
+                                  np.ascontiguousarray(X_slab, np.float32),
+                                  cuts_np, missing=self._missing))  # [F, L]
+                    for k, s_lo, s_hi, _dst in slab_shard_slices(lo, L, S):
+                        pieces[k].append(put(np.ascontiguousarray(
+                            b_slab[:, s_lo:s_hi]), k))
+                        counts[k] += s_hi - s_lo
+                else:
+                    for k, s_lo, s_hi, _dst in slab_shard_slices(lo, L, S):
+                        inflight.append((k, put(np.ascontiguousarray(
+                            X_slab[s_lo:s_hi], dtype=np.float32), k)))
+                        counts[k] += s_hi - s_lo
+                        if len(inflight) >= 2:   # keep one H2D put in flight
+                            bin_oldest()
+                lo += L
+            CHECK_EQ(lo, n_real,
+                     "slab stream ended before the declared rows")
+            while inflight:
+                bin_oldest()
+            sp.set(slabs=n_slabs)
         # pad-tail fill: pad ROWS are zero features, so the f32 routes
         # bin them through the cuts (bin-of-0.0 per feature) exactly
         # like make_device_data's padded matrix — the handles stay
@@ -1305,9 +1356,11 @@ class HistGBT(_ExternalMemoryEngine):
                 pieces[k].append(jax.device_put(
                     np.ascontiguousarray(np.repeat(
                         pad_col, S - counts[k], axis=1)), devs[k]))
-        per_dev = [p[0] if len(p) == 1 else _concat_pieces_fn(len(p))(*p)
-                   for p in pieces]
-        return assemble_row_sharded(per_dev, self.mesh, dim=1, axis="data")
+        with span("dmlc.ingest.concat"):
+            per_dev = [p[0] if len(p) == 1
+                       else _concat_pieces_fn(len(p))(*p) for p in pieces]
+            return assemble_row_sharded(per_dev, self.mesh, dim=1,
+                                        axis="data")
 
     def make_device_data_iter(
         self,
@@ -1479,49 +1532,35 @@ class HistGBT(_ExternalMemoryEngine):
 
         Sets ``self.cuts`` if unset, so trees fitted from this handle
         predict correctly on raw features later.
+
+        Asynchronous: the handle comes back once the last staging call
+        is ENQUEUED; its arrays are ready when the device has drained
+        them (``jax.block_until_ready`` on them to wait).
         """
-        p = self.param
         t_bin = get_time()
-        X = np.ascontiguousarray(X, dtype=np.float32)
-        y = np.ascontiguousarray(y, dtype=np.float32)
-        n, F = X.shape
-        CHECK_EQ(len(y), n, "X/y row mismatch")
-        weight = self._fold_scale_pos_weight(y, weight)
-        # NaN = missing (XGBoost semantics): auto-enter missing mode on
-        # first sight of NaN.  Sticky: once a model has missing-mode
-        # cuts/trees, later NaN-free batches still bin in missing mode;
-        # the reverse (NaN arriving at a non-missing model with cuts
-        # already frozen) must fail loudly, not silently alias NaN into
-        # the top value bin.
-        has_nan = bool(np.isnan(X).any())
-        from dmlc_core_tpu.parallel import collectives as coll
-        if coll.world_size() > 1:
-            # mode selection must be GLOBAL: a shard that happens to hold
-            # no NaN rows would otherwise build differently-shaped cut
-            # summaries (allgather shape mismatch) and a different round
-            # program than its peers (histogram psum divergence)
-            has_nan = bool(coll.allreduce(
-                np.asarray([has_nan], np.int32), op="max")[0])
-        if has_nan and self.cuts is None and cuts is None:
-            CHECK(p.n_bins >= 3,
-                  "NaN features need n_bins >= 3 (one bin is reserved "
-                  "for missing)")
-            finite_any = np.isfinite(X).any(axis=0)
-            if coll.world_size() > 1:
-                # per-feature finiteness must be judged globally too: a
-                # shard whose rows happen to be all-NaN for one feature
-                # must not fatal (false positive) while its peers walk
-                # into the cut allgather without it
-                finite_any = coll.allreduce(
-                    finite_any.astype(np.int32), op="max").astype(bool)
-            CHECK(finite_any.all(),
-                  "a feature is all-NaN: drop it or impute")
-            self._missing = True
-        else:
-            CHECK(not has_nan or self._missing,
-                  "X contains NaN but this model's bins were built "
-                  "without a missing bin — refit from scratch (NaN in "
-                  "the first fit enables missing support) or impute")
+        with span("dmlc.ingest", rows=len(y)) as sp:
+            out = self._stage_device_data(X, y, weight, cuts, sp)
+        # host wall of the staging calls up to their last enqueue (cuts,
+        # puts, binning dispatches) — NOT the completion of the device
+        # work they queue, which the caller waits for on the handle
+        self.last_bin_seconds = get_time() - t_bin
+        if _metrics.enabled():
+            gbt_metrics()["phase"].observe(self.last_bin_seconds,
+                                           engine="incore", phase="bin")
+        return out
+
+    def _stage_device_data(self, X, y, weight, cuts, sp) -> Dict[str, Any]:
+        """:meth:`make_device_data`'s work, inside its ``dmlc.ingest``
+        span ``sp``; every host phase is a child span."""
+        p = self.param
+        with span("dmlc.ingest.host_prep"):
+            X = np.ascontiguousarray(X, dtype=np.float32)
+            y = np.ascontiguousarray(y, dtype=np.float32)
+            n, F = X.shape
+            sp.set(features=F)
+            CHECK_EQ(len(y), n, "X/y row mismatch")
+            weight = self._fold_scale_pos_weight(y, weight)
+            self._settle_missing_mode(X, cuts)
         # explicit cuts always win (a caller injecting boundaries must
         # not be silently overridden by leftovers from an earlier or
         # failed fit); existing self.cuts are kept only when nothing is
@@ -1531,11 +1570,13 @@ class HistGBT(_ExternalMemoryEngine):
         elif self.cuts is None:
             # missing mode: n_bins-1 VALUE bins (cuts [F, n_bins-2]),
             # bin n_bins-1 reserved for NaN
-            self.cuts = compute_cuts(
-                X, p.n_bins - 1 if self._missing else p.n_bins,
-                weight=weight,
-                allgather_fn=self._maybe_allgather(),
-                missing=self._missing)
+            # whole-matrix put, then the summary and merge enqueued
+            with span("dmlc.ingest.cuts", bytes=X.nbytes):
+                self.cuts = compute_cuts(
+                    X, p.n_bins - 1 if self._missing else p.n_bins,
+                    weight=weight,
+                    allgather_fn=self._maybe_allgather(),
+                    missing=self._missing)
         # cut width is the mode's load-bearing invariant: a mismatch
         # (e.g. standard-shaped cuts= injected into a missing-mode
         # model) would silently shift the reserved NaN bin out of the
@@ -1555,7 +1596,8 @@ class HistGBT(_ExternalMemoryEngine):
                        and not self._missing)
         if not pack_wanted:
             self._maybe_start_warmup(F, n + ((-n) % self._pad_multiple()))
-        X, y, mask, n_pad = self._pad_rows(X, y, weight)
+        with span("dmlc.ingest.pad"):
+            X, y, mask, n_pad = self._pad_rows(X, y, weight)
 
         row_sharding = NamedSharding(self.mesh, P("data"))
         mat_sharding = NamedSharding(self.mesh, P("data", None))
@@ -1616,23 +1658,58 @@ class HistGBT(_ExternalMemoryEngine):
             # the deferred cold-start kick (see above): layout is now a
             # pinned compile-time constant of the round program
             self._maybe_start_warmup(F, n + n_pad)
-        out = {
+        with span("dmlc.ingest.labels"):
+            y_d = jax.device_put(y, row_sharding)
+            w_d = jax.device_put(mask, row_sharding)
+        return {
             "bins_t": bins_t,
-            "y_d": jax.device_put(y, row_sharding),
-            "w_d": jax.device_put(mask, row_sharding),
+            "y_d": y_d,
+            "w_d": w_d,
             "n": n,
             "n_padded": n + n_pad,
             "n_features": F,
             "layout": layout,
         }
-        # wall time of the whole quantize+stage pass (cuts, binning,
-        # H2D) — dispatch-async tail included only as far as the
-        # device_put calls themselves block
-        self.last_bin_seconds = get_time() - t_bin
-        if _metrics.enabled():
-            gbt_metrics()["phase"].observe(self.last_bin_seconds,
-                                           engine="incore", phase="bin")
-        return out
+
+    def _settle_missing_mode(self, X: np.ndarray, cuts) -> None:
+        """Scan ``X`` for NaN (a read of the whole matrix) and enter,
+        keep or refuse missing mode accordingly."""
+        p = self.param
+        # NaN = missing (XGBoost semantics): auto-enter missing mode on
+        # first sight of NaN.  Sticky: once a model has missing-mode
+        # cuts/trees, later NaN-free batches still bin in missing mode;
+        # the reverse (NaN arriving at a non-missing model with cuts
+        # already frozen) must fail loudly, not silently alias NaN into
+        # the top value bin.
+        has_nan = bool(np.isnan(X).any())
+        from dmlc_core_tpu.parallel import collectives as coll
+        if coll.world_size() > 1:
+            # mode selection must be GLOBAL: a shard that happens to hold
+            # no NaN rows would otherwise build differently-shaped cut
+            # summaries (allgather shape mismatch) and a different round
+            # program than its peers (histogram psum divergence)
+            has_nan = bool(coll.allreduce(
+                np.asarray([has_nan], np.int32), op="max")[0])
+        if has_nan and self.cuts is None and cuts is None:
+            CHECK(p.n_bins >= 3,
+                  "NaN features need n_bins >= 3 (one bin is reserved "
+                  "for missing)")
+            finite_any = np.isfinite(X).any(axis=0)
+            if coll.world_size() > 1:
+                # per-feature finiteness must be judged globally too: a
+                # shard whose rows happen to be all-NaN for one feature
+                # must not fatal (false positive) while its peers walk
+                # into the cut allgather without it
+                finite_any = coll.allreduce(
+                    finite_any.astype(np.int32), op="max").astype(bool)
+            CHECK(finite_any.all(),
+                  "a feature is all-NaN: drop it or impute")
+            self._missing = True
+        else:
+            CHECK(not has_nan or self._missing,
+                  "X contains NaN but this model's bins were built "
+                  "without a missing bin — refit from scratch (NaN in "
+                  "the first fit enables missing support) or impute")
 
     def _compute_bin_layout(self, bins_t, n_features: int, n_valid: int
                             ) -> Optional["_bl.BinLayout"]:
@@ -2074,6 +2151,13 @@ class HistGBT(_ExternalMemoryEngine):
                 gathered = jax.lax.all_gather(x, "data")   # [dsize, ...]
                 return _tree_fold([gathered[i] for i in range(dsize)])
 
+            def with_siblings(parent, left):
+                """Both children's histograms, interleaved: the right
+                child is its parent less the built left child."""
+                right = parent - left
+                return jnp.stack([left, right], axis=2).reshape(
+                    2, 2 * parent.shape[1], left.shape[2], left.shape[3])
+
             feats = []
             thrs = []
             gains = []
@@ -2088,10 +2172,17 @@ class HistGBT(_ExternalMemoryEngine):
             for level in range(depth):
                 n_nodes = 1 << level
                 scores = None
+                # the level's device phases (doc/observability.md), as
+                # decorators of the calls that trace them: .route (each
+                # row's node's split), .hist (the kernel, fused descend
+                # and sibling subtraction included), .sync, .split
+                in_route, in_hist, in_sync, in_split = (
+                    jax.named_scope(f"dmlc.round.L{level}.{phase}")
+                    for phase in ("route", "hist", "sync", "split"))
                 if level == 0:
                     if n_blk:
-                        hist = _tree_fold([
-                            build_histogram(
+                        hist = in_hist(_tree_fold)([
+                            in_hist(build_histogram)(
                                 bins_tl[:, j * rb:(j + 1) * rb],
                                 node[j * rb:(j + 1) * rb],
                                 g[j * rb:(j + 1) * rb],
@@ -2100,15 +2191,16 @@ class HistGBT(_ExternalMemoryEngine):
                                 layout=layout)
                             for j in range(n_blk)])
                     else:
-                        hist = build_histogram(bins_tl, node, g, h, 1, B,
-                                               methods[0], transposed=True,
-                                               layout=layout)
-                    hist = hist_sync(hist)
+                        hist = in_hist(build_histogram)(
+                            bins_tl, node, g, h, 1, B, methods[0],
+                            transposed=True, layout=layout)
+                    hist = in_sync(hist_sync)(hist)
                 else:
                     n_prev = n_nodes >> 1
-                    feat_sel = table_select(feat, node, n_prev)       # [n]
-                    thr_sel = table_select(thr, node, n_prev)         # [n]
-                    dir_sel = (table_select(dirv, node, n_prev)
+                    select = in_route(table_select)
+                    feat_sel = select(feat, node, n_prev)             # [n]
+                    thr_sel = select(thr, node, n_prev)               # [n]
+                    dir_sel = (select(dirv, node, n_prev)
                                if missing else None)
                     if fused_rounds:
                         # ONE Pallas program: descend + accumulate +
@@ -2119,13 +2211,14 @@ class HistGBT(_ExternalMemoryEngine):
                         want_sums = (mono_arr is not None
                                      or level == depth - 1)
 
+                        @in_split
                         def score_fn(hs, _w=want_sums, _b=bounds):
                             ev = _bl.unbundle_hist(hs, layout, B)
                             if _w:
                                 return best_split_leaf(ev, feat_mask, _b)
                             return best_split(ev, feat_mask)
 
-                        node, hist, scores = fused_round(
+                        node, hist, scores = in_hist(fused_round)(
                             bins_tl, node, feat_sel, thr_sel, g, h,
                             prev_hist, n_prev, B, layout=layout,
                             score_fn=score_fn)
@@ -2133,7 +2226,7 @@ class HistGBT(_ExternalMemoryEngine):
                         lefts, nodes2 = [], []
                         for j in range(n_blk):
                             sl = slice(j * rb, (j + 1) * rb)
-                            l_j, nd_j = fused_descend_histogram(
+                            l_j, nd_j = in_hist(fused_descend_histogram)(
                                 bins_tl[:, sl], node[sl], feat_sel[sl],
                                 thr_sel[sl], g[sl], h[sl],
                                 n_prev, B, methods[level],
@@ -2144,10 +2237,10 @@ class HistGBT(_ExternalMemoryEngine):
                                 layout=layout)
                             lefts.append(l_j)
                             nodes2.append(nd_j)
-                        left = _tree_fold(lefts)
+                        left = in_hist(_tree_fold)(lefts)
                         node = jnp.concatenate(nodes2)
                     else:
-                        left, node = fused_descend_histogram(
+                        left, node = in_hist(fused_descend_histogram)(
                             bins_tl, node, feat_sel, thr_sel, g, h,
                             n_prev, B, methods[level],
                             fuse=fuse_levels,
@@ -2155,10 +2248,8 @@ class HistGBT(_ExternalMemoryEngine):
                             miss_bin=B - 1 if missing else None,
                             layout=layout)
                     if not fused_rounds:
-                        left = hist_sync(left)
-                        right = prev_hist - left
-                        hist = jnp.stack([left, right], axis=2).reshape(
-                            2, n_nodes, left.shape[2], left.shape[3])
+                        left = in_sync(hist_sync)(left)
+                        hist = in_hist(with_siblings)(prev_hist, left)
                 # sibling subtraction stays in STORAGE space (prev_hist);
                 # split evaluation sees original-feature space (identity
                 # when layout is None)
@@ -2174,20 +2265,24 @@ class HistGBT(_ExternalMemoryEngine):
                     else:
                         feat, thr, gn = scores
                 else:
-                    hist = _bl.unbundle_hist(hist, layout, B)
+                    hist = in_split(_bl.unbundle_hist)(hist, layout, B)
                     if mono_arr is not None or level == depth - 1:
                         if missing:
                             feat, thr, dirv, gn, cg_, ch_ = \
-                                best_split_leaf(hist, feat_mask, bounds)
+                                in_split(best_split_leaf)(
+                                    hist, feat_mask, bounds)
                         else:
-                            feat, thr, gn, cg_, ch_ = best_split_leaf(
-                                hist, feat_mask, bounds)
+                            feat, thr, gn, cg_, ch_ = \
+                                in_split(best_split_leaf)(
+                                    hist, feat_mask, bounds)
                         if level == depth - 1:
                             gsum, hsum = cg_, ch_
                     elif missing:
-                        feat, thr, dirv, gn = best_split(hist, feat_mask)
+                        feat, thr, dirv, gn = in_split(best_split)(
+                            hist, feat_mask)
                     else:
-                        feat, thr, gn = best_split(hist, feat_mask)
+                        feat, thr, gn = in_split(best_split)(
+                            hist, feat_mask)
                 # pad per-level arrays to a common width for stacking
                 feats.append(jnp.pad(feat, (0, half - n_nodes)))
                 thrs.append(jnp.pad(thr, (0, half - n_nodes)))
@@ -2214,31 +2309,32 @@ class HistGBT(_ExternalMemoryEngine):
                         jnp.stack([lo_l, up_l], 1),
                         jnp.stack([lo_r, up_r], 1)], axis=1
                     ).reshape(2 * n_nodes, 2)
-            # final descend (the loop's fused kernels advanced node only
-            # up to level depth-1); shared gather-free feature select
-            feat_sel = table_select(feat, node, 1 << (depth - 1))
-            thr_sel = table_select(thr, node, 1 << (depth - 1))
-            row_bin = select_feature_bins(bins_tl, feat_sel,
-                                          layout=layout)             # [n]
-            go_right = row_bin > thr_sel
-            if missing:
-                dir_sel = table_select(dirv, node, 1 << (depth - 1))
-                go_right = jnp.where(row_bin == B - 1, dir_sel == 0,
-                                     go_right)
-            node = 2 * node + go_right.astype(jnp.int32)
-            leaf_w = -_maybe_l1(gsum, alpha) / (hsum + lam)
-            if mono_arr is not None:
-                leaf_w = jnp.clip(leaf_w, bounds[:, 0], bounds[:, 1])
-            leaf = leaf_w * eta
-            tree = {
-                "feat": jnp.stack(feats),                # [depth, half]
-                "thr": jnp.stack(thrs),
-                "gain": jnp.stack(gains),                # [depth, half]
-                "leaf": leaf,                            # [n_leaf]
-            }
-            if missing:
-                tree["dir"] = jnp.stack(dirs)            # [depth, half]
-            return tree, table_select(leaf, node, n_leaf)
+            with jax.named_scope("dmlc.round.leaf"):
+                # final descend (the loop's fused kernels advanced node only
+                # up to level depth-1); shared gather-free feature select
+                feat_sel = table_select(feat, node, 1 << (depth - 1))
+                thr_sel = table_select(thr, node, 1 << (depth - 1))
+                row_bin = select_feature_bins(bins_tl, feat_sel,
+                                              layout=layout)             # [n]
+                go_right = row_bin > thr_sel
+                if missing:
+                    dir_sel = table_select(dirv, node, 1 << (depth - 1))
+                    go_right = jnp.where(row_bin == B - 1, dir_sel == 0,
+                                         go_right)
+                node = 2 * node + go_right.astype(jnp.int32)
+                leaf_w = -_maybe_l1(gsum, alpha) / (hsum + lam)
+                if mono_arr is not None:
+                    leaf_w = jnp.clip(leaf_w, bounds[:, 0], bounds[:, 1])
+                leaf = leaf_w * eta
+                tree = {
+                    "feat": jnp.stack(feats),                # [depth, half]
+                    "thr": jnp.stack(thrs),
+                    "gain": jnp.stack(gains),                # [depth, half]
+                    "leaf": leaf,                            # [n_leaf]
+                }
+                if missing:
+                    tree["dir"] = jnp.stack(dirs)            # [depth, half]
+                return tree, table_select(leaf, node, n_leaf)
 
         def grow_tree_lossguide(bins_tl, g, h, feat_mask):
             """One LEAF-WISE tree on (g, h) → (tree arrays, margin delta).
@@ -2489,22 +2585,25 @@ class HistGBT(_ExternalMemoryEngine):
             if sampling:
                 keep, feat_mask = sample_masks(key, y_l.shape)
             if n_class <= 1:
-                g, h = obj.grad_hess(preds_l, y_l)
-                g = g * w_l
-                h = h * w_l
-                if keep is not None:
-                    g = jnp.where(keep, g, 0.0)
-                    h = jnp.where(keep, h, 0.0)
+                with jax.named_scope("dmlc.round.grad"):
+                    g, h = obj.grad_hess(preds_l, y_l)
+                    g = g * w_l
+                    h = h * w_l
+                    if keep is not None:
+                        g = jnp.where(keep, g, 0.0)
+                        h = jnp.where(keep, h, 0.0)
                 tree, delta = grow(bins_tl, g, h, feat_mask)
-                return preds_l + delta, tree
+                with jax.named_scope("dmlc.round.update"):
+                    return preds_l + delta, tree
             # multiclass: preds_l [n, K]; one tree per class per round,
             # built on the full-softmax gradients (XGBoost multi:softmax)
-            g_all, h_all = obj.grad_hess(preds_l, y_l)    # [n, K]
-            g_all = g_all * w_l[:, None]
-            h_all = h_all * w_l[:, None]
-            if keep is not None:                          # same rows ∀ class
-                g_all = jnp.where(keep[:, None], g_all, 0.0)
-                h_all = jnp.where(keep[:, None], h_all, 0.0)
+            with jax.named_scope("dmlc.round.grad"):
+                g_all, h_all = obj.grad_hess(preds_l, y_l)    # [n, K]
+                g_all = g_all * w_l[:, None]
+                h_all = h_all * w_l[:, None]
+                if keep is not None:                      # same rows ∀ class
+                    g_all = jnp.where(keep[:, None], g_all, 0.0)
+                    h_all = jnp.where(keep[:, None], h_all, 0.0)
             class_trees = []
             deltas = []
             for c in range(n_class):
@@ -2516,7 +2615,8 @@ class HistGBT(_ExternalMemoryEngine):
                 ("dir",) if missing else ())
             tree = {key_: jnp.stack([t[key_] for t in class_trees])
                     for key_ in tree_keys}                    # [K, ...]
-            return preds_l + jnp.stack(deltas, axis=1), tree
+            with jax.named_scope("dmlc.round.update"):
+                return preds_l + jnp.stack(deltas, axis=1), tree
 
         preds_spec = P("data", None) if n_class > 1 else P("data")
         if sampling:
@@ -2584,13 +2684,19 @@ class HistGBT(_ExternalMemoryEngine):
         for lo in range(0, len(X), self._PREDICT_BATCH):
             t_b = get_time()
             xb = X[lo:lo + self._PREDICT_BATCH]
-            bins = self._bin_matrix(jnp.asarray(xb))
-            margin = self._apply_trees(
-                bins, stacked,
-                jnp.full(self._margin_shape(len(xb)), p.base_score,
-                         jnp.float32))
-            outs.append(np.asarray(
-                margin if output_margin else self._obj.transform(margin)))
+            with span("dmlc.predict.put", bytes=xb.nbytes):
+                xb_d = jnp.asarray(xb)
+            with span("dmlc.predict.dispatch"):
+                bins = self._bin_matrix(xb_d)
+                del xb_d
+                margin = self._apply_trees(
+                    bins, stacked,
+                    jnp.full(self._margin_shape(len(xb)), p.base_score,
+                             jnp.float32))
+                out_d = (margin if output_margin
+                         else self._obj.transform(margin))
+            with span("dmlc.predict.fetch", bytes=out_d.nbytes):
+                outs.append(np.asarray(out_d))
             if _metrics.enabled():
                 # np.asarray above is a real fetch, so this wall delta
                 # covers bin + tree apply + D2H for the batch
@@ -2603,8 +2709,9 @@ class HistGBT(_ExternalMemoryEngine):
                 n_trees: Optional[int] = None) -> np.ndarray:
         CHECK(self.cuts is not None, "predict before fit")
         CHECK(len(self.trees) > 0, "no trees trained")
-        stacked = self._stacked_trees(self._resolve_trees(n_trees))
-        return self._predict_stacked(X, stacked, output_margin)
+        with span("dmlc.predict", rows=len(X)):
+            stacked = self._stacked_trees(self._resolve_trees(n_trees))
+            return self._predict_stacked(X, stacked, output_margin)
 
     def predict_iter(self, row_iter, output_margin: bool = False,
                      n_trees: Optional[int] = None,
@@ -2627,9 +2734,11 @@ class HistGBT(_ExternalMemoryEngine):
         F = int(self.cuts.shape[0])
         # stack + upload the forest ONCE, not per slab (50 slabs at 50M
         # rows must not re-ship the model 50 times)
-        stacked = self._stacked_trees(self._resolve_trees(n_trees))
-        outs = [self._predict_stacked(xb, stacked, output_margin)
-                for xb, _, _ in iter_dense_slabs(row_iter, F, batch_rows)]
+        with span("dmlc.predict"):
+            stacked = self._stacked_trees(self._resolve_trees(n_trees))
+            outs = [self._predict_stacked(xb, stacked, output_margin)
+                    for xb, _, _ in iter_dense_slabs(row_iter, F,
+                                                     batch_rows)]
         if not outs:
             return np.zeros(self._margin_shape(0), np.float32)
         return np.concatenate(outs) if len(outs) > 1 else outs[0]
@@ -2735,17 +2844,20 @@ class HistGBT(_ExternalMemoryEngine):
         keys = ("feat", "thr", "leaf") + (
             ("dir",) if "dir" in trees[0] else ())
         chunks: List[Dict[str, jax.Array]] = []
-        for lo in range(0, len(trees), _TREE_CHUNK):
-            part = trees[lo:lo + _TREE_CHUNK]
-            stacked = {k: np.stack([t[k] for t in part]) for k in keys}
-            pad = _TREE_CHUNK - len(part)
-            if pad:
-                stacked = {
-                    k: np.concatenate(
-                        [v, np.zeros((pad,) + v.shape[1:], v.dtype)])
-                    for k, v in stacked.items()
-                }
-            chunks.append({k: jnp.asarray(v) for k, v in stacked.items()})
+        with span("dmlc.predict.stack", trees=len(trees)) as sp:
+            for lo in range(0, len(trees), _TREE_CHUNK):
+                part = trees[lo:lo + _TREE_CHUNK]
+                stacked = {k: np.stack([t[k] for t in part]) for k in keys}
+                pad = _TREE_CHUNK - len(part)
+                if pad:
+                    stacked = {
+                        k: np.concatenate(
+                            [v, np.zeros((pad,) + v.shape[1:], v.dtype)])
+                        for k, v in stacked.items()
+                    }
+                chunks.append({k: jnp.asarray(v)
+                               for k, v in stacked.items()})
+            sp.set(bytes=sum(v.nbytes for c in chunks for v in c.values()))
         return chunks
 
     def _apply_trees(self, bins, stacked, init):
@@ -2978,6 +3090,7 @@ def _descend_step(bins, feat, thr, dirv, node, miss_bin):
 
 
 @partial(jax.jit, static_argnums=(4, 8))
+@jax.named_scope("dmlc.descend")
 def _predict_trees(bins, feats, thrs, leaves, depth: int,
                    base_score: float = 0.0, init=None,
                    dirs=None, miss_bin: int = -1):

@@ -723,29 +723,30 @@ def fused_round(
         key = feat_sel.astype(jnp.int32)
         extras, occ = [], None
     pad = (-n) % tile_rows
-    if pad:
-        node_id = jnp.pad(node_id, (0, pad), constant_values=-1)
-        key = jnp.pad(key, (0, pad))
-        thr_sel = jnp.pad(thr_sel, (0, pad))
-        grad = jnp.pad(grad, (0, pad))
-        hess = jnp.pad(hess, (0, pad))
-        extras = [jnp.pad(e, (0, pad)) for e in extras]
-        if occ is not None:
-            occ = jnp.pad(occ, ((0, 0), (0, pad)))
-    n_pad = n + pad
-    grid = n_pad // tile_rows
-    bins_p = jnp.pad(bins_t, ((0, Fp - Fphys), (0, pad)))
+    with jax.named_scope("dmlc.hist.pad"):
+        if pad:
+            node_id = jnp.pad(node_id, (0, pad), constant_values=-1)
+            key = jnp.pad(key, (0, pad))
+            thr_sel = jnp.pad(thr_sel, (0, pad))
+            grad = jnp.pad(grad, (0, pad))
+            hess = jnp.pad(hess, (0, pad))
+            extras = [jnp.pad(e, (0, pad)) for e in extras]
+            if occ is not None:
+                occ = jnp.pad(occ, ((0, 0), (0, pad)))
+        n_pad = n + pad
+        grid = n_pad // tile_rows
+        bins_p = jnp.pad(bins_t, ((0, Fp - Fphys), (0, pad)))
 
-    # previous level's histograms, PRE-MAPPED into the accumulator
-    # layout [L, (gh, node, hi), lo] so the in-kernel subtraction is
-    # elementwise (dead rows/cells are exact zeros on both sides)
-    Sn = prev_hist.shape[2]
-    prev_p = jnp.pad(prev_hist.astype(jnp.float32),
-                     ((0, 0), (0, 0), (0, 0), (0, hi * lo - Bs)))
-    prev_r = prev_p.reshape(2, n_prev, Sn, hi, lo)
-    prev_r = prev_r.transpose(2, 0, 1, 3, 4).reshape(Sn, A, lo)
-    prev_acc = jnp.zeros((L, A, lo), jnp.float32
-                         ).at[jnp.asarray(perm)].set(prev_r)
+        # previous level's histograms, PRE-MAPPED into the accumulator
+        # layout [L, (gh, node, hi), lo] so the in-kernel subtraction is
+        # elementwise (dead rows/cells are exact zeros on both sides)
+        Sn = prev_hist.shape[2]
+        prev_p = jnp.pad(prev_hist.astype(jnp.float32),
+                         ((0, 0), (0, 0), (0, 0), (0, hi * lo - Bs)))
+        prev_r = prev_p.reshape(2, n_prev, Sn, hi, lo)
+        prev_r = prev_r.transpose(2, 0, 1, 3, 4).reshape(Sn, A, lo)
+        prev_acc = jnp.zeros((L, A, lo), jnp.float32
+                             ).at[jnp.asarray(perm)].set(prev_r)
 
     row_spec = pl.BlockSpec((1, tile_rows), lambda i: (0, i))
     in_specs = [pl.BlockSpec((Fp, tile_rows), lambda i: (0, i)),
@@ -778,6 +779,7 @@ def fused_round(
             pl.BlockSpec((1, tile_rows), lambda i: (0, i)),
         ),
         interpret=pallas_interpret(),
+        name="dmlc_fused_round",
     )(*operands)
 
     def canon(slab):
@@ -785,9 +787,10 @@ def fused_round(
         x = x[jnp.asarray(perm)]
         return x.transpose(1, 2, 0, 3)[..., :Bs]
 
-    hist = jnp.stack([canon(left), canon(right)], axis=2)
-    hist = hist.reshape(2, 2 * n_prev, Sn, Bs)
-    new_node = new_node.reshape(n_pad)[:n]
+    with jax.named_scope("dmlc.hist.unpack"):
+        hist = jnp.stack([canon(left), canon(right)], axis=2)
+        hist = hist.reshape(2, 2 * n_prev, Sn, Bs)
+        new_node = new_node.reshape(n_pad)[:n]
     scores = score_fn(hist) if score_fn is not None else None
     return new_node, hist, scores
 
@@ -851,16 +854,17 @@ def _hist_pallas(bins, node_id, grad, hess, n_nodes, n_bins,
     else:
         L = Fp
     pad = (-n) % tile_rows
-    if pad:
-        node_id = jnp.pad(node_id, (0, pad), constant_values=-1)
-        grad = jnp.pad(grad, (0, pad))
-        hess = jnp.pad(hess, (0, pad))
     n_pad = n + pad
     grid = n_pad // tile_rows
-    if transposed:
-        bins_t = jnp.pad(bins, ((0, Fp - F), (0, pad)))
-    else:
-        bins_t = jnp.pad(bins.T, ((0, Fp - F), (0, pad)))
+    with jax.named_scope("dmlc.hist.pad"):
+        if pad:
+            node_id = jnp.pad(node_id, (0, pad), constant_values=-1)
+            grad = jnp.pad(grad, (0, pad))
+            hess = jnp.pad(hess, (0, pad))
+        if transposed:
+            bins_t = jnp.pad(bins, ((0, Fp - F), (0, pad)))
+        else:
+            bins_t = jnp.pad(bins.T, ((0, Fp - F), (0, pad)))
 
     out = pl.pallas_call(
         partial(_hist_pallas_kernel, n_nodes=n_nodes, hi=hi, lo=lo, pack=S,
@@ -875,19 +879,22 @@ def _hist_pallas(bins, node_id, grad, hess, n_nodes, n_bins,
         ],
         out_specs=pl.BlockSpec((L, S * A, lo), lambda i: (0, 0, 0)),
         interpret=pallas_interpret(),
+        name="dmlc_hist",
     )(bins_t, node_id.reshape(1, n_pad), grad.reshape(1, n_pad),
       hess.reshape(1, n_pad))
-    if layout is not None:
-        # kernel-logical rows → storage order (static permutation)
-        perm = _bl.layout_tables(layout)["logical"]
-        out = out.reshape(L, 2, S, n_nodes, hi * lo).sum(axis=2)
-        out = out[jnp.asarray(perm)]
+    with jax.named_scope("dmlc.hist.unpack"):
+        if layout is not None:
+            # kernel-logical rows → storage order (static permutation)
+            perm = _bl.layout_tables(layout)["logical"]
+            out = out.reshape(L, 2, S, n_nodes, hi * lo).sum(axis=2)
+            out = out[jnp.asarray(perm)]
+            out = out.transpose(1, 2, 0, 3)
+            return out[..., :n_bins]
+        # [Fp, (gh, S, N, hi), lo] → Σ over S → [gh, N, F, hi·lo] → slice
+        # pads
+        out = out[:F].reshape(F, 2, S, n_nodes, hi * lo).sum(axis=2)
         out = out.transpose(1, 2, 0, 3)
         return out[..., :n_bins]
-    # [Fp, (gh, S, N, hi), lo] → Σ over S → [gh, N, F, hi·lo] → slice pads
-    out = out[:F].reshape(F, 2, S, n_nodes, hi * lo).sum(axis=2)
-    out = out.transpose(1, 2, 0, 3)
-    return out[..., :n_bins]
 
 
 @partial(jax.jit, static_argnums=(6, 7, 8, 9))
@@ -904,15 +911,16 @@ def _fused_pallas(bins_t, node_id, feat_sel, thr_sel, grad, hess,
     S = _pack_factor(n_prev, n_bins)
     Fp = -(-F // 8) * 8
     pad = (-n) % tile_rows
-    if pad:
-        node_id = jnp.pad(node_id, (0, pad), constant_values=-1)
-        feat_sel = jnp.pad(feat_sel, (0, pad))
-        thr_sel = jnp.pad(thr_sel, (0, pad))
-        grad = jnp.pad(grad, (0, pad))
-        hess = jnp.pad(hess, (0, pad))
     n_pad = n + pad
     grid = n_pad // tile_rows
-    bins_p = jnp.pad(bins_t, ((0, Fp - F), (0, pad)))
+    with jax.named_scope("dmlc.hist.pad"):
+        if pad:
+            node_id = jnp.pad(node_id, (0, pad), constant_values=-1)
+            feat_sel = jnp.pad(feat_sel, (0, pad))
+            thr_sel = jnp.pad(thr_sel, (0, pad))
+            grad = jnp.pad(grad, (0, pad))
+            hess = jnp.pad(hess, (0, pad))
+        bins_p = jnp.pad(bins_t, ((0, Fp - F), (0, pad)))
 
     hist, new_node = pl.pallas_call(
         partial(_fused_kernel, n_prev=n_prev, hi=hi, lo=lo, pack=S),
@@ -934,12 +942,14 @@ def _fused_pallas(bins_t, node_id, feat_sel, thr_sel, grad, hess,
             pl.BlockSpec((1, tile_rows), lambda i: (0, i)),
         ),
         interpret=pallas_interpret(),
+        name="dmlc_fused_descend",
     )(bins_p, node_id.reshape(1, n_pad), feat_sel.reshape(1, n_pad),
       thr_sel.reshape(1, n_pad), grad.reshape(1, n_pad),
       hess.reshape(1, n_pad))
-    out = hist[:F].reshape(F, 2, S, n_prev, hi * lo).sum(axis=2)
-    out = out.transpose(1, 2, 0, 3)[..., :n_bins]
-    return out, new_node.reshape(n_pad)[:n]
+    with jax.named_scope("dmlc.hist.unpack"):
+        out = hist[:F].reshape(F, 2, S, n_prev, hi * lo).sum(axis=2)
+        out = out.transpose(1, 2, 0, 3)[..., :n_bins]
+        return out, new_node.reshape(n_pad)[:n]
 
 
 def fused_descend_histogram(

@@ -35,6 +35,10 @@ defaults (S = 8·n_bins = 2048, C = 32) even a million pages stay under
 counts (≤ 32k) under 0.7 bin widths.  ``tests/test_external_memory.py``
 property-checks this bound against adversarial distributions
 (heavy-tail, atom-dominated, 10⁶:1 weight skew, sorted streams).
+
+Device phases (doc/observability.md): everything that computes cuts is
+traced under ``jax.named_scope("dmlc.cuts")``, digitizing under
+``"dmlc.bin"`` — one name for ingest and predict, it is one function.
 """
 
 from __future__ import annotations
@@ -54,6 +58,7 @@ __all__ = ["local_summary", "merge_summaries", "compute_cuts", "apply_bins",
 
 
 @partial(jax.jit, static_argnums=(2, 3))
+@jax.named_scope("dmlc.cuts")
 def local_summary(x: jax.Array, weight: Optional[jax.Array],
                   n_summary: int, missing: bool = False) -> jax.Array:
     """Fixed-size weighted quantile summary of local rows.
@@ -103,6 +108,7 @@ def local_summary(x: jax.Array, weight: Optional[jax.Array],
 
 
 @partial(jax.jit, static_argnums=(1,))
+@jax.named_scope("dmlc.cuts")
 def merge_summaries(gathered: jax.Array, n_bins: int) -> jax.Array:
     """Merge ``[W, F, n_summary]`` worker summaries into ``[F, n_bins-1]``
     cut points (interior boundaries; bin b = count of cuts ≤ x).
@@ -164,6 +170,7 @@ def compute_cuts(
 
 
 @partial(jax.jit, static_argnums=(2,))
+@jax.named_scope("dmlc.cuts")
 def _weighted_collapse(stack: jax.Array, wts: jax.Array, n_out: int) -> jax.Array:
     """Merge ``[K, F, S]`` summaries with per-summary weights ``[K]`` into
     one ``[F, n_out]`` summary.
@@ -292,6 +299,7 @@ class SketchAccumulator:
 
 
 @jax.jit
+@jax.named_scope("dmlc.bin")
 def apply_bins(x: jax.Array, cuts: jax.Array) -> jax.Array:
     """Digitize ``x`` [n, F] by per-feature ``cuts`` [F, n_bins-1] →
     integer bins [n, F] (bin = #cuts ≤ value, so bins ∈ [0, n_bins-1]).
@@ -314,6 +322,7 @@ def apply_bins(x: jax.Array, cuts: jax.Array) -> jax.Array:
 
 
 @partial(jax.jit, static_argnums=(2,))
+@jax.named_scope("dmlc.bin")
 def apply_bins_missing(x: jax.Array, cuts: jax.Array,
                        miss_bin: int) -> jax.Array:
     """:func:`apply_bins` with a reserved NaN bin: finite values digitize

@@ -3,10 +3,9 @@
 from dmlc_core_tpu.utils.platform import force_cpu_devices  # noqa: F401
 from dmlc_core_tpu.utils.profiler import (  # noqa: F401
     Tracer,
-    annotate,
     device_trace,
     global_tracer,
     set_tracing,
-    step_annotation,
+    span,
     tracing_enabled,
 )

@@ -9,9 +9,13 @@ module provides
 * :func:`device_trace` — context manager around ``jax.profiler.trace``:
   captures an XLA/TensorBoard profile (HLO timelines, TPU utilization)
   into a logdir;
-* :func:`annotate` / :func:`step_annotation` — named regions that show up
-  inside the device trace (thin wrappers over jax.profiler annotations,
-  no-ops if unavailable);
+* :class:`span` — THE way to mark a host phase of the program: one call
+  site, two sinks.  It always enters a ``jax.profiler.TraceAnnotation``
+  (so the phase sits in the profiler's own trace, on the device trace's
+  clock, next to the XLA ops — free when no trace is being taken) and,
+  only while host tracing is on, records the same name and counts into
+  :func:`global_tracer`.  Device phases are marked where they are
+  traced, with ``jax.named_scope`` (see ``doc/observability.md``);
 * :class:`Tracer` — a dependency-free host-side event tracer writing
   Chrome ``chrome://tracing`` / Perfetto JSON, so host pipeline phases
   (read, parse, device_put, step) can be eyeballed against each other
@@ -33,6 +37,7 @@ to dropped events, never to unbounded host memory.
 from __future__ import annotations
 
 import contextlib
+import itertools
 import json
 import os
 import threading
@@ -41,7 +46,7 @@ from typing import Any, Dict, Iterator, List, Optional
 
 from dmlc_core_tpu.base.timer import get_time
 
-__all__ = ["device_trace", "annotate", "step_annotation", "Tracer",
+__all__ = ["device_trace", "span", "current_op", "Tracer",
            "global_tracer", "tracing_enabled", "set_tracing"]
 
 _TRACING = os.environ.get("DMLC_TRACE", "0").lower() in ("1", "true", "on",
@@ -86,30 +91,67 @@ def device_trace(logdir: str) -> Iterator[None]:
                 pass
 
 
-@contextlib.contextmanager
-def annotate(name: str) -> Iterator[None]:
-    """Named region visible in the device trace (TraceAnnotation)."""
-    try:
+#: one process-wide counter names operations: every top-level span takes
+#: the next value as ``op`` and its children inherit it
+_op_ids = itertools.count(1)
+#: per-thread stack of the ``op`` of the spans open on that thread
+_open = threading.local()
+
+
+def current_op() -> Optional[int]:
+    """``op`` of the innermost :class:`span` open on this thread (None
+    outside any) — what a caller hands to a worker thread so the spans
+    the worker opens join the same operation."""
+    stack = getattr(_open, "ops", None)
+    return stack[-1] if stack else None
+
+
+class span:
+    """Mark one host phase: ``with span("dmlc.ingest.pad", rows=n): ...``.
+
+    The name, start and end go to the profiler's trace as a
+    ``TraceAnnotation`` whose stats are ``counts`` plus ``op``: the
+    identifier all spans of one operation share.  A span opened inside
+    another on the same thread is its child (nesting is the parent
+    link) and inherits its ``op``; a top-level span draws a fresh one;
+    a span on another thread joins an operation by passing
+    ``op=`` explicitly (:func:`current_op` read on the caller's thread).
+    While :func:`tracing_enabled`, the same name and counts are also
+    recorded as a complete event in :func:`global_tracer`.
+
+    Nothing is made synchronous: a span around an enqueue measures the
+    enqueue.  :meth:`set` adds counts known only once the work is done.
+    """
+
+    __slots__ = ("name", "counts", "_ann", "_start_us")
+
+    def __init__(self, name: str, **counts: Any) -> None:
+        self.name = name
+        self.counts = counts
+
+    def __enter__(self) -> "span":
         import jax
 
-        ctx = jax.profiler.TraceAnnotation(name)
-    except Exception:  # noqa: BLE001
-        ctx = contextlib.nullcontext()
-    with ctx:
-        yield
+        stack = _open.__dict__.setdefault("ops", [])
+        if self.counts.get("op") is None:
+            self.counts["op"] = stack[-1] if stack else next(_op_ids)
+        self._ann = jax.profiler.TraceAnnotation(self.name, **self.counts)
+        self._ann.__enter__()
+        stack.append(self.counts["op"])
+        self._start_us = global_tracer()._us() if _TRACING else None
+        return self
 
+    def set(self, **counts: Any) -> None:
+        """Add counts to the open span (a cache verdict, rows seen)."""
+        self.counts.update(counts)
+        self._ann.set_metadata(**counts)
 
-@contextlib.contextmanager
-def step_annotation(step: int, name: str = "train") -> Iterator[None]:
-    """Step marker so the profile viewer groups per-step activity."""
-    try:
-        import jax
-
-        ctx = jax.profiler.StepTraceAnnotation(name, step_num=step)
-    except Exception:  # noqa: BLE001
-        ctx = contextlib.nullcontext()
-    with ctx:
-        yield
+    def __exit__(self, *exc: Any) -> None:
+        self._ann.__exit__(*exc)
+        _open.ops.pop()
+        if self._start_us is not None:
+            global_tracer()._complete(self.name, self._start_us,
+                                      self.counts)
 
 
 class Tracer:
@@ -170,13 +212,18 @@ class Tracer:
         try:
             yield
         finally:
-            end = self._us()
-            self._append({
-                "name": name, "ph": "X", "ts": start,
-                "dur": end - start, "pid": os.getpid(),
-                "tid": threading.get_ident(),
-                "args": args or {},
-            })
+            self._complete(name, start, args)
+
+    def _complete(self, name: str, start_us: float,
+                  args: Dict[str, Any]) -> None:
+        """Append the "X" event of a region that began at ``start_us``
+        and ends now."""
+        self._append({
+            "name": name, "ph": "X", "ts": start_us,
+            "dur": self._us() - start_us, "pid": os.getpid(),
+            "tid": threading.get_ident(),
+            "args": dict(args),
+        })
 
     def instant(self, name: str, **args: Any) -> None:
         self._append({

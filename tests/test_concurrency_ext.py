@@ -14,7 +14,7 @@ import pytest
 
 from dmlc_core_tpu.base.thread_local import ThreadLocalStore
 from dmlc_core_tpu.io.thread_group import ThreadGroup
-from dmlc_core_tpu.utils.profiler import Tracer, annotate, step_annotation
+from dmlc_core_tpu.utils.profiler import Tracer, current_op, span
 
 
 def test_thread_group_runs_and_joins():
@@ -119,10 +119,11 @@ def test_tracer_chrome_json(tmp_path):
 
 def test_tracer_threads_have_distinct_rows():
     tr = Tracer()
+    both_alive = threading.Barrier(2, timeout=10)
 
     def work(name):
         with tr.scope(name):
-            time.sleep(0.001)
+            both_alive.wait()      # a finished thread's id can be reused
 
     ts = [threading.Thread(target=work, args=(f"t{i}",)) for i in range(2)]
     [t.start() for t in ts]
@@ -132,7 +133,9 @@ def test_tracer_threads_have_distinct_rows():
 
 
 def test_annotations_are_safe_noops_anywhere():
-    # must never raise, profiler active or not
-    with annotate("region"):
-        with step_annotation(0):
-            pass
+    # must never raise, profiler active or not; children share the
+    # parent's op, and nothing is left open afterwards
+    with span("region", rows=3) as outer:
+        with span("region.step", step=0) as inner:
+            assert current_op() == inner.counts["op"] == outer.counts["op"]
+    assert current_op() is None

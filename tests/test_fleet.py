@@ -384,6 +384,12 @@ class TestFrontendDrain:
                    for i in range(3)]
         for t in threads:
             t.start()
+        # all three admitted (a loaded host can take longer than any
+        # fixed sleep to get a client thread as far as its request)
+        deadline = time.time() + 10
+        while (fe.inflight() + len(results) < 3
+               and time.time() < deadline):
+            time.sleep(0.005)
         time.sleep(0.1)                  # in-flight inside the batcher
         st, body = _post_raw(fe.url + "/drain")
         assert st == 200 and body["status"] == "draining"

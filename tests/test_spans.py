@@ -1,0 +1,220 @@
+"""The program's own marks: ``profiler.span`` host phases in the
+profiler's trace (and in the Chrome tracer while ``DMLC_TRACE`` is on),
+``jax.named_scope`` device phases in the compiled programs, kernel names
+on the Pallas calls.  The names are a contract (doc/observability.md):
+the benchmark's per-layer readers find the phases by them.
+"""
+
+import glob
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dmlc_core_tpu.models import HistGBT
+from dmlc_core_tpu.models import histgbt as G
+from dmlc_core_tpu.ops import histogram as H
+from dmlc_core_tpu.ops.quantile import apply_bins, local_summary
+from dmlc_core_tpu.parallel.mesh import local_mesh
+from dmlc_core_tpu.utils.profiler import (global_tracer, set_tracing, span,
+                                          tracing_enabled)
+
+# operation -> (root span, its children, the spans that lie inside the
+# child named first)
+OPERATIONS = {
+    "ingest": ("dmlc.ingest",
+               ["dmlc.ingest.stream", "dmlc.ingest.host_prep",
+                "dmlc.ingest.cuts", "dmlc.ingest.pad", "dmlc.ingest.labels"],
+               ["dmlc.ingest.put", "dmlc.ingest.bin_dispatch",
+                "dmlc.ingest.concat"]),
+    "ingest_sharded": ("dmlc.ingest",
+                       ["dmlc.ingest.stream", "dmlc.ingest.host_prep",
+                        "dmlc.ingest.cuts", "dmlc.ingest.pad",
+                        "dmlc.ingest.labels"],
+                       ["dmlc.ingest.put", "dmlc.ingest.bin_dispatch"]),
+    "fit": ("dmlc.fit",
+            ["dmlc.fit.join_warmup", "dmlc.fit.warm_dispatch",
+             "dmlc.fit.dispatch", "dmlc.fit.fetch_chunk", "dmlc.fit.sync"],
+            []),
+    "predict": ("dmlc.predict",
+                ["dmlc.predict.stack", "dmlc.predict.put",
+                 "dmlc.predict.dispatch", "dmlc.predict.fetch"], []),
+}
+
+
+def _events(logdir):
+    """Every ``dmlc.*`` host event of the newest trace under ``logdir``
+    as (name, start_ns, end_ns, stats)."""
+    from jax.profiler import ProfileData
+
+    pb = sorted(glob.glob(os.path.join(
+        logdir, "plugins", "profile", "*", "*.xplane.pb")),
+        key=os.path.getmtime)[-1]
+    return [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+             {k: v for k, v in ev.stats})
+            for plane in ProfileData.from_file(pb).planes
+            for line in plane.lines for ev in line.events
+            if ev.name.startswith("dmlc.")]
+
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    """Each operation once, at a tiny size, under its own CPU profiler
+    trace: operation -> its ``dmlc.*`` events."""
+    rng = np.random.default_rng(0)
+    X = rng.normal(size=(3000, 5)).astype(np.float32)
+    y = (X[:, 0] * X[:, 1] > 0).astype(np.float32)
+    one = HistGBT(n_trees=4, max_depth=3, n_bins=16, mesh=local_mesh(1))
+    many = HistGBT(n_trees=4, max_depth=3, n_bins=16)
+    handle = {}
+
+    def ingest(model):
+        # the worker's span is written when its compile ends: wait for
+        # it inside the trace (fit_device joins the same handle again)
+        out = model.make_device_data(X, y)
+        model._pending_warmup.join()
+        return out
+
+    ops = {
+        "ingest": lambda: handle.update(ingest(one)),
+        "ingest_sharded": lambda: ingest(many),
+        "fit": lambda: one.fit_device(handle),
+        "predict": lambda: one.predict(X[:100]),
+    }
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    out = {}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("DMLC_INGEST_CHUNK_ROWS", "1000")     # three slabs
+        for name, op in ops.items():
+            logdir = str(tmp_path_factory.mktemp("trace_" + name))
+            jax.profiler.start_trace(logdir, profiler_options=opts)
+            try:
+                op()
+            finally:
+                jax.profiler.stop_trace()
+            out[name] = _events(logdir)
+    return out
+
+
+@pytest.mark.parametrize("operation", list(OPERATIONS))
+def test_host_spans_of_one_operation(traced, operation):
+    root_name, children, grandchildren = OPERATIONS[operation]
+    events = traced[operation]
+    names = {e[0] for e in events}
+    assert {root_name, *children, *grandchildren} <= names
+    (root,) = [e for e in events if e[0] == root_name]
+    op = root[3]["op"]
+    # one identifier for the whole operation, the compile worker's
+    # thread included
+    assert {e[3]["op"] for e in events} == {op}
+    inside = [e for e in events if e[0] not in (root_name, "dmlc.compile")]
+    assert all(root[1] <= e[1] and e[2] <= root[2] for e in inside)
+    if grandchildren:
+        (mid,) = [e for e in events if e[0] == children[0]]
+        assert all(mid[1] <= e[1] and e[2] <= mid[2]
+                   for e in events if e[0] in grandchildren)
+    if operation.startswith("ingest"):
+        assert root[3]["rows"] == 3000 and root[3]["features"] == 5
+        (stream,) = [e for e in events if e[0] == "dmlc.ingest.stream"]
+        assert stream[3]["slabs"] == 3
+        (comp,) = [e for e in events if e[0] == "dmlc.compile"]
+        assert comp[3]["program"] == "kfn"
+        assert comp[3]["cache"] in ("hit", "miss")
+
+
+def test_two_operations_carry_two_ops(traced):
+    ops = {name: {e[3]["op"] for e in evs} for name, evs in traced.items()}
+    assert all(len(v) == 1 for v in ops.values())
+    assert len(set.union(*ops.values())) == len(traced)
+
+
+def test_span_records_to_the_tracer_only_while_tracing():
+    was = tracing_enabled()
+    tr = global_tracer()
+    try:
+        set_tracing(False)
+        tr.clear()
+        with span("dmlc.test.phase", rows=7):
+            pass
+        assert tr.events() == []
+        set_tracing(True)
+        with span("dmlc.test.phase", rows=7) as outer:
+            with span("dmlc.test.phase.child") as inner:
+                inner.set(cache="hit")
+        child, phase = tr.events()          # a child completes first
+        assert (phase["name"], phase["ph"]) == ("dmlc.test.phase", "X")
+        assert phase["args"] == {"rows": 7, "op": outer.counts["op"]}
+        assert child["name"] == "dmlc.test.phase.child"
+        assert child["args"] == {"op": outer.counts["op"], "cache": "hit"}
+        assert phase["ts"] <= child["ts"]
+        assert child["ts"] + child["dur"] <= phase["ts"] + phase["dur"]
+    finally:
+        set_tracing(was)
+        tr.clear()
+
+
+def _round_program_text():
+    rng = np.random.default_rng(1)
+    X = rng.normal(size=(512, 4)).astype(np.float32)
+    y = (X[:, 0] > 0).astype(np.float32)
+    m = HistGBT(n_trees=2, max_depth=3, n_bins=16)
+    h = m.make_device_data(X, y)
+    fn = m._build_round_fn(4, 2)
+    return fn.lower(h["bins_t"], h["y_d"], h["w_d"],
+                    m._init_margin_device(h["n_padded"])).compile().as_text()
+
+
+_PROGRAMS = {
+    "apply_bins": (
+        lambda: apply_bins.lower(jnp.zeros((64, 4)), jnp.zeros((4, 15))
+                                 ).compile().as_text(),
+        ["dmlc.bin"]),
+    "local_summary": (
+        lambda: local_summary.lower(jnp.zeros((64, 4)), None, 16, False
+                                    ).compile().as_text(),
+        ["dmlc.cuts"]),
+    "_predict_trees": (
+        lambda: G._predict_trees.lower(
+            jnp.zeros((64, 4), jnp.uint8), jnp.zeros((2, 3, 4), jnp.int32),
+            jnp.zeros((2, 3, 4), jnp.int32), jnp.zeros((2, 8)), 3
+        ).compile().as_text(),
+        ["dmlc.descend"]),
+    "round_program": (
+        _round_program_text,
+        ["dmlc.round.grad", "dmlc.round.leaf", "dmlc.round.update"] + [
+            f"dmlc.round.L{d}.{phase}" for d in range(3)
+            for phase in ("hist", "sync", "split")]),
+}
+
+
+@pytest.mark.parametrize("program", list(_PROGRAMS))
+def test_compiled_programs_carry_their_scopes(program):
+    compiled_text, scopes = _PROGRAMS[program]
+    text = compiled_text()
+    op_names = [line.split('op_name="', 1)[1].split('"', 1)[0]
+                for line in text.splitlines() if 'op_name="' in line]
+    for scope in scopes:
+        assert any(scope in name.split("/") for name in op_names), scope
+
+
+@pytest.mark.parametrize("kernel", ["dmlc_hist", "dmlc_fused_descend",
+                                    "dmlc_fused_round"])
+def test_pallas_kernels_are_named(kernel):
+    n, F, B, T = 512, 8, 16, 256
+    bins = jnp.zeros((F, n), jnp.uint8)
+    node = jnp.zeros(n, jnp.int32)
+    g = jnp.ones(n, jnp.float32)
+    trace = {
+        "dmlc_hist": lambda: H._hist_pallas(bins, node, g, g, 1, B, T, 0,
+                                            True, None),
+        "dmlc_fused_descend": lambda: H._fused_pallas(
+            bins, node, node, node, g, g, 1, B, T, 0),
+        "dmlc_fused_round": lambda: H.fused_round(
+            bins, node, node, node, g, g, jnp.zeros((2, 1, F, B)), 1, B,
+            tile_rows=T),
+    }[kernel]
+    assert f"name={kernel}\n" in str(jax.make_jaxpr(trace)())
