@@ -62,7 +62,7 @@ from dmlc_core_tpu.ops.histogram import (build_histogram,
                                          resolve_hist_method,
                                          select_feature_bins)
 from dmlc_core_tpu.ops.quantile import (apply_bins, apply_bins_missing,
-                                        compute_cuts)
+                                        apply_bins_t, compute_cuts)
 from dmlc_core_tpu.parallel.mesh import device_count, local_mesh
 from dmlc_core_tpu.models.gbt_objectives import (  # noqa: F401  (re-exports:
     # scripts/tests import these via models.histgbt — keep the names)
@@ -246,30 +246,13 @@ def _tree_fold(parts):
 
 
 @lru_cache(maxsize=32)
-def _bin_chunk_fn(mesh: Mesh, missing: bool, miss_bin: int):
+def _bin_chunk_fn(mesh: Mesh, miss_bin: Optional[int]):
     """Jitted per-(mesh, mode) chunk binning: digitize a row-sharded
-    f32 slab against the cuts and emit it feature-major — the streamed
-    ingest's per-chunk kernel (cuts ride as a traced arg so one program
-    serves every fit on the mesh)."""
-    def f(xc, cuts):
-        b = (apply_bins_missing(xc, cuts, miss_bin) if missing
-             else apply_bins(xc, cuts))
-        return _to_feature_major(b)
-    return jax.jit(f, out_shardings=NamedSharding(mesh, P(None, "data")))
-
-
-@lru_cache(maxsize=8)
-def _bin_piece_fn(missing: bool, miss_bin: int):
-    """Jitted single-device piece binning for the SHARDED ingest: the
-    committed f32 piece pins the computation (and its uint8 output) to
-    that piece's device, so each chip bins exactly its own row slice —
-    no global resharding, no cross-chip traffic.  One program per
-    (mode, piece shape); cuts ride as a traced arg."""
-    def f(xp, cuts):
-        b = (apply_bins_missing(xp, cuts, miss_bin) if missing
-             else apply_bins(xp, cuts))
-        return _to_feature_major(b)
-    return jax.jit(f)
+    f32 slab against the cuts, feature-major as the count produces it —
+    the streamed ingest's per-chunk kernel (cuts ride as a traced arg so
+    one program serves every fit on the mesh)."""
+    return jax.jit(lambda xc, cuts: apply_bins_t(xc, cuts, miss_bin=miss_bin),
+                   out_shardings=NamedSharding(mesh, P(None, "data")))
 
 
 @lru_cache(maxsize=64)
@@ -1056,6 +1039,11 @@ class HistGBT(_ExternalMemoryEngine):
         definition every binning/descend site shares."""
         return (int(self.cuts.shape[1]) + 1) if self._missing else -1
 
+    def _nan_bin(self) -> Optional[int]:
+        """:func:`apply_bins_t`'s ``miss_bin``: the reserved NaN bin in
+        missing mode, else None (NaN is refused before it gets there)."""
+        return self._miss_bin() if self._missing else None
+
     def _fold_scale_pos_weight(self, y, weight):
         """Fold ``scale_pos_weight`` into the instance-weight vector —
         called by every data entry point (make_device_data → fit fresh
@@ -1075,7 +1063,7 @@ class HistGBT(_ExternalMemoryEngine):
 
     def _check_nan_allowed(self, X: np.ndarray, where: str) -> None:
         """A non-missing model given NaN must fail loudly — plain
-        searchsorted would silently alias NaN into the top value bin."""
+        binning would silently alias NaN into the top value bin."""
         if not self._missing and np.isnan(X).any():
             log_fatal(f"{where}: X contains NaN but this model was "
                       f"trained without missing support (train with NaN "
@@ -1184,37 +1172,36 @@ class HistGBT(_ExternalMemoryEngine):
         keeps it resident while the bin kernel runs — ~5× the binned
         matrix's HBM at peak.  Here rows stream in ``DMLC_INGEST_CHUNK_
         ROWS`` slabs through a depth-2 pipe (the ``data/device_feed``
-        idiom): while chunk *i*'s bin+transpose kernel runs, chunk
+        idiom): while chunk *i*'s bin kernel runs, chunk
         *i+1*'s H2D copy is already in flight, and each f32 slab's last
         reference drops as soon as its bins exist.  Peak residency: two
         f32 slabs + ~2× the uint8 matrix (the concat transient).
         Binning is per-element, so chunked output is bit-identical to
         the whole-matrix path (pinned by tests/test_compile_cache.py).
+
+        The depth is ENFORCED: a slab is binned only once it has landed
+        (``dmlc.ingest.put_wait``), so at most two puts are in flight.
+        Left to run ahead — every call here is asynchronous — the host
+        hands the runtime all the slabs at once, their host-side
+        relayouts (one task a put) contend with the whole-matrix put the
+        cut sort is waiting for, and a 24M × 28 ingest takes 8–12 s
+        instead of 4.5 (PERF.md §5–6, PR 28).
         """
         n = X.shape[0]
         ndev = device_count(self.mesh)
         chunk = _ingest_chunk_rows(ndev)
         if chunk <= 0 or n <= chunk:
-            with span("dmlc.ingest.stream", slabs=1):
-                with span("dmlc.ingest.put", bytes=X.nbytes):
-                    x_d = jax.device_put(X, mat_sharding)
-                with span("dmlc.ingest.bin_dispatch"):
-                    bins = self._bin_matrix(x_d)
-                    del x_d
-                    # feature-major for the round program (see the
-                    # host-bin branch comment in make_device_data); drop
-                    # the row-major copy right away
-                    bins_t = _transpose_to_feature_major_fn(self.mesh)(bins)
-                    bins.delete()
-                    del bins
-            return bins_t
-        fn = _bin_chunk_fn(self.mesh, self._missing, self._miss_bin())
+            chunk = n                      # one slab: the whole matrix
+        fn = _bin_chunk_fn(self.mesh, self._nan_bin())
         pieces: List[jax.Array] = []
         inflight: deque = deque()
 
         def bin_oldest():
+            slab = inflight.popleft()
+            with span("dmlc.ingest.put_wait"):
+                slab.block_until_ready()
             with span("dmlc.ingest.bin_dispatch"):
-                pieces.append(fn(inflight.popleft(), self.cuts))
+                pieces.append(fn(slab, self.cuts))
 
         with span("dmlc.ingest.stream", slabs=-(-n // chunk)):
             for lo in range(0, n, chunk):
@@ -1292,8 +1279,10 @@ class HistGBT(_ExternalMemoryEngine):
             self._missing and self._mesh_spans_processes())
         cuts_np = (np.asarray(self.cuts)
                    if host_bin and not binned else None)
+        # a committed f32 piece pins the jit (and its uint8 output) to
+        # that piece's device: each chip bins exactly its own row slice
         bin_fn = (None if host_bin
-                  else _bin_piece_fn(self._missing, self._miss_bin()))
+                  else partial(apply_bins_t, miss_bin=self._nan_bin()))
         cuts_dev = None if host_bin else jnp.asarray(self.cuts)
         pieces: List[List[Any]] = [[] for _ in range(ndev)]
         counts = [0] * ndev
@@ -1541,8 +1530,9 @@ class HistGBT(_ExternalMemoryEngine):
         with span("dmlc.ingest", rows=len(y)) as sp:
             out = self._stage_device_data(X, y, weight, cuts, sp)
         # host wall of the staging calls up to their last enqueue (cuts,
-        # puts, binning dispatches) — NOT the completion of the device
-        # work they queue, which the caller waits for on the handle
+        # puts and the waits that pace them, binning dispatches) — NOT
+        # the completion of the device work they queue, which the caller
+        # waits for on the handle
         self.last_bin_seconds = get_time() - t_bin
         if _metrics.enabled():
             gbt_metrics()["phase"].observe(self.last_bin_seconds,

@@ -32,7 +32,7 @@ from dmlc_core_tpu.base import metrics as _metrics
 from dmlc_core_tpu.base.logging import CHECK, LOG
 from dmlc_core_tpu.base.timer import block_until_ready_time, get_time
 from dmlc_core_tpu.ops.histogram import build_histogram
-from dmlc_core_tpu.ops.quantile import apply_bins
+from dmlc_core_tpu.ops.quantile import apply_bins_t
 from dmlc_core_tpu.models.gbt_split import (_advance_node, _host_bin_requested,
                                             _host_bin_t, _leaf_sums,
                                             _make_best_split, _maybe_l1,
@@ -264,7 +264,7 @@ class _ExternalMemoryEngine:
         for block in row_iter:
             X = block.to_dense(F)
             # in pass 2 so it runs on the explicit-cuts path too (pass 1
-            # is skipped there): plain searchsorted would silently alias
+            # is skipped there): plain binning would silently alias
             # NaN into the top value bin
             CHECK(not np.isnan(X).any(),
                   "fit_external: NaN features are only supported by "
@@ -273,7 +273,7 @@ class _ExternalMemoryEngine:
             if host_bin:
                 bins = _host_bin_t(X, cuts_for_bin)
             else:
-                bins = apply_bins(jnp.asarray(X), self.cuts).T  # [F, rows]
+                bins = apply_bins_t(jnp.asarray(X), self.cuts)  # [F, rows]
                 if not cache_device:
                     bins = np.asarray(bins)  # spill to host; one page on
                                              # device at a time (out-of-core)

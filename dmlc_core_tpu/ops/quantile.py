@@ -54,7 +54,7 @@ from dmlc_core_tpu.base.logging import CHECK
 from dmlc_core_tpu.base.parameter import get_env
 
 __all__ = ["local_summary", "merge_summaries", "compute_cuts", "apply_bins",
-           "apply_bins_missing", "SketchAccumulator"]
+           "apply_bins_t", "apply_bins_missing", "SketchAccumulator"]
 
 
 @partial(jax.jit, static_argnums=(2, 3))
@@ -298,42 +298,73 @@ class SketchAccumulator:
         return merge_summaries(merged[None], n_bins)
 
 
+#: the cut axis is counted as [K, _CUT_FOLD]: XLA:CPU rewrites a reduce
+#: over more than 32 elements into a reduce-window tree that it cannot
+#: fuse with the compare, and then holds the whole [F, C, n] int32 (5.6
+#: GB for 200k × 28 × 255); two axes of ≤ 32 it fuses, up to 1024 cuts.
+#: The TPU compiler gives the same one fusion either way.
+_CUT_FOLD = 32
+
+
+@partial(jax.jit, static_argnames=("miss_bin",))
+@jax.named_scope("dmlc.bin")
+def apply_bins_t(x: jax.Array, cuts: jax.Array,
+                 miss_bin: Optional[int] = None) -> jax.Array:
+    """Digitize ``x`` [n, F] by per-feature ``cuts`` [F, n_bins-1] →
+    integer bins FEATURE-MAJOR [F, n]: the layout the round program
+    reads, and the one the count is cheapest in.
+
+    ``bin = #{c : not (v < cuts[f, c])}``: one compare of every value
+    against every cut of its feature and a sum over the cut axis, rows on
+    the lanes — a single loop fusion with no ``[F, C, n]`` buffer (the
+    compiler streams the cut axis; 2M × 28 against 255 cuts holds 244 MiB
+    of temporaries).  A binary search is O(log C) but each of its steps
+    is a gather, which a TPU does element by element: on a v5e it took
+    5.3 s for that slab, this count 22 ms (8 ms at 64 bins, 72 ms at
+    1024: PERF.md §6, PR 28).  ``not (v < c)`` rather than ``c <= v``
+    makes the count equal ``searchsorted(cuts[f], v, side="right")`` for
+    EVERY float32: NaN compares false, so it counts every cut and lands
+    in ``n_cuts``.
+
+    ``miss_bin`` (missing mode) sends NaN to that reserved bin instead —
+    the caller reserves its top bin; without it NaN would alias the top
+    VALUE bin and score garbage.
+
+    dtype: uint8 when bins fit (largest bin < 256: ``n_bins`` ≤ 256, the
+    XGBoost max_bin default) — the bin matrix is the largest resident
+    training array and the narrow dtype quarters its HBM footprint under
+    TPU tiling; int32 otherwise.
+    """
+    F, C = cuts.shape
+    # pad to whole folds with -inf, which EVERY value counts (NaN and
+    # -inf too): the pads come off the sum again, no mask needed
+    n_pad = -C % _CUT_FOLD
+    folded = jnp.pad(cuts, ((0, 0), (n_pad, 0)), constant_values=-jnp.inf
+                     ).reshape(F, -1, _CUT_FOLD)
+    xt = x.T
+    out = jnp.sum(~(xt[:, None, None, :] < folded[:, :, :, None]),
+                  axis=(1, 2), dtype=jnp.int32) - n_pad
+    top = C
+    if miss_bin is not None:
+        out = jnp.where(jnp.isnan(xt), miss_bin, out)
+        top = miss_bin
+    return out.astype(jnp.uint8 if top < 256 else jnp.int32)
+
+
 @jax.jit
 @jax.named_scope("dmlc.bin")
 def apply_bins(x: jax.Array, cuts: jax.Array) -> jax.Array:
-    """Digitize ``x`` [n, F] by per-feature ``cuts`` [F, n_bins-1] →
-    integer bins [n, F] (bin = #cuts ≤ value, so bins ∈ [0, n_bins-1]).
-
-    Per-feature ``searchsorted`` (binary search, O(n·log C)) rather than a
-    broadcast-compare, which would materialize an [n, F, C] intermediate —
-    prohibitive at HIGGS scale (10M × 28 × 255).
-
-    dtype: uint8 when bins fit (n_bins ≤ 256, the XGBoost max_bin default)
-    — the bin matrix is the largest resident training array and the
-    narrow dtype quarters its HBM footprint under TPU tiling; int32
-    otherwise.
+    """:func:`apply_bins_t` row-major: ``x`` [n, F] → bins [n, F]
+    (bin = #cuts ≤ value, so bins ∈ [0, n_bins-1]; NaN → ``n_bins-1``).
     """
-    out = jax.vmap(
-        lambda col, c: jnp.searchsorted(c, col, side="right"),
-        in_axes=(1, 0), out_axes=1,
-    )(x, cuts)
-    dtype = jnp.uint8 if cuts.shape[1] < 256 else jnp.int32
-    return out.astype(dtype)
+    return apply_bins_t(x, cuts).T
 
 
-@partial(jax.jit, static_argnums=(2,))
+@partial(jax.jit, static_argnames=("miss_bin",))
 @jax.named_scope("dmlc.bin")
 def apply_bins_missing(x: jax.Array, cuts: jax.Array,
                        miss_bin: int) -> jax.Array:
     """:func:`apply_bins` with a reserved NaN bin: finite values digitize
-    into ``[0, n_cuts]`` as usual and NaN maps to ``miss_bin`` (the
-    caller reserves its top bin — searchsorted alone would silently
-    alias NaN with the top VALUE bin, scoring garbage).
+    into ``[0, n_cuts]`` as usual and NaN maps to ``miss_bin``.
     """
-    out = jax.vmap(
-        lambda col, c: jnp.searchsorted(c, col, side="right"),
-        in_axes=(1, 0), out_axes=1,
-    )(x, cuts)
-    out = jnp.where(jnp.isnan(x), miss_bin, out)
-    dtype = jnp.uint8 if miss_bin < 256 else jnp.int32
-    return out.astype(dtype)
+    return apply_bins_t(x, cuts, miss_bin=miss_bin).T

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from dmlc_core_tpu.ops import histogram as H
-from dmlc_core_tpu.ops.quantile import apply_bins, compute_cuts
+from dmlc_core_tpu.ops.quantile import apply_bins_t, compute_cuts
 
 ROWS = int(os.environ.get("ROWS", 4_000_000))
 F = int(os.environ.get("FEATURES", 28))
@@ -35,8 +35,7 @@ TILES = [int(x) for x in os.environ.get("TILES", "8192,16384").split(",")]
 
 rng = np.random.default_rng(0)
 X = rng.normal(size=(ROWS, F)).astype(np.float32)
-bins_t = jnp.asarray(np.asarray(
-    apply_bins(jnp.asarray(X), compute_cuts(X, B))).T)
+bins_t = apply_bins_t(jnp.asarray(X), compute_cuts(X, B))
 g0 = jnp.asarray(rng.normal(size=ROWS).astype(np.float32))
 h0 = jnp.abs(g0) + 0.1
 np.asarray(bins_t[0, :1])

@@ -11,7 +11,8 @@ import jax.numpy as jnp
 
 from dmlc_core_tpu.models import HistGBT
 from dmlc_core_tpu.ops.histogram import build_histogram, reference_histogram
-from dmlc_core_tpu.ops.quantile import apply_bins, compute_cuts, local_summary, merge_summaries
+from dmlc_core_tpu.ops.quantile import (apply_bins, apply_bins_missing, apply_bins_t,
+                                        compute_cuts, local_summary, merge_summaries)
 from dmlc_core_tpu.parallel.mesh import local_mesh
 
 
@@ -148,7 +149,80 @@ class TestHistogram:
         assert out[0].sum() == pytest.approx((node >= 0).sum() * F)
 
 
+def _edge_matrix(rng, n, cuts):
+    """[n, F] float32 holding, per feature, what a digitizer gets wrong
+    first: every cut, its float32 neighbours either side, ±0.0 (the
+    middle cut is 0.0), ±inf, denormals, NaN, and plain draws."""
+    cols = []
+    for c in cuts:
+        pool = np.concatenate([
+            c, np.nextafter(c, np.float32(-np.inf)),
+            np.nextafter(c, np.float32(np.inf)),
+            np.float32([-0.0, 0.0, -np.inf, np.inf, np.nan,
+                        1e-45, -1e-45, 1e-40, -1e-40]),
+            rng.normal(size=64).astype(np.float32)])
+        # the specials first, so that even a short column draws on them
+        pool = np.concatenate([pool[3 * len(c):], pool[:3 * len(c)]])
+        take = rng.permutation(len(pool))[:n] if n < len(pool) else (
+            np.concatenate([np.arange(len(pool)),
+                            rng.integers(0, len(pool), n - len(pool))]))
+        cols.append(pool[take])
+    return np.stack(cols, axis=1).astype(np.float32)
+
+
+def _edge_cuts(rng, F, n_cuts):
+    """[F, n_cuts] strictly increasing float32 cuts with 0.0 among them."""
+    c = np.sort(rng.normal(size=(F, n_cuts)).astype(np.float32), axis=1)
+    if n_cuts:
+        c[:, n_cuts // 2] = 0.0
+        c[:, :n_cuts // 2] = -np.abs(c[:, :n_cuts // 2]) - np.float32(1e-3)
+        c[:, n_cuts // 2 + 1:] = np.abs(c[:, n_cuts // 2 + 1:]) + np.float32(1e-3)
+        c = np.sort(c, axis=1)
+    return c
+
+
 class TestQuantile:
+    @pytest.mark.parametrize("n", [1, 127, 4096])
+    @pytest.mark.parametrize("F", [1, 28, 33])
+    @pytest.mark.parametrize("n_bins", [2, 16, 256, 257, 1024])
+    @pytest.mark.parametrize("missing", [False, True])
+    def test_bins_equal_numpy_searchsorted(self, missing, n_bins, F, n):
+        # element for element, every float32 class: the count of cuts
+        # not above the value IS searchsorted(side="right"); NaN lands in
+        # n_cuts, or in the reserved bin in missing mode (whose cuts are
+        # one narrower: HistGBT's cut-width invariant)
+        rng = np.random.default_rng([n_bins, F, n, missing])
+        n_cuts = n_bins - 2 if missing else n_bins - 1
+        cuts = _edge_cuts(rng, F, n_cuts)
+        x = _edge_matrix(rng, n, cuts)
+
+        def numpy_bins(v):
+            b = np.stack([np.searchsorted(cuts[f], v[:, f], side="right")
+                          for f in range(F)], axis=1)
+            assert (b[np.isnan(v)] == n_cuts).all()      # numpy's NaN rule
+            if missing:
+                b[np.isnan(v)] = n_bins - 1
+            return b
+
+        want = numpy_bins(x)
+        # XLA compares denormals as zero (CPU and TPU, the binary search
+        # before this count alike): a denormal may bin as 0.0 does
+        denormal = (x != 0) & (np.abs(x) < np.finfo(np.float32).tiny)
+        as_zero = numpy_bins(np.where(denormal, np.float32(0), x))
+        xd, cd = jnp.asarray(x), jnp.asarray(cuts)
+        if missing:
+            got = apply_bins_missing(xd, cd, n_bins - 1)
+            got_t = apply_bins_t(xd, cd, miss_bin=n_bins - 1)
+        else:
+            got = apply_bins(xd, cd)
+            got_t = apply_bins_t(xd, cd)
+        assert got.dtype == got_t.dtype == (
+            jnp.uint8 if n_bins <= 256 else jnp.int32)
+        got = np.asarray(got)
+        np.testing.assert_array_equal(np.asarray(got_t), got.T)
+        np.testing.assert_array_equal(np.where(denormal, want, got), want)
+        assert ((got == want) | (got == as_zero))[denormal].all()
+
     def test_cuts_monotone_and_binning_balanced(self, rng):
         x = rng.normal(size=(10000, 3)).astype(np.float32)
         cuts = compute_cuts(x, n_bins=16)

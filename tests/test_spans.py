@@ -16,7 +16,8 @@ import pytest
 from dmlc_core_tpu.models import HistGBT
 from dmlc_core_tpu.models import histgbt as G
 from dmlc_core_tpu.ops import histogram as H
-from dmlc_core_tpu.ops.quantile import apply_bins, local_summary
+from dmlc_core_tpu.ops.quantile import (apply_bins, apply_bins_missing,
+                                        apply_bins_t, local_summary)
 from dmlc_core_tpu.parallel.mesh import local_mesh
 from dmlc_core_tpu.utils.profiler import (global_tracer, set_tracing, span,
                                           tracing_enabled)
@@ -27,8 +28,8 @@ OPERATIONS = {
     "ingest": ("dmlc.ingest",
                ["dmlc.ingest.stream", "dmlc.ingest.host_prep",
                 "dmlc.ingest.cuts", "dmlc.ingest.pad", "dmlc.ingest.labels"],
-               ["dmlc.ingest.put", "dmlc.ingest.bin_dispatch",
-                "dmlc.ingest.concat"]),
+               ["dmlc.ingest.put", "dmlc.ingest.put_wait",
+                "dmlc.ingest.bin_dispatch", "dmlc.ingest.concat"]),
     "ingest_sharded": ("dmlc.ingest",
                        ["dmlc.ingest.stream", "dmlc.ingest.host_prep",
                         "dmlc.ingest.cuts", "dmlc.ingest.pad",
@@ -199,6 +200,42 @@ def test_compiled_programs_carry_their_scopes(program):
                 for line in text.splitlines() if 'op_name="' in line]
     for scope in scopes:
         assert any(scope in name.split("/") for name in op_names), scope
+
+
+# a 20,000 x 28 slab against 255 and 1023 cuts, the widths a fit bins at
+_BIN_ARGS = {n_cuts: (jax.ShapeDtypeStruct((20_000, 28), jnp.float32),
+                      jax.ShapeDtypeStruct((28, n_cuts), jnp.float32))
+             for n_cuts in (255, 1023)}
+_BIN_PROGRAMS = {
+    "apply_bins": lambda a: apply_bins.lower(*a),
+    "apply_bins_missing": lambda a: apply_bins_missing.lower(*a, 255),
+    "apply_bins_t": lambda a: apply_bins_t.lower(*a),
+    "_bin_chunk_fn": lambda a: G._bin_chunk_fn(local_mesh(), None).lower(*a),
+    "_bin_chunk_fn.missing": lambda a: G._bin_chunk_fn(local_mesh(),
+                                                       255).lower(*a),
+}
+
+
+@pytest.mark.parametrize("n_cuts", list(_BIN_ARGS))
+@pytest.mark.parametrize("program", list(_BIN_PROGRAMS))
+def test_binning_is_one_count_over_the_cuts(program, n_cuts):
+    """Binning stays a compare-and-count (PERF.md section 6, PR 28): no
+    binary search comes back (a ``gather`` inside a ``while``: 245x the
+    count's time on the chip), the ``dmlc.bin`` scope is on the
+    reduce, and the CPU backend fuses the compare into it instead of
+    holding the [F, C, n] intermediate (572 MB at this size)."""
+    lowered = _BIN_PROGRAMS[program](_BIN_ARGS[n_cuts])
+    compiled = lowered.compile()
+    for text in (lowered.as_text(), compiled.as_text()):
+        assert "gather" not in text and "while" not in text
+    reduces = [line for line in compiled.as_text().splitlines()
+               if " reduce(" in line]
+    assert reduces
+    for line in reduces:
+        op_name = line.split('op_name="', 1)[1].split('"', 1)[0]
+        assert "dmlc.bin" in op_name.split("/"), line
+    # arguments and result are 2.3 MB + 0.6 MB (2.2 MB as int32)
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
 
 
 @pytest.mark.parametrize("kernel", ["dmlc_hist", "dmlc_fused_descend",
