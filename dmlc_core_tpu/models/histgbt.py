@@ -809,14 +809,14 @@ class HistGBT(_ExternalMemoryEngine):
         path.  Appends trees to ``self.trees``, sets
         ``last_fit_seconds``, returns the final margins.
 
-        Rounds run in chunks of K per dispatch (lax.scan inside the
-        jitted program): per-dispatch + per-fetch latency would
-        otherwise add to every round's compute; trees stay on device
-        until the end.
+        Rounds run in chunks of K per dispatch (lax.scan inside the jitted
+        program): per-dispatch + per-fetch latency would otherwise add to
+        every round's compute; trees stay on device until the end.
         ``after_chunk(done, preds, trees_k) -> stop?`` hooks validation/
         early-stopping between dispatches.
         """
-        with span("dmlc.fit", rounds=self.param.n_trees):
+        with span("dmlc.fit", rounds=self.param.n_trees,
+                  mesh_devices=device_count(self.mesh)):
             return self._boost_rounds(
                 bins_t, y_d, w_d, preds, n_features, eval_every,
                 warmup_rounds, after_chunk, chunk_callback, round_offset)
@@ -1289,12 +1289,12 @@ class HistGBT(_ExternalMemoryEngine):
         inflight: deque = deque()
 
         def put(piece: np.ndarray, k: int):
-            with span("dmlc.ingest.put", bytes=piece.nbytes):
+            with span("dmlc.ingest.put", bytes=piece.nbytes, chip=k):
                 return jax.device_put(piece, devs[k])
 
         def bin_oldest():
             kq, xq = inflight.popleft()
-            with span("dmlc.ingest.bin_dispatch"):
+            with span("dmlc.ingest.bin_dispatch", chip=kq):
                 pieces[kq].append(bin_fn(xq, cuts_dev))
 
         lo = 0

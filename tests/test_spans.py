@@ -127,6 +127,30 @@ def test_host_spans_of_one_operation(traced, operation):
         assert comp[3]["cache"] in ("hit", "miss")
 
 
+def test_sharded_ingest_spans_name_their_chip(traced):
+    """On a mesh a put and the binning it feeds belong to ONE chip: the
+    spans say which (``chip=k``, the position on the ``data`` axis), so a
+    four-chip trace can be read chip by chip.  The one-chip ingest has
+    no chip to name."""
+    def chips(operation, name):
+        return [e[3].get("chip") for e in traced[operation] if e[0] == name]
+
+    puts = chips("ingest_sharded", "dmlc.ingest.put")
+    ndev = len(jax.devices())
+    # 3000 rows in 1000-row slabs over 375-row shards: every chip owns
+    # a piece, a slab is cut where it straddles a boundary
+    assert set(puts) == set(range(ndev)) and len(puts) > ndev
+    assert puts == sorted(puts)                    # global row order
+    assert chips("ingest_sharded", "dmlc.ingest.bin_dispatch") == puts
+    assert set(chips("ingest", "dmlc.ingest.put")) == {None}
+    assert set(chips("ingest", "dmlc.ingest.bin_dispatch")) == {None}
+
+
+def test_fit_span_names_its_mesh(traced):
+    (fit,) = [e for e in traced["fit"] if e[0] == "dmlc.fit"]
+    assert fit[3]["mesh_devices"] == 1 and fit[3]["rounds"] == 4
+
+
 def test_two_operations_carry_two_ops(traced):
     ops = {name: {e[3]["op"] for e in evs} for name, evs in traced.items()}
     assert all(len(v) == 1 for v in ops.values())
