@@ -3064,19 +3064,102 @@ class HistGBT(_ExternalMemoryEngine):
 _TREE_CHUNK = 64
 
 
-def _descend_step(bins, feat, thr, dirv, node, miss_bin):
-    """One level of tree descent shared by the predict programs: select
-    the node's feature bin and route right on bin > thr, with missing
-    rows (bin == miss_bin; only produced in missing mode) following the
-    node's learned direction (1 = left)."""
-    f = feat[node]
-    t = thr[node]
-    row_bin = jnp.take_along_axis(bins, f[:, None], axis=1)[:, 0]
-    go_right = row_bin > t
-    if dirv is not None:
-        d = dirv[node]
-        go_right = jnp.where(row_bin == miss_bin, d == 0, go_right)
-    return 2 * node + go_right.astype(jnp.int32)
+#: row-trees one block of the descent walks at once: each of a block's
+#: [trees, rows] intermediates is 4 MiB of int32, whatever n and T are
+_DESCEND_BLOCK = 1 << 20
+
+#: a block's rows are laid out [rows/128, 128] behind the tree axis —
+#: whole (8, 128) tiles, so a tree's table entry is a scalar on the VPU
+_ROW_TILE = 8 * 128
+
+#: longest axis :func:`_select` sums at once; a longer one is folded in
+#: two (why: ``_CUT_FOLD`` in ops/quantile.py — XLA:CPU cannot fuse the
+#: compare into a longer reduce, the TPU compiler does not care)
+_SELECT_FOLD = 32
+
+
+def _select(entries, idx):
+    """``entries[:, idx]`` along axis 1 with no gather: compare ``idx``
+    [trees, rows/128, 128] with every position and sum the one entry
+    that matches (``table_select`` of the round program, vectorised over
+    trees).  ``entries`` broadcasts against ``idx`` on its other axes:
+    [trees, N, 1, 1] is a table per tree, [1, F, rows/128, 128] a value
+    per row.  A TPU does a gather one element at a time (12.8 ns a node
+    visit on a v5e: PERF.md §6, PR 30); this is one loop fusion.  Exact:
+    one term of the sum is not zero."""
+    n, tail = entries.shape[1], entries.shape[2:]
+    groups = -(-n // _SELECT_FOLD)
+    width = -(-n // groups)
+    # positions past n match no index
+    entries = jnp.pad(entries, ((0, 0), (0, groups * width - n))
+                      + ((0, 0),) * len(tail))
+    entries = entries.reshape((-1, groups, width) + tail)
+    pos = jnp.arange(groups * width, dtype=jnp.int32).reshape(
+        (1, groups, width) + (1,) * len(tail))
+    return jnp.sum(jnp.where(idx[:, None, None] == pos, entries, 0),
+                   axis=(1, 2))
+
+
+def _descend(bins_t, feats, thrs, dirs, depth: int, miss_bin: int):
+    """Leaf position [trees, rows/128, 128] of a block's rows in a
+    block's trees, level by level and gather-free: at each level select
+    the row's node's feature, then the row's bin of that feature, and
+    route right on bin > thr; missing rows (bin == miss_bin; only
+    produced in missing mode) follow the node's learned direction
+    (1 = left).  ``bins_t`` is [F, rows/128, 128] int32, the tables
+    [trees, depth, half]."""
+    node = jnp.zeros(feats.shape[:1] + bins_t.shape[1:], jnp.int32)
+    for level in range(depth):
+        def of_node(table):
+            # (indexed in two steps: one mixed index lowers to a gather)
+            return _select(table[:, level, :1 << level][:, :, None, None],
+                           node)
+        row_bin = _select(bins_t[None], of_node(feats))
+        go_right = row_bin > of_node(thrs)
+        if dirs is not None:
+            go_right = jnp.where(row_bin == miss_bin, of_node(dirs) == 0,
+                                 go_right)
+        node = 2 * node + go_right.astype(jnp.int32)
+    return node
+
+
+def _descend_blocks(n: int, n_trees: int) -> Tuple[int, int, int]:
+    """(row blocks, rows a block, tree blocks) for n rows × n_trees:
+    at most ``_TREE_CHUNK`` trees and ``_DESCEND_BLOCK`` row-trees a
+    block, rows in whole tiles — static functions of the shapes, so
+    memory is bounded whatever n is."""
+    tree_blocks = -(-n_trees // _TREE_CHUNK)
+    trees = -(-n_trees // tree_blocks)
+    rows = max(_DESCEND_BLOCK // trees // _ROW_TILE, 1) * _ROW_TILE
+    rows = min(rows, -(-n // _ROW_TILE) * _ROW_TILE)
+    return -(-n // rows), rows, tree_blocks
+
+
+def _row_blocks(a, row_blocks: int, rows: int):
+    """``a`` [n, ...] → [row_blocks, ..., rows/128, 128], zero-padded.
+    Row i sits in block ``i % row_blocks``: strided, so that a sharding
+    of n over a mesh stays a sharding of every block's rows (contiguous
+    blocks would put each block on one device)."""
+    a = jnp.pad(a, ((0, row_blocks * rows - a.shape[0]),)
+                + ((0, 0),) * (a.ndim - 1))
+    a = jnp.moveaxis(a.reshape((rows, row_blocks) + a.shape[1:]), 0, -1)
+    return a.reshape(a.shape[:-1] + (rows // 128, 128))
+
+
+def _rows_of_blocks(a, n: int):
+    """Inverse of :func:`_row_blocks`: [row_blocks, ..., rows/128, 128]
+    → [n, ...]."""
+    a = jnp.moveaxis(a.reshape(a.shape[:-2] + (-1,)), -1, 0)
+    return a.reshape((-1,) + a.shape[2:])[:n]
+
+
+def _tree_blocks(a, tree_blocks: int):
+    """``a`` [T, ...] → [tree_blocks, T/tree_blocks, ...], padded with
+    all-zero trees (they select leaf 0 = 0.0, as ``_stacked_trees``'s)."""
+    trees = -(-a.shape[0] // tree_blocks)
+    a = jnp.pad(a, ((0, tree_blocks * trees - a.shape[0]),)
+                + ((0, 0),) * (a.ndim - 1))
+    return a.reshape((tree_blocks, trees) + a.shape[1:])
 
 
 @partial(jax.jit, static_argnums=(4, 8))
@@ -3084,44 +3167,60 @@ def _descend_step(bins, feat, thr, dirv, node, miss_bin):
 def _predict_trees(bins, feats, thrs, leaves, depth: int,
                    base_score: float = 0.0, init=None,
                    dirs=None, miss_bin: int = -1):
-    """Sum leaf values over trees: scan over trees, unrolled descent.
+    """Sum leaf values over trees: a dense, gather-free descent
+    (:func:`_descend`) over blocks of rows × blocks of trees.
 
     ``init`` carries margins from already-applied trees (the incremental
     validation path); otherwise margins start at ``base_score``.
-    ``dirs``/``miss_bin`` enable missing-mode routing (see
-    :func:`_descend_step`).
+    ``dirs``/``miss_bin`` enable missing-mode routing.  A row's answer
+    depends on neither the block sizes nor the other rows of the call.
     """
-
-    def one_tree(carry, tree):
-        feat, thr, dirv, leaf = tree
-        node = jnp.zeros(bins.shape[0], jnp.int32)
-        for _level in range(depth):
-            node = _descend_step(
-                bins, feat[_level], thr[_level],
-                None if dirv is None else dirv[_level], node, miss_bin)
-        return carry + leaf[node], None
-
+    n = bins.shape[0]
     if init is None:
-        init = jnp.full(bins.shape[0], base_score, jnp.float32)
-    total, _ = jax.lax.scan(one_tree, init, (feats, thrs, dirs, leaves))
-    return total
+        init = jnp.full(n, base_score, jnp.float32)
+    row_blocks, rows, tree_blocks = _descend_blocks(n, feats.shape[0])
+    # (a tree map skips dirs=None)
+    trees = jax.tree.map(partial(_tree_blocks, tree_blocks=tree_blocks),
+                         (feats, thrs, dirs, leaves))
+
+    def row_block(block):
+        bins_t, margin = block
+        bins_t = bins_t.astype(jnp.int32)
+
+        def tree_block(margin, tree):
+            feat, thr, dirv, leaf = tree
+            node = _descend(bins_t, feat, thr, dirv, depth, miss_bin)
+            vals = _select(leaf[:, :, None, None], node)
+            # in tree order, in float32: the summation order of the
+            # incremental updates that built the margins
+            for t in range(vals.shape[0]):
+                margin = margin + vals[t]
+            return margin, None
+
+        return jax.lax.scan(tree_block, margin, trees)[0]
+
+    return _rows_of_blocks(
+        jax.lax.map(row_block, (_row_blocks(bins, row_blocks, rows),
+                                _row_blocks(init, row_blocks, rows))), n)
 
 
 @partial(jax.jit, static_argnums=(3, 5))
+@jax.named_scope("dmlc.descend")
 def _leaf_indices(bins, feats, thrs, depth: int, dirs=None,
                   miss_bin: int = -1):
-    """Per-tree leaf assignment [n, T] (predict_leaf); same unrolled
-    descent as _predict_trees, collecting the final node instead of
-    summing leaf values."""
+    """Per-tree leaf assignment [n, T] (predict_leaf); the same
+    :func:`_descend` as _predict_trees, collecting the final node instead
+    of summing leaf values."""
+    n, n_trees = bins.shape[0], feats.shape[0]
+    row_blocks, rows, tree_blocks = _descend_blocks(n, n_trees)
+    trees = jax.tree.map(partial(_tree_blocks, tree_blocks=tree_blocks),
+                         (feats, thrs, dirs))
 
-    def one_tree(_, tree):
-        feat, thr, dirv = tree
-        node = jnp.zeros(bins.shape[0], jnp.int32)
-        for _level in range(depth):
-            node = _descend_step(
-                bins, feat[_level], thr[_level],
-                None if dirv is None else dirv[_level], node, miss_bin)
-        return 0, node
+    def row_block(bins_t):
+        bins_t = bins_t.astype(jnp.int32)
+        nodes = jax.lax.map(
+            lambda tree: _descend(bins_t, *tree, depth, miss_bin), trees)
+        return nodes.reshape((-1,) + nodes.shape[2:])[:n_trees]
 
-    _, nodes = jax.lax.scan(one_tree, 0, (feats, thrs, dirs))   # [T, n]
-    return nodes.T
+    return _rows_of_blocks(
+        jax.lax.map(row_block, _row_blocks(bins, row_blocks, rows)), n)
