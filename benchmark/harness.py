@@ -13,7 +13,9 @@ every shape the window uses) -> the measured window, a closed loop of
 whole operations: an operation starts while the window is open, the one in
 flight finishes and counts -> the peak memory -> ``correct`` against the
 plain reference (outside the window, not counted in ``setup_s``) -> one
-JSON object as the last line of standard output.
+JSON object as the last line of standard output, every number compared
+beside its limit under its last key ``compared`` and, after it, on the
+last lines of standard error.
 """
 
 from __future__ import annotations
@@ -211,6 +213,12 @@ def per_layer_metrics(bench, ctx: Ctx, logdir: str) -> Dict[str, Any]:
     return out
 
 
+def comparison_line(name: str, c: Dict[str, Any]) -> str:
+    """One number compared, beside its limit, as a run prints it."""
+    return (f"[correct] {name}: {c['value']!r} ({c['passes']} "
+            f"{c['limit']!r}) {'ok' if c['ok'] else 'NOT OK'}")
+
+
 # -- one run -----------------------------------------------------------------------
 
 def run_cell(root: str, workload: str, seed: int, seconds: float,
@@ -282,9 +290,7 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
             say(f"[bench] check raised {type(e).__name__}: {e}")
             ctx.compare("check.raised", 1, 0)
     for c in ctx.comparisons:
-        say(f"[correct] {c['name']}: {c['value']!r} "
-            f"({c['passes']} {c['limit']!r}) "
-            f"{'ok' if c['ok'] else 'NOT OK'}")
+        say(comparison_line(c["name"], c))
     correct = bool(ctx.op_seconds) and all(c["ok"] for c in ctx.comparisons)
     say(f"[bench] check took {time.perf_counter() - t_check:.3f} s")
 
@@ -304,6 +310,10 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
             "idle_gaps": [[n, s] for n, s in ctx.summary.top_gaps(10)]}
         say(f"[bench] trace reduced in {time.perf_counter() - t_red:.3f} s")
     out["device"] = device
+    # every number compared beside its limit, last in the line
+    out["compared"] = {c["name"]: {k: c[k] for k in ("value", "limit",
+                                                     "passes", "ok")}
+                       for c in ctx.comparisons}
     return out
 
 
@@ -325,4 +335,7 @@ def main(argv: Optional[List[str]] = None, root: Optional[str] = None,
         print(f"[bench] refused: {e}", file=sys.stderr)
         return 2
     print(json.dumps(out), flush=True)
+    # ... and as the last lines of standard error
+    for name, c in out["compared"].items():
+        print(comparison_line(name, c), file=sys.stderr)
     return 0

@@ -14,7 +14,7 @@ import util
 from benchmark import harness, xplane
 
 SEED = 2**31 + 77
-KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+KEYS = {"correct", "attempted", "failed", "metrics", "device", "compared"}
 
 
 def run(root, cell, trace=False, seconds=0.3):
@@ -38,9 +38,36 @@ def test_cells_run_and_are_correct(tmp_path, mix, metric):
     assert out["metrics"]["setup_s"]["unit"] == "s"
     assert set(out["device"]) == {"platform", "kind", "count",
                                   "memory_peak_bytes"}
-    # every number compared is printed beside its limit
+    # every number compared is printed beside its limit, and is the
+    # result line's last key
     assert any(ln.startswith("[correct] ") and "at_most" in ln
                for ln in lines)
+    assert list(out)[-1] == "compared" and out["compared"]
+    assert all(set(c) == {"value", "limit", "passes", "ok"}
+               for c in out["compared"].values())
+
+
+def test_the_score_cell_keeps_only_what_its_check_reads(tmp_path, monkeypatch):
+    """However many calls a window holds, the operation keeps the first
+    lap and a seeded sample of them: holding every answer slowed the calls
+    it was timing (PERF.md section 2, PR 32)."""
+    from benchmark import checks
+
+    seen = {}
+    real = checks.apply_limits
+
+    def spy(ctx, numbers):
+        seen["state"] = ctx.state
+        return real(ctx, numbers)
+
+    monkeypatch.setattr(checks, "apply_limits", spy)
+    out, lines = run(util.make_root(tmp_path), "tiny.tiny-score")
+    st = seen["state"]
+    assert out["correct"] is True, lines
+    assert st["calls"] == out["attempted"] > st["n_slabs"] + st["want"]
+    assert len(st["first_lap"]) == st["n_slabs"]
+    assert len({i for i, _o in st["sample"]}) == len(st["sample"]) == st["want"]
+    assert all(len(o) == st["rows"] for _i, o in st["sample"])
 
 
 def fake_trace(monkeypatch):

@@ -2,7 +2,8 @@
 all-reduce and ``dmlc.round.L<d>.sync`` times; each reads nothing on one
 chip; and the shipped ``BENCHMARK.json`` whole: every cell's
 configuration file, mix, operation and every reader it lists is found by
-name, as the harness finds them."""
+name, as the harness finds them, and its bounds and texts are within
+the contract."""
 
 import json
 import os
@@ -146,6 +147,23 @@ def test_shipped_lists_name_cells_that_exist():
         assert set(m.get("workloads", [])) <= cells, m["name"]
     four = [w for w in bench["workloads"] if w["chips"] == 4]
     assert len(four) <= max(1, len(cells) // 4)
+
+
+def test_shipped_bounds_and_texts_are_within_the_contract():
+    """Every end-to-end metric has a bound a check can hold a PR to (over
+    0, at most the contract's 0.1; ``setup_s`` the rule's 0.1), and every
+    text the contract limits is there and fits."""
+    bench = shipped()
+    for m in bench["end_to_end"]:
+        assert isinstance(m.get("bound"), float), m["name"]
+        assert 0 < m["bound"] <= 0.1, m["name"]
+    (setup,) = [m for m in bench["end_to_end"] if m["name"] == "setup_s"]
+    assert setup["bound"] == 0.1
+    texts = ([(w["name"], w["why"]) for w in bench["workloads"]]
+             + [(c["name"], c[k]) for c in bench["configs"]
+                for k in ("source", "why")])
+    for name, text in texts:
+        assert 0 < len(text) <= 200 and "\n" not in text, name
 
 
 @pytest.mark.parametrize("metric", SYNC_METRICS)
