@@ -323,7 +323,7 @@ def _install_guards(deadline):
 
 def _derived_metrics(rows, feats, depth, n_bins, seconds_per_round, peak,
                      n_chips=1, layout=None, grow_policy="depthwise",
-                     max_leaves=0, fused=False, quant=False):
+                     max_leaves=0, fused=False):
     """Auditable per-round cost model of the sibling-subtracted round.
 
     MXU flops: per level ℓ the Pallas histogram dot is [A, T]·[T, lo]
@@ -339,9 +339,8 @@ def _derived_metrics(rows, feats, depth, n_bins, seconds_per_round, peak,
     ISSUE 12 lever evidence: bin-matrix bytes one round's passes pull
     from HBM, and how many node histograms the round actually builds
     (loss-guide builds ``max_leaves`` instead of ``2^(depth-1)``).
-    ``fused``/``quant`` are the ISSUE 18 levers: the fused round kernel
-    halves the bin-matrix passes (descend rides the histogram read) and
-    the int8 sync shrinks each synced node ~4×."""
+    ``fused`` is the ISSUE 18 lever: the fused round kernel halves the
+    bin-matrix passes (descend rides the histogram read)."""
     from dmlc_core_tpu.ops.histogram import (_lo_factor,
                                              bins_bytes_per_round,
                                              hist_psum_bytes_per_round,
@@ -353,7 +352,7 @@ def _derived_metrics(rows, feats, depth, n_bins, seconds_per_round, peak,
     # dmlc_histogram_psum_bytes_total counter the engine increments
     psum_bytes = hist_psum_bytes_per_round(
         depth, feats, n_bins, layout=layout, grow_policy=grow_policy,
-        max_leaves=max_leaves, quant=quant)
+        max_leaves=max_leaves)
     sync_bins = layout.sync_bins if layout is not None else n_bins
     for level in range(depth):
         n_build = 1 if level == 0 else 1 << (level - 1)
@@ -385,7 +384,6 @@ def _derived_metrics(rows, feats, depth, n_bins, seconds_per_round, peak,
                            f"{layout.n_features}F->{layout.phys_rows}rows"
                            f"/{len(layout.pairs)}pairs"),
             "fused_round": fused,
-            "hist_quant": quant,
         },
     }
 
@@ -2077,8 +2075,6 @@ def main() -> None:
                             int(os.environ["DMLC_MAX_LEAVES"] or 0),
                         "fused_round":
                             os.environ.get("DMLC_FUSED_ROUND", "auto"),
-                        "hist_quant":
-                            os.environ.get("DMLC_HIST_QUANT", "0") == "1",
                     }}
 
     # chips=N mode (ISSUE 7): BENCH_CHIPS pins the data-mesh width (0 /
@@ -2181,7 +2177,7 @@ def main() -> None:
         # {trace, dispatch, device} attribution of warm_dispatch (the
         # r06 regression lever: 98 s of "warm dispatch" was the exec
         # warmup running the full K-round chunk on CPU — now the exec
-        # is DMLC_WARMUP_EXEC-gated and trace = inline AOT compile)
+        # runs on a TPU backend only and trace = inline AOT compile)
         if model.last_warmup_breakdown is not None:
             out["warmup_breakdown"] = model.last_warmup_breakdown
         out["compile_cache"] = model.last_compile_cache or "warm"
@@ -2247,8 +2243,7 @@ def main() -> None:
         layout=model._bin_layout,
         grow_policy=model.round_plan["grow_policy"],
         max_leaves=int(os.environ.get("DMLC_MAX_LEAVES", "0") or 0),
-        fused=model.round_plan["fused_round"],
-        quant=model.round_plan["hist_quant"]))
+        fused=model.round_plan["fused_round"]))
     official["round_plan"] = model.round_plan
     EV["official"] = official
     EV["runs"] = runs

@@ -301,7 +301,7 @@ def _node_ids(rng, n, n_nodes):
 def rollcall_phase(sm: Smoke, cfg: SmokeConfig) -> Dict[str, Any]:
     """Every Pallas kernel of ops/histogram.py at the run's widths vs
     the segment engine: ``_hist_pallas`` plain and int4-packed,
-    ``fused_round`` plain and with the layout, ``_fused_pallas``."""
+    ``fused_round`` plain and with the layout."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -395,10 +395,10 @@ def rollcall_phase(sm: Smoke, cfg: SmokeConfig) -> Dict[str, Any]:
             prev, left = build(nid_d), build(node_h)
             both = jnp.stack([left, prev - left], axis=2).reshape(
                 2, 2 * n_prev, left.shape[2], left.shape[3])
-            return new, prev, np.asarray(left), np.asarray(both)
+            return new, prev, np.asarray(both)
 
         def fused(bins_d, lay):
-            new, prev, _, both = staged(bins_d, lay)
+            new, prev, both = staged(bins_d, lay)
             fn = jax.jit(lambda b, i, f, t, gg, hh, pv: H.fused_round(
                 b, i, f, t, gg, hh, pv, n_prev, B, tile_rows=T,
                 layout=lay)[:2])
@@ -406,17 +406,9 @@ def rollcall_phase(sm: Smoke, cfg: SmokeConfig) -> Dict[str, Any]:
             return (np.array_equal(np.asarray(new_f), new)
                     and np.array_equal(np.asarray(hist_f), both))
 
-        def fused_descend():
-            new, _, left, _ = staged(plain_d, None)
-            hist_f, new_f = H._fused_pallas(plain_d, nid_d, fs_d, ts_d,
-                                            g_d, h_d, n_prev, B, T)
-            return (np.array_equal(np.asarray(new_f), new)
-                    and np.array_equal(np.asarray(hist_f), left))
-
         case(f"fused_round[n_prev={n_prev}]", lambda: fused(plain_d, None))
         case(f"fused_round+layout[n_prev={n_prev}]",
              lambda: fused(phys, layout))
-        case(f"_fused_pallas[n_prev={n_prev}]", fused_descend)
     rep["all_ok"] = all(k["ok"] for k in rep["kernels"].values())
     return rep
 

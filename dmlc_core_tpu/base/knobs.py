@@ -162,9 +162,6 @@ declare("DMLC_TPU_ROUNDS_PER_DISPATCH", 25,
         "engine.", "gbt")
 declare("DMLC_TPU_SPARSE_ROUNDS_PER_DISPATCH", 8,
         "Rounds fused per device dispatch in the sparse engine.", "gbt")
-declare("DMLC_TPU_FUSED_DESCEND", "0",
-        "1 selects the fused tree-descent prediction kernel "
-        "variant.", "gbt")
 declare("DMLC_TPU_BIN_BACKEND", "",
         "'cpu' forces host-numpy feature binning; empty bins on "
         "device.", "gbt")
@@ -177,13 +174,6 @@ declare("DMLC_TPU_EXTERNAL_DEVICE_BUDGET", 6 << 30,
 declare("DMLC_INGEST_CHUNK_ROWS", 2_000_000,
         "Rows per double-buffered host-to-device ingest slab "
         "(cold-start streaming).", "gbt")
-declare("DMLC_COLDSTART_OVERLAP", "1",
-        "0 restores the serial bin-then-compile cold start (no "
-        "ingest/compile overlap).", "gbt")
-declare("DMLC_SHARDED_INGEST", "1",
-        "0 restores the single global device_put staging path; 1 "
-        "streams each chip's row slice onto that chip only "
-        "(bit-identical either way).", "gbt")
 declare("DMLC_HIST_BLOCKS", 0,
         "N>0 enables the mesh-shape-invariant deterministic histogram "
         "reduction with N fixed row blocks (rounded up to a power of "
@@ -216,18 +206,6 @@ declare("DMLC_FUSED_ROUND", "auto",
         "off-TPU — the byte-parity test hook), '0' pins the "
         "three-dispatch path; save_model bytes identical either "
         "way.", "gbt")
-declare("DMLC_HIST_QUANT", "0",
-        "1 quantizes the multi-chip histogram sync to int8 codes plus "
-        "an exact f32 per-column total (the correction term): ~4x "
-        "fewer allreduce bytes at n_bins=256, bounded per-cell error "
-        "(n_chips*scale/2), EXACT per-(node,feature) grad/hess totals. "
-        "No-op on one chip and under DMLC_HIST_BLOCKS.", "gbt")
-declare("DMLC_WARMUP_EXEC", "auto",
-        "Whether the fit warmup EXECUTES the round programs after "
-        "compiling them: 'auto' executes on TPU only (first dispatch "
-        "pays real staging there), '1' forces execution everywhere, "
-        "'0' compiles/AOT-warms only — on CPU an exec-warmup just runs "
-        "the whole first dispatch chunk twice.", "gbt")
 declare("DMLC_FEATURE_BUNDLE", "0",
         "1 fuses mutually-exclusive (near-one-hot) feature blocks into "
         "one multi-bin storage feature (LightGBM's EFB with the "

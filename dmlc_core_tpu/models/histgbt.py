@@ -33,7 +33,7 @@ from __future__ import annotations
 import os
 from collections import deque
 from functools import lru_cache, partial
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -43,7 +43,6 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dmlc_core_tpu.base import compile_cache as _cc
-from dmlc_core_tpu.base import knobs as _knobs
 from dmlc_core_tpu.base import metrics as _metrics
 from dmlc_core_tpu.base.logging import CHECK, CHECK_EQ, LOG, log_fatal
 from dmlc_core_tpu.base.parameter import Parameter, field, get_env
@@ -53,12 +52,10 @@ from dmlc_core_tpu.data.device_feed import assemble_row_sharded
 from dmlc_core_tpu.data.iter import slab_shard_slices
 from dmlc_core_tpu.ops import binlayout as _bl
 from dmlc_core_tpu.ops.histogram import (build_histogram,
-                                         dequantize_hist_sum,
-                                         fused_descend_histogram,
+                                         descend_histogram,
                                          fused_round, fused_round_ok,
                                          hist_psum_bytes_per_round,
                                          pallas_interpret,
-                                         quantize_hist_partial,
                                          resolve_hist_method,
                                          select_feature_bins)
 from dmlc_core_tpu.ops.quantile import (apply_bins, apply_bins_missing,
@@ -194,29 +191,38 @@ def _fused_round_mode() -> str:
     return v
 
 
-def _hist_quant_requested() -> bool:
-    """``DMLC_HIST_QUANT=1``: int8-quantized histogram sync — each chip
-    psums int8 partial-histogram codes plus an exact f32 per-column
-    total (the correction term) instead of raw f32 cells, cutting
-    allreduce bytes ~4× at n_bins=256.  Approximate (bounded cell
-    error, exact column totals); default off, no-op on one chip and
-    under the DMLC_HIST_BLOCKS deterministic fold (which stays exact)."""
-    return os.environ.get("DMLC_HIST_QUANT", "0") == "1"
+class _RoundPlan(NamedTuple):
+    """Every choice the traced round program bakes in beyond the
+    ``Parameter``'s fields and the mesh, as :meth:`HistGBT._round_plan`
+    resolved it.  Hashable: it IS the non-param part of the
+    round-program cache key, and the only thing besides the param that
+    ``_build_round_fn`` reads."""
+    n_features: int
+    #: histogram engine of each BUILD in tree order
+    hist_method: Tuple[str, ...]
+    fused_round: bool
+    pallas_interpret: bool
+    grow_policy: str
+    #: loss-guide leaf budget (0 = the depth cap alone)
+    max_leaves: int
+    layout: Optional[_bl.BinLayout]
+    hist_blocks: int
+    mesh_devices: int
 
-
-def _warmup_exec_mode() -> str:
-    """``DMLC_WARMUP_EXEC``: whether the warmup ladder EXECUTES the
-    round programs after compiling them.  ``auto`` (default) executes
-    only on TPU backends, where the first dispatch pays real one-time
-    staging (H2D layout, SMEM program load) worth pulling out of the
-    timed region; on CPU the compiled programs have no such cost and an
-    exec-warmup would just run the whole K-round chunk twice.  ``1``
-    forces the execution everywhere, ``0`` never executes (compile/AOT
-    warm only)."""
-    v = os.environ.get("DMLC_WARMUP_EXEC", "auto")
-    CHECK(v in ("auto", "0", "1"),
-          f"DMLC_WARMUP_EXEC must be 'auto', '0' or '1', got {v!r}")
-    return v
+    def describe(self) -> Dict[str, Any]:
+        """The JSON-serialisable record left on ``HistGBT.round_plan``."""
+        lay = self.layout
+        return {
+            "hist_method": list(self.hist_method),
+            "fused_round": self.fused_round,
+            "pallas_interpret": self.pallas_interpret,
+            "grow_policy": self.grow_policy,
+            "bin_layout": (None if lay is None else
+                           f"{lay.n_features}F->{lay.phys_rows}rows"
+                           f"/{len(lay.pairs)}pairs"),
+            "hist_blocks": self.hist_blocks,
+            "mesh_devices": self.mesh_devices,
+        }
 
 
 #: device phase of the transposes and concats that put binned slabs into
@@ -299,9 +305,7 @@ class _RoundProgramWarmup:
     def __init__(self, model: "HistGBT", n_features: int, n_padded: int,
                  eval_every: int = 0) -> None:
         p = model.param
-        self.n_features = n_features
-        self.n_padded = n_padded
-        self.K, self.rem = _rounds_schedule(p.n_trees, eval_every)
+        K, rem = _rounds_schedule(p.n_trees, eval_every)
         sampling = p.subsample < 1.0 or p.colsample_bytree < 1.0
         mesh = model.mesh
         mat = NamedSharding(mesh, P(None, "data"))
@@ -309,9 +313,11 @@ class _RoundProgramWarmup:
         margin = (NamedSharding(mesh, P("data", None))
                   if p.num_class > 1 else row)
         # packed/bundled layouts change the PHYSICAL bin-matrix height;
-        # the layout is part of the cache key so a mismatch between what
-        # was warmed and what fit dispatches is caught by key equality
-        lay = model._bin_layout
+        # the layout is part of the plan, hence of the cache key, so a
+        # mismatch between what was warmed and what fit dispatches is
+        # caught by key equality
+        plan = model._round_plan(n_features)
+        lay = plan.layout
         mat_rows = lay.phys_rows if lay is not None else n_features
         args = [
             jax.ShapeDtypeStruct((mat_rows, n_padded), np.uint8,
@@ -325,15 +331,14 @@ class _RoundProgramWarmup:
             args.append(jax.random.key(0))   # concrete: tiny, typed aval
         self._keys: Dict[str, tuple] = {}
         jobs: Dict[str, Any] = {}
-        for label, n_rounds in (("kfn", self.K), ("rem", self.rem)):
+        for label, n_rounds in (("kfn", K), ("rem", rem)):
             if n_rounds == 0:
                 continue
-            key = (model._round_fn_cache_key(n_features, n_rounds),
-                   n_features, n_padded)
+            key = (model._round_fn_cache_key(plan, n_rounds), n_padded)
             self._keys[label] = key
             if key in _AOT_EXEC_CACHE:
                 continue                     # warmed by an earlier fit
-            jobs[label] = partial(self._compile, model, n_features,
+            jobs[label] = partial(self._compile, model, plan,
                                   n_rounds, tuple(args))
         self._bg = (_cc.BackgroundCompiler(jobs, what="incore_round")
                     if jobs else None)
@@ -342,10 +347,9 @@ class _RoundProgramWarmup:
         self.cache_verdict: Optional[str] = None
 
     @staticmethod
-    def _compile(model: "HistGBT", n_features: int, n_rounds: int,
+    def _compile(model: "HistGBT", plan: _RoundPlan, n_rounds: int,
                  args: tuple):
-        fn = model._build_round_fn(n_features, n_rounds)
-        return fn.lower(*args).compile()
+        return model._build_round_fn(plan, n_rounds).lower(*args).compile()
 
     def join(self) -> Dict[str, Any]:
         """Block until compiles finish (re-raising a failed one);
@@ -362,18 +366,13 @@ class _RoundProgramWarmup:
                 for label, key in self._keys.items()
                 if key in _AOT_EXEC_CACHE}
 
-    def matches(self, round_key_fn, n_features: int, n_padded: int,
+    def matches(self, model: "HistGBT", plan: _RoundPlan, n_padded: int,
                 K: int, rem: int) -> bool:
         """True iff the warmed programs are exactly the ones the
-        imminent fit will dispatch."""
-        if (self.n_features, self.n_padded, self.K, self.rem) != \
-                (n_features, n_padded, K, rem):
-            return False
-        expect = {("kfn", K), ("rem", rem)} - {("rem", 0)}
-        return all(
-            self._keys.get(label) == (round_key_fn(n_features, n_rounds),
-                                      n_features, n_padded)
-            for label, n_rounds in expect)
+        imminent fit will dispatch (the plan carries ``n_features``)."""
+        return self._keys == {
+            label: (model._round_fn_cache_key(plan, n_rounds), n_padded)
+            for label, n_rounds in (("kfn", K), ("rem", rem)) if n_rounds}
 
 
 @lru_cache(maxsize=32)
@@ -531,8 +530,8 @@ class HistGBT(_ExternalMemoryEngine):
         self.last_warm_dispatch_seconds: Optional[float] = None
         #: {trace, dispatch, device} split of warm_dispatch: trace =
         #: inline lower+compile of the dispatch programs; dispatch =
-        #: async-enqueue wall of the (DMLC_WARMUP_EXEC-gated) exec
-        #: warmup; device = its completion fetch.  Attributes a warmup
+        #: async-enqueue wall of the exec warmup (a TPU backend only);
+        #: device = its completion fetch.  Attributes a warmup
         #: regression to re-tracing vs dispatch latency vs device time.
         self.last_warmup_breakdown: Optional[Dict[str, float]] = None
         self.last_compile_cache: Optional[str] = None
@@ -541,15 +540,16 @@ class HistGBT(_ExternalMemoryEngine):
         self.last_dispatch: Optional[str] = None
         #: what the round program runs, resolved by
         #: :meth:`_round_plan` before tracing — histogram engine per
-        #: level, fused-round engagement, bin layout, sync mode.  The
-        #: ONE record of those choices: ``_build_round_fn`` builds from
-        #: it, ``chip_smoke.py`` and the benchmark read it.
+        #: level, fused-round engagement, growth policy, bin layout,
+        #: deterministic blocks, mesh width.  The JSON view of the
+        #: :class:`_RoundPlan` that ``_build_round_fn`` builds from;
+        #: ``chip_smoke.py`` and the benchmark read it.
         self.round_plan: Optional[Dict[str, Any]] = None
         self._pending_warmup: Optional[_RoundProgramWarmup] = None
         #: active packed/bundled bin layout (ops.binlayout.BinLayout) of
         #: the device-resident bin matrix, or None for the plain uint8
-        #: [F, n] layout.  Set by make_device_data, consumed by
-        #: _build_round_fn (part of the round-program cache key).
+        #: [F, n] layout.  Set by make_device_data, taken into the plan
+        #: by _round_plan (hence part of the round-program cache key).
         self._bin_layout: Optional[_bl.BinLayout] = None
         self.best_iteration: Optional[int] = None
         self.best_score: Optional[float] = None
@@ -868,9 +868,12 @@ class HistGBT(_ExternalMemoryEngine):
         if warm is not None:
             with span("dmlc.fit.join_warmup"):
                 execs = warm.join()          # never leave workers behind
+        # the ONE resolution of this fit's program choices: the key, the
+        # build and the byte accounting below all read ``plan``
+        plan = self._round_plan(n_features)
+        if warm is not None:
             if shardings_ok and warm.matches(
-                    self._round_fn_cache_key, n_features,
-                    int(bins_t.shape[1]), K, rem):
+                    self, plan, int(bins_t.shape[1]), K, rem):
                 kfn = execs.get("kfn")
                 rem_fn = execs.get("rem")
                 join_wait = warm.join_wait_seconds
@@ -880,8 +883,8 @@ class HistGBT(_ExternalMemoryEngine):
         # the shared jitted program is resolved EITHER way (a dict hit
         # when the warmup worker or an earlier fit built it): it keeps
         # the process-wide ``_round_fn`` sharing contract
-        kfn_jit = self._build_round_fn(n_features, K)
-        rem_jit = self._build_round_fn(n_features, rem) if rem else None
+        kfn_jit = self._build_round_fn(plan, K)
+        rem_jit = self._build_round_fn(plan, rem) if rem else None
         if kfn is None:
             kfn = kfn_jit
         if rem and rem_fn is None:
@@ -924,17 +927,19 @@ class HistGBT(_ExternalMemoryEngine):
                     rem_fn = rem_jit.lower(*aot_args).compile()
                 if shardings_ok:
                     n_padded = int(bins_t.shape[1])
-                    _AOT_EXEC_CACHE[(self._round_fn_cache_key(
-                        n_features, K), n_features, n_padded)] = kfn
+                    _AOT_EXEC_CACHE[(self._round_fn_cache_key(plan, K),
+                                     n_padded)] = kfn
                     if rem:
                         _AOT_EXEC_CACHE[(self._round_fn_cache_key(
-                            n_features, rem), n_features, n_padded)] = rem_fn
+                            plan, rem), n_padded)] = rem_fn
                 using_aot = True
                 trace_s = get_time() - t_tr
-            exec_mode = _warmup_exec_mode()
-            if warmup_rounds > 0 and (
-                    exec_mode == "1" or (exec_mode == "auto"
-                                         and jax.default_backend() == "tpu")):
+            # executed on a TPU only: the first dispatch pays real
+            # one-time staging there (H2D layout, program load) worth
+            # pulling out of the timed region; on CPU the compiled
+            # programs have no such cost and an exec-warmup would just
+            # run the whole K-round chunk twice
+            if warmup_rounds > 0 and jax.default_backend() == "tpu":
                 warm_dispatch(kfn, rem_fn)
             np.asarray(preds[:1])
         self.last_dispatch = "aot" if using_aot else "jit"
@@ -955,14 +960,11 @@ class HistGBT(_ExternalMemoryEngine):
         # the jitted dispatch where host instrumentation can't see it —
         # record the analytic per-round byte bill instead (the model
         # bench.py's hist_psum_bytes_per_round shares)
-        dsize = int(self.mesh.shape["data"])
         psum_round_bytes = (hist_psum_bytes_per_round(
             p.max_depth, n_features, p.n_bins,
-            layout=self._bin_layout,
-            grow_policy=self.round_plan["grow_policy"],
-            max_leaves=_max_leaves(),
-            quant=self.round_plan["hist_quant"])
-            * max(p.num_class, 1) if dsize > 1 else 0)
+            layout=plan.layout, grow_policy=plan.grow_policy,
+            max_leaves=plan.max_leaves)
+            * max(p.num_class, 1) if plan.mesh_devices > 1 else 0)
 
         t0 = get_time()
         chunks: List[Any] = []
@@ -1083,15 +1085,13 @@ class HistGBT(_ExternalMemoryEngine):
 
     def _sharded_ingest_ok(self) -> bool:
         """True when ingest may stage per-chip shard slabs directly onto
-        their owning devices (``DMLC_SHARDED_INGEST``, default on).
+        their owning devices.
         Requires a single-process mesh whose rows shard over ``data``
         alone (every other axis size 1): per-device placement of row
         blocks is only well-defined when block ``k`` lives on exactly
         device ``k``.  The fallback — one global ``device_put`` per
         chunk — is bit-identical, just staged through jax's global-array
         path instead."""
-        if os.environ.get("DMLC_SHARDED_INGEST", "1") == "0":
-            return False
         ndev = device_count(self.mesh)
         if ndev != int(self.mesh.shape["data"]):
             return False
@@ -1124,14 +1124,13 @@ class HistGBT(_ExternalMemoryEngine):
         :class:`_RoundProgramWarmup`); the handle parks on
         ``self._pending_warmup`` for ``_boost_binned`` to join.
 
-        ``DMLC_COLDSTART_OVERLAP=0`` restores the serial pre-overlap
-        path exactly; multi-worker jobs stay serial too (a worker whose
-        compile thread races its peers' collective-ordered device_puts
-        is not worth the cold-start win there).  A program the compiler
+        Multi-worker jobs and meshes that span processes return None
+        and stay on the serial path: ``_boost_rounds`` compiles inline
+        (a worker whose compile thread races its peers'
+        collective-ordered device_puts is not worth the cold-start win
+        there).  A program the compiler
         refuses raises — here if tracing fails, at the join if the
         background compile does."""
-        if os.environ.get("DMLC_COLDSTART_OVERLAP", "1") == "0":
-            return None
         from dmlc_core_tpu.parallel import collectives as coll
         if coll.world_size() > 1 or self._mesh_spans_processes():
             return None
@@ -1140,8 +1139,8 @@ class HistGBT(_ExternalMemoryEngine):
             # before datagen; make_device_data must not duplicate the
             # compile work) — keep it; replace only on a real mismatch
             K, rem = _rounds_schedule(self.param.n_trees, eval_every)
-            if self._pending_warmup.matches(self._round_fn_cache_key,
-                                            n_features, n_padded, K, rem):
+            if self._pending_warmup.matches(
+                    self, self._round_plan(n_features), n_padded, K, rem):
                 return self._pending_warmup
         warm = _RoundProgramWarmup(self, n_features, n_padded, eval_every)
         self._pending_warmup = warm
@@ -1859,10 +1858,14 @@ class HistGBT(_ExternalMemoryEngine):
     # ------------------------------------------------------------------
     # the round program
     # ------------------------------------------------------------------
-    def _round_plan(self, n_features: int) -> Dict[str, Any]:
+    def _round_plan(self, n_features: int) -> _RoundPlan:
         """Resolve every path choice of the round program from what can
         be observed before tracing — param, mesh, bin layout, knobs,
-        backend — and leave the result on ``self.round_plan``.
+        backend — ONCE, and leave its record on ``self.round_plan``.
+        The one place a lever is read: ``_round_fn_cache_key``,
+        ``_build_round_fn`` and ``_boost_rounds``' accounting take the
+        returned :class:`_RoundPlan` and consult nothing else, so a
+        lever is added or dropped here and in the code it selects.
 
         ``hist_method`` lists the histogram engine of each BUILD in
         tree order: depth-wise, level 0 builds the root and level ℓ the
@@ -1900,29 +1903,25 @@ class HistGBT(_ExternalMemoryEngine):
         lossguide = _grow_policy() == "lossguide"
         builds = [1] if lossguide else (
             [1] + [1 << (lv - 1) for lv in range(1, depth)])
-        methods = [
-            "pallas" if fused and i > 0 else
-            resolve_hist_method(p.hist_method, sync_bins, mat_rows, nb)
-            for i, nb in enumerate(builds)]
-        self.round_plan = {
-            "hist_method": methods,
-            "fused_round": fused,
-            "fused_descend": bool(int(
-                _knobs.value("DMLC_TPU_FUSED_DESCEND"))) and not fused,
-            "pallas_interpret": pallas_interpret(),
-            "grow_policy": "lossguide" if lossguide else "depthwise",
-            "bin_layout": (None if layout is None else
-                           f"{layout.n_features}F->{layout.phys_rows}rows"
-                           f"/{len(layout.pairs)}pairs"),
-            "hist_blocks": det_blocks,
-            "hist_quant": (_hist_quant_requested() and dsize > 1
-                           and not det_blocks),
-            "mesh_devices": dsize,
-        }
-        return self.round_plan
+        plan = _RoundPlan(
+            n_features=n_features,
+            hist_method=tuple(
+                "pallas" if fused and i > 0 else
+                resolve_hist_method(p.hist_method, sync_bins, mat_rows, nb)
+                for i, nb in enumerate(builds)),
+            fused_round=fused,
+            pallas_interpret=pallas_interpret(),
+            grow_policy="lossguide" if lossguide else "depthwise",
+            max_leaves=_max_leaves() if lossguide else 0,
+            layout=layout,
+            hist_blocks=det_blocks,
+            mesh_devices=dsize)
+        self.round_plan = plan.describe()
+        return plan
 
-    def _round_fn_cache_key(self, n_features: int, n_rounds: int):
-        """Everything baked into the traced round program as a constant.
+    def _round_fn_cache_key(self, plan: _RoundPlan, n_rounds: int):
+        """Everything baked into the traced round program as a constant:
+        the mesh, the param's fields, the objective and ``plan``.
 
         Two HistGBT instances with equal keys trace to the SAME program,
         so the compiled executable is shared process-wide
@@ -1939,26 +1938,21 @@ class HistGBT(_ExternalMemoryEngine):
                    if isinstance(obj, _PairwiseRank) else obj)
         mono = (tuple(int(v) for v in p.monotone_constraints)
                 if p.monotone_constraints else None)
-        return (self.mesh, n_features, n_rounds, p.max_depth, p.n_bins,
+        return (self.mesh, n_rounds, p.max_depth, p.n_bins,
                 p.learning_rate, p.reg_lambda, p.reg_alpha, p.gamma,
-                p.min_child_weight,
-                p.hist_method, obj_key, mono, p.subsample,
-                p.colsample_bytree, p.num_class, self._missing,
-                _knobs.value("DMLC_TPU_FUSED_DESCEND"),
-                _knobs.value("DMLC_FUSED_ROUND"),
-                _knobs.value("DMLC_HIST_QUANT"),
-                _hist_blocks(int(self.mesh.shape["data"])),
-                _grow_policy(), _max_leaves(), self._bin_layout)
+                p.min_child_weight, obj_key, mono, p.subsample,
+                p.colsample_bytree, p.num_class, self._missing, plan)
 
-    def _build_round_fn(self, n_features: int, n_rounds: int = 1):
+    def _build_round_fn(self, plan: _RoundPlan, n_rounds: int = 1):
         """Jitted shard_map program running ``n_rounds`` boosting rounds
-        (lax.scan); returns (new_preds, trees stacked [n_rounds, ...])."""
-        plan = self._round_plan(n_features)
-        cache_key = self._round_fn_cache_key(n_features, n_rounds)
+        (lax.scan); returns (new_preds, trees stacked [n_rounds, ...]).
+        Built from the param and ``plan`` (:meth:`_round_plan`) alone."""
+        cache_key = self._round_fn_cache_key(plan, n_rounds)
         cached = _ROUND_FN_CACHE.get(cache_key)
         if cached is not None:
             self._round_fn = cached
             return cached
+        n_features = plan.n_features
         p = self.param
         depth = p.max_depth
         B = p.n_bins
@@ -1967,7 +1961,7 @@ class HistGBT(_ExternalMemoryEngine):
         alpha = p.reg_alpha
         gamma = p.gamma
         mcw = p.min_child_weight
-        methods = plan["hist_method"]
+        methods = plan.hist_method
         obj = self._obj
         n_leaf = 1 << depth
         half = max(n_leaf >> 1, 1)
@@ -2003,31 +1997,24 @@ class HistGBT(_ExternalMemoryEngine):
         subsample = p.subsample
         colsample = p.colsample_bytree
         sampling = subsample < 1.0 or colsample < 1.0
-        # two-pass descend+hist measured faster than the fused kernel on
-        # v5e (see ops.fused_descend_histogram); env knob for other HW
-        fuse_levels = plan["fused_descend"]
         # deterministic shard-invariant reduction (DMLC_HIST_BLOCKS, see
         # _hist_blocks): fixed global row blocks + fixed-order folds +
         # all_gather instead of psum, so the grown trees are
         # bit-identical across mesh shapes (the single-chip oracle)
-        dsize = int(self.mesh.shape["data"])
-        det_blocks = _hist_blocks(dsize)
+        dsize = plan.mesh_devices
+        det_blocks = plan.hist_blocks
         # packed/bundled storage layout (ops.binlayout): histograms are
         # built at [.., S, Bs] storage shape (smaller HBM reads + psum
         # payload), then unbundled back to [.., F, B] for split
         # evaluation, so split decisions — and save_model bytes — are
         # untouched.  None traces the exact seed program.
-        layout = self._bin_layout
+        layout = plan.layout
         # fully-fused round kernel (ops.fused_round): ONE Pallas program
         # per level/expansion — descend, left-child accumulation and
         # sibling subtraction with the bin tile and both child histogram
         # slabs resident in VMEM; eligibility is _round_plan's
-        fused_rounds = plan["fused_round"]
-        # int8-quantized histogram sync (DMLC_HIST_QUANT): only the
-        # plain multi-chip psum path quantizes — one chip has no wire to
-        # save, and the deterministic block fold stays exact
-        hist_quant = plan["hist_quant"]
-        lossguide = plan["grow_policy"] == "lossguide"
+        fused_rounds = plan.fused_round
+        lossguide = plan.grow_policy == "lossguide"
         if lossguide:
             CHECK(not missing,
                   "DMLC_GROW_POLICY=lossguide with NaN/missing features "
@@ -2036,7 +2023,7 @@ class HistGBT(_ExternalMemoryEngine):
                   "DMLC_GROW_POLICY=lossguide with monotone_constraints "
                   "is not supported (bound propagation is level-order) "
                   "— use depthwise")
-            max_leaves = _max_leaves()
+            max_leaves = plan.max_leaves
             CHECK(max_leaves >= 0, "DMLC_MAX_LEAVES must be >= 0")
             L_leaves = min(max_leaves, n_leaf) if max_leaves else n_leaf
             CHECK(L_leaves >= 2,
@@ -2067,6 +2054,28 @@ class HistGBT(_ExternalMemoryEngine):
             n_iota = jnp.arange(n_entries, dtype=jnp.int32)[None, :]
             oh = (node[:, None] == n_iota)
             return jnp.sum(jnp.where(oh, table[None, :], 0), axis=1)
+
+        def row_blocks(n_local):
+            """(blocks, rows a block) a shard's rows cut into in
+            deterministic mode, else (0, 0).  Blocked mode needs every
+            shard's rows to split into whole fixed-size blocks;
+            _pad_rows guarantees it for fit paths, the ranking regroup
+            (group-padded layout) falls back."""
+            c_local = det_blocks // dsize if det_blocks else 0
+            n_blk = c_local if c_local and n_local % c_local == 0 else 0
+            return n_blk, (n_local // n_blk if n_blk else 0)
+
+        def hist_sync(x, n_blk):
+            """Histogram-sync allreduce over the data axis: a plain
+            psum normally; in deterministic mode an all_gather (no
+            arithmetic) + the same fixed-order fold the per-shard
+            partials used, so total = the one mesh-invariant tree."""
+            if not n_blk:
+                return jax.lax.psum(x, "data")
+            if dsize == 1:
+                return x
+            gathered = jax.lax.all_gather(x, "data")       # [dsize, ...]
+            return _tree_fold([gathered[i] for i in range(dsize)])
 
         def sample_masks(key, row_shape):
             """(row keep mask | None, feature mask | None) for one round."""
@@ -2104,42 +2113,14 @@ class HistGBT(_ExternalMemoryEngine):
             right child is parent − left from the previous level's
             already-synced histogram.  Halves the one-hot matmul height
             AND the psum bytes per level, and the subtraction itself is
-            exact in f32 up to one rounding.  The descend into level ℓ
-            is FUSED into level ℓ's histogram kernel
-            (ops.fused_descend_histogram) — the bin tile is read from
-            HBM once per level instead of twice."""
+            exact in f32 up to one rounding.  Where the plan engages
+            ``fused_round`` the descend into level ℓ, the left children's
+            accumulation and the subtraction are ONE Pallas program (the
+            bin tile is read from HBM once per level); elsewhere the
+            level is staged: ``ops.descend_histogram`` (an XLA descend,
+            then the histogram build), the sync, the subtraction."""
             node = jnp.zeros(bins_tl.shape[1], jnp.int32)
-            n_local = int(bins_tl.shape[1])
-            # blocked mode needs every shard's rows to split into whole
-            # fixed-size blocks; _pad_rows guarantees it for fit paths,
-            # the ranking regroup (group-padded layout) falls back
-            c_local = det_blocks // dsize if det_blocks else 0
-            n_blk = (c_local if c_local and n_local % c_local == 0
-                     else 0)
-            rb = n_local // n_blk if n_blk else 0
-
-            def hist_sync(x):
-                """Histogram-sync allreduce over the data axis: a plain
-                psum normally; in deterministic mode an all_gather (no
-                arithmetic) + the same fixed-order fold the per-shard
-                partials used, so total = the one mesh-invariant tree.
-                DMLC_HIST_QUANT swaps the plain psum for an int8-code
-                psum + exact f32 column-total correction (~4× fewer
-                wire bytes; see ops.histogram.quantize_hist_partial)."""
-                if not n_blk:
-                    if hist_quant:
-                        gmax = jax.lax.pmax(
-                            jnp.max(jnp.abs(x), axis=-1, keepdims=True),
-                            "data")
-                        q, scale, tot = quantize_hist_partial(x, gmax)
-                        qs = jax.lax.psum(q.astype(jnp.int32), "data")
-                        tots = jax.lax.psum(tot, "data")
-                        return dequantize_hist_sum(qs, scale, tots)
-                    return jax.lax.psum(x, "data")
-                if dsize == 1:
-                    return x
-                gathered = jax.lax.all_gather(x, "data")   # [dsize, ...]
-                return _tree_fold([gathered[i] for i in range(dsize)])
+            n_blk, rb = row_blocks(int(bins_tl.shape[1]))
 
             def with_siblings(parent, left):
                 """Both children's histograms, interleaved: the right
@@ -2164,8 +2145,8 @@ class HistGBT(_ExternalMemoryEngine):
                 scores = None
                 # the level's device phases (doc/observability.md), as
                 # decorators of the calls that trace them: .route (each
-                # row's node's split), .hist (the kernel, fused descend
-                # and sibling subtraction included), .sync, .split
+                # row's node's split), .hist (the kernel, the level's
+                # descend and sibling subtraction included), .sync, .split
                 in_route, in_hist, in_sync, in_split = (
                     jax.named_scope(f"dmlc.round.L{level}.{phase}")
                     for phase in ("route", "hist", "sync", "split"))
@@ -2184,7 +2165,7 @@ class HistGBT(_ExternalMemoryEngine):
                         hist = in_hist(build_histogram)(
                             bins_tl, node, g, h, 1, B, methods[0],
                             transposed=True, layout=layout)
-                    hist = in_sync(hist_sync)(hist)
+                    hist = in_sync(hist_sync)(hist, n_blk)
                 else:
                     n_prev = n_nodes >> 1
                     select = in_route(table_select)
@@ -2216,11 +2197,10 @@ class HistGBT(_ExternalMemoryEngine):
                         lefts, nodes2 = [], []
                         for j in range(n_blk):
                             sl = slice(j * rb, (j + 1) * rb)
-                            l_j, nd_j = in_hist(fused_descend_histogram)(
+                            l_j, nd_j = in_hist(descend_histogram)(
                                 bins_tl[:, sl], node[sl], feat_sel[sl],
                                 thr_sel[sl], g[sl], h[sl],
                                 n_prev, B, methods[level],
-                                fuse=fuse_levels,
                                 dir_sel=(None if dir_sel is None
                                          else dir_sel[sl]),
                                 miss_bin=B - 1 if missing else None,
@@ -2230,15 +2210,14 @@ class HistGBT(_ExternalMemoryEngine):
                         left = in_hist(_tree_fold)(lefts)
                         node = jnp.concatenate(nodes2)
                     else:
-                        left, node = in_hist(fused_descend_histogram)(
+                        left, node = in_hist(descend_histogram)(
                             bins_tl, node, feat_sel, thr_sel, g, h,
                             n_prev, B, methods[level],
-                            fuse=fuse_levels,
                             dir_sel=dir_sel,
                             miss_bin=B - 1 if missing else None,
                             layout=layout)
                     if not fused_rounds:
-                        left = in_sync(hist_sync)(left)
+                        left = in_sync(hist_sync)(left, n_blk)
                         hist = in_hist(with_siblings)(prev_hist, left)
                 # sibling subtraction stays in STORAGE space (prev_hist);
                 # split evaluation sees original-feature space (identity
@@ -2300,7 +2279,7 @@ class HistGBT(_ExternalMemoryEngine):
                         jnp.stack([lo_r, up_r], 1)], axis=1
                     ).reshape(2 * n_nodes, 2)
             with jax.named_scope("dmlc.round.leaf"):
-                # final descend (the loop's fused kernels advanced node only
+                # final descend (the loop's levels advanced node only
                 # up to level depth-1); shared gather-free feature select
                 feat_sel = table_select(feat, node, 1 << (depth - 1))
                 thr_sel = table_select(thr, node, 1 << (depth - 1))
@@ -2351,26 +2330,7 @@ class HistGBT(_ExternalMemoryEngine):
             depthwise, and the expansion order derives only from synced
             gains — so mesh-shape invariance survives."""
             n_local = int(bins_tl.shape[1])
-            c_local = det_blocks // dsize if det_blocks else 0
-            n_blk = (c_local if c_local and n_local % c_local == 0
-                     else 0)
-            rb = n_local // n_blk if n_blk else 0
-
-            def hist_sync(x):
-                if not n_blk:
-                    if hist_quant:          # int8-code sync, see grow_tree
-                        gmax = jax.lax.pmax(
-                            jnp.max(jnp.abs(x), axis=-1, keepdims=True),
-                            "data")
-                        q, scale, tot = quantize_hist_partial(x, gmax)
-                        qs = jax.lax.psum(q.astype(jnp.int32), "data")
-                        tots = jax.lax.psum(tot, "data")
-                        return dequantize_hist_sum(qs, scale, tots)
-                    return jax.lax.psum(x, "data")
-                if dsize == 1:
-                    return x
-                gathered = jax.lax.all_gather(x, "data")
-                return _tree_fold([gathered[i] for i in range(dsize)])
+            n_blk, rb = row_blocks(n_local)
 
             def build_one(node_build):
                 """Histogram of the single node whose rows have
@@ -2389,7 +2349,7 @@ class HistGBT(_ExternalMemoryEngine):
                     hh = build_histogram(bins_tl, node_build, g, h, 1, B,
                                          methods[0], transposed=True,
                                          layout=layout)
-                return hist_sync(hh)             # [2, 1, S, Bs]
+                return hist_sync(hh, n_blk)      # [2, 1, S, Bs]
 
             def eval_nodes(hist_st):
                 """(feat, thr, gain, tot_g, tot_h) per node of a synced

@@ -369,14 +369,13 @@ class SparseHistGBT:
         # heavy host pass — bin_sparse_entries searchsorting every nnz
         # entry — hasn't run yet.  AOT-compile the K-round program on a
         # background worker while that binning runs; join before the
-        # boosting loop (a refused compile raises there).
-        # DMLC_COLDSTART_OVERLAP=0 restores the serial path.
+        # boosting loop (a refused compile raises there).  A
+        # multi-worker job and a subsampled fit stay serial.
         self.last_compile_seconds = None
         warm_bg = warm_exec = None
         warm_k = min(int(get_env("DMLC_TPU_SPARSE_ROUNDS_PER_DISPATCH",
                                  8, int)), p.n_trees)
-        if (not distributed and p.subsample >= 1.0 and warm_k > 0
-                and get_env("DMLC_COLDSTART_OVERLAP", True, bool)):
+        if not distributed and p.subsample >= 1.0 and warm_k > 0:
             nnz = len(index)
             obj = self._obj
 

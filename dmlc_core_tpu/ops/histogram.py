@@ -45,12 +45,11 @@ from jax.experimental import pallas as pl
 from dmlc_core_tpu.base.logging import CHECK, log_fatal
 from dmlc_core_tpu.ops import binlayout as _bl
 
-__all__ = ["build_histogram", "fused_descend_histogram", "fused_round",
+__all__ = ["build_histogram", "descend_histogram", "fused_round",
            "select_feature_bins", "histogram_methods",
            "resolve_hist_method", "pallas_interpret",
            "reference_histogram", "hist_psum_bytes_per_round",
-           "bins_bytes_per_round", "leaves_built_per_round",
-           "quantize_hist_partial", "dequantize_hist_sum"]
+           "bins_bytes_per_round", "leaves_built_per_round"]
 
 
 def leaves_built_per_round(depth: int, grow_policy: str = "depthwise",
@@ -69,8 +68,7 @@ def leaves_built_per_round(depth: int, grow_policy: str = "depthwise",
 def hist_psum_bytes_per_round(depth: int, n_features: int,
                               n_bins: int, *, layout=None,
                               grow_policy: str = "depthwise",
-                              max_leaves: int = 0,
-                              quant: bool = False) -> int:
+                              max_leaves: int = 0) -> int:
     """Per-chip bytes contributed to the in-step histogram-sync
     allreduce by ONE boosting round (one tree).
 
@@ -87,20 +85,11 @@ def hist_psum_bytes_per_round(depth: int, n_features: int,
     ``dmlc_histogram_psum_bytes_total`` counter — the cross-chip
     traffic the multi-chip flagship pays per round (the rabit-allreduce
     replacement's byte bill).
-
-    ``quant=True`` models the ``DMLC_HIST_QUANT`` int8 sync: per built
-    node each (plane, feature) column crosses the wire as ``Bs`` int8
-    cells plus one f32 scale and one f32 exact column total (the
-    correction term) — ``2·S·(Bs + 8)`` bytes instead of
-    ``2·S·Bs·4``, a ~3.9× cut at ``Bs = 256``.
     """
     if layout is not None:
         n_features = layout.storage_features
         n_bins = layout.sync_bins
-    if quant:
-        node_bytes = 2 * n_features * (n_bins + 8)
-    else:
-        node_bytes = 2 * n_features * n_bins * 4
+    node_bytes = 2 * n_features * n_bins * 4
     if grow_policy == "lossguide":
         return leaves_built_per_round(depth, "lossguide",
                                       max_leaves) * node_bytes
@@ -137,37 +126,6 @@ def bins_bytes_per_round(depth: int, rows: int, row_bytes: int, *,
     return max(passes, 1) * rows * row_bytes
 
 
-def quantize_hist_partial(hist: jax.Array, gmax: jax.Array):
-    """Quantize one chip's PARTIAL histogram for the int8 sync
-    (``DMLC_HIST_QUANT=1``).  ``hist`` is the shard-local storage-space
-    histogram ``[..., Bs]`` f32; ``gmax`` the GLOBAL (pmax-reduced)
-    per-column ``[..., 1]`` absolute max, so every chip quantizes
-    against the same scale and the int32 psum of the int8 codes is
-    well-defined.  Returns ``(q int8, scale f32, tot f32)`` where
-    ``tot`` is the EXACT f32 column total — the correction term that
-    rides along the allreduce so per-(node, feature) grad/hess sums
-    (what leaf weights integrate) stay exact."""
-    scale = jnp.maximum(gmax, 1e-30) / 127.0
-    q = jnp.clip(jnp.round(hist / scale), -127, 127).astype(jnp.int8)
-    tot = jnp.sum(hist, axis=-1, keepdims=True)
-    return q, scale, tot
-
-
-def dequantize_hist_sum(q_sum: jax.Array, scale: jax.Array,
-                        tot_sum: jax.Array) -> jax.Array:
-    """Reconstruct the synced histogram from the psum of int8 codes.
-    ``q_sum`` is the int32 psum of per-chip codes, ``scale`` the shared
-    quantization scale, ``tot_sum`` the psum of EXACT column totals.
-    The per-column correction spreads the (tiny) total quantization
-    error uniformly so the reconstructed column sums to the exact
-    total: cell error is bounded by ``n_chips · scale / 2`` while the
-    (node, feature) totals — and hence leaf weights at a fixed split —
-    carry NO quantization error."""
-    approx = q_sum.astype(jnp.float32) * scale
-    n_cells = approx.shape[-1]
-    corr = (tot_sum - jnp.sum(approx, axis=-1, keepdims=True)) / n_cells
-    return approx + corr
-
 # rows per MXU block: one-hot RHS is [R, F·B] bf16 — at F=28, B=256 and
 # R=8192 that is ~117MB, safely inside HBM working set while keeping the
 # matmul [2N, R]·[R, F·B] large enough to saturate the systolic array.
@@ -186,20 +144,10 @@ def histogram_methods() -> list[str]:
 _TILE_ROWS = 16384
 
 
-def _pack_factor(n_nodes: int, n_bins: int) -> int:
-    """Row-subtiles packed per MXU dot (block-structured LHS so S row
-    ranges share one [S·A, T] dot).  Measured on v5e: ALWAYS 1 — narrow
-    dots do not pad to 128 sublanes (a [A, T]·[T, 128] dot costs ~A/128
-    of a full pass), so packing only inflates the [S·A, T] one-hot
-    construction, which is the actual per-level floor.  Kept as a
-    seam for hardware where narrow matmuls do pay full freight."""
-    return 1
-
-
 def _pallas_ok(n_bins: int, n_features: int, n_nodes: int = 1,
                bins_itemsize: int = 1, tile_rows: int = 0) -> bool:
     """The factored kernel works for any n_bins; the binding constraints
-    are (a) the [Fp, S·A, lo] f32 accumulator block — empirically
+    are (a) the [Fp, A, lo] f32 accumulator block — empirically
     calibrated on v5e at tile_rows=4096: nominal accumulators up to 32MB
     compile and run (Mosaic windows the out block; fori_loop temporaries
     are reused, so per-row working-set formulas wildly overestimate),
@@ -216,8 +164,7 @@ def _pallas_ok(n_bins: int, n_features: int, n_nodes: int = 1,
     hi = -(-n_bins // lo)
     fp = -(-n_features // 8) * 8
     nh = n_nodes * hi
-    sa = _pack_factor(n_nodes, n_bins) * 2 * nh
-    acc = fp * sa * max(lo, 128) * 4
+    acc = fp * 2 * nh * max(lo, 128) * 4
     T = tile_rows or _TILE_ROWS
     tile_stack = T * (fp * bins_itemsize + 120 + 6 * nh + 2 * lo)
     return acc <= 24 << 20 and tile_stack <= 15 << 20
@@ -392,22 +339,17 @@ def _hist_matmul(bins, node_id, grad, hess, n_nodes, n_bins,
 
 
 def _hist_pallas_kernel(bins_ref, node_ref, g_ref, h_ref, out_ref,
-                        *, n_nodes, hi, lo, pack, n_pack_groups=0):
-    """One row-tile of the FACTORED, SUBTILE-PACKED one-hot matmul.
+                        *, n_nodes, hi, lo, n_pack_groups=0):
+    """One row-tile of the FACTORED one-hot matmul.
 
     bin = hi_part·lo + lo_part.  Per feature, ONE MXU dot
-    ``[S·A, T] · [lo, T]ᵀ`` where A = 2·N·hi one-hot sublanes encode
-    (grad/hess plane, node, hi_part) scaled by g/h, the RHS encodes
-    lo_part, and ``pack`` = S independent row subtiles of T/S rows each
-    share the dot: subtile s's rows one-hot only into sublane block
-    [s·A, (s+1)·A), so cross-subtile terms vanish and the [S, A, lo]
-    output slabs just sum.  This keeps the systolic array FULL at
-    shallow tree levels — without packing a level with A=8 (root, 256
-    bins) pads 8→128 sublanes and wastes 94% of the MXU; with it every
-    level costs ~A/128 of a full pass and a depth-6 tree's histogram
-    work drops from 6 full passes to ~1 (sibling subtraction at the
-    call site halves A again).  One-hots live only in VMEM values
-    (never HBM); HBM traffic is the bin matrix itself.
+    ``[A, T] · [lo, T]ᵀ`` where A = 2·N·hi one-hot sublanes encode
+    (grad/hess plane, node, hi_part) scaled by g/h and the RHS encodes
+    lo_part.  A narrow dot does not pad to 128 sublanes on v5e (it
+    costs ~A/128 of a full pass), so a level costs what its A asks for
+    and the one-hot construction is the per-level floor (sibling
+    subtraction at the call site halves A again).  One-hots live only
+    in VMEM values (never HBM); HBM traffic is the bin matrix itself.
 
     Layout: everything arrives TRANSPOSED (rows on lanes — bins [F, T],
     node/g/h [1, T]) so the per-feature loop can be a fori_loop that
@@ -426,11 +368,11 @@ def _hist_pallas_kernel(bins_ref, node_ref, g_ref, h_ref, out_ref,
         out_ref[:] = jnp.zeros_like(out_ref)
 
     _accum_hist(bins_ref, out_ref, node, g, h,
-                n_nodes=n_nodes, hi=hi, lo=lo, pack=pack,
+                n_nodes=n_nodes, hi=hi, lo=lo,
                 n_pack_groups=n_pack_groups)
 
 
-def _accum_hist(bins_ref, out_ref, node, g, h, *, n_nodes, hi, lo, pack,
+def _accum_hist(bins_ref, out_ref, node, g, h, *, n_nodes, hi, lo,
                 n_pack_groups=0):
     """Shared histogram accumulation loop (see _hist_pallas_kernel doc).
 
@@ -446,13 +388,10 @@ def _accum_hist(bins_ref, out_ref, node, g, h, *, n_nodes, hi, lo, pack,
     """
     F, T = bins_ref.shape
     nh = n_nodes * hi
-    nh_iota = jax.lax.broadcasted_iota(jnp.int32, (pack * nh, T), 0)
+    nh_iota = jax.lax.broadcasted_iota(jnp.int32, (nh, T), 0)
     lo_iota = jax.lax.broadcasted_iota(jnp.int32, (lo, T), 0)
-    # sublane base of each row's subtile block: (r // (T/S)) · nh
-    sub_base = (jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
-                // (T // pack)) * nh
     valid = node >= 0
-    t0_node = jnp.where(valid, sub_base + jnp.where(valid, node, 0) * hi,
+    t0_node = jnp.where(valid, jnp.where(valid, node, 0) * hi,
                         jnp.int32(-(1 << 20)))                        # [1, T]
 
     def emit(t0s, los, k, row):
@@ -461,14 +400,14 @@ def _accum_hist(bins_ref, out_ref, node, g, h, *, n_nodes, hi, lo, pack,
         # [2·nh, T] iota twice.  compare→astype→mul (NOT where):
         # Mosaic can't relayout an i1 mask against a [1, T]-
         # replicated where operand.
-        oh = (nh_iota == t0s[k:k + 1]).astype(jnp.bfloat16)           # [Snh, T]
-        lhs = jnp.concatenate([oh * g, oh * h], axis=0)               # [2Snh, T]
+        oh = (nh_iota == t0s[k:k + 1]).astype(jnp.bfloat16)           # [nh, T]
+        lhs = jnp.concatenate([oh * g, oh * h], axis=0)               # [2nh, T]
         rhs = (lo_iota == los[k:k + 1]).astype(jnp.bfloat16)          # [lo, T]
         d = jax.lax.dot_general(
             lhs, rhs,
             dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )                                                              # [2Snh, lo]
+        )                                                              # [2nh, lo]
         idx = (pl.ds(row, 1), slice(None), slice(None))
         out_ref[idx] = out_ref[idx] + d[None]
 
@@ -507,53 +446,7 @@ def _accum_hist(bins_ref, out_ref, node, g, h, *, n_nodes, hi, lo, pack,
     jax.lax.fori_loop(0, F // 8 - n_pack_groups, body, 0)
 
 
-def _fused_kernel(bins_ref, node_ref, feat_ref, thr_ref, g_ref, h_ref,
-                  out_ref, node_out_ref, *, n_prev, hi, lo, pack):
-    """Descend one tree level AND build the new level's left-child
-    histograms in one pass over the bin tile.
-
-    Each row arrives with its level-(ℓ−1) node id and that node's chosen
-    split (feat_sel, thr_sel, gathered outside).  Phase 1 extracts the
-    selected feature's bin during a cheap batched sweep of the tile
-    (compare-and-sum over sublane groups — the tile is already in VMEM,
-    so the standalone descend's second HBM pass over the bin matrix
-    disappears).  The advanced node id is written out, then phase 2 runs
-    the shared histogram loop over LEFT children only (odd ids one-hot
-    to nothing — sibling subtraction happens at the call site)."""
-    i = pl.program_id(0)
-    F, T = bins_ref.shape
-
-    node = node_ref[:].astype(jnp.int32)                              # [1, T]
-    g = g_ref[:].astype(jnp.bfloat16)
-    h = h_ref[:].astype(jnp.bfloat16)
-    fsel = feat_ref[:].astype(jnp.int32)                              # [1, T]
-    tsel = thr_ref[:].astype(jnp.int32)
-
-    @pl.when(i == 0)
-    def _():
-        out_ref[:] = jnp.zeros_like(out_ref)
-
-    g8_iota = jax.lax.broadcasted_iota(jnp.int32, (8, T), 0)
-
-    def sel_body(fg, sel):
-        base = pl.multiple_of(fg * 8, 8)
-        blk = bins_ref[pl.ds(base, 8), :].astype(jnp.int32)           # [8, T]
-        pick = (g8_iota + base == fsel).astype(jnp.int32)             # [8, T]
-        return sel + jnp.sum(pick * blk, axis=0, keepdims=True)
-
-    sel_bin = jax.lax.fori_loop(0, F // 8, sel_body,
-                                jnp.zeros((1, T), jnp.int32))
-    valid = node >= 0
-    new_node = jnp.where(valid, 2 * node + (sel_bin > tsel), -1)      # [1, T]
-    node_out_ref[:] = new_node
-
-    # left children only: even ids → parent index, odd → build nothing
-    node_h = jnp.where(valid & (new_node % 2 == 0), new_node >> 1, -1)
-    _accum_hist(bins_ref, out_ref, node_h, g, h,
-                n_nodes=n_prev, hi=hi, lo=lo, pack=pack)
-
-
-def _fused_round_kernel(*refs, n_prev, hi, lo, pack, n_pack_groups,
+def _fused_round_kernel(*refs, n_prev, hi, lo, n_pack_groups,
                         with_layout):
     """ONE Pallas program for a whole tree level: bin-read → node
     descend → g/h scatter-accumulate → sibling subtraction, with the
@@ -632,7 +525,7 @@ def _fused_round_kernel(*refs, n_prev, hi, lo, pack, n_pack_groups,
     # left children only — the right slab comes from sibling subtraction
     node_h = jnp.where(valid & (new_node % 2 == 0), new_node >> 1, -1)
     _accum_hist(bins_ref, left_ref, node_h, g, h,
-                n_nodes=n_prev, hi=hi, lo=lo, pack=pack,
+                n_nodes=n_prev, hi=hi, lo=lo,
                 n_pack_groups=n_pack_groups)
 
     @pl.when(i == pl.num_programs(0) - 1)
@@ -651,8 +544,7 @@ def fused_round_ok(n_bins: int, n_features: int, n_prev: int = 1,
     hi = -(-n_bins // lo)
     fp = -(-n_features // 8) * 8
     nh = n_prev * hi
-    sa = _pack_factor(n_prev, n_bins) * 2 * nh
-    acc = fp * sa * max(lo, 128) * 4
+    acc = fp * 2 * nh * max(lo, 128) * 4
     T = tile_rows or _TILE_ROWS
     extra = (5 * 4 + 16 * 4) if with_layout else 0
     tile_stack = T * (fp * bins_itemsize + 136 + extra + 6 * nh + 2 * lo)
@@ -699,7 +591,6 @@ def fused_round(
     lo = min(lo or _lo_factor(n_prev, Bs), Bs)
     hi = -(-Bs // lo)
     A = 2 * n_prev * hi
-    S = _pack_factor(n_prev, Bs)
     Fp = -(-Fphys // 8) * 8
     if layout is not None:
         npg = layout.packed_rows // 8
@@ -760,22 +651,22 @@ def fused_round(
         in_specs.append(pl.BlockSpec((_bl.PACK_WIDTH, tile_rows),
                                      lambda i: (0, i)))
         operands.append(occ)
-    in_specs.append(pl.BlockSpec((L, S * A, lo), lambda i: (0, 0, 0)))
+    in_specs.append(pl.BlockSpec((L, A, lo), lambda i: (0, 0, 0)))
     operands.append(prev_acc)
 
     left, right, new_node = pl.pallas_call(
-        partial(_fused_round_kernel, n_prev=n_prev, hi=hi, lo=lo, pack=S,
+        partial(_fused_round_kernel, n_prev=n_prev, hi=hi, lo=lo,
                 n_pack_groups=npg, with_layout=layout is not None),
         out_shape=(
-            jax.ShapeDtypeStruct((L, S * A, lo), jnp.float32),
-            jax.ShapeDtypeStruct((L, S * A, lo), jnp.float32),
+            jax.ShapeDtypeStruct((L, A, lo), jnp.float32),
+            jax.ShapeDtypeStruct((L, A, lo), jnp.float32),
             jax.ShapeDtypeStruct((1, n_pad), jnp.int32),
         ),
         grid=(grid,),
         in_specs=in_specs,
         out_specs=(
-            pl.BlockSpec((L, S * A, lo), lambda i: (0, 0, 0)),
-            pl.BlockSpec((L, S * A, lo), lambda i: (0, 0, 0)),
+            pl.BlockSpec((L, A, lo), lambda i: (0, 0, 0)),
+            pl.BlockSpec((L, A, lo), lambda i: (0, 0, 0)),
             pl.BlockSpec((1, tile_rows), lambda i: (0, i)),
         ),
         interpret=pallas_interpret(),
@@ -783,8 +674,7 @@ def fused_round(
     )(*operands)
 
     def canon(slab):
-        x = slab.reshape(L, 2, S, n_prev, hi * lo).sum(axis=2)
-        x = x[jnp.asarray(perm)]
+        x = slab.reshape(L, 2, n_prev, hi * lo)[jnp.asarray(perm)]
         return x.transpose(1, 2, 0, 3)[..., :Bs]
 
     with jax.named_scope("dmlc.hist.unpack"):
@@ -829,9 +719,8 @@ def _hist_pallas(bins, node_id, grad, hess, n_nodes, n_bins,
                  tile_rows: int = _TILE_ROWS, lo: int = 0,
                  transposed: bool = False, layout=None):
     """Pallas TPU path: grid over row tiles, all tiles accumulate into the
-    same [F, S·A, lo] VMEM output block (sequential TPU grid ⇒ safe),
-    then the S packed subtile slabs sum and one small reshape/transpose
-    yields [2, N, F, B].
+    same [F, A, lo] VMEM output block (sequential TPU grid ⇒ safe),
+    then one small reshape/transpose yields [2, N, F, B].
 
     With a nibble-packed ``layout`` the input is the PHYSICAL matrix:
     the kernel's packed region emits two logical rows per byte row, the
@@ -844,7 +733,6 @@ def _hist_pallas(bins, node_id, grad, hess, n_nodes, n_bins,
     lo = min(lo or _lo_factor(n_nodes, n_bins), n_bins)
     hi = -(-n_bins // lo)
     A = 2 * n_nodes * hi
-    S = _pack_factor(n_nodes, n_bins)
     Fp = -(-F // 8) * 8          # feature groups of 8 (sublane alignment)
     npg = 0
     if layout is not None:
@@ -867,9 +755,9 @@ def _hist_pallas(bins, node_id, grad, hess, n_nodes, n_bins,
             bins_t = jnp.pad(bins.T, ((0, Fp - F), (0, pad)))
 
     out = pl.pallas_call(
-        partial(_hist_pallas_kernel, n_nodes=n_nodes, hi=hi, lo=lo, pack=S,
+        partial(_hist_pallas_kernel, n_nodes=n_nodes, hi=hi, lo=lo,
                 n_pack_groups=npg),
-        out_shape=jax.ShapeDtypeStruct((L, S * A, lo), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((L, A, lo), jnp.float32),
         grid=(grid,),
         in_specs=[
             pl.BlockSpec((Fp, tile_rows), lambda i: (0, i)),
@@ -877,7 +765,7 @@ def _hist_pallas(bins, node_id, grad, hess, n_nodes, n_bins,
             pl.BlockSpec((1, tile_rows), lambda i: (0, i)),
             pl.BlockSpec((1, tile_rows), lambda i: (0, i)),
         ],
-        out_specs=pl.BlockSpec((L, S * A, lo), lambda i: (0, 0, 0)),
+        out_specs=pl.BlockSpec((L, A, lo), lambda i: (0, 0, 0)),
         interpret=pallas_interpret(),
         name="dmlc_hist",
     )(bins_t, node_id.reshape(1, n_pad), grad.reshape(1, n_pad),
@@ -886,73 +774,14 @@ def _hist_pallas(bins, node_id, grad, hess, n_nodes, n_bins,
         if layout is not None:
             # kernel-logical rows → storage order (static permutation)
             perm = _bl.layout_tables(layout)["logical"]
-            out = out.reshape(L, 2, S, n_nodes, hi * lo).sum(axis=2)
-            out = out[jnp.asarray(perm)]
-            out = out.transpose(1, 2, 0, 3)
-            return out[..., :n_bins]
-        # [Fp, (gh, S, N, hi), lo] → Σ over S → [gh, N, F, hi·lo] → slice
-        # pads
-        out = out[:F].reshape(F, 2, S, n_nodes, hi * lo).sum(axis=2)
-        out = out.transpose(1, 2, 0, 3)
-        return out[..., :n_bins]
+            out = out.reshape(L, 2, n_nodes, hi * lo)[jnp.asarray(perm)]
+        else:
+            out = out[:F].reshape(F, 2, n_nodes, hi * lo)
+        # [F, gh, N, hi·lo] → [gh, N, F, hi·lo] → slice the bin pads
+        return out.transpose(1, 2, 0, 3)[..., :n_bins]
 
 
-@partial(jax.jit, static_argnums=(6, 7, 8, 9))
-def _fused_pallas(bins_t, node_id, feat_sel, thr_sel, grad, hess,
-                  n_prev, n_bins, tile_rows: int = _TILE_ROWS, lo: int = 0):
-    """Fused descend+histogram wrapper (bins already [F, n]).  Returns
-    ``(left_hist [2, n_prev, F, B], new_node [n])`` where new_node is
-    the level-ℓ assignment and left_hist[_, p] is the histogram of
-    parent p's LEFT child."""
-    F, n = bins_t.shape
-    lo = min(lo or _lo_factor(n_prev, n_bins), n_bins)
-    hi = -(-n_bins // lo)
-    A = 2 * n_prev * hi
-    S = _pack_factor(n_prev, n_bins)
-    Fp = -(-F // 8) * 8
-    pad = (-n) % tile_rows
-    n_pad = n + pad
-    grid = n_pad // tile_rows
-    with jax.named_scope("dmlc.hist.pad"):
-        if pad:
-            node_id = jnp.pad(node_id, (0, pad), constant_values=-1)
-            feat_sel = jnp.pad(feat_sel, (0, pad))
-            thr_sel = jnp.pad(thr_sel, (0, pad))
-            grad = jnp.pad(grad, (0, pad))
-            hess = jnp.pad(hess, (0, pad))
-        bins_p = jnp.pad(bins_t, ((0, Fp - F), (0, pad)))
-
-    hist, new_node = pl.pallas_call(
-        partial(_fused_kernel, n_prev=n_prev, hi=hi, lo=lo, pack=S),
-        out_shape=(
-            jax.ShapeDtypeStruct((Fp, S * A, lo), jnp.float32),
-            jax.ShapeDtypeStruct((1, n_pad), jnp.int32),
-        ),
-        grid=(grid,),
-        in_specs=[
-            pl.BlockSpec((Fp, tile_rows), lambda i: (0, i)),
-            pl.BlockSpec((1, tile_rows), lambda i: (0, i)),
-            pl.BlockSpec((1, tile_rows), lambda i: (0, i)),
-            pl.BlockSpec((1, tile_rows), lambda i: (0, i)),
-            pl.BlockSpec((1, tile_rows), lambda i: (0, i)),
-            pl.BlockSpec((1, tile_rows), lambda i: (0, i)),
-        ],
-        out_specs=(
-            pl.BlockSpec((Fp, S * A, lo), lambda i: (0, 0, 0)),
-            pl.BlockSpec((1, tile_rows), lambda i: (0, i)),
-        ),
-        interpret=pallas_interpret(),
-        name="dmlc_fused_descend",
-    )(bins_p, node_id.reshape(1, n_pad), feat_sel.reshape(1, n_pad),
-      thr_sel.reshape(1, n_pad), grad.reshape(1, n_pad),
-      hess.reshape(1, n_pad))
-    with jax.named_scope("dmlc.hist.unpack"):
-        out = hist[:F].reshape(F, 2, S, n_prev, hi * lo).sum(axis=2)
-        out = out.transpose(1, 2, 0, 3)[..., :n_bins]
-        return out, new_node.reshape(n_pad)[:n]
-
-
-def fused_descend_histogram(
+def descend_histogram(
     bins_t: jax.Array,      # [F, n] — transposed binned matrix
     node_id: jax.Array,     # [n] — node ids at level ℓ−1 (−1 = padding)
     feat_sel: jax.Array,    # [n] — each row's node's chosen split feature
@@ -962,39 +791,19 @@ def fused_descend_histogram(
     n_prev: int,            # number of level-(ℓ−1) nodes
     n_bins: int,
     method: str = "auto",
-    fuse: bool = False,
     dir_sel: jax.Array = None,  # [n] learned missing direction (1=left)
     miss_bin: int = None,       # bin index reserved for NaN rows
     layout=None,                # BinLayout: bins_t is the physical matrix
 ):
     """Advance rows one level down the tree and build the new level's
-    LEFT-child histograms.  Returns ``(left_hist, new_node)`` with
-    ``left_hist[_, p]`` the histogram of parent p's left child (node
-    2p) — the caller derives the right child by sibling subtraction.
+    LEFT-child histograms, in two passes over the bin matrix: an XLA
+    descend (:func:`select_feature_bins`), then :func:`build_histogram`.
+    Returns ``(left_hist, new_node)`` with ``left_hist[_, p]`` the
+    histogram of parent p's left child (node 2p) — the caller derives
+    the right child by sibling subtraction.  The staged level of the
+    round program wherever :func:`fused_round` is not engaged.
     Replaces rabit's per-level hist allreduce prep (SURVEY.md §2e
-    data-parallel row).
-
-    ``fuse=True`` runs descend + histogram as ONE Pallas kernel (single
-    HBM read of the bin tile).  Measured on v5e it is mildly NEGATIVE
-    (−5%: the serial in-kernel select loop beats XLA's overlapped
-    standalone descend pass), so the default is the two-pass form; the
-    fused kernel is kept for parts where HBM bandwidth, not VPU issue
-    rate, binds."""
-    if fuse:
-        # an explicit request: run the fused kernel or say why not
-        CHECK(dir_sel is None and layout is None
-              and method in ("auto", "pallas"),
-              "fused descend+histogram (DMLC_TPU_FUSED_DESCEND=1) "
-              "supports neither missing mode, a packed/bundled bin "
-              f"layout, nor hist_method={method!r}")
-        CHECK(_pallas_ok(n_bins, bins_t.shape[0], n_prev,
-                         jnp.dtype(bins_t.dtype).itemsize),
-              "fused descend+histogram: shape outside the kernel's VMEM "
-              f"gate (n_bins={n_bins}, features={bins_t.shape[0]}, "
-              f"n_prev={n_prev})")
-        return _fused_pallas(bins_t, node_id, feat_sel, thr_sel,
-                             grad, hess, n_prev, n_bins)
-    # two-pass form: XLA descend, then the regular histogram
+    data-parallel row)."""
     valid = node_id >= 0
     row_bin = select_feature_bins(bins_t, feat_sel, layout=layout)
     go_right = row_bin > thr_sel
@@ -1017,7 +826,7 @@ def select_feature_bins(bins_t: jax.Array, feat_sel: jax.Array,
     dimension serializes badly on TPU, so the selected feature's bin is
     extracted by compare-and-sum over the F rows (one [F, n] VPU pass).
     Shared by the tree descend in HistGBT (in-core and external-memory)
-    and the two-pass form of fused_descend_histogram.  With ``layout``
+    and :func:`descend_histogram`.  With ``layout``
     the matrix is physical (packed/bundled) and ``feat_sel`` indexes
     ORIGINAL features — ``binlayout.select_bins`` decodes nibbles and
     bundle segments after the same compare-and-sum pass.

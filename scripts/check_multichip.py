@@ -116,10 +116,9 @@ def main() -> int:
         failures.append("1-chip oracle ensemble bytes differ")
 
     # sharded ingest vs global-put staging, same mesh
-    os.environ["DMLC_SHARDED_INGEST"] = "0"
     mG = HistGBT(mesh=Mesh(devs[:N_DEV], ("data",)), **kw)
+    mG._sharded_ingest_ok = lambda: False   # the global-put fallback
     ddG = mG.make_device_data(X, y, cuts=cuts)
-    os.environ["DMLC_SHARDED_INGEST"] = "1"
     mS = HistGBT(mesh=Mesh(devs[:N_DEV], ("data",)), **kw)
     ddS = mS.make_device_data(X, y, cuts=cuts)
     bins_ok = np.array_equal(np.asarray(ddG["bins_t"]),

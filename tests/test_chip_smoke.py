@@ -61,8 +61,7 @@ def test_body_runs_every_phase_on_the_virtual_mesh(monkeypatch):
         "_hist_pallas[n_nodes=1]", "_hist_pallas+int4[n_nodes=1]",
         "_hist_pallas[n_nodes=4]", "_hist_pallas+int4[n_nodes=4]",
         "fused_round[n_prev=1]", "fused_round+layout[n_prev=1]",
-        "_fused_pallas[n_prev=1]", "fused_round[n_prev=2]",
-        "fused_round+layout[n_prev=2]", "_fused_pallas[n_prev=2]"}
+        "fused_round[n_prev=2]", "fused_round+layout[n_prev=2]"}
     assert all(k["ok"] for k in kernels.values())
     # more than one device here, so the mesh phases ran too
     mesh = rep["all_devices"]
@@ -110,4 +109,4 @@ def test_explicit_pallas_on_ineligible_shape_raises():
         m._round_plan(512)
     assert np.all([v == "pallas" for v in HistGBT(
         n_trees=1, max_depth=6, n_bins=256, hist_method="pallas",
-        mesh=local_mesh(1))._round_plan(28)["hist_method"]])
+        mesh=local_mesh(1))._round_plan(28).hist_method])

@@ -184,7 +184,9 @@ class TestOverlapParity:
         m1, X, y = _tiny_fit(n_trees=3, seed=1)          # overlap (default)
         assert m1.last_compile_seconds is not None or \
             m1.last_compile_cache is None   # handle consumed or cache-warm
-        monkeypatch.setenv("DMLC_COLDSTART_OVERLAP", "0")
+        # the serial path a multi-worker job takes: no warmup handle
+        monkeypatch.setattr(HistGBT, "_maybe_start_warmup",
+                            lambda self, *a, **k: None)
         m2 = HistGBT(n_trees=3, max_depth=2, n_bins=8)
         m2.fit(X, y, warmup_rounds=1)
         assert m2.last_compile_seconds is None           # inline path
@@ -200,7 +202,8 @@ class TestOverlapParity:
                   colsample_bytree=0.8, seed=7)
         m1 = HistGBT(**kw)
         m1.fit(X, y, warmup_rounds=1, eval_set=(Xv, yv))
-        monkeypatch.setenv("DMLC_COLDSTART_OVERLAP", "0")
+        monkeypatch.setattr(HistGBT, "_maybe_start_warmup",
+                            lambda self, *a, **k: None)
         m2 = HistGBT(**kw)
         m2.fit(X, y, warmup_rounds=1, eval_set=(Xv, yv))
         _assert_same_trees(_trees(m1), _trees(m2))

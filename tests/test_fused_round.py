@@ -1,4 +1,4 @@
-"""Fully-fused round kernel: byte parity, quant accuracy, analytics.
+"""Fully-fused round kernel: byte parity, analytics.
 
 The ISSUE 18 contracts:
 
@@ -12,12 +12,8 @@ The ISSUE 18 contracts:
   later trees are order-sensitive);
 * ``DMLC_FUSED_ROUND=0`` restores the seed path exactly (same bytes as
   an unset knob on a non-TPU backend, where ``auto`` never engages);
-* the int8 quantized histogram sync (``DMLC_HIST_QUANT``) keeps
-  per-column grad/hess totals EXACT and bounds per-cell error by
-  ``n_chips * scale``;
-* the analytic traffic model (``hist_psum_bytes_per_round(quant=...)``,
-  ``bins_bytes_per_round(fused=...)``) matches the live
-  ``dmlc_histogram_psum_bytes_total`` counter under the quant lever.
+* the analytic traffic model (``bins_bytes_per_round(fused=...)``)
+  prices the fused round's passes over the bin matrix.
 """
 
 import os
@@ -30,10 +26,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from dmlc_core_tpu.models import HistGBT  # noqa: E402
 from dmlc_core_tpu.ops.histogram import (bins_bytes_per_round,  # noqa: E402
-                                         dequantize_hist_sum,
-                                         fused_round_ok,
-                                         hist_psum_bytes_per_round,
-                                         quantize_hist_partial)
+                                         fused_round_ok)
 from dmlc_core_tpu.parallel.mesh import local_mesh  # noqa: E402
 
 # hist_method pinned to pallas: the fused kernel accumulates in pallas
@@ -112,95 +105,6 @@ class TestFusedByteParity:
         monkeypatch.setenv("DMLC_FUSED_ROUND", "0")
         b_off, _ = _fit_bytes(tmp_path / "off.gbt", X, y)
         assert b_auto == b_off
-
-
-class TestQuantAccuracy:
-    def _chip_partials(self, n_chips=8, shape=(2, 4, 6, 16), seed=3):
-        rng = np.random.default_rng(seed)
-        return [rng.normal(size=shape).astype(np.float32) * 7.0
-                for _ in range(n_chips)]
-
-    def test_column_totals_exact_cell_error_bounded(self):
-        # emulate the hist_sync quant branch: shared pmax scale, int32
-        # psum of int8 codes, f32 psum of exact column totals
-        parts = self._chip_partials()
-        n_chips = len(parts)
-        gmax = np.max([np.max(np.abs(p), axis=-1, keepdims=True)
-                       for p in parts], axis=0)
-        q_sum = np.zeros(parts[0].shape, np.int32)
-        tot_sum = np.zeros(gmax.shape, np.float32)
-        scale = None
-        for p in parts:
-            q, scale, tot = quantize_hist_partial(p, gmax)
-            q_sum += np.asarray(q, np.int32)
-            tot_sum += np.asarray(tot)
-        out = np.asarray(dequantize_hist_sum(q_sum, scale, tot_sum))
-        exact = np.sum(parts, axis=0)
-        scale = np.asarray(scale)
-        # the correction term makes per-(plane, node, feature) totals
-        # exact — leaf weights at a fixed split carry NO quant error
-        np.testing.assert_allclose(out.sum(-1, keepdims=True), tot_sum,
-                                   rtol=1e-5, atol=1e-4)
-        # per-cell: each chip rounds within scale/2 and the correction
-        # redistributes at most the same again — n_chips * scale overall
-        assert (np.abs(out - exact) <= n_chips * scale + 1e-5).all()
-
-    def test_shared_scale_never_clips(self):
-        # gmax is the GLOBAL pmax, so |hist/scale| <= 127 on every chip
-        parts = self._chip_partials(seed=9)
-        gmax = np.max([np.max(np.abs(p), axis=-1, keepdims=True)
-                       for p in parts], axis=0)
-        for p in parts:
-            q, scale, _ = quantize_hist_partial(p, gmax)
-            raw = np.round(np.asarray(p) / np.asarray(scale))
-            assert (np.abs(raw) <= 127).all()
-            np.testing.assert_array_equal(np.asarray(q, np.int32),
-                                          raw.astype(np.int32))
-
-    def test_quant_fit_close_to_exact(self, monkeypatch):
-        # end to end on the 8-chip mesh: the quantized sync must not
-        # move the margins materially (splits may flip on near-ties,
-        # the loss surface must not)
-        X, y = _narrow_xy(n=768, seed=11)
-        kw = dict(MODEL_KW, hist_method="segment")
-        base = HistGBT(mesh=local_mesh(8), **kw)
-        base.fit(X, y)
-        monkeypatch.setenv("DMLC_HIST_QUANT", "1")
-        quant = HistGBT(mesh=local_mesh(8), **kw)
-        quant.fit(X, y)
-        p0 = base.predict(X, output_margin=True)
-        p1 = quant.predict(X, output_margin=True)
-        assert float(np.max(np.abs(p0 - p1))) < 0.15
-        assert float(np.mean(np.abs(p0 - p1))) < 0.02
-
-
-class TestQuantTraffic:
-    def _psum_total(self):
-        from dmlc_core_tpu.base.metrics import default_registry
-        snap = default_registry().snapshot()["metrics"]
-        m = snap.get("dmlc_histogram_psum_bytes_total")
-        return (sum(s["value"] for s in m["series"]
-                    if s["labels"].get("engine") == "incore")
-                if m else 0.0)
-
-    def test_counter_matches_quant_model(self, monkeypatch):
-        # the live counter must price the int8 sync the chips actually
-        # pay: 2*F*(B+8) per built node, not 2*F*B*4
-        monkeypatch.setenv("DMLC_HIST_QUANT", "1")
-        X, y = _narrow_xy(n=512, seed=12)
-        kw = dict(MODEL_KW, hist_method="segment")
-        before = self._psum_total()
-        m8 = HistGBT(mesh=local_mesh(8), **kw)
-        m8.fit(X, y)
-        expect = kw["n_trees"] * hist_psum_bytes_per_round(
-            kw["max_depth"], X.shape[1], kw["n_bins"], quant=True)
-        assert self._psum_total() - before == expect
-
-    def test_quant_model_cuts_bytes(self):
-        full = hist_psum_bytes_per_round(6, 28, 256)
-        quant = hist_psum_bytes_per_round(6, 28, 256, quant=True)
-        # 2*S*(Bs+8) vs 2*S*Bs*4: ~3.9x at Bs=256
-        assert quant * 3 < full < quant * 4
 
 
 class TestAnalyticModel:

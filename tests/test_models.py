@@ -77,53 +77,27 @@ class TestHistogram:
         # int32 bins (>256 bin counts) scale the tile budget too
         assert _pallas_ok(512, 28, 1, 4, _TILE_ROWS)
 
-    def test_pallas_subtile_packing(self, rng, monkeypatch):
-        # S>1 subtile packing (ops/histogram.py _pack_factor) is disabled
-        # on v5e (measured slower) but the plumbing is a documented seam
-        # for other hardware — keep it correct: force pack=2 and check
-        # the packed kernel against the numpy oracle in interpret mode.
-        # tile_rows=256 is a unique static arg so the jit cache can't
-        # serve a pack=1 trace from another test.
+    @pytest.mark.parametrize("n_nodes", [1, 2, 4, 8, 16])
+    def test_pallas_partial_tiles_and_masked_rows(self, rng, n_nodes):
+        # the kernel wrapper itself, at every build width of a depth-6
+        # tree: 700 rows over 256-row tiles = the pad path and three
+        # partial-width tiles accumulating into one block, with masked
+        # rows that must drop out — against the numpy oracle (interpret
+        # mode off-TPU).  tile_rows=256 is a static arg of its own, so
+        # the jit cache cannot serve another test's trace.
         import dmlc_core_tpu.ops.histogram as H
 
-        monkeypatch.setattr(H, "_pack_factor", lambda n_nodes, n_bins: 2)
-        n, F, B, N = 700, 3, 128, 2    # pad path + 3 partial tiles
+        n, F, B = 700, 3, 128
         bins = rng.integers(0, B, size=(n, F)).astype(np.int32)
-        node = rng.integers(0, N, size=n).astype(np.int32)
+        node = rng.integers(0, n_nodes, size=n).astype(np.int32)
         node[::7] = -1                 # masked rows must drop out
         g = rng.normal(size=n).astype(np.float32)
         h = rng.uniform(0.1, 1.0, size=n).astype(np.float32)
         out = np.asarray(H._hist_pallas(
             jnp.asarray(bins), jnp.asarray(node), jnp.asarray(g),
-            jnp.asarray(h), N, B, 256))
-        ref = reference_histogram(bins, node, g, h, N, B)
+            jnp.asarray(h), n_nodes, B, 256))
+        ref = reference_histogram(bins, node, g, h, n_nodes, B)
         np.testing.assert_allclose(out, ref, atol=2e-2, rtol=1e-2)
-
-    def test_fused_descend_matches_two_pass(self, rng):
-        # the fused Pallas descend+histogram (off by default on v5e, env
-        # knob DMLC_TPU_FUSED_DESCEND) must stay in lockstep with the
-        # two-pass form: exact node routing, bf16-tolerance histograms.
-        # Interpret mode off-TPU exercises the kernel logic in CI.
-        from dmlc_core_tpu.ops.histogram import (_fused_pallas,
-                                                 fused_descend_histogram)
-
-        n, F, B, N = 9000, 6, 128, 4   # crosses the 8192 row tile
-        bins_t = jnp.asarray(rng.integers(0, B, size=(F, n)).astype(np.uint8))
-        node = rng.integers(0, N, size=n).astype(np.int32)
-        node[::7] = -1                 # padding rows stay -1 and drop out
-        node_d = jnp.asarray(node)
-        fs = jnp.asarray(rng.integers(0, F, size=n).astype(np.int32))
-        ts = jnp.asarray(rng.integers(0, B - 1, size=n).astype(np.int32))
-        g = jnp.asarray(rng.normal(size=n).astype(np.float32))
-        h = jnp.asarray(rng.uniform(0.1, 1.0, size=n).astype(np.float32))
-        hist_f, node_f = _fused_pallas(bins_t, node_d, fs, ts, g, h, N, B)
-        hist_u, node_u = fused_descend_histogram(
-            bins_t, node_d, fs, ts, g, h, N, B, "segment", fuse=False)
-        np.testing.assert_array_equal(np.asarray(node_f), np.asarray(node_u))
-        np.testing.assert_allclose(np.asarray(hist_f), np.asarray(hist_u),
-                                   atol=3e-2, rtol=1e-2)
-        # padding rows must remain -1 after the descend
-        assert np.all(np.asarray(node_f)[::7] == -1)
 
     def test_pallas_guard(self):
         from dmlc_core_tpu.ops.histogram import _pallas_ok
@@ -1053,7 +1027,7 @@ class TestRoundProgramCache:
         X, y = _synthetic(n=1024, f=6, seed=11)
         m1 = HistGBT(n_trees=4, max_depth=3, n_bins=32)
         m1.fit(X, y)
-        key = m1._round_fn_cache_key(6, 4)
+        key = m1._round_fn_cache_key(m1._round_plan(6), 4)
         assert key in hg._ROUND_FN_CACHE
         m2 = HistGBT(n_trees=4, max_depth=3, n_bins=32)
         m2.fit(X, y)

@@ -145,10 +145,11 @@ class TestShardedIngestParity:
         X, y = _make_xy(1013)
         cuts = compute_cuts(X, KW["n_bins"])
         mesh = local_mesh(8)
-        monkeypatch.setenv("DMLC_SHARDED_INGEST", "0")
-        m_gl = HistGBT(mesh=mesh, **KW)
-        dd_gl = m_gl.make_device_data(X, y, cuts=cuts)
-        monkeypatch.setenv("DMLC_SHARDED_INGEST", "1")
+        # the global-put fallback of a mesh that spans processes
+        with monkeypatch.context() as mp:
+            mp.setattr(HistGBT, "_sharded_ingest_ok", lambda self: False)
+            m_gl = HistGBT(mesh=mesh, **KW)
+            dd_gl = m_gl.make_device_data(X, y, cuts=cuts)
         m_sh = HistGBT(mesh=mesh, **KW)
         dd_sh = m_sh.make_device_data(X, y, cuts=cuts)
         assert np.array_equal(np.asarray(dd_gl["bins_t"]),
@@ -202,9 +203,9 @@ class TestShardedIngestParity:
             m.fit_external(RowBlockIter.create(str(path)), num_col=5)
             return m
 
-        monkeypatch.setenv("DMLC_SHARDED_INGEST", "0")
-        m_gl = fit_one()
-        monkeypatch.setenv("DMLC_SHARDED_INGEST", "1")
+        with monkeypatch.context() as mp:
+            mp.setattr(HistGBT, "_sharded_ingest_ok", lambda self: False)
+            m_gl = fit_one()
         m_sh = fit_one()
         assert _trees_equal(m_gl.trees, m_sh.trees)
 

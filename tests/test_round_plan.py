@@ -1,0 +1,196 @@
+"""The round program is chosen in ONE place: ``HistGBT._round_plan``.
+
+* the process-wide program cache is keyed on the param and the plan, so
+  every lever that stays moves the key exactly when it moves the plan;
+* once the plan is resolved, neither the key, the build nor the traced
+  closure reads the environment again;
+* the levers that lost on the chip (ISSUE 33) are gone from the knob
+  registry, the configuration page and the package.
+"""
+
+import os
+import sys
+
+import numpy as np
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax  # noqa: E402
+from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
+
+from dmlc_core_tpu.base import knobs  # noqa: E402
+from dmlc_core_tpu.models import HistGBT  # noqa: E402
+from dmlc_core_tpu.models import histgbt as hg  # noqa: E402
+from dmlc_core_tpu.ops import binlayout as bl  # noqa: E402
+from dmlc_core_tpu.parallel.mesh import local_mesh  # noqa: E402
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+F = 6
+KW = dict(n_trees=4, max_depth=3, n_bins=32)
+
+
+def _packed_layout():
+    """A nibble-packed layout of F features: four narrow, two wide."""
+    rng = np.random.default_rng(0)
+    bins_t = rng.integers(0, KW["n_bins"], size=(F, 256)).astype(np.uint8)
+    bins_t[:4] %= 5
+    lay = bl.compute_layout(bl.bin_counts(bins_t, KW["n_bins"]), F,
+                            KW["n_bins"], pack=True)
+    assert lay is not None and lay.pairs
+    return lay
+
+
+def _model(env, monkeypatch, layout=None, **kw):
+    """A model and its plan, resolved under ``env``; the environment is
+    back to what it was before the key or the build is asked for."""
+    with monkeypatch.context() as mp:
+        for k, v in env.items():
+            mp.setenv(k, v)
+        m = HistGBT(mesh=local_mesh(1), **dict(KW, **kw))
+        m._bin_layout = layout
+        return m, m._round_plan(F)
+
+
+#: lever -> (what the two sides of it are, which public key shows it)
+_LEVERS = {
+    "DMLC_HIST_BLOCKS": (dict(env={}), dict(env={"DMLC_HIST_BLOCKS": "2"}),
+                         "hist_blocks"),
+    "DMLC_GROW_POLICY": (dict(env={}),
+                         dict(env={"DMLC_GROW_POLICY": "lossguide"}),
+                         "grow_policy"),
+    "DMLC_MAX_LEAVES": (
+        dict(env={"DMLC_GROW_POLICY": "lossguide"}),
+        dict(env={"DMLC_GROW_POLICY": "lossguide", "DMLC_MAX_LEAVES": "4"}),
+        None),
+    "DMLC_FUSED_ROUND": (
+        dict(env={"DMLC_FUSED_ROUND": "0"}, hist_method="pallas"),
+        dict(env={"DMLC_FUSED_ROUND": "1"}, hist_method="pallas"),
+        "fused_round"),
+    "packed_layout": (dict(env={}), dict(env={}, layout="packed"),
+                      "bin_layout"),
+    "hist_method": (dict(env={}, hist_method="segment"),
+                    dict(env={}, hist_method="matmul"), "hist_method"),
+}
+
+
+@pytest.mark.parametrize("lever", sorted(_LEVERS))
+def test_cache_key_follows_the_plan(lever, monkeypatch):
+    side_a, side_b, shown = _LEVERS[lever]
+
+    def make(side):
+        side = dict(side)
+        if side.get("layout") == "packed":
+            side["layout"] = _packed_layout()
+        return _model(side.pop("env"), monkeypatch, **side)
+
+    (a, plan_a), (a2, plan_a2), (b, plan_b) = \
+        make(side_a), make(side_a), make(side_b)
+    # two models that differ in the lever: other plan, other key
+    assert plan_a != plan_b
+    assert a._round_fn_cache_key(plan_a, 2) != b._round_fn_cache_key(plan_b, 2)
+    if shown is not None:
+        assert a.round_plan[shown] != b.round_plan[shown]
+    # two that do not: one plan, one key, ONE cached program
+    assert plan_a == plan_a2 and a.round_plan == a2.round_plan
+    key = a._round_fn_cache_key(plan_a, 2)
+    assert key == a2._round_fn_cache_key(plan_a2, 2)
+    hg._ROUND_FN_CACHE.pop(key, None)
+    before = len(hg._ROUND_FN_CACHE)
+    fn = a._build_round_fn(plan_a, 2)
+    assert a2._build_round_fn(plan_a2, 2) is fn
+    assert hg._ROUND_FN_CACHE[key] is fn
+    assert len(hg._ROUND_FN_CACHE) == before + 1
+    # and a lever set AFTER the plan was resolved moves nothing
+    monkeypatch.setenv("DMLC_HIST_BLOCKS", "4")
+    monkeypatch.setenv("DMLC_GROW_POLICY", "lossguide")
+    monkeypatch.setenv("DMLC_FUSED_ROUND", "1")
+    assert a._round_fn_cache_key(plan_a, 2) == key
+    assert a._build_round_fn(plan_a, 2) is fn
+
+
+class _NoLevers(dict):
+    """``os.environ`` with every ``DMLC_*`` name unreadable."""
+
+    @staticmethod
+    def _guard(key):
+        assert not str(key).startswith("DMLC_"), \
+            f"{key} read after _round_plan returned"
+
+    def __getitem__(self, key):
+        self._guard(key)
+        return super().__getitem__(key)
+
+    def get(self, key, default=None):
+        self._guard(key)
+        return super().get(key, default)
+
+    def __contains__(self, key):
+        self._guard(key)
+        return super().__contains__(key)
+
+
+@pytest.mark.parametrize("policy", ["depthwise", "lossguide"])
+def test_no_environment_read_once_the_plan_is_resolved(policy, monkeypatch):
+    m, plan = _model({"DMLC_GROW_POLICY": policy, "DMLC_MAX_LEAVES": "5"},
+                     monkeypatch)
+    assert plan.grow_policy == policy
+    assert plan.max_leaves == (5 if policy == "lossguide" else 0)
+    key_before = m._round_fn_cache_key(plan, 2)
+    hg._ROUND_FN_CACHE.pop(key_before, None)        # a real build below
+    monkeypatch.setattr(os, "environ", _NoLevers(os.environ))
+    with pytest.raises(AssertionError, match="DMLC_HIST_BLOCKS"):
+        m._round_plan(F)                            # the guard does bite
+    for helper in ("_hist_blocks", "_max_leaves", "_grow_policy",
+                   "_fused_round_mode", "get_env"):
+        def refuse(*a, _h=helper, **k):
+            raise AssertionError(f"{_h} called after _round_plan returned")
+        monkeypatch.setattr(hg, helper, refuse)
+    assert m._round_fn_cache_key(plan, 2) == key_before
+    fn = m._build_round_fn(plan, 2)
+    # ... and neither does the closure when the program is traced
+    mesh, n = m.mesh, 64
+    row = NamedSharding(mesh, P("data"))
+    lowered = fn.lower(
+        jax.ShapeDtypeStruct((F, n), np.uint8,
+                             sharding=NamedSharding(mesh, P(None, "data"))),
+        jax.ShapeDtypeStruct((n,), np.float32, sharding=row),
+        jax.ShapeDtypeStruct((n,), np.float32, sharding=row),
+        jax.ShapeDtypeStruct((n,), np.float32, sharding=row))
+    assert "dmlc.round" in lowered.as_text(debug_info=True)
+
+
+def test_round_plan_record_is_the_plans_json_view():
+    import json
+
+    m = HistGBT(mesh=local_mesh(1), **KW)
+    plan = m._round_plan(F)
+    assert m.round_plan == plan.describe()
+    assert json.loads(json.dumps(m.round_plan)) == m.round_plan
+    assert set(m.round_plan) == {
+        "hist_method", "fused_round", "pallas_interpret", "grow_policy",
+        "bin_layout", "hist_blocks", "mesh_devices"}
+    assert m.round_plan["hist_method"] == ["segment"] * KW["max_depth"]
+    assert hash(plan) == hash(m._round_plan(F))
+
+
+_DELETED = ["DMLC_TPU_FUSED_" + "DESCEND", "DMLC_HIST_" + "QUANT",
+            "DMLC_COLDSTART_" + "OVERLAP", "DMLC_SHARDED_" + "INGEST",
+            "DMLC_WARMUP_" + "EXEC"]
+
+
+@pytest.mark.parametrize("name", _DELETED)
+def test_deleted_lever_is_gone(name):
+    assert name not in knobs.names()
+    assert len(knobs.names()) == 100
+    with open(os.path.join(_REPO, "doc", "configuration.md")) as f:
+        assert name not in f.read()
+    hits = []
+    for d, _, files in os.walk(os.path.join(_REPO, "dmlc_core_tpu")):
+        for fn in files:
+            if fn.endswith(".py"):
+                with open(os.path.join(d, fn), errors="replace") as f:
+                    if name in f.read():
+                        hits.append(os.path.relpath(os.path.join(d, fn),
+                                                    _REPO))
+    assert hits == []

@@ -188,7 +188,7 @@ def _round_program_text():
     y = (X[:, 0] > 0).astype(np.float32)
     m = HistGBT(n_trees=2, max_depth=3, n_bins=16)
     h = m.make_device_data(X, y)
-    fn = m._build_round_fn(4, 2)
+    fn = m._build_round_fn(m._round_plan(4), 2)
     return fn.lower(h["bins_t"], h["y_d"], h["w_d"],
                     m._init_margin_device(h["n_padded"])).compile().as_text()
 
@@ -262,8 +262,7 @@ def test_binning_is_one_count_over_the_cuts(program, n_cuts):
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
 
 
-@pytest.mark.parametrize("kernel", ["dmlc_hist", "dmlc_fused_descend",
-                                    "dmlc_fused_round"])
+@pytest.mark.parametrize("kernel", ["dmlc_hist", "dmlc_fused_round"])
 def test_pallas_kernels_are_named(kernel):
     n, F, B, T = 512, 8, 16, 256
     bins = jnp.zeros((F, n), jnp.uint8)
@@ -272,8 +271,6 @@ def test_pallas_kernels_are_named(kernel):
     trace = {
         "dmlc_hist": lambda: H._hist_pallas(bins, node, g, g, 1, B, T, 0,
                                             True, None),
-        "dmlc_fused_descend": lambda: H._fused_pallas(
-            bins, node, node, node, g, g, 1, B, T, 0),
         "dmlc_fused_round": lambda: H.fused_round(
             bins, node, node, node, g, g, jnp.zeros((2, 1, F, B)), 1, B,
             tile_rows=T),
