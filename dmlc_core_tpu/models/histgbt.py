@@ -54,6 +54,7 @@ from dmlc_core_tpu.ops import binlayout as _bl
 from dmlc_core_tpu.ops.histogram import (build_histogram,
                                          descend_histogram,
                                          fused_round, fused_round_ok,
+                                         hist_feature_dots,
                                          hist_psum_bytes_per_round,
                                          pallas_interpret,
                                          resolve_hist_method,
@@ -220,6 +221,9 @@ class _RoundPlan(NamedTuple):
             "bin_layout": (None if lay is None else
                            f"{lay.n_features}F->{lay.phys_rows}rows"
                            f"/{len(lay.pairs)}pairs"),
+            # what the Pallas kernels issue per row tile, for the
+            # record: nothing reads it to choose a path
+            "hist_features": list(hist_feature_dots(self.n_features, lay)),
             "hist_blocks": self.hist_blocks,
             "mesh_devices": self.mesh_devices,
         }

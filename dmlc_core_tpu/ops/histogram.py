@@ -49,7 +49,8 @@ __all__ = ["build_histogram", "descend_histogram", "fused_round",
            "select_feature_bins", "histogram_methods",
            "resolve_hist_method", "pallas_interpret",
            "reference_histogram", "hist_psum_bytes_per_round",
-           "bins_bytes_per_round", "leaves_built_per_round"]
+           "bins_bytes_per_round", "leaves_built_per_round",
+           "hist_feature_dots"]
 
 
 def leaves_built_per_round(depth: int, grow_policy: str = "depthwise",
@@ -63,6 +64,20 @@ def leaves_built_per_round(depth: int, grow_policy: str = "depthwise",
     if grow_policy == "lossguide":
         return min(max_leaves, 1 << depth) if max_leaves else 1 << depth
     return 1 if depth <= 1 else 1 << (depth - 1)
+
+
+def hist_feature_dots(n_features: int, layout=None) -> tuple[int, int]:
+    """``(emitted, padded)``: the MXU dots the Pallas kernels issue per
+    row tile — one per REAL row of the feature-major bin matrix
+    (``n_features`` rows, or a ``layout``'s physical rows, two dots per
+    nibble-packed row) — and the dots the 8-row-padded block they read
+    would be if its zero rows counted.  HIGGS: ``(28, 32)``.  A record
+    (``HistGBT.round_plan``'s ``hist_features``) and the tests' oracle;
+    it selects nothing."""
+    if layout is None:
+        return n_features, -(-n_features // 8) * 8
+    rows, packed = layout.phys_rows, layout.packed_rows
+    return rows + packed, -(-rows // 8) * 8 + packed
 
 
 def hist_psum_bytes_per_round(depth: int, n_features: int,
@@ -159,7 +174,10 @@ def _pallas_ok(n_bins: int, n_features: int, n_nodes: int = 1,
     tile 65536 at lo=32, nh=8, Fp=32 predicts 17.3MB and measurably
     OOMs the 16MB scoped-vmem limit (sweep_hist, 10M rows); tile 16384
     at the deepest default level predicts 9.8MB and runs.  The 15MB
-    budget keeps margin under the measured 16MB wall."""
+    budget keeps margin under the measured 16MB wall.  Both terms stay
+    on ``fp``, not on the real feature count: the kernel skips a pad
+    feature's dot, but its rows are still in the bins block it reads
+    and in the accumulator it holds."""
     lo = _lo_factor(n_nodes, n_bins)
     hi = -(-n_bins // lo)
     fp = -(-n_features // 8) * 8
@@ -339,7 +357,7 @@ def _hist_matmul(bins, node_id, grad, hess, n_nodes, n_bins,
 
 
 def _hist_pallas_kernel(bins_ref, node_ref, g_ref, h_ref, out_ref,
-                        *, n_nodes, hi, lo, n_pack_groups=0):
+                        *, n_nodes, hi, lo, n_rows, n_pack_groups=0):
     """One row-tile of the FACTORED one-hot matmul.
 
     bin = hi_part·lo + lo_part.  Per feature, ONE MXU dot
@@ -357,6 +375,13 @@ def _hist_pallas_kernel(bins_ref, node_ref, g_ref, h_ref, out_ref,
     features blows the scoped-vmem stack, and Mosaic lowers neither
     dynamic_slice on values nor lane-dim dynamic ref slices.  Vector
     compares run in int32 (bf16/int16 compares rejected by this target).
+
+    ``n_rows`` is the matrix's REAL row count: the block is padded to
+    ``Fp`` rows (a multiple of 8) with zeros, and a pad FEATURE is not
+    free the way a padded ROW (``node = -1``, matches nothing) is — its
+    rows all sit in bin 0, so its compare, scalings and dot would cost
+    exactly what a real feature's do, for sums the caller drops.
+    :func:`_accum_hist` stops at ``n_rows``.
     """
     i = pl.program_id(0)
     node = node_ref[:].astype(jnp.int32)                              # [1, T]
@@ -368,11 +393,11 @@ def _hist_pallas_kernel(bins_ref, node_ref, g_ref, h_ref, out_ref,
         out_ref[:] = jnp.zeros_like(out_ref)
 
     _accum_hist(bins_ref, out_ref, node, g, h,
-                n_nodes=n_nodes, hi=hi, lo=lo,
+                n_nodes=n_nodes, hi=hi, lo=lo, n_rows=n_rows,
                 n_pack_groups=n_pack_groups)
 
 
-def _accum_hist(bins_ref, out_ref, node, g, h, *, n_nodes, hi, lo,
+def _accum_hist(bins_ref, out_ref, node, g, h, *, n_nodes, hi, lo, n_rows,
                 n_pack_groups=0):
     """Shared histogram accumulation loop (see _hist_pallas_kernel doc).
 
@@ -385,8 +410,19 @@ def _accum_hist(bins_ref, out_ref, node, g, h, *, n_nodes, hi, lo,
     ``16·n_pack_groups``.  With ``n_pack_groups == 0`` the trace is
     IDENTICAL to the pre-layout kernel (the packed loop is not even
     traced), preserving bit-parity for the default path.
+
+    ``n_rows`` of the block's ``Fp`` rows are real; the zero rows that
+    pad the unpacked region to a multiple of 8 are pad FEATURES.  A dot
+    is emitted only for a real row: the loop runs the whole groups of 8
+    and ONE static tail group unrolls the ``n_rows % 8`` that remain
+    (it reads the same aligned ``[8, T]`` block — the pad is still
+    there to be read, just not multiplied).  Output rows past the last
+    real one keep the zeros of the ``i == 0`` init.  At
+    ``n_rows % 8 == 0`` there is no tail and the trace is the one this
+    loop always had; every real row's sum is made of the same
+    operations in the same order either way.
     """
-    F, T = bins_ref.shape
+    T = bins_ref.shape[1]
     nh = n_nodes * hi
     nh_iota = jax.lax.broadcasted_iota(jnp.int32, (nh, T), 0)
     lo_iota = jax.lax.broadcasted_iota(jnp.int32, (lo, T), 0)
@@ -425,28 +461,35 @@ def _accum_hist(bins_ref, out_ref, node, g, h, *, n_nodes, hi, lo,
         jax.lax.fori_loop(0, n_pack_groups, pbody, 0)
     log_off = 16 * n_pack_groups
 
-    def body(fg, carry):
-        # feature GROUPS of 8: sublane-dim ref slices must be 8-aligned
-        # (pl.multiple_of proves it); within a group a static unroll —
-        # a full 28-feature unroll blows the scoped-vmem stack.  The
-        # integer prep runs BATCHED on [8, T] (a [1, T] op costs the
-        # same VPU tiles as [8, T] — sublane padding), only the one-hot
-        # compares are per-feature.
-        base = pl.multiple_of(fg * 8 + 8 * n_pack_groups if n_pack_groups
-                              else fg * 8, 8)
+    def group(base, row, n_live):
+        # feature GROUPS of 8: sublane-dim ref slices must be 8-aligned;
+        # within a group a static unroll — a full 28-feature unroll
+        # blows the scoped-vmem stack.  The integer prep runs BATCHED on
+        # [8, T] (a [1, T] op costs the same VPU tiles as [8, T] —
+        # sublane padding), only the one-hot compares are per-feature.
         blk = bins_ref[pl.ds(base, 8), :].astype(jnp.int32)           # [8, T]
         # padding rows carry t0_node ≈ -2^20 → t0 < 0 → match nothing
         t0s = t0_node + blk // lo                                     # [8, T]
         los = blk % lo                                                # [8, T]
-        for k in range(8):
-            emit(t0s, los, k, log_off + fg * 8 + k if n_pack_groups
-                 else fg * 8 + k)
+        for k in range(n_live):
+            emit(t0s, los, k, row(k))
+
+    def body(fg, carry):
+        # pl.multiple_of proves the dynamic slice's alignment
+        group(pl.multiple_of(fg * 8 + 8 * n_pack_groups if n_pack_groups
+                             else fg * 8, 8),
+              lambda k: (log_off + fg * 8 + k if n_pack_groups
+                         else fg * 8 + k), 8)
         return carry
 
-    jax.lax.fori_loop(0, F // 8 - n_pack_groups, body, 0)
+    full, rem = divmod(n_rows - 8 * n_pack_groups, 8)
+    jax.lax.fori_loop(0, full, body, 0)
+    if rem:
+        group(8 * (n_pack_groups + full),
+              lambda k: log_off + 8 * full + k, rem)
 
 
-def _fused_round_kernel(*refs, n_prev, hi, lo, n_pack_groups,
+def _fused_round_kernel(*refs, n_prev, hi, lo, n_rows, n_pack_groups,
                         with_layout):
     """ONE Pallas program for a whole tree level: bin-read → node
     descend → g/h scatter-accumulate → sibling subtraction, with the
@@ -525,7 +568,7 @@ def _fused_round_kernel(*refs, n_prev, hi, lo, n_pack_groups,
     # left children only — the right slab comes from sibling subtraction
     node_h = jnp.where(valid & (new_node % 2 == 0), new_node >> 1, -1)
     _accum_hist(bins_ref, left_ref, node_h, g, h,
-                n_nodes=n_prev, hi=hi, lo=lo,
+                n_nodes=n_prev, hi=hi, lo=lo, n_rows=n_rows,
                 n_pack_groups=n_pack_groups)
 
     @pl.when(i == pl.num_programs(0) - 1)
@@ -656,7 +699,8 @@ def fused_round(
 
     left, right, new_node = pl.pallas_call(
         partial(_fused_round_kernel, n_prev=n_prev, hi=hi, lo=lo,
-                n_pack_groups=npg, with_layout=layout is not None),
+                n_rows=Fphys, n_pack_groups=npg,
+                with_layout=layout is not None),
         out_shape=(
             jax.ShapeDtypeStruct((L, A, lo), jnp.float32),
             jax.ShapeDtypeStruct((L, A, lo), jnp.float32),
@@ -756,7 +800,7 @@ def _hist_pallas(bins, node_id, grad, hess, n_nodes, n_bins,
 
     out = pl.pallas_call(
         partial(_hist_pallas_kernel, n_nodes=n_nodes, hi=hi, lo=lo,
-                n_pack_groups=npg),
+                n_rows=F, n_pack_groups=npg),
         out_shape=jax.ShapeDtypeStruct((L, A, lo), jnp.float32),
         grid=(grid,),
         in_specs=[

@@ -57,6 +57,15 @@ def _bundle_xy(n=1404, seed=4):
     return X, y
 
 
+def _wide_xy(F, n=1302, seed=11):
+    # HIGGS's 28 features and 31: the kernels' blocks are padded to 32
+    # rows and the last group of 8 is partly pad features (ISSUE 34)
+    rng = np.random.default_rng(seed + F)
+    X = rng.normal(size=(n, F)).astype(np.float32)
+    y = ((X[:, 0] - X[:, F - 1] + 0.5 * X[:, F // 2]) > 0).astype(np.float32)
+    return X, y
+
+
 def _fit_bytes(path, X, y):
     m = HistGBT(mesh=local_mesh(1), **MODEL_KW)
     m.fit(X, y)
@@ -79,6 +88,11 @@ class TestFusedByteParity:
         ("lossguide_bundle", {"DMLC_GROW_POLICY": "lossguide",
                               "DMLC_MAX_LEAVES": "6",
                               "DMLC_FEATURE_BUNDLE": "1"}, _bundle_xy),
+        ("depthwise_28_features", {}, lambda: _wide_xy(28)),
+        ("depthwise_31_features", {}, lambda: _wide_xy(31)),
+        ("lossguide_28_features", {"DMLC_GROW_POLICY": "lossguide",
+                                   "DMLC_MAX_LEAVES": "6"},
+         lambda: _wide_xy(28)),
     ]
 
     @pytest.mark.parametrize("name,env,mk", CASES,

@@ -1,0 +1,249 @@
+"""The Pallas histogram kernels emit a dot per REAL feature (ISSUE 34).
+
+The bin matrix is padded to a multiple of 8 rows so that the kernels
+can slice it in aligned groups; until ISSUE 34 every pad FEATURE got
+its compare, scalings and MXU dot like a real one (HIGGS: 4 of 32).
+
+* ``_hist_pallas`` equals the scatter-add engine at feature counts on
+  both sides of a group boundary, transposed and not, with masked rows;
+* ``fused_round`` equals the staged descend + build + subtract;
+* a fit at F = 28 equals the fit of the same columns zero-padded to 32
+  by the caller — the arithmetic of the kernels before ISSUE 34;
+* the dots a row tile issues are counted off the kernel's jaxpr: F, not
+  the padded count, and ``HistGBT.round_plan`` records the pair.
+
+The gradients of the kernel-level cases are multiples of 1/8 and 1/4,
+exact in bfloat16, and their float32 sums are exact in any order: the
+engines are compared bit for bit, not within a tolerance.
+"""
+
+import os
+import sys
+
+import numpy as np
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+from dmlc_core_tpu.models import HistGBT  # noqa: E402
+from dmlc_core_tpu.ops import binlayout as bl  # noqa: E402
+from dmlc_core_tpu.ops import histogram as H  # noqa: E402
+from dmlc_core_tpu.parallel.mesh import local_mesh  # noqa: E402
+
+B, TILE = 64, 256
+
+
+def _rows(F, n_nodes, n=700, seed=0):
+    """Feature-major bins, node ids with masked rows, exact gradients."""
+    rng = np.random.default_rng(seed + 131 * F + n_nodes)
+    bins_t = rng.integers(0, B, size=(F, n)).astype(np.uint8)
+    node = rng.integers(0, n_nodes, size=n).astype(np.int32)
+    node[::7] = -1                          # padded / pruned rows
+    g = (rng.integers(-16, 17, size=n) / 8).astype(np.float32)
+    h = (rng.integers(1, 9, size=n) / 4).astype(np.float32)
+    return bins_t, node, g, h
+
+
+@pytest.mark.parametrize("transposed", [True, False],
+                         ids=["feature_major", "row_major"])
+@pytest.mark.parametrize("n_nodes", [1, 4, 16])
+@pytest.mark.parametrize("F", [5, 8, 12, 28, 31, 32, 39])
+def test_hist_pallas_equals_segment(F, n_nodes, transposed):
+    bins_t, node, g, h = _rows(F, n_nodes)
+    # 700 rows over 256-row tiles: three tiles into one block, the last
+    # one partly row padding (node = -1), whatever F's feature padding
+    got = np.asarray(H._hist_pallas(
+        jnp.asarray(bins_t if transposed else bins_t.T), jnp.asarray(node),
+        jnp.asarray(g), jnp.asarray(h), n_nodes, B, TILE, 0, transposed))
+    want = np.asarray(H._hist_segment(
+        jnp.asarray(bins_t.T), jnp.asarray(node), jnp.asarray(g),
+        jnp.asarray(h), n_nodes, B))
+    assert got.shape == (2, n_nodes, F, B)
+    assert np.array_equal(got, want)
+    if F == 5 and n_nodes == 1:             # the oracle itself, once
+        np.testing.assert_array_equal(
+            want, H.reference_histogram(bins_t.T, node, g, h, n_nodes, B))
+
+
+@pytest.mark.parametrize("n_prev", [1, 4])
+@pytest.mark.parametrize("F", [28, 31])
+def test_fused_round_equals_staged_level(F, n_prev):
+    bins_t, node, g, h = _rows(F, n_prev, seed=5)
+    rng = np.random.default_rng(F)
+    feat = rng.integers(0, F, size=n_prev).astype(np.int32)[
+        np.maximum(node, 0)]
+    feat[-3:] = F - 1                       # the last real feature is a key
+    thr = rng.integers(0, B, size=node.size).astype(np.int32)
+    args = [jnp.asarray(a) for a in (bins_t, node, feat, thr, g, h)]
+    prev = H._hist_pallas(args[0], args[1], args[4], args[5], n_prev, B,
+                          TILE, 0, True)
+    new_node, hist, _ = H.fused_round(*args, prev, n_prev, B, tile_rows=TILE)
+    left, staged_node = H.descend_histogram(*args, n_prev, B, "pallas")
+    staged = jnp.stack([left, prev - left], axis=2).reshape(
+        2, 2 * n_prev, F, B)
+    assert np.array_equal(np.asarray(new_node), np.asarray(staged_node))
+    assert np.array_equal(np.asarray(hist), np.asarray(staged))
+    assert np.asarray(hist).any()
+
+
+# -- what a row tile issues, counted off the jaxpr ---------------------
+
+def _dots(jaxpr):
+    """``dot_general``s one execution of ``jaxpr`` issues: a loop's body
+    counts once per trip (a ``fori_loop`` over static bounds is a scan)."""
+    total = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            total += 1
+            continue
+        assert eqn.primitive.name != "while", "a trip count nobody can read"
+        trips = eqn.params.get("length", 1) if eqn.primitive.name == "scan" \
+            else 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            total += trips * _dots(sub)
+    return total
+
+
+def _kernel_dots(fn, *args):
+    """Dots per row tile of the one ``pallas_call`` that ``fn`` traces."""
+    calls = [e for e in jax.make_jaxpr(fn)(*args).eqns
+             if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    return _dots(calls[0].params["jaxpr"])
+
+
+def _shapes(rows, n=2 * TILE):
+    return (jnp.zeros((rows, n), jnp.uint8), jnp.zeros(n, jnp.int32),
+            jnp.zeros(n, jnp.float32), jnp.zeros(n, jnp.float32))
+
+
+@pytest.mark.parametrize("F", [5, 8, 28, 31, 32, 39])
+def test_hist_kernel_issues_one_dot_per_real_feature(F):
+    bins_t, node, g, h = _shapes(F)
+    got = _kernel_dots(
+        lambda *a: H._hist_pallas.__wrapped__(*a, 4, B, TILE, 0, True),
+        bins_t, node, g, h)
+    assert (got, -(-F // 8) * 8) == H.hist_feature_dots(F)
+    assert got == F
+
+
+@pytest.mark.parametrize("F", [28, 32])
+def test_fused_kernel_issues_one_dot_per_real_feature(F):
+    bins_t, node, g, h = _shapes(F)
+    got = _kernel_dots(
+        lambda b, nd, g, h: H.fused_round(
+            b, nd, nd, nd, g, h, jnp.zeros((2, 4, F, B)), 4, B,
+            tile_rows=TILE)[:2],
+        bins_t, node, g, h)
+    assert got == F == H.hist_feature_dots(F)[0]
+
+
+def test_packed_layout_skips_the_pad_rows_of_its_unpacked_region():
+    # four narrow features nibble-packed into two byte rows (one padded
+    # group of 8: 16 logical rows), two wide ones after them: 10
+    # physical rows in a block of 16
+    rng = np.random.default_rng(0)
+    bins_t = rng.integers(0, 32, size=(6, 256)).astype(np.uint8)
+    bins_t[:4] %= 5
+    lay = bl.compute_layout(bl.bin_counts(bins_t, 32), 6, 32, pack=True)
+    assert lay.pairs and (lay.packed_rows, lay.phys_rows) == (8, 10)
+    phys, node, g, h = _shapes(lay.phys_rows)
+    got = _kernel_dots(
+        lambda *a: H._hist_pallas.__wrapped__(
+            *a, 4, lay.sync_bins, TILE, 0, True, lay), phys, node, g, h)
+    assert H.hist_feature_dots(6, lay) == (18, 24)
+    assert got == 18
+
+
+def test_round_plan_records_the_pair():
+    m = HistGBT(mesh=local_mesh(1), n_trees=2, max_depth=3, n_bins=32)
+    assert m._round_plan(28).describe()["hist_features"] == [28, 32]
+    assert m._round_plan(32).describe()["hist_features"] == [32, 32]
+    assert m.round_plan["hist_features"] == [32, 32]
+
+
+# -- a fit: the caller's own zero columns against the kernel's ---------
+
+@pytest.mark.parametrize("fused", ["0", "1"], ids=["staged", "fused"])
+def test_fit_equals_fit_of_zero_padded_features(fused, monkeypatch,
+                                                tmp_path):
+    """With four zero columns appended by the CALLER the kernels build
+    32 features, the four constant ones like any other (no group has a
+    tail): the kernels' arithmetic before ISSUE 34.  A constant feature
+    is never split on, so the trees must be the same bytes."""
+    monkeypatch.setenv("DMLC_FUSED_ROUND", fused)
+    rng = np.random.default_rng(3)
+    X = rng.normal(size=(1201, 28)).astype(np.float32)
+    y = (X[:, 0] - 0.7 * X[:, 27] + 0.3 * X[:, 13] > 0).astype(np.float32)
+    kw = dict(n_trees=3, max_depth=4, n_bins=32, hist_method="pallas",
+              objective="binary:logistic", learning_rate=0.3)
+
+    def saved(X, name):
+        m = HistGBT(mesh=local_mesh(1), **kw)
+        m.fit(X, y)
+        assert m.round_plan["fused_round"] is (fused == "1")
+        assert m.round_plan["hist_features"] == [X.shape[1], 32]
+        m.cuts = m.cuts[:28]                 # the file holds the cuts too
+        m.save_model(str(tmp_path / name))
+        return (tmp_path / name).read_bytes(), m
+
+    b28, m28 = saved(X, "f28.gbt")
+    b32, _ = saved(np.pad(X, ((0, 0), (0, 4))), "f32.gbt")
+    assert b28 == b32
+    assert any(np.asarray(t["feat"]).max() == 27 for t in m28.trees)
+
+
+# -- the chip's compiler, without the chip ------------------------------
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("kernel", ["dmlc_hist", "dmlc_fused_round"])
+@pytest.mark.parametrize("F", [28, 31])
+def test_mosaic_compiles_the_tail_at_the_deepest_level(F, kernel, one_chip,
+                                                       monkeypatch):
+    """The static tail unrolls up to seven more dots beside the loop's
+    eight: Mosaic has to take it inside scoped VMEM at the deepest level
+    of a depth-6 tree (16 built nodes, 256 bins, the real row tile)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    monkeypatch.setattr(H, "pallas_interpret", lambda: False)
+    n, N, bins = 2 * H._TILE_ROWS, 16, 256
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows = [S((F, n), jnp.uint8), S((n,), jnp.int32), S((n,), jnp.float32),
+            S((n,), jnp.float32)]
+    if kernel == "dmlc_hist":
+        def fn(b, nd, g, h):
+            return H._hist_pallas.__wrapped__(b, nd, g, h, N, bins,
+                                              H._TILE_ROWS, 0, True)
+    else:
+        def fn(b, nd, g, h, prev):
+            return H.fused_round(b, nd, nd, nd, g, h, prev, N, bins)[:2]
+        rows.append(S((2, N, F, bins), jnp.float32))
+    # a compile for a described chip cannot be read back from the
+    # persistent cache: keep it out
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        text = jax.jit(fn).lower(*rows).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+    assert kernel in text and "tpu_custom_call" in text
