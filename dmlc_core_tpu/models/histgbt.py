@@ -54,6 +54,7 @@ from dmlc_core_tpu.ops import binlayout as _bl
 from dmlc_core_tpu.ops.histogram import (build_histogram,
                                          descend_histogram,
                                          fused_round, fused_round_ok,
+                                         hist_feature_blocks,
                                          hist_feature_dots,
                                          hist_psum_bytes_per_round,
                                          pallas_interpret,
@@ -201,6 +202,10 @@ class _RoundPlan(NamedTuple):
     n_features: int
     #: histogram engine of each BUILD in tree order
     hist_method: Tuple[str, ...]
+    #: rows of each feature block of each Pallas build (``()`` for the
+    #: other engines): what ``ops.build_histogram`` derives again from
+    #: the same shapes when it traces — a record, it selects nothing
+    hist_feature_blocks: Tuple[Tuple[int, ...], ...]
     fused_round: bool
     pallas_interpret: bool
     grow_policy: str
@@ -224,6 +229,8 @@ class _RoundPlan(NamedTuple):
             # what the Pallas kernels issue per row tile, for the
             # record: nothing reads it to choose a path
             "hist_features": list(hist_feature_dots(self.n_features, lay)),
+            "hist_feature_blocks": [list(b) for b in
+                                    self.hist_feature_blocks],
             "hist_blocks": self.hist_blocks,
             "mesh_devices": self.mesh_devices,
         }
@@ -1907,12 +1914,21 @@ class HistGBT(_ExternalMemoryEngine):
         lossguide = _grow_policy() == "lossguide"
         builds = [1] if lossguide else (
             [1] + [1 << (lv - 1) for lv in range(1, depth)])
+        packed = layout is not None and bool(layout.pairs)
+        methods = tuple(
+            "pallas" if fused and i > 0 else
+            resolve_hist_method(p.hist_method, sync_bins, mat_rows, nb,
+                                whole=packed)
+            for i, nb in enumerate(builds))
         plan = _RoundPlan(
             n_features=n_features,
-            hist_method=tuple(
-                "pallas" if fused and i > 0 else
-                resolve_hist_method(p.hist_method, sync_bins, mat_rows, nb)
-                for i, nb in enumerate(builds)),
+            hist_method=methods,
+            # the fused kernel and a packed layout take the matrix whole
+            hist_feature_blocks=tuple(
+                () if m != "pallas" else
+                (mat_rows,) if (fused and i > 0) or packed else
+                hist_feature_blocks(sync_bins, mat_rows, nb)
+                for i, (m, nb) in enumerate(zip(methods, builds))),
             fused_round=fused,
             pallas_interpret=pallas_interpret(),
             grow_policy="lossguide" if lossguide else "depthwise",

@@ -14,11 +14,17 @@ there.  XLA formulations, selected by ``method``:
   with f32 accumulation (``preferred_element_type``) yields the whole
   block's contribution.  Blocking bounds the one-hot materialization to
   ~100MB regardless of n.
-* ``"pallas"`` — the factored one-hot Pallas kernel (``_hist_pallas``).
-* ``"auto"`` — :func:`resolve_hist_method`: on TPU ``pallas`` where the
-  shape passes the kernel's VMEM gate, else ``matmul``; ``segment`` on
-  every other backend.  An EXPLICIT method is never rewritten: asking
-  for ``pallas`` at a shape the gate rejects raises.
+* ``"pallas"`` — the factored one-hot Pallas kernel (``_hist_pallas``),
+  at any dense feature count: where the whole matrix is more than the
+  kernel's VMEM budgets admit, the build runs in FEATURE BLOCKS
+  (:func:`hist_feature_blocks`), the same kernel once per block of rows
+  of the feature-major matrix, joined on the feature axis.
+* ``"auto"`` — :func:`resolve_hist_method`: on TPU ``pallas`` wherever
+  the kernel's VMEM budgets admit a feature block at all (every dense
+  shape of a depth-wise tree up to depth 7 at 256 bins), else
+  ``matmul``; ``segment`` on every other backend.  An EXPLICIT method
+  is never rewritten: asking for ``pallas`` at a shape the budgets
+  refuse raises.
 
 TPU layout note: the result is ``[2, n_nodes, F, n_bins]`` with the
 grad/hess plane LEADING.  A trailing axis of size 2 is catastrophic under
@@ -50,7 +56,7 @@ __all__ = ["build_histogram", "descend_histogram", "fused_round",
            "resolve_hist_method", "pallas_interpret",
            "reference_histogram", "hist_psum_bytes_per_round",
            "bins_bytes_per_round", "leaves_built_per_round",
-           "hist_feature_dots"]
+           "hist_feature_dots", "hist_feature_blocks"]
 
 
 def leaves_built_per_round(depth: int, grow_policy: str = "depthwise",
@@ -159,33 +165,78 @@ def histogram_methods() -> list[str]:
 _TILE_ROWS = 16384
 
 
+#: scoped VMEM a TPU kernel may hold (the compiler's limit on v5e), and
+#: what of it the histogram kernel needs beside its double-buffered bins
+#: block, per row of the tile.  Read off the v5e compiler at tile 16384,
+#: 256 bins (PERF.md section 6, PR 35): a build's scoped allocation is
+#: ``2·T·Fp`` bytes + 2.91-3.62 MiB for n_nodes 1..16; 240 B a row keeps
+#: 3.75 MiB.
+_SCOPED_VMEM = 16 << 20
+_SCOPED_ROW_RESERVE = 240
+
+
 def _pallas_ok(n_bins: int, n_features: int, n_nodes: int = 1,
-               bins_itemsize: int = 1, tile_rows: int = 0) -> bool:
-    """The factored kernel works for any n_bins; the binding constraints
-    are (a) the [Fp, A, lo] f32 accumulator block — empirically
-    calibrated on v5e at tile_rows=4096: nominal accumulators up to 32MB
-    compile and run (Mosaic windows the out block; fori_loop temporaries
-    are reused, so per-row working-set formulas wildly overestimate),
-    64MB fails, 24MB keeps margin — and (b) the tile-scaled VMEM stack:
-    per row-tile of T rows the kernel holds the [Fp, T] bins block, the
-    int32 prep ([8,T] blk/t0s/los), the per-feature one-hots (oh [nh,T] +
-    lhs [2nh,T] bf16, rhs [lo,T] bf16) and ~6 [1,T] i32/f32 vectors —
+               bins_itemsize: int = 1, tile_rows: int = 0) -> int:
+    """The FEATURE BLOCK of a Pallas build at this shape: how many rows
+    of the feature-major bin matrix one kernel call takes.
+    ``n_features`` itself where the whole matrix fits the kernel's VMEM
+    budgets (one call); else the largest multiple of 8 that fits, and the build runs block
+    by block (:func:`hist_feature_blocks`); 0 where not even 8 rows fit
+    (``n_nodes·hi`` too tall: deep levels, huge bin counts) and the
+    kernel cannot serve the shape.  Truthy = the kernel serves it.
+
+    The factored kernel works for any n_bins; the budgets, all linear
+    in the block's padded rows ``Fp``, are (a) the [Fp, A, lo] f32
+    accumulator block — empirically calibrated on v5e at
+    tile_rows=4096: nominal accumulators up to 32MB compile and run
+    (Mosaic windows the out block; fori_loop temporaries are reused, so
+    per-row working-set formulas wildly overestimate), 64MB fails, 24MB
+    keeps margin — (b) the tile-scaled VMEM stack: per row-tile of T
+    rows the kernel holds the [Fp, T] bins block, the int32 prep ([8,T]
+    blk/t0s/los), the per-feature one-hots (oh [nh,T] + lhs [2nh,T]
+    bf16, rhs [lo,T] bf16) and ~6 [1,T] i32/f32 vectors —
     ≈ T·(Fp·itemsize + 120 + 6·nh + 2·lo) bytes.  Calibration anchor:
     tile 65536 at lo=32, nh=8, Fp=32 predicts 17.3MB and measurably
     OOMs the 16MB scoped-vmem limit (sweep_hist, 10M rows); tile 16384
     at the deepest default level predicts 9.8MB and runs.  The 15MB
-    budget keeps margin under the measured 16MB wall.  Both terms stay
-    on ``fp``, not on the real feature count: the kernel skips a pad
-    feature's dot, but its rows are still in the bins block it reads
-    and in the accumulator it holds."""
+    budget keeps margin under the measured 16MB wall — and (c) the
+    pipeline's DOUBLE-buffered bins block, which (b) counts once and
+    which is all that matters once Fp is in the hundreds:
+    ``T·(2·Fp·itemsize + 240) <= 16 MiB`` (``_SCOPED_ROW_RESERVE``; at
+    the default tile Fp <= 392, where the compiler takes 416 / 392 / 408
+    rows at n_nodes 1 / 8 / 16 and refuses 424 / 400 / 416).  (a) and
+    (b) alone admit a root build of 728 rows, which Mosaic refuses.
+    All three stay on ``Fp``, not on the real feature count: the kernel
+    skips a pad feature's dot, but its rows are still in the bins block
+    it reads and in the accumulator it holds."""
     lo = _lo_factor(n_nodes, n_bins)
     hi = -(-n_bins // lo)
-    fp = -(-n_features // 8) * 8
     nh = n_nodes * hi
-    acc = fp * 2 * nh * max(lo, 128) * 4
     T = tile_rows or _TILE_ROWS
-    tile_stack = T * (fp * bins_itemsize + 120 + 6 * nh + 2 * lo)
-    return acc <= 24 << 20 and tile_stack <= 15 << 20
+    fp_max = min(
+        (24 << 20) // (2 * nh * max(lo, 128) * 4),
+        ((15 << 20) // T - (120 + 6 * nh + 2 * lo)) // bins_itemsize,
+        (_SCOPED_VMEM // T - _SCOPED_ROW_RESERVE) // (2 * bins_itemsize))
+    if -(-n_features // 8) * 8 <= fp_max:
+        return n_features
+    return max(fp_max // 8 * 8, 0)
+
+
+def hist_feature_blocks(n_bins: int, n_features: int, n_nodes: int = 1,
+                        bins_itemsize: int = 1) -> tuple[int, ...]:
+    """Rows of each feature block a Pallas build of this shape runs in,
+    in matrix order: ``(n_features,)`` where one kernel call takes the
+    whole matrix, else whole blocks of :func:`_pallas_ok` rows and the
+    rest; ``()`` where the kernel cannot serve the shape.  What
+    :func:`build_histogram` traces and ``HistGBT.round_plan`` records
+    (``hist_feature_blocks``).  Epsilon's 2000 features at 256 bins:
+    five blocks of 392 and one of 40, at every level of a depth-6
+    tree."""
+    fb = _pallas_ok(n_bins, n_features, n_nodes, bins_itemsize)
+    if not fb:
+        return ()
+    full, rest = divmod(n_features, fb)
+    return (fb,) * full + ((rest,) if rest else ())
 
 
 def pallas_interpret() -> bool:
@@ -198,27 +249,35 @@ def pallas_interpret() -> bool:
 
 
 def resolve_hist_method(method: str, n_bins: int, n_rows: int,
-                        n_nodes: int = 1, bins_itemsize: int = 1) -> str:
+                        n_nodes: int = 1, bins_itemsize: int = 1,
+                        whole: bool = False) -> str:
     """The histogram engine ``method`` stands for at this shape
     (``n_rows`` = rows of the feature-major matrix the kernel reads:
     features, or a layout's physical rows).  ``auto`` chooses from what
-    it can observe — backend and the kernel's VMEM gate; an explicit
-    method is returned as is, except that ``pallas`` at a shape
-    :func:`_pallas_ok` rejects is an error, never a quiet ``matmul``.
+    it can observe — backend and the kernel's VMEM budgets: on a TPU
+    ``pallas`` wherever :func:`_pallas_ok` admits a feature block, which
+    for a plain matrix is every width (the build runs block by block);
+    ``whole`` says the matrix cannot be cut (a nibble-packed layout's
+    packed rows lead the block), so all of ``n_rows`` have to fit.  An
+    explicit method is returned as is, except that ``pallas`` at a shape
+    the budgets refuse is an error, never a quiet ``matmul``.
     ``models.histgbt`` calls this per tree level up front, so the
     choice is on record (``HistGBT.round_plan``) before anything
     traces."""
+    block = _pallas_ok(n_bins, n_rows, n_nodes, bins_itemsize)
+    fits = block >= n_rows if whole else block > 0
     if method == "auto":
         if jax.default_backend() != "tpu":
             return "segment"
-        return ("pallas" if _pallas_ok(n_bins, n_rows, n_nodes,
-                                       bins_itemsize) else "matmul")
-    if method == "pallas" and not _pallas_ok(n_bins, n_rows, n_nodes,
-                                            bins_itemsize):
+        return "pallas" if fits else "matmul"
+    if method == "pallas" and not fits:
         log_fatal(f"build_histogram: method='pallas' was requested but "
-                  f"the kernel's VMEM gate rejects n_bins={n_bins}, "
-                  f"rows={n_rows}, n_nodes={n_nodes}, "
-                  f"itemsize={bins_itemsize} at tile_rows={_TILE_ROWS} — "
+                  f"the kernel's VMEM budgets admit "
+                  + (f"only {block} of the {n_rows} rows of a packed "
+                     f"layout, which cannot be built in feature blocks"
+                     if block else "no feature block, not even 8 rows,")
+                  + f" at n_bins={n_bins}, n_nodes={n_nodes}, "
+                  f"itemsize={bins_itemsize}, tile_rows={_TILE_ROWS} — "
                   f"use 'auto' or 'matmul'")
     if method not in ("segment", "matmul", "pallas"):
         log_fatal(f"build_histogram: unknown method {method!r}")
@@ -261,14 +320,14 @@ def build_histogram(
         CHECK(transposed, "layout= requires the transposed [F, n] matrix")
         n_bins = layout.sync_bins
         method = resolve_hist_method(method, n_bins, layout.phys_rows,
-                                     n_nodes, 1)
+                                     n_nodes, 1, whole=bool(layout.pairs))
         if method == "pallas":
             if layout.pairs:
                 return _hist_pallas(bins, node_id, grad, hess, n_nodes,
                                     n_bins, transposed=True, layout=layout)
             # bundle-only layout: physical == storage, plain kernel
-            return _hist_pallas(bins, node_id, grad, hess, n_nodes,
-                                n_bins, transposed=True)
+            return _hist_pallas_blocks(bins, node_id, grad, hess, n_nodes,
+                                       n_bins, transposed=True)
         storage = _bl.unpack_matrix(bins, layout)
         if method == "segment":
             return _hist_segment(storage.T, node_id, grad, hess,
@@ -284,8 +343,35 @@ def build_histogram(
     if method == "matmul":
         return _hist_matmul(bins.T if transposed else bins,
                             node_id, grad, hess, n_nodes, n_bins)
-    return _hist_pallas(bins, node_id, grad, hess, n_nodes, n_bins,
-                        transposed=transposed)
+    return _hist_pallas_blocks(bins, node_id, grad, hess, n_nodes, n_bins,
+                               transposed=transposed)
+
+
+def _hist_pallas_blocks(bins, node_id, grad, hess, n_nodes, n_bins, *,
+                        transposed):
+    """:func:`_hist_pallas` over the feature blocks of
+    :func:`hist_feature_blocks`.  One block (every shape the whole-matrix
+    budgets admit) is the plain call and traces nothing else.  Several:
+    the same kernel once per contiguous slab of feature rows, the
+    histograms joined on the feature axis — a feature's sums depend on
+    no other feature, so each is made of the same operations in the same
+    order as in an unblocked build, bit for bit.  What blocking adds
+    outside the kernels (the slabs, the join) runs under the device
+    scope ``dmlc.hist.fblock``."""
+    F = bins.shape[0] if transposed else bins.shape[1]
+    blocks = hist_feature_blocks(n_bins, F, n_nodes,
+                                 jnp.dtype(bins.dtype).itemsize)
+    if len(blocks) == 1:
+        return _hist_pallas(bins, node_id, grad, hess, n_nodes, n_bins,
+                            transposed=transposed)
+    in_fblock = jax.named_scope("dmlc.hist.fblock")
+    slab = in_fblock(lambda lo, hi: (bins[lo:hi] if transposed
+                                     else bins[:, lo:hi]))
+    edges = [sum(blocks[:k]) for k in range(len(blocks) + 1)]
+    return in_fblock(jnp.concatenate)(
+        [_hist_pallas(slab(lo, hi), node_id, grad, hess, n_nodes, n_bins,
+                      transposed=transposed)
+         for lo, hi in zip(edges[:-1], edges[1:])], axis=2)
 
 
 @partial(jax.jit, static_argnums=(4, 5))
@@ -579,7 +665,8 @@ def _fused_round_kernel(*refs, n_prev, hi, lo, n_rows, n_pack_groups,
 def fused_round_ok(n_bins: int, n_features: int, n_prev: int = 1,
                    bins_itemsize: int = 1, tile_rows: int = 0,
                    with_layout: bool = False) -> bool:
-    """Eligibility of the fused ROUND kernel (cf. :func:`_pallas_ok`):
+    """Eligibility of the fused ROUND kernel (cf. :func:`_pallas_ok`;
+    this one is a yes or no — the fused kernel is not built in blocks):
     it holds THREE accumulator-shaped slabs in VMEM (prev, left, right)
     instead of one, and the layout mode streams five extra [1, T] int32
     decode vectors plus the [16, T] compact-remap table per tile."""

@@ -211,6 +211,22 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _compile_for_the_chip(fn, *shapes):
+    """XLA:TPU + Mosaic on ``fn`` at ``shapes``; the compiled text.  A
+    compile for a described chip cannot be read back from the persistent
+    cache: it is kept out."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        return jax.jit(fn).lower(*shapes).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+
+
 @pytest.mark.parametrize("kernel", ["dmlc_hist", "dmlc_fused_round"])
 @pytest.mark.parametrize("F", [28, 31])
 def test_mosaic_compiles_the_tail_at_the_deepest_level(F, kernel, one_chip,
@@ -218,8 +234,6 @@ def test_mosaic_compiles_the_tail_at_the_deepest_level(F, kernel, one_chip,
     """The static tail unrolls up to seven more dots beside the loop's
     eight: Mosaic has to take it inside scoped VMEM at the deepest level
     of a depth-6 tree (16 built nodes, 256 bins, the real row tile)."""
-    from jax.experimental.compilation_cache import compilation_cache as cc
-
     monkeypatch.setattr(H, "pallas_interpret", lambda: False)
     n, N, bins = 2 * H._TILE_ROWS, 16, 256
 
@@ -236,14 +250,53 @@ def test_mosaic_compiles_the_tail_at_the_deepest_level(F, kernel, one_chip,
         def fn(b, nd, g, h, prev):
             return H.fused_round(b, nd, nd, nd, g, h, prev, N, bins)[:2]
         rows.append(S((2, N, F, bins), jnp.float32))
-    # a compile for a described chip cannot be read back from the
-    # persistent cache: keep it out
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    try:
-        text = jax.jit(fn).lower(*rows).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
-        cc.reset_cache()
+    text = _compile_for_the_chip(fn, *rows)
     assert kernel in text and "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("n_nodes", [1, 8, 16])
+def test_mosaic_takes_the_largest_feature_block(n_nodes, one_chip,
+                                                monkeypatch):
+    """ISSUE 35: the block ``_pallas_ok`` gives at 256 bins is 392 rows
+    at every build of a depth-6 tree, and the chip's compiler takes a
+    kernel of that block at the real row tile (it takes 416 / 392 / 408
+    at these three builds and refuses 8 more)."""
+    monkeypatch.setattr(H, "pallas_interpret", lambda: False)
+    fb = H._pallas_ok(256, 2000, n_nodes)
+    assert fb == 392
+    n = 2 * H._TILE_ROWS
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = _compile_for_the_chip(
+        lambda b, nd, g, h: H.build_histogram(b, nd, g, h, n_nodes, 256,
+                                              "pallas", transposed=True),
+        S((fb, n), jnp.uint8), S((n,), jnp.int32), S((n,), jnp.float32),
+        S((n,), jnp.float32))
+    assert text.count("tpu_custom_call") >= 1 and "dmlc_hist" in text
+
+
+def test_mosaic_refuses_what_the_gate_used_to_admit(one_chip, monkeypatch):
+    """Why ``_pallas_ok`` counts the bins block twice: the gate as PR 34
+    left it admitted a root build of up to 728 feature rows, and the
+    compiler refuses one of 424 — the pipeline double-buffers the
+    ``[Fp, T]`` block.  The blocked build of the same matrix compiles."""
+    monkeypatch.setattr(H, "pallas_interpret", lambda: False)
+    n, F = 2 * H._TILE_ROWS, 424
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    shapes = (S((F, n), jnp.uint8), S((n,), jnp.int32),
+              S((n,), jnp.float32), S((n,), jnp.float32))
+    with pytest.raises(Exception, match="vmem"):
+        _compile_for_the_chip(
+            lambda b, nd, g, h: H._hist_pallas.__wrapped__(
+                b, nd, g, h, 1, 256, H._TILE_ROWS, 0, True), *shapes)
+    assert H.hist_feature_blocks(256, F, 1) == (392, 32)
+    text = _compile_for_the_chip(
+        lambda b, nd, g, h: H.build_histogram(b, nd, g, h, 1, 256,
+                                              "pallas", transposed=True),
+        *shapes)
+    assert text.count("tpu_custom_call") == 2

@@ -65,15 +65,16 @@ class TestHistogram:
                 assert lo * (-(-n_bins // lo)) >= n_bins
 
     def test_pallas_ok_vmem_guard(self):
-        # calibrated VMEM-stack guard: the default tile passes at every
-        # default level; tile 65536 (measured 16MB scoped-vmem OOM on
-        # v5e at 10M rows) must be rejected so build_histogram falls
-        # back to matmul instead of failing compilation
+        # calibrated VMEM-stack guard: the default tile takes all 28
+        # features in one block at every default level; at tile 65536
+        # (measured 16MB scoped-vmem OOM on v5e at 10M rows with all 28
+        # in one block) the budgets admit a block of 8, never the whole
+        # matrix, so nothing compiles what Mosaic refused
         from dmlc_core_tpu.ops.histogram import _TILE_ROWS, _pallas_ok
 
         for n_build in (1, 2, 4, 8, 16):
-            assert _pallas_ok(256, 28, n_build, 1, _TILE_ROWS)
-        assert not _pallas_ok(256, 28, 1, 1, 65536)
+            assert _pallas_ok(256, 28, n_build, 1, _TILE_ROWS) == 28
+        assert _pallas_ok(256, 28, 1, 1, 65536) == 8
         # int32 bins (>256 bin counts) scale the tile budget too
         assert _pallas_ok(512, 28, 1, 4, _TILE_ROWS)
 
