@@ -44,6 +44,12 @@ def gbt_metrics():
             "trees": r.counter("gbt_trees_total",
                                "trees fetched to host",
                                labels=("engine",)),
+            "forest_chunks": r.counter(
+                "gbt_forest_chunks_total",
+                "chunks of the device forest a predict or margin replay "
+                "asked for: hit (kept on the model from an earlier call) "
+                "or built (stacked, padded and put)",
+                labels=("engine", "result")),
             "phase": r.histogram(
                 "gbt_phase_seconds",
                 "per-phase wall time: bin (host wall of the staging "

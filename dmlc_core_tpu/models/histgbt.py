@@ -30,6 +30,7 @@ is ~1 full MXU row-pass instead of 6.
 
 from __future__ import annotations
 
+import operator
 import os
 from collections import deque
 from functools import lru_cache, partial
@@ -517,6 +518,8 @@ class HistGBT(_ExternalMemoryEngine):
         #: lifetime (cuts/trees are mode-specific) and persisted.
         self._missing: bool = False
         self.trees: List[Dict[str, np.ndarray]] = []   # per-tree arrays
+        #: device forest kept by :meth:`_stacked_trees`, chunk by chunk
+        self._forest_chunks: Tuple["_ForestChunk", ...] = ()
         self._round_fn = None
         self.last_fit_seconds: Optional[float] = None
         #: per-chunk timing evidence (bench.py auditability): _boost_binned
@@ -2797,8 +2800,8 @@ class HistGBT(_ExternalMemoryEngine):
         K = self.param.num_class
         return (n, K) if K > 1 else (n,)
 
-    @staticmethod
-    def _stacked_trees(trees: List[Dict[str, np.ndarray]]
+    def _stacked_trees(self, trees: List[Dict[str, np.ndarray]],
+                       engine: str = "incore"
                        ) -> List[Dict[str, jax.Array]]:
         """Device forest as fixed-shape chunks of ``_TREE_CHUNK`` trees
         (last chunk zero-padded at host level).
@@ -2810,25 +2813,45 @@ class HistGBT(_ExternalMemoryEngine):
         same stall shape as the PR 18 warmup miss).  A padded tree is
         all zeros, so its ``leaf[node]`` contribution is exactly 0.0 —
         margins are unchanged while every forest size ≤ the chunk
-        multiple shares one compiled program per batch shape."""
+        multiple shares one compiled program per batch shape.
+
+        A chunk's device arrays stay on the model and come back when
+        the same chunk is asked for again: same position, built from
+        the SAME host arrays (:meth:`_ForestChunk.holds`) —
+        ``self.trees`` is appended to, cut and replaced from outside
+        the class, so nothing weaker would hold.  A growing forest
+        rebuilds its partial last chunk only; a prefix (``n_trees=``,
+        the early-stop winner) shares the full chunks.  The model keeps
+        the chunks of the forest last asked for, and one partial chunk
+        more so that a prefix and the whole forest can take turns.
+        Concurrent callers each build what they miss and publish with
+        one assignment.  ``engine`` labels the counter."""
         keys = ("feat", "thr", "leaf") + (
             ("dir",) if "dir" in trees[0] else ())
-        chunks: List[Dict[str, jax.Array]] = []
+        kept = self._forest_chunks
+        chunks: List[_ForestChunk] = []
         with span("dmlc.predict.stack", trees=len(trees)) as sp:
-            for lo in range(0, len(trees), _TREE_CHUNK):
+            for index, lo in enumerate(range(0, len(trees), _TREE_CHUNK)):
                 part = trees[lo:lo + _TREE_CHUNK]
-                stacked = {k: np.stack([t[k] for t in part]) for k in keys}
-                pad = _TREE_CHUNK - len(part)
-                if pad:
-                    stacked = {
-                        k: np.concatenate(
-                            [v, np.zeros((pad,) + v.shape[1:], v.dtype)])
-                        for k, v in stacked.items()
-                    }
-                chunks.append({k: jnp.asarray(v)
-                               for k, v in stacked.items()})
-            sp.set(bytes=sum(v.nbytes for c in chunks for v in c.values()))
-        return chunks
+                hosts = [t[k] for t in part for k in keys]
+                chunks.append(
+                    next((c for c in kept if c.holds(index, hosts)), None)
+                    or _ForestChunk(index, hosts, _put_chunk(part, keys)))
+            built = [c for c in chunks if c not in kept]
+            n_hit = len(chunks) - len(built)
+            sp.set(bytes=sum(v.nbytes for c in built
+                             for v in c.arrays.values()),
+                   chunks_hit=n_hit, chunks_built=len(built))
+        spare = next((c for c in kept
+                      if c.n_trees < _TREE_CHUNK and c not in chunks), None)
+        keep = tuple(chunks) + (() if spare is None else (spare,))
+        if keep != kept:
+            self._forest_chunks = keep
+        if _metrics.enabled():
+            count = gbt_metrics()["forest_chunks"]
+            count.inc(n_hit, engine=engine, result="hit")
+            count.inc(len(built), engine=engine, result="built")
+        return [c.arrays for c in chunks]
 
     def _apply_trees(self, bins, stacked, init):
         """Add the chunked forest's margins onto ``init`` ([n] or
@@ -2862,6 +2885,11 @@ class HistGBT(_ExternalMemoryEngine):
     # persistence & introspection
     # ------------------------------------------------------------------
     _MODEL_MAGIC = b"DCTGBT01"
+
+    def __getstate__(self) -> Dict[str, Any]:
+        """``pickle`` and ``copy`` carry the model and never the device
+        forest: :meth:`_stacked_trees` builds it again from ``trees``."""
+        return {**self.__dict__, "_forest_chunks": ()}
 
     def save_model(self, uri: str) -> None:
         """Serialize params + bin cuts + trees to any Stream URI
@@ -3042,6 +3070,42 @@ class HistGBT(_ExternalMemoryEngine):
 #: pads forests to a multiple of this) — the program's shape must not
 #: track ensemble size, or every online refresh recompiles it
 _TREE_CHUNK = 64
+
+
+class _ForestChunk:
+    """One chunk of ``_stacked_trees`` as the model keeps it: the device
+    arrays, and the host arrays they were built from.  The references
+    are held, so the identity of a host array cannot come back as
+    another array's while the chunk lives.  Compared by identity."""
+
+    __slots__ = ("index", "n_trees", "hosts", "arrays")
+
+    def __init__(self, index: int, hosts: List[np.ndarray],
+                 arrays: Dict[str, jax.Array]) -> None:
+        self.index = index
+        self.n_trees = len(hosts) // len(arrays)
+        self.hosts = hosts
+        self.arrays = arrays
+
+    def holds(self, index: int, hosts: List[np.ndarray]) -> bool:
+        """True if this is chunk ``index`` of a forest whose tables there
+        are ``hosts``, array for array the same objects."""
+        return (index == self.index and len(hosts) == len(self.hosts)
+                and all(map(operator.is_, hosts, self.hosts)))
+
+
+def _put_chunk(part: List[Dict[str, np.ndarray]], keys: Tuple[str, ...]
+               ) -> Dict[str, jax.Array]:
+    """Stack the tables of at most ``_TREE_CHUNK`` trees, zero-pad to
+    the chunk and put each on the device."""
+    pad = _TREE_CHUNK - len(part)
+    arrays = {}
+    for k in keys:
+        v = np.stack([t[k] for t in part])
+        if pad:
+            v = np.concatenate([v, np.zeros((pad,) + v.shape[1:], v.dtype)])
+        arrays[k] = jnp.asarray(v)
+    return arrays
 
 
 #: row-trees one block of the descent walks at once: each of a block's

@@ -399,7 +399,8 @@ class _ExternalMemoryEngine:
 
             bins_rm = _transpose_from_feature_major_fn(self.mesh)(bins_t)
             preds = self._apply_trees(bins_rm,
-                                      self._stacked_trees(self.trees),
+                                      self._stacked_trees(
+                                          self.trees, engine="external"),
                                       preds)
             if preds.sharding != margin_sharding:
                 preds = jax.device_put(preds, margin_sharding)
@@ -536,7 +537,8 @@ class _ExternalMemoryEngine:
             # leaf values in the same order the incremental updates
             # applied them, so a resumed run carries bit-identical
             # margins into its first new round
-            stacked_prior = self._stacked_trees(self.trees)
+            stacked_prior = self._stacked_trees(self.trees,
+                                                engine="external")
             for c in range(n_chunks):
                 preds_d[c] = self._apply_trees(
                     jnp.asarray(chunk_bins(c)).T, stacked_prior,
