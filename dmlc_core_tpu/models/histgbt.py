@@ -57,6 +57,7 @@ from dmlc_core_tpu.ops.histogram import (build_histogram,
                                          fused_round, fused_round_ok,
                                          hist_feature_blocks,
                                          hist_feature_dots,
+                                         hist_node_blocks,
                                          hist_psum_bytes_per_round,
                                          pallas_interpret,
                                          resolve_hist_method,
@@ -207,6 +208,11 @@ class _RoundPlan(NamedTuple):
     #: other engines): what ``ops.build_histogram`` derives again from
     #: the same shapes when it traces — a record, it selects nothing
     hist_feature_blocks: Tuple[Tuple[int, ...], ...]
+    #: nodes of each node block of each Pallas build, likewise a record:
+    #: ``(n_build,)`` wherever one kernel call takes the build (every
+    #: level up to depth 7 at 256 bins); the feature blocks above are
+    #: those of a node block's calls
+    hist_node_blocks: Tuple[Tuple[int, ...], ...]
     fused_round: bool
     pallas_interpret: bool
     grow_policy: str
@@ -232,6 +238,7 @@ class _RoundPlan(NamedTuple):
             "hist_features": list(hist_feature_dots(self.n_features, lay)),
             "hist_feature_blocks": [list(b) for b in
                                     self.hist_feature_blocks],
+            "hist_node_blocks": [list(b) for b in self.hist_node_blocks],
             "hist_blocks": self.hist_blocks,
             "mesh_devices": self.mesh_devices,
         }
@@ -1884,11 +1891,19 @@ class HistGBT(_ExternalMemoryEngine):
         ``hist_method`` lists the histogram engine of each BUILD in
         tree order: depth-wise, level 0 builds the root and level ℓ the
         ``2^(ℓ-1)`` left children; loss-guide builds one node at a time.
+        ``hist_node_blocks`` / ``hist_feature_blocks`` record the blocks
+        a Pallas build runs in where one kernel call does not take it
+        (more than 32 nodes at 256 bins: ``max_depth`` >= 8; a matrix
+        wider than 392 rows), as ``ops.build_histogram`` derives them
+        again from the same shapes.
         ``fused_round`` says the levels below the root run
         :func:`~dmlc_core_tpu.ops.histogram.fused_round` (a Pallas
         kernel, so those levels read ``pallas``).  "auto" engages it on
-        a TPU backend at shapes inside the kernel's VMEM budget (the
-        deepest level binds); ``DMLC_FUSED_ROUND=1`` wherever it is
+        a TPU backend at shapes inside the kernel's VMEM budget, asked
+        ONCE for the deepest level's parents (the fused kernel is not
+        built in blocks: at ``max_depth`` >= 8 the whole round is the
+        staged one, which on the chip reads no slower per level —
+        PERF.md section 6, PR 37); ``DMLC_FUSED_ROUND=1`` wherever it is
         eligible at all (interpreted off-TPU — the byte-parity test
         hook).  The fused subtraction consumes ALREADY-synced parent
         histograms, so it needs the trivial one-chip sync: multi-chip
@@ -1923,15 +1938,22 @@ class HistGBT(_ExternalMemoryEngine):
             resolve_hist_method(p.hist_method, sync_bins, mat_rows, nb,
                                 whole=packed)
             for i, nb in enumerate(builds))
+        # the fused kernel takes its level whole; a packed layout is cut
+        # on nodes, never on features
+        node_blocks = tuple(
+            () if m != "pallas" else
+            (nb,) if fused and i > 0 else
+            hist_node_blocks(sync_bins, mat_rows, nb, whole=packed)
+            for i, (m, nb) in enumerate(zip(methods, builds)))
         plan = _RoundPlan(
             n_features=n_features,
             hist_method=methods,
-            # the fused kernel and a packed layout take the matrix whole
             hist_feature_blocks=tuple(
                 () if m != "pallas" else
                 (mat_rows,) if (fused and i > 0) or packed else
-                hist_feature_blocks(sync_bins, mat_rows, nb)
-                for i, (m, nb) in enumerate(zip(methods, builds))),
+                hist_feature_blocks(sync_bins, mat_rows, nbs[0])
+                for i, (m, nbs) in enumerate(zip(methods, node_blocks))),
+            hist_node_blocks=node_blocks,
             fused_round=fused,
             pallas_interpret=pallas_interpret(),
             grow_policy="lossguide" if lossguide else "depthwise",

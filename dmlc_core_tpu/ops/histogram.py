@@ -18,13 +18,19 @@ there.  XLA formulations, selected by ``method``:
   at any dense feature count: where the whole matrix is more than the
   kernel's VMEM budgets admit, the build runs in FEATURE BLOCKS
   (:func:`hist_feature_blocks`), the same kernel once per block of rows
-  of the feature-major matrix, joined on the feature axis.
+  of the feature-major matrix, joined on the feature axis; and at any
+  node count: where a build has more nodes than the budgets admit in
+  one call (more than 32 at 256 bins: the last level of a depth-8
+  tree), it runs in NODE BLOCKS (:func:`hist_node_blocks`), the same
+  kernel once per block of nodes over all rows, joined on the node
+  axis.
 * ``"auto"`` — :func:`resolve_hist_method`: on TPU ``pallas`` wherever
-  the kernel's VMEM budgets admit a feature block at all (every dense
-  shape of a depth-wise tree up to depth 7 at 256 bins), else
-  ``matmul``; ``segment`` on every other backend.  An EXPLICIT method
-  is never rewritten: asking for ``pallas`` at a shape the budgets
-  refuse raises.
+  the kernel's VMEM budgets admit a feature block of even ONE node
+  (every plain dense matrix, at any depth); ``matmul`` only for a
+  packed layout whose rows, which cannot be cut, do not fit;
+  ``segment`` on every other backend.  An EXPLICIT method is never
+  rewritten: asking for ``pallas`` at a shape the budgets refuse
+  raises.
 
 TPU layout note: the result is ``[2, n_nodes, F, n_bins]`` with the
 grad/hess plane LEADING.  A trailing axis of size 2 is catastrophic under
@@ -42,6 +48,7 @@ tree, SURVEY.md §5; reference: rabit's Allreduce over
 from __future__ import annotations
 
 from functools import partial
+from itertools import accumulate, pairwise
 
 import jax
 import jax.numpy as jnp
@@ -56,7 +63,8 @@ __all__ = ["build_histogram", "descend_histogram", "fused_round",
            "resolve_hist_method", "pallas_interpret",
            "reference_histogram", "hist_psum_bytes_per_round",
            "bins_bytes_per_round", "leaves_built_per_round",
-           "hist_feature_dots", "hist_feature_blocks"]
+           "hist_feature_dots", "hist_feature_blocks",
+           "hist_node_blocks"]
 
 
 def leaves_built_per_round(depth: int, grow_policy: str = "depthwise",
@@ -136,6 +144,9 @@ def bins_bytes_per_round(depth: int, rows: int, row_bytes: int, *,
     drops to ``depth`` passes (root build, ``depth − 2`` fused levels,
     final descend).  Loss-guide: one pass per expansion plus the
     root/final passes — ``2·leaves − 1`` unfused, ``leaves`` fused.
+    Not counted: a level built in k NODE blocks
+    (:func:`hist_node_blocks`; ``max_depth`` >= 8 at 256 bins) reads
+    the matrix k times for its histogram, not once.
     Feeds bench.py's ``kernel.bins_bytes_per_round`` field and the HBM
     roofline estimate.
     """
@@ -182,8 +193,12 @@ def _pallas_ok(n_bins: int, n_features: int, n_nodes: int = 1,
     ``n_features`` itself where the whole matrix fits the kernel's VMEM
     budgets (one call); else the largest multiple of 8 that fits, and the build runs block
     by block (:func:`hist_feature_blocks`); 0 where not even 8 rows fit
-    (``n_nodes·hi`` too tall: deep levels, huge bin counts) and the
-    kernel cannot serve the shape.  Truthy = the kernel serves it.
+    in ONE call of ``n_nodes`` (``n_nodes·hi`` too tall: deep levels,
+    huge bin counts).  Truthy = one kernel call serves ``n_nodes``.  A 0
+    at ``n_nodes`` > 1 does not refuse the build: it runs in NODE
+    BLOCKS (:func:`hist_node_blocks`), and this function answers for
+    each block's node count; the kernel cannot serve a shape only where
+    the answer is 0 at even ONE node (huge bin counts).
 
     The factored kernel works for any n_bins; the budgets, all linear
     in the block's padded rows ``Fp``, are (a) the [Fp, A, lo] f32
@@ -239,6 +254,55 @@ def hist_feature_blocks(n_bins: int, n_features: int, n_nodes: int = 1,
     return (fb,) * full + ((rest,) if rest else ())
 
 
+def _node_block(n_bins: int, n_rows: int, n_nodes: int = 1,
+                bins_itemsize: int = 1, whole: bool = False) -> int:
+    """The NODE BLOCK of a Pallas build at this shape: how many nodes
+    one kernel call takes.  ``n_nodes`` itself wherever :func:`_pallas_ok`
+    admits the build in one call (every level of a depth-wise tree up to
+    depth 7 at 256 bins); else the largest power of two below it that
+    the gate admits — from the gate's own budgets, from shapes alone;
+    0 where not even one node is admitted.  Admitted means a feature
+    block of at least 8 rows, or with ``whole`` (a nibble-packed layout,
+    which cannot be cut on features) all of ``n_rows``."""
+    def admits(n):
+        block = _pallas_ok(n_bins, n_rows, n, bins_itemsize)
+        return block >= n_rows if whole else block > 0
+
+    if admits(n_nodes):
+        return n_nodes
+    nb = 1 << ((n_nodes - 1).bit_length() - 1) if n_nodes > 1 else 0
+    while nb and not admits(nb):
+        nb >>= 1
+    return nb
+
+
+def hist_node_blocks(n_bins: int, n_rows: int, n_nodes: int = 1,
+                     bins_itemsize: int = 1,
+                     whole: bool = False) -> tuple[int, ...]:
+    """Nodes of each node block a Pallas build of this shape runs in, in
+    node order: ``(n_nodes,)`` where one kernel call takes the build,
+    else whole blocks of :func:`_node_block` nodes and the rest; ``()``
+    where the kernel cannot serve the shape.  What
+    :func:`build_histogram` traces and ``HistGBT.round_plan`` records
+    (``hist_node_blocks``).  The gate by nodes at 256 bins — feature
+    rows ONE call takes, ``_pallas_ok(256, F, n)``::
+
+        n_nodes    1    2    4    8   16   32   64  128  256
+        F = 28    28   28   28   28   28   28    0    0    0
+        F = 2000 392  392  392  392  392  200    0    0    0
+
+    so: one block up to 32 nodes, ``(32, 32)`` for the 64 left children
+    of a depth-8 tree's last level, ``(32,) * 8`` at depth 10.  Each
+    block is then built in the feature blocks of ITS node count
+    (:func:`hist_feature_blocks`): 2000 features at 64 nodes are two
+    node blocks of ten 200-row feature blocks."""
+    nb = _node_block(n_bins, n_rows, n_nodes, bins_itemsize, whole)
+    if not nb:
+        return ()
+    full, rest = divmod(n_nodes, nb)
+    return (nb,) * full + ((rest,) if rest else ())
+
+
 def pallas_interpret() -> bool:
     """Whether this module's ``pallas_call``s run in the Pallas
     INTERPRETER instead of being compiled by Mosaic: everywhere but on a
@@ -255,30 +319,32 @@ def resolve_hist_method(method: str, n_bins: int, n_rows: int,
     (``n_rows`` = rows of the feature-major matrix the kernel reads:
     features, or a layout's physical rows).  ``auto`` chooses from what
     it can observe — backend and the kernel's VMEM budgets: on a TPU
-    ``pallas`` wherever :func:`_pallas_ok` admits a feature block, which
-    for a plain matrix is every width (the build runs block by block);
-    ``whole`` says the matrix cannot be cut (a nibble-packed layout's
-    packed rows lead the block), so all of ``n_rows`` have to fit.  An
-    explicit method is returned as is, except that ``pallas`` at a shape
-    the budgets refuse is an error, never a quiet ``matmul``.
+    ``pallas`` wherever :func:`_node_block` admits a block of nodes,
+    which for a plain matrix is every width and every node count (the
+    build runs feature block by feature block, node block by node
+    block); ``whole`` says the matrix cannot be cut on features (a
+    nibble-packed layout's packed rows lead the block), so all of
+    ``n_rows`` have to fit at some node block.  An explicit method is
+    returned as is, except that ``pallas`` at a shape the budgets
+    refuse is an error, never a quiet ``matmul``.
     ``models.histgbt`` calls this per tree level up front, so the
     choice is on record (``HistGBT.round_plan``) before anything
     traces."""
-    block = _pallas_ok(n_bins, n_rows, n_nodes, bins_itemsize)
-    fits = block >= n_rows if whole else block > 0
+    fits = _node_block(n_bins, n_rows, n_nodes, bins_itemsize, whole) > 0
     if method == "auto":
         if jax.default_backend() != "tpu":
             return "segment"
         return "pallas" if fits else "matmul"
     if method == "pallas" and not fits:
+        block = _pallas_ok(n_bins, n_rows, 1, bins_itemsize)
         log_fatal(f"build_histogram: method='pallas' was requested but "
                   f"the kernel's VMEM budgets admit "
                   + (f"only {block} of the {n_rows} rows of a packed "
-                     f"layout, which cannot be built in feature blocks"
+                     f"layout, which cannot be built in feature blocks,"
                      if block else "no feature block, not even 8 rows,")
-                  + f" at n_bins={n_bins}, n_nodes={n_nodes}, "
-                  f"itemsize={bins_itemsize}, tile_rows={_TILE_ROWS} — "
-                  f"use 'auto' or 'matmul'")
+                  + f" for even one node at n_bins={n_bins} (asked: "
+                  f"n_nodes={n_nodes}), itemsize={bins_itemsize}, "
+                  f"tile_rows={_TILE_ROWS} — use 'auto' or 'matmul'")
     if method not in ("segment", "matmul", "pallas"):
         log_fatal(f"build_histogram: unknown method {method!r}")
     return method
@@ -322,12 +388,12 @@ def build_histogram(
         method = resolve_hist_method(method, n_bins, layout.phys_rows,
                                      n_nodes, 1, whole=bool(layout.pairs))
         if method == "pallas":
-            if layout.pairs:
-                return _hist_pallas(bins, node_id, grad, hess, n_nodes,
-                                    n_bins, transposed=True, layout=layout)
-            # bundle-only layout: physical == storage, plain kernel
-            return _hist_pallas_blocks(bins, node_id, grad, hess, n_nodes,
-                                       n_bins, transposed=True)
+            # a bundle-only layout is physical == storage: the plain
+            # kernel; packed rows lead the block, so it is never cut on
+            # features
+            return _hist_pallas_blocks(
+                bins, node_id, grad, hess, n_nodes, n_bins, transposed=True,
+                layout=layout if layout.pairs else None)
         storage = _bl.unpack_matrix(bins, layout)
         if method == "segment":
             return _hist_segment(storage.T, node_id, grad, hess,
@@ -348,16 +414,59 @@ def build_histogram(
 
 
 def _hist_pallas_blocks(bins, node_id, grad, hess, n_nodes, n_bins, *,
-                        transposed):
-    """:func:`_hist_pallas` over the feature blocks of
-    :func:`hist_feature_blocks`.  One block (every shape the whole-matrix
-    budgets admit) is the plain call and traces nothing else.  Several:
-    the same kernel once per contiguous slab of feature rows, the
-    histograms joined on the feature axis — a feature's sums depend on
-    no other feature, so each is made of the same operations in the same
-    order as in an unblocked build, bit for bit.  What blocking adds
-    outside the kernels (the slabs, the join) runs under the device
+                        transposed, layout=None):
+    """:func:`_hist_pallas` over the node blocks of
+    :func:`hist_node_blocks` and, inside each, the feature blocks of
+    :func:`hist_feature_blocks`.  One block of each (every shape the
+    one-call budgets admit) is the plain call and traces nothing else.
+
+    Several NODE blocks: the same kernel once per block over ALL rows,
+    the node ids of the block mapped to ``0..nb-1`` and every other row
+    to ``-1`` (the kernel ignores ``node < 0``), the histograms joined
+    on the node axis.  A node's sums depend on no other node, and a
+    row's place in its tile and the tiles' order do not change, so each
+    node's sums are made of the same operations in the same order as in
+    an unblocked build, bit for bit — also where a block factors the
+    bins with another ``lo`` than the whole build would (the rest block
+    of a node count that is no power of two; at 256 bins every build of
+    8 to 256 nodes factors alike): ``lo`` decides which dot a cell
+    sits in, not what is added into it
+    (``tests/test_hist_node_blocks.py`` holds both).  What node blocking
+    adds outside the kernels (the per-block node maps, the join) runs
+    under the device scope ``dmlc.hist.nblock``.  ``layout`` is a
+    nibble-packed layout: cut on nodes like any other build, never on
+    features."""
+    rows = bins.shape[0] if transposed else bins.shape[1]
+    blocks = hist_node_blocks(n_bins, rows, n_nodes,
+                              jnp.dtype(bins.dtype).itemsize,
+                              whole=layout is not None)
+    if len(blocks) == 1:
+        return _hist_pallas_fblocks(bins, node_id, grad, hess, n_nodes,
+                                    n_bins, transposed=transposed,
+                                    layout=layout)
+    in_nblock = jax.named_scope("dmlc.hist.nblock")
+    own = in_nblock(lambda lo, hi: jnp.where(
+        (node_id >= lo) & (node_id < hi), node_id - lo, -1))
+    return in_nblock(jnp.concatenate)(
+        [_hist_pallas_fblocks(bins, own(lo, hi), grad, hess, hi - lo,
+                              n_bins, transposed=transposed, layout=layout)
+         for lo, hi in pairwise(accumulate(blocks, initial=0))], axis=1)
+
+
+def _hist_pallas_fblocks(bins, node_id, grad, hess, n_nodes, n_bins, *,
+                         transposed, layout=None):
+    """One node block of :func:`_hist_pallas_blocks` over its feature
+    blocks.  One block (every shape the whole-matrix budgets admit, and
+    every packed ``layout``) is the plain call and traces nothing else.
+    Several: the same kernel once per contiguous slab of feature rows,
+    the histograms joined on the feature axis — a feature's sums depend
+    on no other feature, so each is made of the same operations in the
+    same order as in an unblocked build, bit for bit.  What blocking
+    adds outside the kernels (the slabs, the join) runs under the device
     scope ``dmlc.hist.fblock``."""
+    if layout is not None:
+        return _hist_pallas(bins, node_id, grad, hess, n_nodes, n_bins,
+                            transposed=True, layout=layout)
     F = bins.shape[0] if transposed else bins.shape[1]
     blocks = hist_feature_blocks(n_bins, F, n_nodes,
                                  jnp.dtype(bins.dtype).itemsize)
@@ -367,11 +476,10 @@ def _hist_pallas_blocks(bins, node_id, grad, hess, n_nodes, n_bins, *,
     in_fblock = jax.named_scope("dmlc.hist.fblock")
     slab = in_fblock(lambda lo, hi: (bins[lo:hi] if transposed
                                      else bins[:, lo:hi]))
-    edges = [sum(blocks[:k]) for k in range(len(blocks) + 1)]
     return in_fblock(jnp.concatenate)(
         [_hist_pallas(slab(lo, hi), node_id, grad, hess, n_nodes, n_bins,
                       transposed=transposed)
-         for lo, hi in zip(edges[:-1], edges[1:])], axis=2)
+         for lo, hi in pairwise(accumulate(blocks, initial=0))], axis=2)
 
 
 @partial(jax.jit, static_argnums=(4, 5))

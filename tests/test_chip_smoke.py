@@ -88,25 +88,40 @@ def test_failed_check_fails_the_run():
     assert len(sm.failures) == 1 and "ZeroDivisionError" in sm.failures[0]
 
 
-def test_explicit_pallas_on_ineligible_shape_raises():
-    # the VMEM gate rejects this accumulator; "auto" may pick another
-    # engine there, an explicit request must not be rewritten
-    n_bins, F, n_nodes = 256, 512, 64
-    assert not _pallas_ok(n_bins, F, n_nodes)
+def test_explicit_pallas_on_ineligible_shape_raises(monkeypatch):
+    # the VMEM gate rejects this accumulator at even ONE node; "auto" may
+    # pick another engine there, an explicit request must not be
+    # rewritten.  (A build of many nodes is no longer such a shape: it
+    # runs in node blocks, ISSUE 37.)
+    n_bins, F, n_nodes = 1 << 17, 512, 4
+    assert not _pallas_ok(n_bins, F, 1)
     with pytest.raises(Error, match="method='pallas' was requested"):
         resolve_hist_method("pallas", n_bins, F, n_nodes)
     n = 64
     with pytest.raises(Error, match="method='pallas' was requested"):
-        build_histogram(jnp.zeros((F, n), jnp.uint8),
+        build_histogram(jnp.zeros((F, n), jnp.int32),
                         jnp.zeros(n, jnp.int32), jnp.ones(n, jnp.float32),
                         jnp.ones(n, jnp.float32), n_nodes, n_bins, "pallas",
                         transposed=True)
     assert resolve_hist_method("auto", n_bins, F, n_nodes) == "segment"
     # and the model says so before anything traces or compiles
-    m = HistGBT(n_trees=1, max_depth=8, n_bins=256, hist_method="pallas",
+    # (the model's n_bins stops at 256: a scoped-VMEM figure that holds
+    # no bins block at all refuses every build there)
+    from dmlc_core_tpu.ops import histogram as H
+    m = HistGBT(n_trees=1, max_depth=3, n_bins=256, hist_method="pallas",
                 mesh=local_mesh(1))
-    with pytest.raises(Error, match="method='pallas' was requested"):
-        m._round_plan(512)
+    with monkeypatch.context() as mp:
+        mp.setattr(H, "_SCOPED_VMEM", H._TILE_ROWS * H._SCOPED_ROW_RESERVE)
+        with pytest.raises(Error, match="method='pallas' was requested"):
+            m._round_plan(512)
+    # 64 nodes at 256 bins, once refused: one call does not take them,
+    # two node blocks do
+    assert not _pallas_ok(256, 512, 64)
+    assert resolve_hist_method("pallas", 256, 512, 64) == "pallas"
+    deep = HistGBT(n_trees=1, max_depth=8, n_bins=256, hist_method="pallas",
+                   mesh=local_mesh(1))
+    assert deep._round_plan(512).hist_method == ("pallas",) * 8
+    assert deep.round_plan["hist_node_blocks"][-1] == [32, 32]
     assert np.all([v == "pallas" for v in HistGBT(
         n_trees=1, max_depth=6, n_bins=256, hist_method="pallas",
         mesh=local_mesh(1))._round_plan(28).hist_method])

@@ -177,9 +177,12 @@ def test_the_shipped_shapes():
         assert H.hist_feature_blocks(256, 2000, n_build) == (392,) * 5 + (40,)
         assert H.resolve_hist_method("pallas", 256, 2000, n_build) == "pallas"
     assert H.hist_feature_blocks(256, 392, 16) == (392,)
-    # past depth 7 the kernel's one-hots are too tall for any block
+    # past depth 7 the kernel's one-hots are too tall for any feature
+    # block of ONE call; the build runs in node blocks (ISSUE 37,
+    # test_hist_node_blocks.py), each in the feature blocks of ITS nodes
     assert H.hist_feature_blocks(256, 28, 64) == ()
     assert H._pallas_ok(256, 28, 64) == 0
+    assert H.hist_node_blocks(256, 28, 64) == (32, 32)
 
 
 @pytest.mark.parametrize("F", [5, 28, 32, 392])

@@ -169,8 +169,8 @@ def test_round_plan_record_is_the_plans_json_view():
     assert json.loads(json.dumps(m.round_plan)) == m.round_plan
     assert set(m.round_plan) == {
         "hist_method", "fused_round", "pallas_interpret", "grow_policy",
-        "bin_layout", "hist_features", "hist_feature_blocks", "hist_blocks",
-        "mesh_devices"}
+        "bin_layout", "hist_features", "hist_feature_blocks",
+        "hist_node_blocks", "hist_blocks", "mesh_devices"}
     # a record of what the Pallas kernels issue per row tile (dots
     # emitted, dots of the padded block): derived, never a field
     assert m.round_plan["hist_features"] == [F, 8]
@@ -178,6 +178,7 @@ def test_round_plan_record_is_the_plans_json_view():
     assert m.round_plan["hist_method"] == ["segment"] * KW["max_depth"]
     # feature blocks are the Pallas builds': none for another engine
     assert m.round_plan["hist_feature_blocks"] == [[]] * KW["max_depth"]
+    assert m.round_plan["hist_node_blocks"] == [[]] * KW["max_depth"]
     assert hash(plan) == hash(m._round_plan(F))
 
 
