@@ -57,6 +57,52 @@ __all__ = ["local_summary", "merge_summaries", "compute_cuts", "apply_bins",
            "apply_bins_t", "apply_bins_missing", "SketchAccumulator"]
 
 
+def _key_sort_quantiles(x: jax.Array, qs: jax.Array) -> jax.Array:
+    """``jnp.quantile(x, qs, axis=0)`` (linear) → ``[len(qs), F]``, with
+    a sort of the KEYS ALONE in the middle.
+
+    ``jnp.quantile`` sorts with ``lax.sort``'s default ``is_stable=True``;
+    the TPU compiler keeps a one-operand sort stable by sorting a second
+    operand beside it, an ``s32[n, F]`` row index from an ``iota`` that
+    nothing reads afterwards (at 24M x 28 one more 2.86 GiB matrix of
+    temporaries and half the sort's time, PERF.md section 6, PR 38).
+    For keys alone stability has no meaning: the float comparator is a
+    total order (NaN and -0.0 canonicalised, then the bit patterns as
+    integers), so keys that compare equal ARE the same float32 and the
+    sorted column is the same column whichever way ties fall.  The
+    exceptions are the zeros: -0.0 and +0.0 compare equal, and so do the
+    denormals, which XLA compares as zero on the CPU and the TPU alike.
+    They may swap places; the interpolation flushes a denormal to zero,
+    so at most a zero's sign moves in the summary, and
+    :func:`merge_summaries`' guard adds +0.0 to every cut: the cuts are
+    the same bits.
+
+    Everything around the sort is ``_quantile``'s, operation for
+    operation and in its order — the all-NaN guard, ``q * (n - 1)`` in
+    float32, floor / ceil, the clamp, two row gathers of ``[len(qs),
+    F]``, ``low * (1 - w) + high * w`` — so the result equals
+    ``jnp.quantile``'s to the bit (``tests/test_models.py::TestQuantile``).
+    """
+    n = x.shape[0]
+    x = jnp.where(jnp.any(jnp.isnan(x), axis=0, keepdims=True), jnp.nan, x)
+    xs = jax.lax.sort(x, dimension=0, is_stable=False)
+    last = jax.lax.convert_element_type(n, qs.dtype) - 1
+    q = qs * last
+    low, high = jax.lax.floor(q), jax.lax.ceil(q)
+    high_weight = q - low
+    low_weight = 1 - high_weight
+    dnums = jax.lax.GatherDimensionNumbers(
+        offset_dims=(1,), collapsed_slice_dims=(0,), start_index_map=(0,))
+
+    def rows(at):                                          # [len(qs), F]
+        at = jax.lax.clamp(0.0, at, last).astype(jnp.int32)
+        return jax.lax.gather(xs, at[:, None], dnums, (1, x.shape[1]),
+                              mode="promise_in_bounds")
+
+    return (rows(low) * low_weight[:, None]
+            + rows(high) * high_weight[:, None])
+
+
 @partial(jax.jit, static_argnums=(2, 3))
 @jax.named_scope("dmlc.cuts")
 def local_summary(x: jax.Array, weight: Optional[jax.Array],
@@ -65,6 +111,15 @@ def local_summary(x: jax.Array, weight: Optional[jax.Array],
 
     ``x``: [n, F] f32; ``weight``: [n] or None.  Returns [F, n_summary]
     (per-feature weighted quantiles on an even probability grid).
+
+    Without weights and without ``missing`` — the path of every dense
+    fit, a whole-matrix sort — the summary is ``jnp.quantile(x, qs,
+    axis=0).T`` to the bit, but its sort is of the keys ALONE
+    (``is_stable=False``, :func:`_key_sort_quantiles`): a stable sort
+    makes the TPU compiler carry an ``s32[n, F]`` row index through
+    every pass, and no value of the sorted column depends on how ties
+    fall.  The weighted and the ``missing`` paths need their
+    permutation (``argsort``) and keep it.
 
     ``missing=True``: NaN entries are excluded from the summary by
     rewriting them to the feature's max finite value with weight 0 —
@@ -88,7 +143,7 @@ def local_summary(x: jax.Array, weight: Optional[jax.Array],
         fmax = jnp.max(jnp.where(nan, -jnp.inf, x), axis=0)    # [F]
         x = jnp.where(nan, fmax[None, :], x)
     elif weight is None:
-        return jnp.quantile(x, qs, axis=0).T  # [F, n_summary]
+        return _key_sort_quantiles(x, qs).T   # [F, n_summary]
     else:
         w2d = jnp.broadcast_to(weight[:, None], x.shape)
     order = jnp.argsort(x, axis=0)                                    # [n, F]

@@ -211,20 +211,24 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile_for_the_chip(fn, *shapes):
-    """XLA:TPU + Mosaic on ``fn`` at ``shapes``; the compiled text.  A
-    compile for a described chip cannot be read back from the persistent
-    cache: it is kept out."""
+def _compiled_for_the_chip(fn, *shapes):
+    """XLA:TPU + Mosaic on ``fn`` at ``shapes``; the compiled program.
+    A compile for a described chip cannot be read back from the
+    persistent cache: it is kept out."""
     from jax.experimental.compilation_cache import compilation_cache as cc
 
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
     try:
-        return jax.jit(fn).lower(*shapes).compile().as_text()
+        return jax.jit(fn).lower(*shapes).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", was)
         cc.reset_cache()
+
+
+def _compile_for_the_chip(fn, *shapes):
+    return _compiled_for_the_chip(fn, *shapes).as_text()
 
 
 @pytest.mark.parametrize("kernel", ["dmlc_hist", "dmlc_fused_round"])
@@ -300,3 +304,31 @@ def test_mosaic_refuses_what_the_gate_used_to_admit(one_chip, monkeypatch):
                                               "pallas", transposed=True),
         *shapes)
     assert text.count("tpu_custom_call") == 2
+
+
+def test_the_cut_sort_carries_no_row_index(one_chip):
+    """ISSUE 38: asked for a STABLE sort of one operand, the v5e compiler
+    sorts a second one beside it, an ``s32[n, F]`` row index from an
+    ``iota`` (a third of the sort's bytes, one more matrix of
+    temporaries).  ``local_summary`` sorts the keys alone: one ``sort(``
+    of ONE operand, no such iota, and the sorted copy the only matrix
+    among the temporaries (the stable sort holds two)."""
+    from dmlc_core_tpu.ops.quantile import local_summary
+
+    n, F = 1 << 20, 28
+    compiled = _compiled_for_the_chip(
+        lambda x: local_summary(x, None, 2048),
+        jax.ShapeDtypeStruct((n, F), jnp.float32, sharding=one_chip))
+    lines = compiled.as_text().splitlines()
+    sorts = [line for line in lines if " sort(" in line]
+    assert len(sorts) == 1, sorts
+    result, operands = sorts[0].split(" sort(", 1)
+    assert result.split("= ", 1)[1].startswith(f"f32[{n},{F}]"), sorts[0]
+    assert "," not in operands.split(")", 1)[0], sorts[0]
+    assert "is_stable=true" not in sorts[0]
+    assert "dmlc.cuts" in sorts[0]
+    assert not [line for line in lines
+                if " iota(" in line and f"s32[{n},{F}]" in line]
+    # rows on the lanes, the 28 features padded to 32 sublanes
+    matrix = n * 32 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.25 * matrix

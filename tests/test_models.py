@@ -4,9 +4,12 @@ Oracles: numpy reference histogram; monotone loss decrease; near-perfect
 fit on separable synthetic data; sharded-vs-single-device equivalence
 (the histogram psum correctness check — BASELINE config 1's semantics)."""
 
+from functools import partial
+
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from dmlc_core_tpu.models import HistGBT
@@ -156,6 +159,39 @@ def _edge_cuts(rng, F, n_cuts):
     return c
 
 
+def _tied_matrix(rng, n, F):
+    """[n, F] float32 on which a sort has ties to break: long runs of
+    duplicates, both zeros, denormals (XLA compares them as zero, so
+    they tie with the zeros), ±inf; the last column partly NaN and the
+    one before it all NaN."""
+    specials = np.float32([0.0, -0.0, 1e-45, -1e-45, 1e-40, -1e-40,
+                           np.inf, -np.inf, 0.25, 0.25, 0.25, -1.5])
+    x = rng.normal(size=(n, F)).astype(np.float32)
+    for f in range(F):
+        kind = f % 4
+        if kind == 0:                       # mostly ties, some draws
+            runs = rng.random(n) < 0.8
+            x[runs, f] = specials[rng.integers(0, len(specials), runs.sum())]
+        elif kind == 1:                     # a few values, long runs
+            x[:, f] = np.round(x[:, f] * 2) / 2
+        elif kind == 2:                     # one atom and the zeros
+            x[:, f] = np.where(rng.random(n) < 0.6, np.float32(-0.0),
+                               np.where(rng.random(n) < 0.5, 0.0, x[:, f]))
+    if F > 1:
+        x[::3, F - 1] = np.nan
+    if F > 2:
+        x[:, F - 2] = np.nan
+    return x
+
+
+@partial(jax.jit, static_argnums=(1,))
+def _stable_summary(x, n_summary):
+    """``local_summary``'s unweighted path as it stood before ISSUE 38,
+    word for word: ``jnp.quantile`` sorts with ``is_stable=True``."""
+    qs = jnp.linspace(0.0, 1.0, n_summary)
+    return jnp.quantile(x, qs, axis=0).T
+
+
 class TestQuantile:
     @pytest.mark.parametrize("n", [1, 127, 4096])
     @pytest.mark.parametrize("F", [1, 28, 33])
@@ -197,6 +233,37 @@ class TestQuantile:
         np.testing.assert_array_equal(np.asarray(got_t), got.T)
         np.testing.assert_array_equal(np.where(denormal, want, got), want)
         assert ((got == want) | (got == as_zero))[denormal].all()
+
+    @pytest.mark.parametrize("n_summary", [64, 2048])
+    @pytest.mark.parametrize("F", [1, 28, 33])
+    @pytest.mark.parametrize("n", [1, 2, 127, 4096, 100003])
+    def test_key_only_summary_equals_jnp_quantile(self, n, F, n_summary):
+        # ISSUE 38: the sort of the keys alone (is_stable=False) changes
+        # no value of the summary, on ties of every kind
+        x = jnp.asarray(_tied_matrix(np.random.default_rng([n, F]), n, F))
+        got = np.asarray(local_summary(x, None, n_summary))
+        want = np.asarray(_stable_summary(x, n_summary))
+        assert got.shape == want.shape == (F, n_summary)
+        assert got.dtype == want.dtype == np.float32
+        assert np.array_equal(got, want, equal_nan=True)
+        if F > 1:
+            assert np.isnan(got[F - 1]).all()        # the NaN guard's column
+            assert F == 2 or np.isnan(got[F - 2]).all()
+
+    @pytest.mark.parametrize("F", [1, 28, 33])
+    @pytest.mark.parametrize("n", [1, 2, 127, 4096, 100003])
+    def test_cuts_and_bins_equal_the_stable_sorts(self, n, F):
+        # ... hence the cuts and the bins: bit for bit (merge_summaries'
+        # guard adds a +0.0 to every cut, so not even a zero's sign moves)
+        x = _tied_matrix(np.random.default_rng([F, n]), n, F)
+        cuts = np.asarray(compute_cuts(x, n_bins=256))
+        want = np.asarray(merge_summaries(
+            _stable_summary(jnp.asarray(x), 2048)[None], 256))
+        assert cuts.shape == (F, 255)
+        assert np.array_equal(cuts.view(np.uint32), want.view(np.uint32))
+        np.testing.assert_array_equal(
+            np.asarray(apply_bins_t(jnp.asarray(x), jnp.asarray(cuts))),
+            np.asarray(apply_bins_t(jnp.asarray(x), jnp.asarray(want))))
 
     def test_cuts_monotone_and_binning_balanced(self, rng):
         x = rng.normal(size=(10000, 3)).astype(np.float32)

@@ -226,6 +226,23 @@ def test_compiled_programs_carry_their_scopes(program):
         assert any(scope in name.split("/") for name in op_names), scope
 
 
+def test_the_cut_sort_is_of_the_keys_alone():
+    """ISSUE 38: ``local_summary`` asks for an UNSTABLE sort of one
+    operand (a stable one makes the TPU compiler sort a row index beside
+    the keys), and the sort still sits under ``dmlc.cuts``, where
+    ``ingest.cuts_device_s`` reads it."""
+    lowered = local_summary.lower(jnp.zeros((64, 4)), None, 16, False)
+    sorts = [line for line in lowered.as_text().splitlines()
+             if "stablehlo.sort" in line]
+    assert len(sorts) == 1 and "is_stable = false" in sorts[0], sorts
+    assert sorts[0].split("stablehlo.sort", 1)[1].count("%") == 1, sorts[0]
+    compiled = [line for line in lowered.compile().as_text().splitlines()
+                if " sort(" in line]
+    assert len(compiled) == 1, compiled
+    op_name = compiled[0].split('op_name="', 1)[1].split('"', 1)[0]
+    assert "dmlc.cuts" in op_name.split("/"), compiled[0]
+
+
 # a 20,000 x 28 slab against 255 and 1023 cuts, the widths a fit bins at
 _BIN_ARGS = {n_cuts: (jax.ShapeDtypeStruct((20_000, 28), jnp.float32),
                       jax.ShapeDtypeStruct((28, n_cuts), jnp.float32))
