@@ -1218,7 +1218,7 @@ class HistGBT(_ExternalMemoryEngine):
 
         def bin_oldest():
             slab = inflight.popleft()
-            with span("dmlc.ingest.put_wait"):
+            with span("dmlc.ingest.put_wait", bytes=slab.nbytes):
                 slab.block_until_ready()
             with span("dmlc.ingest.bin_dispatch"):
                 pieces.append(fn(slab, self.cuts))
@@ -1546,14 +1546,13 @@ class HistGBT(_ExternalMemoryEngine):
         is ENQUEUED; its arrays are ready when the device has drained
         them (``jax.block_until_ready`` on them to wait).
         """
-        t_bin = get_time()
         with span("dmlc.ingest", rows=len(y)) as sp:
             out = self._stage_device_data(X, y, weight, cuts, sp)
         # host wall of the staging calls up to their last enqueue (cuts,
         # puts and the waits that pace them, binning dispatches) — NOT
         # the completion of the device work they queue, which the caller
         # waits for on the handle
-        self.last_bin_seconds = get_time() - t_bin
+        self.last_bin_seconds = sp.seconds
         if _metrics.enabled():
             gbt_metrics()["phase"].observe(self.last_bin_seconds,
                                            engine="incore", phase="bin")
@@ -2691,7 +2690,14 @@ class HistGBT(_ExternalMemoryEngine):
                 out_d = (margin if output_margin
                          else self._obj.transform(margin))
             with span("dmlc.predict.fetch", bytes=out_d.nbytes):
-                outs.append(np.asarray(out_d))
+                # the copy is asked for BEHIND the programs, as
+                # np.asarray alone would ask: a copy started only once
+                # the wait is over costs a call 0.2 ms more (PERF.md §6)
+                out_d.copy_to_host_async()
+                with span("dmlc.predict.fetch.wait"):
+                    out_d.block_until_ready()
+                with span("dmlc.predict.fetch.copy", bytes=out_d.nbytes):
+                    outs.append(np.asarray(out_d))
             if _metrics.enabled():
                 # np.asarray above is a real fetch, so this wall delta
                 # covers bin + tree apply + D2H for the batch

@@ -10,11 +10,14 @@ module provides
   captures an XLA/TensorBoard profile (HLO timelines, TPU utilization)
   into a logdir;
 * :class:`span` — THE way to mark a host phase of the program: one call
-  site, two sinks.  It always enters a ``jax.profiler.TraceAnnotation``
+  site, three sinks.  It always enters a ``jax.profiler.TraceAnnotation``
   (so the phase sits in the profiler's own trace, on the device trace's
-  clock, next to the XLA ops — free when no trace is being taken) and,
-  only while host tracing is on, records the same name and counts into
-  :func:`global_tracer`.  Device phases are marked where they are
+  clock, next to the XLA ops — free when no trace is being taken);
+  while the metrics layer is on (the default) it folds its wall into
+  the record of its operation (:func:`op_log`: one bounded record an
+  operation, there when no trace is — set-up, every untraced run); and,
+  only while host tracing is on, it records the same name and counts
+  into :func:`global_tracer`.  Device phases are marked where they are
   traced, with ``jax.named_scope`` (see ``doc/observability.md``);
 * :class:`Tracer` — a dependency-free host-side event tracer writing
   Chrome ``chrome://tracing`` / Perfetto JSON, so host pipeline phases
@@ -36,18 +39,21 @@ to dropped events, never to unbounded host memory.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import itertools
 import json
 import os
 import threading
 import time
-from typing import Any, Dict, Iterator, List, Optional
+from typing import Any, Deque, Dict, Iterator, List, Optional
 
+from dmlc_core_tpu.base import metrics as _metrics
 from dmlc_core_tpu.base.timer import get_time
 
-__all__ = ["device_trace", "span", "current_op", "Tracer",
-           "global_tracer", "tracing_enabled", "set_tracing"]
+__all__ = ["device_trace", "span", "current_op", "op_log",
+           "op_log_dropped", "Tracer", "global_tracer", "tracing_enabled",
+           "set_tracing"]
 
 _TRACING = os.environ.get("DMLC_TRACE", "0").lower() in ("1", "true", "on",
                                                          "yes")
@@ -94,7 +100,7 @@ def device_trace(logdir: str) -> Iterator[None]:
 #: one process-wide counter names operations: every top-level span takes
 #: the next value as ``op`` and its children inherit it
 _op_ids = itertools.count(1)
-#: per-thread stack of the ``op`` of the spans open on that thread
+#: per-thread stack of the spans open on that thread
 _open = threading.local()
 
 
@@ -102,8 +108,81 @@ def current_op() -> Optional[int]:
     """``op`` of the innermost :class:`span` open on this thread (None
     outside any) — what a caller hands to a worker thread so the spans
     the worker opens join the same operation."""
-    stack = getattr(_open, "ops", None)
-    return stack[-1] if stack else None
+    stack = getattr(_open, "spans", None)
+    return stack[-1].counts["op"] if stack else None
+
+
+#: records the ring keeps; the oldest go first.  A 20 s window of the
+#: scoring benchmark is ~6,200 ``predict`` calls, one record each
+OP_LOG_RECORDS = 16_384
+_log: Deque[Dict[str, Any]] = collections.deque(maxlen=OP_LOG_RECORDS)
+#: op -> the record of an operation whose top-level span is still open
+_open_records: Dict[int, Dict[str, Any]] = {}
+#: orders a joined span's fold against its operation's close, and the
+#: ring's writers against its readers
+_log_lock = threading.Lock()
+_log_appended = 0
+#: a record's ``compile`` before any span with a ``cache`` verdict joined
+_NO_VERDICTS = {"hit": 0, "miss": 0, "seconds": 0.0}
+
+
+def _new_record(op: int, name: str, start: float) -> Dict[str, Any]:
+    return {"op": op, "name": name, "start": start, "end": start,
+            "counts": None, "children": {}, "compile": None}
+
+
+def _count_verdict(rec: Dict[str, Any], cache: str, seconds: float) -> None:
+    """One more span with a ``cache`` verdict under ``rec``."""
+    verdicts = rec["compile"]
+    if verdicts is None:
+        rec["compile"] = verdicts = dict(_NO_VERDICTS)
+    verdicts[cache] = verdicts.get(cache, 0) + 1
+    verdicts["seconds"] += seconds
+
+
+def _fold(children: Dict[str, List[Any]], name: str, n: int,
+          seconds: float, longest: float, nbytes: int) -> None:
+    """Add ``n`` spans named ``name`` to a record's ``children``."""
+    child = children.get(name)
+    if child is None:
+        children[name] = [n, seconds, longest, nbytes]
+        return
+    child[0] += n
+    child[1] += seconds
+    if longest > child[2]:
+        child[2] = longest
+    child[3] += nbytes
+
+
+def op_log() -> List[Dict[str, Any]]:
+    """The per-operation record of the spans, oldest first: one plain
+    dict for every top-level :class:`span` that has closed — ``op``,
+    ``name``, ``start`` and ``end`` (``base.timer.get_time``'s clock:
+    the host's, not the profiler's), its ``counts``, per child span
+    name ``children[name] = [n, seconds, max_seconds, bytes]`` (every
+    span below it on any thread that carried its ``op``; ``bytes`` sums
+    that count where a span has one) and ``compile = {hit, miss,
+    seconds}`` (the spans of other threads that joined it with a
+    ``cache`` verdict).  A span that joined by ``op=`` and closed after
+    its operation's top-level span is a record of its own under that
+    ``op``.  Kept whenever ``base.metrics.enabled()``, trace or no
+    trace, in a ring of :data:`OP_LOG_RECORDS` (see
+    :func:`op_log_dropped`); a trace's spans are joined to it by
+    ``op``, not by time."""
+    with _log_lock:
+        records = list(_log)
+    # a closed record is written no more: copied outside the lock
+    return [{**r,
+             "counts": {k: v for k, v in r["counts"].items() if k != "op"},
+             "children": {k: list(v) for k, v in r["children"].items()},
+             "compile": dict(r["compile"] or _NO_VERDICTS)}
+            for r in records]
+
+
+def op_log_dropped() -> int:
+    """Records the ring has overwritten so far (the oldest go first)."""
+    with _log_lock:
+        return _log_appended - len(_log)
 
 
 class span:
@@ -116,14 +195,18 @@ class span:
     link) and inherits its ``op``; a top-level span draws a fresh one;
     a span on another thread joins an operation by passing
     ``op=`` explicitly (:func:`current_op` read on the caller's thread).
-    While :func:`tracing_enabled`, the same name and counts are also
-    recorded as a complete event in :func:`global_tracer`.
+    While ``base.metrics.enabled()`` a top-level span is a record of
+    :func:`op_log` and every other span is folded into the record of its
+    ``op``.  While :func:`tracing_enabled`, the same name and counts are
+    also recorded as a complete event in :func:`global_tracer`.
 
     Nothing is made synchronous: a span around an enqueue measures the
-    enqueue.  :meth:`set` adds counts known only once the work is done.
+    enqueue.  :meth:`set` adds counts known only once the work is done;
+    ``seconds`` is the span's wall once it has closed.
     """
 
-    __slots__ = ("name", "counts", "_ann", "_start_us")
+    __slots__ = ("name", "counts", "seconds", "_ann", "_start_us", "_t0",
+                 "_rec", "_top")
 
     def __init__(self, name: str, **counts: Any) -> None:
         self.name = name
@@ -132,13 +215,27 @@ class span:
     def __enter__(self) -> "span":
         import jax
 
-        stack = _open.__dict__.setdefault("ops", [])
+        stack = _open.__dict__.setdefault("spans", [])
+        # the record this span folds into without a lock: its own, or
+        # the one its parent on this thread folds into.  A span that
+        # joins by ``op=`` has none, and finds its operation's at exit
+        self._rec = None
+        self._top = False
         if self.counts.get("op") is None:
-            self.counts["op"] = stack[-1] if stack else next(_op_ids)
+            if stack:
+                self.counts["op"] = stack[-1].counts["op"]
+                self._rec = stack[-1]._rec
+            else:
+                self.counts["op"] = next(_op_ids)
+                self._top = True
         self._ann = jax.profiler.TraceAnnotation(self.name, **self.counts)
         self._ann.__enter__()
-        stack.append(self.counts["op"])
+        stack.append(self)
         self._start_us = global_tracer()._us() if _TRACING else None
+        self._t0 = get_time()
+        if self._top and _metrics.enabled():
+            self._rec = _open_records[self.counts["op"]] = _new_record(
+                self.counts["op"], self.name, self._t0)
         return self
 
     def set(self, **counts: Any) -> None:
@@ -147,11 +244,70 @@ class span:
         self._ann.set_metadata(**counts)
 
     def __exit__(self, *exc: Any) -> None:
+        end = get_time()
+        self.seconds = end - self._t0
         self._ann.__exit__(*exc)
-        _open.ops.pop()
+        _open.spans.pop()
         if self._start_us is not None:
             global_tracer()._complete(self.name, self._start_us,
                                       self.counts)
+        rec = self._rec
+        if rec is None:
+            if _metrics.enabled():
+                self._join(end)
+        elif self._top:
+            self._close(rec, end)
+        else:
+            # this thread alone writes ``children``: no lock.  ``_fold``
+            # written out: a call is a sixth of what a span may cost
+            seconds = self.seconds
+            child = rec["children"].get(self.name)
+            if child is None:
+                rec["children"][self.name] = [
+                    1, seconds, seconds, self.counts.get("bytes", 0)]
+                return
+            child[0] += 1
+            child[1] += seconds
+            if seconds > child[2]:
+                child[2] = seconds
+            child[3] += self.counts.get("bytes", 0)
+
+    def _close(self, rec: Dict[str, Any], end: float) -> None:
+        """Finish a record and hand it to the ring.  The record keeps the
+        span's own ``counts`` (nothing else holds them once it has
+        closed; :func:`op_log` leaves ``op`` out)."""
+        global _log_appended
+        rec["end"] = end
+        rec["counts"] = self.counts
+        with _log_lock:
+            _open_records.pop(rec["op"], None)
+            if "joined" in rec:
+                for name, child in rec.pop("joined").items():
+                    _fold(rec["children"], name, *child)
+            _log.append(rec)
+            _log_appended += 1
+
+    def _join(self, end: float) -> None:
+        """A span with no record on its own thread (it joined by
+        ``op=``, or metrics came on while it was open): fold it into the
+        open record of its ``op`` — under ``joined``, which the close
+        merges, so that the operation's own thread never takes the lock
+        — or, where that operation has closed (a compile worker outlives
+        the ingest that started it), keep it as a record of its own."""
+        cache = self.counts.get("cache")
+        with _log_lock:
+            rec = _open_records.get(self.counts["op"])
+            if rec is not None:
+                _fold(rec.setdefault("joined", {}), self.name, 1,
+                      self.seconds, self.seconds,
+                      self.counts.get("bytes", 0))
+                if cache is not None:
+                    _count_verdict(rec, cache, self.seconds)
+                return
+        rec = _new_record(self.counts["op"], self.name, self._t0)
+        if cache is not None:
+            _count_verdict(rec, cache, self.seconds)
+        self._close(rec, end)
 
 
 class Tracer:

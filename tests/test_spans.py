@@ -1,25 +1,32 @@
 """The program's own marks: ``profiler.span`` host phases in the
-profiler's trace (and in the Chrome tracer while ``DMLC_TRACE`` is on),
-``jax.named_scope`` device phases in the compiled programs, kernel names
-on the Pallas calls.  The names are a contract (doc/observability.md):
-the benchmark's per-layer readers find the phases by them.
+profiler's trace, in the per-operation record (``profiler.op_log``:
+always, trace or no trace) and in the Chrome tracer while ``DMLC_TRACE``
+is on; ``jax.named_scope`` device phases in the compiled programs, kernel
+names on the Pallas calls.  The names are a contract
+(doc/observability.md): the benchmark's per-layer readers find the phases
+by them.
 """
 
+import collections
 import glob
 import os
+import threading
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from dmlc_core_tpu.base import metrics
 from dmlc_core_tpu.models import HistGBT
 from dmlc_core_tpu.models import histgbt as G
 from dmlc_core_tpu.ops import histogram as H
 from dmlc_core_tpu.ops.quantile import (apply_bins, apply_bins_missing,
                                         apply_bins_t, local_summary)
 from dmlc_core_tpu.parallel.mesh import local_mesh
-from dmlc_core_tpu.utils.profiler import (global_tracer, set_tracing, span,
+from dmlc_core_tpu.utils import profiler
+from dmlc_core_tpu.utils.profiler import (global_tracer, op_log,
+                                          op_log_dropped, set_tracing, span,
                                           tracing_enabled)
 
 # operation -> (root span, its children, the spans that lie inside the
@@ -40,9 +47,31 @@ OPERATIONS = {
              "dmlc.fit.dispatch", "dmlc.fit.fetch_chunk", "dmlc.fit.sync"],
             []),
     "predict": ("dmlc.predict",
-                ["dmlc.predict.stack", "dmlc.predict.put",
-                 "dmlc.predict.dispatch", "dmlc.predict.fetch"], []),
+                ["dmlc.predict.fetch", "dmlc.predict.stack",
+                 "dmlc.predict.put", "dmlc.predict.dispatch"],
+                ["dmlc.predict.fetch.wait", "dmlc.predict.fetch.copy"]),
 }
+# operation -> the counts its record keeps
+RECORD_COUNTS = {
+    "ingest": {"rows": 3000, "features": 5},
+    "ingest_sharded": {"rows": 3000, "features": 5},
+    "fit": {"rounds": 4, "mesh_devices": 1},
+    "predict": {"rows": 100},
+}
+
+
+def _empty_log(mp, records=profiler.OP_LOG_RECORDS):
+    """An empty ring of the record for as long as ``mp`` lasts: what
+    other tests of this process logged stays out of the way, and comes
+    back."""
+    mp.setattr(profiler, "_log", collections.deque(maxlen=records))
+    mp.setattr(profiler, "_log_appended", 0)
+    mp.setattr(profiler, "_open_records", {})
+
+
+@pytest.fixture
+def empty_log(monkeypatch):
+    _empty_log(monkeypatch)
 
 
 def _events(logdir):
@@ -61,9 +90,10 @@ def _events(logdir):
 
 
 @pytest.fixture(scope="module")
-def traced(tmp_path_factory):
+def traced_and_logged(tmp_path_factory):
     """Each operation once, at a tiny size, under its own CPU profiler
-    trace: operation -> its ``dmlc.*`` events."""
+    trace: operation -> (its ``dmlc.*`` events, what it added to
+    ``op_log()``)."""
     rng = np.random.default_rng(0)
     X = rng.normal(size=(3000, 5)).astype(np.float32)
     y = (X[:, 0] * X[:, 1] > 0).astype(np.float32)
@@ -90,15 +120,23 @@ def traced(tmp_path_factory):
     out = {}
     with pytest.MonkeyPatch.context() as mp:
         mp.setenv("DMLC_INGEST_CHUNK_ROWS", "1000")     # three slabs
+        _empty_log(mp)
         for name, op in ops.items():
             logdir = str(tmp_path_factory.mktemp("trace_" + name))
+            before = len(op_log())
             jax.profiler.start_trace(logdir, profiler_options=opts)
             try:
                 op()
             finally:
                 jax.profiler.stop_trace()
-            out[name] = _events(logdir)
+            out[name] = (_events(logdir), op_log()[before:])
     return out
+
+
+@pytest.fixture(scope="module")
+def traced(traced_and_logged):
+    return {name: events for name, (events, _log)
+            in traced_and_logged.items()}
 
 
 @pytest.mark.parametrize("operation", list(OPERATIONS))
@@ -125,6 +163,151 @@ def test_host_spans_of_one_operation(traced, operation):
         (comp,) = [e for e in events if e[0] == "dmlc.compile"]
         assert comp[3]["program"] == "kfn"
         assert comp[3]["cache"] in ("hit", "miss")
+
+
+@pytest.mark.parametrize("operation", list(OPERATIONS))
+def test_one_record_of_one_operation(traced_and_logged, operation):
+    """``op_log()`` holds ONE record an operation, joined to the trace by
+    ``op``: every span the trace shows below the operation's top-level
+    span is in the record's ``children`` under its name, as many times
+    and with as many bytes."""
+    root_name, children, grandchildren = OPERATIONS[operation]
+    events, log = traced_and_logged[operation]
+    (rec,) = [r for r in log if r["name"] == root_name]
+    (root,) = [e for e in events if e[0] == root_name]
+    assert rec["op"] == root[3]["op"]
+    assert rec["counts"] == RECORD_COUNTS[operation]
+    assert rec["end"] - rec["start"] > 0
+    below = [e for e in events if e[0] not in (root_name, "dmlc.compile")]
+    assert set(rec["children"]) - {"dmlc.compile"} == {e[0] for e in below}
+    assert {*children, *grandchildren} <= set(rec["children"])
+    for name in {e[0] for e in below}:
+        n, seconds, longest, nbytes = rec["children"][name]
+        same = [e for e in below if e[0] == name]
+        assert n == len(same)
+        assert nbytes == sum(e[3].get("bytes", 0) for e in same)
+        assert 0 < longest <= seconds <= rec["end"] - rec["start"]
+    if operation == "ingest":
+        assert rec["children"]["dmlc.ingest.put"][0] == 3
+        # a wait carries the bytes of the slab it waits for
+        assert (rec["children"]["dmlc.ingest.put_wait"][3]
+                == rec["children"]["dmlc.ingest.put"][3] > 0)
+    if operation.startswith("ingest"):
+        # the worker's compile carries the ingest's ``op``: folded into
+        # its record if it ended first, else a record of its own
+        own = [r for r in log if r["name"] == "dmlc.compile"]
+        assert all(r["op"] == rec["op"] for r in own)
+        assert len(own) + rec["children"].get(
+            "dmlc.compile", [0])[0] == 1
+        verdicts = [r["compile"] for r in (rec, *own)]
+        assert sum(v["hit"] + v["miss"] for v in verdicts) == 1
+        assert sum(v["seconds"] for v in verdicts) > 0
+        assert [r["counts"]["program"] for r in own] == ["kfn"] * len(own)
+    else:
+        assert [r["name"] for r in log] == [root_name]
+
+
+def _compile_on_a_worker(op, started=None, go=None):
+    def work():
+        if started is not None:
+            started.set()
+            assert go.wait(10)
+        with span("dmlc.compile", op=op, what="test", program="k") as sp:
+            sp.set(cache="hit")
+
+    worker = threading.Thread(target=work)
+    worker.start()
+    return worker
+
+
+def test_a_worker_span_lands_under_its_op(empty_log):
+    """A span on another thread that joined by ``op=`` is folded into its
+    operation's record while that is open, and is a record of its own,
+    under the same ``op``, once the operation has closed: no span is
+    lost."""
+    started, go = threading.Event(), threading.Event()
+    with span("dmlc.test.op", rows=1) as top:
+        op = top.counts["op"]
+        early = _compile_on_a_worker(op)
+        early.join(10)
+        late = _compile_on_a_worker(op, started, go)
+        assert started.wait(10)
+    go.set()
+    late.join(10)
+    assert not early.is_alive() and not late.is_alive()
+    first, second = op_log()
+    assert (first["name"], first["op"]) == ("dmlc.test.op", op)
+    n, seconds, longest, nbytes = first["children"]["dmlc.compile"]
+    assert (n, nbytes) == (1, 0) and seconds == longest > 0
+    assert first["compile"] == {"hit": 1, "miss": 0, "seconds": seconds}
+    assert (second["name"], second["op"]) == ("dmlc.compile", op)
+    assert second["counts"] == {"what": "test", "program": "k",
+                                "cache": "hit"}
+    assert second["children"] == {}
+    assert second["compile"] == {"hit": 1, "miss": 0,
+                                 "seconds": second["end"] - second["start"]}
+
+
+def test_many_workers_fold_into_one_record(empty_log):
+    """More workers than cores, all joining one open operation while its
+    own thread folds children too: every span is counted."""
+    import sys
+
+    workers, each = 16, 200
+
+    def work(op):
+        for _ in range(each):
+            with span("dmlc.compile", op=op, bytes=1) as sp:
+                sp.set(cache="miss")
+
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        with span("dmlc.test.op") as top:
+            threads = [threading.Thread(target=work,
+                                        args=(top.counts["op"],))
+                       for _ in range(workers)]
+            for t in threads:
+                t.start()
+            for _ in range(each):
+                with span("dmlc.test.op.child", bytes=2):
+                    pass
+            for t in threads:
+                t.join(30)
+            assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(was)
+    (rec,) = op_log()
+    assert rec["children"]["dmlc.compile"][0] == workers * each
+    assert rec["children"]["dmlc.compile"][3] == workers * each
+    assert rec["compile"]["miss"] == workers * each
+    assert rec["children"]["dmlc.test.op.child"][0::3] == [each, 2 * each]
+
+
+def test_the_ring_overwrites_and_counts_what_it_dropped(monkeypatch):
+    _empty_log(monkeypatch, records=8)
+    for i in range(11):
+        with span("dmlc.test.op", i=i):
+            pass
+    assert op_log_dropped() == 3
+    assert [r["counts"]["i"] for r in op_log()] == list(range(3, 11))
+    assert profiler.OP_LOG_RECORDS == 16_384       # no knob: a constant
+
+
+def test_metrics_off_records_nothing(empty_log):
+    was = metrics.enabled()
+    try:
+        metrics.set_enabled(False)
+        with span("dmlc.test.op") as top:
+            with span("dmlc.test.op.child"):
+                pass
+        assert op_log() == [] and op_log_dropped() == 0
+        assert top.seconds > 0          # a span's own wall is always there
+    finally:
+        metrics.set_enabled(was)
+    with span("dmlc.test.op"):
+        pass
+    assert [r["name"] for r in op_log()] == ["dmlc.test.op"]
 
 
 def test_sharded_ingest_spans_name_their_chip(traced):
@@ -157,7 +340,7 @@ def test_two_operations_carry_two_ops(traced):
     assert len(set.union(*ops.values())) == len(traced)
 
 
-def test_span_records_to_the_tracer_only_while_tracing():
+def test_span_records_to_the_tracer_only_while_tracing(empty_log):
     was = tracing_enabled()
     tr = global_tracer()
     try:
@@ -166,6 +349,10 @@ def test_span_records_to_the_tracer_only_while_tracing():
         with span("dmlc.test.phase", rows=7):
             pass
         assert tr.events() == []
+        # ... and to the record of its operation with ``DMLC_TRACE`` unset
+        (rec,) = op_log()
+        assert (rec["name"], rec["counts"]) == ("dmlc.test.phase",
+                                                {"rows": 7})
         set_tracing(True)
         with span("dmlc.test.phase", rows=7) as outer:
             with span("dmlc.test.phase.child") as inner:
@@ -177,6 +364,9 @@ def test_span_records_to_the_tracer_only_while_tracing():
         assert child["args"] == {"op": outer.counts["op"], "cache": "hit"}
         assert phase["ts"] <= child["ts"]
         assert child["ts"] + child["dur"] <= phase["ts"] + phase["dur"]
+        rec = op_log()[1]
+        assert rec["op"] == outer.counts["op"] and rec["counts"] == {"rows": 7}
+        assert list(rec["children"]) == ["dmlc.test.phase.child"]
     finally:
         set_tracing(was)
         tr.clear()
