@@ -2664,33 +2664,34 @@ class HistGBT(_ExternalMemoryEngine):
             n_trees = self.best_iteration + 1
         return self.trees if n_trees is None else self.trees[:n_trees]
 
-    def _predict_stacked(self, X: np.ndarray, stacked,
-                         output_margin: bool) -> np.ndarray:
+    def _predict_stacked(self, X: np.ndarray, stacked, output_margin: bool,
+                         op: span) -> np.ndarray:
         """Batched margin/transform over an already-stacked (device)
         forest — shared by predict and predict_iter so the streaming
-        path uploads the model once."""
+        path uploads the model once.  One program a slab
+        (:func:`_predict_slab`); ``op`` is the caller's ``dmlc.predict``
+        span, whose ``programs`` counts the enqueues."""
         p = self.param
         X = np.ascontiguousarray(X, dtype=np.float32)
         self._check_nan_allowed(X, "predict")
         if len(X) == 0:
             return np.zeros(self._margin_shape(0), np.float32)
+        transform = None if output_margin else self._obj.transform
+        miss_bin = self._miss_bin()
         outs = []
         for lo in range(0, len(X), self._PREDICT_BATCH):
             t_b = get_time()
             xb = X[lo:lo + self._PREDICT_BATCH]
             with span("dmlc.predict.put", bytes=xb.nbytes):
                 xb_d = jnp.asarray(xb)
-            with span("dmlc.predict.dispatch"):
-                bins = self._bin_matrix(xb_d)
+            with span("dmlc.predict.dispatch", programs=1):
+                out_d = _predict_slab(xb_d, self.cuts, stacked,
+                                      p.max_depth, miss_bin, p.base_score,
+                                      transform)
                 del xb_d
-                margin = self._apply_trees(
-                    bins, stacked,
-                    jnp.full(self._margin_shape(len(xb)), p.base_score,
-                             jnp.float32))
-                out_d = (margin if output_margin
-                         else self._obj.transform(margin))
+            op.set(programs=op.counts["programs"] + 1)
             with span("dmlc.predict.fetch", bytes=out_d.nbytes):
-                # the copy is asked for BEHIND the programs, as
+                # the copy is asked for BEHIND the program, as
                 # np.asarray alone would ask: a copy started only once
                 # the wait is over costs a call 0.2 ms more (PERF.md §6)
                 out_d.copy_to_host_async()
@@ -2710,9 +2711,9 @@ class HistGBT(_ExternalMemoryEngine):
                 n_trees: Optional[int] = None) -> np.ndarray:
         CHECK(self.cuts is not None, "predict before fit")
         CHECK(len(self.trees) > 0, "no trees trained")
-        with span("dmlc.predict", rows=len(X)):
+        with span("dmlc.predict", rows=len(X), programs=0) as op:
             stacked = self._stacked_trees(self._resolve_trees(n_trees))
-            return self._predict_stacked(X, stacked, output_margin)
+            return self._predict_stacked(X, stacked, output_margin, op)
 
     def predict_iter(self, row_iter, output_margin: bool = False,
                      n_trees: Optional[int] = None,
@@ -2735,9 +2736,9 @@ class HistGBT(_ExternalMemoryEngine):
         F = int(self.cuts.shape[0])
         # stack + upload the forest ONCE, not per slab (50 slabs at 50M
         # rows must not re-ship the model 50 times)
-        with span("dmlc.predict"):
+        with span("dmlc.predict", programs=0) as op:
             stacked = self._stacked_trees(self._resolve_trees(n_trees))
-            outs = [self._predict_stacked(xb, stacked, output_margin)
+            outs = [self._predict_stacked(xb, stacked, output_margin, op)
                     for xb, _, _ in iter_dense_slabs(row_iter, F,
                                                      batch_rows)]
         if not outs:
@@ -2886,27 +2887,10 @@ class HistGBT(_ExternalMemoryEngine):
         [n, K]) — one fixed-shape ``_predict_trees`` dispatch per chunk,
         margins threaded through so summation order matches the
         incremental updates that built them."""
-        depth = self.param.max_depth
-        miss = self._miss_bin()
         margin = init
         for chunk in stacked:
-            dirs = chunk.get("dir")
-            if chunk["feat"].ndim == 4:    # multiclass: [T, K, depth, half]
-                cols = [
-                    _predict_trees(bins,
-                                   chunk["feat"][:, c],
-                                   chunk["thr"][:, c],
-                                   chunk["leaf"][:, c], depth, 0.0,
-                                   margin[:, c],
-                                   dirs[:, c] if dirs is not None else None,
-                                   miss)
-                    for c in range(chunk["feat"].shape[1])
-                ]
-                margin = jnp.stack(cols, axis=1)
-            else:
-                margin = _predict_trees(bins, chunk["feat"], chunk["thr"],
-                                        chunk["leaf"], depth, 0.0, margin,
-                                        dirs, miss)
+            margin = _add_trees(bins, chunk, margin, self.param.max_depth,
+                                self._miss_bin())
         return margin
 
     # ------------------------------------------------------------------
@@ -3137,8 +3121,15 @@ def _put_chunk(part: List[Dict[str, np.ndarray]], keys: Tuple[str, ...]
 
 
 #: row-trees one block of the descent walks at once: each of a block's
-#: [trees, rows] intermediates is 4 MiB of int32, whatever n and T are
-_DESCEND_BLOCK = 1 << 20
+#: [trees, rows] intermediates is 512 KiB of int32, whatever n and T
+#: are.  Swept on a v5e (PERF.md §6, PR 40): at 2^20 the same trees
+#: cost 1.7-2.4x as much, at every width and depth tried
+_DESCEND_BLOCK = 1 << 17
+
+#: trees a block walks: the fewest and the most.  A block reads its
+#: rows' bins once a level for all its trees, so it takes more trees
+#: the wider the rows are (:func:`_descend_blocks`)
+_TREE_BLOCK = (8, 32)
 
 #: a block's rows are laid out [rows/128, 128] behind the tree axis —
 #: whole (8, 128) tiles, so a tree's table entry is a scalar on the VPU
@@ -3195,12 +3186,14 @@ def _descend(bins_t, feats, thrs, dirs, depth: int, miss_bin: int):
     return node
 
 
-def _descend_blocks(n: int, n_trees: int) -> Tuple[int, int, int]:
+def _descend_blocks(n: int, n_trees: int, n_features: int
+                    ) -> Tuple[int, int, int]:
     """(row blocks, rows a block, tree blocks) for n rows × n_trees:
-    at most ``_TREE_CHUNK`` trees and ``_DESCEND_BLOCK`` row-trees a
-    block, rows in whole tiles — static functions of the shapes, so
-    memory is bounded whatever n is."""
-    tree_blocks = -(-n_trees // _TREE_CHUNK)
+    8 trees a block and one more for every 64 features, at most 32, and
+    ``_DESCEND_BLOCK`` row-trees a block, rows in whole tiles — static
+    functions of the shapes, so memory is bounded whatever n is."""
+    fewest, most = _TREE_BLOCK
+    tree_blocks = -(-n_trees // min(max(n_features // 64, fewest), most))
     trees = -(-n_trees // tree_blocks)
     rows = max(_DESCEND_BLOCK // trees // _ROW_TILE, 1) * _ROW_TILE
     rows = min(rows, -(-n // _ROW_TILE) * _ROW_TILE)
@@ -3250,7 +3243,8 @@ def _predict_trees(bins, feats, thrs, leaves, depth: int,
     n = bins.shape[0]
     if init is None:
         init = jnp.full(n, base_score, jnp.float32)
-    row_blocks, rows, tree_blocks = _descend_blocks(n, feats.shape[0])
+    row_blocks, rows, tree_blocks = _descend_blocks(n, feats.shape[0],
+                                                   bins.shape[1])
     # (a tree map skips dirs=None)
     trees = jax.tree.map(partial(_tree_blocks, tree_blocks=tree_blocks),
                          (feats, thrs, dirs, leaves))
@@ -3276,6 +3270,61 @@ def _predict_trees(bins, feats, thrs, leaves, depth: int,
                                 _row_blocks(init, row_blocks, rows))), n)
 
 
+def _add_trees(bins, forest, margin, depth: int, miss_bin: int):
+    """``margin`` ([n], multiclass [n, K]) plus the leaf values of
+    ``forest``, a dict of tree tables [T, ...] (multiclass [T, K, ...]:
+    class c's trees add onto column c) — :func:`_predict_trees` with the
+    margin as its ``init``."""
+    dirs = forest.get("dir")
+    if forest["feat"].ndim == 4:       # multiclass: [T, K, depth, half]
+        cols = [
+            _predict_trees(bins,
+                           forest["feat"][:, c],
+                           forest["thr"][:, c],
+                           forest["leaf"][:, c], depth, 0.0,
+                           margin[:, c],
+                           dirs[:, c] if dirs is not None else None,
+                           miss_bin)
+            for c in range(forest["feat"].shape[1])
+        ]
+        return jnp.stack(cols, axis=1)
+    return _predict_trees(bins, forest["feat"], forest["thr"],
+                          forest["leaf"], depth, 0.0, margin, dirs, miss_bin)
+
+
+@partial(jax.jit, static_argnums=(3, 4, 5, 6))
+def _predict_slab(x, cuts, chunks, depth: int, miss_bin: int,
+                  base_score: float, transform):
+    """One slab of ``predict`` as ONE program: bin ``x`` [n, F] (float32,
+    on the device) against ``cuts``, start the margins at ``base_score``
+    (a constant of the program), add the forest's leaf values and apply
+    ``transform`` (the objective's, a static method; None = margins).
+
+    ``chunks`` is what ``_stacked_trees`` returns.  Laid end to end they
+    are ONE forest of ``len(chunks) * _TREE_CHUNK`` trees, which
+    :func:`_predict_trees` walks as it walks any: a ``lax.scan`` over
+    blocks of trees, the margins threaded through in tree order —
+    every float32 sum of one ``_predict_trees`` call a chunk, in the
+    same order.  The program is keyed on the slab's
+    shape, the NUMBER of chunks, whether they carry ``dir``, and the
+    statics: a forest that grows inside a chunk, or a prefix with as
+    many chunks, compiles nothing; one that crosses a ``_TREE_CHUNK``
+    mark compiles once per slab shape."""
+    if miss_bin < 0:
+        bins = apply_bins(x, cuts)
+    else:
+        bins = apply_bins_missing(x, cuts, miss_bin)
+    with jax.named_scope("dmlc.descend"):
+        forest = {k: jnp.concatenate([chunk[k] for chunk in chunks])
+                  for k in chunks[0]}
+    n_out = forest["leaf"].shape[1:-1]     # () or, multiclass, (K,)
+    margin = _add_trees(
+        bins, forest,
+        jnp.full(x.shape[:1] + n_out, base_score, jnp.float32),
+        depth, miss_bin)
+    return margin if transform is None else transform(margin)
+
+
 @partial(jax.jit, static_argnums=(3, 5))
 @jax.named_scope("dmlc.descend")
 def _leaf_indices(bins, feats, thrs, depth: int, dirs=None,
@@ -3284,7 +3333,8 @@ def _leaf_indices(bins, feats, thrs, depth: int, dirs=None,
     :func:`_descend` as _predict_trees, collecting the final node instead
     of summing leaf values."""
     n, n_trees = bins.shape[0], feats.shape[0]
-    row_blocks, rows, tree_blocks = _descend_blocks(n, n_trees)
+    row_blocks, rows, tree_blocks = _descend_blocks(n, n_trees,
+                                                   bins.shape[1])
     trees = jax.tree.map(partial(_tree_blocks, tree_blocks=tree_blocks),
                          (feats, thrs, dirs))
 

@@ -216,20 +216,24 @@ def test_predict_trees_memory_is_bounded_in_n():
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
-@pytest.mark.parametrize("n,T,want", [
-    (16_384, 64, (1, 16_384, 1)),          # the scoring cell: one block
-    (1, 1, (1, 1024, 1)),
-    (2_000_000, 64, (123, 16_384, 1)),
-    (16_384, 25, (1, 16_384, 1)),          # fit(eval_set=...)'s chunk
-    (100_000, 25, (3, 40_960, 1)),
-    (40_000, 100, (2, 20_480, 2)),         # predict_leaf: exact tree count
+@pytest.mark.parametrize("n,T,F,want", [
+    (16_384, 64, 28, (1, 16_384, 8)),      # a chunk of the scoring cell
+    (16_384, 128, 28, (1, 16_384, 16)),    # its slab program: both chunks
+    (1, 1, 28, (1, 1024, 1)),
+    (2_000_000, 64, 28, (123, 16_384, 8)),
+    (16_384, 25, 28, (1, 16_384, 4)),      # fit(eval_set=...)'s chunk
+    (100_000, 25, 2000, (20, 5120, 1)),    # wide rows: more trees a block
+    (100_000, 64, 2000, (20, 5120, 3)),
+    (65_536, 64, 256, (4, 16_384, 8)),
+    (40_000, 100, 28, (3, 16_384, 13)),    # predict_leaf: exact tree count
 ])
-def test_descend_blocks_follow_the_shapes(n, T, want):
-    row_blocks, rows, tree_blocks = G._descend_blocks(n, T)
+def test_descend_blocks_follow_the_shapes(n, T, F, want):
+    row_blocks, rows, tree_blocks = G._descend_blocks(n, T, F)
     assert (row_blocks, rows, tree_blocks) == want
     assert row_blocks * rows >= n and rows % G._ROW_TILE == 0
-    assert rows * -(-T // tree_blocks) <= max(
-        G._DESCEND_BLOCK, G._ROW_TILE * G._TREE_CHUNK)
+    trees = -(-T // tree_blocks)
+    assert trees <= G._TREE_BLOCK[1]
+    assert rows * trees <= max(G._DESCEND_BLOCK, G._ROW_TILE * trees)
 
 
 @pytest.fixture(scope="module")
@@ -243,9 +247,9 @@ def fitted():
 @pytest.mark.parametrize("output", ["predict", "margin", "predict_leaf"])
 def test_predict_equals_predict_of_its_halves(fitted, output):
     """Block-size independence: 20,000 rows walk as two strided blocks of
-    10,240, each half as one block."""
-    assert G._descend_blocks(20_000, G._TREE_CHUNK)[0] == 2
-    assert G._descend_blocks(10_000, G._TREE_CHUNK)[0] == 1
+    16,384, each half as one block."""
+    assert G._descend_blocks(20_000, G._TREE_CHUNK, 6)[0] == 2
+    assert G._descend_blocks(10_000, G._TREE_CHUNK, 6)[0] == 1
     X = np.random.default_rng(6).normal(size=(20_000, 6)).astype(np.float32)
     call = {"predict": fitted.predict,
             "margin": partial(fitted.predict, output_margin=True),

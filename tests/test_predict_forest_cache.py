@@ -593,11 +593,12 @@ def test_growing_forest_under_a_scoring_thread(fitted):
 
 # -- the compiled programs are the ones they were --------------------------
 def test_no_new_program_for_a_kept_forest(fitted):
-    """A hit hands `_predict_trees` arrays of the shapes a build does:
-    nothing compiles on the second call."""
+    """A hit hands the slab program (`_predict_slab`, the one program
+    `predict` runs) arrays of the shapes a build does: nothing compiles
+    on the second call, nor for a prefix of as many chunks."""
     model, X = _forest(fitted, "binary", 100)
     model.predict(X)
-    before = G._predict_trees._cache_size()
+    before = G._predict_slab._cache_size()
     model.predict(X)
     model.predict(X, n_trees=70)
-    assert G._predict_trees._cache_size() == before
+    assert G._predict_slab._cache_size() == before

@@ -56,7 +56,7 @@ RECORD_COUNTS = {
     "ingest": {"rows": 3000, "features": 5},
     "ingest_sharded": {"rows": 3000, "features": 5},
     "fit": {"rounds": 4, "mesh_devices": 1},
-    "predict": {"rows": 100},
+    "predict": {"rows": 100, "programs": 1},
 }
 
 
@@ -398,6 +398,14 @@ _PROGRAMS = {
             jnp.zeros((2, 3, 4), jnp.int32), jnp.zeros((2, 8)), 3
         ).compile().as_text(),
         ["dmlc.descend"]),
+    "_predict_slab": (
+        lambda: G._predict_slab.lower(
+            jnp.zeros((64, 4)), jnp.zeros((4, 15)),
+            [{"feat": jnp.zeros((2, 3, 4), jnp.int32),
+              "thr": jnp.zeros((2, 3, 4), jnp.int32),
+              "leaf": jnp.zeros((2, 8))}] * 2, 3, -1, 0.5,
+            G._Logistic.transform).compile().as_text(),
+        ["dmlc.bin", "dmlc.descend"]),
     "round_program": (
         _round_program_text,
         ["dmlc.round.grad", "dmlc.round.leaf", "dmlc.round.update"] + [
