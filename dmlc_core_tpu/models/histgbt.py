@@ -214,6 +214,9 @@ class _RoundPlan(NamedTuple):
     #: those of a node block's calls
     hist_node_blocks: Tuple[Tuple[int, ...], ...]
     fused_round: bool
+    #: NaN is missing: the reserved bin, the two-direction split scan and
+    #: the direction descend (``HistGBT._settle_missing_mode`` decided)
+    missing: bool
     pallas_interpret: bool
     grow_policy: str
     #: loss-guide leaf budget (0 = the depth cap alone)
@@ -228,6 +231,7 @@ class _RoundPlan(NamedTuple):
         return {
             "hist_method": list(self.hist_method),
             "fused_round": self.fused_round,
+            "missing": self.missing,
             "pallas_interpret": self.pallas_interpret,
             "grow_policy": self.grow_policy,
             "bin_layout": (None if lay is None else
@@ -268,6 +272,55 @@ def _tree_fold(parts):
     while len(parts) > 1:
         parts = [parts[i] + parts[i + 1] for i in range(0, len(parts), 2)]
     return parts[0]
+
+
+#: a single host-to-device transfer of 2**32 bytes or more crawls: one
+#: v5e chip took 22.0-22.1 s to land 4.58 GB (0.21 GB/s) where 2.69 GB
+#: land in ~1.2 s (PERF.md section 6, PR 42; on the four-chip host 5.12
+#: GB took 23.5 s, PR 29).  A matrix that large goes in row pieces of at
+#: most ``_PUT_PIECE_BYTES``, written into place on the device.
+_PUT_CLIFF_BYTES = 1 << 32
+_PUT_PIECE_BYTES = 1 << 31
+
+
+@lru_cache(maxsize=32)
+def _write_rows_fn(sharding: NamedSharding):
+    """Jitted ``whole[lo:lo + len(piece)] = piece`` in place (``whole``
+    is donated; ``lo`` rides as an operand, so one program serves every
+    piece of a shape)."""
+    return jax.jit(
+        lambda whole, piece, lo: jax.lax.dynamic_update_slice_in_dim(
+            whole, piece, lo, axis=0),
+        donate_argnums=(0,), out_shardings=sharding)
+
+
+@lru_cache(maxsize=32)
+def _empty_matrix_fn(sharding: NamedSharding, shape: tuple, dtype):
+    """Jitted allocation of an uninitialised device matrix (per shape: a
+    fresh closure would compile on every call)."""
+    return jax.jit(partial(jnp.empty, shape, dtype), out_shardings=sharding)
+
+
+def _put_matrix(X: np.ndarray, sharding: NamedSharding) -> jax.Array:
+    """``X`` on the device under ``sharding``: one put below the cliff
+    (``_PUT_CLIFF_BYTES``), else row pieces put one after another —
+    each waited for, so that no transfer queues behind another — and
+    written into one array in place while the next piece travels.
+    Every put is a ``dmlc.ingest.put`` span."""
+    if X.nbytes < _PUT_CLIFF_BYTES:
+        with span("dmlc.ingest.put", bytes=X.nbytes):
+            return jax.device_put(X, sharding)
+    n = X.shape[0]
+    rows = -(-n // -(-X.nbytes // _PUT_PIECE_BYTES))
+    whole = _empty_matrix_fn(sharding, X.shape, X.dtype)()
+    write = _write_rows_fn(sharding)
+    for lo in range(0, n, rows):
+        piece = X[lo:lo + rows]
+        with span("dmlc.ingest.put", bytes=piece.nbytes):
+            landed = jax.device_put(piece, sharding)
+            landed.block_until_ready()
+        whole = write(whole, landed, lo)
+    return whole
 
 
 @lru_cache(maxsize=32)
@@ -1184,9 +1237,22 @@ class HistGBT(_ExternalMemoryEngine):
         n_padded = n_rows + ((-n_rows) % self._pad_multiple())
         return self._maybe_start_warmup(n_features, n_padded) is not None
 
+    def _one_slab(self, n_rows: int) -> bool:
+        """Is a matrix of ``n_rows`` a single slab of the streamed ingest
+        (:meth:`_bin_ingest_streamed`)?"""
+        chunk = _ingest_chunk_rows(device_count(self.mesh))
+        return chunk <= 0 or n_rows <= chunk
+
     def _bin_ingest_streamed(self, X: np.ndarray,
-                             mat_sharding: NamedSharding) -> jax.Array:
+                             mat_sharding: NamedSharding,
+                             resident: Optional[jax.Array] = None
+                             ) -> jax.Array:
         """Chunked, double-buffered host→device ingest + binning.
+
+        ``resident`` is ``X`` already on the device under
+        ``mat_sharding`` (the cut sort's operand, where the matrix is one
+        slab: ``_stage_device_data``): it is binned as it lies and
+        nothing is put.
 
         The whole-matrix path ships the full f32 ``X`` to device and
         keeps it resident while the bin kernel runs — ~5× the binned
@@ -1210,9 +1276,13 @@ class HistGBT(_ExternalMemoryEngine):
         n = X.shape[0]
         ndev = device_count(self.mesh)
         chunk = _ingest_chunk_rows(ndev)
-        if chunk <= 0 or n <= chunk:
+        if self._one_slab(n):
             chunk = n                      # one slab: the whole matrix
         fn = _bin_chunk_fn(self.mesh, self._nan_bin())
+        if resident is not None:
+            with span("dmlc.ingest.stream", slabs=1):
+                with span("dmlc.ingest.bin_dispatch"):
+                    return fn(resident, self.cuts)
         pieces: List[jax.Array] = []
         inflight: deque = deque()
 
@@ -1569,11 +1639,15 @@ class HistGBT(_ExternalMemoryEngine):
             sp.set(features=F)
             CHECK_EQ(len(y), n, "X/y row mismatch")
             weight = self._fold_scale_pos_weight(y, weight)
-            self._settle_missing_mode(X, cuts)
+            with span("dmlc.ingest.host_prep.nan_scan", bytes=X.nbytes):
+                missing_share = self._settle_missing_mode(X, cuts)
+            sp.set(missing=int(self._missing), missing_share=missing_share)
         # explicit cuts always win (a caller injecting boundaries must
         # not be silently overridden by leftovers from an earlier or
         # failed fit); existing self.cuts are kept only when nothing is
         # passed, so repeated handles share one binning
+        mat_sharding = NamedSharding(self.mesh, P("data", None))
+        x_dev = None
         if cuts is not None:
             self.cuts = cuts
         elif self.cuts is None:
@@ -1581,11 +1655,24 @@ class HistGBT(_ExternalMemoryEngine):
             # bin n_bins-1 reserved for NaN
             # whole-matrix put, then the summary and merge enqueued
             with span("dmlc.ingest.cuts", bytes=X.nbytes):
+                x_cuts = X
+                if (device_count(self.mesh) == 1 and self._one_slab(n)
+                        and n % self._pad_multiple() == 0
+                        and not _host_bin_requested()
+                        and not self._mesh_spans_processes()):
+                    # one chip, one slab: the matrix the cut sort reads
+                    # is the slab the binning reads, so it is put ONCE
+                    # (at 1,183,747 x 968 a second copy beside the sort's
+                    # temporaries does not fit the chip by the compiler's
+                    # figures: 4.27 + 8.79 + 4.27 GiB of 15.75, PERF.md
+                    # section 6, PR 42)
+                    x_cuts = x_dev = _put_matrix(X, mat_sharding)
                 self.cuts = compute_cuts(
-                    X, p.n_bins - 1 if self._missing else p.n_bins,
+                    x_cuts, p.n_bins - 1 if self._missing else p.n_bins,
                     weight=weight,
                     allgather_fn=self._maybe_allgather(),
                     missing=self._missing)
+                del x_cuts
         # cut width is the mode's load-bearing invariant: a mismatch
         # (e.g. standard-shaped cuts= injected into a missing-mode
         # model) would silently shift the reserved NaN bin out of the
@@ -1609,7 +1696,6 @@ class HistGBT(_ExternalMemoryEngine):
             X, y, mask, n_pad = self._pad_rows(X, y, weight)
 
         row_sharding = NamedSharding(self.mesh, P("data"))
-        mat_sharding = NamedSharding(self.mesh, P("data", None))
         # DMLC_TPU_BIN_BACKEND=cpu (see _host_bin_requested) bins on the
         # host and uploads the uint8 result — 4× less transfer than
         # shipping f32 X to bin on device, at the price of host-side
@@ -1645,7 +1731,8 @@ class HistGBT(_ExternalMemoryEngine):
             # Large inputs stream through the chunked double-buffered
             # path so the full f32 matrix is never device-resident next
             # to its uint8 bins (see _bin_ingest_streamed).
-            bins_t = self._bin_ingest_streamed(X, mat_sharding)
+            bins_t = self._bin_ingest_streamed(X, mat_sharding, x_dev)
+        del x_dev
         layout = None
         if pack_wanted:
             from dmlc_core_tpu.parallel import collectives as coll2
@@ -1680,9 +1767,11 @@ class HistGBT(_ExternalMemoryEngine):
             "layout": layout,
         }
 
-    def _settle_missing_mode(self, X: np.ndarray, cuts) -> None:
-        """Scan ``X`` for NaN (a read of the whole matrix) and enter,
-        keep or refuse missing mode accordingly."""
+    def _settle_missing_mode(self, X: np.ndarray, cuts) -> float:
+        """Scan ``X`` for NaN (a read of the whole matrix; a second one
+        for the columns' finiteness on entering missing mode) and enter,
+        keep or refuse missing mode accordingly.  Returns the share of
+        ``X``'s cells that are NaN."""
         p = self.param
         # NaN = missing (XGBoost semantics): auto-enter missing mode on
         # first sight of NaN.  Sticky: once a model has missing-mode
@@ -1690,7 +1779,12 @@ class HistGBT(_ExternalMemoryEngine):
         # the reverse (NaN arriving at a non-missing model with cuts
         # already frozen) must fail loudly, not silently alias NaN into
         # the top value bin.
-        has_nan = bool(np.isnan(X).any())
+        nan = np.isnan(X)
+        has_nan = bool(nan.any())
+        # counting the marks costs a twentieth of making them, and only
+        # a matrix that has some pays it
+        share = np.count_nonzero(nan) / max(nan.size, 1) if has_nan else 0.0
+        del nan
         from dmlc_core_tpu.parallel import collectives as coll
         if coll.world_size() > 1:
             # mode selection must be GLOBAL: a shard that happens to hold
@@ -1719,6 +1813,7 @@ class HistGBT(_ExternalMemoryEngine):
                   "X contains NaN but this model's bins were built "
                   "without a missing bin — refit from scratch (NaN in "
                   "the first fit enables missing support) or impute")
+        return float(share)
 
     def _compute_bin_layout(self, bins_t, n_features: int, n_valid: int
                             ) -> Optional["_bl.BinLayout"]:
@@ -1954,6 +2049,7 @@ class HistGBT(_ExternalMemoryEngine):
                 for i, (m, nbs) in enumerate(zip(methods, node_blocks))),
             hist_node_blocks=node_blocks,
             fused_round=fused,
+            missing=self._missing,
             pallas_interpret=pallas_interpret(),
             grow_policy="lossguide" if lossguide else "depthwise",
             max_leaves=_max_leaves() if lossguide else 0,
@@ -1985,7 +2081,7 @@ class HistGBT(_ExternalMemoryEngine):
         return (self.mesh, n_rounds, p.max_depth, p.n_bins,
                 p.learning_rate, p.reg_lambda, p.reg_alpha, p.gamma,
                 p.min_child_weight, obj_key, mono, p.subsample,
-                p.colsample_bytree, p.num_class, self._missing, plan)
+                p.colsample_bytree, p.num_class, plan)
 
     def _build_round_fn(self, plan: _RoundPlan, n_rounds: int = 1):
         """Jitted shard_map program running ``n_rounds`` boosting rounds
@@ -2016,7 +2112,7 @@ class HistGBT(_ExternalMemoryEngine):
                             np.int32)
             if np.any(mc):
                 mono_arr = mc
-        missing = self._missing
+        missing = plan.missing
         if missing:
             CHECK(mono_arr is None,
                   "monotone_constraints with NaN features is not "

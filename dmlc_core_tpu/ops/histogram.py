@@ -54,6 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from dmlc_core_tpu.base.logging import CHECK, log_fatal
 from dmlc_core_tpu.ops import binlayout as _bl
@@ -184,6 +185,17 @@ _TILE_ROWS = 16384
 #: 3.75 MiB.
 _SCOPED_VMEM = 16 << 20
 _SCOPED_ROW_RESERVE = 240
+#: the scoped-VMEM limit the calls of a build cut BOTH ways state for
+#: themselves (node blocks around feature blocks: the last level of a
+#: depth-8 tree on a matrix wider than one feature block).  Alone, and
+#: in the 25-round program of a table with holes, each of those calls
+#: fits the compiler's 16 MiB; in other programs around the very same
+#: calls the v5e compiler charges them 16.50-19.12 MiB and refuses the
+#: program (968 features, 32-node blocks: the one-round program, the
+#: dense 25-round program), by no rule of the block's size — 17.34 MiB
+#: at 152 rows, 16.50 at 168, 19.12 at 200 (compile-only client,
+#: PERF.md section 6, PR 42).  A v5e core has 128 MiB of VMEM.
+_NESTED_BLOCKS_VMEM = 32 << 20
 
 
 def _pallas_ok(n_bins: int, n_features: int, n_nodes: int = 1,
@@ -449,12 +461,13 @@ def _hist_pallas_blocks(bins, node_id, grad, hess, n_nodes, n_bins, *,
         (node_id >= lo) & (node_id < hi), node_id - lo, -1))
     return in_nblock(jnp.concatenate)(
         [_hist_pallas_fblocks(bins, own(lo, hi), grad, hess, hi - lo,
-                              n_bins, transposed=transposed, layout=layout)
+                              n_bins, transposed=transposed, layout=layout,
+                              in_node_block=True)
          for lo, hi in pairwise(accumulate(blocks, initial=0))], axis=1)
 
 
 def _hist_pallas_fblocks(bins, node_id, grad, hess, n_nodes, n_bins, *,
-                         transposed, layout=None):
+                         transposed, layout=None, in_node_block=False):
     """One node block of :func:`_hist_pallas_blocks` over its feature
     blocks.  One block (every shape the whole-matrix budgets admit, and
     every packed ``layout``) is the plain call and traces nothing else.
@@ -463,7 +476,9 @@ def _hist_pallas_fblocks(bins, node_id, grad, hess, n_nodes, n_bins, *,
     on no other feature, so each is made of the same operations in the
     same order as in an unblocked build, bit for bit.  What blocking
     adds outside the kernels (the slabs, the join) runs under the device
-    scope ``dmlc.hist.fblock``."""
+    scope ``dmlc.hist.fblock``.  ``in_node_block``: this is one of
+    several node blocks, so a cut on features too makes the build one of
+    blocks inside blocks, whose calls state ``_NESTED_BLOCKS_VMEM``."""
     if layout is not None:
         return _hist_pallas(bins, node_id, grad, hess, n_nodes, n_bins,
                             transposed=True, layout=layout)
@@ -476,9 +491,10 @@ def _hist_pallas_fblocks(bins, node_id, grad, hess, n_nodes, n_bins, *,
     in_fblock = jax.named_scope("dmlc.hist.fblock")
     slab = in_fblock(lambda lo, hi: (bins[lo:hi] if transposed
                                      else bins[:, lo:hi]))
+    vmem = _NESTED_BLOCKS_VMEM if in_node_block else 0
     return in_fblock(jnp.concatenate)(
         [_hist_pallas(slab(lo, hi), node_id, grad, hess, n_nodes, n_bins,
-                      transposed=transposed)
+                      transposed=transposed, vmem_limit_bytes=vmem)
          for lo, hi in pairwise(accumulate(blocks, initial=0))], axis=2)
 
 
@@ -953,10 +969,11 @@ def _lo_factor(n_nodes: int, n_bins: int) -> int:
     return best
 
 
-@partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9))
+@partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9, 10))
 def _hist_pallas(bins, node_id, grad, hess, n_nodes, n_bins,
                  tile_rows: int = _TILE_ROWS, lo: int = 0,
-                 transposed: bool = False, layout=None):
+                 transposed: bool = False, layout=None,
+                 vmem_limit_bytes: int = 0):
     """Pallas TPU path: grid over row tiles, all tiles accumulate into the
     same [F, A, lo] VMEM output block (sequential TPU grid ⇒ safe),
     then one small reshape/transpose yields [2, N, F, B].
@@ -964,7 +981,11 @@ def _hist_pallas(bins, node_id, grad, hess, n_nodes, n_bins,
     With a nibble-packed ``layout`` the input is the PHYSICAL matrix:
     the kernel's packed region emits two logical rows per byte row, the
     logical output rows are permuted back to STORAGE feature order, and
-    the result is the storage-space histogram [2, N, S, Bs]."""
+    the result is the storage-space histogram [2, N, S, Bs].
+
+    ``vmem_limit_bytes``: the scoped-VMEM limit the call states for
+    itself; 0 leaves the compiler's (``_SCOPED_VMEM``), as every build
+    that is not cut both ways does."""
     if transposed:
         F, n = bins.shape
     else:
@@ -1007,6 +1028,8 @@ def _hist_pallas(bins, node_id, grad, hess, n_nodes, n_bins,
         out_specs=pl.BlockSpec((L, A, lo), lambda i: (0, 0, 0)),
         interpret=pallas_interpret(),
         name="dmlc_hist",
+        **({"compiler_params": pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes)} if vmem_limit_bytes else {}),
     )(bins_t, node_id.reshape(1, n_pad), grad.reshape(1, n_pad),
       hess.reshape(1, n_pad))
     with jax.named_scope("dmlc.hist.unpack"):

@@ -103,6 +103,55 @@ def _key_sort_quantiles(x: jax.Array, qs: jax.Array) -> jax.Array:
             + rows(high) * high_weight[:, None])
 
 
+def _finite_key_sort_summary(x: jax.Array, n_summary: int) -> jax.Array:
+    """``[F, n_summary]`` midpoint-rule quantiles of each column's
+    non-NaN values, from a sort of the keys alone.
+
+    Without row weights a value weighs 1 and a NaN 0, so the weighted
+    quantile function needs no permutation: ``lax.sort`` puts NaN last
+    (its comparator canonicalises NaN above +inf), the first ``c`` rows
+    of a sorted column are its ``c`` non-NaN values in order, and value
+    ``k`` sits at probability ``(k + 0.5) / c``.  The summary point of
+    probability ``q = j / (S - 1)`` is therefore read at position
+    ``t = q * c - 0.5``, clipped to ``[0, c - 1]`` (what ``jnp.interp``
+    does at its ends), by linear interpolation between rows ``floor(t)``
+    and ``floor(t) + 1``: ``2 * S * F`` gathered elements, where the
+    weighted path gathers every element of the matrix twice
+    (``argsort`` + two ``take_along_axis``; at 1,183,747 x 968 that
+    path's stable index-carrying sort alone asks the v5e compiler for
+    25.7 GiB of 15.75, PERF.md section 6, PR 42).
+
+    ``t`` is computed exactly, in integers: with ``c = a * (S - 1) + b``,
+    ``j * c / (S - 1) = j * a + (j * b) / (S - 1)`` — quotient ``k`` and
+    remainder ``r`` — so ``t = k + r / (S - 1) - 0.5`` for any
+    ``c < 2**31`` (``q * c`` in float32 loses the fraction beyond 2**24
+    rows, and a sixteenth of a row at a million).  A column without a
+    value (``c = 0``) gives the all-NaN sentinel row.
+    """
+    S = n_summary
+    den = max(S - 1, 1)
+    CHECK(S * S < 2 ** 31, "n_summary too large for the exact positions")
+    with jax.named_scope("dmlc.cuts.finite"):
+        c = jnp.sum(~jnp.isnan(x), axis=0, dtype=jnp.int32)           # [F]
+        xs = jax.lax.sort(x, dimension=0, is_stable=False)
+        j = jnp.arange(S, dtype=jnp.int32)[:, None]                   # [S, 1]
+        a, b = c // den, c % den
+        k = j * a[None, :] + (j * b[None, :]) // den                  # [S, F]
+        r = (j * b[None, :]) % den
+        upper = 2 * r >= den             # is the fraction of q*c >= 1/2
+        low = jnp.where(upper, k, k - 1)
+        w_high = (r.astype(x.dtype) / den
+                  + jnp.where(upper, -0.5, 0.5).astype(x.dtype))
+        last = jnp.maximum(c - 1, 0)[None, :]
+
+        def rows(at):                                                 # [S, F]
+            return jnp.take_along_axis(xs, jnp.clip(at, 0, last), axis=0,
+                                       mode="promise_in_bounds")
+
+        out = rows(low) * (1 - w_high) + rows(low + 1) * w_high
+        return jnp.where((c == 0)[None, :], jnp.nan, out).T
+
+
 @partial(jax.jit, static_argnums=(2, 3))
 @jax.named_scope("dmlc.cuts")
 def local_summary(x: jax.Array, weight: Optional[jax.Array],
@@ -112,40 +161,48 @@ def local_summary(x: jax.Array, weight: Optional[jax.Array],
     ``x``: [n, F] f32; ``weight``: [n] or None.  Returns [F, n_summary]
     (per-feature weighted quantiles on an even probability grid).
 
-    Without weights and without ``missing`` — the path of every dense
-    fit, a whole-matrix sort — the summary is ``jnp.quantile(x, qs,
-    axis=0).T`` to the bit, but its sort is of the keys ALONE
-    (``is_stable=False``, :func:`_key_sort_quantiles`): a stable sort
-    makes the TPU compiler carry an ``s32[n, F]`` row index through
-    every pass, and no value of the sorted column depends on how ties
-    fall.  The weighted and the ``missing`` paths need their
-    permutation (``argsort``) and keep it.
+    Which paths carry a permutation, and why:
 
-    ``missing=True``: NaN entries are excluded from the summary by
-    rewriting them to the feature's max finite value with weight 0 —
-    a zero-weight duplicate knot that cannot move any quantile (the
-    fixed-shape alternative to per-feature nan-filtering, which would
-    break the [F, n_summary] contract when NaN counts differ by
-    feature).  A feature with NO finite value on this worker emits an
-    explicit all-NaN sentinel row (total weight 0), which
-    :func:`merge_summaries` excludes — a shard-local all-NaN column is
-    legal in distributed fits as long as the feature is finite on SOME
-    worker (callers enforce the global check, histgbt's finite_any
-    allreduce).
+    * no weights, no ``missing`` — every dense fit, a whole-matrix sort:
+      ``jnp.quantile(x, qs, axis=0).T`` to the bit, its sort of the keys
+      ALONE (``is_stable=False``, :func:`_key_sort_quantiles`): a stable
+      sort makes the TPU compiler carry an ``s32[n, F]`` row index
+      through every pass, and no value of the sorted column depends on
+      how ties fall;
+    * no weights, ``missing=True`` — every fit of a table with holes:
+      the midpoint-rule summary of each column's non-NaN values, again
+      from a key-only sort (:func:`_finite_key_sort_summary`, device
+      scope ``dmlc.cuts.finite``): weights of 0 and 1 need no
+      permutation, the NaN sort last and the count of the others says
+      where each quantile point lies;
+    * ``weight`` given, with or without ``missing``: the weights have to
+      follow their values through the sort, so this path keeps its
+      permutation (``argsort``, two ``take_along_axis``, a cumsum and a
+      ``vmap``ped ``interp``) — ROADMAP M8.
+
+    ``missing=True`` excludes NaN entries from the summary.  On the
+    weighted path they are rewritten to the feature's max finite value
+    with weight 0 (the fixed-shape alternative to per-feature
+    nan-filtering, which would break the [F, n_summary] contract when
+    NaN counts differ by feature).  A feature with NO finite value on
+    this worker emits an explicit all-NaN sentinel row (total weight
+    0), which :func:`merge_summaries` excludes — a shard-local all-NaN
+    column is legal in distributed fits as long as the feature is
+    finite on SOME worker (callers enforce the global check, histgbt's
+    finite_any allreduce).
     """
     n, F = x.shape
     qs = jnp.linspace(0.0, 1.0, n_summary)
+    if weight is None:
+        if missing:
+            return _finite_key_sort_summary(x, n_summary)
+        return _key_sort_quantiles(x, qs).T   # [F, n_summary]
+    w2d = jnp.broadcast_to(weight[:, None], x.shape)
     if missing:
         nan = jnp.isnan(x)
-        w2d = (jnp.ones_like(x) if weight is None
-               else jnp.broadcast_to(weight[:, None], x.shape))
         w2d = jnp.where(nan, 0.0, w2d)
         fmax = jnp.max(jnp.where(nan, -jnp.inf, x), axis=0)    # [F]
         x = jnp.where(nan, fmax[None, :], x)
-    elif weight is None:
-        return _key_sort_quantiles(x, qs).T   # [F, n_summary]
-    else:
-        w2d = jnp.broadcast_to(weight[:, None], x.shape)
     order = jnp.argsort(x, axis=0)                                    # [n, F]
     xs = jnp.take_along_axis(x, order, axis=0)
     ws = jnp.take_along_axis(w2d, order, axis=0)                      # [n, F]

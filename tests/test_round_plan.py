@@ -168,9 +168,10 @@ def test_round_plan_record_is_the_plans_json_view():
     assert m.round_plan == plan.describe()
     assert json.loads(json.dumps(m.round_plan)) == m.round_plan
     assert set(m.round_plan) == {
-        "hist_method", "fused_round", "pallas_interpret", "grow_policy",
-        "bin_layout", "hist_features", "hist_feature_blocks",
+        "hist_method", "fused_round", "missing", "pallas_interpret",
+        "grow_policy", "bin_layout", "hist_features", "hist_feature_blocks",
         "hist_node_blocks", "hist_blocks", "mesh_devices"}
+    assert m.round_plan["missing"] is False
     # a record of what the Pallas kernels issue per row tile (dots
     # emitted, dots of the padded block): derived, never a field
     assert m.round_plan["hist_features"] == [F, 8]
