@@ -276,18 +276,22 @@ def _tree_fold(parts):
 
 #: a single host-to-device transfer of 2**32 bytes or more crawls: one
 #: v5e chip took 22.0-22.1 s to land 4.58 GB (0.21 GB/s) where 2.69 GB
-#: land in ~1.2 s (PERF.md section 6, PR 42; on the four-chip host 5.12
-#: GB took 23.5 s, PR 29).  A matrix that large goes in row pieces of at
-#: most ``_PUT_PIECE_BYTES``, written into place on the device.
+#: land in ~1.2 s (PERF.md section 6, PR 42; on the four-chip host 4.48
+#: GB to device 0 held the caller 24.9 s, PR 39, and 5.12 GB took 23.5 s,
+#: PR 29).  A matrix that large goes in row pieces of at most
+#: ``_PUT_PIECE_BYTES``, written into place on the device — on EVERY
+#: path of ``_stage_device_data`` that puts a whole matrix (one slab,
+#: multi-slab and mesh: PR 43); ``_put_matrix`` is the one helper that
+#: cuts a put in pieces.
 _PUT_CLIFF_BYTES = 1 << 32
 _PUT_PIECE_BYTES = 1 << 31
 
 
 @lru_cache(maxsize=32)
-def _write_rows_fn(sharding: NamedSharding):
+def _write_rows_fn(sharding: Optional[NamedSharding]):
     """Jitted ``whole[lo:lo + len(piece)] = piece`` in place (``whole``
     is donated; ``lo`` rides as an operand, so one program serves every
-    piece of a shape)."""
+    piece of a shape).  ``sharding=None``: where the operands lie."""
     return jax.jit(
         lambda whole, piece, lo: jax.lax.dynamic_update_slice_in_dim(
             whole, piece, lo, axis=0),
@@ -295,18 +299,24 @@ def _write_rows_fn(sharding: NamedSharding):
 
 
 @lru_cache(maxsize=32)
-def _empty_matrix_fn(sharding: NamedSharding, shape: tuple, dtype):
+def _empty_matrix_fn(sharding: Optional[NamedSharding], shape: tuple, dtype):
     """Jitted allocation of an uninitialised device matrix (per shape: a
-    fresh closure would compile on every call)."""
+    fresh closure would compile on every call).  ``sharding=None``: on
+    the default device, uncommitted."""
     return jax.jit(partial(jnp.empty, shape, dtype), out_shardings=sharding)
 
 
-def _put_matrix(X: np.ndarray, sharding: NamedSharding) -> jax.Array:
+def _put_matrix(X: np.ndarray,
+                sharding: Optional[NamedSharding]) -> jax.Array:
     """``X`` on the device under ``sharding``: one put below the cliff
     (``_PUT_CLIFF_BYTES``), else row pieces put one after another —
     each waited for, so that no transfer queues behind another — and
     written into one array in place while the next piece travels.
-    Every put is a ``dmlc.ingest.put`` span."""
+    Every put is a ``dmlc.ingest.put`` span.
+
+    ``sharding=None`` is ``jnp.asarray``'s placement: the default
+    device, whole, UNCOMMITTED — what follows from the array (the cuts)
+    may then meet operands on any chip of a mesh."""
     if X.nbytes < _PUT_CLIFF_BYTES:
         with span("dmlc.ingest.put", bytes=X.nbytes):
             return jax.device_put(X, sharding)
@@ -1653,9 +1663,10 @@ class HistGBT(_ExternalMemoryEngine):
         elif self.cuts is None:
             # missing mode: n_bins-1 VALUE bins (cuts [F, n_bins-2]),
             # bin n_bins-1 reserved for NaN
-            # whole-matrix put, then the summary and merge enqueued
+            # whole-matrix put (in pieces past the 2^32-byte cliff, on
+            # every path: _put_matrix), then the summary and merge
+            # enqueued
             with span("dmlc.ingest.cuts", bytes=X.nbytes):
-                x_cuts = X
                 if (device_count(self.mesh) == 1 and self._one_slab(n)
                         and n % self._pad_multiple() == 0
                         and not _host_bin_requested()
@@ -1667,6 +1678,12 @@ class HistGBT(_ExternalMemoryEngine):
                     # figures: 4.27 + 8.79 + 4.27 GiB of 15.75, PERF.md
                     # section 6, PR 42)
                     x_cuts = x_dev = _put_matrix(X, mat_sharding)
+                else:
+                    # multi-slab and mesh: the cut sort's operand alone,
+                    # whole on the default device as jnp.asarray laid it
+                    # (same rows, same order: the cuts are the same
+                    # bytes); the binning streams its own slabs below
+                    x_cuts = _put_matrix(X, None)
                 self.cuts = compute_cuts(
                     x_cuts, p.n_bins - 1 if self._missing else p.n_bins,
                     weight=weight,
@@ -1703,9 +1720,11 @@ class HistGBT(_ExternalMemoryEngine):
         if self._sharded_ingest_ok() and device_count(self.mesh) > 1:
             # SHARDED ingest (the multi-chip staging path): each chip
             # receives — and, on the device-bin route, bins — exactly
-            # its own row slice, streamed slab by slab; the matrix is
-            # never resident on a single device and never staged
-            # through a global put.  Binning is per-element and the
+            # its own row slice, streamed slab by slab; the BINNED
+            # matrix is never resident on a single device and its slabs
+            # never staged through a global put (the float32 matrix the
+            # cuts were computed from was, whole, on the default device:
+            # _put_matrix above).  Binning is per-element and the
             # final layout is the same P(None, "data") block layout, so
             # the result is bit-identical to both fallback paths
             # (pinned by tests/test_multichip.py).

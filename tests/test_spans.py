@@ -158,8 +158,18 @@ def test_host_spans_of_one_operation(traced, operation):
     assert all(root[1] <= e[1] and e[2] <= root[2] for e in inside)
     if grandchildren:
         (mid,) = [e for e in events if e[0] == children[0]]
-        assert all(mid[1] <= e[1] and e[2] <= mid[2]
-                   for e in events if e[0] in grandchildren)
+        below_mid = [e for e in events if e[0] in grandchildren]
+        if operation.startswith("ingest"):
+            # the matrix the cuts are computed from is put too, in
+            # ``.cuts`` and before any slab of the stream (PR 43:
+            # multi-slab and mesh paths go through ``_put_matrix``)
+            (cuts,) = [e for e in events if e[0] == "dmlc.ingest.cuts"]
+            cut_puts = [e for e in below_mid
+                        if cuts[1] <= e[1] and e[2] <= cuts[2]]
+            assert [e[0] for e in cut_puts] == ["dmlc.ingest.put"]
+            assert cut_puts[0][3]["bytes"] == cuts[3]["bytes"] == 3000 * 5 * 4
+            below_mid.remove(cut_puts[0])
+        assert all(mid[1] <= e[1] and e[2] <= mid[2] for e in below_mid)
     if operation.startswith("ingest"):
         assert root[3]["rows"] == 3000 and root[3]["features"] == 5
         (stream,) = [e for e in events if e[0] == "dmlc.ingest.stream"]
@@ -192,10 +202,11 @@ def test_one_record_of_one_operation(traced_and_logged, operation):
         assert nbytes == sum(e[3].get("bytes", 0) for e in same)
         assert 0 < longest <= seconds <= rec["end"] - rec["start"]
     if operation == "ingest":
-        assert rec["children"]["dmlc.ingest.put"][0] == 3
+        # three slabs and, before them, the matrix the cuts read
+        assert rec["children"]["dmlc.ingest.put"][0] == 3 + 1
         # a wait carries the bytes of the slab it waits for
         assert (rec["children"]["dmlc.ingest.put_wait"][3]
-                == rec["children"]["dmlc.ingest.put"][3] > 0)
+                == rec["children"]["dmlc.ingest.put"][3] - 3000 * 5 * 4 > 0)
     if operation.startswith("ingest"):
         # the worker's compile carries the ingest's ``op``: folded into
         # its record if it ended first, else a record of its own
@@ -322,7 +333,10 @@ def test_sharded_ingest_spans_name_their_chip(traced):
     def chips(operation, name):
         return [e[3].get("chip") for e in traced[operation] if e[0] == name]
 
-    puts = chips("ingest_sharded", "dmlc.ingest.put")
+    cut_put, *puts = chips("ingest_sharded", "dmlc.ingest.put")
+    # the matrix the cuts read goes whole to the default device, which
+    # is no position on the ``data`` axis
+    assert cut_put is None
     ndev = len(jax.devices())
     # 3000 rows in 1000-row slabs over 375-row shards: every chip owns
     # a piece, a slab is cut where it straddles a boundary
