@@ -14,10 +14,13 @@ mean-of-sums finalizer).
 
 from __future__ import annotations
 
+from typing import Any, Dict, List, NamedTuple, Sequence, Tuple
+
 import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from dmlc_core_tpu.base.logging import CHECK, log_fatal
 from dmlc_core_tpu.base.registry import Registry
@@ -113,109 +116,354 @@ class _SquaredError(_ObjectiveBase):
         return float(np.sqrt(m))
 
 
+#: pair slots a block of one bucket may hold (queries a block x width^2):
+#: the budget is of SLOTS, not of queries — 256 queries of width 2,048
+#: would be 1.07G.  2^22 float32 slots are 16 MiB a pair tensor, and the
+#: LambdaMART weights keep five or six alive at once.
+RANK_PAIR_SLOTS = 1 << 22
+#: below this width a bucket's blocks carry the QUERIES on the lanes
+#: (``[W, W, queries]``): a ``[queries, 8, 8]`` block would fill 8 of a
+#: vector register's 128 lanes
+_LANES = 128
+
+
+def rank_width(n_docs: int) -> int:
+    """The bucket width of a query of ``n_docs`` documents: the smallest
+    step of the ladder that holds it — half octaves in whole sublanes: 8,
+    16, 24, 32, 48, 64, 96, 128, 192, 256, 384, 512, 768, 1024, 1536,
+    2048, ...  A whole octave a step computes 1.7 slots for a pair of the
+    data, half octaves 1.3 (a query is at worst 1.5 x 1.5 = 2.25 times
+    its own pairs, not 4); the programs' worth of shapes doubles (17 up
+    to 2,048) and stays small beside the round's."""
+    w = 8
+    while True:
+        for cand in (w, w + w // 2):
+            if cand >= n_docs and cand % 8 == 0:
+                return cand
+        w *= 2
+
+
+class RankBucket(NamedTuple):
+    """One width bucket of a group table, as the program needs it."""
+    width: int
+    queries: int        # queries a shard, pad queries included
+    block: int          # queries a block of the pair sums (divides ``queries``)
+
+
+class RankGroups(NamedTuple):
+    """The STATIC part of a handle's group table: what shapes the round
+    program (hashable: part of its cache key).  The arrays that go with
+    it (:meth:`_PairwiseRank.table_specs`) ride as operands."""
+    buckets: Tuple[RankBucket, ...]
+    #: sum over the data's queries of G_q^2 and what the buckets compute
+    pairs: int
+    pair_slots: int
+
+    def describe(self) -> Dict[str, Any]:
+        return {"rank_buckets": [[b.width, b.queries] for b in self.buckets],
+                "rank_pair_slots": self.pair_slots,
+                "rank_pairs": self.pairs}
+
+
+def rank_buckets(lens_by_shard: Sequence[np.ndarray],
+                 pair_slots: int = RANK_PAIR_SLOTS
+                 ) -> Tuple[RankGroups, List[List[np.ndarray]]]:
+    """Sort every shard's queries (``lens_by_shard[k]``: documents of
+    each, in row order) into width buckets.  Returns the static table
+    and, per bucket, per shard, the indices of that shard's queries in
+    it.  Every shard gets the same bucket shapes (the largest count over
+    the shards, rounded up to whole blocks): one program serves a mesh."""
+    widths = [np.asarray([rank_width(int(n)) for n in lens], np.int64)
+              for lens in lens_by_shard]
+    ladder = sorted({int(w) for ws in widths for w in ws})
+    if ladder:
+        # the widest bucket stops at its longest query, in whole sublanes
+        # (whole registers from 128 up): MSLR's 1,251 is 1,280, not 1,536
+        longest = max(int(l.max()) for l in lens_by_shard if len(l))
+        unit = _LANES if longest > _LANES else 8
+        top = -(-longest // unit) * unit
+        widths = [np.minimum(ws, top) for ws in widths]
+        ladder = sorted({min(w, top) for w in ladder})
+    buckets, members = [], []
+    for w in ladder:
+        member = [np.flatnonzero(ws == w) for ws in widths]
+        count = max(len(m) for m in member)
+        block = max(1, pair_slots // (w * w))
+        if w < _LANES:
+            # queries on the lanes: whole registers where there are enough
+            block = max(_LANES, block // _LANES * _LANES)
+            block = min(block, -(-count // 8) * 8)
+        else:
+            block = min(block, count)
+        buckets.append(RankBucket(w, -(-count // block) * block, block))
+        members.append(member)
+    n_shards = max(len(lens_by_shard), 1)
+    return RankGroups(
+        tuple(buckets),
+        pairs=int(sum(int((np.asarray(l, np.int64) ** 2).sum())
+                      for l in lens_by_shard)),
+        pair_slots=int(sum(n_shards * b.queries * b.width * b.width
+                           for b in buckets))), members
+
+
+class _PairAxes(NamedTuple):
+    """Where the two documents of a pair lie in a block's pair tensors.
+    A block's per-document arrays are 2-D, documents along axis ``i``:
+    ``[queries, W]`` makes ``[queries, W_i, W_j]`` (``i, j = 1, 2``),
+    ``[W, queries]`` makes ``[W_i, W_j, queries]`` (``0, 1``)."""
+    i: int
+    j: int
+
+    def of_i(self, x):
+        return jnp.expand_dims(x, self.j)
+
+    def of_j(self, x):
+        return jnp.expand_dims(x, self.i)
+
+    def of_query(self, x):
+        return jnp.expand_dims(x, (self.i, self.j))
+
+    def sum_j(self, x):                  # a per-document array, by i
+        return x.sum(axis=self.j)
+
+    def sum_i(self, x):                  # a per-document array, by j
+        return x.sum(axis=self.i)
+
+
+_QUERY_MAJOR = _PairAxes(1, 2)
+_QUERY_MINOR = _PairAxes(0, 1)
+
+
+def _ranks(ax: _PairAxes, s, valid):
+    """Rank of every document of a block under the current scores, over
+    its WHOLE query (0 = best): the documents ahead of it, counted.
+    THE RULE FOR TIES: descending score, then position in the query —
+    what a stable sort by ``-score`` gives.  Pad slots are ahead of
+    nothing, so the real documents' ranks are those of the query alone
+    whatever the bucket's width."""
+    pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, ax.i)
+    si, sj = ax.of_i(s), ax.of_j(s)
+    ahead = ax.of_j(valid) & ((sj > si) | (
+        (sj == si) & (ax.of_j(pos) < ax.of_i(pos))))
+    return ax.sum_j(ahead.astype(jnp.int32))
+
+
 @OBJECTIVES.register("rank:pairwise")
 class _PairwiseRank(_ObjectiveBase):
     """RankNet-style pairwise ranking over ``qid`` groups (XGBoost
     ``rank:pairwise`` — the consumer of the data plane's qid column,
     reference ``data.h :: Row::qid``, SURVEY.md §2a).
 
-    Contract with :meth:`HistGBT.fit`: rows arrive GROUPED AND PADDED —
-    every query occupies exactly ``group_size`` consecutive rows (pad
-    docs carry ``y = -1`` and weight 0), and shard boundaries fall on
-    group boundaries, so each device's shard is whole groups and the
-    pairwise gradients are shard-local (no cross-device pairs; the
-    histogram psum is the only collective, unchanged).
+    Contract with :meth:`HistGBT.make_device_data`: rows arrive in QUERY
+    ORDER, each query's documents consecutive and in their input order,
+    ragged — no query is padded to another's length and none is cut —
+    and shard boundaries fall on query boundaries, so each device's
+    shard is whole queries and the pairwise gradients are shard-local
+    (no cross-device pairs; the histogram psum is the only collective,
+    unchanged).  The handle's GROUP TABLE says where the queries lie:
+    its static part is this objective's configuration
+    (:class:`RankGroups`, from :func:`rank_buckets`), its arrays
+    (:meth:`table_specs`) are operands of the round program.
 
-    Per better-pair (i, j) with rel_i > rel_j inside one group:
-    ``λ = σ(s_j − s_i)``; ``∂L/∂s_i −= λ``, ``∂L/∂s_j += λ``, and both
-    docs accumulate hessian ``λ(1−λ)``.  Groups are processed in
-    ``lax.map`` blocks of ``block_queries`` so the [QB, G, G] pairwise
-    tensors stay a bounded transient instead of O(n·G) at once.
+    The gradient works in WIDTH BUCKETS.  Queries are sorted into the
+    ladder of :func:`rank_width`; a bucket's scores are gathered from
+    row order as ``[queries, width]`` (row ``start + j`` in slot ``j``,
+    the slots past a query's length masked: their relevance reads -1),
+    the pair sums run over blocks of at most :data:`RANK_PAIR_SLOTS`
+    pair slots (a budget of slots, not of queries), and ``g``, ``h`` go
+    back to row order together, every row reading its own slot
+    (``slot``: a gather, which the chip does faster than the scatter it
+    stands for — PERF.md section 6, PR 44).  The table is static for a
+    handle, so a fit's program compiles once.
+
+    Per better-pair (i, j) with rel_i > rel_j inside one query:
+    ``p = σ(s_j − s_i)``; ``g_i −= p·w``, ``g_j += p·w``, and both
+    documents accumulate hessian ``p(1−p)·w``, floored at 1e-16; ``w`` is
+    1 here and |Δmetric| of swapping the pair in the subclasses.  A query
+    of one document, or of one relevance level, has no pair: ``g = 0``,
+    ``h = 1e-16``.  Ranks are over the whole query, ties by
+    :func:`_ranks`' rule.
     """
 
     is_ranking = True
 
-    def __init__(self, group_size: int, block_queries: int = 256):
-        self.G = int(group_size)
-        self.QB = int(block_queries)
+    def __init__(self, groups: RankGroups):
+        self.groups = groups
 
-    def _map_blocks(self, pred, y, block_fn):
-        """Shared scaffolding: reshape flat rows into [Q, G] queries, pad
-        the query count to the block multiple (pad queries carry rel −1 →
-        no pairs), and ``lax.map`` over [QB, G] blocks.  ``block_fn``
-        receives the pairwise margin differences ``S[i, j] = s_i − s_j``,
-        the better-pair mask, and the raw per-block scores/relevances
-        ``sb, rb`` [QB, G] (the lambda-weighting subclasses need rank
-        positions) and returns any pytree of per-block results (the
-        gradients and the loss derive from exactly these tensors, so
-        padding/sentinel rules live in ONE place).
-        """
-        G = self.G
-        Q = pred.shape[0] // G
-        QB = min(self.QB, Q)
-        qpad = (-Q) % QB
-        s = jnp.pad(pred.reshape(Q, G), ((0, qpad), (0, 0)))
-        r = jnp.pad(y.reshape(Q, G), ((0, qpad), (0, 0)),
-                    constant_values=-1.0)
+    # -- the group table ---------------------------------------------------
+    @classmethod
+    def from_queries(cls, lens_by_shard: Sequence[np.ndarray],
+                     rel_by_shard: Sequence[np.ndarray], rows_a_shard: int,
+                     pair_slots: int = RANK_PAIR_SLOTS):
+        """The objective configured for these queries, and the arrays of
+        its group table on the host (every shard's part laid end to end,
+        as ``P("data")`` cuts them).  ``lens_by_shard[k]`` are the
+        documents of shard ``k``'s queries in row order, ``rel_by_shard
+        [k]`` its rows' relevances (the queries' documents first, then
+        pad rows up to ``rows_a_shard``, which no query owns)."""
+        groups, members = rank_buckets(lens_by_shard, pair_slots)
+        n_shards = len(lens_by_shard)
+        starts = [np.cumsum(l) - l for l in lens_by_shard]
+        # every row's slot in its shard's buckets laid end to end; a pad
+        # row reads the slot past the last
+        slots_a_shard = sum(b.queries * b.width for b in groups.buckets)
+        slot_of = np.full((n_shards, rows_a_shard), slots_a_shard, np.int32)
+        table = {"start": [], "rel": [], "scale": []}
+        first_slot = 0
+        for b, member in zip(groups.buckets, members):
+            start = np.zeros((n_shards, b.queries), np.int32)
+            rel = np.full((n_shards, b.queries, b.width), -1.0, np.float32)
+            slot = np.arange(b.width)
+            for k, q in enumerate(member):
+                start[k, :len(q)] = starts[k][q]
+                inside = slot[None, :] < lens_by_shard[k][q][:, None]
+                rows = (starts[k][q][:, None] + slot[None, :])[inside]
+                rel[k, :len(q)][inside] = rel_by_shard[k][rows]
+                slot_of[k, rows] = (
+                    first_slot + np.arange(len(q))[:, None] * b.width
+                    + slot[None, :])[inside]
+            first_slot += b.queries * b.width
+            rel = rel.reshape(-1, b.width)
+            table["start"].append(start.reshape(-1))
+            table["rel"].append(rel)
+            table["scale"].append(cls.query_scale(rel))
+        return cls(groups), {**{k: tuple(v) for k, v in table.items()},
+                             "slot": slot_of.reshape(-1)}, members
 
-        def block(args):
-            sb, rb = args                                   # [QB, G]
-            vb = rb >= 0
-            S = sb[:, :, None] - sb[:, None, :]             # s_i − s_j
-            better = ((rb[:, :, None] > rb[:, None, :])
-                      & vb[:, :, None] & vb[:, None, :])
-            return block_fn(S, better, sb, rb)
+    @staticmethod
+    def query_scale(rel: np.ndarray) -> np.ndarray:
+        """Per-query factor of the pair weights, from a bucket's padded
+        relevances ``[queries, width]`` (pads -1): static for a handle,
+        so it is computed once, in float64, on the host."""
+        return np.ones(len(rel), np.float32)
 
-        nb = (Q + qpad) // QB
-        out = jax.lax.map(block, (s.reshape(nb, QB, G),
-                                  r.reshape(nb, QB, G)))
-        return out, Q
+    def table_specs(self):
+        """``PartitionSpec`` of every array of the table, as a pytree."""
+        return {"start": tuple(P("data") for _ in self.groups.buckets),
+                "rel": tuple(P("data", None) for _ in self.groups.buckets),
+                "scale": tuple(P("data") for _ in self.groups.buckets),
+                "slot": P("data")}
 
-    def _pair_weight(self, sb, rb, better):
-        """Per-pair lambda weight ``[QB, G, G]`` or None (unweighted
-        RankNet).  The LambdaMART subclasses return |Δmetric| of
-        swapping the pair in the current ranking."""
+    def table_structs(self, mesh, n_rows: int):
+        """Shape, dtype and sharding of every array of the table of a
+        handle of ``n_rows`` rows over ``mesh`` (what the round program
+        is lowered against)."""
+        k = int(mesh.shape["data"])
+
+        def struct(shape, dtype, spec):
+            return jax.ShapeDtypeStruct(
+                shape, dtype, sharding=NamedSharding(mesh, spec))
+
+        bs = self.groups.buckets
+        return {
+            "start": tuple(struct((k * b.queries,), np.int32, P("data"))
+                           for b in bs),
+            "rel": tuple(struct((k * b.queries, b.width), np.float32,
+                                P("data", None)) for b in bs),
+            "scale": tuple(struct((k * b.queries,), np.float32, P("data"))
+                           for b in bs),
+            "slot": struct((n_rows,), np.int32, P("data"))}
+
+    # -- the stage ---------------------------------------------------------
+    def _pair_weight(self, ax, s, r, valid, scale):
+        """Per-pair lambda weight (a block's pair tensor) or None
+        (unweighted RankNet).  The LambdaMART subclasses return
+        |Δmetric| of swapping the pair in the current ranking."""
         return None
 
-    def grad_hess(self, pred, y):
-        def block_fn(S, better, sb, rb):
-            lam = jnp.where(better, jax.nn.sigmoid(-S), 0.0)
-            rho = lam * (1.0 - lam)
-            w = self._pair_weight(sb, rb, better)
-            if w is not None:
-                lam = lam * w
-                rho = rho * w
-            g = -lam.sum(axis=2) + lam.sum(axis=1)          # winner/loser
-            h = rho.sum(axis=2) + rho.sum(axis=1)
-            return g, h
+    def _block_pairs(self, ax: _PairAxes, s, r):
+        """``s_i − s_j`` and the better-pair mask of one block."""
+        valid = r >= 0
+        S = ax.of_i(s) - ax.of_j(s)
+        better = ((ax.of_i(r) > ax.of_j(r))
+                  & ax.of_i(valid) & ax.of_j(valid))
+        return S, better, valid
 
-        (g, h), Q = self._map_blocks(pred, y, block_fn)
-        G = self.G
-        g = g.reshape(-1, G)[:Q].reshape(Q * G)
-        h = h.reshape(-1, G)[:Q].reshape(Q * G)
-        # docs with no pairs get h=0 → leaf math guards with +lambda, but
-        # keep hessians nonnegative-and-tiny like XGBoost's floor
-        return g, jnp.maximum(h, 1e-16)
+    def _block_grad_hess(self, ax: _PairAxes, s, r, scale):
+        S, better, valid = self._block_pairs(ax, s, r)
+        lam = jnp.where(better, jax.nn.sigmoid(-S), 0.0)
+        rho = lam * (1.0 - lam)
+        w = self._pair_weight(ax, s, r, valid, scale)
+        if w is not None:
+            lam = lam * w
+            rho = rho * w
+        g = -ax.sum_j(lam) + ax.sum_i(lam)                  # winner/loser
+        h = ax.sum_j(rho) + ax.sum_i(rho)
+        return g, h
+
+    def _map_buckets(self, pred, table, block_fn):
+        """Shared scaffolding: per bucket, the scores gathered from row
+        order as ``[queries, width]``, ``lax.map`` of ``block_fn(ax, s,
+        r, scale)`` over the bucket's blocks, under the bucket's scope.
+        Returns every bucket's per-block results: the gradients and the
+        loss derive from exactly these tensors, so the masking rules
+        live in ONE place."""
+        outs = []
+        for b, start, rel, scale in zip(self.groups.buckets, table["start"],
+                                        table["rel"], table["scale"]):
+            with jax.named_scope(f"dmlc.round.grad.rank.w{b.width}"):
+                rows = start[:, None] + jnp.arange(b.width, dtype=jnp.int32)
+                # a slot past the shard's last row reads 0 (and is masked)
+                s = pred.at[rows].get(mode="fill", fill_value=0.0)
+                nb = b.queries // b.block
+                minor = b.width < _LANES
+                ax = _QUERY_MINOR if minor else _QUERY_MAJOR
+
+                def block(args, ax=ax, minor=minor):
+                    sb, rb, cb = args                       # [block, W]
+                    if minor:
+                        sb, rb = sb.T, rb.T
+                    return block_fn(ax, sb, rb, cb)
+
+                outs.append(jax.lax.map(block, (
+                    s.reshape(nb, b.block, b.width),
+                    rel.reshape(nb, b.block, b.width),
+                    scale.reshape(nb, b.block))))
+        return outs
+
+    def grad_hess(self, pred, y, table):
+        """``(g, h)`` of a shard's rows ``pred`` (row order) under its
+        group ``table``; ``y`` is not read (the table holds the
+        relevances by slot)."""
+        del y
+        with jax.named_scope("dmlc.round.grad.rank"):
+            by_slot = []
+            for b, (g, h) in zip(self.groups.buckets, self._map_buckets(
+                    pred, table, self._block_grad_hess)):
+                gh = jnp.stack([g, h], axis=-1)       # [blocks, .., .., 2]
+                if b.width < _LANES:
+                    gh = gh.transpose(0, 2, 1, 3)
+                by_slot.append(gh.reshape(b.queries * b.width, 2))
+            # a pad row reads the last slot, which no query owns
+            by_slot.append(jnp.zeros((1, 2), jnp.float32))
+            gh_rows = jnp.concatenate(by_slot)[table["slot"]]
+            # docs with no pairs get h=0 → leaf math guards with +lambda,
+            # but keep hessians nonnegative-and-tiny like XGBoost's floor
+            return gh_rows[:, 0], jnp.maximum(gh_rows[:, 1], 1e-16)
 
     @staticmethod
     def transform(pred):
         return pred
 
     def row_loss(self, pred, y):  # pairwise logloss, averaged per pair
-        log_fatal("rank objectives have no per-row loss; use metric()")
+        log_fatal("rank objectives have no per-row loss: the mean pairwise "
+                  "loss needs the group table (loss_sums; a group-aware "
+                  "ndcg/map eval lives in models.ranking, on predictions)")
 
-    def metric(self, pred, y):
-        """Mean pairwise logistic loss over all better-pairs (same
-        blocked scaffolding as grad_hess — one padding/sentinel rule).
-        Shared by the LambdaMART subclasses: the weighted objectives
-        still bound pairwise misordering, and a group-aware ndcg/map
-        eval lives in ``models.ranking`` (host-side, on predictions)."""
-        def block_fn(S, better, sb, rb):
+    def loss_sums(self, pred, table):
+        """(summed pairwise logistic loss, better-pairs) of a shard —
+        same bucket scaffolding as grad_hess, one masking rule."""
+        def block_fn(ax, s, r, scale):
+            S, better, _ = self._block_pairs(ax, s, r)
             return (jnp.where(better, jnp.logaddexp(0.0, -S), 0.0).sum(),
                     better.sum())
 
-        (losses, counts), _ = self._map_blocks(pred, y, block_fn)
-        return losses.sum() / jnp.maximum(counts.sum(), 1)
+        loss = jnp.float32(0.0)
+        count = jnp.int32(0)
+        for losses, counts in self._map_buckets(pred, table, block_fn):
+            loss = loss + losses.sum()
+            count = count + counts.sum()
+        return loss, count
 
 
 @OBJECTIVES.register("rank:ndcg")
@@ -225,32 +473,33 @@ class _NDCGRank(_PairwiseRank):
     NDCG if the two docs swapped places in the CURRENT ranking — so
     gradient mass concentrates on misorderings near the top of the list
     (Burges' LambdaMART; the delta uses the standard exp2 gain and
-    log2 position discount over the full group).
+    log2 position discount over the full group, no truncation level).
 
-    Pads (rel −1) rank last (score key +inf) and carry zero gain, so
+    Pad slots (rel −1) are ahead of no document and carry zero gain, so
     they contribute no weight; a query with IDCG 0 (all rel 0) has no
-    better-pairs to weight.
+    better-pairs to weight.  1 / IDCG depends on the labels alone: it is
+    the table's ``scale``.
     """
 
-    def _pair_weight(self, sb, rb, better):
-        vb = rb >= 0
-        G = sb.shape[-1]
-        f32 = sb.dtype
-        # rank of each doc under the current scores (0 = best), pads last
-        keyed = jnp.where(vb, -sb, jnp.inf)
-        ranks = jnp.argsort(jnp.argsort(keyed, axis=-1), axis=-1)
-        disc = 1.0 / jnp.log2(2.0 + ranks.astype(f32))      # [QB, G]
-        gain = jnp.where(vb, jnp.exp2(rb) - 1.0, 0.0)
-        rel_best = jnp.sort(rb, axis=-1)[:, ::-1]           # ideal order
-        igain = jnp.where(rel_best >= 0, jnp.exp2(rel_best) - 1.0, 0.0)
-        pos_disc = 1.0 / jnp.log2(2.0 + jnp.arange(G, dtype=f32))
-        idcg = (igain * pos_disc[None, :]).sum(axis=-1)     # [QB]
-        inv_idcg = jnp.where(idcg > 0.0, 1.0 / idcg, 0.0)
+    @staticmethod
+    def query_scale(rel):
+        rel = np.asarray(rel, np.float64)
+        best = -np.sort(-rel, axis=1)                       # ideal order
+        igain = np.where(best >= 0, np.exp2(best) - 1.0, 0.0)
+        disc = 1.0 / np.log2(2.0 + np.arange(rel.shape[1]))
+        idcg = (igain * disc[None, :]).sum(axis=1)
+        return np.where(idcg > 0.0, 1.0 / np.where(idcg > 0.0, idcg, 1.0),
+                        0.0).astype(np.float32)
+
+    def _pair_weight(self, ax, s, r, valid, scale):
+        f32 = s.dtype
+        disc = 1.0 / jnp.log2(2.0 + _ranks(ax, s, valid).astype(f32))
+        gain = jnp.where(valid, jnp.exp2(r) - 1.0, 0.0)
         # swapping i and j moves gain_i to disc_j and vice versa:
         # |ΔDCG| = |g_i − g_j| · |d_i − d_j|
-        return (jnp.abs(gain[:, :, None] - gain[:, None, :])
-                * jnp.abs(disc[:, :, None] - disc[:, None, :])
-                * inv_idcg[:, None, None])
+        return (jnp.abs(ax.of_i(gain) - ax.of_j(gain))
+                * jnp.abs(ax.of_i(disc) - ax.of_j(disc))
+                * ax.of_query(scale))
 
 
 @OBJECTIVES.register("rank:map")
@@ -268,44 +517,44 @@ class _MAPRank(_PairwiseRank):
               + s·(T_{b−1} − T_a)
 
     verified against a brute-force swap-and-rescore in
-    ``tests/test_ranking.py``.
+    ``tests/test_ranking.py``.  The prefix sums are taken per document by
+    counting (the relevant documents ranked no later), so nothing is
+    sorted; 1 / R is the table's ``scale``.
     """
 
-    def _pair_weight(self, sb, rb, better):
-        vb = rb >= 0
-        G = sb.shape[-1]
-        f32 = sb.dtype
-        rel = jnp.where(vb, (rb > 0.0).astype(f32), 0.0)    # [QB, G]
-        keyed = jnp.where(vb, -sb, jnp.inf)
-        order = jnp.argsort(keyed, axis=-1)                 # doc at rank
-        ranks = jnp.argsort(order, axis=-1)                 # rank of doc
-        rel_sorted = jnp.take_along_axis(rel, order, axis=-1)
-        invp = 1.0 / jnp.arange(1, G + 1, dtype=f32)        # 1/(p+1)
-        c = jnp.cumsum(rel_sorted, axis=-1)                 # c_p (incl.)
-        T = jnp.cumsum(rel_sorted * invp, axis=-1)          # T_p
-        R = c[:, -1]                                        # [QB]
-        inv_R = jnp.where(R > 0.0, 1.0 / R, 0.0)
-        # per-DOC values at the doc's own rank position
-        C = jnp.take_along_axis(c, ranks, axis=-1)
-        Td = jnp.take_along_axis(T, ranks, axis=-1)
-        P = (ranks + 1).astype(f32)                         # 1-based pos
+    @staticmethod
+    def query_scale(rel):
+        R = (np.asarray(rel) > 0).sum(axis=1).astype(np.float64)
+        return np.where(R > 0, 1.0 / np.maximum(R, 1.0),
+                        0.0).astype(np.float32)
+
+    def _pair_weight(self, ax, s, r, valid, scale):
+        f32 = s.dtype
+        rel = jnp.where(valid, (r > 0.0).astype(f32), 0.0)
+        ranks = _ranks(ax, s, valid)
+        P_ = (ranks + 1).astype(f32)                        # 1-based pos
+        # per-DOC prefix sums at the doc's own rank position
+        no_later = (ax.of_j(ranks) <= ax.of_i(ranks)) & ax.of_j(valid)
+        C = ax.sum_j(jnp.where(no_later, ax.of_j(rel), 0.0))
+        Td = ax.sum_j(jnp.where(no_later, ax.of_j(rel / P_), 0.0))
+        i_first = ax.of_i(ranks) < ax.of_j(ranks)
 
         def pick(x):                                        # a/b selection
-            xi, xj = x[:, :, None], x[:, None, :]
-            i_first = ranks[:, :, None] < ranks[:, None, :]
+            xi, xj = ax.of_i(x), ax.of_j(x)
             return (jnp.where(i_first, xi, xj),
                     jnp.where(i_first, xj, xi))
 
         rel_a, rel_b = pick(rel)
         C_a, C_b = pick(C)
         T_a, T_b = pick(Td)
-        P_a, P_b = pick(P)
-        s = rel_b - rel_a
+        P_a, P_b = pick(P_)
+        sh = rel_b - rel_a
         T_bm1 = T_b - rel_b / P_b
-        delta = ((rel_b * (C_a + s) - rel_a * C_a) / P_a
+        delta = ((rel_b * (C_a + sh) - rel_a * C_a) / P_a
                  + (rel_a - rel_b) * C_b / P_b
-                 + s * (T_bm1 - T_a))
-        return jnp.abs(delta) * inv_R[:, None, None]
+                 + sh * (T_bm1 - T_a))
+        return jnp.abs(delta) * ax.of_query(scale)
+
 
 def fold_scale_pos_weight(param, y, weight):
     """Fold ``param.scale_pos_weight`` into the instance-weight vector.

@@ -50,6 +50,11 @@ def gbt_metrics():
                 "asked for: hit (kept on the model from an earlier call) "
                 "or built (stacked, padded and put)",
                 labels=("engine", "result")),
+            "rank_queries": r.counter(
+                "gbt_rank_queries_total",
+                "queries a ranking handle staged, by the width bucket "
+                "their pair sums run in",
+                labels=("engine", "bucket")),
             "phase": r.histogram(
                 "gbt_phase_seconds",
                 "per-phase wall time: bin (host wall of the staging "

@@ -33,6 +33,7 @@ from __future__ import annotations
 import operator
 import os
 from collections import deque
+from concurrent.futures import ThreadPoolExecutor
 from functools import lru_cache, partial
 from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
@@ -68,8 +69,8 @@ from dmlc_core_tpu.parallel.mesh import device_count, local_mesh
 from dmlc_core_tpu.models.gbt_objectives import (  # noqa: F401  (re-exports:
     # scripts/tests import these via models.histgbt — keep the names)
     EVAL_METRICS, OBJECTIVES, _METRICS_BY_OBJECTIVE, _Logistic,
-    _ObjectiveBase, _PairwiseRank, _Softmax, _SquaredError, _metric_auc,
-    fold_scale_pos_weight)
+    RankGroups, _ObjectiveBase, _PairwiseRank, _Softmax, _SquaredError,
+    _metric_auc, fold_scale_pos_weight)
 from dmlc_core_tpu.models.gbt_split import (  # noqa: F401  (re-exports)
     _advance_node, _host_bin_requested, _host_bin_t, _leaf_sums,
     _make_best_split, _maybe_l1, _soft_threshold, gbt_metrics)
@@ -224,6 +225,9 @@ class _RoundPlan(NamedTuple):
     layout: Optional[_bl.BinLayout]
     hist_blocks: int
     mesh_devices: int
+    #: a ranking handle's static group table (its width buckets): the
+    #: shapes of the gradient stage, ``None`` for every other objective
+    rank: Optional[RankGroups] = None
 
     def describe(self) -> Dict[str, Any]:
         """The JSON-serialisable record left on ``HistGBT.round_plan``."""
@@ -245,6 +249,7 @@ class _RoundPlan(NamedTuple):
             "hist_node_blocks": [list(b) for b in self.hist_node_blocks],
             "hist_blocks": self.hist_blocks,
             "mesh_devices": self.mesh_devices,
+            **(self.rank.describe() if self.rank is not None else {}),
         }
 
 
@@ -409,6 +414,9 @@ class _RoundProgramWarmup:
             jax.ShapeDtypeStruct(model._margin_shape(n_padded),
                                  np.float32, sharding=margin),
         ]
+        if plan.rank is not None:
+            # a ranking handle's group table rides as operands
+            args.append(model._obj.table_structs(mesh, n_padded))
         if sampling:
             args.append(jax.random.key(0))   # concrete: tiny, typed aval
         self._keys: Dict[str, tuple] = {}
@@ -489,6 +497,37 @@ def _init_margin_fn(mesh: Mesh, shape: tuple, base_score: float,
         lambda: jnp.full(shape, base_score, jnp.float32),
         out_shardings=sh)
 
+
+
+class _RankStaging(NamedTuple):
+    """What :meth:`HistGBT._regroup_ranking` hands the staging: the rows
+    in query order and the group table, still on the host."""
+    X: np.ndarray
+    y: np.ndarray
+    weight: Optional[np.ndarray]
+    n_padded: int
+    #: the padded layout's row of every row in query order (``None``
+    #: where there are no pad rows: one chip)
+    place: Optional[np.ndarray]
+    #: the padded layout's row of every row the CALLER gave (-1: cut by
+    #: ``max_group_size``) — what :meth:`HistGBT.train_margins` unwinds
+    pos: np.ndarray
+    groups: RankGroups
+    table: Dict[str, Tuple[np.ndarray, ...]]
+
+
+def _take_rows(X: np.ndarray, order: np.ndarray) -> np.ndarray:
+    """``X[order]`` by a few threads (numpy's ``take`` releases the
+    interpreter lock): the ONE host copy a ranking ingest makes."""
+    out = np.empty((len(order),) + X.shape[1:], X.dtype)
+    step = max(-(-len(order) // 8), 1)
+
+    def part(lo):
+        np.take(X, order[lo:lo + step], axis=0, out=out[lo:lo + step])
+
+    with ThreadPoolExecutor(8) as pool:
+        list(pool.map(part, range(0, len(order), step)))
+    return out
 
 
 class HistGBTParam(Parameter):
@@ -671,24 +710,20 @@ class HistGBT(_ExternalMemoryEngine):
         :meth:`predict` then uses trees up to ``best_iteration+1`` by
         default.
 
-        ``qid`` (required for ``objective='rank:pairwise'``) groups rows
-        into queries: rows regroup and pad so each query occupies one
-        fixed-size block and shard boundaries fall on query boundaries —
-        pairwise gradients stay shard-local (see :class:`_PairwiseRank`)."""
+        ``qid`` (required for the ``rank:*`` objectives) groups rows
+        into queries: ``make_device_data(qid=)`` puts the rows in query
+        order, ragged, with shard boundaries on query boundaries —
+        pairwise gradients stay shard-local (see :class:`_PairwiseRank`)
+        — and the boosting is ``fit_device``'s: one ranking layout."""
         p = self.param
         X = np.ascontiguousarray(X, dtype=np.float32)
         y = np.ascontiguousarray(y, dtype=np.float32)
         self._rank_pos = None
-        if p.objective.startswith("rank:"):
-            CHECK(qid is not None, f"{p.objective} needs qid=")
+        ranking = p.objective.startswith("rank:")
+        if ranking:                  # make_device_data asks for the qid
             CHECK(eval_set is None,
                   f"{p.objective} eval_set not supported (metrics need "
                   "qid groups; use models.ranking.ndcg on predictions)")
-            CHECK(len(self.trees) == 0,
-                  f"{p.objective} continued fit not supported (padded "
-                  "layout is per-fit)")
-            X, y, weight = self._regroup_ranking(X, y, np.asarray(qid),
-                                                 weight)
         else:
             CHECK(qid is None, f"qid= only valid for rank objectives "
                   f"(objective is {p.objective!r})")
@@ -717,7 +752,8 @@ class HistGBT(_ExternalMemoryEngine):
         row_sharding = NamedSharding(self.mesh, P("data"))
         mat_sharding = NamedSharding(self.mesh, P("data", None))
         K_cls = p.num_class
-        if continuing:
+        rank = None
+        if continuing and not ranking:
             CHECK(self.cuts is not None, "continue-fit without cuts")
             self._check_nan_allowed(X, "fit (continued)")
             weight = self._fold_scale_pos_weight(y, weight)
@@ -766,11 +802,19 @@ class HistGBT(_ExternalMemoryEngine):
             # pre-refactor contract): leftovers from an aborted fit or
             # an earlier fit_device must not silently quantize new data.
             # Handle-sharing reuse is make_device_data's own contract.
-            if cuts is None:
+            # A continued RANKING fit stages its rows like a fresh one
+            # (the group table is the handle's) against the cuts the
+            # model has, and replays the ensemble over the handle.
+            if continuing:
+                CHECK(self.cuts is not None, "continue-fit without cuts")
+            elif cuts is None:
                 self.cuts = None
-            dd = self.make_device_data(X, y, weight=weight, cuts=cuts)
+            dd = self.make_device_data(X, y, weight=weight, cuts=cuts,
+                                       qid=qid)
             bins_t, y_d, w_d = dd["bins_t"], dd["y_d"], dd["w_d"]
-            preds = self._init_margin_device(dd["n_padded"])
+            rank, self._rank_pos = dd.get("rank"), dd.get("rank_pos")
+            preds = (self._replay_margin_device(dd) if continuing
+                     else self._init_margin_device(dd["n_padded"]))
 
         # validation state (binned once; margins updated incrementally)
         eval_bins = eval_margin = yv_d = None
@@ -827,66 +871,92 @@ class HistGBT(_ExternalMemoryEngine):
                                    eval_every=eval_every,
                                    warmup_rounds=warmup_rounds,
                                    after_chunk=after_chunk,
-                                   round_offset=n_prior)
+                                   round_offset=n_prior, rank=rank)
         self._train_preds = preds
         self._n_real_rows = n
         return self
 
-    def _regroup_ranking(self, X, y, qid, weight):
-        """Rearrange rows into fixed-size query blocks for rank:pairwise.
+    def _regroup_ranking(self, X, y, qid, weight, rs) -> "_RankStaging":
+        """Put the rows in QUERY ORDER and build the group table, inside
+        the ``dmlc.ingest.host_prep.regroup`` span ``rs``.
 
-        Stable-sorts by qid, pads every query to ``G`` docs (pad docs:
-        y = −1 sentinel, weight 0, zero features) and pads the query
-        count to a multiple of the mesh size so each shard holds whole
-        queries.  ``max_group_size`` caps G; longer queries TRUNCATE to
-        their first G docs in input order (XGBoost's
-        lambdarank_truncation_level spirit — document counts, don't
-        reorder).  Sets ``self._obj`` to a configured _PairwiseRank and
-        ``self._rank_pos`` (padded position per original row, −1 =
-        truncated away) for :meth:`train_margins`."""
+        One stable sort by ``qid``: documents keep their input order
+        inside a query, and nothing is padded to the longest query.  The
+        queries are dealt to the mesh's ``data`` shards whole and in
+        order, as evenly as their boundaries allow; only where a shard
+        ends short of the longest shard do pad rows follow (weight 0, in
+        no query): at most one query's documents a shard, nothing on one
+        chip.  ``max_group_size`` caps a query at its first G documents
+        in input order (XGBoost's lambdarank_truncation_level spirit —
+        document counts, don't reorder); 0, the default, cuts nothing.
+        Sets ``self._obj`` to the objective configured with the table's
+        static part (:func:`rank_buckets`: width buckets, the same
+        shapes on every shard)."""
         p = self.param
         n = len(y)
         CHECK_EQ(len(qid), n, "qid/X row mismatch")
+        CHECK(n > 0, f"{p.objective} needs rows")
+        CHECK(float(y.min()) >= 0.0,
+              f"{p.objective}: relevance labels must be >= 0")
         order = np.argsort(qid, kind="stable")
         qs = qid[order]
         starts = np.flatnonzero(np.r_[True, qs[1:] != qs[:-1]])
         lens = np.diff(np.r_[starts, n])
-        G = int(lens.max())
-        if p.max_group_size:
-            G = min(G, p.max_group_size)
-        ndev = device_count(self.mesh)
-        Q = len(starts)
-        Qp = Q + ((-Q) % ndev)
-        Xp = np.zeros((Qp * G, X.shape[1]), np.float32)
-        yp = np.full(Qp * G, -1.0, np.float32)
-        wp = np.zeros(Qp * G, np.float32)
-        pos = np.full(n, -1, np.int64)
-        w_in = (np.asarray(weight, np.float32) if weight is not None
-                else np.ones(n, np.float32))
-        # one vectorized scatter (a per-query Python loop is O(Q)
-        # interpreter work on the flagship's hot path): rank of each
-        # sorted row within its query = index − its query's start;
-        # rows ranked ≥ G are truncated away
-        within = np.arange(n) - np.repeat(starts, lens)
-        kept = within < G
-        rows_all = order[kept]
-        dst_all = (np.repeat(np.arange(Q, dtype=np.int64), lens)[kept] * G
-                   + within[kept])
-        Xp[dst_all] = X[rows_all]
-        yp[dst_all] = y[rows_all]
-        wp[dst_all] = w_in[rows_all]
-        pos[rows_all] = dst_all
-        truncated = int(n - kept.sum())
-        if truncated:
+        if p.max_group_size and int(lens.max()) > p.max_group_size:
+            G = p.max_group_size
+            within = np.arange(n) - np.repeat(starts, lens)
+            order = order[within < G]
+            lens = np.minimum(lens, G)
             LOG("WARNING", "%s: truncated %d docs beyond "
-                "max_group_size=%d", p.objective, truncated, G)
-        self._obj = OBJECTIVES[p.objective](G)
-        self._rank_pos = pos
-        return Xp, yp, wp
+                "max_group_size=%d", p.objective, n - len(order), G)
+        n_kept = len(order)
+        ends = np.cumsum(lens)
+        starts = ends - lens
+        # whole queries to every shard, in order: shard k ends with the
+        # last query that ends at or before k/K of the documents
+        dsize = int(self.mesh.shape["data"])
+        q_cut = np.r_[0, np.searchsorted(
+            ends, n_kept * np.arange(1, dsize) / dsize, side="right"),
+            len(lens)]
+        row_cut = np.r_[0, ends][q_cut]
+        unit = int(np.lcm(self._pad_multiple(), dsize)) // dsize
+        per_shard = max(int(np.diff(row_cut).max()), 1)
+        per_shard = -(-per_shard // unit) * unit
+        n_padded = per_shard * dsize
+        shard_of_row = np.repeat(np.arange(dsize), np.diff(row_cut))
+        place = None
+        if n_padded != n_kept:
+            place = (np.arange(n_kept) - row_cut[shard_of_row]
+                     + shard_of_row * per_shard)
+        pos = np.full(n, -1, np.int64)
+        pos[order] = np.arange(n_kept) if place is None else place
+        sorted_already = n_kept == n and bool(
+            np.array_equal(order, np.arange(n)))
+        if not sorted_already:
+            X = _take_rows(X, order)
+            y = y[order]
+            if weight is not None:
+                weight = np.asarray(weight, np.float32)[order]
+
+        y_by_shard = [y[row_cut[k]:row_cut[k + 1]] for k in range(dsize)]
+        self._obj, table, members = OBJECTIVES[p.objective].from_queries(
+            [lens[q_cut[k]:q_cut[k + 1]] for k in range(dsize)],
+            y_by_shard, per_shard)
+        groups = self._obj.groups
+        if _metrics.enabled():
+            for b, member in zip(groups.buckets, members):
+                gbt_metrics()["rank_queries"].inc(
+                    sum(len(m) for m in member), engine="incore",
+                    bucket=str(b.width))
+        rs.set(queries=len(lens), docs=n_kept, max_group=int(lens.max()),
+               buckets=len(groups.buckets),
+               pad_share=(n_padded - n_kept) / n_padded)
+        return _RankStaging(X, y, weight, n_padded, place, pos, groups,
+                            table)
 
     def _boost_binned(self, bins_t, y_d, w_d, preds, n_features,
                       eval_every=0, warmup_rounds=0, after_chunk=None,
-                      chunk_callback=None, round_offset=0):
+                      chunk_callback=None, round_offset=0, rank=None):
         """Run ``n_trees`` boosting rounds over device-resident binned
         data (bins feature-major [F, n], rows sharded on the mesh's data
         axis).  Shared by :meth:`fit` and the cached external-memory
@@ -897,17 +967,20 @@ class HistGBT(_ExternalMemoryEngine):
         program): per-dispatch + per-fetch latency would otherwise add to
         every round's compute; trees stay on device until the end.
         ``after_chunk(done, preds, trees_k) -> stop?`` hooks validation/
-        early-stopping between dispatches.
+        early-stopping between dispatches.  ``rank`` is a ranking
+        handle's group table (device arrays), an operand of every
+        dispatch.
         """
         with span("dmlc.fit", rounds=self.param.n_trees,
                   mesh_devices=device_count(self.mesh)):
             return self._boost_rounds(
                 bins_t, y_d, w_d, preds, n_features, eval_every,
-                warmup_rounds, after_chunk, chunk_callback, round_offset)
+                warmup_rounds, after_chunk, chunk_callback, round_offset,
+                rank)
 
     def _boost_rounds(self, bins_t, y_d, w_d, preds, n_features,
                       eval_every, warmup_rounds, after_chunk,
-                      chunk_callback, round_offset):
+                      chunk_callback, round_offset, rank):
         """:meth:`_boost_binned`'s work, inside its ``dmlc.fit`` span."""
         p = self.param
         # rounds per dispatch (_rounds_schedule): 25 amortizes
@@ -918,6 +991,11 @@ class HistGBT(_ExternalMemoryEngine):
         sampling = p.subsample < 1.0 or p.colsample_bytree < 1.0
         base_key = jax.random.key(p.seed) if sampling else None
 
+        CHECK((rank is not None) == p.objective.startswith("rank:"),
+              f"{p.objective}: a ranking fit needs a handle made with "
+              "make_device_data(qid=...), and no other fit takes one")
+        table = () if rank is None else (rank,)
+
         def run(fn, preds_c, done):
             if sampling:
                 # chunk key derives from the GLOBAL round index (prior
@@ -925,9 +1003,9 @@ class HistGBT(_ExternalMemoryEngine):
                 # sample no matter how rounds are chunked into
                 # dispatches — or split across resumed fits (elastic
                 # recovery replays a round with its original draw)
-                return fn(bins_t, y_d, w_d, preds_c,
+                return fn(bins_t, y_d, w_d, preds_c, *table,
                           jax.random.fold_in(base_key, round_offset + done))
-            return fn(bins_t, y_d, w_d, preds_c)
+            return fn(bins_t, y_d, w_d, preds_c, *table)
 
         # join the overlapped compile (make_device_data / fit_device
         # kicked it off before ingest); the AOT executables are used
@@ -1003,7 +1081,7 @@ class HistGBT(_ExternalMemoryEngine):
                 # warmup path's, and published for later fits only when the
                 # buffers carry the canonical shardings they key on.
                 t_tr = get_time()
-                aot_args = (bins_t, y_d, w_d, preds) + (
+                aot_args = (bins_t, y_d, w_d, preds) + table + (
                     (jax.random.fold_in(base_key, round_offset),)
                     if sampling else ())
                 kfn = kfn_jit.lower(*aot_args).compile()
@@ -1062,7 +1140,8 @@ class HistGBT(_ExternalMemoryEngine):
             chunks.append(trees_k)        # stacked [k, ...] device arrays
             done += k_now
             if eval_every and done % eval_every == 0:
-                loss = float(self._obj.metric(preds, y_d))
+                loss = float(self._obj.metric(preds, y_d) if rank is None
+                             else self._rank_loss(preds, rank))
                 LOG("INFO", "round %d: loss=%.5f", done, loss)
             if after_chunk is not None and after_chunk(done, preds, trees_k):
                 break
@@ -1181,11 +1260,22 @@ class HistGBT(_ExternalMemoryEngine):
             return False
         return not self._mesh_spans_processes()
 
-    def _pad_rows(self, X, y, weight):
+    def _pad_rows(self, X, y, weight, staged: Optional[_RankStaging] = None):
         """Pad rows to a mesh-size multiple (a block multiple in
         deterministic-histogram mode) and build the weight mask
-        (pad rows weigh 0, so they are invisible to cuts/grads/hists)."""
+        (pad rows weigh 0, so they are invisible to cuts/grads/hists).
+        A ranking handle's pad rows close each shard, not the matrix:
+        ``staged`` says where every row goes."""
         n = len(y)
+        if staged is not None and staged.place is not None:
+            rows = staged.n_padded
+            Xp = np.zeros((rows, X.shape[1]), np.float32)
+            yp = np.zeros(rows, np.float32)
+            mask = np.zeros(rows, np.float32)
+            Xp[staged.place] = X
+            yp[staged.place] = y
+            mask[staged.place] = 1.0 if weight is None else weight
+            return Xp, yp, mask, rows - n
         n_pad = (-n) % self._pad_multiple()
         if n_pad:
             X = np.concatenate([X, np.zeros((n_pad, X.shape[1]),
@@ -1239,10 +1329,15 @@ class HistGBT(_ExternalMemoryEngine):
         ``_maybe_start_warmup`` makes the ingest-time kick a no-op.
 
         Returns False without compiling when a packed bin layout is
-        requested (``DMLC_BIN_PACK``/``DMLC_FEATURE_BUNDLE``): the
-        layout is a compile-time constant derived from the binned data,
-        so the compile cannot start before ingest."""
+        requested (``DMLC_BIN_PACK``/``DMLC_FEATURE_BUNDLE``) and for a
+        ``rank:*`` objective: the layout, and a ranking handle's width
+        buckets, are compile-time constants derived from the data, so
+        the compile cannot start before ingest."""
         if _bin_pack_requested() or _feature_bundle_requested():
+            return False
+        if self.param.objective.startswith("rank:"):
+            # the group table's width buckets shape the gradient stage:
+            # known only once make_device_data(qid=) has seen the queries
             return False
         n_padded = n_rows + ((-n_rows) % self._pad_multiple())
         return self._maybe_start_warmup(n_features, n_padded) is not None
@@ -1607,6 +1702,7 @@ class HistGBT(_ExternalMemoryEngine):
         y: np.ndarray,
         weight: Optional[np.ndarray] = None,
         cuts: Optional[jax.Array] = None,
+        qid: Optional[np.ndarray] = None,
     ) -> Dict[str, Any]:
         """Quantize + upload a training set ONCE, for repeated fits.
 
@@ -1622,12 +1718,20 @@ class HistGBT(_ExternalMemoryEngine):
         Sets ``self.cuts`` if unset, so trees fitted from this handle
         predict correctly on raw features later.
 
+        ``qid`` (the ``rank:*`` objectives need it, no other takes it)
+        groups the rows into queries, in any order: the handle holds the
+        rows in query order (one stable sort; ragged, nothing padded to
+        the longest query and nothing cut unless ``max_group_size`` says
+        so) and carries its GROUP TABLE — query boundaries in width
+        buckets, device arrays made once (:class:`_PairwiseRank`) — so
+        :meth:`fit_device` boosts on it like on any other handle.
+
         Asynchronous: the handle comes back once the last staging call
         is ENQUEUED; its arrays are ready when the device has drained
         them (``jax.block_until_ready`` on them to wait).
         """
         with span("dmlc.ingest", rows=len(y)) as sp:
-            out = self._stage_device_data(X, y, weight, cuts, sp)
+            out = self._stage_device_data(X, y, weight, cuts, sp, qid)
         # host wall of the staging calls up to their last enqueue (cuts,
         # puts and the waits that pace them, binning dispatches) — NOT
         # the completion of the device work they queue, which the caller
@@ -1638,16 +1742,27 @@ class HistGBT(_ExternalMemoryEngine):
                                            engine="incore", phase="bin")
         return out
 
-    def _stage_device_data(self, X, y, weight, cuts, sp) -> Dict[str, Any]:
+    def _stage_device_data(self, X, y, weight, cuts, sp,
+                           qid=None) -> Dict[str, Any]:
         """:meth:`make_device_data`'s work, inside its ``dmlc.ingest``
         span ``sp``; every host phase is a child span."""
         p = self.param
+        staged = None
         with span("dmlc.ingest.host_prep"):
             X = np.ascontiguousarray(X, dtype=np.float32)
             y = np.ascontiguousarray(y, dtype=np.float32)
+            CHECK_EQ(len(y), X.shape[0], "X/y row mismatch")
+            if p.objective.startswith("rank:"):
+                CHECK(qid is not None, f"{p.objective} needs qid=")
+                with span("dmlc.ingest.host_prep.regroup") as rs:
+                    staged = self._regroup_ranking(X, y, np.asarray(qid),
+                                                   weight, rs)
+                X, y, weight = staged.X, staged.y, staged.weight
+            else:
+                CHECK(qid is None, f"qid= only valid for rank objectives "
+                      f"(objective is {p.objective!r})")
             n, F = X.shape
             sp.set(features=F)
-            CHECK_EQ(len(y), n, "X/y row mismatch")
             weight = self._fold_scale_pos_weight(y, weight)
             with span("dmlc.ingest.host_prep.nan_scan", bytes=X.nbytes):
                 missing_share = self._settle_missing_mode(X, cuts)
@@ -1658,6 +1773,8 @@ class HistGBT(_ExternalMemoryEngine):
         # passed, so repeated handles share one binning
         mat_sharding = NamedSharding(self.mesh, P("data", None))
         x_dev = None
+        n_padded = (staged.n_padded if staged is not None
+                    else n + ((-n) % self._pad_multiple()))
         if cuts is not None:
             self.cuts = cuts
         elif self.cuts is None:
@@ -1668,7 +1785,7 @@ class HistGBT(_ExternalMemoryEngine):
             # enqueued
             with span("dmlc.ingest.cuts", bytes=X.nbytes):
                 if (device_count(self.mesh) == 1 and self._one_slab(n)
-                        and n % self._pad_multiple() == 0
+                        and n_padded == n
                         and not _host_bin_requested()
                         and not self._mesh_spans_processes()):
                     # one chip, one slab: the matrix the cut sort reads
@@ -1708,9 +1825,9 @@ class HistGBT(_ExternalMemoryEngine):
         pack_wanted = ((_bin_pack_requested() or _feature_bundle_requested())
                        and not self._missing)
         if not pack_wanted:
-            self._maybe_start_warmup(F, n + ((-n) % self._pad_multiple()))
+            self._maybe_start_warmup(F, n_padded)
         with span("dmlc.ingest.pad"):
-            X, y, mask, n_pad = self._pad_rows(X, y, weight)
+            X, y, mask, n_pad = self._pad_rows(X, y, weight, staged)
 
         row_sharding = NamedSharding(self.mesh, P("data"))
         # DMLC_TPU_BIN_BACKEND=cpu (see _host_bin_requested) bins on the
@@ -1776,7 +1893,12 @@ class HistGBT(_ExternalMemoryEngine):
         with span("dmlc.ingest.labels"):
             y_d = jax.device_put(y, row_sharding)
             w_d = jax.device_put(mask, row_sharding)
-        return {
+            # a ranking handle's group table: device arrays, made once
+            rank = None if staged is None else jax.tree.map(
+                lambda a, spec: jax.device_put(
+                    a, NamedSharding(self.mesh, spec)),
+                staged.table, self._obj.table_specs())
+        out = {
             "bins_t": bins_t,
             "y_d": y_d,
             "w_d": w_d,
@@ -1785,6 +1907,10 @@ class HistGBT(_ExternalMemoryEngine):
             "n_features": F,
             "layout": layout,
         }
+        if staged is not None:
+            out.update(rank=rank, rank_groups=staged.groups,
+                       rank_pos=staged.pos)
+        return out
 
     def _settle_missing_mode(self, X: np.ndarray, cuts) -> float:
         """Scan ``X`` for NaN (a read of the whole matrix; a second one
@@ -1918,17 +2044,23 @@ class HistGBT(_ExternalMemoryEngine):
         they match the handle (bit-identical to replay), else replays
         the ensemble's margins on device, and threads the global round
         index through so sampling draws match an uninterrupted run.
-        The :meth:`fit`-only extras (eval_set / early stopping / ranking
-        regroup) are not available here; use :meth:`fit` for those.
+        The :meth:`fit`-only extras (eval_set / early stopping) are not
+        available here; use :meth:`fit` for those.  A ``rank:*`` fit
+        takes a handle made with ``make_device_data(qid=...)``: the
+        group table is the handle's.
         ``chunk_callback(rounds_fetched, elapsed_s)`` fires as each
         dispatch chunk's trees arrive on host — incremental timing
         evidence for benchmark harnesses (bench.py's provisional
         emission rides this).
         """
         p = self.param
-        CHECK(not p.objective.startswith("rank:"),
-              f"fit_device does not support {p.objective} (padded layout "
-              "is per-fit); use fit(qid=...)")
+        rank = device_data.get("rank")
+        if p.objective.startswith("rank:"):
+            CHECK(rank is not None,
+                  f"{p.objective}: the handle has no group table — make "
+                  "it with make_device_data(X, y, qid=...)")
+            # the handle knows its own groups, like its layout below
+            self._obj = OBJECTIVES[p.objective](device_data["rank_groups"])
         # the handle knows its own storage layout — adopt it so the round
         # program matches the matrix even if another make_device_data ran
         # on this model in between
@@ -1951,12 +2083,12 @@ class HistGBT(_ExternalMemoryEngine):
         self.best_iteration = None
         self.best_score = None
         self._early_stopped = False
-        self._rank_pos = None
+        self._rank_pos = device_data.get("rank_pos")
         preds = self._boost_binned(
             device_data["bins_t"], device_data["y_d"], device_data["w_d"],
             preds, device_data["n_features"],
             warmup_rounds=warmup_rounds, chunk_callback=chunk_callback,
-            round_offset=n_prior)
+            round_offset=n_prior, rank=rank)
         self._train_preds = preds
         self._n_real_rows = device_data["n"]
         return self
@@ -1974,6 +2106,12 @@ class HistGBT(_ExternalMemoryEngine):
         carried = self._train_preds
         if carried is not None and getattr(carried, "shape", (0,))[0] == n_padded:
             return carried
+        return self._replay_margin_device(device_data)
+
+    def _replay_margin_device(self, device_data: Dict[str, Any]) -> jax.Array:
+        """The existing ensemble's margins over a handle's rows, replayed
+        on the device from the handle's own bins."""
+        n_padded = device_data["n_padded"]
         CHECK(device_data.get("layout") is None,
               "resume-fit margin replay on a packed/bundled handle needs "
               "the carried training margins (a restored process has "
@@ -1992,6 +2130,24 @@ class HistGBT(_ExternalMemoryEngine):
     # ------------------------------------------------------------------
     # the round program
     # ------------------------------------------------------------------
+    def _rank_loss(self, preds: jax.Array, rank) -> jax.Array:
+        """Mean pairwise logistic loss of a ranking handle's margins:
+        every shard's sums by its own part of the group table, then one
+        ``psum`` (the training log's ``eval_every``)."""
+        obj = self._obj
+        kept = getattr(self, "_rank_loss_fn", None)
+        if kept is None or kept[0] != obj.groups:
+            def sums(preds_l, table_l):
+                loss, count = obj.loss_sums(preds_l, table_l)
+                return (jax.lax.psum(loss, "data")
+                        / jnp.maximum(jax.lax.psum(count, "data"), 1))
+
+            kept = self._rank_loss_fn = (obj.groups, jax.jit(shard_map(
+                sums, mesh=self.mesh,
+                in_specs=(P("data"), obj.table_specs()), out_specs=P(),
+                check_vma=False)))
+        return kept[1](preds, rank)
+
     def _round_plan(self, n_features: int) -> _RoundPlan:
         """Resolve every path choice of the round program from what can
         be observed before tracing — param, mesh, bin layout, knobs,
@@ -2074,7 +2230,8 @@ class HistGBT(_ExternalMemoryEngine):
             max_leaves=_max_leaves() if lossguide else 0,
             layout=layout,
             hist_blocks=det_blocks,
-            mesh_devices=dsize)
+            mesh_devices=dsize,
+            rank=getattr(self._obj, "groups", None))
         self.round_plan = plan.describe()
         return plan
 
@@ -2092,9 +2249,10 @@ class HistGBT(_ExternalMemoryEngine):
         p = self.param
         obj = self._obj
         # registry objectives are per-name singletons (hashable as-is);
-        # _PairwiseRank is configured per fit → key on its config
-        obj_key = ((type(obj).__name__, obj.G, obj.QB)
-                   if isinstance(obj, _PairwiseRank) else obj)
+        # _PairwiseRank is configured per handle → key on its class (its
+        # configuration, the group table's static part, is ``plan.rank``)
+        obj_key = (type(obj).__name__ if isinstance(obj, _PairwiseRank)
+                   else obj)
         mono = (tuple(int(v) for v in p.monotone_constraints)
                 if p.monotone_constraints else None)
         return (self.mesh, n_rounds, p.max_depth, p.n_bins,
@@ -2218,8 +2376,9 @@ class HistGBT(_ExternalMemoryEngine):
             """(blocks, rows a block) a shard's rows cut into in
             deterministic mode, else (0, 0).  Blocked mode needs every
             shard's rows to split into whole fixed-size blocks;
-            _pad_rows guarantees it for fit paths, the ranking regroup
-            (group-padded layout) falls back."""
+            _pad_rows guarantees it for fit paths; a ranking handle on a
+            mesh (a shard is whole queries) falls back where its rows do
+            not divide."""
             c_local = det_blocks // dsize if det_blocks else 0
             n_blk = c_local if c_local and n_local % c_local == 0 else 0
             return n_blk, (n_local // n_blk if n_blk else 0)
@@ -2689,13 +2848,16 @@ class HistGBT(_ExternalMemoryEngine):
 
         n_class = p.num_class
 
-        def round_body(bins_tl, y_l, w_l, preds_l, key=None):
+        ranking = plan.rank is not None
+
+        def round_body(bins_tl, y_l, w_l, preds_l, table_l=None, key=None):
             keep = feat_mask = None
             if sampling:
                 keep, feat_mask = sample_masks(key, y_l.shape)
             if n_class <= 1:
                 with jax.named_scope("dmlc.round.grad"):
-                    g, h = obj.grad_hess(preds_l, y_l)
+                    g, h = (obj.grad_hess(preds_l, y_l, table_l) if ranking
+                            else obj.grad_hess(preds_l, y_l))
                     g = g * w_l
                     h = h * w_l
                     if keep is not None:
@@ -2728,29 +2890,31 @@ class HistGBT(_ExternalMemoryEngine):
                 return preds_l + jnp.stack(deltas, axis=1), tree
 
         preds_spec = P("data", None) if n_class > 1 else P("data")
-        if sampling:
-            def k_rounds_body(bins_tl, y_l, w_l, preds_l, key):
+        # a ranking handle's group table, then the sampling key, follow
+        # the four arrays every fit has
+        table_specs = (obj.table_specs(),) if ranking else ()
+
+        def k_rounds_body(bins_tl, y_l, w_l, preds_l, *rest):
+            table_l = rest[0] if ranking else None
+            if sampling:
                 def step(carry, _):
                     preds_c, key_c = carry
                     key_c, key_r = jax.random.split(key_c)
                     preds2, tree = round_body(bins_tl, y_l, w_l, preds_c,
-                                              key_r)
+                                              table_l, key_r)
                     return (preds2, key_c), tree
 
                 (preds_out, _), trees = jax.lax.scan(
-                    step, (preds_l, key), None, length=n_rounds)
+                    step, (preds_l, rest[-1]), None, length=n_rounds)
                 return preds_out, trees
 
-            in_specs = (P(None, "data"), P("data"), P("data"), preds_spec,
-                        P())
-        else:
-            def k_rounds_body(bins_tl, y_l, w_l, preds_l):
-                def step(preds_c, _):
-                    return round_body(bins_tl, y_l, w_l, preds_c)
+            def step(preds_c, _):
+                return round_body(bins_tl, y_l, w_l, preds_c, table_l)
 
-                return jax.lax.scan(step, preds_l, None, length=n_rounds)
+            return jax.lax.scan(step, preds_l, None, length=n_rounds)
 
-            in_specs = (P(None, "data"), P("data"), P("data"), preds_spec)
+        in_specs = ((P(None, "data"), P("data"), P("data"), preds_spec)
+                    + table_specs + ((P(),) if sampling else ()))
 
         mapped = shard_map(
             k_rounds_body,
@@ -2924,8 +3088,8 @@ class HistGBT(_ExternalMemoryEngine):
         Available after :meth:`fit` and ``fit_external(cache_device=
         True)``; the page-loop external path keeps margins per page and
         clears this state (stale-evidence rule in fit_external).  After
-        a rank:pairwise fit, margins return in the ORIGINAL row order
-        (the padded-group layout is unwound); docs truncated by
+        a ``rank:*`` fit, margins return in the CALLER's row order (the
+        handle's query order is unwound); docs truncated by
         ``max_group_size`` get NaN."""
         CHECK(getattr(self, "_train_preds", None) is not None,
               "call fit first (train_margins is unavailable after a "

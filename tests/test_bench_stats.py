@@ -100,29 +100,28 @@ class TestPairwiseRankAutodiffOracle:
         from dmlc_core_tpu.models.histgbt import _PairwiseRank
 
         rng = np.random.default_rng(0)
-        G, Q = 5, 3
-        obj = _PairwiseRank(G, block_queries=2)  # exercises query padding
-        pred = jnp.asarray(rng.normal(size=Q * G).astype(np.float32))
-        rel = rng.integers(0, 3, size=Q * G).astype(np.float32)
-        rel[::7] = -1.0                          # pad docs must drop out
+        lens = np.array([5, 3, 9, 1, 4])         # ragged: three buckets' worth
+        n = int(lens.sum())
+        pred = jnp.asarray(rng.normal(size=n).astype(np.float32))
+        rel = rng.integers(0, 3, size=n).astype(np.float32)
+        # a budget of 128 pair slots a block exercises query padding
+        obj, table, _ = _PairwiseRank.from_queries([lens], [rel], n,
+                                                   pair_slots=128)
+        table = jax.tree.map(jnp.asarray, table)
         rel_j = jnp.asarray(rel)
+        bounds = np.r_[0, np.cumsum(lens)]
 
         def total_loss(s):
-            sq = s.reshape(Q, G)
-            rq = rel_j.reshape(Q, G)
             loss = 0.0
-            for q in range(Q):
-                for i in range(G):
-                    for j in range(G):
-                        better = ((rq[q, i] > rq[q, j])
-                                  & (rq[q, i] >= 0) & (rq[q, j] >= 0))
+            for lo, hi in zip(bounds[:-1], bounds[1:]):
+                for i in range(lo, hi):
+                    for j in range(lo, hi):
                         loss = loss + jnp.where(
-                            better,
-                            jnp.logaddexp(0.0, -(sq[q, i] - sq[q, j])),
-                            0.0)
+                            rel_j[i] > rel_j[j],
+                            jnp.logaddexp(0.0, -(s[i] - s[j])), 0.0)
             return loss
 
-        g, h = obj.grad_hess(pred, rel_j)
+        g, h = obj.grad_hess(pred, rel_j, table)
         g_ref = jax.grad(total_loss)(pred)
         np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref),
                                    rtol=1e-5, atol=1e-6)

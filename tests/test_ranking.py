@@ -1,11 +1,14 @@
-"""rank:pairwise objective + ranking metrics.
+"""rank:* objectives + ranking metrics.
 
 Oracles: a synthetic learning-to-rank problem with a known scoring
 function (pairwise accuracy and ndcg must rise well above chance);
 numpy metric cross-checks; 8-device-mesh vs 1-device equivalence (the
 shard-local-pairs design claim — groups never straddle shards, so the
 mesh trajectory must match single-device bit-for-bit up to f32 psum
-rounding); padding/truncation bookkeeping."""
+rounding); the ragged query-order layout of ``make_device_data(qid=)``
+(no query padded to another's length, none cut unless
+``max_group_size`` says so) and its width buckets against a plain
+per-query loop."""
 
 import numpy as np
 import pytest
@@ -169,52 +172,71 @@ def _brute_delta(scores, rel, kind):
     return out
 
 
+def _one_query_table(cls, rel):
+    """The objective and the device table of ONE query of ``rel``."""
+    import jax.numpy as jnp
+    rel = np.asarray(rel, np.float32)
+    obj, table, _ = cls.from_queries([np.array([len(rel)])], [rel],
+                                     len(rel))
+    return obj, jax.tree.map(jnp.asarray, table)
+
+
+def _pair_weights(obj, table, scores):
+    """``_pair_weight`` of the one bucket's one block, as [W, W] with
+    document i on axis 0."""
+    import jax.numpy as jnp
+    from dmlc_core_tpu.models.gbt_objectives import _QUERY_MAJOR
+    (b,) = obj.groups.buckets
+    s = np.zeros(b.width, np.float32)
+    s[:len(scores)] = scores
+    r = table["rel"][0][:1]
+    w = obj._pair_weight(_QUERY_MAJOR, jnp.asarray(s[None]), r, r >= 0,
+                         table["scale"][0][:1])
+    return np.asarray(w)[0]
+
+
 class TestLambdaWeights:
     """The LambdaMART pair weights must equal brute-force
     swap-and-rescore |Δmetric| — the closed forms have enough index
-    algebra (rank gathers, prefix sums, a/b selection) to deserve an
-    oracle."""
+    algebra (ranks by counting, prefix sums, a/b selection) to deserve
+    an oracle."""
 
     @pytest.mark.parametrize("kind", ["ndcg", "map"])
     def test_matches_brute_force(self, kind):
-        import jax.numpy as jnp
         from dmlc_core_tpu.models.gbt_objectives import (_MAPRank,
                                                          _NDCGRank)
         rng = np.random.default_rng(11)
         G = 9
-        obj = (_NDCGRank if kind == "ndcg" else _MAPRank)(G)
         for trial in range(5):
             scores = rng.normal(size=G).astype(np.float32)
             rel = rng.integers(0, 4, size=G).astype(np.float32)
             if kind == "map":
                 rel = (rel > 1).astype(np.float32)
-            sb = jnp.asarray(scores[None])
-            rb = jnp.asarray(rel[None])
-            better = (rb[:, :, None] > rb[:, None, :])
-            w = np.asarray(obj._pair_weight(sb, rb, better))[0]
+            obj, table = _one_query_table(
+                _NDCGRank if kind == "ndcg" else _MAPRank, rel)
+            w = _pair_weights(obj, table, scores)[:G, :G]
             brute = _brute_delta(scores, rel.astype(np.float64), kind)
             np.testing.assert_allclose(w, brute, rtol=2e-4, atol=1e-6)
 
     def test_pads_carry_zero_weight(self):
-        import jax.numpy as jnp
         from dmlc_core_tpu.models.gbt_objectives import _NDCGRank
-        # two pad docs (rel −1): weights involving them must be 0 and
-        # the real docs' weights must equal the pad-free computation at
-        # the same rank positions (pads rank last via the +inf key)
-        scores = np.array([0.3, -1.2, 2.0, 0.9, -0.5], np.float32)
-        rel = np.array([2.0, 0.0, 1.0, -1.0, -1.0], np.float32)
-        sb, rb = jnp.asarray(scores[None]), jnp.asarray(rel[None])
-        vb = rb >= 0
-        better = ((rb[:, :, None] > rb[:, None, :])
-                  & vb[:, :, None] & vb[:, None, :])
-        w = np.asarray(_NDCGRank(5)._pair_weight(sb, rb, better))[0]
-        w = w * np.asarray(better[0])        # weights are consumed masked
+        # a query of 3 in a bucket of 8: five pad slots (rel −1).  Weights
+        # involving them must be 0 once masked, and the real docs'
+        # weights must be those of the query alone — pads are ahead of
+        # no document, whatever their scores
+        scores = np.array([0.3, -1.2, 2.0], np.float32)
+        rel = np.array([2.0, 0.0, 1.0], np.float32)
+        obj, table = _one_query_table(_NDCGRank, rel)
+        assert obj.groups.buckets[0].width == 8
+        r = np.asarray(table["rel"][0][0])
+        better = (r[:, None] > r[None, :]) & (r >= 0)[:, None] \
+            & (r >= 0)[None, :]
+        loud = np.r_[scores, np.full(5, 9.0, np.float32)]
+        w = _pair_weights(obj, table, loud) * better
         assert (w[3:, :] == 0).all() and (w[:, 3:] == 0).all()
-        sb3, rb3 = jnp.asarray(scores[None, :3]), jnp.asarray(rel[None, :3])
-        b3 = (rb3[:, :, None] > rb3[:, None, :])
-        w3 = np.asarray(_NDCGRank(3)._pair_weight(sb3, rb3, b3))[0]
-        np.testing.assert_allclose(w[:3, :3], w3 * np.asarray(b3[0]),
-                                   rtol=1e-6)
+        brute = _brute_delta(scores, rel.astype(np.float64), "ndcg")
+        np.testing.assert_allclose(w[:3, :3], brute * better[:3, :3],
+                                   rtol=1e-5, atol=1e-7)
 
 
 def _graded_ltr_problem(n_queries=128, docs=30, F=6, seed=0):
@@ -277,3 +299,299 @@ class TestLambdaMARTObjectives:
         from dmlc_core_tpu.base.logging import Error
         with pytest.raises(Error, match="rank"):
             GBTRanker(objective="binary:logistic").fit(X, y, qid=qid)
+
+
+# -- the ragged layout and its width buckets (ISSUE 44) -------------------------------
+
+def _plain_grad_hess(scores, rel, kind):
+    """A copy of the plain per-query loop: every pair of ONE query with
+    rel_i > rel_j, p = sigmoid(s_j - s_i), w = |Δmetric| of the swap
+    (brute force) or 1, in float64."""
+    G = len(scores)
+    g, h = np.zeros(G), np.zeros(G)
+    w = (np.ones((G, G)) if kind == "pairwise"
+         else _brute_delta(scores, rel, kind))
+    for i in range(G):
+        for j in range(G):
+            if rel[i] > rel[j]:
+                p = 1.0 / (1.0 + np.exp(scores[i] - scores[j]))
+                g[i] -= p * w[i, j]
+                g[j] += p * w[i, j]
+                h[i] += p * (1 - p) * w[i, j]
+                h[j] += p * (1 - p) * w[i, j]
+    return g, np.maximum(h, 1e-16)
+
+
+_RAGGED_LENS = np.array([1, 2, 3, 5, 8, 9, 13, 16, 17, 24, 25, 31, 33, 37,
+                         1, 7, 37, 20, 4, 12])
+
+
+def _ragged_problem(seed=0, F=5):
+    rng = np.random.default_rng(seed)
+    n = int(_RAGGED_LENS.sum())
+    qid = np.repeat(np.arange(len(_RAGGED_LENS)), _RAGGED_LENS)
+    X = rng.normal(size=(n, F)).astype(np.float32)
+    y = np.clip(np.round(X[:, 0] + 0.7 * rng.normal(size=n) + 1.0), 0,
+                4).astype(np.float32)
+    return X, y, qid
+
+
+def _interleave(qid, rng):
+    """A row permutation that mixes the queries and keeps every query's
+    documents in their order."""
+    key = rng.random(len(qid))
+    for q in np.unique(qid):
+        key[qid == q] = np.sort(key[qid == q])
+    return np.argsort(key, kind="stable")
+
+
+def _same_trees(a, b):
+    return len(a) == len(b) and all(
+        np.array_equal(ta[k], tb[k]) for ta, tb in zip(a, b) for k in ta)
+
+
+_KINDS = {"rank:pairwise": "pairwise", "rank:ndcg": "ndcg",
+          "rank:map": "map"}
+
+
+class TestWidthBuckets:
+    @pytest.mark.parametrize("scores", ["all_ties", "random"])
+    @pytest.mark.parametrize("objective", sorted(_KINDS))
+    def test_bucketed_gradient_is_the_plain_loop(self, objective, scores):
+        """(a) sizes 1 to 37 fall into five buckets; the gradient taken in
+        them equals the per-query loop to float32 rounding, at the
+        all-ties round (ranks by the rule for ties alone) and at random
+        scores."""
+        import jax.numpy as jnp
+        from dmlc_core_tpu.models.gbt_objectives import OBJECTIVES
+        _, y, _ = _ragged_problem()
+        if objective == "rank:map":
+            y = (y > 1).astype(np.float32)
+        lens = _RAGGED_LENS
+        n = int(lens.sum())
+        obj, table, _ = OBJECTIVES[objective].from_queries([lens], [y], n)
+        assert len(obj.groups.buckets) == 5
+        assert obj.groups.pairs == int((lens ** 2).sum())
+        s = (np.full(n, 0.25, np.float32) if scores == "all_ties" else
+             np.random.default_rng(3).normal(size=n).astype(np.float32))
+        g, h = jax.jit(obj.grad_hess)(jnp.asarray(s), None,
+                                      jax.tree.map(jnp.asarray, table))
+        lo = 0
+        for G in lens:
+            gw, hw = _plain_grad_hess(s[lo:lo + G].astype(np.float64),
+                                      y[lo:lo + G].astype(np.float64),
+                                      _KINDS[objective])
+            np.testing.assert_allclose(np.asarray(g)[lo:lo + G], gw,
+                                       rtol=2e-4, atol=2e-6)
+            np.testing.assert_allclose(np.asarray(h)[lo:lo + G], hw,
+                                       rtol=2e-4, atol=2e-6)
+            lo += G
+
+    def test_one_document_and_one_level_queries_have_no_gradient(self):
+        """(d) no pair: g = 0, h = 1e-16."""
+        import jax.numpy as jnp
+        from dmlc_core_tpu.models.gbt_objectives import _NDCGRank
+        lens = np.array([1, 6, 1, 4])
+        y = np.array([3, 2, 2, 2, 2, 2, 2, 0, 1, 0, 2, 0], np.float32)
+        obj, table, _ = _NDCGRank.from_queries([lens], [y], len(y))
+        s = np.random.default_rng(0).normal(size=len(y)).astype(np.float32)
+        g, h = obj.grad_hess(jnp.asarray(s), None,
+                             jax.tree.map(jnp.asarray, table))
+        g, h = np.asarray(g), np.asarray(h)
+        assert (g[:8] == 0).all() and (h[:8] == np.float32(1e-16)).all()
+        assert (g[8:] != 0).any() and (h[8:] > 1e-16).any()
+
+    def test_ties_rank_by_position_in_the_query(self):
+        import jax.numpy as jnp
+        from dmlc_core_tpu.models.gbt_objectives import (_QUERY_MAJOR,
+                                                         _QUERY_MINOR,
+                                                         _ranks)
+        s = jnp.asarray(np.array([[1.0, 2.0, 1.0, 1.0, 9.0, 2.0]],
+                                 np.float32))
+        valid = jnp.asarray(np.array([[1, 1, 1, 1, 0, 1]], bool))
+        want = [2, 0, 3, 4, 5, 1]          # the pad is ahead of nothing
+        got = np.asarray(_ranks(_QUERY_MAJOR, s, valid))[0]
+        assert got[[0, 1, 2, 3, 5]].tolist() == [want[i] for i in
+                                                 (0, 1, 2, 3, 5)]
+        got_t = np.asarray(_ranks(_QUERY_MINOR, s.T, valid.T))[:, 0]
+        assert np.array_equal(got, got_t)
+
+    def test_the_ladder_is_half_octaves_in_whole_sublanes(self):
+        from dmlc_core_tpu.models.gbt_objectives import (rank_buckets,
+                                                         rank_width)
+        assert [rank_width(n) for n in (1, 8, 9, 17, 25, 33, 49, 65, 97,
+                                        129, 193, 1251, 2049)] == \
+            [8, 8, 16, 24, 32, 48, 64, 96, 128, 192, 256, 1536, 3072]
+        # the widest bucket stops at its longest query: 1,251 -> 1,280
+        groups, members = rank_buckets([np.array([5, 1251, 40, 1100])])
+        assert [b.width for b in groups.buckets] == [8, 48, 1280]
+        assert [len(m[0]) for m in members] == [1, 1, 2]
+        # blocks hold a budget of pair SLOTS, not of queries
+        for b in groups.buckets:
+            assert b.queries % b.block == 0
+            assert b.block * b.width ** 2 <= max(1 << 22,
+                                                 128 * b.width ** 2)
+        assert groups.pairs == 5 ** 2 + 1251 ** 2 + 40 ** 2 + 1100 ** 2
+
+
+class TestRankingHandle:
+    @pytest.mark.parametrize("objective", ["rank:ndcg", "rank:pairwise"])
+    def test_fit_device_on_a_qid_handle_is_fit(self, objective):
+        """(b) ``make_device_data(qid=)`` + ``fit_device`` gives
+        byte-identical trees to ``fit(qid=)``; interleaving the queries'
+        rows in the input changes no tree."""
+        X, y, qid = _ragged_problem()
+        kw = dict(n_trees=5, max_depth=3, n_bins=16, objective=objective,
+                  mesh=local_mesh(1))
+        a = HistGBT(**kw)
+        a.fit(X, y, qid=qid)
+        b = HistGBT(**kw)
+        handle = b.make_device_data(X, y, qid=qid)
+        b.fit_device(handle)
+        assert _same_trees(a.trees, b.trees)
+        b.fit_device(handle)                         # and again
+        assert _same_trees(a.trees, b.trees)
+        perm = _interleave(qid, np.random.default_rng(5))
+        assert (np.diff(qid[perm]) < 0).any()
+        c = HistGBT(**kw)
+        c.fit_device(c.make_device_data(X[perm], y[perm], qid=qid[perm]))
+        assert _same_trees(a.trees, c.trees)
+        # margins and predictions answer in the CALLER's row order
+        np.testing.assert_allclose(c.train_margins(),
+                                   a.train_margins()[perm], rtol=1e-6)
+        np.testing.assert_allclose(
+            c.train_margins(), c.predict(X[perm], output_margin=True),
+            rtol=1e-4, atol=1e-5)
+
+    def test_a_long_query_is_neither_cut_nor_the_width_of_all(self):
+        """(c) one query of 150 among queries of 1 to 37: the handle's
+        rows are the data's, every document has a margin, and the pair
+        slots are nowhere near queries x 150^2."""
+        rng = np.random.default_rng(2)
+        lens = np.r_[_RAGGED_LENS, 150]
+        n = int(lens.sum())
+        qid = np.repeat(np.arange(len(lens)), lens)
+        X = rng.normal(size=(n, 4)).astype(np.float32)
+        y = rng.integers(0, 3, n).astype(np.float32)
+        m = HistGBT(n_trees=2, max_depth=2, n_bins=16, objective="rank:ndcg",
+                    mesh=local_mesh(1))
+        handle = m.make_device_data(X, y, qid=qid)
+        assert handle["n_padded"] == handle["n"] == n
+        assert handle["bins_t"].shape == (4, n)
+        m.fit_device(handle)
+        assert not np.isnan(m.train_margins()).any()
+        plan = m.round_plan
+        assert plan["rank_buckets"][-1][0] == 192
+        assert plan["rank_pairs"] == int((lens ** 2).sum())
+        assert plan["rank_pair_slots"] < len(lens) * 150 ** 2 / 2
+        # on four chips a shard is whole queries; the pad rows are at
+        # most what evening the shards out takes
+        m4 = HistGBT(n_trees=2, max_depth=2, n_bins=16,
+                     objective="rank:ndcg", mesh=local_mesh(4))
+        h4 = m4.make_device_data(X, y, qid=qid)
+        assert h4["n"] == n and h4["n_padded"] - n <= 4 * 150
+        assert int(np.asarray(h4["w_d"]).sum()) == n
+
+    def test_mesh_trees_equal_one_device(self):
+        """(e) shard boundaries fall on query boundaries and the gradient
+        is shard-local: four devices grow the one-device trees."""
+        X, y, qid = _ragged_problem(seed=4)
+        kw = dict(n_trees=4, max_depth=3, n_bins=16, objective="rank:ndcg")
+        m1 = HistGBT(mesh=local_mesh(1), **kw)
+        m1.fit_device(m1.make_device_data(X, y, qid=qid))
+        m4 = HistGBT(mesh=local_mesh(4), **kw)
+        m4.fit_device(m4.make_device_data(X, y, qid=qid))
+        for t1, t4 in zip(m1.trees, m4.trees):
+            np.testing.assert_array_equal(t1["feat"], t4["feat"])
+            np.testing.assert_array_equal(t1["thr"], t4["thr"])
+            np.testing.assert_allclose(t1["leaf"], t4["leaf"], rtol=1e-4,
+                                       atol=1e-6)
+        np.testing.assert_allclose(m4.train_margins(), m1.train_margins(),
+                                   atol=1e-5)
+
+    def test_resume_continues_on_a_ranking_handle(self):
+        """(f) three rounds, then three more with ``resume=True``, are
+        six rounds."""
+        X, y, qid = _ragged_problem(seed=6)
+        kw = dict(max_depth=3, n_bins=16, objective="rank:ndcg",
+                  mesh=local_mesh(1))
+        whole = HistGBT(n_trees=6, **kw)
+        whole.fit_device(whole.make_device_data(X, y, qid=qid))
+        legs = HistGBT(n_trees=3, **kw)
+        handle = legs.make_device_data(X, y, qid=qid)
+        legs.fit_device(handle)
+        legs.fit_device(handle, resume=True)
+        assert len(legs.trees) == 6
+        for tw, tl in zip(whole.trees, legs.trees):
+            np.testing.assert_array_equal(tw["feat"], tl["feat"])
+            np.testing.assert_allclose(tw["leaf"], tl["leaf"], rtol=1e-5,
+                                       atol=1e-7)
+
+    def test_a_continued_fit_is_no_longer_refused(self):
+        """``fit(qid=)`` on a model that has trees continues from them
+        (the layout is the handle's, not the fit's)."""
+        X, y, qid = _ragged_problem(seed=7)
+        m = HistGBT(n_trees=3, max_depth=3, n_bins=16, objective="rank:ndcg",
+                    mesh=local_mesh(1))
+        m.fit(X, y, qid=qid)
+        first = [dict(t) for t in m.trees]
+        before = ndcg(y, m.predict(X), qid, k=5)
+        m.fit(X, y, qid=qid)
+        assert len(m.trees) == 6 and _same_trees(first, m.trees[:3])
+        assert ndcg(y, m.predict(X), qid, k=5) >= before - 1e-9
+
+    def test_the_training_log_reads_the_pairwise_loss(self):
+        """``eval_every`` on a ranking fit: the mean pairwise logistic
+        loss over the handle's group table equals the plain one."""
+        X, y, qid = _ragged_problem(seed=8)
+        m = HistGBT(n_trees=2, max_depth=2, n_bins=16,
+                    objective="rank:pairwise", mesh=local_mesh(4))
+        handle = m.make_device_data(X, y, qid=qid)
+        m.fit_device(handle)
+        got = float(m._rank_loss(m._train_preds, handle["rank"]))
+        s = m.train_margins().astype(np.float64)
+        loss = pairs = 0
+        for q in np.unique(qid):
+            sq, rq = s[qid == q], y[qid == q]
+            better = rq[:, None] > rq[None, :]
+            loss += np.logaddexp(0.0, -(sq[:, None] - sq[None, :]))[
+                better].sum()
+            pairs += better.sum()
+        assert got == pytest.approx(loss / pairs, rel=1e-5)
+        m2 = HistGBT(n_trees=2, max_depth=2, n_bins=16,
+                     objective="rank:pairwise", mesh=local_mesh(4))
+        m2.fit(X, y, qid=qid, eval_every=1)          # logs, does not raise
+
+    def test_handles_and_objectives_must_match(self):
+        from dmlc_core_tpu.base.logging import Error
+        X, y, qid = _ragged_problem()
+        with pytest.raises(Error, match="needs qid"):
+            HistGBT(objective="rank:ndcg").make_device_data(X, y)
+        with pytest.raises(Error, match="only valid for rank"):
+            HistGBT().make_device_data(X, y, qid=qid)
+        plain = HistGBT(n_trees=1, max_depth=2, n_bins=16)
+        handle = plain.make_device_data(X, (y > 1).astype(np.float32))
+        ranker = HistGBT(n_trees=1, max_depth=2, n_bins=16,
+                         objective="rank:ndcg")
+        with pytest.raises(Error, match="no group table"):
+            ranker.fit_device(handle)
+        with pytest.raises(Error, match="labels must be >= 0"):
+            ranker.make_device_data(X, y - 1.0, qid=qid)
+
+    def test_no_warmup_before_the_queries_are_known(self):
+        """The width buckets shape the round program, so it cannot be
+        compiled before ``make_device_data(qid=)`` has seen the queries."""
+        assert HistGBT(objective="rank:ndcg").start_warmup(1000, 8) is False
+
+    def test_the_regroup_span_and_the_counter(self):
+        from dmlc_core_tpu.base import metrics
+        from dmlc_core_tpu.utils import profiler
+        if not metrics.enabled():
+            pytest.skip("metrics layer off")
+        X, y, qid = _ragged_problem()
+        m = HistGBT(n_trees=1, max_depth=2, n_bins=16, objective="rank:ndcg",
+                    mesh=local_mesh(1))
+        m.make_device_data(X, y, qid=qid)
+        rec = [r for r in profiler.op_log() if r["name"] == "dmlc.ingest"][-1]
+        assert "dmlc.ingest.host_prep.regroup" in rec["children"]
+        assert rec["children"]["dmlc.ingest.host_prep.regroup"][0] == 1
