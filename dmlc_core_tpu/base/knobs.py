@@ -200,12 +200,14 @@ declare("DMLC_FUSED_ROUND", "auto",
         "(depthwise) or expansion (lossguide) doing bin-read, node "
         "descend, g/h accumulation and sibling subtraction with the "
         "node histograms VMEM-resident — no HBM round-trip between "
-        "phases.  'auto' engages on TPU at eligible shapes "
+        "phases.  '1' plans it wherever it can run at all "
         "(single-chip, no DMLC_HIST_BLOCKS, no missing values, pallas "
-        "hist_method), '1' forces it everywhere (interpret mode "
-        "off-TPU — the byte-parity test hook), '0' pins the "
-        "three-dispatch path; save_model bytes identical either "
-        "way.", "gbt")
+        "hist_method; interpret mode off-TPU — the byte-parity test "
+        "hook); 'auto' and '0' plan the staged round (descend, "
+        "build, sync, subtract) at every shape: on the chip the "
+        "staged kernels build the flagship's six levels in 224 ms "
+        "where the fused ones took 245 (PERF.md section 6, PR 45); "
+        "save_model bytes identical either way.", "gbt")
 declare("DMLC_FEATURE_BUNDLE", "0",
         "1 fuses mutually-exclusive (near-one-hot) feature blocks into "
         "one multi-bin storage feature (LightGBM's EFB with the "

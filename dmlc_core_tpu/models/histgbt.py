@@ -55,7 +55,7 @@ from dmlc_core_tpu.data.iter import slab_shard_slices
 from dmlc_core_tpu.ops import binlayout as _bl
 from dmlc_core_tpu.ops.histogram import (build_histogram,
                                          descend_histogram,
-                                         fused_round, fused_round_ok,
+                                         fused_round,
                                          hist_feature_blocks,
                                          hist_feature_dots,
                                          hist_node_blocks,
@@ -184,12 +184,12 @@ def _feature_bundle_requested() -> bool:
 
 
 def _fused_round_mode() -> str:
-    """``DMLC_FUSED_ROUND``: the fully-fused Pallas round kernel
-    (ops.histogram.fused_round — one program per level/expansion doing
-    bin-read → descend → accumulate → sibling subtraction in VMEM).
-    ``auto`` (default) turns it on for TPU backends at eligible shapes;
-    ``1`` forces it everywhere (interpret mode off-TPU — the parity-test
-    hook); ``0`` pins the three-dispatch path."""
+    """``DMLC_FUSED_ROUND``: ``1`` plans the fully-fused Pallas round
+    kernel (ops.histogram.fused_round — one program per level/expansion
+    doing bin-read → descend → accumulate → sibling subtraction in VMEM;
+    interpret mode off-TPU — the byte-parity tests' hook).  ``auto``
+    (default) and ``0`` plan the staged round at every shape: on the
+    chip it is the faster of the two (PERF.md section 6, PR 45)."""
     v = os.environ.get("DMLC_FUSED_ROUND", "auto")
     CHECK(v in ("auto", "0", "1"),
           f"DMLC_FUSED_ROUND must be 'auto', '0' or '1', got {v!r}")
@@ -2167,14 +2167,14 @@ class HistGBT(_ExternalMemoryEngine):
         again from the same shapes.
         ``fused_round`` says the levels below the root run
         :func:`~dmlc_core_tpu.ops.histogram.fused_round` (a Pallas
-        kernel, so those levels read ``pallas``).  "auto" engages it on
-        a TPU backend at shapes inside the kernel's VMEM budget, asked
-        ONCE for the deepest level's parents (the fused kernel is not
-        built in blocks: at ``max_depth`` >= 8 the whole round is the
-        staged one, which on the chip reads no slower per level —
-        PERF.md section 6, PR 37); ``DMLC_FUSED_ROUND=1`` wherever it is
-        eligible at all (interpreted off-TPU — the byte-parity test
-        hook).  The fused subtraction consumes ALREADY-synced parent
+        kernel, so those levels read ``pallas``): under
+        ``DMLC_FUSED_ROUND=1`` alone (interpreted off-TPU — the
+        byte-parity test hook).  "auto" plans the STAGED round at every
+        shape since PR 45: on the chip the staged ``dmlc_hist`` kernels
+        build the flagship's six levels in 224 ms where
+        ``dmlc_fused_round`` took 245 on the same rows, ~4 ms a level
+        at any width or depth read (PERF.md section 6, PR 45).
+        The fused subtraction consumes ALREADY-synced parent
         histograms, so it needs the trivial one-chip sync: multi-chip
         meshes, the deterministic block fold and the learned-missing
         descend run the staged descend + build + subtract — byte parity
@@ -2188,16 +2188,16 @@ class HistGBT(_ExternalMemoryEngine):
         det_blocks = _hist_blocks(dsize)
         sync_bins = layout.sync_bins if layout is not None else p.n_bins
         mat_rows = layout.phys_rows if layout is not None else n_features
-        fr_mode = _fused_round_mode()
-        fused = (not self._missing and dsize == 1 and det_blocks == 0
-                 and p.hist_method in ("auto", "pallas")
-                 and (fr_mode == "1"
-                      or (fr_mode == "auto"
-                          and jax.default_backend() == "tpu"
-                          and fused_round_ok(
-                              sync_bins, mat_rows,
-                              max(1 << max(depth - 2, 0), 1),
-                              with_layout=layout is not None))))
+        # "auto" is the staged round (the docstring has the A/B).  The
+        # fused kernel runs where it is asked for by name, at any shape
+        # its other conditions admit: whether it fits VMEM there is the
+        # asker's to know (ops.histogram.fused_round_ok)
+        fused = (
+            _fused_round_mode() == "1"
+            and not self._missing
+            and dsize == 1
+            and det_blocks == 0
+            and p.hist_method in ("auto", "pallas"))
         lossguide = _grow_policy() == "lossguide"
         builds = [1] if lossguide else (
             [1] + [1 << (lv - 1) for lv in range(1, depth)])

@@ -137,14 +137,14 @@ def bins_bytes_per_round(depth: int, rows: int, row_bytes: int, *,
     """Bin-matrix HBM bytes ONE boosting round streams: the number of
     full passes over the ``[phys_rows, n]`` matrix times its size.
 
-    Unfused depth-wise: level 0 is a histogram-only pass, every deeper
-    level pays a descend pass plus a histogram pass, and the final leaf
-    assignment is one more descend — ``2·depth − 1`` passes.  The fused
-    round kernel (``DMLC_FUSED_ROUND``) collapses each level's descend +
-    histogram + subtraction into ONE read of the bin tile, so the bill
-    drops to ``depth`` passes (root build, ``depth − 2`` fused levels,
-    final descend).  Loss-guide: one pass per expansion plus the
-    root/final passes — ``2·leaves − 1`` unfused, ``leaves`` fused.
+    Staged depth-wise (what ``DMLC_FUSED_ROUND=auto`` plans): level 0 a
+    histogram pass, every deeper level a descend pass plus a histogram
+    pass, the final leaf assignment one more descend — ``2·depth − 1``
+    passes.  The fused round kernel (``=1`` alone) reads a level's bin
+    tile ONCE: ``depth`` passes.  Fewer bytes, and slower on the chip,
+    where the round is bound by its dots: 245 ms of kernels a flagship
+    round against the staged round's 224 (PERF.md section 6, PR 45).
+    Loss-guide: ``2·leaves − 1`` passes staged, ``leaves`` fused.
     Not counted: a level built in k NODE blocks
     (:func:`hist_node_blocks`; ``max_depth`` >= 8 at 256 bins) reads
     the matrix k times for its histogram, not once.
@@ -789,9 +789,9 @@ def _fused_round_kernel(*refs, n_prev, hi, lo, n_rows, n_pack_groups,
 def fused_round_ok(n_bins: int, n_features: int, n_prev: int = 1,
                    bins_itemsize: int = 1, tile_rows: int = 0,
                    with_layout: bool = False) -> bool:
-    """Eligibility of the fused ROUND kernel (cf. :func:`_pallas_ok`;
-    this one is a yes or no — the fused kernel is not built in blocks):
-    it holds THREE accumulator-shaped slabs in VMEM (prev, left, right)
+    """Whether the fused ROUND kernel fits VMEM (a yes or no: it is not
+    built in blocks; no plan asks since PR 45, ``auto`` being the staged
+    round): it holds THREE accumulator-shaped slabs (prev, left, right)
     instead of one, and the layout mode streams five extra [1, T] int32
     decode vectors plus the [16, T] compact-remap table per tile."""
     lo = _lo_factor(n_prev, n_bins)
@@ -1062,8 +1062,8 @@ def descend_histogram(
     descend (:func:`select_feature_bins`), then :func:`build_histogram`.
     Returns ``(left_hist, new_node)`` with ``left_hist[_, p]`` the
     histogram of parent p's left child (node 2p) — the caller derives
-    the right child by sibling subtraction.  The staged level of the
-    round program wherever :func:`fused_round` is not engaged.
+    the right child by sibling subtraction.  The level of the round
+    program at every shape, but under ``DMLC_FUSED_ROUND=1``.
     Replaces rabit's per-level hist allreduce prep (SURVEY.md §2e
     data-parallel row)."""
     valid = node_id >= 0

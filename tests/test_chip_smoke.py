@@ -27,8 +27,10 @@ from dmlc_core_tpu.ops.histogram import (_pallas_ok,  # noqa: E402
 from dmlc_core_tpu.parallel.mesh import local_mesh  # noqa: E402
 
 #: the flagship's shape of run, cut to what the interpreter can carry:
-#: explicit pallas + DMLC_FUSED_ROUND=1 stand in for what "auto" picks
-#: on the chip, so the same kernels (and the same checks) are live
+#: explicit pallas stands in for what "auto" picks on the chip, so the
+#: same kernels (and the same checks) are live; DMLC_FUSED_ROUND=1
+#: below drives the fused round through the smoke too ("auto" plans the
+#: staged one since PR 45)
 TINY = chip_smoke.SmokeConfig(
     rows=4096, features=8, n_trees=4, max_depth=3, n_bins=32,
     holdout_rows=1200, hist_method="pallas",

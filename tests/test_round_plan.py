@@ -109,6 +109,40 @@ def test_cache_key_follows_the_plan(lever, monkeypatch):
     assert a._build_round_fn(plan_a, 2) is fn
 
 
+@pytest.mark.parametrize("n_features", [28, 136])
+@pytest.mark.parametrize("depth", [2, 3, 4, 5, 6, 7])
+def test_auto_plans_the_staged_round_on_a_tpu(depth, n_features,
+                                              monkeypatch):
+    """With no ``DMLC_*`` variable set, a one-chip dense fit on a TPU
+    plans the staged round — ``dmlc_hist`` at every level — at every
+    shape the fused kernel used to take (HIGGS's and MSLR's width, any
+    depth up to 7): on the chip it is the faster one (PERF.md section
+    6, PR 45).  ``DMLC_FUSED_ROUND=1`` still plans the fused round: the
+    byte-parity tests' hook."""
+    for name in [k for k in os.environ if k.startswith("DMLC_")]:
+        monkeypatch.delenv(name)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def resolve():
+        m = HistGBT(mesh=local_mesh(1), n_trees=2, max_depth=depth,
+                    n_bins=256)
+        return m._round_plan(n_features), m.round_plan
+
+    plan, record = resolve()
+    assert plan.fused_round is False and record["fused_round"] is False
+    assert record["hist_method"] == ["pallas"] * depth
+    builds = [1] + [1 << (lv - 1) for lv in range(1, depth)]
+    assert record["hist_node_blocks"] == [[nb] for nb in builds]
+    assert record["hist_feature_blocks"] == [[n_features]] * depth
+    # "0" is the same plan, so the same cached program
+    monkeypatch.setenv("DMLC_FUSED_ROUND", "0")
+    assert resolve()[0] == plan
+    monkeypatch.setenv("DMLC_FUSED_ROUND", "1")
+    asked, record = resolve()
+    assert asked.fused_round is True and record["fused_round"] is True
+    assert record["hist_method"] == ["pallas"] * depth
+
+
 class _NoLevers(dict):
     """``os.environ`` with every ``DMLC_*`` name unreadable."""
 
