@@ -65,6 +65,7 @@ from dmlc_core_tpu.ops.histogram import (build_histogram,
                                          select_feature_bins)
 from dmlc_core_tpu.ops.quantile import (apply_bins, apply_bins_missing,
                                         apply_bins_t, compute_cuts)
+from dmlc_core_tpu.ops.table_select import table_select
 from dmlc_core_tpu.parallel.mesh import device_count, local_mesh
 from dmlc_core_tpu.models.gbt_objectives import (  # noqa: F401  (re-exports:
     # scripts/tests import these via models.histgbt — keep the names)
@@ -2362,15 +2363,6 @@ class HistGBT(_ExternalMemoryEngine):
                 level_np[i] = lvl
                 # leaf position = leftmost depth-level descendant
                 pos_np[i] = (i - (1 << lvl)) << (depth - lvl)
-
-        def table_select(table, node, n_entries):
-            """Gather-free ``table[node]`` for a tiny per-node table: a
-            compare-and-sum over the (≤2^depth) entries.  TPU gathers over
-            row-indexed tables serialize badly; a [n, N] broadcast-compare
-            fuses into one VPU loop."""
-            n_iota = jnp.arange(n_entries, dtype=jnp.int32)[None, :]
-            oh = (node[:, None] == n_iota)
-            return jnp.sum(jnp.where(oh, table[None, :], 0), axis=1)
 
         def row_blocks(n_local):
             """(blocks, rows a block) a shard's rows cut into in
