@@ -323,7 +323,7 @@ def _install_guards(deadline):
 
 def _derived_metrics(rows, feats, depth, n_bins, seconds_per_round, peak,
                      n_chips=1, layout=None, grow_policy="depthwise",
-                     max_leaves=0, fused=False):
+                     max_leaves=0):
     """Auditable per-round cost model of the sibling-subtracted round.
 
     MXU flops: per level ℓ the Pallas histogram dot is [A, T]·[T, lo]
@@ -338,9 +338,7 @@ def _derived_metrics(rows, feats, depth, n_bins, seconds_per_round, peak,
     (the rabit-allreduce replacement).  The ``kernel`` block is the
     ISSUE 12 lever evidence: bin-matrix bytes one round's passes pull
     from HBM, and how many node histograms the round actually builds
-    (loss-guide builds ``max_leaves`` instead of ``2^(depth-1)``).
-    ``fused`` is the ISSUE 18 lever: the fused round kernel halves the
-    bin-matrix passes (descend rides the histogram read)."""
+    (loss-guide builds ``max_leaves`` instead of ``2^(depth-1)``)."""
     from dmlc_core_tpu.ops.histogram import (_lo_factor,
                                              bins_bytes_per_round,
                                              hist_psum_bytes_per_round,
@@ -366,7 +364,7 @@ def _derived_metrics(rows, feats, depth, n_bins, seconds_per_round, peak,
     leaves_built = leaves_built_per_round(depth, grow_policy, max_leaves)
     bins_bytes = bins_bytes_per_round(
         depth, rows, row_bytes, grow_policy=grow_policy,
-        max_leaves=max_leaves, fused=fused)
+        max_leaves=max_leaves)
     hbm = bins_bytes + 6 * rows * 4       # + g/h/preds/update f32 vectors
     mfu = (mxu_flops / seconds_per_round / peak) if peak else None
     return {
@@ -383,7 +381,6 @@ def _derived_metrics(rows, feats, depth, n_bins, seconds_per_round, peak,
             "bin_layout": (None if layout is None else
                            f"{layout.n_features}F->{layout.phys_rows}rows"
                            f"/{len(layout.pairs)}pairs"),
-            "fused_round": fused,
         },
     }
 
@@ -2073,8 +2070,6 @@ def main() -> None:
                         "grow_policy": os.environ["DMLC_GROW_POLICY"],
                         "max_leaves":
                             int(os.environ["DMLC_MAX_LEAVES"] or 0),
-                        "fused_round":
-                            os.environ.get("DMLC_FUSED_ROUND", "auto"),
                     }}
 
     # chips=N mode (ISSUE 7): BENCH_CHIPS pins the data-mesh width (0 /
@@ -2242,8 +2237,7 @@ def main() -> None:
         1.0 / (value * n_chips), peak, n_chips,
         layout=model._bin_layout,
         grow_policy=model.round_plan["grow_policy"],
-        max_leaves=int(os.environ.get("DMLC_MAX_LEAVES", "0") or 0),
-        fused=model.round_plan["fused_round"]))
+        max_leaves=int(os.environ.get("DMLC_MAX_LEAVES", "0") or 0)))
     official["round_plan"] = model.round_plan
     EV["official"] = official
     EV["runs"] = runs

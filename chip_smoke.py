@@ -82,7 +82,6 @@ class SmokeConfig:
     serve_sizes: Tuple[int, ...] = (1, 8, 9, 100, 1000, 1024, 2500)
     rollcall_rows: int = 1_000_000
     rollcall_nodes: Tuple[int, ...] = (1, 2, 4, 8, 16, 32)
-    rollcall_prev: Tuple[int, ...] = (1, 2, 4, 8, 16)
     tile_rows: int = 0                      # 0 = the library's _TILE_ROWS
     det_rows: int = 1_000_000               # DMLC_HIST_BLOCKS parity size
     det_trees: int = 10
@@ -210,8 +209,6 @@ def fit_phase(sm: Smoke, cfg: SmokeConfig, mesh, X, y, Xh, yh, tag: str,
     sm.check(all(m == "pallas" for m in plan["hist_method"]),
              f"{tag}: histogram method per level {plan['hist_method']} — "
              f"want pallas at every level")
-    sm.check(not plan["fused_round"] or plan["mesh_devices"] == 1,
-             f"{tag}: fused round on a {plan['mesh_devices']}-device mesh")
     if cfg.require_tpu:
         sm.check(plan["pallas_interpret"] is False,
                  f"{tag}: Pallas kernels were INTERPRETED, not compiled")
@@ -300,9 +297,7 @@ def _node_ids(rng, n, n_nodes):
 
 def rollcall_phase(sm: Smoke, cfg: SmokeConfig) -> Dict[str, Any]:
     """Every Pallas kernel of ops/histogram.py at the run's widths vs
-    the segment engine: ``_hist_pallas`` plain and int4-packed,
-    ``fused_round`` plain and with the layout."""
-    import jax
+    the segment engine: ``_hist_pallas`` plain and int4-packed."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -374,41 +369,6 @@ def rollcall_phase(sm: Smoke, cfg: SmokeConfig) -> Dict[str, Any]:
         case(f"_hist_pallas[n_nodes={nn}]", hist_plain)
         case(f"_hist_pallas+int4[n_nodes={nn}]", hist_packed)
 
-    for n_prev in cfg.rollcall_prev:
-        nid = _node_ids(rng, n, n_prev)
-        nid_d = jnp.asarray(nid)
-        safe = np.where(nid >= 0, nid, 0)
-        feat_sel = rng.integers(0, F, n_prev).astype(np.int32)[safe]
-        thr_sel = rng.integers(0, B, n_prev).astype(np.int32)[safe]
-        fs_d, ts_d = jnp.asarray(feat_sel), jnp.asarray(thr_sel)
-
-        def staged(bins_d, lay):
-            """descend + left build + parent − left by the XLA path."""
-            row_bin = np.asarray(H.select_feature_bins(bins_d, fs_d,
-                                                       layout=lay))
-            new = np.where(nid >= 0, 2 * nid + (row_bin > thr_sel), -1)
-            node_h = jnp.asarray(np.where(
-                (nid >= 0) & (new % 2 == 0), new >> 1, -1).astype(np.int32))
-            build = (lambda i: H.build_histogram(
-                bins_d, i, g_d, h_d, n_prev, B, "segment",
-                transposed=True, layout=lay))
-            prev, left = build(nid_d), build(node_h)
-            both = jnp.stack([left, prev - left], axis=2).reshape(
-                2, 2 * n_prev, left.shape[2], left.shape[3])
-            return new, prev, np.asarray(both)
-
-        def fused(bins_d, lay):
-            new, prev, both = staged(bins_d, lay)
-            fn = jax.jit(lambda b, i, f, t, gg, hh, pv: H.fused_round(
-                b, i, f, t, gg, hh, pv, n_prev, B, tile_rows=T,
-                layout=lay)[:2])
-            new_f, hist_f = fn(bins_d, nid_d, fs_d, ts_d, g_d, h_d, prev)
-            return (np.array_equal(np.asarray(new_f), new)
-                    and np.array_equal(np.asarray(hist_f), both))
-
-        case(f"fused_round[n_prev={n_prev}]", lambda: fused(plain_d, None))
-        case(f"fused_round+layout[n_prev={n_prev}]",
-             lambda: fused(phys, layout))
     rep["all_ok"] = all(k["ok"] for k in rep["kernels"].values())
     return rep
 

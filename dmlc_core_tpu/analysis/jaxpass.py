@@ -72,7 +72,7 @@ EXPLAIN = {
             "\n"
             "def _cache_key(self):\n"
             "    return (self.depth,\n"
-            "            os.environ.get('DMLC_FUSED_ROUND', 'auto'))\n"),
+            "            os.environ.get('DMLC_HIST_BLOCKS', '0'))\n"),
         "clean": (
             "def __init__(self):\n"
             "    self._kernel_jit = jax.jit(self._kernel)  # built once\n"
@@ -81,7 +81,7 @@ EXPLAIN = {
             "    return self._kernel_jit(x)\n"
             "\n"
             "def _cache_key(self):\n"
-            "    return (self.depth, knobs.value('DMLC_FUSED_ROUND'))\n"),
+            "    return (self.depth, knobs.value('DMLC_HIST_BLOCKS'))\n"),
     },
     "donation-discipline": {
         "doc": "Donated buffers are freed for reuse by XLA the moment "

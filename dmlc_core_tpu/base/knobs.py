@@ -195,19 +195,6 @@ declare("DMLC_BIN_PACK", "0",
         "compact-remapped and nibble-paired, shrinking the HBM bin "
         "traffic every histogram pass pays; split decisions and "
         "save_model bytes are bit-identical.", "gbt")
-declare("DMLC_FUSED_ROUND", "auto",
-        "Fully-fused Pallas round kernel: ONE program per level "
-        "(depthwise) or expansion (lossguide) doing bin-read, node "
-        "descend, g/h accumulation and sibling subtraction with the "
-        "node histograms VMEM-resident — no HBM round-trip between "
-        "phases.  '1' plans it wherever it can run at all "
-        "(single-chip, no DMLC_HIST_BLOCKS, no missing values, pallas "
-        "hist_method; interpret mode off-TPU — the byte-parity test "
-        "hook); 'auto' and '0' plan the staged round (descend, "
-        "build, sync, subtract) at every shape: on the chip the "
-        "staged kernels build the flagship's six levels in 224 ms "
-        "where the fused ones took 245 (PERF.md section 6, PR 45); "
-        "save_model bytes identical either way.", "gbt")
 declare("DMLC_FEATURE_BUNDLE", "0",
         "1 fuses mutually-exclusive (near-one-hot) feature blocks into "
         "one multi-bin storage feature (LightGBM's EFB with the "

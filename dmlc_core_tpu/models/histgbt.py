@@ -55,7 +55,6 @@ from dmlc_core_tpu.data.iter import slab_shard_slices
 from dmlc_core_tpu.ops import binlayout as _bl
 from dmlc_core_tpu.ops.histogram import (build_histogram,
                                          descend_histogram,
-                                         fused_round,
                                          hist_feature_blocks,
                                          hist_feature_dots,
                                          hist_node_blocks,
@@ -184,19 +183,6 @@ def _feature_bundle_requested() -> bool:
     return os.environ.get("DMLC_FEATURE_BUNDLE", "0") == "1"
 
 
-def _fused_round_mode() -> str:
-    """``DMLC_FUSED_ROUND``: ``1`` plans the fully-fused Pallas round
-    kernel (ops.histogram.fused_round — one program per level/expansion
-    doing bin-read → descend → accumulate → sibling subtraction in VMEM;
-    interpret mode off-TPU — the byte-parity tests' hook).  ``auto``
-    (default) and ``0`` plan the staged round at every shape: on the
-    chip it is the faster of the two (PERF.md section 6, PR 45)."""
-    v = os.environ.get("DMLC_FUSED_ROUND", "auto")
-    CHECK(v in ("auto", "0", "1"),
-          f"DMLC_FUSED_ROUND must be 'auto', '0' or '1', got {v!r}")
-    return v
-
-
 class _RoundPlan(NamedTuple):
     """Every choice the traced round program bakes in beyond the
     ``Parameter``'s fields and the mesh, as :meth:`HistGBT._round_plan`
@@ -215,7 +201,6 @@ class _RoundPlan(NamedTuple):
     #: level up to depth 7 at 256 bins); the feature blocks above are
     #: those of a node block's calls
     hist_node_blocks: Tuple[Tuple[int, ...], ...]
-    fused_round: bool
     #: NaN is missing: the reserved bin, the two-direction split scan and
     #: the direction descend (``HistGBT._settle_missing_mode`` decided)
     missing: bool
@@ -235,7 +220,6 @@ class _RoundPlan(NamedTuple):
         lay = self.layout
         return {
             "hist_method": list(self.hist_method),
-            "fused_round": self.fused_round,
             "missing": self.missing,
             "pallas_interpret": self.pallas_interpret,
             "grow_policy": self.grow_policy,
@@ -575,7 +559,7 @@ class HistGBTParam(Parameter):
                                  description="per-feature -1/0/+1 monotone "
                                              "constraints (empty = none)")
     hist_method = field(str, default="auto",
-                        enum=["auto", "segment", "matmul", "pallas"],
+                        enum=["auto", "segment", "pallas"],
                         description="histogram engine (ops.histogram)")
 
 
@@ -664,8 +648,8 @@ class HistGBT(_ExternalMemoryEngine):
         self.last_dispatch: Optional[str] = None
         #: what the round program runs, resolved by
         #: :meth:`_round_plan` before tracing — histogram engine per
-        #: level, fused-round engagement, growth policy, bin layout,
-        #: deterministic blocks, mesh width.  The JSON view of the
+        #: level, growth policy, bin layout, deterministic blocks, mesh
+        #: width.  The JSON view of the
         #: :class:`_RoundPlan` that ``_build_round_fn`` builds from;
         #: ``chip_smoke.py`` and the benchmark read it.
         self.round_plan: Optional[Dict[str, Any]] = None
@@ -2165,23 +2149,10 @@ class HistGBT(_ExternalMemoryEngine):
         a Pallas build runs in where one kernel call does not take it
         (more than 32 nodes at 256 bins: ``max_depth`` >= 8; a matrix
         wider than 392 rows), as ``ops.build_histogram`` derives them
-        again from the same shapes.
-        ``fused_round`` says the levels below the root run
-        :func:`~dmlc_core_tpu.ops.histogram.fused_round` (a Pallas
-        kernel, so those levels read ``pallas``): under
-        ``DMLC_FUSED_ROUND=1`` alone (interpreted off-TPU — the
-        byte-parity test hook).  "auto" plans the STAGED round at every
-        shape since PR 45: on the chip the staged ``dmlc_hist`` kernels
-        build the flagship's six levels in 224 ms where
-        ``dmlc_fused_round`` took 245 on the same rows, ~4 ms a level
-        at any width or depth read (PERF.md section 6, PR 45).
-        The fused subtraction consumes ALREADY-synced parent
-        histograms, so it needs the trivial one-chip sync: multi-chip
-        meshes, the deterministic block fold and the learned-missing
-        descend run the staged descend + build + subtract — byte parity
-        either way.  The kernel accumulates in the pallas tile order, so
-        an explicit segment/matmul ``hist_method`` also pins the staged
-        path (real-gradient f32 sums are order-sensitive)."""
+        again from the same shapes.  Every level is the staged descend
+        + build + subtract: the one-kernel level it was measured against
+        lost on the chip and went with PR 47 (PERF.md section 6, PRs 45
+        and 47)."""
         p = self.param
         depth = p.max_depth
         layout = self._bin_layout
@@ -2189,42 +2160,28 @@ class HistGBT(_ExternalMemoryEngine):
         det_blocks = _hist_blocks(dsize)
         sync_bins = layout.sync_bins if layout is not None else p.n_bins
         mat_rows = layout.phys_rows if layout is not None else n_features
-        # "auto" is the staged round (the docstring has the A/B).  The
-        # fused kernel runs where it is asked for by name, at any shape
-        # its other conditions admit: whether it fits VMEM there is the
-        # asker's to know (ops.histogram.fused_round_ok)
-        fused = (
-            _fused_round_mode() == "1"
-            and not self._missing
-            and dsize == 1
-            and det_blocks == 0
-            and p.hist_method in ("auto", "pallas"))
         lossguide = _grow_policy() == "lossguide"
         builds = [1] if lossguide else (
             [1] + [1 << (lv - 1) for lv in range(1, depth)])
         packed = layout is not None and bool(layout.pairs)
         methods = tuple(
-            "pallas" if fused and i > 0 else
             resolve_hist_method(p.hist_method, sync_bins, mat_rows, nb,
                                 whole=packed)
-            for i, nb in enumerate(builds))
-        # the fused kernel takes its level whole; a packed layout is cut
-        # on nodes, never on features
+            for nb in builds)
+        # a packed layout is cut on nodes, never on features
         node_blocks = tuple(
             () if m != "pallas" else
-            (nb,) if fused and i > 0 else
             hist_node_blocks(sync_bins, mat_rows, nb, whole=packed)
-            for i, (m, nb) in enumerate(zip(methods, builds)))
+            for m, nb in zip(methods, builds))
         plan = _RoundPlan(
             n_features=n_features,
             hist_method=methods,
             hist_feature_blocks=tuple(
                 () if m != "pallas" else
-                (mat_rows,) if (fused and i > 0) or packed else
+                (mat_rows,) if packed else
                 hist_feature_blocks(sync_bins, mat_rows, nbs[0])
-                for i, (m, nbs) in enumerate(zip(methods, node_blocks))),
+                for m, nbs in zip(methods, node_blocks)),
             hist_node_blocks=node_blocks,
-            fused_round=fused,
             missing=self._missing,
             pallas_interpret=pallas_interpret(),
             grow_policy="lossguide" if lossguide else "depthwise",
@@ -2327,11 +2284,6 @@ class HistGBT(_ExternalMemoryEngine):
         # evaluation, so split decisions — and save_model bytes — are
         # untouched.  None traces the exact seed program.
         layout = plan.layout
-        # fully-fused round kernel (ops.fused_round): ONE Pallas program
-        # per level/expansion — descend, left-child accumulation and
-        # sibling subtraction with the bin tile and both child histogram
-        # slabs resident in VMEM; eligibility is _round_plan's
-        fused_rounds = plan.fused_round
         lossguide = plan.grow_policy == "lossguide"
         if lossguide:
             CHECK(not missing,
@@ -2423,12 +2375,9 @@ class HistGBT(_ExternalMemoryEngine):
             right child is parent − left from the previous level's
             already-synced histogram.  Halves the one-hot matmul height
             AND the psum bytes per level, and the subtraction itself is
-            exact in f32 up to one rounding.  Where the plan engages
-            ``fused_round`` the descend into level ℓ, the left children's
-            accumulation and the subtraction are ONE Pallas program (the
-            bin tile is read from HBM once per level); elsewhere the
-            level is staged: ``ops.descend_histogram`` (an XLA descend,
-            then the histogram build), the sync, the subtraction."""
+            exact in f32 up to one rounding.  A level is staged:
+            ``ops.descend_histogram`` (an XLA descend, then the
+            histogram build), the sync, the subtraction."""
             node = jnp.zeros(bins_tl.shape[1], jnp.int32)
             n_blk, rb = row_blocks(int(bins_tl.shape[1]))
 
@@ -2452,7 +2401,6 @@ class HistGBT(_ExternalMemoryEngine):
                                     jnp.full(1, jnp.inf, jnp.float32)], 1)
             for level in range(depth):
                 n_nodes = 1 << level
-                scores = None
                 # the level's device phases (doc/observability.md), as
                 # decorators of the calls that trace them: .route (each
                 # row's node's split), .hist (the kernel, the level's
@@ -2483,27 +2431,7 @@ class HistGBT(_ExternalMemoryEngine):
                     thr_sel = select(thr, node, n_prev)               # [n]
                     dir_sel = (select(dirv, node, n_prev)
                                if missing else None)
-                    if fused_rounds:
-                        # ONE Pallas program: descend + accumulate +
-                        # sibling subtraction in VMEM; split scoring
-                        # (the SAME closures as the unfused chain, so
-                        # byte parity holds by construction) runs on
-                        # the kernel's emitted per-node histograms
-                        want_sums = (mono_arr is not None
-                                     or level == depth - 1)
-
-                        @in_split
-                        def score_fn(hs, _w=want_sums, _b=bounds):
-                            ev = _bl.unbundle_hist(hs, layout, B)
-                            if _w:
-                                return best_split_leaf(ev, feat_mask, _b)
-                            return best_split(ev, feat_mask)
-
-                        node, hist, scores = in_hist(fused_round)(
-                            bins_tl, node, feat_sel, thr_sel, g, h,
-                            prev_hist, n_prev, B, layout=layout,
-                            score_fn=score_fn)
-                    elif n_blk:
+                    if n_blk:
                         lefts, nodes2 = [], []
                         for j in range(n_blk):
                             sl = slice(j * rb, (j + 1) * rb)
@@ -2526,42 +2454,30 @@ class HistGBT(_ExternalMemoryEngine):
                             dir_sel=dir_sel,
                             miss_bin=B - 1 if missing else None,
                             layout=layout)
-                    if not fused_rounds:
-                        left = in_sync(hist_sync)(left, n_blk)
-                        hist = in_hist(with_siblings)(prev_hist, left)
+                    left = in_sync(hist_sync)(left, n_blk)
+                    hist = in_hist(with_siblings)(prev_hist, left)
                 # sibling subtraction stays in STORAGE space (prev_hist);
                 # split evaluation sees original-feature space (identity
                 # when layout is None)
                 prev_hist = hist
-                if scores is not None:
-                    # fused level: the per-node (feat, thr, gain, child
-                    # stats) tuple came with the round kernel's outputs
-                    # — the SAME closures, so identical values/bytes
-                    if mono_arr is not None or level == depth - 1:
-                        feat, thr, gn, cg_, ch_ = scores
-                        if level == depth - 1:
-                            gsum, hsum = cg_, ch_
+                hist = in_split(_bl.unbundle_hist)(hist, layout, B)
+                if mono_arr is not None or level == depth - 1:
+                    if missing:
+                        feat, thr, dirv, gn, cg_, ch_ = \
+                            in_split(best_split_leaf)(
+                                hist, feat_mask, bounds)
                     else:
-                        feat, thr, gn = scores
+                        feat, thr, gn, cg_, ch_ = \
+                            in_split(best_split_leaf)(
+                                hist, feat_mask, bounds)
+                    if level == depth - 1:
+                        gsum, hsum = cg_, ch_
+                elif missing:
+                    feat, thr, dirv, gn = in_split(best_split)(
+                        hist, feat_mask)
                 else:
-                    hist = in_split(_bl.unbundle_hist)(hist, layout, B)
-                    if mono_arr is not None or level == depth - 1:
-                        if missing:
-                            feat, thr, dirv, gn, cg_, ch_ = \
-                                in_split(best_split_leaf)(
-                                    hist, feat_mask, bounds)
-                        else:
-                            feat, thr, gn, cg_, ch_ = \
-                                in_split(best_split_leaf)(
-                                    hist, feat_mask, bounds)
-                        if level == depth - 1:
-                            gsum, hsum = cg_, ch_
-                    elif missing:
-                        feat, thr, dirv, gn = in_split(best_split)(
-                            hist, feat_mask)
-                    else:
-                        feat, thr, gn = in_split(best_split)(
-                            hist, feat_mask)
+                    feat, thr, gn = in_split(best_split)(
+                        hist, feat_mask)
                 # pad per-level arrays to a common width for stacking
                 feats.append(jnp.pad(feat, (0, half - n_nodes)))
                 thrs.append(jnp.pad(thr, (0, half - n_nodes)))
@@ -2744,37 +2660,18 @@ class HistGBT(_ExternalMemoryEngine):
                                                    mode="drop")
                 mine = node == hc
                 slot = jnp.argmax(pool_id == hc)
-                if fused_rounds:
-                    # ONE Pallas program per expansion: descend the
-                    # leaf's rows, build the left child and subtract it
-                    # from the pooled parent histogram in VMEM; child
-                    # evaluation runs on the kernel's emitted pair
-                    node_in = jnp.where(ok & mine, 0, -1)
-                    nn, pair, sc2 = fused_round(
-                        bins_tl, node_in,
-                        jnp.full(node.shape, fsel, jnp.int32),
-                        jnp.full(node.shape, tsel, jnp.int32),
-                        g, h, pool[slot][:, None], 1, B,
-                        layout=layout, score_fn=eval_nodes)
-                    node = jnp.where(ok & mine,
-                                     2 * node + (nn == 1).astype(
-                                         jnp.int32), node)
-                    left = pair[:, 0]                     # [2, S, Bs]
-                    right = pair[:, 1]
-                    f2, t2, g2, tg2, th2 = sc2
-                else:
-                    # descend the expanded leaf's rows on (fsel, tsel)
-                    v = row_bins_of(fsel)
-                    go_right = v > tsel
-                    node = jnp.where(ok & mine,
-                                     2 * node + go_right.astype(jnp.int32),
-                                     node)
-                    # ONE build: left child only; right = parent − left
-                    node_build = jnp.where(ok & mine & ~go_right, 0, -1)
-                    left = build_one(node_build)[:, 0]    # [2, S, Bs]
-                    right = pool[slot] - left
-                    f2, t2, g2, tg2, th2 = eval_nodes(
-                        jnp.stack([left, right], axis=1))
+                # descend the expanded leaf's rows on (fsel, tsel)
+                v = row_bins_of(fsel)
+                go_right = v > tsel
+                node = jnp.where(ok & mine,
+                                 2 * node + go_right.astype(jnp.int32),
+                                 node)
+                # ONE build: left child only; right = parent − left
+                node_build = jnp.where(ok & mine & ~go_right, 0, -1)
+                left = build_one(node_build)[:, 0]        # [2, S, Bs]
+                right = pool[slot] - left
+                f2, t2, g2, tg2, th2 = eval_nodes(
+                    jnp.stack([left, right], axis=1))
                 # children at the depth cap never expand
                 g2 = jnp.where(levels[2 * hc] < depth, g2, -jnp.inf)
                 lc = jnp.where(ok, 2 * hc, NH)

@@ -2,18 +2,12 @@
 
 The hot op of XGBoost-style training (BASELINE config 1): for every tree
 node, feature and bin, accumulate Σgrad and Σhess of the rows that land
-there.  XLA formulations, selected by ``method``:
+there.  Two engines, selected by ``method``:
 
 * ``"segment"`` — one flat ``segment_sum`` over the combined
   ``(node, feature, bin)`` index, run separately for grad and hess.
   Lowers to XLA scatter-add: fast on CPU, slow on TPU (scatter
   serializes); the CPU default.
-* ``"matmul"`` — MXU formulation in plain XLA: scan over row blocks;
-  per block the LHS ``[R, 2N]`` holds the node one-hot scaled by g (then
-  h) and the RHS ``[R, F·B]`` is the bin one-hot, so ONE bf16 matmul
-  with f32 accumulation (``preferred_element_type``) yields the whole
-  block's contribution.  Blocking bounds the one-hot materialization to
-  ~100MB regardless of n.
 * ``"pallas"`` — the factored one-hot Pallas kernel (``_hist_pallas``),
   at any dense feature count: where the whole matrix is more than the
   kernel's VMEM budgets admit, the build runs in FEATURE BLOCKS
@@ -26,11 +20,10 @@ there.  XLA formulations, selected by ``method``:
   axis.
 * ``"auto"`` — :func:`resolve_hist_method`: on TPU ``pallas`` wherever
   the kernel's VMEM budgets admit a feature block of even ONE node
-  (every plain dense matrix, at any depth); ``matmul`` only for a
-  packed layout whose rows, which cannot be cut, do not fit;
-  ``segment`` on every other backend.  An EXPLICIT method is never
-  rewritten: asking for ``pallas`` at a shape the budgets refuse
-  raises.
+  (every plain dense matrix, at any depth); ``segment`` for a packed
+  layout whose rows, which cannot be cut, do not fit, and on every
+  other backend.  An EXPLICIT method is never rewritten: asking for
+  ``pallas`` at a shape the budgets refuse raises.
 
 TPU layout note: the result is ``[2, n_nodes, F, n_bins]`` with the
 grad/hess plane LEADING.  A trailing axis of size 2 is catastrophic under
@@ -59,7 +52,7 @@ from jax.experimental.pallas import tpu as pltpu
 from dmlc_core_tpu.base.logging import CHECK, log_fatal
 from dmlc_core_tpu.ops import binlayout as _bl
 
-__all__ = ["build_histogram", "descend_histogram", "fused_round",
+__all__ = ["build_histogram", "descend_histogram",
            "select_feature_bins", "histogram_methods",
            "resolve_hist_method", "pallas_interpret",
            "reference_histogram", "hist_psum_bytes_per_round",
@@ -132,19 +125,13 @@ def hist_psum_bytes_per_round(depth: int, n_features: int,
 
 def bins_bytes_per_round(depth: int, rows: int, row_bytes: int, *,
                          grow_policy: str = "depthwise",
-                         max_leaves: int = 0,
-                         fused: bool = False) -> int:
+                         max_leaves: int = 0) -> int:
     """Bin-matrix HBM bytes ONE boosting round streams: the number of
     full passes over the ``[phys_rows, n]`` matrix times its size.
 
-    Staged depth-wise (what ``DMLC_FUSED_ROUND=auto`` plans): level 0 a
-    histogram pass, every deeper level a descend pass plus a histogram
-    pass, the final leaf assignment one more descend — ``2·depth − 1``
-    passes.  The fused round kernel (``=1`` alone) reads a level's bin
-    tile ONCE: ``depth`` passes.  Fewer bytes, and slower on the chip,
-    where the round is bound by its dots: 245 ms of kernels a flagship
-    round against the staged round's 224 (PERF.md section 6, PR 45).
-    Loss-guide: ``2·leaves − 1`` passes staged, ``leaves`` fused.
+    Depth-wise: level 0 a histogram pass, every deeper level a descend
+    pass plus a histogram pass, the final leaf assignment one more
+    descend — ``2·depth − 1`` passes.  Loss-guide: ``2·leaves − 1``.
     Not counted: a level built in k NODE blocks
     (:func:`hist_node_blocks`; ``max_depth`` >= 8 at 256 bins) reads
     the matrix k times for its histogram, not once.
@@ -153,22 +140,16 @@ def bins_bytes_per_round(depth: int, rows: int, row_bytes: int, *,
     """
     if grow_policy == "lossguide":
         leaves = leaves_built_per_round(depth, "lossguide", max_leaves)
-        passes = leaves if fused else 2 * leaves - 1
+        passes = 2 * leaves - 1
     else:
-        passes = depth if fused else 2 * depth - 1
+        passes = 2 * depth - 1
     return max(passes, 1) * rows * row_bytes
-
-
-# rows per MXU block: one-hot RHS is [R, F·B] bf16 — at F=28, B=256 and
-# R=8192 that is ~117MB, safely inside HBM working set while keeping the
-# matmul [2N, R]·[R, F·B] large enough to saturate the systolic array.
-_BLOCK_ROWS = 8192
 
 
 def histogram_methods() -> list[str]:
     """Names of the available histogram engines (``auto`` resolves per
-    platform: Pallas on TPU, matmul/segment elsewhere)."""
-    return ["auto", "segment", "matmul", "pallas"]
+    platform: Pallas on TPU, segment elsewhere)."""
+    return ["auto", "segment", "pallas"]
 
 
 #: pallas row-tile.  v5e sweeps: 8192 beat 4096 by 3-8% (round 2, 4M
@@ -338,15 +319,14 @@ def resolve_hist_method(method: str, n_bins: int, n_rows: int,
     nibble-packed layout's packed rows lead the block), so all of
     ``n_rows`` have to fit at some node block.  An explicit method is
     returned as is, except that ``pallas`` at a shape the budgets
-    refuse is an error, never a quiet ``matmul``.
+    refuse is an error, never a quiet ``segment``.
     ``models.histgbt`` calls this per tree level up front, so the
     choice is on record (``HistGBT.round_plan``) before anything
     traces."""
     fits = _node_block(n_bins, n_rows, n_nodes, bins_itemsize, whole) > 0
     if method == "auto":
-        if jax.default_backend() != "tpu":
-            return "segment"
-        return "pallas" if fits else "matmul"
+        on_tpu = jax.default_backend() == "tpu"
+        return "pallas" if on_tpu and fits else "segment"
     if method == "pallas" and not fits:
         block = _pallas_ok(n_bins, n_rows, 1, bins_itemsize)
         log_fatal(f"build_histogram: method='pallas' was requested but "
@@ -356,8 +336,8 @@ def resolve_hist_method(method: str, n_bins: int, n_rows: int,
                      if block else "no feature block, not even 8 rows,")
                   + f" for even one node at n_bins={n_bins} (asked: "
                   f"n_nodes={n_nodes}), itemsize={bins_itemsize}, "
-                  f"tile_rows={_TILE_ROWS} — use 'auto' or 'matmul'")
-    if method not in ("segment", "matmul", "pallas"):
+                  f"tile_rows={_TILE_ROWS} — use 'auto' or 'segment'")
+    if method not in ("segment", "pallas"):
         log_fatal(f"build_histogram: unknown method {method!r}")
     return method
 
@@ -390,7 +370,7 @@ def build_histogram(
     ``[2, n_nodes, S, layout.sync_bins]`` — callers unbundle/pad back to
     ``[2, N, F, n_bins]`` via ``binlayout.unbundle_hist`` before split
     evaluation.  The Pallas kernel reads packed bytes natively (the HBM
-    win); segment/matmul unpack to the storage matrix first (exact
+    win); ``segment`` unpacks to the storage matrix first (exact
     integer nibble extraction, so cell values stay bit-identical to an
     unpacked build — the cross-method parity contract).
     """
@@ -406,21 +386,14 @@ def build_histogram(
             return _hist_pallas_blocks(
                 bins, node_id, grad, hess, n_nodes, n_bins, transposed=True,
                 layout=layout if layout.pairs else None)
-        storage = _bl.unpack_matrix(bins, layout)
-        if method == "segment":
-            return _hist_segment(storage.T, node_id, grad, hess,
-                                 n_nodes, n_bins)
-        return _hist_matmul(storage.T, node_id, grad, hess,
-                            n_nodes, n_bins)
+        return _hist_segment(_bl.unpack_matrix(bins, layout).T, node_id,
+                             grad, hess, n_nodes, n_bins)
     F = bins.shape[0] if transposed else bins.shape[1]
     method = resolve_hist_method(method, n_bins, F, n_nodes,
                                  jnp.dtype(bins.dtype).itemsize)
     if method == "segment":
         return _hist_segment(bins.T if transposed else bins,
                              node_id, grad, hess, n_nodes, n_bins)
-    if method == "matmul":
-        return _hist_matmul(bins.T if transposed else bins,
-                            node_id, grad, hess, n_nodes, n_bins)
     return _hist_pallas_blocks(bins, node_id, grad, hess, n_nodes, n_bins,
                                transposed=transposed)
 
@@ -518,54 +491,6 @@ def _hist_segment(bins, node_id, grad, hess, n_nodes, n_bins):
     return jnp.stack([one(grad), one(hess)]).reshape(2, n_nodes, F, n_bins)
 
 
-@partial(jax.jit, static_argnums=(4, 5, 6))
-def _hist_matmul(bins, node_id, grad, hess, n_nodes, n_bins,
-                 block_rows: int = _BLOCK_ROWS):
-    n, F = bins.shape
-    # even out block sizes (rounded to sublane multiples) so padding is at
-    # most nblk·8 rows — a fixed R would pad up to R-1 rows (≈2× work for
-    # n just above a block multiple)
-    nblk = -(-n // block_rows)
-    per_blk = -(-n // nblk)
-    R = -(-per_blk // 8) * 8
-    pad = nblk * R - n
-    if pad:
-        bins = jnp.pad(bins, ((0, pad), (0, 0)))
-        node_id = jnp.pad(node_id, (0, pad), constant_values=-1)
-        grad = jnp.pad(grad, (0, pad))
-        hess = jnp.pad(hess, (0, pad))
-    nblk = (n + pad) // R
-    blocks = (
-        bins.reshape(nblk, R, F),
-        node_id.reshape(nblk, R),
-        grad.reshape(nblk, R),
-        hess.reshape(nblk, R),
-    )
-
-    def body(acc, blk):
-        b_bins, b_node, b_g, b_h = blk
-        valid = b_node >= 0
-        safe = jnp.where(valid, b_node, 0)
-        node_oh = jax.nn.one_hot(safe, n_nodes, dtype=jnp.bfloat16)       # [R, N]
-        g = jnp.where(valid, b_g, 0.0).astype(jnp.bfloat16)
-        h = jnp.where(valid, b_h, 0.0).astype(jnp.bfloat16)
-        lhs = jnp.concatenate(
-            [node_oh * g[:, None], node_oh * h[:, None]], axis=1)         # [R, 2N]
-        bin_oh = jax.nn.one_hot(
-            b_bins.astype(jnp.int32), n_bins, dtype=jnp.bfloat16
-        ).reshape(R, F * n_bins)                                          # [R, F·B]
-        m = jax.lax.dot_general(
-            lhs, bin_oh,
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                                                  # [2N, F·B]
-        return acc + m, None
-
-    acc0 = jnp.zeros((2 * n_nodes, F * n_bins), jnp.float32)
-    acc, _ = jax.lax.scan(body, acc0, blocks)
-    return acc.reshape(2, n_nodes, F, n_bins)
-
-
 def _hist_pallas_kernel(bins_ref, node_ref, g_ref, h_ref, out_ref,
                         *, n_nodes, hi, lo, n_rows, n_pack_groups=0):
     """One row-tile of the FACTORED one-hot matmul.
@@ -609,7 +534,7 @@ def _hist_pallas_kernel(bins_ref, node_ref, g_ref, h_ref, out_ref,
 
 def _accum_hist(bins_ref, out_ref, node, g, h, *, n_nodes, hi, lo, n_rows,
                 n_pack_groups=0):
-    """Shared histogram accumulation loop (see _hist_pallas_kernel doc).
+    """The histogram accumulation loop of :func:`_hist_pallas_kernel`.
 
     ``n_pack_groups`` > 0 marks the first ``8·n_pack_groups`` physical
     rows as NIBBLE-PACKED (two int4 storage features per byte, see
@@ -697,247 +622,6 @@ def _accum_hist(bins_ref, out_ref, node, g, h, *, n_nodes, hi, lo, n_rows,
     if rem:
         group(8 * (n_pack_groups + full),
               lambda k: log_off + 8 * full + k, rem)
-
-
-def _fused_round_kernel(*refs, n_prev, hi, lo, n_rows, n_pack_groups,
-                        with_layout):
-    """ONE Pallas program for a whole tree level: bin-read → node
-    descend → g/h scatter-accumulate → sibling subtraction, with the
-    bin tile and both child histogram slabs resident in VMEM.
-
-    Phase A (every row tile): extract each row's selected feature's bin
-    during one batched sweep of the tile — with a layout the PHYSICAL
-    byte is selected by physical source row, then nibble-extracted,
-    bundle-decoded and compact-unmapped to the ORIGINAL bin id via
-    per-row decode vectors (gathered outside from the static layout
-    tables), so the threshold compare runs in the same original bin
-    space as the XLA fallback (bit-exact integer descend).  The
-    advanced node id is written out and the LEFT children accumulate
-    into the left slab (the slab doubles as the cross-tile VMEM
-    accumulator — the sequential TPU grid revisits block (0,0,0)).
-
-    Phase B (last row tile only): sibling subtraction.  The previous
-    level's histograms arrive PRE-MAPPED into the same accumulator
-    layout ``[L, 2·N·hi, lo]``, so ``right = prev − left`` is one
-    elementwise VPU pass over VMEM — the subtraction state never makes
-    an HBM round-trip between phases.  The kernel emits only the two
-    child slabs plus the new node vector; canonicalization back to
-    ``[2, 2N, S, Bs]`` happens on the (node-sized, KB-scale) outputs.
-    """
-    if with_layout:
-        (bins_ref, node_ref, src_ref, thr_ref, g_ref, h_ref,
-         nib_ref, bnd_ref, off_ref, wid_ref, rmp_ref, occ_ref,
-         prev_ref, left_ref, right_ref, node_out_ref) = refs
-    else:
-        (bins_ref, node_ref, src_ref, thr_ref, g_ref, h_ref,
-         prev_ref, left_ref, right_ref, node_out_ref) = refs
-    i = pl.program_id(0)
-    F, T = bins_ref.shape
-
-    node = node_ref[:].astype(jnp.int32)                              # [1, T]
-    g = g_ref[:].astype(jnp.bfloat16)
-    h = h_ref[:].astype(jnp.bfloat16)
-    key = src_ref[:].astype(jnp.int32)     # physical row (layout) / feature
-    tsel = thr_ref[:].astype(jnp.int32)
-
-    @pl.when(i == 0)
-    def _():
-        left_ref[:] = jnp.zeros_like(left_ref)
-        right_ref[:] = jnp.zeros_like(right_ref)
-
-    g8_iota = jax.lax.broadcasted_iota(jnp.int32, (8, T), 0)
-
-    def sel_body(fg, sel):
-        base = pl.multiple_of(fg * 8, 8)
-        blk = bins_ref[pl.ds(base, 8), :].astype(jnp.int32)           # [8, T]
-        pick = (g8_iota + base == key).astype(jnp.int32)              # [8, T]
-        return sel + jnp.sum(pick * blk, axis=0, keepdims=True)
-
-    v = jax.lax.fori_loop(0, F // 8, sel_body,
-                          jnp.zeros((1, T), jnp.int32))
-    if with_layout:
-        # physical byte → ORIGINAL bin id, mirroring binlayout.select_bins
-        # exactly (integer relabelings — the descend stays bit-exact):
-        # nibble extract, bundle segment decode, compact-remap inverse.
-        nib = nib_ref[:].astype(jnp.int32)
-        v = jnp.where(nib == 1, v >> 4, jnp.where(nib == 0, v & 15, v))
-        off = off_ref[:].astype(jnp.int32)
-        wid = wid_ref[:].astype(jnp.int32)
-        in_seg = (v >= off) & (v < off + wid - 1)
-        v = jnp.where(bnd_ref[:].astype(jnp.int32) == 1,
-                      jnp.where(in_seg, v - off + 1, 0), v)
-        occ_blk = occ_ref[:].astype(jnp.int32)                # [16, T]
-        orig = jnp.zeros_like(v)
-        for k in range(_bl.PACK_WIDTH):
-            orig = orig + (v == k).astype(jnp.int32) * occ_blk[k:k + 1]
-        v = jnp.where(rmp_ref[:].astype(jnp.int32) == 1, orig, v)
-    valid = node >= 0
-    new_node = jnp.where(valid, 2 * node + (v > tsel), -1)            # [1, T]
-    node_out_ref[:] = new_node
-
-    # left children only — the right slab comes from sibling subtraction
-    node_h = jnp.where(valid & (new_node % 2 == 0), new_node >> 1, -1)
-    _accum_hist(bins_ref, left_ref, node_h, g, h,
-                n_nodes=n_prev, hi=hi, lo=lo, n_rows=n_rows,
-                n_pack_groups=n_pack_groups)
-
-    @pl.when(i == pl.num_programs(0) - 1)
-    def _():
-        right_ref[:] = prev_ref[:] - left_ref[:]
-
-
-def fused_round_ok(n_bins: int, n_features: int, n_prev: int = 1,
-                   bins_itemsize: int = 1, tile_rows: int = 0,
-                   with_layout: bool = False) -> bool:
-    """Whether the fused ROUND kernel fits VMEM (a yes or no: it is not
-    built in blocks; no plan asks since PR 45, ``auto`` being the staged
-    round): it holds THREE accumulator-shaped slabs (prev, left, right)
-    instead of one, and the layout mode streams five extra [1, T] int32
-    decode vectors plus the [16, T] compact-remap table per tile."""
-    lo = _lo_factor(n_prev, n_bins)
-    hi = -(-n_bins // lo)
-    fp = -(-n_features // 8) * 8
-    nh = n_prev * hi
-    acc = fp * 2 * nh * max(lo, 128) * 4
-    T = tile_rows or _TILE_ROWS
-    extra = (5 * 4 + 16 * 4) if with_layout else 0
-    tile_stack = T * (fp * bins_itemsize + 136 + extra + 6 * nh + 2 * lo)
-    return 3 * acc <= 24 << 20 and tile_stack <= 15 << 20
-
-
-def fused_round(
-    bins_t: jax.Array,      # [F, n] (or physical [phys_rows, n] w/ layout)
-    node_id: jax.Array,     # [n] — node ids at level ℓ−1 (−1 = padding)
-    feat_sel: jax.Array,    # [n] — each row's node's chosen split feature
-    thr_sel: jax.Array,     # [n] — chosen split threshold (ORIGINAL bin id)
-    grad: jax.Array,
-    hess: jax.Array,
-    prev_hist: jax.Array,   # [2, n_prev, S, Bs] level-(ℓ−1) histograms
-    n_prev: int,
-    n_bins: int,
-    *,
-    tile_rows: int = _TILE_ROWS,
-    lo: int = 0,
-    layout=None,
-    score_fn=None,
-):
-    """Advance rows one level AND produce BOTH children's histograms in
-    one pass over the bin matrix: descend, left-child accumulation and
-    sibling subtraction run inside one Pallas program per level (the
-    fully-fused round kernel), so the only HBM traffic is the bin tile
-    itself plus the per-node outputs.  Returns ``(new_node, hist,
-    scores)`` with ``hist[_, c]`` the histogram of child ``c``
-    (``2p``/``2p+1`` interleaved, STORAGE space under a layout — same
-    shape/values as the unfused build+subtract+stack sequence, exactly)
-    and ``scores = score_fn(hist)`` when a scoring closure is supplied
-    (the per-node ``(feat, thr, gain, child stats)`` tuple), evaluated
-    on the kernel's emitted histograms without re-reading any
-    row-dimension array.
-
-    Parity contract: the descend is exact integer relabeling and the
-    accumulation order equals the plain Pallas histogram's, so with
-    order-exact gradients (or on-TPU where the unfused path is the same
-    kernel family) the result is bit-identical to the three-dispatch
-    path — ``save_model`` byte parity, pinned by tests/test_fused_round.
-    """
-    Fphys, n = bins_t.shape
-    Bs = layout.sync_bins if layout is not None else n_bins
-    lo = min(lo or _lo_factor(n_prev, Bs), Bs)
-    hi = -(-Bs // lo)
-    A = 2 * n_prev * hi
-    Fp = -(-Fphys // 8) * 8
-    if layout is not None:
-        npg = layout.packed_rows // 8
-        L = 16 * npg + (Fp - 8 * npg)
-        t = _bl.layout_tables(layout)
-        perm = t["logical"]
-        src_of = t["src"][t["owner"]]
-        nib_of = t["nib"][t["owner"]]
-        fs = feat_sel.astype(jnp.int32)
-        key = jnp.asarray(src_of)[fs]
-        extras = [jnp.asarray(nib_of)[fs],
-                  jnp.asarray(t["bundled"].astype(np.int32))[fs],
-                  jnp.asarray(t["off"])[fs],
-                  jnp.asarray(t["wid"])[fs],
-                  jnp.asarray(t["remap"].astype(np.int32))[fs]]
-        occ = jnp.asarray(t["occ_pad"])[fs].T                  # [16, n]
-    else:
-        npg = 0
-        L = Fp
-        perm = np.arange(Fphys, dtype=np.int32)
-        key = feat_sel.astype(jnp.int32)
-        extras, occ = [], None
-    pad = (-n) % tile_rows
-    with jax.named_scope("dmlc.hist.pad"):
-        if pad:
-            node_id = jnp.pad(node_id, (0, pad), constant_values=-1)
-            key = jnp.pad(key, (0, pad))
-            thr_sel = jnp.pad(thr_sel, (0, pad))
-            grad = jnp.pad(grad, (0, pad))
-            hess = jnp.pad(hess, (0, pad))
-            extras = [jnp.pad(e, (0, pad)) for e in extras]
-            if occ is not None:
-                occ = jnp.pad(occ, ((0, 0), (0, pad)))
-        n_pad = n + pad
-        grid = n_pad // tile_rows
-        bins_p = jnp.pad(bins_t, ((0, Fp - Fphys), (0, pad)))
-
-        # previous level's histograms, PRE-MAPPED into the accumulator
-        # layout [L, (gh, node, hi), lo] so the in-kernel subtraction is
-        # elementwise (dead rows/cells are exact zeros on both sides)
-        Sn = prev_hist.shape[2]
-        prev_p = jnp.pad(prev_hist.astype(jnp.float32),
-                         ((0, 0), (0, 0), (0, 0), (0, hi * lo - Bs)))
-        prev_r = prev_p.reshape(2, n_prev, Sn, hi, lo)
-        prev_r = prev_r.transpose(2, 0, 1, 3, 4).reshape(Sn, A, lo)
-        prev_acc = jnp.zeros((L, A, lo), jnp.float32
-                             ).at[jnp.asarray(perm)].set(prev_r)
-
-    row_spec = pl.BlockSpec((1, tile_rows), lambda i: (0, i))
-    in_specs = [pl.BlockSpec((Fp, tile_rows), lambda i: (0, i)),
-                row_spec, row_spec, row_spec, row_spec, row_spec]
-    operands = [bins_p, node_id.reshape(1, n_pad), key.reshape(1, n_pad),
-                thr_sel.reshape(1, n_pad), grad.reshape(1, n_pad),
-                hess.reshape(1, n_pad)]
-    if layout is not None:
-        in_specs += [row_spec] * 5
-        operands += [e.reshape(1, n_pad) for e in extras]
-        in_specs.append(pl.BlockSpec((_bl.PACK_WIDTH, tile_rows),
-                                     lambda i: (0, i)))
-        operands.append(occ)
-    in_specs.append(pl.BlockSpec((L, A, lo), lambda i: (0, 0, 0)))
-    operands.append(prev_acc)
-
-    left, right, new_node = pl.pallas_call(
-        partial(_fused_round_kernel, n_prev=n_prev, hi=hi, lo=lo,
-                n_rows=Fphys, n_pack_groups=npg,
-                with_layout=layout is not None),
-        out_shape=(
-            jax.ShapeDtypeStruct((L, A, lo), jnp.float32),
-            jax.ShapeDtypeStruct((L, A, lo), jnp.float32),
-            jax.ShapeDtypeStruct((1, n_pad), jnp.int32),
-        ),
-        grid=(grid,),
-        in_specs=in_specs,
-        out_specs=(
-            pl.BlockSpec((L, A, lo), lambda i: (0, 0, 0)),
-            pl.BlockSpec((L, A, lo), lambda i: (0, 0, 0)),
-            pl.BlockSpec((1, tile_rows), lambda i: (0, i)),
-        ),
-        interpret=pallas_interpret(),
-        name="dmlc_fused_round",
-    )(*operands)
-
-    def canon(slab):
-        x = slab.reshape(L, 2, n_prev, hi * lo)[jnp.asarray(perm)]
-        return x.transpose(1, 2, 0, 3)[..., :Bs]
-
-    with jax.named_scope("dmlc.hist.unpack"):
-        hist = jnp.stack([canon(left), canon(right)], axis=2)
-        hist = hist.reshape(2, 2 * n_prev, Sn, Bs)
-        new_node = new_node.reshape(n_pad)[:n]
-    scores = score_fn(hist) if score_fn is not None else None
-    return new_node, hist, scores
 
 
 #: measured-best lo per n_build at n_bins=256 on v5e, tile 16384, 10M
@@ -1063,7 +747,7 @@ def descend_histogram(
     Returns ``(left_hist, new_node)`` with ``left_hist[_, p]`` the
     histogram of parent p's left child (node 2p) — the caller derives
     the right child by sibling subtraction.  The level of the round
-    program at every shape, but under ``DMLC_FUSED_ROUND=1``.
+    program at every shape.
     Replaces rabit's per-level hist allreduce prep (SURVEY.md §2e
     data-parallel row)."""
     valid = node_id >= 0

@@ -4,8 +4,8 @@
 Two halves, one JSON artifact (``CHECK_HIST_OUT``, default
 ``/tmp/hist_kernel.json``):
 
-* **Cross-method parity sweep** — every histogram engine (segment /
-  matmul / pallas-interpret) must produce the BIT-IDENTICAL
+* **Cross-method parity sweep** — both histogram engines (segment /
+  pallas-interpret) must produce the BIT-IDENTICAL
   ``[2, N, F, B]`` histogram on the same inputs, at odd row counts with
   masked (``node_id < 0``) rows, through an int4-packed
   :class:`~dmlc_core_tpu.ops.binlayout.BinLayout` (compact remap), and
@@ -36,15 +36,12 @@ if os.environ.get("JAX_PLATFORMS", "") == "cpu":
     force_cpu_devices(1)
 
 import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from dmlc_core_tpu.ops import binlayout as bl  # noqa: E402
-from dmlc_core_tpu.ops.histogram import (build_histogram,  # noqa: E402
-                                         fused_round,
-                                         select_feature_bins)
+from dmlc_core_tpu.ops.histogram import build_histogram  # noqa: E402
 
-METHODS = ("segment", "matmul", "pallas")
+METHODS = ("segment", "pallas")
 
 
 def _exact_gh(rng, n):
@@ -118,57 +115,6 @@ def _parity_case(name, bins_t, layout, n_nodes, n_bins, rng):
             "ok": not mismatches, "mismatches": mismatches}
 
 
-def _fused_parity_case(name, bins_t, layout, n_prev, n_bins, rng,
-                       tile_rows=256):
-    """Fused round kernel (interpret mode off-TPU) vs the unfused
-    segment sequence: descend + left-child build + sibling subtraction
-    must agree bit-for-bit on BOTH outputs — the stacked child
-    histograms and the advanced node ids.  A small ``tile_rows`` at odd
-    row counts exercises the multi-tile VMEM-resident accumulation."""
-    n = bins_t.shape[1]
-    F = layout.n_features if layout is not None else bins_t.shape[0]
-    g, h = _exact_gh(rng, n)
-    nid = _node_ids(rng, n, n_prev)
-    feat_tab = rng.integers(0, F, n_prev).astype(np.int32)
-    thr_tab = rng.integers(0, n_bins, n_prev).astype(np.int32)
-    safe = np.where(nid >= 0, nid, 0)
-    feat_sel = feat_tab[safe]
-    thr_sel = thr_tab[safe]
-    phys = (np.asarray(bl.pack_matrix(bins_t, layout))
-            if layout is not None else bins_t)
-    prev = _build(phys, nid, g, h, n_prev, n_bins, "segment",
-                  layout=layout)
-    # unfused reference: select + compare descend, left build, parent −
-    # left in storage space
-    row_bin = np.asarray(select_feature_bins(
-        jnp.asarray(phys), jnp.asarray(feat_sel), layout=layout))
-    new_ref = np.where(nid >= 0, 2 * nid + (row_bin > thr_sel), -1)
-    node_h = np.where((nid >= 0) & (new_ref % 2 == 0),
-                      new_ref >> 1, -1).astype(np.int32)
-    left = _build(phys, node_h, g, h, n_prev, n_bins, "segment",
-                  layout=layout)
-    hist_ref = np.stack([left, prev - left], axis=2).reshape(
-        2, 2 * n_prev, left.shape[2], left.shape[3])
-    new_f, hist_f, _ = fused_round(
-        jnp.asarray(phys), jnp.asarray(nid), jnp.asarray(feat_sel),
-        jnp.asarray(thr_sel), jnp.asarray(g), jnp.asarray(h),
-        jnp.asarray(prev), n_prev, n_bins, tile_rows=tile_rows,
-        layout=layout)
-    mismatches = []
-    if not np.array_equal(np.asarray(new_f), new_ref):
-        mismatches.append(
-            f"node: {int(np.sum(np.asarray(new_f) != new_ref))} "
-            "rows differ")
-    if not np.array_equal(np.asarray(hist_f), hist_ref):
-        mismatches.append(
-            f"hist: {int(np.sum(np.asarray(hist_f) != hist_ref))} "
-            "cells differ")
-    return {"case": name, "rows": n, "methods": ["fused_round"],
-            "layout": (None if layout is None else
-                       f"{layout.n_features}F->{layout.phys_rows}phys"),
-            "ok": not mismatches, "mismatches": mismatches}
-
-
 def _microbench(rows, reps):
     """Per-method ns/row on a jitted plain build (F=28, B=64, 8 nodes)
     plus the packed-layout pallas read path (28 narrow features -> 14
@@ -199,25 +145,6 @@ def _microbench(rows, reps):
     if layout is not None:
         phys = np.asarray(bl.pack_matrix(narrow, layout))
         timed("pallas_packed", phys, "pallas", layout=layout)
-
-    # fused round kernel: descend + build + sibling subtraction in one
-    # program (interpret mode on CPU — relative drift is the signal)
-    n_prev = n_nodes >> 1
-    prev = _build(plain, _node_ids(rng, rows, n_prev), g, h, n_prev, B,
-                  "segment")
-    feat_sel = rng.integers(0, F, rows).astype(np.int32)
-    thr_sel = rng.integers(0, B, rows).astype(np.int32)
-    nid4 = _node_ids(rng, rows, n_prev)
-    fused_fn = jax.jit(lambda b, i, fs, ts, gg, hh, pv: fused_round(
-        b, i, fs, ts, gg, hh, pv, n_prev, B))
-    args = (plain, nid4, feat_sel, thr_sel, g, h, prev)
-    jax.block_until_ready(fused_fn(*args))          # compile outside
-    ts = []
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        jax.block_until_ready(fused_fn(*args))
-        ts.append(time.perf_counter() - t0)
-    out["fused_round"] = round(sorted(ts)[len(ts) // 2] / rows * 1e9, 2)
     return out
 
 
@@ -245,17 +172,6 @@ def main() -> int:
     lay_b = bl.compute_layout(counts_b, 3, B, pack=True, bundles=bundles)
     assert lay_b is not None and lay_b.has_bundles
     results.append(_parity_case("bundled", bins_b, lay_b, 2, B, rng))
-
-    # 4-6. fused round kernel (ISSUE 18): one Pallas program doing
-    # descend + accumulate + sibling subtraction, vs the unfused
-    # segment sequence — plain, packed-remap and bundled layouts
-    results.append(_fused_parity_case(
-        "fused_plain", _spread_bins(rng, 1021, 9, B, narrow=()), None,
-        4, B, rng))
-    results.append(_fused_parity_case(
-        "fused_packed", bins_n, lay_n, 4, B, rng))
-    results.append(_fused_parity_case(
-        "fused_bundled", bins_b, lay_b, 2, B, rng, tile_rows=512))
 
     rows = int(os.environ.get("CHECK_HIST_ROWS", 50_000))
     reps = int(os.environ.get("CHECK_HIST_REPS", 3))

@@ -46,14 +46,11 @@ echo "== interleave model check (schedule exploration) =="
 env JAX_PLATFORMS=cpu DMLC_TPU_FORCE_CPU=1 \
     python -m dmlc_core_tpu.analysis.interleave
 
-echo "== histogram kernel drill (cross-method + fused-round parity, ns/row archive) =="
-# every histogram engine (segment / matmul / pallas-interpret) must be
+echo "== histogram kernel drill (cross-method parity, ns/row archive) =="
+# both histogram engines (segment / pallas-interpret) must be
 # BIT-identical — including through the int4-packed compact-remap layout
 # and through a feature bundle's tot-minus-segments reconstruction — on
-# odd row counts with masked rows; the fused-round cases additionally
-# prove the single-program descend+accumulate+sibling-subtract kernel
-# bit-identical to the staged reference through the same layouts
-# (doc/performance.md "Fused round kernel"); the timed half archives
+# odd row counts with masked rows; the timed half archives
 # per-method ns/row JSON so kernel regressions land in the artifact
 # chain (doc/performance.md "Packed narrow bins").
 env JAX_PLATFORMS=cpu CHECK_HIST_OUT="${CHECK_HIST_OUT:-/tmp/hist_kernel.json}" \

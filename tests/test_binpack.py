@@ -47,6 +47,18 @@ def _counts(bins, B):
     return bl.bin_counts(jnp.asarray(bins), B)
 
 
+def _exclusive_bins(rng, n, B=32):
+    """Two near-one-hot features whose DEFAULT bin is NOT 0 (the
+    quantile sketch maps the common value wherever it likes) plus a
+    wide feature; the one-hots never fire on the same row."""
+    bins = np.zeros((3, n), np.uint8)
+    bins[0] = np.arange(n) % B
+    onehot = rng.integers(0, 3, n)
+    bins[1] = np.where(onehot == 1, 20, 5)
+    bins[2] = np.where(onehot == 2, 25, 7)
+    return bins
+
+
 class TestLayout:
     def test_all_wide_is_trivial(self, rng):
         bins = _spread_bins(rng, 500, 4, 32, narrow=())
@@ -100,7 +112,7 @@ class TestLayout:
 
 
 class TestPackedParity:
-    @pytest.mark.parametrize("method", ["segment", "matmul", "pallas"])
+    @pytest.mark.parametrize("method", ["segment", "pallas"])
     def test_bit_parity_vs_plain(self, method, rng):
         n, F, B, N = 1021, 9, 32, 3            # odd row count on purpose
         bins = _spread_bins(rng, n, F, B, narrow=(1, 4, 7, 8))
@@ -124,20 +136,9 @@ class TestPackedParity:
 
 
 class TestBundling:
-    def _exclusive_bins(self, rng, n, B=32):
-        """Two near-one-hot features whose DEFAULT bin is NOT 0 (the
-        quantile sketch maps the common value wherever it likes) plus a
-        wide feature; the one-hots never fire on the same row."""
-        bins = np.zeros((3, n), np.uint8)
-        bins[0] = np.arange(n) % B
-        onehot = rng.integers(0, 3, n)
-        bins[1] = np.where(onehot == 1, 20, 5)
-        bins[2] = np.where(onehot == 2, 25, 7)
-        return bins
-
     def test_detect_and_exact_roundtrip(self, rng):
         n, B = 1021, 32
-        bins = self._exclusive_bins(rng, n, B)
+        bins = _exclusive_bins(rng, n, B)
         counts = _counts(bins, B)
         bundles = bl.detect_bundles(bins, np.asarray(counts), B)
         assert bundles == ((1, 2),)
@@ -155,7 +156,7 @@ class TestBundling:
 
     def test_bundle_hist_parity(self, rng):
         n, B, N = 1021, 32, 2
-        bins = self._exclusive_bins(rng, n, B)
+        bins = _exclusive_bins(rng, n, B)
         node = rng.integers(0, N, n).astype(np.int32)
         g = rng.choice([-1.0, -0.5, 0.5, 1.0], n).astype(np.float32)
         h = rng.choice([0.5, 1.0], n).astype(np.float32)

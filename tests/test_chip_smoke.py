@@ -1,8 +1,8 @@
 """chip_smoke.py cannot rot between chip runs: its body runs here at a
 tiny size on the virtual CPU mesh with the Pallas kernels interpreted,
 its entry point must refuse any backend but ``tpu``, and the no-quiet-
-fallback rule it relies on (explicit ``pallas`` never becomes ``matmul``)
-is pinned next to it."""
+fallback rule it relies on (explicit ``pallas`` never becomes another
+engine) is pinned next to it."""
 
 import json
 import os
@@ -28,20 +28,17 @@ from dmlc_core_tpu.parallel.mesh import local_mesh  # noqa: E402
 
 #: the flagship's shape of run, cut to what the interpreter can carry:
 #: explicit pallas stands in for what "auto" picks on the chip, so the
-#: same kernels (and the same checks) are live; DMLC_FUSED_ROUND=1
-#: below drives the fused round through the smoke too ("auto" plans the
-#: staged one since PR 45)
+#: same kernels (and the same checks) are live
 TINY = chip_smoke.SmokeConfig(
     rows=4096, features=8, n_trees=4, max_depth=3, n_bins=32,
     holdout_rows=1200, hist_method="pallas",
     serve_sizes=(1, 8, 9, 100, 1024, 1100),
-    rollcall_rows=700, rollcall_nodes=(1, 4), rollcall_prev=(1, 2),
+    rollcall_rows=700, rollcall_nodes=(1, 4),
     tile_rows=256, det_rows=1024, det_trees=2,
     auc_floor=0.75, require_tpu=False)
 
 
 def test_body_runs_every_phase_on_the_virtual_mesh(monkeypatch):
-    monkeypatch.setenv("DMLC_FUSED_ROUND", "1")
     monkeypatch.setenv("DMLC_TPU_ROUNDS_PER_DISPATCH", "2")  # 2 dispatches
     sm = chip_smoke.run_smoke(TINY)
     assert sm.failures == [], sm.failures
@@ -52,7 +49,6 @@ def test_body_runs_every_phase_on_the_virtual_mesh(monkeypatch):
         "count": len(jax.devices())}
     one = rep["one_device"]
     assert one["round_plan"]["hist_method"] == ["pallas"] * TINY.max_depth
-    assert one["round_plan"]["fused_round"] is True
     assert one["round_plan"]["pallas_interpret"] is True      # CPU here
     assert one["dispatch"] in ("aot", "jit")
     assert [d for d, _ in one["chunk_times"]] == [2, 4]
@@ -61,14 +57,11 @@ def test_body_runs_every_phase_on_the_virtual_mesh(monkeypatch):
     kernels = rep["rollcall"]["kernels"]
     assert set(kernels) == {
         "_hist_pallas[n_nodes=1]", "_hist_pallas+int4[n_nodes=1]",
-        "_hist_pallas[n_nodes=4]", "_hist_pallas+int4[n_nodes=4]",
-        "fused_round[n_prev=1]", "fused_round+layout[n_prev=1]",
-        "fused_round[n_prev=2]", "fused_round+layout[n_prev=2]"}
+        "_hist_pallas[n_nodes=4]", "_hist_pallas+int4[n_nodes=4]"}
     assert all(k["ok"] for k in kernels.values())
     # more than one device here, so the mesh phases ran too
     mesh = rep["all_devices"]
     assert mesh["round_plan"]["mesh_devices"] == len(jax.devices())
-    assert mesh["round_plan"]["fused_round"] is False   # staged + psum
     assert mesh["bins_t_shard_devices"] == len(jax.devices())
     assert rep["hist_blocks_parity"]["byte_identical"] is True
     # the report is what main() writes out: it must serialize

@@ -221,7 +221,7 @@ def test_a_packed_layout_is_never_cut(monkeypatch):
                               whole=True)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert H.resolve_hist_method("auto", lay.sync_bins, lay.phys_rows, 1,
-                                 whole=True) == "matmul"
+                                 whole=True) == "segment"
     assert H.resolve_hist_method("auto", lay.sync_bins, lay.phys_rows, 1
                                  ) == "pallas"
 
@@ -238,7 +238,6 @@ def test_round_plan_records_the_blocks_of_every_build(monkeypatch):
     assert m.round_plan["hist_feature_blocks"] == [[16, 16, 12]] * depth
     assert m.round_plan["hist_method"] == ["pallas"] * depth
     assert m.round_plan["hist_features"] == [44, 48]
-    assert m.round_plan["fused_round"] is False
     assert json.loads(json.dumps(m.round_plan)) == m.round_plan
     # one block: the list says so, for every engine that has blocks
     assert HistGBT(mesh=local_mesh(1), n_trees=2, max_depth=depth,
@@ -272,13 +271,7 @@ def test_round_plan_records_the_blocks_of_every_build(monkeypatch):
     assert "dmlc.round.L2.hist/dmlc.hist.fblock" in text
 
 
-def test_fused_levels_and_other_engines_in_the_record(monkeypatch):
-    monkeypatch.setenv("DMLC_FUSED_ROUND", "1")
-    m = HistGBT(mesh=local_mesh(1), n_trees=2, max_depth=3, n_bins=32,
-                hist_method="pallas")
-    assert m._round_plan(28).hist_feature_blocks == ((28,),) * 3
-    assert m.round_plan["fused_round"] is True
-    monkeypatch.delenv("DMLC_FUSED_ROUND")
+def test_the_other_engine_in_the_record():
     seg = HistGBT(mesh=local_mesh(1), n_trees=2, max_depth=3, n_bins=32)
     seg._round_plan(28)
     assert seg.round_plan["hist_feature_blocks"] == [[]] * 3
@@ -326,7 +319,6 @@ def test_wide_fit_runs_the_staged_round_in_blocks(wide_fit):
     assert m.round_plan["hist_method"] == ["pallas"] * cfg["max_depth"]
     assert m.round_plan["hist_feature_blocks"] == \
         [[104, 104, 92]] * cfg["max_depth"]
-    assert m.round_plan["fused_round"] is False
     # a split in the last block, past its last whole group of 8, would
     # show a block that lost its tail; the label lives in columns 0..4,
     # so look at the histogram the last block built instead

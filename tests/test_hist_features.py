@@ -6,7 +6,8 @@ its compare, scalings and MXU dot like a real one (HIGGS: 4 of 32).
 
 * ``_hist_pallas`` equals the scatter-add engine at feature counts on
   both sides of a group boundary, transposed and not, with masked rows;
-* ``fused_round`` equals the staged descend + build + subtract;
+* the staged level (``descend_histogram``) equals a numpy descend and
+  the loop oracle over its left children, plain, packed and bundled;
 * a fit at F = 28 equals the fit of the same columns zero-padded to 32
   by the caller — the arithmetic of the kernels before ISSUE 34;
 * the dots a row tile issues are counted off the kernel's jaxpr: F, not
@@ -32,6 +33,8 @@ from dmlc_core_tpu.models import HistGBT  # noqa: E402
 from dmlc_core_tpu.ops import binlayout as bl  # noqa: E402
 from dmlc_core_tpu.ops import histogram as H  # noqa: E402
 from dmlc_core_tpu.parallel.mesh import local_mesh  # noqa: E402
+
+from test_binpack import _exclusive_bins, _spread_bins  # noqa: E402
 
 B, TILE = 64, 256
 
@@ -68,25 +71,70 @@ def test_hist_pallas_equals_segment(F, n_nodes, transposed):
             want, H.reference_histogram(bins_t.T, node, g, h, n_nodes, B))
 
 
-@pytest.mark.parametrize("n_prev", [1, 4])
-@pytest.mark.parametrize("F", [28, 31])
-def test_fused_round_equals_staged_level(F, n_prev):
-    bins_t, node, g, h = _rows(F, n_prev, seed=5)
-    rng = np.random.default_rng(F)
+def _level_against_numpy(bins_t, node, g, h, n_prev, n_bins, rng,
+                         layout=None):
+    """One staged level by ``method="pallas"`` against the plain
+    reference: a numpy descend (``bin > thr``) for the node ids, the
+    loop oracle over the left children for the histograms."""
+    F = bins_t.shape[0]
     feat = rng.integers(0, F, size=n_prev).astype(np.int32)[
         np.maximum(node, 0)]
     feat[-3:] = F - 1                       # the last real feature is a key
-    thr = rng.integers(0, B, size=node.size).astype(np.int32)
-    args = [jnp.asarray(a) for a in (bins_t, node, feat, thr, g, h)]
-    prev = H._hist_pallas(args[0], args[1], args[4], args[5], n_prev, B,
-                          TILE, 0, True)
-    new_node, hist, _ = H.fused_round(*args, prev, n_prev, B, tile_rows=TILE)
-    left, staged_node = H.descend_histogram(*args, n_prev, B, "pallas")
-    staged = jnp.stack([left, prev - left], axis=2).reshape(
-        2, 2 * n_prev, F, B)
-    assert np.array_equal(np.asarray(new_node), np.asarray(staged_node))
-    assert np.array_equal(np.asarray(hist), np.asarray(staged))
-    assert np.asarray(hist).any()
+    thr = rng.integers(0, n_bins, size=node.size).astype(np.int32)
+    mat = (bins_t if layout is None
+           else np.asarray(bl.pack_matrix(jnp.asarray(bins_t), layout)))
+    left, new_node = H.descend_histogram(
+        *(jnp.asarray(a) for a in (mat, node, feat, thr, g, h)),
+        n_prev, n_bins, "pallas", layout=layout)
+    if layout is not None:
+        left = bl.unbundle_hist(left, layout, n_bins)
+    row_bin = bins_t[feat, np.arange(node.size)].astype(np.int32)
+    want_node = np.where(node >= 0, 2 * node + (row_bin > thr), -1)
+    lefts = np.where((node >= 0) & (want_node % 2 == 0), want_node >> 1, -1)
+    assert np.array_equal(np.asarray(new_node), want_node)
+    assert np.array_equal(
+        np.asarray(left),
+        H.reference_histogram(bins_t.T, lefts, g, h, n_prev, n_bins))
+    assert np.asarray(left).any() and (lefts >= 0).any()
+
+
+@pytest.mark.parametrize("n_prev", [1, 4])
+@pytest.mark.parametrize("F", [28, 31, 39])
+def test_staged_level_equals_numpy_reference(F, n_prev):
+    bins_t, node, g, h = _rows(F, n_prev, n=701, seed=5)
+    _level_against_numpy(bins_t, node, g, h, n_prev, B,
+                         np.random.default_rng(F))
+
+
+def _packed_rows(n, n_bins, rng):
+    """Nine features, four of them narrow with SPREAD bin ids: the
+    compact remap, then nibble pairs."""
+    bins_t = _spread_bins(rng, n, 9, n_bins, narrow=(1, 4, 7, 8))
+    lay = bl.compute_layout(bl.bin_counts(bins_t, n_bins), 9, n_bins)
+    assert lay.pairs
+    return bins_t, lay
+
+
+def _bundled_rows(n, n_bins, rng):
+    """One wide feature and two near-one-hot ones that never leave
+    their default bin together: one bundle."""
+    bins_t = _exclusive_bins(rng, n, n_bins)
+    counts = bl.bin_counts(bins_t, n_bins)
+    lay = bl.compute_layout(
+        counts, 3, n_bins, bundles=bl.detect_bundles(bins_t, counts, n_bins))
+    assert lay.has_bundles
+    return bins_t, lay
+
+
+@pytest.mark.parametrize("n_prev", [1, 2])
+@pytest.mark.parametrize("rows_of", [_packed_rows, _bundled_rows],
+                         ids=["packed", "bundled"])
+def test_staged_level_equals_numpy_reference_through_a_layout(rows_of,
+                                                              n_prev):
+    rng = np.random.default_rng(17 + n_prev)
+    bins_t, lay = rows_of(701, 32, rng)
+    _, node, g, h = _rows(bins_t.shape[0], n_prev, n=701, seed=9)
+    _level_against_numpy(bins_t, node, g, h, n_prev, 32, rng, layout=lay)
 
 
 # -- what a row tile issues, counted off the jaxpr ---------------------
@@ -130,17 +178,6 @@ def test_hist_kernel_issues_one_dot_per_real_feature(F):
     assert got == F
 
 
-@pytest.mark.parametrize("F", [28, 32])
-def test_fused_kernel_issues_one_dot_per_real_feature(F):
-    bins_t, node, g, h = _shapes(F)
-    got = _kernel_dots(
-        lambda b, nd, g, h: H.fused_round(
-            b, nd, nd, nd, g, h, jnp.zeros((2, 4, F, B)), 4, B,
-            tile_rows=TILE)[:2],
-        bins_t, node, g, h)
-    assert got == F == H.hist_feature_dots(F)[0]
-
-
 def test_packed_layout_skips_the_pad_rows_of_its_unpacked_region():
     # four narrow features nibble-packed into two byte rows (one padded
     # group of 8: 16 logical rows), two wide ones after them: 10
@@ -167,14 +204,11 @@ def test_round_plan_records_the_pair():
 
 # -- a fit: the caller's own zero columns against the kernel's ---------
 
-@pytest.mark.parametrize("fused", ["0", "1"], ids=["staged", "fused"])
-def test_fit_equals_fit_of_zero_padded_features(fused, monkeypatch,
-                                                tmp_path):
+def test_fit_equals_fit_of_zero_padded_features(tmp_path):
     """With four zero columns appended by the CALLER the kernels build
     32 features, the four constant ones like any other (no group has a
     tail): the kernels' arithmetic before ISSUE 34.  A constant feature
     is never split on, so the trees must be the same bytes."""
-    monkeypatch.setenv("DMLC_FUSED_ROUND", fused)
     rng = np.random.default_rng(3)
     X = rng.normal(size=(1201, 28)).astype(np.float32)
     y = (X[:, 0] - 0.7 * X[:, 27] + 0.3 * X[:, 13] > 0).astype(np.float32)
@@ -184,7 +218,6 @@ def test_fit_equals_fit_of_zero_padded_features(fused, monkeypatch,
     def saved(X, name):
         m = HistGBT(mesh=local_mesh(1), **kw)
         m.fit(X, y)
-        assert m.round_plan["fused_round"] is (fused == "1")
         assert m.round_plan["hist_features"] == [X.shape[1], 32]
         m.cuts = m.cuts[:28]                 # the file holds the cuts too
         m.save_model(str(tmp_path / name))
@@ -231,9 +264,8 @@ def _compile_for_the_chip(fn, *shapes):
     return _compiled_for_the_chip(fn, *shapes).as_text()
 
 
-@pytest.mark.parametrize("kernel", ["dmlc_hist", "dmlc_fused_round"])
 @pytest.mark.parametrize("F", [28, 31])
-def test_mosaic_compiles_the_tail_at_the_deepest_level(F, kernel, one_chip,
+def test_mosaic_compiles_the_tail_at_the_deepest_level(F, one_chip,
                                                        monkeypatch):
     """The static tail unrolls up to seven more dots beside the loop's
     eight: Mosaic has to take it inside scoped VMEM at the deepest level
@@ -244,18 +276,12 @@ def test_mosaic_compiles_the_tail_at_the_deepest_level(F, kernel, one_chip,
     def S(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    rows = [S((F, n), jnp.uint8), S((n,), jnp.int32), S((n,), jnp.float32),
-            S((n,), jnp.float32)]
-    if kernel == "dmlc_hist":
-        def fn(b, nd, g, h):
-            return H._hist_pallas.__wrapped__(b, nd, g, h, N, bins,
-                                              H._TILE_ROWS, 0, True)
-    else:
-        def fn(b, nd, g, h, prev):
-            return H.fused_round(b, nd, nd, nd, g, h, prev, N, bins)[:2]
-        rows.append(S((2, N, F, bins), jnp.float32))
-    text = _compile_for_the_chip(fn, *rows)
-    assert kernel in text and "tpu_custom_call" in text
+    text = _compile_for_the_chip(
+        lambda b, nd, g, h: H._hist_pallas.__wrapped__(
+            b, nd, g, h, N, bins, H._TILE_ROWS, 0, True),
+        S((F, n), jnp.uint8), S((n,), jnp.int32), S((n,), jnp.float32),
+        S((n,), jnp.float32))
+    assert "dmlc_hist" in text and "tpu_custom_call" in text
 
 
 @pytest.mark.parametrize("n_nodes", [1, 8, 16])

@@ -15,7 +15,7 @@ rows, the block's node ids mapped to ``0..nb-1`` and every other row to
 * the node block is the whole build exactly where the gate said yes
   (``_pallas_ok``, which this PR leaves word for word), and there the
   build traces ``_hist_pallas`` and nothing else: the parent's program;
-* ``auto`` on a TPU never resolves to ``matmul`` for a plain matrix;
+* ``auto`` on a TPU resolves to ``pallas`` for every plain matrix;
 * ``HistGBT.round_plan`` records the node blocks of every build, and the
   trees of a fit whose deep levels are node-blocked are the trees of a
   fit that builds them in one call.
@@ -297,7 +297,7 @@ def test_at_one_block_the_build_traces_hist_pallas_alone(F, n_nodes):
 # -- auto ------------------------------------------------------------------
 
 @pytest.mark.parametrize("F", [1, 28, 392, 2000, 10000])
-def test_auto_on_a_tpu_is_never_matmul_for_a_plain_matrix(F, monkeypatch):
+def test_auto_on_a_tpu_is_pallas_for_a_plain_matrix(F, monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     for n_bins in (32, 64, 256, 1024):
         for n_nodes in range(1, 257):
@@ -313,9 +313,8 @@ def test_explicit_pallas_raises_where_not_even_one_node_fits(monkeypatch):
     with pytest.raises(Error, match="not even 8 rows, for even one node"):
         H.resolve_hist_method("pallas", 1 << 17, 28, 4)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert H.resolve_hist_method("auto", 1 << 17, 28, 4) == "matmul"
-    # the other engines are returned as asked, at any node count
-    assert H.resolve_hist_method("matmul", 256, 28, 64) == "matmul"
+    assert H.resolve_hist_method("auto", 1 << 17, 28, 4) == "segment"
+    # the other engine is returned as asked, at any node count
     assert H.resolve_hist_method("segment", 256, 28, 64) == "segment"
 
 
@@ -338,22 +337,14 @@ def test_round_plan_records_the_node_blocks(depth, F, fblocks_deep,
     shallow = [F] if F == 28 else [392] * 5 + [40]
     assert m.round_plan["hist_feature_blocks"] == [
         shallow if nb < 32 else fblocks_deep for nb in builds]
-    # "auto" plans the staged round at every shape: on the chip the
-    # staged kernels are the faster (PERF.md section 6, PR 45)
-    assert m.round_plan["fused_round"] is False
     assert json.loads(json.dumps(m.round_plan)) == m.round_plan
 
 
-def test_other_engines_have_no_node_blocks():
+def test_the_other_engine_has_no_node_blocks():
     seg = HistGBT(mesh=local_mesh(1), n_trees=2, max_depth=8, n_bins=256)
     seg._round_plan(28)
     assert seg.round_plan["hist_method"] == ["segment"] * 8
     assert seg.round_plan["hist_node_blocks"] == [[]] * 8
-    mm = HistGBT(mesh=local_mesh(1), n_trees=2, max_depth=8, n_bins=256,
-                 hist_method="matmul")
-    mm._round_plan(28)
-    assert mm.round_plan["hist_method"] == ["matmul"] * 8
-    assert mm.round_plan["hist_node_blocks"] == [[]] * 8
 
 
 def test_the_node_blocks_move_the_plan_and_the_cache_key(monkeypatch):

@@ -67,8 +67,6 @@ def test_mesh_fit_against_the_unsharded_reference(chips, rows, whole, limits,
     model.fit_device(handle)
     again = [{k: np.asarray(v) for k, v in t.items()} for t in model.trees]
     assert model.round_plan["mesh_devices"] == chips
-    if chips > 1:
-        assert model.round_plan["fused_round"] is False
 
     cuts, bins_t = whole
     assert np.array_equal(np.asarray(model.cuts), cuts)

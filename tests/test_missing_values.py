@@ -252,7 +252,6 @@ def test_blocked_missing_round_is_the_unblocked_round(bosch, monkeypatch):
     monkeypatch.setattr(H, "_SCOPED_VMEM", _budget(16))
     blocked = fit()
     assert whole.round_plan["missing"] and blocked.round_plan["missing"]
-    assert whole.round_plan["fused_round"] is False
     assert whole.round_plan["hist_node_blocks"][-1] == [16]
     assert whole.round_plan["hist_feature_blocks"][-1] == [64]
     assert blocked.round_plan["hist_node_blocks"][-1] == [4] * 4

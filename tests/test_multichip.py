@@ -258,29 +258,6 @@ class TestOneChipOracle:
         assert m1._bin_layout == m8._bin_layout   # identical layout
         assert _trees_equal(m1.trees, m8.trees)
 
-    def test_fused_round_falls_back_under_hist_blocks(self, monkeypatch,
-                                                      tmp_path):
-        # ISSUE 18: the fused round kernel accumulates in pallas tile
-        # order, which would break the per-block deterministic fold —
-        # so the eligibility gate excludes DMLC_HIST_BLOCKS (and any
-        # multi-chip mesh) even when the knob FORCES fused.  An N-chip
-        # deterministic fit must therefore serialize byte-identically
-        # with the knob on or off.
-        monkeypatch.setenv("DMLC_HIST_BLOCKS", "8")
-        X, y = _make_xy(1003, F=7, seed=1)
-        cuts = compute_cuts(X, KW["n_bins"])
-        devs = np.array(jax.devices())
-
-        def fit_bytes(path, fused):
-            monkeypatch.setenv("DMLC_FUSED_ROUND", fused)
-            m = HistGBT(mesh=Mesh(devs[:8], ("data",)), **KW)
-            m.fit(X, y, cuts=cuts)
-            m.save_model(str(path))
-            return path.read_bytes()
-
-        assert fit_bytes(tmp_path / "off.gbt", "0") \
-            == fit_bytes(tmp_path / "on.gbt", "1")
-
     def test_deterministic_mode_prediction_parity(self, monkeypatch):
         # deterministic-mode trees predict identically from either mesh
         monkeypatch.setenv("DMLC_HIST_BLOCKS", "8")

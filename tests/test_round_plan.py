@@ -4,8 +4,9 @@
   every lever that stays moves the key exactly when it moves the plan;
 * once the plan is resolved, neither the key, the build nor the traced
   closure reads the environment again;
-* the levers that lost on the chip (ISSUE 33) are gone from the knob
-  registry, the configuration page and the package.
+* the levers that lost on the chip (ISSUEs 33 and 47) are gone from
+  the knob registry, the configuration page and the package, and so are
+  the second round kernel and the third histogram engine.
 """
 
 import os
@@ -20,9 +21,11 @@ import jax  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from dmlc_core_tpu.base import knobs  # noqa: E402
+from dmlc_core_tpu.base.logging import Error  # noqa: E402
 from dmlc_core_tpu.models import HistGBT  # noqa: E402
 from dmlc_core_tpu.models import histgbt as hg  # noqa: E402
 from dmlc_core_tpu.ops import binlayout as bl  # noqa: E402
+from dmlc_core_tpu.ops import histogram as H  # noqa: E402
 from dmlc_core_tpu.parallel.mesh import local_mesh  # noqa: E402
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -63,14 +66,10 @@ _LEVERS = {
         dict(env={"DMLC_GROW_POLICY": "lossguide"}),
         dict(env={"DMLC_GROW_POLICY": "lossguide", "DMLC_MAX_LEAVES": "4"}),
         None),
-    "DMLC_FUSED_ROUND": (
-        dict(env={"DMLC_FUSED_ROUND": "0"}, hist_method="pallas"),
-        dict(env={"DMLC_FUSED_ROUND": "1"}, hist_method="pallas"),
-        "fused_round"),
     "packed_layout": (dict(env={}), dict(env={}, layout="packed"),
                       "bin_layout"),
     "hist_method": (dict(env={}, hist_method="segment"),
-                    dict(env={}, hist_method="matmul"), "hist_method"),
+                    dict(env={}, hist_method="pallas"), "hist_method"),
 }
 
 
@@ -104,7 +103,6 @@ def test_cache_key_follows_the_plan(lever, monkeypatch):
     # and a lever set AFTER the plan was resolved moves nothing
     monkeypatch.setenv("DMLC_HIST_BLOCKS", "4")
     monkeypatch.setenv("DMLC_GROW_POLICY", "lossguide")
-    monkeypatch.setenv("DMLC_FUSED_ROUND", "1")
     assert a._round_fn_cache_key(plan_a, 2) == key
     assert a._build_round_fn(plan_a, 2) is fn
 
@@ -114,33 +112,19 @@ def test_cache_key_follows_the_plan(lever, monkeypatch):
 def test_auto_plans_the_staged_round_on_a_tpu(depth, n_features,
                                               monkeypatch):
     """With no ``DMLC_*`` variable set, a one-chip dense fit on a TPU
-    plans the staged round — ``dmlc_hist`` at every level — at every
-    shape the fused kernel used to take (HIGGS's and MSLR's width, any
-    depth up to 7): on the chip it is the faster one (PERF.md section
-    6, PR 45).  ``DMLC_FUSED_ROUND=1`` still plans the fused round: the
-    byte-parity tests' hook."""
+    plans the staged round — ``dmlc_hist`` at every level, one kernel
+    call a build — at HIGGS's and MSLR's width and any depth up to 7."""
     for name in [k for k in os.environ if k.startswith("DMLC_")]:
         monkeypatch.delenv(name)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
-    def resolve():
-        m = HistGBT(mesh=local_mesh(1), n_trees=2, max_depth=depth,
-                    n_bins=256)
-        return m._round_plan(n_features), m.round_plan
-
-    plan, record = resolve()
-    assert plan.fused_round is False and record["fused_round"] is False
+    m = HistGBT(mesh=local_mesh(1), n_trees=2, max_depth=depth, n_bins=256)
+    m._round_plan(n_features)
+    record = m.round_plan
     assert record["hist_method"] == ["pallas"] * depth
     builds = [1] + [1 << (lv - 1) for lv in range(1, depth)]
     assert record["hist_node_blocks"] == [[nb] for nb in builds]
     assert record["hist_feature_blocks"] == [[n_features]] * depth
-    # "0" is the same plan, so the same cached program
-    monkeypatch.setenv("DMLC_FUSED_ROUND", "0")
-    assert resolve()[0] == plan
-    monkeypatch.setenv("DMLC_FUSED_ROUND", "1")
-    asked, record = resolve()
-    assert asked.fused_round is True and record["fused_round"] is True
-    assert record["hist_method"] == ["pallas"] * depth
 
 
 class _NoLevers(dict):
@@ -176,7 +160,7 @@ def test_no_environment_read_once_the_plan_is_resolved(policy, monkeypatch):
     with pytest.raises(AssertionError, match="DMLC_HIST_BLOCKS"):
         m._round_plan(F)                            # the guard does bite
     for helper in ("_hist_blocks", "_max_leaves", "_grow_policy",
-                   "_fused_round_mode", "get_env"):
+                   "get_env"):
         def refuse(*a, _h=helper, **k):
             raise AssertionError(f"{_h} called after _round_plan returned")
         monkeypatch.setattr(hg, helper, refuse)
@@ -202,14 +186,15 @@ def test_round_plan_record_is_the_plans_json_view():
     assert m.round_plan == plan.describe()
     assert json.loads(json.dumps(m.round_plan)) == m.round_plan
     assert set(m.round_plan) == {
-        "hist_method", "fused_round", "missing", "pallas_interpret",
-        "grow_policy", "bin_layout", "hist_features", "hist_feature_blocks",
+        "hist_method", "missing", "pallas_interpret", "grow_policy",
+        "bin_layout", "hist_features", "hist_feature_blocks",
         "hist_node_blocks", "hist_blocks", "mesh_devices"}
     assert m.round_plan["missing"] is False
     # a record of what the Pallas kernels issue per row tile (dots
     # emitted, dots of the padded block): derived, never a field
     assert m.round_plan["hist_features"] == [F, 8]
     assert "hist_features" not in hg._RoundPlan._fields
+    assert "fused_" + "round" not in hg._RoundPlan._fields
     assert m.round_plan["hist_method"] == ["segment"] * KW["max_depth"]
     # feature blocks are the Pallas builds': none for another engine
     assert m.round_plan["hist_feature_blocks"] == [[]] * KW["max_depth"]
@@ -219,13 +204,18 @@ def test_round_plan_record_is_the_plans_json_view():
 
 _DELETED = ["DMLC_TPU_FUSED_" + "DESCEND", "DMLC_HIST_" + "QUANT",
             "DMLC_COLDSTART_" + "OVERLAP", "DMLC_SHARDED_" + "INGEST",
-            "DMLC_WARMUP_" + "EXEC"]
+            "DMLC_WARMUP_" + "EXEC", "DMLC_FUSED_" + "ROUND"]
 
 
 @pytest.mark.parametrize("name", _DELETED)
-def test_deleted_lever_is_gone(name):
+def test_deleted_lever_is_gone(name, monkeypatch):
     assert name not in knobs.names()
-    assert len(knobs.names()) == 100
+    assert len(knobs.names()) == 99
+    # set, it is any undeclared name: the plan and the key do not move
+    m, plan = _model({}, monkeypatch, hist_method="pallas")
+    m1, plan1 = _model({name: "1"}, monkeypatch, hist_method="pallas")
+    assert plan1 == plan and m1.round_plan == m.round_plan
+    assert m1._round_fn_cache_key(plan1, 2) == m._round_fn_cache_key(plan, 2)
     with open(os.path.join(_REPO, "doc", "configuration.md")) as f:
         assert name not in f.read()
     hits = []
@@ -237,3 +227,24 @@ def test_deleted_lever_is_gone(name):
                         hits.append(os.path.relpath(os.path.join(d, fn),
                                                     _REPO))
     assert hits == []
+
+
+#: the one-kernel level, its VMEM gate and the plain-XLA MXU engine
+#: (ISSUE 47): git has them, the package does not
+@pytest.mark.parametrize("name", ["fused_" + "round", "fused_" + "round_ok",
+                                  "_hist_" + "matmul"])
+def test_deleted_kernel_is_gone(name):
+    assert not hasattr(H, name) and name not in H.__all__
+
+
+def test_the_deleted_engine_is_refused_by_the_enum():
+    assert H.histogram_methods() == ["auto", "segment", "pallas"]
+    with pytest.raises(Error, match="'hist_method'.*not in allowed set"):
+        HistGBT(mesh=local_mesh(1), hist_method="mat" + "mul", **KW)
+
+
+def test_the_deleted_engine_is_an_unknown_method():
+    z = np.zeros(8, np.float32)
+    with pytest.raises(Error, match="unknown method"):
+        H.build_histogram(np.zeros((8, 2), np.uint8), z.astype(np.int32),
+                          z, z, 1, 4, "mat" + "mul")
