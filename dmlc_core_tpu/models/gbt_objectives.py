@@ -1,7 +1,7 @@
 """GBT training objectives and eval metrics (the booster's loss surface).
 
 Functional parity: XGBoost's objective registry (reference
-``src/objective/`` family — binary:logistic, multi:softmax,
+``src/objective/`` family — binary:logistic, multi:softmax/softprob,
 reg:squarederror, rank:pairwise; SURVEY.md §1 consumer surface) and its
 ``eval_metric`` table.  Split out of ``histgbt.py`` so the objective
 registry is importable without the tree engine (GBLinear shares it).
@@ -66,31 +66,46 @@ class _Logistic(_ObjectiveBase):
 
 @OBJECTIVES.register("multi:softmax")
 class _Softmax(_ObjectiveBase):
-    """K-class softmax objective (XGBoost ``multi:softmax``) — margins are
-    [n, K]; grad/hess per class from the full softmax row.  ``predict``
-    returns argmax classes (``multi:softprob`` = same training, transform
-    returns the probability matrix)."""
+    """K-class softmax objective (XGBoost ``multi:softmax``).  Margins are
+    CLASS-MAJOR on the device, ``[K, n]``: the rows on the lanes, the
+    layout of every other per-row array of the round, and a class's
+    gradients one contiguous row (``[n, K]`` exists only at the host's
+    edge: ``predict(output_margin=True)``, ``predict_proba``,
+    ``train_margins``).  grad/hess per class from the full softmax over a
+    row's K margins — the classes are coupled through it — with
+    XGBoost's factor 2 on the hessian.  ``predict`` returns the argmax
+    class; ``multi:softprob`` is the same training and returns the
+    probabilities."""
 
     @staticmethod
-    def grad_hess(pred, y):                  # pred [n,K], y [n] labels
-        K = pred.shape[1]
-        prob = jax.nn.softmax(pred, axis=1)
-        yoh = jax.nn.one_hot(y.astype(jnp.int32), K, dtype=pred.dtype)
+    def grad_hess(pred, y):                  # pred [K, n], y [n] labels
+        prob = jax.nn.softmax(pred, axis=0)
+        yoh = jax.nn.one_hot(y.astype(jnp.int32), pred.shape[0],
+                             dtype=pred.dtype, axis=0)
         return prob - yoh, jnp.maximum(2.0 * prob * (1.0 - prob), 1e-6)
 
     @staticmethod
-    def transform(pred):                     # class index
-        return jnp.argmax(pred, axis=1).astype(jnp.float32)
+    def transform(pred):                     # [K, n] -> class index [n]
+        return jnp.argmax(pred, axis=0).astype(jnp.float32)
 
     @staticmethod
-    def prob(pred):
-        return jax.nn.softmax(pred, axis=1)
+    def prob(pred):                          # [K, n] -> [K, n]
+        return jax.nn.softmax(pred, axis=0)
 
     @staticmethod
-    def row_loss(pred, y):                   # mlogloss
-        logp = jax.nn.log_softmax(pred, axis=1)
+    def row_loss(pred, y):                   # mlogloss, [K, n] -> [n]
+        logp = jax.nn.log_softmax(pred, axis=0)
         return -jnp.take_along_axis(
-            logp, y.astype(jnp.int32)[:, None], axis=1)[:, 0]
+            logp, y.astype(jnp.int32)[None, :], axis=0)[0]
+
+
+@OBJECTIVES.register("multi:softprob")
+class _Softprob(_Softmax):
+    """``multi:softmax``'s training; ``predict`` returns the ``[n, K]``
+    class probabilities (``XGBClassifier``'s default for several
+    classes) where ``multi:softmax`` returns the argmax class."""
+
+    transform = _Softmax.prob
 
 
 @OBJECTIVES.register("reg:squarederror")
@@ -602,7 +617,7 @@ EVAL_METRICS = {
     "mae": (lambda m, y: jnp.mean(jnp.abs(m - y)), False),
     "mlogloss": (_Softmax.metric, False),
     "merror": (lambda m, y: jnp.mean(
-        jnp.argmax(m, axis=1) != y.astype(jnp.int32)), False),
+        jnp.argmax(m, axis=0) != y.astype(jnp.int32)), False),
 }
 
 #: which metrics make sense for which objective's margin shape
@@ -610,6 +625,7 @@ _METRICS_BY_OBJECTIVE = {
     "binary:logistic": {"logloss", "error", "auc"},
     "reg:squarederror": {"rmse", "mae"},
     "multi:softmax": {"mlogloss", "merror"},
+    "multi:softprob": {"mlogloss", "merror"},
     # rank eval (ndcg/map) needs qid groups, which EVAL_METRICS'
     # (margin, y) signature can't see — use models.ranking.ndcg on
     # predictions instead; in-training eval reports pairwise loss

@@ -214,6 +214,8 @@ class _RoundPlan(NamedTuple):
     #: a ranking handle's static group table (its width buckets): the
     #: shapes of the gradient stage, ``None`` for every other objective
     rank: Optional[RankGroups] = None
+    #: trees a round: the classes of a ``multi:*`` objective (1 otherwise)
+    num_class: int = 1
 
     def describe(self) -> Dict[str, Any]:
         """The JSON-serialisable record left on ``HistGBT.round_plan``."""
@@ -234,6 +236,12 @@ class _RoundPlan(NamedTuple):
             "hist_node_blocks": [list(b) for b in self.hist_node_blocks],
             "hist_blocks": self.hist_blocks,
             "mesh_devices": self.mesh_devices,
+            # a multi:* round grows one tree a class from margins held
+            # class-major on the device (rows on the lanes)
+            "num_class": self.num_class,
+            "trees_per_round": self.num_class,
+            "margin_layout": "[num_class, n]" if self.num_class > 1
+                             else "[n]",
             **(self.rank.describe() if self.rank is not None else {}),
         }
 
@@ -382,8 +390,7 @@ class _RoundProgramWarmup:
         mesh = model.mesh
         mat = NamedSharding(mesh, P(None, "data"))
         row = NamedSharding(mesh, P("data"))
-        margin = (NamedSharding(mesh, P("data", None))
-                  if p.num_class > 1 else row)
+        margin = NamedSharding(mesh, model._margin_spec())
         # packed/bundled layouts change the PHYSICAL bin-matrix height;
         # the layout is part of the plan, hence of the cache key, so a
         # mismatch between what was warmed and what fit dispatches is
@@ -473,14 +480,13 @@ def _transpose_from_feature_major_fn(mesh: Mesh):
 # and evicting the jit wrapper drops the last reference to its compiled
 # executables (pre-cache, per-instance closures freed with the instance)
 @lru_cache(maxsize=256)
-def _init_margin_fn(mesh: Mesh, shape: tuple, base_score: float,
-                    multiclass: bool):
+def _init_margin_fn(mesh: Mesh, shape: tuple, base_score: float, spec: P):
     """Shared jitted on-device base-score fill (see
-    :meth:`HistGBT._init_margin_device`)."""
-    sh = NamedSharding(mesh, P("data", None) if multiclass else P("data"))
+    :meth:`HistGBT._init_margin_device`): ``[n]``, or class-major
+    ``[K, n]``, sharded by ``spec`` (:meth:`HistGBT._margin_spec`)."""
     return jax.jit(
         lambda: jnp.full(shape, base_score, jnp.float32),
-        out_shardings=sh)
+        out_shardings=NamedSharding(mesh, spec))
 
 
 
@@ -531,14 +537,17 @@ class HistGBTParam(Parameter):
     min_child_weight = field(float, default=1.0, lower_bound=0.0)
     objective = field(str, default="binary:logistic",
                       enum=["binary:logistic", "reg:squarederror",
-                            "multi:softmax", "rank:pairwise",
+                            "multi:softmax", "multi:softprob",
+                            "rank:pairwise",
                             "rank:ndcg", "rank:map"])
     max_group_size = field(int, default=0, lower_bound=0,
                            description="rank:pairwise — cap docs per "
                                        "query (0 = largest group; larger "
                                        "groups are truncated)")
     num_class = field(int, default=1, lower_bound=1,
-                      description="classes for multi:softmax")
+                      description="classes of a multi:* objective; left "
+                                  "at 1 it is learned from the first "
+                                  "labels seen (largest label + 1)")
     base_score = field(float, default=0.0, description="initial raw margin")
     scale_pos_weight = field(float, default=1.0, lower_bound=0.0,
                              description="binary:logistic — weight "
@@ -585,12 +594,12 @@ class HistGBT(_ExternalMemoryEngine):
         CHECK(self.param.subsample > 0.0, "subsample must be in (0, 1]")
         CHECK(self.param.colsample_bytree > 0.0,
               "colsample_bytree must be in (0, 1]")
-        if self.param.objective == "multi:softmax":
-            CHECK(self.param.num_class >= 2,
-                  "multi:softmax needs num_class >= 2")
-        else:
+        # under multi:* a num_class left unset (1) is learned from the
+        # labels where the data entry points scan them
+        # (:meth:`_settle_num_class`), as XGBClassifier does
+        if not self.param.objective.startswith("multi:"):
             CHECK(self.param.num_class == 1,
-                  f"num_class > 1 requires multi:softmax, "
+                  f"num_class > 1 requires a multi:* objective, "
                   f"got {self.param.objective!r}")
         if self.param.eval_metric:
             allowed = _METRICS_BY_OBJECTIVE[self.param.objective]
@@ -718,9 +727,7 @@ class HistGBT(_ExternalMemoryEngine):
             CHECK(eval_set is not None,
                   "early_stopping_rounds needs an eval_set")
 
-        if p.num_class > 1:
-            CHECK(y.min() >= 0 and y.max() < p.num_class,
-                  f"multi:softmax labels must be in [0, {p.num_class})")
+        self._settle_num_class(y)
         if p.monotone_constraints:
             CHECK_EQ(len(p.monotone_constraints), F,
                      "monotone_constraints length must equal n_features")
@@ -772,7 +779,7 @@ class HistGBT(_ExternalMemoryEngine):
             # elastic-recovery resume path is exactly that case.  The
             # base margin is laid out with the target sharding so the
             # replayed margins inherit it by propagation.
-            tgt_sharding = mat_sharding if K_cls > 1 else row_sharding
+            tgt_sharding = NamedSharding(self.mesh, self._margin_spec())
             preds = self._apply_trees(
                 bins, self._stacked_trees(self.trees),
                 jax.device_put(np.full(margin_shape, p.base_score,
@@ -1005,8 +1012,7 @@ class HistGBT(_ExternalMemoryEngine):
         self.last_compile_seconds = None
         self.last_compile_cache = None
         row_sh = NamedSharding(self.mesh, P("data"))
-        margin_sh = (NamedSharding(self.mesh, P("data", None))
-                     if p.num_class > 1 else row_sh)
+        margin_sh = NamedSharding(self.mesh, self._margin_spec())
         shardings_ok = (
             bins_t.sharding == NamedSharding(self.mesh,
                                              P(None, "data"))
@@ -1193,6 +1199,23 @@ class HistGBT(_ExternalMemoryEngine):
         """:func:`apply_bins_t`'s ``miss_bin``: the reserved NaN bin in
         missing mode, else None (NaN is refused before it gets there)."""
         return self._miss_bin() if self._missing else None
+
+    def _settle_num_class(self, y: np.ndarray) -> None:
+        """Under a ``multi:*`` objective, where a data entry point scans
+        its labels: learn ``num_class`` if it was left unset (the largest
+        label + 1, as ``XGBClassifier`` does; it then stays the model's)
+        and hold every label to ``[0, num_class)``."""
+        p = self.param
+        if not p.objective.startswith("multi:") or not len(y):
+            return
+        lo, hi = float(np.min(y)), float(np.max(y))
+        if p.num_class == 1:
+            CHECK(lo >= 0 and hi >= 1,
+                  f"{p.objective}: num_class is unset and the labels "
+                  f"[{lo:g}, {hi:g}] do not name two classes 0..K-1")
+            p.num_class = int(hi) + 1
+        CHECK(lo >= 0 and hi < p.num_class,
+              f"{p.objective} labels must be in [0, {p.num_class})")
 
     def _fold_scale_pos_weight(self, y, weight):
         """Fold ``scale_pos_weight`` into the instance-weight vector —
@@ -1646,10 +1669,7 @@ class HistGBT(_ExternalMemoryEngine):
                 # async H2D piece must never see the next slab's bytes
                 X_s = np.array(X_s, dtype=np.float32)
                 y_np = np.array(y_s, dtype=np.float32)
-                if p.num_class > 1 and len(y_np):
-                    CHECK(y_np.min() >= 0 and y_np.max() < p.num_class,
-                          f"multi:softmax labels must be in "
-                          f"[0, {p.num_class})")
+                self._settle_num_class(y_np)
                 ys.append(y_np)
                 ws.append(self._fold_scale_pos_weight(
                     y_np, np.ones(len(y_np), np.float32) if w_s is None
@@ -1748,6 +1768,7 @@ class HistGBT(_ExternalMemoryEngine):
                       f"(objective is {p.objective!r})")
             n, F = X.shape
             sp.set(features=F)
+            self._settle_num_class(y)
             weight = self._fold_scale_pos_weight(y, weight)
             with span("dmlc.ingest.host_prep.nan_scan", bytes=X.nbytes):
                 missing_share = self._settle_missing_mode(X, cuts)
@@ -2011,7 +2032,7 @@ class HistGBT(_ExternalMemoryEngine):
         p = self.param
         shape = self._margin_shape(n_padded)
         return _init_margin_fn(self.mesh, shape, p.base_score,
-                               p.num_class > 1)()
+                               self._margin_spec())()
 
     def fit_device(
         self,
@@ -2089,7 +2110,7 @@ class HistGBT(_ExternalMemoryEngine):
         """
         n_padded = device_data["n_padded"]
         carried = self._train_preds
-        if carried is not None and getattr(carried, "shape", (0,))[0] == n_padded:
+        if carried is not None and getattr(carried, "shape", (0,))[-1] == n_padded:
             return carried
         return self._replay_margin_device(device_data)
 
@@ -2189,7 +2210,8 @@ class HistGBT(_ExternalMemoryEngine):
             layout=layout,
             hist_blocks=det_blocks,
             mesh_devices=dsize,
-            rank=getattr(self._obj, "groups", None))
+            rank=getattr(self._obj, "groups", None),
+            num_class=p.num_class)
         self.round_plan = plan.describe()
         return plan
 
@@ -2216,7 +2238,7 @@ class HistGBT(_ExternalMemoryEngine):
         return (self.mesh, n_rounds, p.max_depth, p.n_bins,
                 p.learning_rate, p.reg_lambda, p.reg_alpha, p.gamma,
                 p.min_child_weight, obj_key, mono, p.subsample,
-                p.colsample_bytree, p.num_class, plan)
+                p.colsample_bytree, plan)
 
     def _build_round_fn(self, plan: _RoundPlan, n_rounds: int = 1):
         """Jitted shard_map program running ``n_rounds`` boosting rounds
@@ -2735,7 +2757,7 @@ class HistGBT(_ExternalMemoryEngine):
 
         grow = grow_tree_lossguide if lossguide else grow_tree
 
-        n_class = p.num_class
+        n_class = plan.num_class
 
         ranking = plan.rank is not None
 
@@ -2755,30 +2777,33 @@ class HistGBT(_ExternalMemoryEngine):
                 tree, delta = grow(bins_tl, g, h, feat_mask)
                 with jax.named_scope("dmlc.round.update"):
                     return preds_l + delta, tree
-            # multiclass: preds_l [n, K]; one tree per class per round,
-            # built on the full-softmax gradients (XGBoost multi:softmax)
+            # multiclass: preds_l class-major [K, n] (rows on the lanes,
+            # a class's margins one contiguous row); one tree per class
+            # per round, built on the full-softmax gradients (XGBoost
+            # multi:softmax), every class from the margins the round
+            # STARTED with
             with jax.named_scope("dmlc.round.grad"):
-                g_all, h_all = obj.grad_hess(preds_l, y_l)    # [n, K]
-                g_all = g_all * w_l[:, None]
-                h_all = h_all * w_l[:, None]
+                g_all, h_all = obj.grad_hess(preds_l, y_l)    # [K, n]
+                g_all = g_all * w_l
+                h_all = h_all * w_l
                 if keep is not None:                      # same rows ∀ class
-                    g_all = jnp.where(keep[:, None], g_all, 0.0)
-                    h_all = jnp.where(keep[:, None], h_all, 0.0)
-            class_trees = []
-            deltas = []
-            for c in range(n_class):
-                tree_c, delta_c = grow(
-                    bins_tl, g_all[:, c], h_all[:, c], feat_mask)
-                class_trees.append(tree_c)
-                deltas.append(delta_c)
-            tree_keys = ("feat", "thr", "gain", "leaf") + (
-                ("dir",) if missing else ())
-            tree = {key_: jnp.stack([t[key_] for t in class_trees])
-                    for key_ in tree_keys}                    # [K, ...]
-            with jax.named_scope("dmlc.round.update"):
-                return preds_l + jnp.stack(deltas, axis=1), tree
+                    g_all = jnp.where(keep, g_all, 0.0)
+                    h_all = jnp.where(keep, h_all, 0.0)
 
-        preds_spec = P("data", None) if n_class > 1 else P("data")
+            # the class loop, rolled: ONE tree's program, K trips (seven
+            # unrolled copies of grow_tree are seven times the program to
+            # compile and to read back from the cache); the trees are
+            # byte-identical to the unrolled loop's
+            def one_class(_, gh_c):
+                return None, grow(bins_tl, gh_c[0], gh_c[1], feat_mask)
+
+            with jax.named_scope("dmlc.round.class"):
+                _, (tree, deltas) = jax.lax.scan(
+                    one_class, None, (g_all, h_all))      # [K, ...], [K, n]
+            with jax.named_scope("dmlc.round.update"):
+                return preds_l + deltas, tree
+
+        preds_spec = P(None, "data") if n_class > 1 else P("data")
         # a ranking handle's group table, then the sampling key, follow
         # the four arrays every fit has
         table_specs = (obj.table_specs(),) if ranking else ()
@@ -2842,9 +2867,9 @@ class HistGBT(_ExternalMemoryEngine):
         p = self.param
         X = np.ascontiguousarray(X, dtype=np.float32)
         self._check_nan_allowed(X, "predict")
-        if len(X) == 0:
-            return np.zeros(self._margin_shape(0), np.float32)
         transform = None if output_margin else self._obj.transform
+        if len(X) == 0:
+            return self._no_rows(transform)
         miss_bin = self._miss_bin()
         outs = []
         for lo in range(0, len(X), self._PREDICT_BATCH):
@@ -2866,7 +2891,10 @@ class HistGBT(_ExternalMemoryEngine):
                 with span("dmlc.predict.fetch.wait"):
                     out_d.block_until_ready()
                 with span("dmlc.predict.fetch.copy", bytes=out_d.nbytes):
-                    outs.append(np.asarray(out_d))
+                    out = np.asarray(out_d)
+                    # class-major on the device, [n, K] for the caller
+                    outs.append(out if out.ndim == 1
+                                else np.ascontiguousarray(out.T))
             if _metrics.enabled():
                 # np.asarray above is a real fetch, so this wall delta
                 # covers bin + tree apply + D2H for the batch
@@ -2910,8 +2938,17 @@ class HistGBT(_ExternalMemoryEngine):
                     for xb, _, _ in iter_dense_slabs(row_iter, F,
                                                      batch_rows)]
         if not outs:
-            return np.zeros(self._margin_shape(0), np.float32)
+            return self._no_rows(None if output_margin
+                                 else self._obj.transform)
         return np.concatenate(outs) if len(outs) > 1 else outs[0]
+
+    def _no_rows(self, transform) -> np.ndarray:
+        """What ``predict`` answers for no rows: the shape of its answer
+        for some (``[0, K]`` where a row's answer is K numbers)."""
+        out = jax.eval_shape(
+            lambda m: m if transform is None else transform(m),
+            jax.ShapeDtypeStruct(self._margin_shape(0), np.float32))
+        return np.zeros(out.shape[::-1], np.float32)
 
     def predict_leaf(self, X: np.ndarray,
                      n_trees: Optional[int] = None) -> np.ndarray:
@@ -2962,18 +2999,21 @@ class HistGBT(_ExternalMemoryEngine):
         """Class probability matrix [n, K] (``multi:softprob`` semantics);
         for the binary objective, [n, 2] columns (1-p, p)."""
         p = self.param
-        CHECK(p.objective in ("binary:logistic", "multi:softmax"),
+        CHECK(p.objective in ("binary:logistic", "multi:softmax",
+                              "multi:softprob"),
               f"predict_proba needs a classification objective, "
               f"got {p.objective!r}")
         margin = self.predict(X, output_margin=True, n_trees=n_trees)
-        if p.num_class > 1:
-            return np.asarray(self._obj.prob(jnp.asarray(margin)))
+        if margin.ndim == 2:           # [n, K]; the objective's is [K, n]
+            return np.ascontiguousarray(
+                np.asarray(self._obj.prob(jnp.asarray(margin.T))).T)
         prob1 = np.asarray(self._obj.transform(jnp.asarray(margin)))
         return np.stack([1.0 - prob1, prob1], axis=1)
 
     def train_margins(self) -> np.ndarray:
         """Raw training-set margins after fit (real rows only).
 
+        ``[n]``, under a ``multi:*`` objective ``[n, K]``.
         Available after :meth:`fit` and ``fit_external(cache_device=
         True)``; the page-loop external path keeps margins per page and
         clears this state (stale-evidence rule in fit_external).  After
@@ -2984,6 +3024,8 @@ class HistGBT(_ExternalMemoryEngine):
               "call fit first (train_margins is unavailable after a "
               "cache_device=False external fit)")
         flat = np.asarray(self._train_preds)
+        if flat.ndim == 2:             # class-major on the device
+            return np.ascontiguousarray(flat[:, : self._n_real_rows].T)
         pos = getattr(self, "_rank_pos", None)
         if pos is not None:
             out = np.full(len(pos), np.nan, np.float32)
@@ -2993,9 +3035,14 @@ class HistGBT(_ExternalMemoryEngine):
         return flat[: self._n_real_rows]
 
     def _margin_shape(self, n: int) -> Tuple[int, ...]:
-        """Margins are [n] single-output, [n, K] multiclass."""
+        """Margins ON THE DEVICE are [n] single-output and class-major
+        [K, n] multiclass (``[n, K]`` only at the host's edge)."""
         K = self.param.num_class
-        return (n, K) if K > 1 else (n,)
+        return (K, n) if K > 1 else (n,)
+
+    def _margin_spec(self) -> P:
+        """How device margins shard: the rows over ``data``."""
+        return P(None, "data") if self.param.num_class > 1 else P("data")
 
     def _stacked_trees(self, trees: List[Dict[str, np.ndarray]],
                        engine: str = "incore"
@@ -3439,23 +3486,24 @@ def _predict_trees(bins, feats, thrs, leaves, depth: int,
 
 
 def _add_trees(bins, forest, margin, depth: int, miss_bin: int):
-    """``margin`` ([n], multiclass [n, K]) plus the leaf values of
-    ``forest``, a dict of tree tables [T, ...] (multiclass [T, K, ...]:
-    class c's trees add onto column c) — :func:`_predict_trees` with the
-    margin as its ``init``."""
+    """``margin`` ([n], multiclass class-major [K, n]) plus the leaf
+    values of ``forest``, a dict of tree tables [T, ...] (multiclass
+    [T, K, ...]: class c's trees add onto row c) —
+    :func:`_predict_trees` with the margin as its ``init``, for several
+    classes ONE descent program walked K times (``lax.map`` over the
+    class axis)."""
     dirs = forest.get("dir")
     if forest["feat"].ndim == 4:       # multiclass: [T, K, depth, half]
-        cols = [
-            _predict_trees(bins,
-                           forest["feat"][:, c],
-                           forest["thr"][:, c],
-                           forest["leaf"][:, c], depth, 0.0,
-                           margin[:, c],
-                           dirs[:, c] if dirs is not None else None,
-                           miss_bin)
-            for c in range(forest["feat"].shape[1])
-        ]
-        return jnp.stack(cols, axis=1)
+        by_class = jax.tree.map(
+            lambda a: jnp.moveaxis(a, 1, 0),
+            (forest["feat"], forest["thr"], forest["leaf"], dirs))
+
+        def one_class(args):
+            (feat, thr, leaf, dirv), init = args
+            return _predict_trees(bins, feat, thr, leaf, depth, 0.0, init,
+                                  dirv, miss_bin)
+
+        return jax.lax.map(one_class, (by_class, margin))
     return _predict_trees(bins, forest["feat"], forest["thr"],
                           forest["leaf"], depth, 0.0, margin, dirs, miss_bin)
 
@@ -3488,7 +3536,7 @@ def _predict_slab(x, cuts, chunks, depth: int, miss_bin: int,
     n_out = forest["leaf"].shape[1:-1]     # () or, multiclass, (K,)
     margin = _add_trees(
         bins, forest,
-        jnp.full(x.shape[:1] + n_out, base_score, jnp.float32),
+        jnp.full(n_out + x.shape[:1], base_score, jnp.float32),
         depth, miss_bin)
     return margin if transform is None else transform(margin)
 

@@ -48,11 +48,11 @@ __all__ = ["_ExternalMemoryEngine"]
 # recompiled every call (~2·depth+5 programs, seconds each on a 1-core
 # host).
 
-@partial(jax.jit, static_argnames=("obj", "multiclass"))
-def _ext_gh(preds, y, wk, *, obj, multiclass):
+@partial(jax.jit, static_argnames=("obj",))
+def _ext_gh(preds, y, wk, *, obj):
+    # multiclass margins are class-major [K, n]: the weights broadcast
     g, h = obj.grad_hess(preds, y)
-    w_col = wk[:, None] if multiclass else wk
-    return g * w_col, h * w_col
+    return g * wk, h * wk
 
 
 @partial(jax.jit, static_argnames=("level", "col", "B", "method"))
@@ -64,8 +64,8 @@ def _ext_adv_hist_lvl(bins, node, g, h, feat_prev, thr_prev, *,
     again for advance."""
     if level > 0:
         node = _advance_node(bins, node, feat_prev, thr_prev)
-    g_c = g if col is None else g[:, col]
-    h_c = h if col is None else h[:, col]
+    g_c = g if col is None else g[col]
+    h_c = h if col is None else h[col]
     n_nodes = 1 << level
     n_build = 1 if level == 0 else n_nodes >> 1
     nd = node
@@ -101,7 +101,7 @@ def _ext_upd_preds(preds, node, leaf, *, col, n_leaf):
     gain = leaf[jnp.clip(node, 0, n_leaf - 1)]
     if col is None:
         return preds + gain
-    return preds.at[:, col].add(gain)
+    return preds.at[col].add(gain)
 
 
 @partial(jax.jit, static_argnames=("lam", "eta", "alpha"))
@@ -125,7 +125,7 @@ def _ext_pack_tree(feats, thrs, gains, leaf, *, half):
 
 @partial(jax.jit, static_argnames=("nv", "obj"))
 def _ext_eval_loss(preds, y, *, nv, obj):
-    return jnp.sum(obj.row_loss(preds[:nv], y[:nv]))
+    return jnp.sum(obj.row_loss(preds[..., :nv], y[:nv]))
 
 
 @lru_cache(maxsize=256)
@@ -252,7 +252,6 @@ class _ExternalMemoryEngine:
             self.cuts = sketch.finalize(B, allgather_fn=self._maybe_allgather())
 
         # -- pass 2: bin pages (uint8, FEATURE-major like fit()) -----------
-        K_cls = p.num_class
         pages: List[Dict[str, Any]] = []   # "bins" is a jax.Array when cache_device
         # DMLC_TPU_BIN_BACKEND=cpu (see _host_bin_requested) bins pages on
         # the host backend and uploads nothing per page: the cached
@@ -286,11 +285,10 @@ class _ExternalMemoryEngine:
                 "y": np.asarray(block.label, np.float32),
                 "w": w,
             })
-        if K_cls > 1:
-            for pg in pages:
-                if len(pg["y"]):   # empty shard pages are legal
-                    CHECK(pg["y"].min() >= 0 and pg["y"].max() < K_cls,
-                          f"multi:softmax labels must be in [0, {K_cls})")
+        # empty shard pages are legal; an unset num_class is learned from
+        # the first page's labels (give it where pages hold few classes)
+        for pg in pages:
+            self._settle_num_class(pg["y"])
 
         distributed = coll.world_size() > 1
         if cache_device and not distributed:
@@ -310,7 +308,7 @@ class _ExternalMemoryEngine:
         N_total = sum(len(pg["y"]) for pg in pages)
         from dmlc_core_tpu.base.parameter import get_env
         budget = get_env("DMLC_TPU_EXTERNAL_DEVICE_BUDGET", 6 << 30, int)
-        row_state = 12 + 12 * K_cls          # y/w/node + preds/g/h per class
+        row_state = 12 + 12 * p.num_class    # y/w/node + preds/g/h per class
         no_sampling = p.subsample >= 1.0 and p.colsample_bytree >= 1.0
         if (not distributed and no_sampling
                 and N_total * (2 * F + row_state) <= budget):
@@ -385,8 +383,7 @@ class _ExternalMemoryEngine:
         row_sharding = NamedSharding(self.mesh, P("data"))
         y_d = jax.device_put(y, row_sharding)
         w_d = jax.device_put(w, row_sharding)
-        margin_sharding = (NamedSharding(self.mesh, P("data", None))
-                           if p.num_class > 1 else row_sharding)
+        margin_sharding = NamedSharding(self.mesh, self._margin_spec())
         preds = jax.device_put(
             np.full(self._margin_shape(n + n_pad), p.base_score, np.float32),
             margin_sharding)
@@ -519,7 +516,7 @@ class _ExternalMemoryEngine:
         # -- device-resident per-row state ------------------------------
         y_d = [jnp.asarray(y_h[c]) for c in range(n_chunks)]
         w_d = [jnp.asarray(w_h[c]) for c in range(n_chunks)]
-        mshape = (Rc, K_cls) if K_cls > 1 else (Rc,)
+        mshape = (K_cls, Rc) if K_cls > 1 else (Rc,)
         init_margin = _ext_const_fn(mshape, p.base_score, "float32")
         preds_d = [init_margin() for _ in range(n_chunks)]
         zeros_node = _ext_const_fn((Rc,), 0, "int32")()
@@ -547,7 +544,7 @@ class _ExternalMemoryEngine:
         # -- round pieces: module-level jits (_ext_*) bound to this fit's
         # config via static kwargs, so compiled programs persist across
         # fits/instances in jax.jit's own cache
-        gh_fn = partial(_ext_gh, obj=obj, multiclass=K_cls > 1)
+        gh_fn = partial(_ext_gh, obj=obj)
 
         def adv_hist_lvl(bins, node, g, h, feat_prev, thr_prev, level, col):
             return _ext_adv_hist_lvl(bins, node, g, h, feat_prev, thr_prev,
@@ -612,8 +609,8 @@ class _ExternalMemoryEngine:
                 gains.append(gain)
             gsum = hsum = None
             for c in range(n_chunks):
-                g_c = g_d[c] if col is None else g_d[c][:, col]
-                h_c = h_d[c] if col is None else h_d[c][:, col]
+                g_c = g_d[c] if col is None else g_d[c][col]
+                h_c = h_d[c] if col is None else h_d[c][col]
                 node[c], gs, hs = timed_phase(
                     "leaf", final_adv_leaf, chunk_bins(c), node[c],
                     g_c, h_c, feat, thr)

@@ -438,6 +438,16 @@ def apply_bins_t(x: jax.Array, cuts: jax.Array,
     EVERY float32: NaN compares false, so it counts every cut and lands
     in ``n_cuts``.
 
+    TIES: a value EQUAL to a cut counts that cut — it falls in the bin to
+    the cut's RIGHT (``bin`` = number of cuts ``<= v``), and a tree's
+    ``bin > thr`` sends ``v >= cuts[f, thr]`` right.  Integer-valued and
+    indicator columns sit on their cuts in every row (a two-valued
+    column's cuts repeat one value, bumped apart by ``max(|c|, 1) * 1e-6``
+    steps: all of them count for the larger value, the first alone for
+    the smaller); the benchmark's reference bins by the same rule
+    (``reference.bin_rows``: ``searchsorted(..., side="right")``) and the
+    multiclass cell counts the mismatches on such columns.
+
     ``miss_bin`` (missing mode) sends NaN to that reserved bin instead —
     the caller reserves its top bin; without it NaN would alias the top
     VALUE bin and score garbage.

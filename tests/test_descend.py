@@ -155,8 +155,8 @@ def test_descent_equals_the_gather_descent(case, program):
                          ids=["plain", "missing"])
 def test_apply_trees_multiclass_equals_the_gather_descent(missing):
     """`_apply_trees` on a [T, K, depth, half] forest of 70 trees: two
-    chunks of `_TREE_CHUNK`, the second zero-padded, margins [n, K]
-    threaded through both."""
+    chunks of `_TREE_CHUNK`, the second zero-padded, margins class-major
+    [K, n] (as the device holds them) threaded through both."""
     depth, T, K, F, n = 3, 70, 3, 28, 1000
     rng = np.random.default_rng(3)
     per_class = [_forest(rng, T, depth, F, missing) for _ in range(K)]
@@ -176,7 +176,8 @@ def test_apply_trees_multiclass_equals_the_gather_descent(missing):
     stacked = model._stacked_trees(trees)
     assert [c["feat"].shape for c in stacked] == [
         (G._TREE_CHUNK, K, depth, 1 << (depth - 1))] * 2
-    got = model._apply_trees(jnp.asarray(bins), stacked, jnp.asarray(init))
+    got = model._apply_trees(jnp.asarray(bins), stacked,
+                             jnp.asarray(init.T)).T
     want = np.stack(
         [np.asarray(_gather_predict(
             bins, *per_class[c][:3], depth, init[:, c], per_class[c][3],

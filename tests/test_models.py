@@ -434,7 +434,7 @@ class TestHistGBT:
         with pytest.raises(Error):
             HistGBT(max_depth=50)
         with pytest.raises(Error):
-            HistGBT(objective="multi:softmax")
+            HistGBT(objective="multi:softmax", num_class=0)
 
 
 class TestGBTExtras:
@@ -790,8 +790,17 @@ class TestMulticlass:
         from dmlc_core_tpu.base.logging import Error
         from dmlc_core_tpu.models import HistGBT
 
-        with pytest.raises(Error):
-            HistGBT(objective="multi:softmax")           # num_class missing
+        # num_class left unset under multi:* is learned from the labels
+        # (as XGBClassifier does) where make_device_data / fit scan them
+        m = HistGBT(objective="multi:softmax", n_trees=1, max_depth=2,
+                    n_bins=16)
+        assert m.param.num_class == 1                    # not yet known
+        X, y = self._data(n=500)
+        m.fit(X, y)
+        assert m.param.num_class == 3 and m.trees[0]["leaf"].shape[0] == 3
+        with pytest.raises(Error):                       # one class only
+            HistGBT(objective="multi:softmax").make_device_data(
+                X, np.zeros(len(y), np.float32))
         with pytest.raises(Error):
             HistGBT(num_class=3)                         # objective not multi
 

@@ -75,8 +75,10 @@ def _as_five_programs(model, X, output_margin, n_trees=None):
     margin = model._apply_trees(
         bins, stacked, jnp.full(model._margin_shape(len(X)),
                                 model.param.base_score, jnp.float32))
-    return np.asarray(margin if output_margin
-                      else model._obj.transform(margin))
+    out = np.asarray(margin if output_margin
+                     else model._obj.transform(margin))
+    # several classes: class-major on the device, [n, K] for the caller
+    return out if out.ndim == 1 else np.ascontiguousarray(out.T)
 
 
 # -- (a) the answers ---------------------------------------------------------
