@@ -55,6 +55,7 @@ from dmlc_core_tpu.data.iter import slab_shard_slices
 from dmlc_core_tpu.ops import binlayout as _bl
 from dmlc_core_tpu.ops.histogram import (build_histogram,
                                          descend_histogram,
+                                         hist_class_blocks,
                                          hist_feature_blocks,
                                          hist_feature_dots,
                                          hist_node_blocks,
@@ -216,6 +217,11 @@ class _RoundPlan(NamedTuple):
     rank: Optional[RankGroups] = None
     #: trees a round: the classes of a ``multi:*`` objective (1 otherwise)
     num_class: int = 1
+    #: classes of each kernel call of each Pallas build, likewise a
+    #: record: ``(1,)`` for one tree a round; a ``multi:*`` round's K
+    #: trees grow together and a build stacks as many classes a call as
+    #: fit the MXU's 128 rows — the stacked kernel's engagement counter
+    hist_class_blocks: Tuple[Tuple[int, ...], ...] = ()
 
     def describe(self) -> Dict[str, Any]:
         """The JSON-serialisable record left on ``HistGBT.round_plan``."""
@@ -234,6 +240,7 @@ class _RoundPlan(NamedTuple):
             "hist_feature_blocks": [list(b) for b in
                                     self.hist_feature_blocks],
             "hist_node_blocks": [list(b) for b in self.hist_node_blocks],
+            "hist_class_blocks": [list(b) for b in self.hist_class_blocks],
             "hist_blocks": self.hist_blocks,
             "mesh_devices": self.mesh_devices,
             # a multi:* round grows one tree a class from margins held
@@ -259,6 +266,56 @@ def _pack_matrix_fn(mesh: Mesh, layout: "_bl.BinLayout"):
     sharded P(None, "data") so the pack is shard-local."""
     return jax.jit(lambda bt: _bl.pack_matrix(bt, layout),
                    out_shardings=NamedSharding(mesh, P(None, "data")))
+
+
+#: classes one batch of a multiclass round grows together: a ``[8, n]``
+#: 32-bit array fills its sublane tiles, and no kernel call stacks more
+#: (8 x the shortest left operand, A = 16, are the MXU's 128 rows:
+#: ``ops.hist_class_blocks``).  Every ``[K, n]`` int32 intermediate of a
+#: level is 4·K·n bytes (0.26 GiB at Covertype's 7 x 9.3M).
+_CLASS_BATCH = 8
+
+
+def _class_batches(n_class: int) -> Tuple[int, int]:
+    """``(batches, classes a batch)`` of a round of ``n_class`` trees,
+    from K alone: ``ceil(K / _CLASS_BATCH)`` equal batches."""
+    batches = -(-max(n_class, 1) // _CLASS_BATCH)
+    return batches, -(-max(n_class, 1) // batches)
+
+
+def _per_class(g):
+    """How a piece of ``grow_tree`` written for ONE tree runs where the
+    gradients ``g`` carry a class axis (``[K, n]``): ``jax.vmap`` of it
+    over the leading axis of every argument, applied INSIDE the piece's
+    device scope, so the scope's name stays what the trace's readers
+    look for (a scope entered under ``vmap`` reads ``vmap(dmlc...)``).
+    No class axis (``[n]``: every round but a ``multi:*`` one): the
+    piece itself, the program it always traced."""
+    return jax.vmap if g.ndim == 2 else (lambda fn: fn)
+
+
+def _grow_classes(grow, bins_tl, g_all, h_all, feat_mask):
+    """The K trees of a multiclass round, every class from ``g_all[c]``
+    / ``h_all[c]`` (``[K, n]``): ``(trees, deltas)`` with K leading.
+    Up to ``_CLASS_BATCH`` classes are ONE batched ``grow``.  More go in
+    equal batches from K alone (:func:`_class_batches`), the last filled
+    up with classes of zero gradients whose trees are dropped — scanned,
+    so the program stays one batch's whatever K is and a level's
+    ``[K, n]`` intermediates stay one batch's too."""
+    n_class = g_all.shape[0]
+    batches, size = _class_batches(n_class)
+    if batches == 1:
+        return grow(bins_tl, g_all, h_all, feat_mask)
+
+    def cut(a):
+        a = jnp.pad(a, ((0, batches * size - n_class), (0, 0)))
+        return a.reshape(batches, size, a.shape[1])
+
+    _, out = jax.lax.scan(
+        lambda _, gh: (None, grow(bins_tl, gh[0], gh[1], feat_mask)),
+        None, (cut(g_all), cut(h_all)))
+    return jax.tree.map(
+        lambda a: a.reshape(batches * size, *a.shape[2:])[:n_class], out)
 
 
 def _tree_fold(parts):
@@ -2170,10 +2227,12 @@ class HistGBT(_ExternalMemoryEngine):
         a Pallas build runs in where one kernel call does not take it
         (more than 32 nodes at 256 bins: ``max_depth`` >= 8; a matrix
         wider than 392 rows), as ``ops.build_histogram`` derives them
-        again from the same shapes.  Every level is the staged descend
-        + build + subtract: the one-kernel level it was measured against
-        lost on the chip and went with PR 47 (PERF.md section 6, PRs 45
-        and 47)."""
+        again from the same shapes; ``hist_class_blocks`` the classes
+        each kernel call of a build takes (a ``multi:*`` round: the
+        stack of ``ops.hist_class_blocks``; ``[1]`` otherwise).  Every
+        level is the staged descend + build + subtract: the one-kernel
+        level it was measured against lost on the chip and went with PR
+        47 (PERF.md section 6, PRs 45 and 47)."""
         p = self.param
         depth = p.max_depth
         layout = self._bin_layout
@@ -2211,7 +2270,13 @@ class HistGBT(_ExternalMemoryEngine):
             hist_blocks=det_blocks,
             mesh_devices=dsize,
             rank=getattr(self._obj, "groups", None),
-            num_class=p.num_class)
+            num_class=p.num_class,
+            hist_class_blocks=tuple(
+                () if m != "pallas" else
+                hist_class_blocks(sync_bins, mat_rows, nb,
+                                  _class_batches(p.num_class)[1],
+                                  whole=packed)
+                for m, nb in zip(methods, builds)))
         self.round_plan = plan.describe()
         return plan
 
@@ -2339,15 +2404,19 @@ class HistGBT(_ExternalMemoryEngine):
                 pos_np[i] = (i - (1 << lvl)) << (depth - lvl)
 
         def row_blocks(n_local):
-            """(blocks, rows a block) a shard's rows cut into in
-            deterministic mode, else (0, 0).  Blocked mode needs every
-            shard's rows to split into whole fixed-size blocks;
-            _pad_rows guarantees it for fit paths; a ranking handle on a
-            mesh (a shard is whole queries) falls back where its rows do
-            not divide."""
+            """The row blocks a shard's rows are cut into in
+            deterministic mode, each as the index of its columns in a
+            per-row array (``[n]``, ``[K, n]`` or the ``[F, n]`` matrix);
+            none otherwise.  Blocked mode needs every shard's rows to
+            split into whole fixed-size blocks; _pad_rows guarantees it
+            for fit paths; a ranking handle on a mesh (a shard is whole
+            queries) falls back where its rows do not divide."""
             c_local = det_blocks // dsize if det_blocks else 0
-            n_blk = c_local if c_local and n_local % c_local == 0 else 0
-            return n_blk, (n_local // n_blk if n_blk else 0)
+            if not c_local or n_local % c_local:
+                return []
+            rb = n_local // c_local
+            return [(Ellipsis, slice(j * rb, (j + 1) * rb))
+                    for j in range(c_local)]
 
         def hist_sync(x, n_blk):
             """Histogram-sync allreduce over the data axis: a plain
@@ -2399,9 +2468,19 @@ class HistGBT(_ExternalMemoryEngine):
             AND the psum bytes per level, and the subtraction itself is
             exact in f32 up to one rounding.  A level is staged:
             ``ops.descend_histogram`` (an XLA descend, then the
-            histogram build), the sync, the subtraction."""
-            node = jnp.zeros(bins_tl.shape[1], jnp.int32)
-            n_blk, rb = row_blocks(int(bins_tl.shape[1]))
+            histogram build), the sync, the subtraction.
+
+            A CLASS axis: ``g`` / ``h`` ``[K, n]`` (a multiclass round)
+            grow the K trees level by level TOGETHER — one tree's
+            program, every per-class piece batched (``per_class``) inside
+            the level's own scopes, and a level's histograms ONE
+            ``build_histogram`` of K classes; every array of the result
+            leads with K.  Class c's tree and delta are, bit for bit,
+            this function's on ``(g[c], h[c])``."""
+            per_class = _per_class(g)
+            node = jnp.zeros(g.shape, jnp.int32)
+            rows = row_blocks(int(bins_tl.shape[1]))
+            n_blk = len(rows)
 
             def with_siblings(parent, left):
                 """Both children's histograms, interleaved: the right
@@ -2410,125 +2489,34 @@ class HistGBT(_ExternalMemoryEngine):
                 return jnp.stack([left, right], axis=2).reshape(
                     2, 2 * parent.shape[1], left.shape[2], left.shape[3])
 
-            feats = []
-            thrs = []
-            gains = []
-            dirs = []                                # missing mode only
-            gsum = hsum = None
-            prev_hist = None
-            feat = thr = dirv = None
-            bounds = None
-            if mono_arr is not None:
-                bounds = jnp.stack([jnp.full(1, -jnp.inf, jnp.float32),
-                                    jnp.full(1, jnp.inf, jnp.float32)], 1)
-            for level in range(depth):
-                n_nodes = 1 << level
-                # the level's device phases (doc/observability.md), as
-                # decorators of the calls that trace them: .route (each
-                # row's node's split), .hist (the kernel, the level's
-                # descend and sibling subtraction included), .sync, .split
-                in_route, in_hist, in_sync, in_split = (
-                    jax.named_scope(f"dmlc.round.L{level}.{phase}")
-                    for phase in ("route", "hist", "sync", "split"))
-                if level == 0:
-                    if n_blk:
-                        hist = in_hist(_tree_fold)([
-                            in_hist(build_histogram)(
-                                bins_tl[:, j * rb:(j + 1) * rb],
-                                node[j * rb:(j + 1) * rb],
-                                g[j * rb:(j + 1) * rb],
-                                h[j * rb:(j + 1) * rb],
-                                1, B, methods[0], transposed=True,
-                                layout=layout)
-                            for j in range(n_blk)])
-                    else:
-                        hist = in_hist(build_histogram)(
-                            bins_tl, node, g, h, 1, B, methods[0],
-                            transposed=True, layout=layout)
-                    hist = in_sync(hist_sync)(hist, n_blk)
-                else:
-                    n_prev = n_nodes >> 1
-                    select = in_route(table_select)
-                    feat_sel = select(feat, node, n_prev)             # [n]
-                    thr_sel = select(thr, node, n_prev)               # [n]
-                    dir_sel = (select(dirv, node, n_prev)
-                               if missing else None)
-                    if n_blk:
-                        lefts, nodes2 = [], []
-                        for j in range(n_blk):
-                            sl = slice(j * rb, (j + 1) * rb)
-                            l_j, nd_j = in_hist(descend_histogram)(
-                                bins_tl[:, sl], node[sl], feat_sel[sl],
-                                thr_sel[sl], g[sl], h[sl],
-                                n_prev, B, methods[level],
-                                dir_sel=(None if dir_sel is None
-                                         else dir_sel[sl]),
-                                miss_bin=B - 1 if missing else None,
-                                layout=layout)
-                            lefts.append(l_j)
-                            nodes2.append(nd_j)
-                        left = in_hist(_tree_fold)(lefts)
-                        node = jnp.concatenate(nodes2)
-                    else:
-                        left, node = in_hist(descend_histogram)(
-                            bins_tl, node, feat_sel, thr_sel, g, h,
-                            n_prev, B, methods[level],
-                            dir_sel=dir_sel,
-                            miss_bin=B - 1 if missing else None,
-                            layout=layout)
-                    left = in_sync(hist_sync)(left, n_blk)
-                    hist = in_hist(with_siblings)(prev_hist, left)
-                # sibling subtraction stays in STORAGE space (prev_hist);
-                # split evaluation sees original-feature space (identity
-                # when layout is None)
-                prev_hist = hist
-                hist = in_split(_bl.unbundle_hist)(hist, layout, B)
-                if mono_arr is not None or level == depth - 1:
-                    if missing:
-                        feat, thr, dirv, gn, cg_, ch_ = \
-                            in_split(best_split_leaf)(
-                                hist, feat_mask, bounds)
-                    else:
-                        feat, thr, gn, cg_, ch_ = \
-                            in_split(best_split_leaf)(
-                                hist, feat_mask, bounds)
-                    if level == depth - 1:
-                        gsum, hsum = cg_, ch_
-                elif missing:
-                    feat, thr, dirv, gn = in_split(best_split)(
-                        hist, feat_mask)
-                else:
-                    feat, thr, gn = in_split(best_split)(
-                        hist, feat_mask)
-                # pad per-level arrays to a common width for stacking
-                feats.append(jnp.pad(feat, (0, half - n_nodes)))
-                thrs.append(jnp.pad(thr, (0, half - n_nodes)))
-                gains.append(jnp.pad(gn, (0, half - n_nodes)))
-                if missing:
-                    dirs.append(jnp.pad(dirv, (0, half - n_nodes)))
-                if mono_arr is not None:
-                    lo, hi = bounds[:, 0], bounds[:, 1]               # [N]
-                    w_child = jnp.clip(
-                        (-cg_ / (ch_ + lam)).reshape(n_nodes, 2),
-                        lo[:, None], hi[:, None])
-                    mid = w_child.mean(axis=1)                        # [N]
-                    c = jnp.asarray(mono_arr)[feat]                   # [N]
-                    real = thr < B - 1           # degenerate splits inert
-                    up_l = jnp.where((c > 0) & real,
-                                     jnp.minimum(hi, mid), hi)
-                    lo_r = jnp.where((c > 0) & real,
-                                     jnp.maximum(lo, mid), lo)
-                    lo_l = jnp.where((c < 0) & real,
-                                     jnp.maximum(lo, mid), lo)
-                    up_r = jnp.where((c < 0) & real,
-                                     jnp.minimum(hi, mid), hi)
-                    bounds = jnp.stack([
-                        jnp.stack([lo_l, up_l], 1),
-                        jnp.stack([lo_r, up_r], 1)], axis=1
-                    ).reshape(2 * n_nodes, 2)
-            with jax.named_scope("dmlc.round.leaf"):
-                # final descend (the loop's levels advanced node only
-                # up to level depth-1); shared gather-free feature select
+            def child_bounds(bounds, cg_, ch_, feat, thr):
+                """A level's weight bounds handed down to its children
+                (monotone constraints)."""
+                n_nodes = bounds.shape[0]
+                lo, hi = bounds[:, 0], bounds[:, 1]                   # [N]
+                w_child = jnp.clip(
+                    (-cg_ / (ch_ + lam)).reshape(n_nodes, 2),
+                    lo[:, None], hi[:, None])
+                mid = w_child.mean(axis=1)                            # [N]
+                c = jnp.asarray(mono_arr)[feat]                       # [N]
+                real = thr < B - 1               # degenerate splits inert
+                up_l = jnp.where((c > 0) & real,
+                                 jnp.minimum(hi, mid), hi)
+                lo_r = jnp.where((c > 0) & real,
+                                 jnp.maximum(lo, mid), lo)
+                lo_l = jnp.where((c < 0) & real,
+                                 jnp.maximum(lo, mid), lo)
+                up_r = jnp.where((c < 0) & real,
+                                 jnp.minimum(hi, mid), hi)
+                return jnp.stack([
+                    jnp.stack([lo_l, up_l], 1),
+                    jnp.stack([lo_r, up_r], 1)], axis=1
+                ).reshape(2 * n_nodes, 2)
+
+            def leaf_tail(feat, thr, dirv, node, gsum, hsum, bounds):
+                """The leaf values and each row's leaf: the final descend
+                (the loop's levels advanced node only up to level
+                depth-1); shared gather-free feature select."""
                 feat_sel = table_select(feat, node, 1 << (depth - 1))
                 thr_sel = table_select(thr, node, 1 << (depth - 1))
                 row_bin = select_feature_bins(bins_tl, feat_sel,
@@ -2542,16 +2530,123 @@ class HistGBT(_ExternalMemoryEngine):
                 leaf_w = -_maybe_l1(gsum, alpha) / (hsum + lam)
                 if mono_arr is not None:
                     leaf_w = jnp.clip(leaf_w, bounds[:, 0], bounds[:, 1])
-                leaf = leaf_w * eta
+                return leaf_w * eta, node
+
+            feats = []
+            thrs = []
+            gains = []
+            dirs = []                                # missing mode only
+            gsum = hsum = None
+            prev_hist = None
+            feat = thr = dirv = None
+            bounds = None
+            if mono_arr is not None:
+                bounds = jnp.stack([jnp.full(1, -jnp.inf, jnp.float32),
+                                    jnp.full(1, jnp.inf, jnp.float32)], 1)
+                # every class's root unbounded: [(K,) 1, 2]
+                bounds = jnp.broadcast_to(bounds,
+                                          g.shape[:-1] + bounds.shape)
+            for level in range(depth):
+                n_nodes = 1 << level
+                # the level's device phases (doc/observability.md), as
+                # decorators of the calls that trace them: .route (each
+                # row's node's split), .hist (the kernel, the level's
+                # descend and sibling subtraction included), .sync, .split
+                in_route, in_hist, in_sync, in_split = (
+                    jax.named_scope(f"dmlc.round.L{level}.{phase}")
+                    for phase in ("route", "hist", "sync", "split"))
+                if level == 0:
+                    if n_blk:
+                        hist = in_hist(_tree_fold)([
+                            in_hist(build_histogram)(
+                                bins_tl[sl], node[sl], g[sl], h[sl],
+                                1, B, methods[0], transposed=True,
+                                layout=layout)
+                            for sl in rows])
+                    else:
+                        hist = in_hist(build_histogram)(
+                            bins_tl, node, g, h, 1, B, methods[0],
+                            transposed=True, layout=layout)
+                    hist = in_sync(hist_sync)(hist, n_blk)
+                else:
+                    n_prev = n_nodes >> 1
+                    select = in_route(per_class(
+                        partial(table_select, n_entries=n_prev)))
+                    feat_sel = select(feat, node)                     # [n]
+                    thr_sel = select(thr, node)                       # [n]
+                    dir_sel = select(dirv, node) if missing else None
+                    if n_blk:
+                        lefts, nodes2 = [], []
+                        for sl in rows:
+                            l_j, nd_j = in_hist(descend_histogram)(
+                                bins_tl[sl], node[sl], feat_sel[sl],
+                                thr_sel[sl], g[sl], h[sl],
+                                n_prev, B, methods[level],
+                                dir_sel=(None if dir_sel is None
+                                         else dir_sel[sl]),
+                                miss_bin=B - 1 if missing else None,
+                                layout=layout)
+                            lefts.append(l_j)
+                            nodes2.append(nd_j)
+                        left = in_hist(_tree_fold)(lefts)
+                        node = jnp.concatenate(nodes2, axis=-1)
+                    else:
+                        left, node = in_hist(descend_histogram)(
+                            bins_tl, node, feat_sel, thr_sel, g, h,
+                            n_prev, B, methods[level],
+                            dir_sel=dir_sel,
+                            miss_bin=B - 1 if missing else None,
+                            layout=layout)
+                    left = in_sync(hist_sync)(left, n_blk)
+                    hist = in_hist(per_class(with_siblings))(prev_hist,
+                                                             left)
+                # sibling subtraction stays in STORAGE space (prev_hist);
+                # split evaluation sees original-feature space (identity
+                # when layout is None)
+                prev_hist = hist
+                hist = in_split(per_class(partial(
+                    _bl.unbundle_hist, layout=layout, n_bins=B)))(hist)
+                if mono_arr is not None or level == depth - 1:
+                    split = in_split(per_class(
+                        lambda hh, bb: best_split_leaf(hh, feat_mask, bb)))
+                    if missing:
+                        feat, thr, dirv, gn, cg_, ch_ = split(hist, bounds)
+                    else:
+                        feat, thr, gn, cg_, ch_ = split(hist, bounds)
+                    if level == depth - 1:
+                        gsum, hsum = cg_, ch_
+                else:
+                    split = in_split(per_class(
+                        lambda hh: best_split(hh, feat_mask)))
+                    if missing:
+                        feat, thr, dirv, gn = split(hist)
+                    else:
+                        feat, thr, gn = split(hist)
+                # pad per-level arrays to a common width for stacking
+                pad_n = per_class(
+                    lambda a: jnp.pad(a, (0, half - n_nodes)))
+                feats.append(pad_n(feat))
+                thrs.append(pad_n(thr))
+                gains.append(pad_n(gn))
+                if missing:
+                    dirs.append(pad_n(dirv))
+                if mono_arr is not None:
+                    bounds = per_class(child_bounds)(bounds, cg_, ch_,
+                                                     feat, thr)
+            with jax.named_scope("dmlc.round.leaf"):
+                leaf, node = per_class(leaf_tail)(
+                    feat, thr, dirv, node, gsum, hsum, bounds)
+                # the levels' tables side by side: [(K,) depth, half]
                 tree = {
-                    "feat": jnp.stack(feats),                # [depth, half]
-                    "thr": jnp.stack(thrs),
-                    "gain": jnp.stack(gains),                # [depth, half]
-                    "leaf": leaf,                            # [n_leaf]
+                    "feat": jnp.stack(feats, axis=-2),
+                    "thr": jnp.stack(thrs, axis=-2),
+                    "gain": jnp.stack(gains, axis=-2),
+                    "leaf": leaf,                        # [(K,) n_leaf]
                 }
                 if missing:
-                    tree["dir"] = jnp.stack(dirs)            # [depth, half]
-                return tree, table_select(leaf, node, n_leaf)
+                    tree["dir"] = jnp.stack(dirs, axis=-2)
+                return tree, per_class(partial(
+                    table_select, n_entries=n_leaf))(leaf, node)
 
         def grow_tree_lossguide(bins_tl, g, h, feat_mask):
             """One LEAF-WISE tree on (g, h) → (tree arrays, margin delta).
@@ -2576,9 +2671,16 @@ class HistGBT(_ExternalMemoryEngine):
             Deterministic mode (DMLC_HIST_BLOCKS) uses the same
             per-block build + fixed-order fold + all_gather combine as
             depthwise, and the expansion order derives only from synced
-            gains — so mesh-shape invariance survives."""
+            gains — so mesh-shape invariance survives.
+
+            A CLASS axis (``g`` / ``h`` ``[K, n]``): the K trees expand
+            in step — each class its own queue, leaf and split, the
+            pieces batched (``per_class``) — and an expansion's K
+            builds are ONE ``build_histogram`` of K classes."""
+            per_class = _per_class(g)
             n_local = int(bins_tl.shape[1])
-            n_blk, rb = row_blocks(n_local)
+            rows = row_blocks(n_local)
+            n_blk = len(rows)
 
             def build_one(node_build):
                 """Histogram of the single node whose rows have
@@ -2586,18 +2688,15 @@ class HistGBT(_ExternalMemoryEngine):
                 if n_blk:
                     hh = _tree_fold([
                         build_histogram(
-                            bins_tl[:, j * rb:(j + 1) * rb],
-                            node_build[j * rb:(j + 1) * rb],
-                            g[j * rb:(j + 1) * rb],
-                            h[j * rb:(j + 1) * rb],
+                            bins_tl[sl], node_build[sl], g[sl], h[sl],
                             1, B, methods[0], transposed=True,
                             layout=layout)
-                        for j in range(n_blk)])
+                        for sl in rows])
                 else:
                     hh = build_histogram(bins_tl, node_build, g, h, 1, B,
                                          methods[0], transposed=True,
                                          layout=layout)
-                return hist_sync(hh, n_blk)      # [2, 1, S, Bs]
+                return hist_sync(hh, n_blk)      # [(K,) 2, 1, S, Bs]
 
             def eval_nodes(hist_st):
                 """(feat, thr, gain, tot_g, tot_h) per node of a synced
@@ -2643,28 +2742,32 @@ class HistGBT(_ExternalMemoryEngine):
                                   orig, v)
                 return v
 
-            # ---- root ----
-            node = jnp.ones(n_local, jnp.int32)          # heap ids
-            root = build_one(jnp.zeros(n_local, jnp.int32))
-            f0, t0_, g0, tg0, th0 = eval_nodes(root)
-            open_ = jnp.zeros(NH, bool).at[1].set(True)
-            leaf_g = jnp.zeros(NH, jnp.float32).at[1].set(tg0[0])
-            leaf_h = jnp.zeros(NH, jnp.float32).at[1].set(th0[0])
-            cand_feat = jnp.zeros(NH, jnp.int32).at[1].set(f0[0])
-            cand_thr = jnp.full(NH, B - 1, jnp.int32).at[1].set(t0_[0])
-            cand_gain = jnp.full(NH, -jnp.inf,
-                                 jnp.float32).at[1].set(g0[0])
-            rec_feat = jnp.zeros(NH, jnp.int32)
-            rec_thr = jnp.full(NH, B - 1, jnp.int32)
-            rec_gain = jnp.zeros(NH, jnp.float32)
-            pool = jnp.zeros((L_leaves,) + root[:, 0].shape,
-                             jnp.float32).at[0].set(root[:, 0])
-            pool_id = jnp.zeros(L_leaves, jnp.int32).at[0].set(1)
+            def open_root(root):
+                """The queue with the root alone in it."""
+                f0, t0_, g0, tg0, th0 = eval_nodes(root)
+                open_ = jnp.zeros(NH, bool).at[1].set(True)
+                leaf_g = jnp.zeros(NH, jnp.float32).at[1].set(tg0[0])
+                leaf_h = jnp.zeros(NH, jnp.float32).at[1].set(th0[0])
+                cand_feat = jnp.zeros(NH, jnp.int32).at[1].set(f0[0])
+                cand_thr = jnp.full(NH, B - 1, jnp.int32).at[1].set(t0_[0])
+                cand_gain = jnp.full(NH, -jnp.inf,
+                                     jnp.float32).at[1].set(g0[0])
+                rec_feat = jnp.zeros(NH, jnp.int32)
+                rec_thr = jnp.full(NH, B - 1, jnp.int32)
+                rec_gain = jnp.zeros(NH, jnp.float32)
+                pool = jnp.zeros((L_leaves,) + root[:, 0].shape,
+                                 jnp.float32).at[0].set(root[:, 0])
+                pool_id = jnp.zeros(L_leaves, jnp.int32).at[0].set(1)
+                return (open_, leaf_g, leaf_h, cand_feat, cand_thr,
+                        cand_gain, pool, pool_id, rec_feat, rec_thr,
+                        rec_gain)
 
-            def expand(carry, _):
-                (node, open_, leaf_g, leaf_h, cand_feat, cand_thr,
-                 cand_gain, pool, pool_id, rec_feat, rec_thr,
-                 rec_gain) = carry
+            def pick(node, state):
+                """The open leaf to expand and its rows sent down: the
+                new node ids, the left child's rows to build, and what
+                :func:`settle` needs of the choice."""
+                (open_, _, _, cand_feat, cand_thr, cand_gain, _, pool_id,
+                 rec_feat, rec_thr, rec_gain) = state
                 # priority queue: best candidate gain among open leaves
                 # that can still grow.  A real split always has recorded
                 # gain > gamma (best_split's own split_ok gate), so the
@@ -2690,7 +2793,16 @@ class HistGBT(_ExternalMemoryEngine):
                                  node)
                 # ONE build: left child only; right = parent − left
                 node_build = jnp.where(ok & mine & ~go_right, 0, -1)
-                left = build_one(node_build)[:, 0]        # [2, S, Bs]
+                return (node, node_build,
+                        (hc, ok, hc_eff, slot, rec_feat, rec_thr, rec_gain))
+
+            def settle(state, picked, left):
+                """The expansion's two children into the queue and the
+                pool, from the built left child ``left`` [2, 1, S, Bs]."""
+                (open_, leaf_g, leaf_h, cand_feat, cand_thr, cand_gain,
+                 pool, pool_id, _, _, _) = state
+                hc, ok, hc_eff, slot, rec_feat, rec_thr, rec_gain = picked
+                left = left[:, 0]                         # [2, S, Bs]
                 right = pool[slot] - left
                 f2, t2, g2, tg2, th2 = eval_nodes(
                     jnp.stack([left, right], axis=1))
@@ -2721,39 +2833,53 @@ class HistGBT(_ExternalMemoryEngine):
                 pool_id = pool_id.at[slot_eff].set(2 * hc, mode="drop")
                 pool_id = pool_id.at[free_eff].set(2 * hc + 1,
                                                    mode="drop")
-                return (node, open_, leaf_g, leaf_h, cand_feat, cand_thr,
+                return (open_, leaf_g, leaf_h, cand_feat, cand_thr,
                         cand_gain, pool, pool_id, rec_feat, rec_thr,
-                        rec_gain), None
+                        rec_gain)
 
-            carry = (node, open_, leaf_g, leaf_h, cand_feat, cand_thr,
-                     cand_gain, pool, pool_id, rec_feat, rec_thr,
-                     rec_gain)
-            carry, _ = jax.lax.scan(expand, carry, None,
-                                    length=L_leaves - 1)
-            (node, open_, leaf_g, leaf_h, _, _, _, _, _, rec_feat,
-             rec_thr, rec_gain) = carry
-            # leaf table in depthwise's positional layout: every slot an
-            # open leaf doesn't own is a depthwise empty leaf, whose
-            # value is exactly −0.0 (−(+0)/(0+λ)·η)
-            w_all = (-_maybe_l1(leaf_g, alpha) / (leaf_h + lam)) * eta
-            pos_eff = jnp.where(open_, poss, n_leaf)
-            leaf = jnp.full(n_leaf, -0.0,
-                            jnp.float32).at[pos_eff].set(w_all,
-                                                         mode="drop")
-            tree = {
-                "feat": jnp.stack([
-                    jnp.pad(rec_feat[1 << lv:1 << (lv + 1)],
-                            (0, half - (1 << lv))) for lv in range(depth)]),
-                "thr": jnp.stack([
-                    jnp.pad(rec_thr[1 << lv:1 << (lv + 1)],
-                            (0, half - (1 << lv))) for lv in range(depth)]),
-                "gain": jnp.stack([
-                    jnp.pad(rec_gain[1 << lv:1 << (lv + 1)],
-                            (0, half - (1 << lv))) for lv in range(depth)]),
-                "leaf": leaf,                            # [n_leaf]
-            }
-            delta = table_select(jnp.where(open_, w_all, 0.0), node, NH)
-            return tree, delta
+            def expand(carry, _):
+                node, state = carry
+                node, node_build, picked = per_class(pick)(node, state)
+                left = build_one(node_build)
+                return (node, per_class(settle)(state, picked, left)), None
+
+            def finish(node, state):
+                """The tree in depthwise's arrays and each row's delta."""
+                (open_, leaf_g, leaf_h, _, _, _, _, _, rec_feat, rec_thr,
+                 rec_gain) = state
+                # leaf table in depthwise's positional layout: every slot
+                # an open leaf doesn't own is a depthwise empty leaf,
+                # whose value is exactly −0.0 (−(+0)/(0+λ)·η)
+                w_all = (-_maybe_l1(leaf_g, alpha) / (leaf_h + lam)) * eta
+                pos_eff = jnp.where(open_, poss, n_leaf)
+                leaf = jnp.full(n_leaf, -0.0,
+                                jnp.float32).at[pos_eff].set(w_all,
+                                                             mode="drop")
+                tree = {
+                    "feat": jnp.stack([
+                        jnp.pad(rec_feat[1 << lv:1 << (lv + 1)],
+                                (0, half - (1 << lv)))
+                        for lv in range(depth)]),
+                    "thr": jnp.stack([
+                        jnp.pad(rec_thr[1 << lv:1 << (lv + 1)],
+                                (0, half - (1 << lv)))
+                        for lv in range(depth)]),
+                    "gain": jnp.stack([
+                        jnp.pad(rec_gain[1 << lv:1 << (lv + 1)],
+                                (0, half - (1 << lv)))
+                        for lv in range(depth)]),
+                    "leaf": leaf,                            # [n_leaf]
+                }
+                delta = table_select(jnp.where(open_, w_all, 0.0), node, NH)
+                return tree, delta
+
+            # ---- root ----
+            node = jnp.ones(g.shape, jnp.int32)          # heap ids
+            state = per_class(open_root)(
+                build_one(jnp.zeros(g.shape, jnp.int32)))
+            (node, state), _ = jax.lax.scan(expand, (node, state), None,
+                                            length=L_leaves - 1)
+            return per_class(finish)(node, state)
 
         grow = grow_tree_lossguide if lossguide else grow_tree
 
@@ -2790,16 +2916,14 @@ class HistGBT(_ExternalMemoryEngine):
                     g_all = jnp.where(keep, g_all, 0.0)
                     h_all = jnp.where(keep, h_all, 0.0)
 
-            # the class loop, rolled: ONE tree's program, K trips (seven
-            # unrolled copies of grow_tree are seven times the program to
-            # compile and to read back from the cache); the trees are
-            # byte-identical to the unrolled loop's
-            def one_class(_, gh_c):
-                return None, grow(bins_tl, gh_c[0], gh_c[1], feat_mask)
-
+            # the class loop, batched: ONE tree's program with a class
+            # axis, the K trees grown level by level together and a
+            # level's histograms one kernel call per block of classes
+            # (ops.hist_class_blocks); the trees are byte-identical to K
+            # single-class ``grow``s
             with jax.named_scope("dmlc.round.class"):
-                _, (tree, deltas) = jax.lax.scan(
-                    one_class, None, (g_all, h_all))      # [K, ...], [K, n]
+                tree, deltas = _grow_classes(
+                    grow, bins_tl, g_all, h_all, feat_mask)  # [K, ...], [K, n]
             with jax.named_scope("dmlc.round.update"):
                 return preds_l + deltas, tree
 

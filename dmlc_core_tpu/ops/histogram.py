@@ -17,7 +17,12 @@ there.  Two engines, selected by ``method``:
   one call (more than 32 at 256 bins: the last level of a depth-8
   tree), it runs in NODE BLOCKS (:func:`hist_node_blocks`), the same
   kernel once per block of nodes over all rows, joined on the node
-  axis.
+  axis; and with a CLASS axis: ``node_id``, ``grad``, ``hess`` given
+  class-major ``[K, n]`` (a multiclass round: K trees grown level by
+  level together) build ``[K, 2, n_nodes, F, n_bins]``, one kernel call
+  per block of classes (:func:`hist_class_blocks`) whose one-hots are
+  stacked to the MXU's 128 rows over ONE read of the bins block and
+  ONE right operand a feature.
 * ``"auto"`` — :func:`resolve_hist_method`: on TPU ``pallas`` wherever
   the kernel's VMEM budgets admit a feature block of even ONE node
   (every plain dense matrix, at any depth); ``segment`` for a packed
@@ -58,7 +63,7 @@ __all__ = ["build_histogram", "descend_histogram",
            "reference_histogram", "hist_psum_bytes_per_round",
            "bins_bytes_per_round", "leaves_built_per_round",
            "hist_feature_dots", "hist_feature_blocks",
-           "hist_node_blocks"]
+           "hist_node_blocks", "hist_class_blocks"]
 
 
 def leaves_built_per_round(depth: int, grow_policy: str = "depthwise",
@@ -177,10 +182,28 @@ _SCOPED_ROW_RESERVE = 240
 #: at 152 rows, 16.50 at 168, 19.12 at 200 (compile-only client,
 #: PERF.md section 6, PR 42).  A v5e core has 128 MiB of VMEM.
 _NESTED_BLOCKS_VMEM = 32 << 20
+#: the scoped-VMEM limit a STACKED call states for itself (a block of
+#: classes in one kernel, :func:`hist_class_blocks`), and what each
+#: stacked class adds per row of the tile: its node, gradient and
+#: hessian rows, ``[1, T]`` values that fill whole sublane tiles, and
+#: its ``[8, T]`` one-hot targets of a feature group.  Read off the v5e
+#: compiler at tile 16384, 56 rows, 256 bins (compile-only client,
+#: PERF.md section 6, PR 49): 4.9 / 7.24 / 10.37 / 12.43 / 19.85 MiB at
+#: 1 / 2 / 3 / 4 / 7 classes, whatever the level — 2.1-3.1 MiB a class;
+#: 176 B a row keeps 2.75.
+_STACKED_VMEM = 48 << 20
+_STACKED_ROW_RESERVE = 176
+
+
+#: rows of the MXU (v5e: four 128 x 128 arrays): the height a stack of
+#: classes fills.  One call of A = 256 is 19% slower than two of 128
+#: (PERF.md section 7 (6)), so a call never stacks past it.
+_MXU_ROWS = 128
 
 
 def _pallas_ok(n_bins: int, n_features: int, n_nodes: int = 1,
-               bins_itemsize: int = 1, tile_rows: int = 0) -> int:
+               bins_itemsize: int = 1, tile_rows: int = 0,
+               n_class: int = 1) -> int:
     """The FEATURE BLOCK of a Pallas build at this shape: how many rows
     of the feature-major bin matrix one kernel call takes.
     ``n_features`` itself where the whole matrix fits the kernel's VMEM
@@ -216,22 +239,39 @@ def _pallas_ok(n_bins: int, n_features: int, n_nodes: int = 1,
     (b) alone admit a root build of 728 rows, which Mosaic refuses.
     All three stay on ``Fp``, not on the real feature count: the kernel
     skips a pad feature's dot, but its rows are still in the bins block
-    it reads and in the accumulator it holds."""
-    lo = _lo_factor(n_nodes, n_bins)
+    it reads and in the accumulator it holds.
+
+    ``n_class`` > 1 asks for a call that STACKS that many classes
+    (:func:`hist_class_blocks`): the left operand and the out block are
+    ``n_class`` times as tall at the stack's ``lo``
+    (:func:`_lo_stacked`), which is what (a) and (b) have to learn;
+    the call states ``_STACKED_VMEM`` for itself, so (b) and (c) count
+    against that wall, (c) with ``_STACKED_ROW_RESERVE`` more a class
+    (it is the term that binds a stacked call);
+    0 where no ``lo`` keeps the classes' one-hots on sublane tiles."""
+    lo = (_lo_factor(n_nodes, n_bins) if n_class == 1
+          else _lo_stacked(n_nodes, n_bins))
+    if not lo:
+        return 0
     hi = -(-n_bins // lo)
-    nh = n_nodes * hi
+    nh = n_class * n_nodes * hi
     T = tile_rows or _TILE_ROWS
+    stack, wall, reserve = 15 << 20, _SCOPED_VMEM, _SCOPED_ROW_RESERVE
+    if n_class > 1:
+        stack = wall = _STACKED_VMEM
+        reserve += n_class * _STACKED_ROW_RESERVE
     fp_max = min(
         (24 << 20) // (2 * nh * max(lo, 128) * 4),
-        ((15 << 20) // T - (120 + 6 * nh + 2 * lo)) // bins_itemsize,
-        (_SCOPED_VMEM // T - _SCOPED_ROW_RESERVE) // (2 * bins_itemsize))
+        (stack // T - (120 + 6 * nh + 2 * lo)) // bins_itemsize,
+        (wall // T - reserve) // (2 * bins_itemsize))
     if -(-n_features // 8) * 8 <= fp_max:
         return n_features
     return max(fp_max // 8 * 8, 0)
 
 
 def hist_feature_blocks(n_bins: int, n_features: int, n_nodes: int = 1,
-                        bins_itemsize: int = 1) -> tuple[int, ...]:
+                        bins_itemsize: int = 1,
+                        n_class: int = 1) -> tuple[int, ...]:
     """Rows of each feature block a Pallas build of this shape runs in,
     in matrix order: ``(n_features,)`` where one kernel call takes the
     whole matrix, else whole blocks of :func:`_pallas_ok` rows and the
@@ -239,8 +279,10 @@ def hist_feature_blocks(n_bins: int, n_features: int, n_nodes: int = 1,
     :func:`build_histogram` traces and ``HistGBT.round_plan`` records
     (``hist_feature_blocks``).  Epsilon's 2000 features at 256 bins:
     five blocks of 392 and one of 40, at every level of a depth-6
-    tree."""
-    fb = _pallas_ok(n_bins, n_features, n_nodes, bins_itemsize)
+    tree.  ``n_class`` > 1: the blocks of a call that stacks that many
+    classes."""
+    fb = _pallas_ok(n_bins, n_features, n_nodes, bins_itemsize,
+                    n_class=n_class)
     if not fb:
         return ()
     full, rest = divmod(n_features, fb)
@@ -294,6 +336,53 @@ def hist_node_blocks(n_bins: int, n_rows: int, n_nodes: int = 1,
         return ()
     full, rest = divmod(n_nodes, nb)
     return (nb,) * full + ((rest,) if rest else ())
+
+
+def _class_block(n_bins: int, n_rows: int, n_nodes: int, n_class: int,
+                 bins_itemsize: int = 1, whole: bool = False) -> int:
+    """The CLASS BLOCK of a Pallas build with a class axis: how many
+    classes one kernel call stacks.  The largest ``kb`` whose stacked
+    left operand ``kb x A`` (``A = 2 x n_nodes x hi`` at the stack's
+    ``lo``, :func:`_lo_stacked`) is no taller than the MXU
+    (``_MXU_ROWS``) and which :func:`_pallas_ok` admits with a feature
+    block of at least 8 rows (``whole``: all of ``n_rows``) — from
+    shapes alone.  1, a call a class, where nothing can be stacked: no
+    aligned ``lo``, one class's ``A`` already past half the array, or a
+    build that one call does not take (node blocks)."""
+    lo = _lo_stacked(n_nodes, n_bins)
+    if (n_class == 1 or not lo or _node_block(
+            n_bins, n_rows, n_nodes, bins_itemsize, whole) != n_nodes):
+        return 1
+    kb = min(n_class, _MXU_ROWS // (2 * n_nodes * -(-n_bins // lo)))
+    while kb > 1:
+        block = _pallas_ok(n_bins, n_rows, n_nodes, bins_itemsize,
+                           n_class=kb)
+        if block >= n_rows if whole else block > 0:
+            break
+        kb -= 1
+    return max(kb, 1)
+
+
+def hist_class_blocks(n_bins: int, n_rows: int, n_nodes: int,
+                      n_class: int, bins_itemsize: int = 1,
+                      whole: bool = False) -> tuple[int, ...]:
+    """Classes of each kernel call of a Pallas build with a class axis
+    of ``n_class``, in class order: whole blocks of :func:`_class_block`
+    classes and the rest.  What :func:`build_histogram` traces and
+    ``HistGBT.round_plan`` records (``hist_class_blocks``): the
+    engagement counter of the stacked kernel.  Seven classes at 256
+    bins, by the level's builds::
+
+        n_build    1     2     4     8      16           32
+        lo        32    64   128   128     128          128
+        A         16    16    16    32      64          128
+        blocks   (7,)  (7,)  (7,)  (4, 3)  (2, 2, 2, 1)  (1,) * 7
+
+    One class is ``(1,)`` at every shape."""
+    kb = _class_block(n_bins, n_rows, n_nodes, n_class, bins_itemsize,
+                      whole)
+    full, rest = divmod(n_class, kb)
+    return (kb,) * full + ((rest,) if rest else ())
 
 
 def pallas_interpret() -> bool:
@@ -359,6 +448,12 @@ def build_histogram(
     Static ``n_nodes``/``n_bins`` keep shapes XLA-compilable; rows with
     ``node_id < 0`` (e.g. padding) contribute nothing.
 
+    A CLASS axis: ``node_id``, ``grad``, ``hess`` all ``[K, n]`` (K
+    trees over the same rows, each class its own node ids and
+    gradients) return ``hist[K, 2, n_nodes, F, n_bins]``, class c's
+    histogram bit for bit what its own call would build
+    (:func:`_hist_class_blocks`).
+
     ``transposed=True`` means ``bins`` is already ``[F, n]`` — the Pallas
     kernel's native layout.  The training loop stores bins transposed so
     the per-level kernel never re-transposes the matrix (a full HBM
@@ -383,19 +478,68 @@ def build_histogram(
             # a bundle-only layout is physical == storage: the plain
             # kernel; packed rows lead the block, so it is never cut on
             # features
-            return _hist_pallas_blocks(
+            return _hist_class_blocks(
                 bins, node_id, grad, hess, n_nodes, n_bins, transposed=True,
                 layout=layout if layout.pairs else None)
-        return _hist_segment(_bl.unpack_matrix(bins, layout).T, node_id,
-                             grad, hess, n_nodes, n_bins)
+        return _hist_segment_classes(_bl.unpack_matrix(bins, layout).T,
+                                     node_id, grad, hess, n_nodes, n_bins)
     F = bins.shape[0] if transposed else bins.shape[1]
     method = resolve_hist_method(method, n_bins, F, n_nodes,
                                  jnp.dtype(bins.dtype).itemsize)
     if method == "segment":
-        return _hist_segment(bins.T if transposed else bins,
-                             node_id, grad, hess, n_nodes, n_bins)
-    return _hist_pallas_blocks(bins, node_id, grad, hess, n_nodes, n_bins,
-                               transposed=transposed)
+        return _hist_segment_classes(bins.T if transposed else bins,
+                                     node_id, grad, hess, n_nodes, n_bins)
+    return _hist_class_blocks(bins, node_id, grad, hess, n_nodes, n_bins,
+                              transposed=transposed)
+
+
+def _hist_segment_classes(bins, node_id, grad, hess, n_nodes, n_bins):
+    """:func:`_hist_segment`, over the class axis where there is one."""
+    if node_id.ndim == 1:
+        return _hist_segment(bins, node_id, grad, hess, n_nodes, n_bins)
+    return jax.vmap(lambda nd, g, h: _hist_segment(
+        bins, nd, g, h, n_nodes, n_bins))(node_id, grad, hess)
+
+
+def _hist_class_blocks(bins, node_id, grad, hess, n_nodes, n_bins, *,
+                       transposed, layout=None):
+    """:func:`_hist_pallas_blocks` over the class blocks of
+    :func:`hist_class_blocks`.  No class axis is the plain call and
+    traces nothing else.  With one, ``node_id`` / ``grad`` / ``hess``
+    ``[K, n]``: one call a block of classes, a block of ONE class the
+    plain call on that class's rows, the histograms joined on the
+    leading class axis.  A block of several is the STACKED call: per
+    feature the kernel reads the bins block once, builds the right
+    one-hot once and lays the classes' left one-hots — each from its own
+    class's node ids, scaled by its own gradients — one under another
+    for ONE dot.  A cell of a class's histogram is a row of that dot
+    times a column of it: the sum of the same products over the same
+    rows in the same tile order, whoever shares the dot and at whichever
+    ``lo`` (``tests/test_hist_class_blocks.py``).  What class blocking
+    adds outside the kernels (the slabs of classes, the join) runs
+    under the device scope ``dmlc.hist.cblock``."""
+    if node_id.ndim == 1:
+        return _hist_pallas_blocks(bins, node_id, grad, hess, n_nodes,
+                                   n_bins, transposed=transposed,
+                                   layout=layout)
+    rows = bins.shape[0] if transposed else bins.shape[1]
+    n_class = node_id.shape[0]
+    blocks = hist_class_blocks(n_bins, rows, n_nodes, n_class,
+                               jnp.dtype(bins.dtype).itemsize,
+                               whole=layout is not None)
+    build = partial(_hist_pallas_blocks, n_nodes=n_nodes, n_bins=n_bins,
+                    transposed=transposed, layout=layout)
+    if blocks == (n_class,) and n_class > 1:
+        return build(bins, node_id, grad, hess)
+    with jax.named_scope("dmlc.hist.cblock"):
+        # one class: the call it always was, on that class's rows
+        slabs = [[a[lo] if hi - lo == 1 else a[lo:hi]
+                  for a in (node_id, grad, hess)]
+                 for lo, hi in pairwise(accumulate(blocks, initial=0))]
+    hists = [build(bins, *slab) for slab in slabs]
+    with jax.named_scope("dmlc.hist.cblock"):
+        return jnp.concatenate([h if h.ndim == 5 else h[None]
+                                for h in hists], axis=0)
 
 
 def _hist_pallas_blocks(bins, node_id, grad, hess, n_nodes, n_bins, *,
@@ -420,7 +564,9 @@ def _hist_pallas_blocks(bins, node_id, grad, hess, n_nodes, n_bins, *,
     adds outside the kernels (the per-block node maps, the join) runs
     under the device scope ``dmlc.hist.nblock``.  ``layout`` is a
     nibble-packed layout: cut on nodes like any other build, never on
-    features."""
+    features.  A class axis (``node_id`` ``[Kb, n]``: a stacked call
+    of :func:`_hist_class_blocks`) rides through: the maps are
+    elementwise and the joins count their axes from the end."""
     rows = bins.shape[0] if transposed else bins.shape[1]
     blocks = hist_node_blocks(n_bins, rows, n_nodes,
                               jnp.dtype(bins.dtype).itemsize,
@@ -436,7 +582,7 @@ def _hist_pallas_blocks(bins, node_id, grad, hess, n_nodes, n_bins, *,
         [_hist_pallas_fblocks(bins, own(lo, hi), grad, hess, hi - lo,
                               n_bins, transposed=transposed, layout=layout,
                               in_node_block=True)
-         for lo, hi in pairwise(accumulate(blocks, initial=0))], axis=1)
+         for lo, hi in pairwise(accumulate(blocks, initial=0))], axis=-3)
 
 
 def _hist_pallas_fblocks(bins, node_id, grad, hess, n_nodes, n_bins, *,
@@ -456,8 +602,9 @@ def _hist_pallas_fblocks(bins, node_id, grad, hess, n_nodes, n_bins, *,
         return _hist_pallas(bins, node_id, grad, hess, n_nodes, n_bins,
                             transposed=True, layout=layout)
     F = bins.shape[0] if transposed else bins.shape[1]
-    blocks = hist_feature_blocks(n_bins, F, n_nodes,
-                                 jnp.dtype(bins.dtype).itemsize)
+    blocks = hist_feature_blocks(
+        n_bins, F, n_nodes, jnp.dtype(bins.dtype).itemsize,
+        n_class=1 if node_id.ndim == 1 else node_id.shape[0])
     if len(blocks) == 1:
         return _hist_pallas(bins, node_id, grad, hess, n_nodes, n_bins,
                             transposed=transposed)
@@ -468,7 +615,7 @@ def _hist_pallas_fblocks(bins, node_id, grad, hess, n_nodes, n_bins, *,
     return in_fblock(jnp.concatenate)(
         [_hist_pallas(slab(lo, hi), node_id, grad, hess, n_nodes, n_bins,
                       transposed=transposed, vmem_limit_bytes=vmem)
-         for lo, hi in pairwise(accumulate(blocks, initial=0))], axis=2)
+         for lo, hi in pairwise(accumulate(blocks, initial=0))], axis=-2)
 
 
 @partial(jax.jit, static_argnums=(4, 5))
@@ -517,11 +664,26 @@ def _hist_pallas_kernel(bins_ref, node_ref, g_ref, h_ref, out_ref,
     rows all sit in bin 0, so its compare, scalings and dot would cost
     exactly what a real feature's do, for sums the caller drops.
     :func:`_accum_hist` stops at ``n_rows``.
+
+    A STACKED call: node/g/h arrive ``[Kb, T]``, a class a row, and the
+    left operand is the Kb classes' ``[A, T]`` one-hots one under
+    another, ``[Kb·A, T]`` — one dot a feature for all of them, against
+    the one right operand, which reads the bins alone.  Kb = 1 is the
+    kernel above, operation for operation.
     """
     i = pl.program_id(0)
-    node = node_ref[:].astype(jnp.int32)                              # [1, T]
-    g = g_ref[:].astype(jnp.bfloat16)                                 # [1, T]
-    h = h_ref[:].astype(jnp.bfloat16)
+
+    def rows(ref, dtype):
+        # a class's [1, T] row, cut out in 32 bits (bf16 packs two rows
+        # a sublane) and converted after
+        a = ref[:]                                                    # [Kb, T]
+        if a.shape[0] == 1:
+            return [a.astype(dtype)]
+        return [a[c:c + 1].astype(dtype) for c in range(a.shape[0])]
+
+    node = rows(node_ref, jnp.int32)
+    g = rows(g_ref, jnp.bfloat16)
+    h = rows(h_ref, jnp.bfloat16)
 
     @pl.when(i == 0)
     def _():
@@ -535,6 +697,8 @@ def _hist_pallas_kernel(bins_ref, node_ref, g_ref, h_ref, out_ref,
 def _accum_hist(bins_ref, out_ref, node, g, h, *, n_nodes, hi, lo, n_rows,
                 n_pack_groups=0):
     """The histogram accumulation loop of :func:`_hist_pallas_kernel`.
+    ``node`` / ``g`` / ``h`` are lists of the stacked classes' ``[1, T]``
+    rows (one class: lists of one).
 
     ``n_pack_groups`` > 0 marks the first ``8·n_pack_groups`` physical
     rows as NIBBLE-PACKED (two int4 storage features per byte, see
@@ -561,24 +725,30 @@ def _accum_hist(bins_ref, out_ref, node, g, h, *, n_nodes, hi, lo, n_rows,
     nh = n_nodes * hi
     nh_iota = jax.lax.broadcasted_iota(jnp.int32, (nh, T), 0)
     lo_iota = jax.lax.broadcasted_iota(jnp.int32, (lo, T), 0)
-    valid = node >= 0
-    t0_node = jnp.where(valid, jnp.where(valid, node, 0) * hi,
-                        jnp.int32(-(1 << 20)))                        # [1, T]
+    t0_node = []
+    for node_c in node:
+        valid = node_c >= 0
+        t0_node.append(jnp.where(valid, jnp.where(valid, node_c, 0) * hi,
+                                 jnp.int32(-(1 << 20))))              # [1, T]
 
     def emit(t0s, los, k, row):
-        # ONE [nh, T] compare then scale by g and h (the grad/hess
-        # planes share the one-hot) — 2× cheaper than comparing a
-        # [2·nh, T] iota twice.  compare→astype→mul (NOT where):
-        # Mosaic can't relayout an i1 mask against a [1, T]-
-        # replicated where operand.
-        oh = (nh_iota == t0s[k:k + 1]).astype(jnp.bfloat16)           # [nh, T]
-        lhs = jnp.concatenate([oh * g, oh * h], axis=0)               # [2nh, T]
+        # per class ONE [nh, T] compare then scale by g and h (the
+        # grad/hess planes share the one-hot) — 2× cheaper than
+        # comparing a [2·nh, T] iota twice.  compare→astype→mul (NOT
+        # where): Mosaic can't relayout an i1 mask against a [1, T]-
+        # replicated where operand.  The classes' planes one under
+        # another; the right one-hot once for all of them.
+        planes = []
+        for t0s_c, g_c, h_c in zip(t0s, g, h):
+            oh = (nh_iota == t0s_c[k:k + 1]).astype(jnp.bfloat16)     # [nh, T]
+            planes += [oh * g_c, oh * h_c]
+        lhs = jnp.concatenate(planes, axis=0)                    # [Kb·2nh, T]
         rhs = (lo_iota == los[k:k + 1]).astype(jnp.bfloat16)          # [lo, T]
         d = jax.lax.dot_general(
             lhs, rhs,
             dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )                                                              # [2nh, lo]
+        )                                                         # [Kb·2nh, lo]
         idx = (pl.ds(row, 1), slice(None), slice(None))
         out_ref[idx] = out_ref[idx] + d[None]
 
@@ -587,7 +757,8 @@ def _accum_hist(bins_ref, out_ref, node, g, h, *, n_nodes, hi, lo, n_rows,
             base = pl.multiple_of(fg * 8, 8)
             blk = bins_ref[pl.ds(base, 8), :].astype(jnp.int32)       # [8, T]
             for nb, vals in ((0, blk & 15), (1, blk >> 4)):
-                t0s = t0_node + vals // lo                            # [8, T]
+                his = vals // lo                                      # [8, T]
+                t0s = [t0_c + his for t0_c in t0_node]
                 los = vals % lo                                       # [8, T]
                 for k in range(8):
                     emit(t0s, los, k, 2 * (fg * 8 + k) + nb)
@@ -604,7 +775,8 @@ def _accum_hist(bins_ref, out_ref, node, g, h, *, n_nodes, hi, lo, n_rows,
         # sublane padding), only the one-hot compares are per-feature.
         blk = bins_ref[pl.ds(base, 8), :].astype(jnp.int32)           # [8, T]
         # padding rows carry t0_node ≈ -2^20 → t0 < 0 → match nothing
-        t0s = t0_node + blk // lo                                     # [8, T]
+        his = blk // lo                                               # [8, T]
+        t0s = [t0_c + his for t0_c in t0_node]
         los = blk % lo                                                # [8, T]
         for k in range(n_live):
             emit(t0s, los, k, row(k))
@@ -653,6 +825,22 @@ def _lo_factor(n_nodes: int, n_bins: int) -> int:
     return best
 
 
+def _lo_stacked(n_nodes: int, n_bins: int) -> int:
+    """``lo`` of a call that stacks classes (:func:`hist_class_blocks`):
+    the widest right operand of 128 / 64 / 32 — so the shortest left
+    operand a class, ``A = 2·N·hi``, and the most classes in the MXU's
+    128 rows — at which a class's ``nh = N·hi`` is a multiple of 8, so
+    that every class's one-hot starts on a sublane tile of the stack.
+    At 256 bins: 32 for one node, 64 for two, 128 from four on.  0 where
+    none of the three does (few nodes at few bins): such a build is not
+    stacked.  A cell's sums do not depend on ``lo``
+    (:func:`_hist_pallas_blocks`)."""
+    for lo in (128, 64, 32):
+        if lo <= n_bins and (n_nodes * -(-n_bins // lo)) % 8 == 0:
+            return lo
+    return 0
+
+
 @partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9, 10))
 def _hist_pallas(bins, node_id, grad, hess, n_nodes, n_bins,
                  tile_rows: int = _TILE_ROWS, lo: int = 0,
@@ -669,14 +857,27 @@ def _hist_pallas(bins, node_id, grad, hess, n_nodes, n_bins,
 
     ``vmem_limit_bytes``: the scoped-VMEM limit the call states for
     itself; 0 leaves the compiler's (``_SCOPED_VMEM``), as every build
-    that is not cut both ways does."""
+    that is not cut both ways does.
+
+    ``node_id`` / ``grad`` / ``hess`` ``[Kb, n]`` make the STACKED call
+    (:func:`_hist_class_blocks`): in-blocks ``(Kb, tile_rows)`` — class
+    -major as the round program holds them, no re-layout — the out
+    block ``[F, Kb·A, lo]`` at the stack's ``lo`` (:func:`_lo_stacked`),
+    the result ``[Kb, 2, N, F, B]``.  ``[n]`` is the call of one class
+    it always was, the same program."""
     if transposed:
         F, n = bins.shape
     else:
         n, F = bins.shape
-    lo = min(lo or _lo_factor(n_nodes, n_bins), n_bins)
+    stack = node_id.shape[:-1]            # () or (Kb,)
+    n_class = stack[0] if stack else 1
+    lo = min(lo or (_lo_stacked(n_nodes, n_bins) if stack
+                    else _lo_factor(n_nodes, n_bins)), n_bins)
+    CHECK(lo > 0, "a stacked histogram call needs an aligned lo")
+    if stack:
+        vmem_limit_bytes = vmem_limit_bytes or _STACKED_VMEM
     hi = -(-n_bins // lo)
-    A = 2 * n_nodes * hi
+    A = 2 * n_nodes * hi * n_class        # rows of the left operand
     Fp = -(-F // 8) * 8          # feature groups of 8 (sublane alignment)
     npg = 0
     if layout is not None:
@@ -690,9 +891,10 @@ def _hist_pallas(bins, node_id, grad, hess, n_nodes, n_bins,
     grid = n_pad // tile_rows
     with jax.named_scope("dmlc.hist.pad"):
         if pad:
-            node_id = jnp.pad(node_id, (0, pad), constant_values=-1)
-            grad = jnp.pad(grad, (0, pad))
-            hess = jnp.pad(hess, (0, pad))
+            row_pad = [(0, 0)] * len(stack) + [(0, pad)]
+            node_id = jnp.pad(node_id, row_pad, constant_values=-1)
+            grad = jnp.pad(grad, row_pad)
+            hess = jnp.pad(hess, row_pad)
         if transposed:
             bins_t = jnp.pad(bins, ((0, Fp - F), (0, pad)))
         else:
@@ -705,26 +907,29 @@ def _hist_pallas(bins, node_id, grad, hess, n_nodes, n_bins,
         grid=(grid,),
         in_specs=[
             pl.BlockSpec((Fp, tile_rows), lambda i: (0, i)),
-            pl.BlockSpec((1, tile_rows), lambda i: (0, i)),
-            pl.BlockSpec((1, tile_rows), lambda i: (0, i)),
-            pl.BlockSpec((1, tile_rows), lambda i: (0, i)),
+            pl.BlockSpec((n_class, tile_rows), lambda i: (0, i)),
+            pl.BlockSpec((n_class, tile_rows), lambda i: (0, i)),
+            pl.BlockSpec((n_class, tile_rows), lambda i: (0, i)),
         ],
         out_specs=pl.BlockSpec((L, A, lo), lambda i: (0, 0, 0)),
         interpret=pallas_interpret(),
         name="dmlc_hist",
         **({"compiler_params": pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit_bytes)} if vmem_limit_bytes else {}),
-    )(bins_t, node_id.reshape(1, n_pad), grad.reshape(1, n_pad),
-      hess.reshape(1, n_pad))
+    )(bins_t, node_id.reshape(n_class, n_pad), grad.reshape(n_class, n_pad),
+      hess.reshape(n_class, n_pad))
     with jax.named_scope("dmlc.hist.unpack"):
         if layout is not None:
             # kernel-logical rows → storage order (static permutation)
             perm = _bl.layout_tables(layout)["logical"]
-            out = out.reshape(L, 2, n_nodes, hi * lo)[jnp.asarray(perm)]
+            out = out.reshape(L, *stack, 2, n_nodes,
+                              hi * lo)[jnp.asarray(perm)]
         else:
-            out = out[:F].reshape(F, 2, n_nodes, hi * lo)
-        # [F, gh, N, hi·lo] → [gh, N, F, hi·lo] → slice the bin pads
-        return out.transpose(1, 2, 0, 3)[..., :n_bins]
+            out = out[:F].reshape(F, *stack, 2, n_nodes, hi * lo)
+        # [F, (Kb,) gh, N, hi·lo] → [(Kb,) gh, N, F, hi·lo] → slice the
+        # bin pads
+        return out.transpose(*range(1, out.ndim - 1), 0,
+                             out.ndim - 1)[..., :n_bins]
 
 
 def descend_histogram(
@@ -747,11 +952,15 @@ def descend_histogram(
     Returns ``(left_hist, new_node)`` with ``left_hist[_, p]`` the
     histogram of parent p's left child (node 2p) — the caller derives
     the right child by sibling subtraction.  The level of the round
-    program at every shape.
+    program at every shape.  With a CLASS axis (every per-row array
+    ``[K, n]``: the level of a multiclass round's K trees) the descend
+    is each class's own and the build ONE :func:`build_histogram` of K
+    classes; ``left_hist`` and ``new_node`` lead with K.
     Replaces rabit's per-level hist allreduce prep (SURVEY.md §2e
     data-parallel row)."""
     valid = node_id >= 0
-    row_bin = select_feature_bins(bins_t, feat_sel, layout=layout)
+    select = partial(select_feature_bins, bins_t, layout=layout)
+    row_bin = (select if node_id.ndim == 1 else jax.vmap(select))(feat_sel)
     go_right = row_bin > thr_sel
     if dir_sel is not None:
         # learned missing direction: NaN rows (bin == miss_bin) follow

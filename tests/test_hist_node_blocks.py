@@ -52,9 +52,9 @@ def _cap(monkeypatch, cap):
     """The gate, refusing a call of more than ``cap`` nodes."""
     monkeypatch.setattr(
         H, "_pallas_ok",
-        lambda n_bins, n_features, n_nodes=1, bins_itemsize=1, tile_rows=0:
-        _GATE(n_bins, n_features, n_nodes, bins_itemsize, tile_rows)
-        if n_nodes <= cap else 0)
+        lambda n_bins, n_features, n_nodes=1, bins_itemsize=1, tile_rows=0,
+        n_class=1: _GATE(n_bins, n_features, n_nodes, bins_itemsize,
+                         tile_rows, n_class) if n_nodes <= cap else 0)
 
 
 def _rows(F, n_nodes, n_bins, n=700, seed=0):

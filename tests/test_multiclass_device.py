@@ -1,8 +1,9 @@
 """Several classes on the device path: ``HistGBT(objective="multi:softmax")``
 through ``make_device_data`` and ``fit_device`` — K trees a round from one
 softmax over a row's K margins, the margins CLASS-MAJOR ``[K, n]`` on the
-device and ``[n, K]`` only at the host's edge, the class loop a
-``lax.scan`` over the classes.  Held against the benchmark's plain
+device and ``[n, K]`` only at the host's edge, the class loop BATCHED
+(one ``grow_tree`` with a class axis, a level's K histograms one
+``build_histogram`` of K classes).  Held against the benchmark's plain
 reference (``benchmark/reference_multi.py``: float64, row by row, no
 scan and no class-major layout in it) on seeded rows of whole numbers
 with indicator columns, where values sit ON their cuts.
@@ -138,33 +139,88 @@ def test_binning_puts_a_value_equal_to_a_cut_to_its_right(fitted):
     assert on_a_cut.mean() > 0.5           # the ties are there
 
 
-# -- the rolled class loop --------------------------------------------------------
+# -- the batched class loop ------------------------------------------------------
 
-def test_the_rolled_class_loop_is_the_loop_written_out(fitted, monkeypatch):
-    """``round_body`` scans ONE tree's program over the classes.  The same
-    round with every ``lax.scan`` of the trace written out as a Python
-    loop — K copies of ``grow_tree`` on static slices ``g_all[c]``, the
-    parent's form — gives byte-identical trees and margins."""
+def _written_out(grow, bins_tl, g_all, h_all, feat_mask):
+    """The class loop written out: K ``grow``s of ONE class each on the
+    static slices ``g_all[c]`` — the program of PR 48's parent."""
+    outs = [grow(bins_tl, g_all[c], h_all[c], feat_mask)
+            for c in range(g_all.shape[0])]
+    return jax.tree.map(lambda *a: jnp.stack(a), *outs)
+
+
+def _fit(X, y, grow_classes=None, **kw):
+    """A fresh fit through a round program traced now, with the class
+    loop replaced by ``grow_classes`` where given."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(G, "_ROUND_FN_CACHE", {})
+        mp.setattr(G, "_AOT_EXEC_CACHE", {})
+        if grow_classes is not None:
+            mp.setattr(G, "_grow_classes", grow_classes)
+        model = _model(**kw)
+        model.fit_device(model.make_device_data(X, y))
+    return model
+
+
+def test_the_batched_class_loop_is_the_loop_written_out(fitted):
+    """``round_body`` grows the K trees together, ONE ``grow_tree`` with a
+    class axis.  The same round as K ``grow_tree``s of one class written
+    out on ``g_all[c]`` gives byte-identical trees and margins."""
     X, y, model, handle, trees = fitted
-
-    def written_out(f, init, xs=None, length=None, **_):
-        n = length if xs is None else len(jax.tree.leaves(xs)[0])
-        carry, ys = init, []
-        for i in range(n):
-            carry, y_i = f(carry, None if xs is None else
-                           jax.tree.map(lambda a: a[i], xs))
-            ys.append(y_i)
-        return carry, jax.tree.map(lambda *a: jnp.stack(a), *ys)
-
-    monkeypatch.setattr(G, "_ROUND_FN_CACHE", {})
-    monkeypatch.setattr(G, "_AOT_EXEC_CACHE", {})
-    monkeypatch.setattr(jax.lax, "scan", written_out)
-    loop = _model()
-    loop.cuts = model.cuts
-    loop.fit_device(loop.make_device_data(X, y))
-    monkeypatch.undo()
+    loop = _fit(X, y, _written_out)
     assert checks.trees_differ(_host(loop.trees), trees) == 0
     assert np.array_equal(loop.train_margins(), model.train_margins())
+
+
+@pytest.mark.parametrize("case", [
+    "pallas", "pallas_blocks", "lossguide", "lossguide_leaves", "missing",
+    "monotone", "sampled", "ten_classes", "det_blocks_mesh2"])
+def test_the_batched_loop_is_the_written_out_loop_on_every_path(
+        case, monkeypatch):
+    """Byte for byte on every path of the round a class axis goes through:
+    the stacked Pallas kernel (interpreted; one call a level for the seven
+    classes, then in blocks of classes where 7 x A passes 128 rows),
+    loss-guide growth (the K trees expanding in step, with and without a
+    leaf budget), learned missing directions, monotone bounds handed
+    down, row and column sampling (one mask for all classes), more
+    classes than one batch holds (ten: two batches of five, scanned), and
+    deterministic row blocks on a mesh."""
+    n = 1024
+    X, y = _rows(n, seed=7)
+    kw = dict(n_trees=2)
+    if case == "pallas":
+        kw.update(hist_method="pallas")            # 32 bins: L2 stacks
+    elif case == "pallas_blocks":
+        kw.update(hist_method="pallas", n_bins=256, max_depth=5, n_trees=1)
+    elif case.startswith("lossguide"):
+        monkeypatch.setenv("DMLC_GROW_POLICY", "lossguide")
+        if case == "lossguide_leaves":
+            monkeypatch.setenv("DMLC_MAX_LEAVES", "5")
+    elif case == "missing":
+        X = X.copy()
+        X[::5, 1] = np.nan
+        X[1::3, 5] = np.nan
+    elif case == "monotone":
+        kw.update(monotone_constraints=(1, -1) + (0,) * (F - 2))
+    elif case == "sampled":
+        kw.update(subsample=0.7, colsample_bytree=0.6, seed=3)
+    elif case == "ten_classes":
+        y = (y + K * (X[:, 1] > 200)).clip(0, 9).astype(np.float32)
+    elif case == "det_blocks_mesh2":
+        monkeypatch.setenv("DMLC_HIST_BLOCKS", "4")
+        kw.update(mesh_devices=2)
+    batched = _fit(X, y, **kw)
+    loop = _fit(X, y, _written_out, **kw)
+    if case == "ten_classes":
+        assert batched.param.num_class == 10
+    if case == "pallas_blocks":
+        assert batched.round_plan["hist_class_blocks"] == [
+            [7], [7], [7], [7], [4, 3]]
+    assert np.asarray(batched.trees[0]["leaf"]).shape[0] == \
+        batched.param.num_class
+    assert checks.trees_differ(_host(loop.trees), _host(batched.trees)) == 0
+    assert np.array_equal(loop.train_margins(), batched.train_margins())
+    assert np.abs(batched.train_margins()).max() > 0.1
 
 
 def test_two_fits_of_one_handle_are_byte_identical(fitted):

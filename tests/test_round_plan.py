@@ -188,7 +188,8 @@ def test_round_plan_record_is_the_plans_json_view():
     assert set(m.round_plan) == {
         "hist_method", "missing", "pallas_interpret", "grow_policy",
         "bin_layout", "hist_features", "hist_feature_blocks",
-        "hist_node_blocks", "hist_blocks", "mesh_devices",
+        "hist_node_blocks", "hist_class_blocks", "hist_blocks",
+        "mesh_devices",
         "num_class", "trees_per_round", "margin_layout"}
     assert (m.round_plan["num_class"], m.round_plan["trees_per_round"],
             m.round_plan["margin_layout"]) == (1, 1, "[n]")
