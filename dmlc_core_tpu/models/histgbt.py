@@ -64,7 +64,7 @@ from dmlc_core_tpu.ops.histogram import (build_histogram,
                                          resolve_hist_method,
                                          select_feature_bins)
 from dmlc_core_tpu.ops.quantile import (apply_bins, apply_bins_missing,
-                                        apply_bins_t, compute_cuts)
+                                        apply_bins_t, compute_cuts, nan_scan)
 from dmlc_core_tpu.ops.table_select import table_select
 from dmlc_core_tpu.parallel.mesh import device_count, local_mesh
 from dmlc_core_tpu.models.gbt_objectives import (  # noqa: F401  (re-exports:
@@ -1827,9 +1827,15 @@ class HistGBT(_ExternalMemoryEngine):
             sp.set(features=F)
             self._settle_num_class(y)
             weight = self._fold_scale_pos_weight(y, weight)
-            with span("dmlc.ingest.host_prep.nan_scan", bytes=X.nbytes):
-                missing_share = self._settle_missing_mode(X, cuts)
-            sp.set(missing=int(self._missing), missing_share=missing_share)
+            # the NaN scan reads the matrix where it lies: one about to
+            # be put whole for the cut sort is read on the device (in
+            # .cuts below), any other here
+            scan = "device" if cuts is None and self.cuts is None else "host"
+            if scan == "host":
+                with span("dmlc.ingest.host_prep.nan_scan", bytes=X.nbytes,
+                          on="host"):
+                    missing_share = self._settle_missing_mode(
+                        *self._nan_facts_host(X), cuts)
         # explicit cuts always win (a caller injecting boundaries must
         # not be silently overridden by leftovers from an earlier or
         # failed fit); existing self.cuts are kept only when nothing is
@@ -1864,12 +1870,21 @@ class HistGBT(_ExternalMemoryEngine):
                     # (same rows, same order: the cuts are the same
                     # bytes); the binning streams its own slabs below
                     x_cuts = _put_matrix(X, None)
+                # the mode, which compute_cuts needs, from ONE scan of
+                # the matrix just put: the host waits here until it has
+                # landed, as the sort has to
+                with span("dmlc.ingest.cuts.nan_scan", bytes=X.nbytes,
+                          on="device"):
+                    facts = self._nan_facts_device(x_cuts)
+                missing_share = self._settle_missing_mode(*facts, cuts)
                 self.cuts = compute_cuts(
                     x_cuts, p.n_bins - 1 if self._missing else p.n_bins,
                     weight=weight,
                     allgather_fn=self._maybe_allgather(),
                     missing=self._missing)
                 del x_cuts
+        sp.set(missing=int(self._missing), missing_share=missing_share,
+               nan_scan=scan)
         # cut width is the mode's load-bearing invariant: a mismatch
         # (e.g. standard-shaped cuts= injected into a missing-mode
         # model) would silently shift the reserved NaN bin out of the
@@ -1975,11 +1990,46 @@ class HistGBT(_ExternalMemoryEngine):
                        rank_pos=staged.pos)
         return out
 
-    def _settle_missing_mode(self, X: np.ndarray, cuts) -> float:
-        """Scan ``X`` for NaN (a read of the whole matrix; a second one
-        for the columns' finiteness on entering missing mode) and enter,
-        keep or refuse missing mode accordingly.  Returns the share of
-        ``X``'s cells that are NaN."""
+    @staticmethod
+    def _nan_facts_host(X: np.ndarray):
+        """The three facts :meth:`_settle_missing_mode` decides from, read
+        off ``X`` ON THE HOST: the remainder.  A first ingest (no
+        ``cuts=``, no cuts on the model) puts its matrix whole for the
+        cut sort and reads them there (:meth:`_nan_facts_device`); this
+        source is left where no whole matrix goes to the device — a
+        continued fit, an eval handle, ``cuts=`` passed.  ``np.isnan``
+        writes a mask a quarter of the matrix's size and reads it back,
+        single-threaded (ROADMAP S2a(i): one blocked pass would do)."""
+        nan = np.isnan(X)
+        has_nan = bool(nan.any())
+        # counting the marks costs a twentieth of making them, and only
+        # a matrix that has some pays it
+        share = np.count_nonzero(nan) / max(nan.size, 1) if has_nan else 0.0
+        del nan
+        return has_nan, share, lambda: np.isfinite(X).any(axis=0)
+
+    @staticmethod
+    def _nan_facts_device(x: jax.Array):
+        """The same three facts from ONE scan of ``x`` where it lies on
+        the device (``ops.quantile.nan_scan``): two ``[F]`` vectors come
+        back.  The cells are summed in Python ints: a column's count
+        fits int32, the matrix's need not (40M x 28 is over half of
+        2**31)."""
+        nan_count, finite_any = jax.device_get(nan_scan(x))
+        nan_cells = sum(map(int, nan_count))
+        return nan_cells > 0, nan_cells / max(x.size, 1), lambda: finite_any
+
+    def _settle_missing_mode(self, has_nan: bool, share: float, finite_any,
+                             cuts) -> float:
+        """Enter, keep or refuse missing mode from a scan's three facts:
+        any NaN in the local rows, the share of cells that are NaN
+        (returned), and — ``finite_any()``, asked only on entering the
+        mode — per column whether it holds a finite value.  The ONE
+        decision for both sources of the facts
+        (:meth:`_nan_facts_device` where the matrix is put whole,
+        :meth:`_nan_facts_host` elsewhere).  From the device source its
+        errors come AFTER the matrix was put, where they used to come
+        before any transfer; the array is dropped with the exception."""
         p = self.param
         # NaN = missing (XGBoost semantics): auto-enter missing mode on
         # first sight of NaN.  Sticky: once a model has missing-mode
@@ -1987,12 +2037,6 @@ class HistGBT(_ExternalMemoryEngine):
         # the reverse (NaN arriving at a non-missing model with cuts
         # already frozen) must fail loudly, not silently alias NaN into
         # the top value bin.
-        nan = np.isnan(X)
-        has_nan = bool(nan.any())
-        # counting the marks costs a twentieth of making them, and only
-        # a matrix that has some pays it
-        share = np.count_nonzero(nan) / max(nan.size, 1) if has_nan else 0.0
-        del nan
         from dmlc_core_tpu.parallel import collectives as coll
         if coll.world_size() > 1:
             # mode selection must be GLOBAL: a shard that happens to hold
@@ -2005,7 +2049,7 @@ class HistGBT(_ExternalMemoryEngine):
             CHECK(p.n_bins >= 3,
                   "NaN features need n_bins >= 3 (one bin is reserved "
                   "for missing)")
-            finite_any = np.isfinite(X).any(axis=0)
+            finite_any = finite_any()
             if coll.world_size() > 1:
                 # per-feature finiteness must be judged globally too: a
                 # shard whose rows happen to be all-NaN for one feature

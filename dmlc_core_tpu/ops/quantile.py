@@ -54,7 +54,7 @@ from dmlc_core_tpu.base.logging import CHECK
 from dmlc_core_tpu.base.parameter import get_env
 
 __all__ = ["local_summary", "merge_summaries", "compute_cuts", "apply_bins",
-           "apply_bins_t", "apply_bins_missing", "SketchAccumulator"]
+           "apply_bins_t", "apply_bins_missing", "SketchAccumulator", "nan_scan"]
 
 
 def _key_sort_quantiles(x: jax.Array, qs: jax.Array) -> jax.Array:
@@ -490,3 +490,26 @@ def apply_bins_missing(x: jax.Array, cuts: jax.Array,
     into ``[0, n_cuts]`` as usual and NaN maps to ``miss_bin``.
     """
     return apply_bins_t(x, cuts, miss_bin=miss_bin).T
+
+
+@jax.jit
+@jax.named_scope("dmlc.cuts.nan_scan")
+def nan_scan(x: jax.Array) -> tuple:
+    """What the choice of the missing mode needs to know of ``x`` [n, F],
+    from ONE pass over it where it lies: ``(nan_count, finite_any)`` —
+    per column the number of NaN (int32: a column has fewer than 2**31
+    rows; a caller that wants the matrix's total adds the F counts up in
+    Python ints) and whether it holds a finite value (bool).  NumPy's
+    meanings: +-inf is not NaN and not finite, so a column of inf and
+    NaN alone has no finite value.
+
+    Both facts are int32 column sums, so that the TPU compiler makes
+    them ONE fusion that reads the matrix once, at HBM's rate (5.0 ms at
+    24M x 28, 7.0 at 1,183,747 x 968; a sum beside an ``any`` is two
+    fusions, two reads, twice the time: PERF.md section 6, PR 50), and
+    writes nothing of the matrix's size.  A device scope of its own
+    (``dmlc.cuts.nan_scan``): ``dmlc.cuts`` stays the summary and the
+    merge.  (Defined last: the compile cache keys a program on its
+    source lines, and nothing above has moved.)"""
+    return (jnp.sum(jnp.isnan(x), axis=0, dtype=jnp.int32),
+            jnp.sum(jnp.isfinite(x), axis=0, dtype=jnp.int32) > 0)

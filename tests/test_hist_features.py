@@ -358,3 +358,26 @@ def test_the_cut_sort_carries_no_row_index(one_chip):
     # rows on the lanes, the 28 features padded to 32 sublanes
     matrix = n * 32 * 4
     assert compiled.memory_analysis().temp_size_in_bytes < 1.25 * matrix
+
+
+@pytest.mark.parametrize("n, F", [(1_183_747, 968), (40_000_000, 28)])
+def test_the_nan_scan_is_one_read_and_writes_nothing(n, F, one_chip):
+    """ISSUE 50: the scan that settles the missing mode runs beside the
+    matrix the cut sort is about to read — at Bosch's shape 4.27 GiB of
+    15.75, with the sort's 8.79 still to come — so it may hold nothing
+    of the matrix's size; and both its facts come from ONE fusion, one
+    read of the matrix (two fusions took twice the time on the chip)."""
+    from dmlc_core_tpu.ops.quantile import nan_scan
+
+    compiled = _compiled_for_the_chip(
+        nan_scan, jax.ShapeDtypeStruct((n, F), jnp.float32,
+                                       sharding=one_chip))
+    text = compiled.as_text()
+    assert "dmlc.cuts.nan_scan" in text
+    entry = text[text.index("ENTRY "):]
+    readers = [line for line in entry.splitlines()
+               if " fusion(" in line and "%x" in line.split(" fusion(")[1]]
+    assert len(readers) == 1, readers
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1 << 20
+    assert mem.output_size_in_bytes < 1 << 16

@@ -274,10 +274,14 @@ def test_the_record_says_missing_and_how_much(bosch):
     assert rec["counts"]["missing"] == 1
     assert rec["counts"]["missing_share"] == pytest.approx(
         np.isnan(X).mean())
+    # a first ingest: the matrix the cut sort reads is scanned where it
+    # was put, inside the cuts span, and the host reads nothing of it
+    assert rec["counts"]["nan_scan"] == "device"
     n, seconds, _longest, nbytes = \
-        rec["children"]["dmlc.ingest.host_prep.nan_scan"]
+        rec["children"]["dmlc.ingest.cuts.nan_scan"]
     assert (n, nbytes) == (1, X.nbytes) and seconds > 0
-    assert seconds <= rec["children"]["dmlc.ingest.host_prep"][1]
+    assert seconds <= rec["children"]["dmlc.ingest.cuts"][1]
+    assert "dmlc.ingest.host_prep.nan_scan" not in rec["children"]
     # one chip, one slab: ONE put, inside the cuts span, and the slab is
     # binned as it lies
     assert rec["children"]["dmlc.ingest.put"][0] == 1

@@ -34,13 +34,13 @@ from dmlc_core_tpu.utils.profiler import (global_tracer, op_log,
 OPERATIONS = {
     "ingest": ("dmlc.ingest",
                ["dmlc.ingest.stream", "dmlc.ingest.host_prep",
-                "dmlc.ingest.host_prep.nan_scan",
+                "dmlc.ingest.cuts.nan_scan",
                 "dmlc.ingest.cuts", "dmlc.ingest.pad", "dmlc.ingest.labels"],
                ["dmlc.ingest.put", "dmlc.ingest.put_wait",
                 "dmlc.ingest.bin_dispatch", "dmlc.ingest.concat"]),
     "ingest_sharded": ("dmlc.ingest",
                        ["dmlc.ingest.stream", "dmlc.ingest.host_prep",
-                        "dmlc.ingest.host_prep.nan_scan",
+                        "dmlc.ingest.cuts.nan_scan",
                         "dmlc.ingest.cuts", "dmlc.ingest.pad",
                         "dmlc.ingest.labels"],
                        ["dmlc.ingest.put", "dmlc.ingest.bin_dispatch"]),
@@ -56,9 +56,9 @@ OPERATIONS = {
 # operation -> the counts its record keeps
 RECORD_COUNTS = {
     "ingest": {"rows": 3000, "features": 5, "missing": 0,
-               "missing_share": 0.0},
+               "missing_share": 0.0, "nan_scan": "device"},
     "ingest_sharded": {"rows": 3000, "features": 5, "missing": 0,
-                       "missing_share": 0.0},
+                       "missing_share": 0.0, "nan_scan": "device"},
     "fit": {"rounds": 4, "mesh_devices": 1},
     "predict": {"rows": 100, "programs": 1},
 }
