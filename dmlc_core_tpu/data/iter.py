@@ -27,7 +27,8 @@ from dmlc_core_tpu.data.parsers import Parser, parse_uri_spec
 from dmlc_core_tpu.data.row_block import RowBlock, RowBlockContainer
 from dmlc_core_tpu.io.stream import Stream
 from dmlc_core_tpu.io.threaded_iter import ThreadedIter
-from dmlc_core_tpu.utils.profiler import global_tracer, tracing_enabled
+from dmlc_core_tpu.utils.profiler import (global_tracer, phase, span,
+                                          tracing_enabled)
 
 __all__ = ["RowBlockIter", "BasicRowIter", "DiskRowIter", "ArrayRowIter",
            "iter_dense_slabs", "iter_csr_minibatches", "slab_shard_slices"]
@@ -215,7 +216,9 @@ class DiskRowIter(RowBlockIter):
         ctx = (global_tracer().scope("disk_row_iter.build_cache",
                                      cache=self._cache_uri)
                if tracing_enabled() else contextlib.nullcontext())
-        with ctx:
+        # an operation of its own in the spans' record (``op_log``): the
+        # parse-and-write pass a ``#cache`` user pays once per data set
+        with ctx, span("dmlc.pages.build") as sp:
             out = Stream.create(self._cache_uri, "w")
             container = RowBlockContainer()
             held = 0
@@ -235,6 +238,7 @@ class DiskRowIter(RowBlockIter):
                 self._max_index = max(self._max_index, container.max_index)
             out.close()
             parser.close()
+            sp.set(pages=self._num_pages, rows=self._num_rows)
         if _metrics.enabled():
             m = _data_metrics()
             m["pages"].inc(self._num_pages, path="build")
@@ -280,7 +284,10 @@ class DiskRowIter(RowBlockIter):
     def next_block(self) -> Optional[RowBlock]:
         if self._iter is None:
             self._start_reader()
-        return self._iter.next()
+        # the consumer's wait on the page reader: a phase of the
+        # operation that pulls the page (doc/observability.md)
+        with phase("dmlc.ingest.iter.page_wait"):
+            return self._iter.next()
 
     @property
     def num_col(self) -> int:
@@ -289,6 +296,11 @@ class DiskRowIter(RowBlockIter):
     @property
     def num_rows(self) -> int:
         return self._num_rows
+
+    @property
+    def num_pages(self) -> int:
+        """Pages the cache holds: one replay yields this many blocks."""
+        return self._num_pages
 
     def close(self) -> None:
         self._stop_reader()
@@ -340,9 +352,19 @@ def iter_dense_slabs(row_iter, num_col: int, batch_rows: int):
     The yielded arrays are VIEWS of the reused buffers: consumers must
     copy (or upload with an explicit host copy) before advancing the
     generator.  ``w`` is 1.0 where the page carries no weights.
+
+    A slab is ``batch_rows x num_col x 4`` bytes, whatever the pages
+    hold: size ``batch_rows`` from the columns (65,536 rows of 4,227
+    columns are 1.11 GB, and a consumer that copies and uploads holds
+    two).  A slab of 2^32 bytes or more is refused: no transfer of that
+    size reaches a device whole (``models/histgbt.py::_put_matrix``).
     """
     from dmlc_core_tpu.stream.dataset import Dataset
 
+    CHECK(batch_rows * num_col * 4 < 1 << 32,
+          f"iter_dense_slabs: a slab of {batch_rows} rows x {num_col} "
+          f"columns is {batch_rows * num_col * 4} bytes of float32, past "
+          f"2^32 - 1: pass batch_rows <= {((1 << 32) - 1) // (num_col * 4)}")
     return iter(Dataset.from_row_iter(row_iter)
                 .dense_slabs(num_col, batch_rows))
 

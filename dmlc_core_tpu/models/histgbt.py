@@ -1642,12 +1642,46 @@ class HistGBT(_ExternalMemoryEngine):
         tests/test_multichip.py and scripts/check_multichip.py).
         NaN/missing mode is not supported on this path (same contract
         as :meth:`fit_external`): impute before streaming or use
-        :meth:`fit`.
+        :meth:`fit`.  An entry a CSR page does not hold is 0.0 in the
+        slab (``RowBlock.to_dense_into``) and bins as 0.0: this path
+        learns no default direction for absent entries.
+
+        Slab width: a slab is ``slab_rows x F x 4`` bytes of float32 on
+        the host and on the device, and TWICE that is in flight on each
+        side (the source's staging buffer and this method's copy of it;
+        the slab being binned and the next one's put): 65,536 rows of
+        4,227 columns are 1.11 GB a slab.  Size ``slab_rows`` from the
+        columns, not from a row count that suited a narrow table;
+        :func:`~dmlc_core_tpu.data.iter.iter_dense_slabs` refuses a
+        slab of 2^32 bytes or more.
+
+        Host phases (doc/observability.md): the whole call is one
+        ``dmlc.ingest`` operation (counts ``rows``, ``features``,
+        ``pages``, ``slabs``, ``nnz``, ``dense_bytes``); the two passes
+        are ``dmlc.ingest.iter.sketch_pass`` and
+        ``dmlc.ingest.iter.bin_pass``; per slab ``dmlc.ingest.iter.copy``
+        and, in the sketch pass, ``dmlc.ingest.iter.nan_scan`` and
+        ``dmlc.ingest.iter.sketch_add``; the source's own
+        ``dmlc.ingest.iter.page_wait`` (``DiskRowIter``) and
+        ``dmlc.ingest.iter.densify`` (``Dataset.dense_slabs``) fall
+        under them.
         """
+        with span("dmlc.ingest") as sp:
+            out = self._stage_device_data_iter(slab_source, n_features,
+                                               cuts, n_rows, sp)
+        self.last_bin_seconds = sp.seconds
+        if _metrics.enabled():
+            gbt_metrics()["phase"].observe(self.last_bin_seconds,
+                                           engine="incore", phase="bin")
+        return out
+
+    def _stage_device_data_iter(self, slab_source, n_features, cuts,
+                                n_rows, sp) -> Dict[str, Any]:
+        """:meth:`make_device_data_iter`'s work, inside its
+        ``dmlc.ingest`` span ``sp``."""
         from dmlc_core_tpu.ops.quantile import SketchAccumulator
 
         p = self.param
-        t_bin = get_time()
         CHECK(not self._missing,
               "make_device_data_iter: streamed ingest does not support "
               "missing mode (NaN bin) — impute, or fit in-core")
@@ -1672,32 +1706,44 @@ class HistGBT(_ExternalMemoryEngine):
             sketch: Optional[SketchAccumulator] = None
             count = 0
             F_seen = n_features or 0
-            for X_s, y_s, w_s in slab_source():
-                # real copies (np.array): slab sources may yield views
-                # of a reused buffer, and the sketch's device ops
-                # consume the slab asynchronously
-                X_s = np.array(X_s, dtype=np.float32)
-                CHECK(not np.isnan(X_s).any(),
-                      "make_device_data_iter: NaN features are only "
-                      "supported by the in-core fit — impute before "
-                      "streaming")
-                F_seen = max(F_seen, X_s.shape[1])
-                count += len(X_s)
-                if self.cuts is None:
-                    if sketch is None:
-                        sketch = SketchAccumulator(
-                            X_s.shape[1], n_summary=max(8 * p.n_bins, 64))
-                    sketch.add(X_s, self._fold_scale_pos_weight(
-                        np.array(y_s, dtype=np.float32),
-                        None if w_s is None
-                        else np.array(w_s, dtype=np.float32)))
+            with span("dmlc.ingest.iter.sketch_pass") as ps:
+                n_slabs = 0
+                for X_s, y_s, w_s in slab_source():
+                    n_slabs += 1
+                    # real copies (np.array): slab sources may yield
+                    # views of a reused buffer, and the sketch's device
+                    # ops consume the slab asynchronously
+                    with span("dmlc.ingest.iter.copy",
+                              bytes=X_s.shape[0] * X_s.shape[1] * 4):
+                        X_s = np.array(X_s, dtype=np.float32)
+                    with span("dmlc.ingest.iter.nan_scan",
+                              bytes=X_s.nbytes):
+                        CHECK(not np.isnan(X_s).any(),
+                              "make_device_data_iter: NaN features are "
+                              "only supported by the in-core fit — "
+                              "impute before streaming")
+                    F_seen = max(F_seen, X_s.shape[1])
+                    count += len(X_s)
+                    if self.cuts is None:
+                        if sketch is None:
+                            sketch = SketchAccumulator(
+                                X_s.shape[1],
+                                n_summary=max(8 * p.n_bins, 64))
+                        with span("dmlc.ingest.iter.sketch_add",
+                                  bytes=X_s.nbytes):
+                            sketch.add(X_s, self._fold_scale_pos_weight(
+                                np.array(y_s, dtype=np.float32),
+                                None if w_s is None
+                                else np.array(w_s, dtype=np.float32)))
+                ps.set(slabs=n_slabs)
             CHECK(count > 0, "make_device_data_iter: empty input")
             n_rows = count if n_rows is None else n_rows
             CHECK_EQ(n_rows, count, "declared n_rows != streamed rows")
             n_features = F_seen
             if self.cuts is None:
-                self.cuts = sketch.finalize(
-                    p.n_bins, allgather_fn=self._maybe_allgather())
+                with span("dmlc.ingest.iter.sketch_finalize"):
+                    self.cuts = sketch.finalize(
+                        p.n_bins, allgather_fn=self._maybe_allgather())
         F = int(n_features)
         CHECK_EQ(int(self.cuts.shape[0]), F,
                  "cuts width does not match the streamed feature count")
@@ -1716,15 +1762,21 @@ class HistGBT(_ExternalMemoryEngine):
         ys: List[np.ndarray] = []
         ws: List[np.ndarray] = []
 
+        n_slabs = 0
+
         def x_slabs():
+            nonlocal n_slabs
             for X_s, y_s, w_s in (slab_source() if callable(slab_source)
                                   else slab_source):
+                n_slabs += 1
                 # REAL copy, not ascontiguousarray: slab sources may
                 # yield views of a reused staging buffer
                 # (iter_dense_slabs' contract), and device_put can
                 # alias host memory on the CPU backend — an in-flight
                 # async H2D piece must never see the next slab's bytes
-                X_s = np.array(X_s, dtype=np.float32)
+                with span("dmlc.ingest.iter.copy",
+                          bytes=X_s.shape[0] * X_s.shape[1] * 4):
+                    X_s = np.array(X_s, dtype=np.float32)
                 y_np = np.array(y_s, dtype=np.float32)
                 self._settle_num_class(y_np)
                 ys.append(y_np)
@@ -1733,7 +1785,14 @@ class HistGBT(_ExternalMemoryEngine):
                     else np.array(w_s, dtype=np.float32)))
                 yield X_s
 
-        bins_t = self._ingest_slabs_sharded(x_slabs(), n, n_padded, F)
+        with span("dmlc.ingest.iter.bin_pass") as pb:
+            bins_t = self._ingest_slabs_sharded(x_slabs(), n, n_padded, F)
+            pb.set(slabs=n_slabs)
+        # pages and nnz are the source's count (count_in_op), over the
+        # passes made; dense_bytes what ONE pass densifies
+        sp.set(rows=n, features=F, slabs=n_slabs, dense_bytes=n * F * 4,
+               pages=sp.counts.get("pages", 0),
+               nnz=sp.counts.get("nnz", 0))
         y = np.concatenate(ys) if len(ys) > 1 else ys[0]
         mask = np.concatenate(ws) if len(ws) > 1 else ws[0]
         CHECK_EQ(len(y), n, "slab stream row count changed between passes")
@@ -1749,10 +1808,6 @@ class HistGBT(_ExternalMemoryEngine):
             "n_padded": n_padded,
             "n_features": F,
         }
-        self.last_bin_seconds = get_time() - t_bin
-        if _metrics.enabled():
-            gbt_metrics()["phase"].observe(self.last_bin_seconds,
-                                           engine="incore", phase="bin")
         return out
 
     # ------------------------------------------------------------------

@@ -171,16 +171,18 @@ _TILE_ROWS = 16384
 #: 3.75 MiB.
 _SCOPED_VMEM = 16 << 20
 _SCOPED_ROW_RESERVE = 240
-#: the scoped-VMEM limit the calls of a build cut BOTH ways state for
-#: themselves (node blocks around feature blocks: the last level of a
-#: depth-8 tree on a matrix wider than one feature block).  Alone, and
-#: in the 25-round program of a table with holes, each of those calls
-#: fits the compiler's 16 MiB; in other programs around the very same
-#: calls the v5e compiler charges them 16.50-19.12 MiB and refuses the
-#: program (968 features, 32-node blocks: the one-round program, the
-#: dense 25-round program), by no rule of the block's size — 17.34 MiB
-#: at 152 rows, 16.50 at 168, 19.12 at 200 (compile-only client,
-#: PERF.md section 6, PR 42).  A v5e core has 128 MiB of VMEM.
+#: the scoped-VMEM limit every call of a build cut on FEATURES states
+#: for itself (a matrix wider than one feature block, whether or not
+#: node blocks lie around the feature blocks).  Alone, and in some
+#: programs, each of those calls fits the compiler's 16 MiB; in other
+#: programs around the very same calls the v5e compiler charges them
+#: 16.50-19.12 MiB and refuses the program, by no rule of the block's
+#: size a call can observe — 17.34 MiB at 152 rows, 16.50 at 168, 19.12
+#: at 200 (968 features, 32-node blocks: PERF.md section 6, PR 42);
+#: 18.75 for the 8 builds of a level 4 on a 392-row block at 4,227
+#: features (PR 51; compile-only client both).  Stating the limit costs
+#: nothing: the same kernel, the same time (A/B on the chip, PERF.md
+#: section 6, PR 51).  A v5e core has 128 MiB of VMEM.
 _NESTED_BLOCKS_VMEM = 32 << 20
 #: the scoped-VMEM limit a STACKED call states for itself (a block of
 #: classes in one kernel, :func:`hist_class_blocks`), and what each
@@ -580,13 +582,12 @@ def _hist_pallas_blocks(bins, node_id, grad, hess, n_nodes, n_bins, *,
         (node_id >= lo) & (node_id < hi), node_id - lo, -1))
     return in_nblock(jnp.concatenate)(
         [_hist_pallas_fblocks(bins, own(lo, hi), grad, hess, hi - lo,
-                              n_bins, transposed=transposed, layout=layout,
-                              in_node_block=True)
+                              n_bins, transposed=transposed, layout=layout)
          for lo, hi in pairwise(accumulate(blocks, initial=0))], axis=-3)
 
 
 def _hist_pallas_fblocks(bins, node_id, grad, hess, n_nodes, n_bins, *,
-                         transposed, layout=None, in_node_block=False):
+                         transposed, layout=None):
     """One node block of :func:`_hist_pallas_blocks` over its feature
     blocks.  One block (every shape the whole-matrix budgets admit, and
     every packed ``layout``) is the plain call and traces nothing else.
@@ -595,9 +596,10 @@ def _hist_pallas_fblocks(bins, node_id, grad, hess, n_nodes, n_bins, *,
     on no other feature, so each is made of the same operations in the
     same order as in an unblocked build, bit for bit.  What blocking
     adds outside the kernels (the slabs, the join) runs under the device
-    scope ``dmlc.hist.fblock``.  ``in_node_block``: this is one of
-    several node blocks, so a cut on features too makes the build one of
-    blocks inside blocks, whose calls state ``_NESTED_BLOCKS_VMEM``."""
+    scope ``dmlc.hist.fblock``.  Every call of a build cut on features
+    states ``_NESTED_BLOCKS_VMEM`` for itself (a stacked call keeps its
+    ``_STACKED_VMEM``): the limit is stated, not used — the kernel is
+    the same kernel."""
     if layout is not None:
         return _hist_pallas(bins, node_id, grad, hess, n_nodes, n_bins,
                             transposed=True, layout=layout)
@@ -611,10 +613,10 @@ def _hist_pallas_fblocks(bins, node_id, grad, hess, n_nodes, n_bins, *,
     in_fblock = jax.named_scope("dmlc.hist.fblock")
     slab = in_fblock(lambda lo, hi: (bins[lo:hi] if transposed
                                      else bins[:, lo:hi]))
-    vmem = _NESTED_BLOCKS_VMEM if in_node_block else 0
     return in_fblock(jnp.concatenate)(
         [_hist_pallas(slab(lo, hi), node_id, grad, hess, n_nodes, n_bins,
-                      transposed=transposed, vmem_limit_bytes=vmem)
+                      transposed=transposed,
+                      vmem_limit_bytes=_NESTED_BLOCKS_VMEM)
          for lo, hi in pairwise(accumulate(blocks, initial=0))], axis=-2)
 
 
@@ -857,7 +859,7 @@ def _hist_pallas(bins, node_id, grad, hess, n_nodes, n_bins,
 
     ``vmem_limit_bytes``: the scoped-VMEM limit the call states for
     itself; 0 leaves the compiler's (``_SCOPED_VMEM``), as every build
-    that is not cut both ways does.
+    of one feature block does.
 
     ``node_id`` / ``grad`` / ``hess`` ``[Kb, n]`` make the STACKED call
     (:func:`_hist_class_blocks`): in-blocks ``(Kb, tile_rows)`` — class
@@ -875,7 +877,7 @@ def _hist_pallas(bins, node_id, grad, hess, n_nodes, n_bins,
                     else _lo_factor(n_nodes, n_bins)), n_bins)
     CHECK(lo > 0, "a stacked histogram call needs an aligned lo")
     if stack:
-        vmem_limit_bytes = vmem_limit_bytes or _STACKED_VMEM
+        vmem_limit_bytes = max(vmem_limit_bytes, _STACKED_VMEM)
     hi = -(-n_bins // lo)
     A = 2 * n_nodes * hi * n_class        # rows of the left operand
     Fp = -(-F // 8) * 8          # feature groups of 8 (sublane alignment)

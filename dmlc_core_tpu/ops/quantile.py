@@ -152,8 +152,6 @@ def _finite_key_sort_summary(x: jax.Array, n_summary: int) -> jax.Array:
         return jnp.where((c == 0)[None, :], jnp.nan, out).T
 
 
-@partial(jax.jit, static_argnums=(2, 3))
-@jax.named_scope("dmlc.cuts")
 def local_summary(x: jax.Array, weight: Optional[jax.Array],
                   n_summary: int, missing: bool = False) -> jax.Array:
     """Fixed-size weighted quantile summary of local rows.
@@ -219,8 +217,19 @@ def local_summary(x: jax.Array, weight: Optional[jax.Array],
     return out
 
 
-@partial(jax.jit, static_argnums=(1,))
-@jax.named_scope("dmlc.cuts")
+def _scoped(fn, scope: str, static_argnums):
+    """``fn`` as a program of its own whose operations carry the device
+    scope ``scope``: the same function is ``dmlc.cuts`` where
+    ``compute_cuts`` runs it on a whole matrix and ``dmlc.sketch.*``
+    where :class:`SketchAccumulator` runs it on a page (the innermost
+    scope names an operation, so one body cannot carry both)."""
+    return jax.jit(jax.named_scope(scope)(fn), static_argnums=static_argnums)
+
+
+_page_summary = _scoped(local_summary, "dmlc.sketch.add", (2, 3))
+local_summary = _scoped(local_summary, "dmlc.cuts", (2, 3))
+
+
 def merge_summaries(gathered: jax.Array, n_bins: int) -> jax.Array:
     """Merge ``[W, F, n_summary]`` worker summaries into ``[F, n_bins-1]``
     cut points (interior boundaries; bin b = count of cuts ≤ x).
@@ -252,6 +261,10 @@ def merge_summaries(gathered: jax.Array, n_bins: int) -> jax.Array:
     return E + jax.lax.cummax(cuts - E, axis=1)
 
 
+_sketch_cuts = _scoped(merge_summaries, "dmlc.sketch.finalize", (1,))
+merge_summaries = _scoped(merge_summaries, "dmlc.cuts", (1,))
+
+
 def compute_cuts(
     x: np.ndarray,
     n_bins: int = 256,
@@ -281,8 +294,6 @@ def compute_cuts(
     return merge_summaries(gathered, n_bins)
 
 
-@partial(jax.jit, static_argnums=(2,))
-@jax.named_scope("dmlc.cuts")
 def _weighted_collapse(stack: jax.Array, wts: jax.Array, n_out: int) -> jax.Array:
     """Merge ``[K, F, S]`` summaries with per-summary weights ``[K]`` into
     one ``[F, n_out]`` summary.
@@ -303,6 +314,10 @@ def _weighted_collapse(stack: jax.Array, wts: jax.Array, n_out: int) -> jax.Arra
     probs = (cw - 0.5 * ws) / total                                    # midpoint rule
     qs = jnp.linspace(0.0, 1.0, n_out)
     return jax.vmap(lambda xf, pf: jnp.interp(qs, pf, xf))(xs, probs)  # [F, n_out]
+
+
+_ladder_merge = _scoped(_weighted_collapse, "dmlc.sketch.merge", (2,))
+_final_collapse = _scoped(_weighted_collapse, "dmlc.sketch.finalize", (2,))
 
 
 class SketchAccumulator:
@@ -347,13 +362,21 @@ class SketchAccumulator:
         CHECK(x.shape[1] == self._F, "feature-count mismatch")
         if x.shape[0] == 0:
             return
+        wt = float(x.shape[0] if weight is None else np.sum(weight))
+        if weight is not None and wt > 0 and np.all(weight == weight[0]):
+            # one weight for every row (``iter_dense_slabs`` hands out
+            # 1.0 where a page has none) is no weight: the quantile
+            # function is the same, and the key-only sort computes it
+            # where the weighted path carries a permutation through
+            # the sort and gathers the page twice (a TPU gathers
+            # element by element: PERF.md section 6, PR 51)
+            weight = None
         with self._on_device():
-            s = local_summary(
+            s = _page_summary(
                 jnp.asarray(x),
                 None if weight is None else jnp.asarray(weight),
                 self._S)
             s = np.asarray(s)
-        wt = float(x.shape[0] if weight is None else np.sum(weight))
         self.pages_seen += 1
         self._levels[0].append((s, wt))
         lvl = 0
@@ -376,7 +399,7 @@ class SketchAccumulator:
             stack = jnp.asarray(np.stack([s for s, _ in group]))
             wts = np.asarray([w for _, w in group], np.float32)
             merged = np.asarray(
-                _weighted_collapse(stack, jnp.asarray(wts), self._S))
+                _ladder_merge(stack, jnp.asarray(wts), self._S))
         return merged, float(wts.sum())
 
     def summary(self) -> tuple:
@@ -405,9 +428,9 @@ class SketchAccumulator:
         else:
             gathered = local[None]
             wts = np.asarray([wt], np.float32)
-        merged = _weighted_collapse(
+        merged = _final_collapse(
             jnp.asarray(gathered), jnp.asarray(wts), self._S)     # [F, S]
-        return merge_summaries(merged[None], n_bins)
+        return _sketch_cuts(merged[None], n_bins)
 
 
 #: the cut axis is counted as [K, _CUT_FOLD]: XLA:CPU rewrites a reduce

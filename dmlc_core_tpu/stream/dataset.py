@@ -41,6 +41,7 @@ from typing import Any, Callable, Iterable, Iterator, List, Optional, Tuple
 import numpy as np
 
 from dmlc_core_tpu.base.logging import CHECK
+from dmlc_core_tpu.utils.profiler import count_in_op, phase
 
 __all__ = ["Dataset", "encode_dense_event", "encode_dense_events",
            "decode_dense_events"]
@@ -64,11 +65,14 @@ def _dense_slab_iter(blocks: Iterable[Any], num_col: int,
         CHECK(b.nnz == 0 or b.max_index < num_col,
               f"dense_slabs: page has feature index {b.max_index} "
               f"but the consumer expects {num_col} features")
+        count_in_op(pages=1, nnz=b.nnz)
         done = 0
         while done < b.size:
             take = min(b.size - done, batch_rows - filled)
-            b.slice(done, done + take).to_dense_into(
-                stage[filled:filled + take])
+            with phase("dmlc.ingest.iter.densify",
+                       bytes=take * num_col * 4):
+                b.slice(done, done + take).to_dense_into(
+                    stage[filled:filled + take])
             ys[filled:filled + take] = b.label[done:done + take]
             if b.weight is not None:
                 ws[filled:filled + take] = b.weight[done:done + take]

@@ -51,9 +51,9 @@ from typing import Any, Deque, Dict, Iterator, List, Optional
 from dmlc_core_tpu.base import metrics as _metrics
 from dmlc_core_tpu.base.timer import get_time
 
-__all__ = ["device_trace", "span", "current_op", "op_log",
-           "op_log_dropped", "Tracer", "global_tracer", "tracing_enabled",
-           "set_tracing"]
+__all__ = ["device_trace", "span", "phase", "count_in_op", "current_op",
+           "op_log", "op_log_dropped", "Tracer", "global_tracer",
+           "tracing_enabled", "set_tracing"]
 
 _TRACING = os.environ.get("DMLC_TRACE", "0").lower() in ("1", "true", "on",
                                                          "yes")
@@ -110,6 +110,31 @@ def current_op() -> Optional[int]:
     the worker opens join the same operation."""
     stack = getattr(_open, "spans", None)
     return stack[-1].counts["op"] if stack else None
+
+
+def count_in_op(**counts: float) -> None:
+    """Add numbers to the counts of the operation open on this thread
+    (its top-level :class:`span`; nothing outside any): how a layer
+    below an operation — a page reader, a densifier — reports what it
+    moved without knowing who consumes it.  The sums are in the
+    operation's :func:`op_log` record; the opener hands them to the
+    trace with :meth:`span.set` before it closes."""
+    stack = getattr(_open, "spans", None)
+    if stack:
+        top = stack[0].counts
+        for key, value in counts.items():
+            top[key] = top.get(key, 0) + value
+
+
+def phase(name: str, **counts: Any) -> Any:
+    """:class:`span` ``name`` where an operation is open on this thread,
+    else a context that marks nothing: for code BELOW the entry points
+    (``DiskRowIter.next_block``, ``Dataset.dense_slabs``), whose wait or
+    copy is a phase of whichever operation pulls it, and which outside
+    any would draw an ``op`` and a record of its own per page."""
+    if getattr(_open, "spans", None):
+        return span(name, **counts)
+    return contextlib.nullcontext()
 
 
 #: records the ring keeps; the oldest go first.  A 20 s window of the

@@ -42,6 +42,22 @@ def main():
     preds = model.predict_iter(it)
     acc = float(((preds > 0.5) == y).mean())
     print(f"streamed predictions over {len(preds)} rows, train acc {acc:.3f}")
+
+    # the same pages as a DEVICE-RESIDENT handle for repeated fits
+    # (make_device_data_iter: a streaming sketch pass, then each slab
+    # binned onto the chip).  A slab is slab_rows x F x 4 bytes of
+    # float32 whatever the pages hold, and two are in flight on the
+    # host and on the device: size slab_rows from the COLUMNS (here
+    # 8,192 x 16 x 4 = 0.5 MB; a 4,227-column one-hot table at 65,536
+    # rows is 1.11 GB a slab).  An entry a row lacks is 0.0.
+    from dmlc_core_tpu.data.iter import iter_dense_slabs
+
+    resident = HistGBT(n_trees=10, max_depth=5, n_bins=64, learning_rate=0.3)
+    handle = resident.make_device_data_iter(
+        lambda: iter_dense_slabs(it, F, 8192))
+    resident.fit_device(handle)
+    print(f"paged handle of {handle['n']} rows x {handle['n_features']} "
+          f"columns: {len(resident.trees)} trees")
     it.close()
 
 

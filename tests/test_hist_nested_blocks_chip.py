@@ -8,7 +8,8 @@ refused the program — the one-round program of the missing-value
 deployment (what ``make_device_data`` compiles in the background for a
 model of one tree), the dense 25-round program — while the 25-round
 program under ``missing`` compiled and ran.  The calls of a build cut
-both ways therefore state their own limit (``_NESTED_BLOCKS_VMEM``).
+both ways therefore state their own limit (``_NESTED_BLOCKS_VMEM``);
+since PR 51 every call of a build cut on features does.
 """
 
 import os
@@ -89,10 +90,12 @@ def _limits(jaxpr):
     return out
 
 
-def test_only_a_build_cut_both_ways_states_a_limit(monkeypatch):
-    """The limit rides on the calls of node blocks that are cut on
-    features too, and on no other call: the accepted cells' kernels (one
-    call a build; feature blocks alone; node blocks alone) trace as they
+def test_every_call_of_a_build_cut_on_features_states_a_limit(monkeypatch):
+    """The limit rides on every call of a build cut on features, with
+    node blocks around the feature blocks or without (since PR 51: the
+    8 builds of a level 4 on a 392-row block were refused at 18.75 MiB
+    with no node block in sight), and on no other call: a build of one
+    feature block (one call a build; node blocks alone) traces as it
     did."""
     rng = np.random.default_rng(0)
     n, n_bins = 700, 64
@@ -109,6 +112,6 @@ def test_only_a_build_cut_both_ways_states_a_limit(monkeypatch):
     _cap(monkeypatch, 4)
     assert limits(12, 16) == [None] * 4           # node blocks alone
     monkeypatch.setattr(H, "_SCOPED_VMEM", _budget(16))
-    assert limits(44, 4) == [None] * 3            # feature blocks alone
+    assert limits(44, 4) == [H._NESTED_BLOCKS_VMEM] * 3   # feature blocks
     assert limits(44, 16) == [H._NESTED_BLOCKS_VMEM] * 12
     assert H._NESTED_BLOCKS_VMEM > 16 << 20
