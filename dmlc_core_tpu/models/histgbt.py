@@ -42,7 +42,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import shard_map
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P, Sharding,
+                          SingleDeviceSharding)
 
 from dmlc_core_tpu.base import compile_cache as _cc
 from dmlc_core_tpu.base import metrics as _metrics
@@ -64,7 +65,8 @@ from dmlc_core_tpu.ops.histogram import (build_histogram,
                                          resolve_hist_method,
                                          select_feature_bins)
 from dmlc_core_tpu.ops.quantile import (apply_bins, apply_bins_missing,
-                                        apply_bins_t, compute_cuts, nan_scan)
+                                        apply_bins_t, compute_cuts,
+                                        mesh_nan_scan, nan_scan)
 from dmlc_core_tpu.ops.table_select import table_select
 from dmlc_core_tpu.parallel.mesh import device_count, local_mesh
 from dmlc_core_tpu.models.gbt_objectives import (  # noqa: F401  (re-exports:
@@ -343,7 +345,7 @@ _PUT_PIECE_BYTES = 1 << 31
 
 
 @lru_cache(maxsize=32)
-def _write_rows_fn(sharding: Optional[NamedSharding]):
+def _write_rows_fn(sharding: Optional[Sharding]):
     """Jitted ``whole[lo:lo + len(piece)] = piece`` in place (``whole``
     is donated; ``lo`` rides as an operand, so one program serves every
     piece of a shape).  ``sharding=None``: where the operands lie."""
@@ -354,7 +356,7 @@ def _write_rows_fn(sharding: Optional[NamedSharding]):
 
 
 @lru_cache(maxsize=32)
-def _empty_matrix_fn(sharding: Optional[NamedSharding], shape: tuple, dtype):
+def _empty_matrix_fn(sharding: Optional[Sharding], shape: tuple, dtype):
     """Jitted allocation of an uninitialised device matrix (per shape: a
     fresh closure would compile on every call).  ``sharding=None``: on
     the default device, uncommitted."""
@@ -370,8 +372,11 @@ def _put_matrix(X: np.ndarray,
     Every put is a ``dmlc.ingest.put`` span.
 
     ``sharding=None`` is ``jnp.asarray``'s placement: the default
-    device, whole, UNCOMMITTED — what follows from the array (the cuts)
-    may then meet operands on any chip of a mesh."""
+    device, whole, UNCOMMITTED (one chip and several slabs, and a mesh
+    whose rows cannot be placed chip by chip).  A mesh that can takes
+    its rows as one shard a chip, its own paced pieces
+    (:meth:`HistGBT._put_row_shards`): never the whole matrix on one
+    of them."""
     if X.nbytes < _PUT_CLIFF_BYTES:
         with span("dmlc.ingest.put", bytes=X.nbytes):
             return jax.device_put(X, sharding)
@@ -1330,7 +1335,8 @@ class HistGBT(_ExternalMemoryEngine):
         deterministic-histogram mode) and build the weight mask
         (pad rows weigh 0, so they are invisible to cuts/grads/hists).
         A ranking handle's pad rows close each shard, not the matrix:
-        ``staged`` says where every row goes."""
+        ``staged`` says where every row goes.  ``X`` is None where the
+        matrix already lies padded on the device."""
         n = len(y)
         if staged is not None and staged.place is not None:
             rows = staged.n_padded
@@ -1343,8 +1349,9 @@ class HistGBT(_ExternalMemoryEngine):
             return Xp, yp, mask, rows - n
         n_pad = (-n) % self._pad_multiple()
         if n_pad:
-            X = np.concatenate([X, np.zeros((n_pad, X.shape[1]),
-                                            np.float32)])
+            if X is not None:
+                X = np.concatenate([X, np.zeros((n_pad, X.shape[1]),
+                                                np.float32)])
             y = np.concatenate([y, np.zeros(n_pad, np.float32)])
         mask = np.ones(n + n_pad, np.float32)
         if weight is not None:
@@ -1419,10 +1426,11 @@ class HistGBT(_ExternalMemoryEngine):
                              ) -> jax.Array:
         """Chunked, double-buffered host→device ingest + binning.
 
-        ``resident`` is ``X`` already on the device under
-        ``mat_sharding`` (the cut sort's operand, where the matrix is one
-        slab: ``_stage_device_data``): it is binned as it lies and
-        nothing is put.
+        ``resident`` is ``X`` (padded) already on the device under
+        ``mat_sharding`` (the cut sort's operand — one chip's one slab,
+        a mesh's row shards: ``_stage_device_data``): it is binned as it
+        lies, each chip its own rows, and nothing is put; ``X`` is not
+        read.
 
         The whole-matrix path ships the full f32 ``X`` to device and
         keeps it resident while the bin kernel runs — ~5× the binned
@@ -1443,16 +1451,16 @@ class HistGBT(_ExternalMemoryEngine):
         cut sort is waiting for, and a 24M × 28 ingest takes 8–12 s
         instead of 4.5 (PERF.md §5–6, PR 28).
         """
-        n = X.shape[0]
-        ndev = device_count(self.mesh)
-        chunk = _ingest_chunk_rows(ndev)
-        if self._one_slab(n):
-            chunk = n                      # one slab: the whole matrix
         fn = _bin_chunk_fn(self.mesh, self._nan_bin())
         if resident is not None:
             with span("dmlc.ingest.stream", slabs=1):
                 with span("dmlc.ingest.bin_dispatch"):
                     return fn(resident, self.cuts)
+        n = X.shape[0]
+        ndev = device_count(self.mesh)
+        chunk = _ingest_chunk_rows(ndev)
+        if self._one_slab(n):
+            chunk = n                      # one slab: the whole matrix
         pieces: List[jax.Array] = []
         inflight: deque = deque()
 
@@ -1510,6 +1518,59 @@ class HistGBT(_ExternalMemoryEngine):
         for lo in range(0, len(X), chunk):
             yield X[lo:lo + chunk]
 
+    def _put_row_shards(self, X: np.ndarray, n_padded: int) -> jax.Array:
+        """``X`` [n, F] float32 on the mesh as ``[n_padded, F]`` in ROW
+        shards, every row put ONCE and only to the chip that owns it
+        (device ``k``: global rows ``[k·S, (k+1)·S)``, the cut of
+        :meth:`_ingest_slabs_sharded`); the pad rows, the tail, are
+        zeros.  No host copy of ``X`` is made: a piece is a view of its
+        rows, but for the one the pad rows close.
+
+        A shard goes in pieces of at most ``DMLC_INGEST_CHUNK_ROWS``
+        rows (and ``_PUT_PIECE_BYTES``), chip after chip, written into
+        place on their chip, and the puts are PACED like the one-chip
+        slab stream's (``dmlc.ingest.put_wait``): at most two in flight.
+        Four 1.12 GB shards handed to the runtime at once — or as one
+        put under the row sharding — took 6.5 s to land on four v5e
+        chips where one of them alone lands in 0.12 s; one after another
+        0.48 s; in sixteen pieces two at a time 0.25 s (PERF.md section
+        6, PR 52)."""
+        n, F = X.shape
+        devs = list(np.asarray(self.mesh.devices).flat)
+        CHECK_EQ(n_padded % len(devs), 0, "padded rows must divide the mesh")
+        S = n_padded // len(devs)
+        step = max(1, min(_ingest_chunk_rows(1) or S,
+                          _PUT_PIECE_BYTES // (F * 4), S))
+        on = [SingleDeviceSharding(d) for d in devs]
+        # a shard of one piece is that piece; else pieces are written
+        # into an array allocated on their chip
+        shards = [None if step == S
+                  else _empty_matrix_fn(sh, (S, F), np.dtype(np.float32))()
+                  for sh in on]
+        inflight: deque = deque()
+
+        def land_oldest():
+            k, lo, piece = inflight.popleft()
+            with span("dmlc.ingest.put_wait", bytes=piece.nbytes, chip=k):
+                piece.block_until_ready()
+            shards[k] = (piece if step == S
+                         else _write_rows_fn(on[k])(shards[k], piece, lo))
+
+        for lo in range(0, S, step):
+            rows = min(step, S - lo)
+            for k in range(len(devs)):
+                piece = X[min(k * S + lo, n):min(k * S + lo + rows, n)]
+                if len(piece) < rows:
+                    piece = np.concatenate(
+                        [piece, np.zeros((rows - len(piece), F), np.float32)])
+                with span("dmlc.ingest.put", bytes=piece.nbytes, chip=k):
+                    inflight.append((k, lo, jax.device_put(piece, on[k])))
+                if len(inflight) >= 2:       # keep one H2D put in flight
+                    land_oldest()
+        while inflight:
+            land_oldest()
+        return assemble_row_sharded(shards, self.mesh, dim=0, axis="data")
+
     def _ingest_slabs_sharded(self, slabs, n_real: int, n_padded: int,
                               n_features: int,
                               binned: bool = False) -> jax.Array:
@@ -1543,7 +1604,10 @@ class HistGBT(_ExternalMemoryEngine):
         # that piece's device: each chip bins exactly its own row slice
         bin_fn = (None if host_bin
                   else partial(apply_bins_t, miss_bin=self._nan_bin()))
-        cuts_dev = None if host_bin else jnp.asarray(self.cuts)
+        # a copy a chip (the cuts of a mesh's first ingest are committed
+        # to the whole mesh until _stage_device_data has fetched them)
+        cuts_dev = (None if host_bin
+                    else [jax.device_put(self.cuts, d) for d in devs])
         pieces: List[List[Any]] = [[] for _ in range(ndev)]
         counts = [0] * ndev
         inflight: deque = deque()
@@ -1555,7 +1619,7 @@ class HistGBT(_ExternalMemoryEngine):
         def bin_oldest():
             kq, xq = inflight.popleft()
             with span("dmlc.ingest.bin_dispatch", chip=kq):
-                pieces[kq].append(bin_fn(xq, cuts_dev))
+                pieces[kq].append(bin_fn(xq, cuts_dev[kq]))
 
         lo = 0
         with span("dmlc.ingest.stream") as sp:
@@ -1844,8 +1908,8 @@ class HistGBT(_ExternalMemoryEngine):
         :meth:`fit_device` boosts on it like on any other handle.
 
         Asynchronous: the handle comes back once the last staging call
-        is ENQUEUED; its arrays are ready when the device has drained
-        them (``jax.block_until_ready`` on them to wait).
+        is ENQUEUED (a mesh's first: once its cuts exist, fetched LAST);
+        ready when drained (``jax.block_until_ready`` on its arrays).
         """
         with span("dmlc.ingest", rows=len(y)) as sp:
             out = self._stage_device_data(X, y, weight, cuts, sp, qid)
@@ -1899,17 +1963,33 @@ class HistGBT(_ExternalMemoryEngine):
         x_dev = None
         n_padded = (staged.n_padded if staged is not None
                     else n + ((-n) % self._pad_multiple()))
+        ndev = device_count(self.mesh)
+        sharded = ndev > 1 and self._sharded_ingest_ok()
         if cuts is not None:
             self.cuts = cuts
         elif self.cuts is None:
             # missing mode: n_bins-1 VALUE bins (cuts [F, n_bins-2]),
             # bin n_bins-1 reserved for NaN
-            # whole-matrix put (in pieces past the 2^32-byte cliff, on
-            # every path: _put_matrix), then the summary and merge
-            # enqueued
+            # the matrix put for the cut sort (in pieces past the
+            # 2^32-byte cliff, on every path), then the summary and
+            # merge enqueued
             with span("dmlc.ingest.cuts", bytes=X.nbytes):
-                if (device_count(self.mesh) == 1 and self._one_slab(n)
-                        and n_padded == n
+                if sharded:
+                    # a mesh whose rows are placed chip by chip: every
+                    # row goes ONCE, to the chip that owns it, the chips
+                    # sort the columns between them (compute_cuts(mesh=))
+                    # and no chip holds the whole matrix.  Where the bin
+                    # matrix's row shards are these (nothing placed by a
+                    # ranking handle, binning on the device) they stay
+                    # resident until binned; else they serve the cuts
+                    # alone and the slab stream below puts its own
+                    resident = ((staged is None or staged.place is None)
+                                and not _host_bin_requested())
+                    x_cuts = self._put_row_shards(
+                        X, n_padded if resident else n + (-n) % ndev)
+                    if resident:
+                        x_dev = x_cuts
+                elif (ndev == 1 and self._one_slab(n) and n_padded == n
                         and not _host_bin_requested()
                         and not self._mesh_spans_processes()):
                     # one chip, one slab: the matrix the cut sort reads
@@ -1920,26 +2000,32 @@ class HistGBT(_ExternalMemoryEngine):
                     # section 6, PR 42)
                     x_cuts = x_dev = _put_matrix(X, mat_sharding)
                 else:
-                    # multi-slab and mesh: the cut sort's operand alone,
-                    # whole on the default device as jnp.asarray laid it
-                    # (same rows, same order: the cuts are the same
-                    # bytes); the binning streams its own slabs below
+                    # one chip and several slabs (or a mesh whose rows
+                    # cannot be placed chip by chip): the cut sort's
+                    # operand alone, whole on the default device as
+                    # jnp.asarray laid it (same rows, same order: the
+                    # cuts are the same bytes); the binning streams its
+                    # own slabs below
                     x_cuts = _put_matrix(X, None)
                 # the mode, which compute_cuts needs, from ONE scan of
                 # the matrix just put: the host waits here until it has
                 # landed, as the sort has to
                 with span("dmlc.ingest.cuts.nan_scan", bytes=X.nbytes,
                           on="device"):
-                    facts = self._nan_facts_device(x_cuts)
+                    facts = self._nan_facts_device(
+                        x_cuts, n, self.mesh if sharded else None)
                 missing_share = self._settle_missing_mode(*facts, cuts)
                 self.cuts = compute_cuts(
                     x_cuts, p.n_bins - 1 if self._missing else p.n_bins,
                     weight=weight,
                     allgather_fn=self._maybe_allgather(),
-                    missing=self._missing)
+                    missing=self._missing,
+                    mesh=self.mesh if sharded else None, n_rows=n)
                 del x_cuts
         sp.set(missing=int(self._missing), missing_share=missing_share,
-               nan_scan=scan)
+               nan_scan=scan,
+               cuts_sort=("none" if scan == "host" else
+                          "features" if sharded else "whole"))
         # cut width is the mode's load-bearing invariant: a mismatch
         # (e.g. standard-shaped cuts= injected into a missing-mode
         # model) would silently shift the reserved NaN bin out of the
@@ -1960,24 +2046,26 @@ class HistGBT(_ExternalMemoryEngine):
         if not pack_wanted:
             self._maybe_start_warmup(F, n_padded)
         with span("dmlc.ingest.pad"):
-            X, y, mask, n_pad = self._pad_rows(X, y, weight, staged)
+            # a resident matrix is padded where it lies
+            X, y, mask, n_pad = self._pad_rows(
+                X if x_dev is None else None, y, weight, staged)
 
         row_sharding = NamedSharding(self.mesh, P("data"))
         # DMLC_TPU_BIN_BACKEND=cpu (see _host_bin_requested) bins on the
         # host and uploads the uint8 result — 4× less transfer than
         # shipping f32 X to bin on device, at the price of host-side
         # searchsorted; opt-in, default (unset) is the device path.
-        if self._sharded_ingest_ok() and device_count(self.mesh) > 1:
-            # SHARDED ingest (the multi-chip staging path): each chip
+        if sharded and x_dev is None:
+            # SHARDED slab stream (cuts given or kept, a ranking
+            # handle's placed rows, binning on the host): each chip
             # receives — and, on the device-bin route, bins — exactly
-            # its own row slice, streamed slab by slab; the BINNED
+            # its own row slice, streamed slab by slab; the binned
             # matrix is never resident on a single device and its slabs
-            # never staged through a global put (the float32 matrix the
-            # cuts were computed from was, whole, on the default device:
-            # _put_matrix above).  Binning is per-element and the
-            # final layout is the same P(None, "data") block layout, so
-            # the result is bit-identical to both fallback paths
-            # (pinned by tests/test_multichip.py).
+            # never staged through a global put.  Binning is
+            # per-element and the final layout is the same
+            # P(None, "data") block layout, so the result is
+            # bit-identical to both fallback paths (pinned by
+            # tests/test_multichip.py).
             bins_t = self._ingest_slabs_sharded(
                 self._slab_stream(X), len(X), len(X), F)
         elif _host_bin_requested() or (self._missing
@@ -1999,7 +2087,10 @@ class HistGBT(_ExternalMemoryEngine):
             # every boosting round (a full HBM round-trip per round).
             # Large inputs stream through the chunked double-buffered
             # path so the full f32 matrix is never device-resident next
-            # to its uint8 bins (see _bin_ingest_streamed).
+            # to its uint8 bins (see _bin_ingest_streamed).  What the
+            # cut sort left resident (one chip's one slab; a mesh's row
+            # shards, padded) is binned where it lies: on a mesh each
+            # chip its own shard, no row moves.
             bins_t = self._bin_ingest_streamed(X, mat_sharding, x_dev)
         del x_dev
         layout = None
@@ -2031,6 +2122,16 @@ class HistGBT(_ExternalMemoryEngine):
                 lambda a, spec: jax.device_put(
                     a, NamedSharding(self.mesh, spec)),
                 staged.table, self._obj.table_specs())
+        if len(self.cuts.devices()) > 1:
+            # the cuts the mesh computed are committed to it, and so
+            # would be every array computed from them (a predict's bins
+            # against the model's trees).  The model keeps them where a
+            # one-device ingest leaves them — default device, uncommitted
+            # — which takes them through the host: LAST, with every
+            # staging call enqueued, the host waits here until the cuts
+            # exist (the binning still runs)
+            with span("dmlc.ingest.cuts_fetch"):
+                self.cuts = jnp.asarray(np.asarray(self.cuts))
         out = {
             "bins_t": bins_t,
             "y_d": y_d,
@@ -2064,15 +2165,23 @@ class HistGBT(_ExternalMemoryEngine):
         return has_nan, share, lambda: np.isfinite(X).any(axis=0)
 
     @staticmethod
-    def _nan_facts_device(x: jax.Array):
+    def _nan_facts_device(x: jax.Array, n_rows: Optional[int] = None,
+                          mesh: Optional[Mesh] = None):
         """The same three facts from ONE scan of ``x`` where it lies on
         the device (``ops.quantile.nan_scan``): two ``[F]`` vectors come
-        back.  The cells are summed in Python ints: a column's count
-        fits int32, the matrix's need not (40M x 28 is over half of
-        2**31)."""
-        nan_count, finite_any = jax.device_get(nan_scan(x))
+        back.  With ``mesh``, ``x`` lies there in row shards, its first
+        ``n_rows`` rows the data: every chip scans its own and one
+        ``psum`` adds up (``mesh_nan_scan``).  The cells are summed in
+        Python ints: a column's count fits int32, the matrix's need not
+        (40M x 28 is over half of 2**31)."""
+        if mesh is None:
+            n_rows, scan = x.shape[0], nan_scan(x)
+        else:
+            scan = mesh_nan_scan(x, n_rows, mesh)
+        nan_count, finite_any = jax.device_get(scan)
         nan_cells = sum(map(int, nan_count))
-        return nan_cells > 0, nan_cells / max(x.size, 1), lambda: finite_any
+        return (nan_cells > 0, nan_cells / max(n_rows * x.shape[1], 1),
+                lambda: finite_any)
 
     def _settle_missing_mode(self, has_nan: bool, share: float, finite_any,
                              cuts) -> float:

@@ -43,18 +43,21 @@ traced under ``jax.named_scope("dmlc.cuts")``, digitizing under
 
 from __future__ import annotations
 
-from functools import partial
+from functools import lru_cache, partial
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
+from jax.sharding import Mesh, PartitionSpec as P
 
 from dmlc_core_tpu.base.logging import CHECK
 from dmlc_core_tpu.base.parameter import get_env
 
-__all__ = ["local_summary", "merge_summaries", "compute_cuts", "apply_bins",
-           "apply_bins_t", "apply_bins_missing", "SketchAccumulator", "nan_scan"]
+__all__ = ["local_summary", "mesh_summary", "merge_summaries", "compute_cuts",
+           "apply_bins", "apply_bins_t", "apply_bins_missing",
+           "SketchAccumulator", "nan_scan", "mesh_nan_scan"]
 
 
 def _key_sort_quantiles(x: jax.Array, qs: jax.Array) -> jax.Array:
@@ -230,6 +233,59 @@ _page_summary = _scoped(local_summary, "dmlc.sketch.add", (2, 3))
 local_summary = _scoped(local_summary, "dmlc.cuts", (2, 3))
 
 
+@lru_cache(maxsize=32)
+def _mesh_summary_fn(mesh: Mesh, n_rows: int, n_summary: int, missing: bool,
+                     weighted: bool):
+    """The program behind :func:`mesh_summary`: one per mesh, row count
+    and mode."""
+    ndev = int(mesh.shape["data"])
+
+    def per_chip(x, *weight):                  # x: this chip's rows [S, F]
+        F = x.shape[1]
+        x = jnp.pad(x, ((0, 0), (0, -F % ndev)))
+        cols = jax.lax.all_to_all(x, "data", split_axis=1, concat_axis=0,
+                                  tiled=True)  # [n_padded, F_padded / ndev]
+        s = local_summary(cols[:n_rows], weight[0] if weighted else None,
+                          n_summary, missing)
+        return jax.lax.all_gather(s, "data", axis=0, tiled=True)[:F]
+
+    return jax.jit(jax.named_scope("dmlc.cuts")(shard_map(
+        per_chip, mesh=mesh,
+        in_specs=(P("data", None),) + ((P(),) if weighted else ()),
+        out_specs=P(), check_vma=False)))
+
+
+def mesh_summary(x: jax.Array, weight: Optional[jax.Array], n_rows: int,
+                 n_summary: int, missing: bool, mesh: Mesh) -> jax.Array:
+    """:func:`local_summary` of the first ``n_rows`` rows of ``x``
+    ``[n_padded, F]``, which lies in equal ROW shards over ``mesh``'s
+    ``data`` axis (its only axis of more than one device; the pad rows
+    are the tail) — computed BY FEATURE COLUMNS, every chip sorting
+    ``F / ndev`` columns of ALL rows, and equal to :func:`local_summary`
+    of ``x[:n_rows]`` on one device to the bit.
+
+    A column's summary depends on no other column (all three bodies of
+    :func:`local_summary` are per-column; ``weight`` is replicated), so
+    the sort is split by columns and not by rows: a row shard's own
+    quantiles scatter around the column's, and a merge of four such
+    summaries is an approximate answer where this one is exact
+    (PERF.md section 6, PR 52).  ONE ``shard_map`` program, device scope
+    ``dmlc.cuts``: the columns padded with zeros to a multiple of the
+    device count, ONE ``all_to_all`` that turns the ``[S, F]`` row
+    shards into ``[n_padded, F / ndev]`` column shards in global row
+    order, the pad rows sliced off (static: they are the tail, so they
+    never reach the sort), the per-column body, and an ``all_gather`` of
+    the ``[F / ndev, n_summary]`` results.  No chip ever holds the whole
+    matrix: a row shard and a column shard, ``2 / ndev`` of it.
+
+    Every chip ends with the whole ``[F, n_summary]`` summary: the
+    result is replicated over the mesh (and committed to it, as
+    everything computed from it is)."""
+    fn = _mesh_summary_fn(mesh, n_rows, n_summary, missing,
+                          weight is not None)
+    return fn(x) if weight is None else fn(x, weight)
+
+
 def merge_summaries(gathered: jax.Array, n_bins: int) -> jax.Array:
     """Merge ``[W, F, n_summary]`` worker summaries into ``[F, n_bins-1]``
     cut points (interior boundaries; bin b = count of cuts ≤ x).
@@ -272,8 +328,17 @@ def compute_cuts(
     n_summary: Optional[int] = None,
     allgather_fn=None,
     missing: bool = False,
+    mesh: Optional[Mesh] = None,
+    n_rows: Optional[int] = None,
 ) -> jax.Array:
     """End-to-end cut computation.
+
+    ``mesh`` (more than one device, one process): ``x`` is a device
+    array ``[n_padded, F]`` in row shards over the mesh, its first
+    ``n_rows`` rows the data, and the summary is computed by feature
+    columns across the chips (:func:`mesh_summary`) — the same cuts to
+    the bit, replicated over the mesh (without ``allgather_fn``, which
+    takes the summary through the host).
 
     ``allgather_fn(summary) -> [W, F, S]`` injects the distributed gather
     (e.g. ``collectives.allgather`` across processes, or an in-mesh
@@ -285,8 +350,11 @@ def compute_cuts(
     """
     CHECK(n_bins >= 2, "need at least 2 bins")
     n_summary = n_summary or max(8 * n_bins, 64)
-    summary = local_summary(jnp.asarray(x), None if weight is None else jnp.asarray(weight),
-                            n_summary, missing)
+    weight = None if weight is None else jnp.asarray(weight)
+    if mesh is None:
+        summary = local_summary(jnp.asarray(x), weight, n_summary, missing)
+    else:
+        summary = mesh_summary(x, weight, n_rows, n_summary, missing, mesh)
     if allgather_fn is not None:
         gathered = jnp.asarray(allgather_fn(np.asarray(summary)))
     else:
@@ -515,6 +583,13 @@ def apply_bins_missing(x: jax.Array, cuts: jax.Array,
     return apply_bins_t(x, cuts, miss_bin=miss_bin).T
 
 
+def _column_counts(x: jax.Array) -> tuple:
+    """Per column of ``x`` [n, F] the number of NaN and the number of
+    finite values, both int32 (a column has fewer than 2**31 rows)."""
+    return (jnp.sum(jnp.isnan(x), axis=0, dtype=jnp.int32),
+            jnp.sum(jnp.isfinite(x), axis=0, dtype=jnp.int32))
+
+
 @jax.jit
 @jax.named_scope("dmlc.cuts.nan_scan")
 def nan_scan(x: jax.Array) -> tuple:
@@ -532,7 +607,27 @@ def nan_scan(x: jax.Array) -> tuple:
     fusions, two reads, twice the time: PERF.md section 6, PR 50), and
     writes nothing of the matrix's size.  A device scope of its own
     (``dmlc.cuts.nan_scan``): ``dmlc.cuts`` stays the summary and the
-    merge.  (Defined last: the compile cache keys a program on its
-    source lines, and nothing above has moved.)"""
-    return (jnp.sum(jnp.isnan(x), axis=0, dtype=jnp.int32),
-            jnp.sum(jnp.isfinite(x), axis=0, dtype=jnp.int32) > 0)
+    merge."""
+    nan_count, finite_count = _column_counts(x)
+    return nan_count, finite_count > 0
+
+
+@lru_cache(maxsize=32)
+def _mesh_nan_scan_fn(mesh: Mesh, n_pad: int):
+    def per_chip(x):
+        nan_count, finite_count = jax.lax.psum(_column_counts(x), "data")
+        return nan_count, finite_count - n_pad > 0
+
+    return jax.jit(jax.named_scope("dmlc.cuts.nan_scan")(shard_map(
+        per_chip, mesh=mesh, in_specs=(P("data", None),), out_specs=P(),
+        check_vma=False)))
+
+
+def mesh_nan_scan(x: jax.Array, n_rows: int, mesh: Mesh) -> tuple:
+    """:func:`nan_scan` of the first ``n_rows`` rows of ``x``
+    ``[n_padded, F]`` lying in row shards over ``mesh`` (as
+    :func:`mesh_summary` takes it): every chip scans its own shard, ONE
+    ``psum`` adds the counts up.  The pad rows are ZEROS — never NaN,
+    always finite — so they come off the finite count and a column
+    whose data holds no finite value still reads so."""
+    return _mesh_nan_scan_fn(mesh, x.shape[0] - n_rows)(x)

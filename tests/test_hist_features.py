@@ -232,16 +232,26 @@ def test_fit_equals_fit_of_zero_padded_features(tmp_path):
 # -- the chip's compiler, without the chip ------------------------------
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
     return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    from jax.sharding import Mesh
+    return Mesh(np.asarray(topo.devices), ("data",))
 
 
 def _compiled_for_the_chip(fn, *shapes):
@@ -358,6 +368,35 @@ def test_the_cut_sort_carries_no_row_index(one_chip):
     # rows on the lanes, the 28 features padded to 32 sublanes
     matrix = n * 32 * 4
     assert compiled.memory_analysis().temp_size_in_bytes < 1.25 * matrix
+
+
+def test_a_mesh_sorts_the_cut_columns_between_its_chips(four_chips):
+    """ISSUE 52: on a mesh the cut sort is split by FEATURE COLUMNS —
+    one ``all-to-all`` turns a chip's ``[S, 28]`` row shard into 7
+    columns of ALL rows, which it sorts keys alone; no chip is given, or
+    makes, the whole matrix: the arguments are a row shard, the
+    temporaries a column shard and the sort's copy of it."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from dmlc_core_tpu.ops.quantile import _mesh_summary_fn
+
+    n, F, ndev = 4_000_000, 28, 4
+    compiled = _compiled_for_the_chip(
+        _mesh_summary_fn(four_chips, n - 3, 2048, False, False),
+        jax.ShapeDtypeStruct((n, F), jnp.float32, sharding=NamedSharding(
+            four_chips, P("data", None))))
+    lines = compiled.as_text().splitlines()
+    assert len([line for line in lines if " all-to-all(" in line]) == 1
+    assert [line for line in lines if " all-gather(" in line]
+    (sort,) = [line for line in lines if " sort(" in line]
+    result, operands = sort.split(" sort(", 1)
+    assert result.split("= ", 1)[1].startswith(f"f32[{n - 3},{F // ndev}]")
+    assert "," not in operands.split(")", 1)[0], sort
+    assert "dmlc.cuts" in sort
+    # rows on the lanes: 28 columns lie in 32 sublanes, 7 in 8
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes < 1.01 * n // ndev * 32 * 4
+    assert mem.temp_size_in_bytes < 2.1 * n * 8 * 4
+    assert mem.output_size_in_bytes < 1 << 19
 
 
 @pytest.mark.parametrize("n, F", [(1_183_747, 968), (40_000_000, 28)])

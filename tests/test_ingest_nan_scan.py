@@ -71,7 +71,8 @@ def host_source(monkeypatch):
     ingest did: the matrix is put all the same."""
     monkeypatch.setattr(
         HistGBT, "_nan_facts_device",
-        staticmethod(lambda x: HistGBT._nan_facts_host(np.asarray(x))))
+        staticmethod(lambda x, n_rows=None, mesh=None:
+                     HistGBT._nan_facts_host(np.asarray(x)[:n_rows])))
 
 
 # -- (a) the facts ------------------------------------------------------------

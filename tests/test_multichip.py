@@ -10,7 +10,10 @@ whole suite runs under (conftest):
   N-chip fit serializes byte-identically to the 1-chip oracle;
 * out-of-core streamed ingest (``make_device_data_iter``, tiny chunk
   slabs, DiskRowIter-backed) matches the in-core ensemble bit-exactly;
-* the histogram-psum traffic metric matches the analytic model.
+* the histogram-psum traffic metric matches the analytic model;
+* (ISSUE 52) a first ingest on a mesh sorts the cut columns BY FEATURES
+  over the chips — the cuts and the bin matrix are a one-device model's
+  to the byte, and no chip is ever given the whole matrix.
 """
 
 import os
@@ -26,13 +29,17 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from dmlc_core_tpu.data.iter import (RowBlockIter, iter_dense_slabs,  # noqa: E402
                                      slab_shard_slices)
+from dmlc_core_tpu.base.logging import Error  # noqa: E402
 from dmlc_core_tpu.models import HistGBT  # noqa: E402
+from dmlc_core_tpu.models import histgbt as G  # noqa: E402
 from dmlc_core_tpu.models.histgbt import _tree_fold  # noqa: E402
+from dmlc_core_tpu.ops import quantile as Q  # noqa: E402
 from dmlc_core_tpu.ops.histogram import hist_psum_bytes_per_round  # noqa: E402
 from dmlc_core_tpu.ops.quantile import compute_cuts  # noqa: E402
 from dmlc_core_tpu.parallel.mesh import (device_count, local_mesh,  # noqa: E402
                                          row_shard_layout,
                                          shard_row_ranges)
+from dmlc_core_tpu.utils import profiler  # noqa: E402
 
 KW = dict(n_trees=3, max_depth=3, n_bins=16, learning_rate=0.3)
 
@@ -208,6 +215,166 @@ class TestShardedIngestParity:
             m_gl = fit_one()
         m_sh = fit_one()
         assert _trees_equal(m_gl.trees, m_sh.trees)
+
+
+def _ingest_record(model, X, y, **kw):
+    """One ``make_device_data``: the handle and the operation's record."""
+    before = len(profiler.op_log())
+    handle = model.make_device_data(X, y, **kw)
+    if model._pending_warmup is not None:
+        model._pending_warmup.join()
+    (rec,) = [r for r in profiler.op_log()[before:]
+              if r["name"] == "dmlc.ingest"]
+    return handle, rec
+
+
+class TestCutSortByFeatures:
+    """ISSUE 52: on a mesh the rows go to the chips once, as row shards,
+    one ``all_to_all`` makes them column shards, every chip sorts
+    ``F / ndev`` columns of ALL rows: the same cuts to the bit."""
+
+    NDEV = 4
+
+    @pytest.mark.parametrize("n", [1001, 1000],
+                             ids=["padded_rows", "whole_shards"])
+    @pytest.mark.parametrize("F", [3, 5, 28])
+    @pytest.mark.parametrize("mode", ["standard", "nan_on_one_chip",
+                                      "row_weights"])
+    def test_cuts_and_bins_are_a_one_device_models_bytes(self, mode, F, n):
+        # F = 3: fewer columns than chips; 5: not a multiple of them;
+        # n = 1001: three pad rows close the last shard and must never
+        # reach the sort (they are zeros: they would move every quantile)
+        X, y = _make_xy(n, F=F, seed=F)
+        X[::7, 0] = 0.0                       # ties, and both zeros
+        X[::5, 1] = -0.0
+        weight = None
+        if mode == "nan_on_one_chip":
+            # chip 2's rows alone hold NaN: the mode is entered for all
+            # of them, and the reserved bin is the NaN's
+            S = -(-n // self.NDEV)
+            hole = np.random.default_rng(1).random((S, F)) < 0.3
+            X[2 * S:3 * S][hole] = np.nan
+        elif mode == "row_weights":
+            weight = np.random.default_rng(2).uniform(
+                0.5, 2.0, size=n).astype(np.float32)
+        m1 = HistGBT(mesh=local_mesh(1), **KW)
+        h1, rec1 = _ingest_record(m1, X, y, weight=weight)
+        m4 = HistGBT(mesh=local_mesh(self.NDEV), **KW)
+        h4, rec4 = _ingest_record(m4, X, y, weight=weight)
+
+        assert (rec1["counts"]["cuts_sort"], rec4["counts"]["cuts_sort"]) == (
+            "whole", "features")
+        assert rec4["counts"]["nan_scan"] == "device"
+        assert m4._missing is m1._missing is (mode == "nan_on_one_chip")
+        assert rec4["counts"]["missing_share"] == rec1["counts"][
+            "missing_share"]
+        c1, c4 = np.asarray(m1.cuts), np.asarray(m4.cuts)
+        assert c1.dtype == c4.dtype and c1.shape == c4.shape
+        assert c1.tobytes() == c4.tobytes()
+        # the cuts a model keeps are not tied to the mesh, nor is what
+        # is computed from them (a predict's bins): one device, uncommitted
+        assert m4.cuts.devices() == {jax.devices()[0]}
+        assert not m4.cuts.committed
+
+        b1, b4 = np.asarray(h1["bins_t"]), np.asarray(h4["bins_t"])
+        assert h4["n_padded"] == n + (-n) % self.NDEV == b4.shape[1]
+        assert b1.dtype == b4.dtype
+        assert b1.tobytes() == b4[:, :n].tobytes()
+        if mode == "nan_on_one_chip":
+            assert (b4[:, :n] == m4._miss_bin()).sum() == np.isnan(X).sum()
+        # the pad rows are zero rows, binned like any other
+        zero_bins = np.asarray(m1._bin_matrix(np.zeros((1, F), np.float32)))
+        assert np.array_equal(b4[:, n:], np.repeat(zero_bins.T, b4.shape[1] - n,
+                                                   axis=1))
+        assert np.array_equal(np.asarray(h4["w_d"])[n:], np.zeros(b4.shape[1] - n))
+
+        # a second handle from the kept cuts (the slab stream, a copy of
+        # the cuts a chip) is the same matrix
+        h4b, rec4b = _ingest_record(m4, X, y, weight=weight)
+        assert rec4b["counts"]["cuts_sort"] == "none"
+        assert np.array_equal(np.asarray(h4b["bins_t"]), b4)
+
+    def test_no_chip_is_given_the_whole_matrix(self, monkeypatch):
+        n, F, ndev = 1001, 28, self.NDEV
+        X, y = _make_xy(n, F=F)
+        S = -(-n // ndev)
+        row_shard = S * F * 4
+        col_shard = ndev * S * (-(-F // ndev)) * 4
+        puts = []
+        real_put = G._put_matrix
+
+        def spy(M, sharding):
+            puts.append((M.nbytes, sharding))
+            return real_put(M, sharding)
+
+        monkeypatch.setattr(G, "_put_matrix", spy)
+        monkeypatch.setenv("DMLC_INGEST_CHUNK_ROWS", "100")
+        m4 = HistGBT(mesh=local_mesh(ndev), **KW)
+        seen = []
+        real_cuts = G.compute_cuts
+
+        def cuts_spy(x, *a, **kw):
+            seen.extend(sh.data.shape for sh in x.addressable_shards)
+            return real_cuts(x, *a, **kw)
+
+        monkeypatch.setattr(G, "compute_cuts", cuts_spy)
+        h4, rec = _ingest_record(m4, X, y)
+        # the whole-matrix put is not taken: every chip is put its row
+        # shard, in pieces of at most a slab's rows, every row once (the
+        # last shard's three pad rows beside them)
+        assert puts == []
+        n_puts, _s, _l, put_bytes = rec["children"]["dmlc.ingest.put"]
+        assert n_puts == ndev * -(-S // 100)
+        assert put_bytes == ndev * row_shard == (n + 3) * F * 4
+        assert rec["children"]["dmlc.ingest.put_wait"][0::3] == [
+            n_puts, put_bytes]
+        assert seen == [(S, F)] * ndev
+        # nothing the cut program makes on a chip is larger than a row
+        # shard plus a column shard (per-chip shapes: inside shard_map)
+        fn = Q._mesh_summary_fn(m4.mesh, n, max(8 * KW["n_bins"], 64),
+                                False, False)
+        sizes = []
+
+        def walk(jaxpr):
+            for eqn in jaxpr.eqns:
+                sizes.extend(v.aval.size * v.aval.dtype.itemsize
+                             for v in eqn.outvars)
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+
+        jaxpr = jax.make_jaxpr(fn)(jax.ShapeDtypeStruct(
+            (ndev * S, F), np.float32)).jaxpr
+        (mapped,) = jaxpr.eqns                 # the shard_map, alone
+        for sub in jax.core.jaxprs_in_params(mapped.params):
+            walk(sub)
+        assert col_shard in sizes              # the sort's operand
+        assert max(sizes) <= row_shard + col_shard, max(sizes)
+        # what stays on a chip afterwards: its slice of the bin matrix
+        assert max(sh.data.nbytes for sh in h4["bins_t"].addressable_shards
+                   ) == S * F
+        # one device, several slabs: the whole matrix, no sharding, as before
+        m1 = HistGBT(mesh=local_mesh(1), **KW)
+        m1.make_device_data(X, y)
+        assert puts == [(X.nbytes, None)]
+
+    @pytest.mark.parametrize("case", ["all_nan_column", "two_bins"])
+    def test_the_missing_mode_errors_are_raised_as_before(self, case):
+        # 1001 rows: the zero pad rows of the last shard are finite in
+        # every column and must not hide a column without a value
+        X, y = _make_xy(1001, F=5)
+        X[::3, 0] = np.nan
+        kw = dict(KW)
+        if case == "all_nan_column":
+            X[:, 3] = np.nan
+            X[::2, 3] = np.inf
+            message = "a feature is all-NaN: drop it or impute"
+        else:
+            kw["n_bins"] = 2
+            message = "NaN features need n_bins >= 3"
+        m4 = HistGBT(mesh=local_mesh(self.NDEV), **kw)
+        with pytest.raises(Error, match=message):
+            m4.make_device_data(X, y)
+        assert m4.cuts is None and not m4._missing
 
 
 class TestOneChipOracle:

@@ -38,12 +38,21 @@ OPERATIONS = {
                 "dmlc.ingest.cuts", "dmlc.ingest.pad", "dmlc.ingest.labels"],
                ["dmlc.ingest.put", "dmlc.ingest.put_wait",
                 "dmlc.ingest.bin_dispatch", "dmlc.ingest.concat"]),
+    # a mesh's first ingest: a row shard a chip put in ``.cuts``, sorted
+    # by columns and binned where it lies (one dispatch, no slab stream)
     "ingest_sharded": ("dmlc.ingest",
                        ["dmlc.ingest.stream", "dmlc.ingest.host_prep",
                         "dmlc.ingest.cuts.nan_scan",
                         "dmlc.ingest.cuts", "dmlc.ingest.pad",
-                        "dmlc.ingest.labels"],
+                        "dmlc.ingest.labels", "dmlc.ingest.cuts_fetch"],
                        ["dmlc.ingest.put", "dmlc.ingest.bin_dispatch"]),
+    # the same model's next handle keeps its cuts: the slab stream, each
+    # piece put to and binned on the chip that owns it
+    "ingest_sharded_again": ("dmlc.ingest",
+                             ["dmlc.ingest.stream", "dmlc.ingest.host_prep",
+                              "dmlc.ingest.host_prep.nan_scan",
+                              "dmlc.ingest.pad", "dmlc.ingest.labels"],
+                             ["dmlc.ingest.put", "dmlc.ingest.bin_dispatch"]),
     "fit": ("dmlc.fit",
             ["dmlc.fit.join_warmup", "dmlc.fit.warm_dispatch",
              "dmlc.fit.dispatch", "dmlc.fit.fetch_chunk", "dmlc.fit.sync"],
@@ -56,9 +65,14 @@ OPERATIONS = {
 # operation -> the counts its record keeps
 RECORD_COUNTS = {
     "ingest": {"rows": 3000, "features": 5, "missing": 0,
-               "missing_share": 0.0, "nan_scan": "device"},
+               "missing_share": 0.0, "nan_scan": "device",
+               "cuts_sort": "whole"},
     "ingest_sharded": {"rows": 3000, "features": 5, "missing": 0,
-                       "missing_share": 0.0, "nan_scan": "device"},
+                       "missing_share": 0.0, "nan_scan": "device",
+                       "cuts_sort": "features"},
+    "ingest_sharded_again": {"rows": 3000, "features": 5, "missing": 0,
+                             "missing_share": 0.0, "nan_scan": "host",
+                             "cuts_sort": "none"},
     "fit": {"rounds": 4, "mesh_devices": 1},
     "predict": {"rows": 100, "programs": 1},
 }
@@ -115,6 +129,7 @@ def traced_and_logged(tmp_path_factory):
     ops = {
         "ingest": lambda: handle.update(ingest(one)),
         "ingest_sharded": lambda: ingest(many),
+        "ingest_sharded_again": lambda: ingest(many),
         "fit": lambda: one.fit_device(handle),
         "predict": lambda: one.predict(X[:100]),
     }
@@ -159,24 +174,34 @@ def test_host_spans_of_one_operation(traced, operation):
     if grandchildren:
         (mid,) = [e for e in events if e[0] == children[0]]
         below_mid = [e for e in events if e[0] in grandchildren]
-        if operation.startswith("ingest"):
+        if operation in ("ingest", "ingest_sharded"):
             # the matrix the cuts are computed from is put too, in
-            # ``.cuts`` and before any slab of the stream (PR 43:
-            # multi-slab and mesh paths go through ``_put_matrix``)
+            # ``.cuts`` and before any slab of the stream (PR 43: every
+            # path goes through ``_put_matrix``): whole on one chip, a
+            # row shard a chip on a mesh, which no stream puts again
             (cuts,) = [e for e in events if e[0] == "dmlc.ingest.cuts"]
             cut_puts = [e for e in below_mid
                         if cuts[1] <= e[1] and e[2] <= cuts[2]]
-            assert [e[0] for e in cut_puts] == ["dmlc.ingest.put"]
-            assert cut_puts[0][3]["bytes"] == cuts[3]["bytes"] == 3000 * 5 * 4
-            below_mid.remove(cut_puts[0])
+            ndev = 1 if operation == "ingest" else len(jax.devices())
+            assert [e[0] for e in cut_puts] == ["dmlc.ingest.put"] * ndev
+            assert (sum(e[3]["bytes"] for e in cut_puts)
+                    == cuts[3]["bytes"] == 3000 * 5 * 4)
+            for e in cut_puts:
+                below_mid.remove(e)
+            assert bool(below_mid) and (operation == "ingest") == any(
+                e[0] == "dmlc.ingest.put" for e in below_mid)
         assert all(mid[1] <= e[1] and e[2] <= mid[2] for e in below_mid)
     if operation.startswith("ingest"):
         assert root[3]["rows"] == 3000 and root[3]["features"] == 5
         (stream,) = [e for e in events if e[0] == "dmlc.ingest.stream"]
-        assert stream[3]["slabs"] == 3
-        (comp,) = [e for e in events if e[0] == "dmlc.compile"]
-        assert comp[3]["program"] == "kfn"
-        assert comp[3]["cache"] in ("hit", "miss")
+        assert stream[3]["slabs"] == (1 if operation == "ingest_sharded"
+                                      else 3)
+        # (a model's next handle finds the round program's compile
+        # already started: no worker, no span)
+        comps = [e for e in events if e[0] == "dmlc.compile"]
+        assert len(comps) == (0 if operation == "ingest_sharded_again" else 1)
+        assert all(c[3]["program"] == "kfn"
+                   and c[3]["cache"] in ("hit", "miss") for c in comps)
 
 
 @pytest.mark.parametrize("operation", list(OPERATIONS))
@@ -207,7 +232,11 @@ def test_one_record_of_one_operation(traced_and_logged, operation):
         # a wait carries the bytes of the slab it waits for
         assert (rec["children"]["dmlc.ingest.put_wait"][3]
                 == rec["children"]["dmlc.ingest.put"][3] - 3000 * 5 * 4 > 0)
-    if operation.startswith("ingest"):
+    if operation == "ingest_sharded":
+        # a row shard a chip, every row put once
+        assert rec["children"]["dmlc.ingest.put"][0::3] == [
+            len(jax.devices()), 3000 * 5 * 4]
+    if operation in ("ingest", "ingest_sharded"):
         # the worker's compile carries the ingest's ``op``: folded into
         # its record if it ended first, else a record of its own
         own = [r for r in log if r["name"] == "dmlc.compile"]
@@ -333,16 +362,20 @@ def test_sharded_ingest_spans_name_their_chip(traced):
     def chips(operation, name):
         return [e[3].get("chip") for e in traced[operation] if e[0] == name]
 
-    cut_put, *puts = chips("ingest_sharded", "dmlc.ingest.put")
-    # the matrix the cuts read goes whole to the default device, which
-    # is no position on the ``data`` axis
-    assert cut_put is None
     ndev = len(jax.devices())
-    # 3000 rows in 1000-row slabs over 375-row shards: every chip owns
-    # a piece, a slab is cut where it straddles a boundary
+    # a first ingest puts every chip its row shard, paced, and ONE
+    # program over the mesh bins them where they lie
+    assert chips("ingest_sharded", "dmlc.ingest.put") == list(range(ndev))
+    assert chips("ingest_sharded", "dmlc.ingest.put_wait") == list(
+        range(ndev))
+    assert chips("ingest_sharded", "dmlc.ingest.bin_dispatch") == [None]
+    # the slab stream (the cuts kept): 3000 rows in 1000-row slabs over
+    # 375-row shards: every chip owns a piece, a slab is cut where it
+    # straddles a boundary
+    puts = chips("ingest_sharded_again", "dmlc.ingest.put")
     assert set(puts) == set(range(ndev)) and len(puts) > ndev
     assert puts == sorted(puts)                    # global row order
-    assert chips("ingest_sharded", "dmlc.ingest.bin_dispatch") == puts
+    assert chips("ingest_sharded_again", "dmlc.ingest.bin_dispatch") == puts
     assert set(chips("ingest", "dmlc.ingest.put")) == {None}
     assert set(chips("ingest", "dmlc.ingest.bin_dispatch")) == {None}
 
