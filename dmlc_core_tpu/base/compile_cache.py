@@ -36,6 +36,14 @@ local counters that :func:`stats` reports even with metrics disabled —
 ``bench.py`` stamps its final JSON with ``compile_cache: hit|miss``
 from exactly this.
 
+The same listeners keep the compile LEDGER: jax reports, on the
+compiling thread and with the program's name, how long it traced,
+lowered and compiled (or read back) each program, and every program
+becomes one entry of the ``programs`` of the operation open on that
+thread (:func:`dmlc_core_tpu.utils.profiler.fold_program`;
+``doc/observability.md`` says which event feeds which field).  What
+compiles outside any operation is summed in ``stats()["unowned"]``.
+
 :class:`BackgroundCompiler` is the shared cold-start overlap helper:
 it runs AOT ``lower(...).compile()`` thunks concurrently on
 :class:`~dmlc_core_tpu.io.thread_group.ThreadGroup` workers so compiles
@@ -56,7 +64,7 @@ from dmlc_core_tpu.base import metrics as _metrics
 from dmlc_core_tpu.base.logging import LOG
 from dmlc_core_tpu.base.parameter import get_env
 from dmlc_core_tpu.base.timer import get_time
-from dmlc_core_tpu.utils.profiler import current_op, span
+from dmlc_core_tpu.utils.profiler import current_op, fold_program, span
 
 __all__ = [
     "BackgroundCompiler", "cache_dir", "compile_cache_metrics",
@@ -74,6 +82,42 @@ _lock = threading.Lock()
 #: — stats() is evidence for bench records, not optional telemetry)
 _counts = {"hits": 0, "misses": 0, "saved_seconds": 0.0}
 _listeners_registered = False
+#: the ledger's process tallies: ``unowned`` = entries (and their
+#: seconds) that closed with no operation open on their thread;
+#: ``nested_traces`` = traces jax reported inside another phase of the
+#: same thread, whose seconds that phase already holds
+_tallies = {"unowned": {"n": 0, "seconds": 0.0},
+            "nested_traces": {"n": 0, "seconds": 0.0}}
+#: jax's event -> the field of a ledger entry it feeds
+_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "backend_s",
+}
+_CACHE_READ = "/jax/compilation_cache/cache_retrieval_time_sec"
+#: traces a thread may keep unclaimed (a trace of that many sibling
+#: calls): past it the oldest half are folded as they are
+_LOOSE_TRACES = 16_384
+
+
+class _ThreadLedger(threading.local):
+    """What this thread's compiles have reported and no entry holds yet."""
+
+    def __init__(self) -> None:
+        #: ``(name, start, seconds)`` of the traces no lowering has
+        #: claimed and no later phase has enclosed, in order of closing
+        self.loose: list = []
+        #: the entry (and whether a record took it) of the program this
+        #: thread lowered last, until its backend phase completes it
+        self.lowered: Optional[Tuple[Dict[str, Any], bool]] = None
+        #: all this thread ever reported (``_thread_mark`` /
+        #: ``_thread_since``), and as its last backend phase left it
+        self.totals = {"trace_s": 0.0, "lower_s": 0.0, "backend_s": 0.0,
+                       "read_s": 0.0, "hit": 0, "miss": 0}
+        self.closed = dict(self.totals)
+
+
+_ledger = _ThreadLedger()
 
 _M: Dict[str, Any] = {}
 
@@ -108,17 +152,115 @@ def _on_event(event: str, **kw: Any) -> None:
         return
     with _lock:
         _counts[name + ("s" if name == "hit" else "es")] += 1
+    _ledger.totals[name] += 1
     if _metrics.enabled():
         compile_cache_metrics()["events"].inc(1, event=name)
 
 
 def _on_duration(event: str, duration_secs: float, **kw: Any) -> None:
+    if event == _CACHE_READ:
+        _ledger.totals["read_s"] += duration_secs
+        return
     if event != "/jax/compilation_cache/compile_time_saved_sec":
         return
     with _lock:
         _counts["saved_seconds"] += max(duration_secs, 0.0)
     if _metrics.enabled():
         compile_cache_metrics()["saved"].inc(max(duration_secs, 0.0))
+
+
+def _fold(program: str, verdict: str = "none",
+          opened: Optional[Tuple[Dict[str, Any], bool]] = None,
+          **seconds: float) -> Tuple[Dict[str, Any], bool]:
+    """One entry, or the phases that complete ``opened``, into the record
+    of the operation open on this thread, else into ``unowned``."""
+    entry = fold_program(program, verdict,
+                         opened[0] if opened and opened[1] else None,
+                         **seconds)
+    if entry is not None:
+        return entry, True
+    # a program lowered and then compiled outside any operation is one
+    completes_unowned = opened is not None and not opened[1]
+    with _lock:
+        _tallies["unowned"]["n"] += 0 if completes_unowned else 1
+        _tallies["unowned"]["seconds"] += sum(
+            v for k, v in seconds.items() if k != "read_s")
+    return {"program": program}, False
+
+
+def _on_time_span(event: str, start_time: float, end_time: float,
+                  fun_name: str = "", **kw: Any) -> None:
+    """A phase of a compile has ended on this thread.  An entry opens at
+    the program's lowering, with the trace that went before it, and is
+    complete at its backend phase; a phase with no other around it (a
+    compile of an earlier lowering, a trace nothing lowers) is an entry
+    of what it has.  Seconds are counted once: the spans of one thread
+    nest or follow each other, so the traces that began after this span
+    did are the tail of ``loose``, and lie inside it."""
+    field = _PHASES.get(event)
+    if field is None:
+        return
+    tl = _ledger
+    seconds = max(end_time - start_time, 0.0)
+    loose = tl.loose
+    nested, inside = 0, 0.0
+    while loose and loose[-1][1] >= start_time:
+        nested += 1
+        inside += loose.pop()[2]
+    if nested:
+        tl.totals["trace_s"] -= inside
+        with _lock:
+            _tallies["nested_traces"]["n"] += nested
+            _tallies["nested_traces"]["seconds"] += inside
+    tl.totals[field] += seconds
+    if field == "trace_s":
+        loose.append((fun_name, start_time, seconds))
+        if len(loose) > _LOOSE_TRACES:
+            _fold_loose(loose, len(loose) // 2)
+        return
+    if field == "lower_s":
+        traced = 0.0
+        if loose and "<unknown>" in fun_name:
+            # a lowering jax has no name for takes its trace's
+            fun_name = fun_name.replace("<unknown>", loose[-1][0])
+        if loose and fun_name in (loose[-1][0], f"jit({loose[-1][0]})",
+                                  f"pmap({loose[-1][0]})"):
+            traced = loose.pop()[2]
+        _fold_loose(loose, len(loose))
+        tl.lowered = _fold(fun_name, trace_s=traced, lower_s=seconds)
+        return
+    _fold_loose(loose, len(loose))
+    opened, tl.lowered = tl.lowered, None
+    if opened and "<unknown>" in fun_name:
+        fun_name = opened[0]["program"]
+    if opened and opened[0]["program"] not in (fun_name, "(more)"):
+        opened = None
+    # the cache's events fire inside the backend phase they belong to
+    since = _thread_since(tl.closed)
+    tl.closed = dict(tl.totals)
+    _fold(fun_name, since["cache"], opened, backend_s=seconds,
+          read_s=since["read_s"])
+
+
+def _fold_loose(loose: list, n: int) -> None:
+    """The oldest ``n`` unclaimed traces become entries of their own."""
+    for name, _start, seconds in loose[:n]:
+        _fold(name, trace_s=seconds)
+    del loose[:n]
+
+
+def _thread_mark() -> Dict[str, float]:
+    """What this thread's compiles have reported so far; pair with
+    :func:`_thread_since`."""
+    return dict(_ledger.totals)
+
+
+def _thread_since(mark: Dict[str, float]) -> Dict[str, Any]:
+    """This thread's own compile seconds and cache verdict since
+    ``mark``, as a ``dmlc.compile`` span carries them."""
+    own = {k: v - mark[k] for k, v in _ledger.totals.items()}
+    hit, miss = own.pop("hit"), own.pop("miss")
+    return {**own, "cache": "miss" if miss else "hit" if hit else "none"}
 
 
 def _register_listeners() -> None:
@@ -134,6 +276,7 @@ def _register_listeners() -> None:
     from jax._src import monitoring
     monitoring.register_event_listener(_on_event)
     monitoring.register_event_duration_secs_listener(_on_duration)
+    monitoring.register_event_time_span_listener(_on_time_span)
 
 
 _register_listeners()
@@ -208,11 +351,17 @@ def _set_cache_options() -> None:
 
 
 def stats() -> Dict[str, Any]:
-    """Process-local cache evidence: enabled state, directory, and
-    hit/miss/saved-seconds counts since process start."""
+    """Process-local cache evidence: enabled state, directory,
+    hit/miss/saved-seconds counts since process start, and the compile
+    ledger's tallies — ``unowned = {n, seconds}``, the programs (and
+    their trace + lower + backend seconds) no operation's record holds,
+    and ``nested_traces = {n, seconds}``, the traces counted inside the
+    phase that enclosed them: the records' seconds plus ``unowned`` plus
+    ``nested_traces`` are every second jax reported."""
     with _lock:
         counts = dict(_counts)
-    return {"enabled": enabled(), "dir": cache_dir(), **counts}
+        tallies = {k: dict(v) for k, v in _tallies.items()}
+    return {"enabled": enabled(), "dir": cache_dir(), **counts, **tallies}
 
 
 def marker() -> Tuple[int, int]:
@@ -261,7 +410,7 @@ class BackgroundCompiler:
         self._what = what
         self._results: Dict[str, Any] = {}
         self._walls: Dict[str, float] = {}
-        self._mark = marker()
+        self._verdicts: Dict[str, str] = {}
         self._joined = False
         self.compile_seconds = 0.0
         self.join_wait_seconds = 0.0
@@ -276,15 +425,18 @@ class BackgroundCompiler:
     def _runner(self, name: str, thunk: Callable[[], Any]):
         def run(_shutdown) -> None:
             t0 = get_time()
-            mark = marker()
+            mark = _thread_mark()
             with span("dmlc.compile", op=self._op, what=self._what,
                       program=name) as sp:
                 try:
                     self._results[name] = thunk()
                 finally:
-                    # process-wide counts: two programs compiling at
-                    # once see each other's cache traffic
-                    sp.set(cache=verdict(mark) or "none")
+                    # this thread's own phases and cache traffic: the
+                    # programs compiling beside it have theirs
+                    own = _thread_since(mark)
+                    self._verdicts[name] = own.pop("cache")
+                    sp.set(cache=self._verdicts[name],
+                           **{k: round(v, 6) for k, v in own.items()})
                     self._walls[name] = get_time() - t0
                     if _metrics.enabled():
                         compile_cache_metrics()["compile"].observe(
@@ -301,5 +453,8 @@ class BackgroundCompiler:
             self._joined = True
             self.join_wait_seconds = get_time() - t0
             self.compile_seconds = max(self._walls.values(), default=0.0)
-            self.cache_verdict = verdict(self._mark)
+            # the worst of the workers' own verdicts
+            self.cache_verdict = next(
+                (v for v in ("miss", "hit")
+                 if v in self._verdicts.values()), None)
         return self._results

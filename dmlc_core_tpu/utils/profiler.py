@@ -52,8 +52,8 @@ from dmlc_core_tpu.base import metrics as _metrics
 from dmlc_core_tpu.base.timer import get_time
 
 __all__ = ["device_trace", "span", "phase", "count_in_op", "current_op",
-           "op_log", "op_log_dropped", "Tracer", "global_tracer",
-           "tracing_enabled", "set_tracing"]
+           "fold_program", "op_log", "op_log_dropped", "Tracer",
+           "global_tracer", "tracing_enabled", "set_tracing"]
 
 _TRACING = os.environ.get("DMLC_TRACE", "0").lower() in ("1", "true", "on",
                                                          "yes")
@@ -149,6 +149,12 @@ _log_lock = threading.Lock()
 _log_appended = 0
 #: a record's ``compile`` before any span with a ``cache`` verdict joined
 _NO_VERDICTS = {"hit": 0, "miss": 0, "seconds": 0.0}
+#: entries a record's ``programs`` keeps; what comes after them is summed
+#: into one more, named ``"(more)"``
+OP_LOG_PROGRAMS = 64
+#: the seconds of a ``programs`` entry (``read_s`` lies inside ``backend_s``)
+PROGRAM_SECONDS = ("trace_s", "lower_s", "backend_s", "read_s")
+_VERDICT_RANK = {"none": 0, "hit": 1, "miss": 2}
 
 
 def _new_record(op: int, name: str, start: float) -> Dict[str, Any]:
@@ -179,6 +185,68 @@ def _fold(children: Dict[str, List[Any]], name: str, n: int,
     child[3] += nbytes
 
 
+def fold_program(program: str, verdict: str, opened: Optional[Dict[str, Any]]
+                 = None, **seconds: float) -> Optional[Dict[str, Any]]:
+    """One program's compile phases into the record of the operation
+    open on this thread: ``base/compile_cache.py``'s listeners call this
+    when jax has traced, lowered, compiled or read back a program here,
+    and no span does.  ``seconds`` are some of :data:`PROGRAM_SECONDS`.
+    The entry is ``thread`` ``"own"`` on the thread that opened the
+    operation and ``"joined"`` on one that joined it by ``op=``, ``under``
+    the innermost ``dmlc.*`` span open here; where the joined operation
+    has closed, the entry waits for the record the joining span closes
+    as (:meth:`span._join`).  ``opened`` is the entry this thread got
+    back when the same program was lowered: while it still lies in the
+    record open here, the new phases complete it in place.  Past
+    :data:`OP_LOG_PROGRAMS` entries a record sums the rest into one
+    named ``"(more)"`` with their count ``n``, its ``thread`` and
+    ``under`` ``"mixed"`` unless all agree.  Returns the entry, or None
+    where no operation is open here (or the record is off): the caller
+    keeps those seconds."""
+    stack = getattr(_open, "spans", None)
+    if not stack:
+        return None
+    top = stack[0]
+    where = {"thread": "own", "under": next(
+        (s.name for s in reversed(stack) if s.name.startswith("dmlc.")),
+        stack[-1].name)}
+    rec = top._rec
+    with _log_lock:
+        if rec is not None:
+            programs = rec.setdefault("programs", [])
+        elif top._top or not _metrics.enabled():
+            return None              # opened while the record was off
+        else:
+            where["thread"] = "joined"
+            rec = _open_records.get(top.counts["op"])
+            programs = (rec.setdefault("programs", []) if rec is not None
+                        else _open.__dict__.setdefault("programs", []))
+        completes = (opened is not None
+                     and any(p is opened for p in programs))
+        if completes and opened["program"] != "(more)":
+            for key, value in seconds.items():
+                opened[key] += value
+            opened.update(where, verdict=verdict)
+            return opened
+        if not completes and len(programs) <= OP_LOG_PROGRAMS:
+            full = len(programs) == OP_LOG_PROGRAMS
+            programs.append({
+                "program": "(more)" if full else program,
+                **dict.fromkeys(PROGRAM_SECONDS, 0.0), **seconds,
+                "verdict": verdict, **where, **({"n": 1} if full else {})})
+            return programs[-1]
+        more = programs[-1]
+        more["n"] += not completes
+        for key, value in seconds.items():
+            more[key] += value
+        if _VERDICT_RANK[verdict] > _VERDICT_RANK[more["verdict"]]:
+            more["verdict"] = verdict
+        for key, value in where.items():
+            if more[key] != value:
+                more[key] = "mixed"
+        return more
+
+
 def op_log() -> List[Dict[str, Any]]:
     """The per-operation record of the spans, oldest first: one plain
     dict for every top-level :class:`span` that has closed — ``op``,
@@ -188,9 +256,12 @@ def op_log() -> List[Dict[str, Any]]:
     span below it on any thread that carried its ``op``; ``bytes`` sums
     that count where a span has one) and ``compile = {hit, miss,
     seconds}`` (the spans of other threads that joined it with a
-    ``cache`` verdict).  A span that joined by ``op=`` and closed after
-    its operation's top-level span is a record of its own under that
-    ``op``.  Kept whenever ``base.metrics.enabled()``, trace or no
+    ``cache`` verdict) and ``programs``: one entry, in the order they
+    were folded, for every program jax traced, lowered, compiled or read
+    back from the persistent cache on a thread that had the operation
+    open (:func:`fold_program`).  A span that joined by ``op=`` and
+    closed after its operation's top-level span is a record of its own
+    under that ``op``.  Kept whenever ``base.metrics.enabled()``, trace or no
     trace, in a ring of :data:`OP_LOG_RECORDS` (see
     :func:`op_log_dropped`); a trace's spans are joined to it by
     ``op``, not by time."""
@@ -200,7 +271,8 @@ def op_log() -> List[Dict[str, Any]]:
     return [{**r,
              "counts": {k: v for k, v in r["counts"].items() if k != "op"},
              "children": {k: list(v) for k, v in r["children"].items()},
-             "compile": dict(r["compile"] or _NO_VERDICTS)}
+             "compile": dict(r["compile"] or _NO_VERDICTS),
+             "programs": [dict(p) for p in r.get("programs", ())]}
             for r in records]
 
 
@@ -332,6 +404,11 @@ class span:
         rec = _new_record(self.counts["op"], self.name, self._t0)
         if cache is not None:
             _count_verdict(rec, cache, self.seconds)
+        # the programs this thread compiled once its operation had closed
+        # (``fold_program``): only an outliving worker comes this way
+        waiting = _open.__dict__.pop("programs", None)
+        if waiting:
+            rec["programs"] = waiting
         self._close(rec, end)
 
 
