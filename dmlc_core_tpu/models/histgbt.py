@@ -67,7 +67,8 @@ from dmlc_core_tpu.ops.histogram import (build_histogram,
 from dmlc_core_tpu.ops.quantile import (apply_bins, apply_bins_missing,
                                         apply_bins_t, compute_cuts,
                                         mesh_nan_scan, nan_scan)
-from dmlc_core_tpu.ops.table_select import table_select
+from dmlc_core_tpu.ops.table_select import (SplitWord, chain_select,
+                                            route_form, table_select)
 from dmlc_core_tpu.parallel.mesh import device_count, local_mesh
 from dmlc_core_tpu.models.gbt_objectives import (  # noqa: F401  (re-exports:
     # scripts/tests import these via models.histgbt — keep the names)
@@ -224,6 +225,13 @@ class _RoundPlan(NamedTuple):
     #: trees grow together and a build stacks as many classes a call as
     #: fit the MXU's 128 rows — the stacked kernel's engagement counter
     hist_class_blocks: Tuple[Tuple[int, ...], ...] = ()
+    #: how ``route`` reads each row's split at each level below the root
+    #: (``ops.table_select.route_form`` of the level's parents and the
+    #: rows a device; ``()`` for loss-guide growth, which has no levels)
+    route_forms: Tuple[str, ...] = ()
+    #: where a node's split sits in the ONE int32 the packed forms look
+    #: up; ``None`` where every level reads the unpacked tables
+    route_word: Optional[SplitWord] = None
 
     def describe(self) -> Dict[str, Any]:
         """The JSON-serialisable record left on ``HistGBT.round_plan``."""
@@ -243,6 +251,14 @@ class _RoundPlan(NamedTuple):
                                     self.hist_feature_blocks],
             "hist_node_blocks": [list(b) for b in self.hist_node_blocks],
             "hist_class_blocks": [list(b) for b in self.hist_class_blocks],
+            # route's engagement counter: entries a tree (a class counted
+            # once) looked up as a chain of selects, of ONE packed word
+            "route_lookups": {
+                "form": "chain" if "chain" in self.route_forms else "pieces",
+                "packed": self.route_word is not None,
+                "chained_entries": sum(
+                    1 << lv for lv, f in enumerate(self.route_forms)
+                    if f == "chain")},
             "hist_blocks": self.hist_blocks,
             "mesh_devices": self.mesh_devices,
             # a multi:* round grows one tree a class from margins held
@@ -457,7 +473,7 @@ class _RoundProgramWarmup:
         # the layout is part of the plan, hence of the cache key, so a
         # mismatch between what was warmed and what fit dispatches is
         # caught by key equality
-        plan = model._round_plan(n_features)
+        plan = model._round_plan(n_features, n_padded)
         lay = plan.layout
         mat_rows = lay.phys_rows if lay is not None else n_features
         args = [
@@ -1085,7 +1101,7 @@ class HistGBT(_ExternalMemoryEngine):
                 execs = warm.join()          # never leave workers behind
         # the ONE resolution of this fit's program choices: the key, the
         # build and the byte accounting below all read ``plan``
-        plan = self._round_plan(n_features)
+        plan = self._round_plan(n_features, int(bins_t.shape[1]))
         if warm is not None:
             if shardings_ok and warm.matches(
                     self, plan, int(bins_t.shape[1]), K, rem):
@@ -1386,7 +1402,8 @@ class HistGBT(_ExternalMemoryEngine):
             # compile work) — keep it; replace only on a real mismatch
             K, rem = _rounds_schedule(self.param.n_trees, eval_every)
             if self._pending_warmup.matches(
-                    self, self._round_plan(n_features), n_padded, K, rem):
+                    self, self._round_plan(n_features, n_padded), n_padded,
+                    K, rem):
                 return self._pending_warmup
         warm = _RoundProgramWarmup(self, n_features, n_padded, eval_every)
         self._pending_warmup = warm
@@ -2419,10 +2436,11 @@ class HistGBT(_ExternalMemoryEngine):
                 check_vma=False)))
         return kept[1](preds, rank)
 
-    def _round_plan(self, n_features: int) -> _RoundPlan:
+    def _round_plan(self, n_features: int, n_rows: int = 0) -> _RoundPlan:
         """Resolve every path choice of the round program from what can
         be observed before tracing — param, mesh, bin layout, knobs,
-        backend — ONCE, and leave its record on ``self.round_plan``.
+        backend, the (padded) rows it will be given — ONCE, and leave
+        its record on ``self.round_plan``.
         The one place a lever is read: ``_round_fn_cache_key``,
         ``_build_round_fn`` and ``_boost_rounds``' accounting take the
         returned :class:`_RoundPlan` and consult nothing else, so a
@@ -2440,7 +2458,11 @@ class HistGBT(_ExternalMemoryEngine):
         stack of ``ops.hist_class_blocks``; ``[1]`` otherwise).  Every
         level is the staged descend + build + subtract: the one-kernel
         level it was measured against lost on the chip and went with PR
-        47 (PERF.md section 6, PRs 45 and 47)."""
+        47 (PERF.md section 6, PRs 45 and 47).  ``route_forms`` is each
+        level's way to read a row's split: one packed word through a
+        chain of selects where the parents are few and a device's rows
+        many (``ops.table_select.route_form``; ``n_rows`` 0, a plan made
+        without rows, reads the unpacked tables of a small fit)."""
         p = self.param
         depth = p.max_depth
         layout = self._bin_layout
@@ -2452,6 +2474,11 @@ class HistGBT(_ExternalMemoryEngine):
         builds = [1] if lossguide else (
             [1] + [1 << (lv - 1) for lv in range(1, depth)])
         packed = layout is not None and bool(layout.pairs)
+        route = () if lossguide else tuple(
+            route_form(1 << (lv - 1), n_rows // dsize)
+            for lv in range(1, depth))
+        route_word = (None if all(f == "tables" for f in route) else
+                      SplitWord.of(n_features, p.n_bins, self._missing))
         methods = tuple(
             resolve_hist_method(p.hist_method, sync_bins, mat_rows, nb,
                                 whole=packed)
@@ -2484,7 +2511,8 @@ class HistGBT(_ExternalMemoryEngine):
                 hist_class_blocks(sync_bins, mat_rows, nb,
                                   _class_batches(p.num_class)[1],
                                   whole=packed)
-                for m, nb in zip(methods, builds)))
+                for m, nb in zip(methods, builds)),
+            route_forms=route, route_word=route_word)
         self.round_plan = plan.describe()
         return plan
 
@@ -2543,6 +2571,7 @@ class HistGBT(_ExternalMemoryEngine):
             if np.any(mc):
                 mono_arr = mc
         missing = plan.missing
+        split_word = plan.route_word
         if missing:
             CHECK(mono_arr is None,
                   "monotone_constraints with NaN features is not "
@@ -2778,11 +2807,22 @@ class HistGBT(_ExternalMemoryEngine):
                     hist = in_sync(hist_sync)(hist, n_blk)
                 else:
                     n_prev = n_nodes >> 1
-                    select = in_route(per_class(
-                        partial(table_select, n_entries=n_prev)))
-                    feat_sel = select(feat, node)                     # [n]
-                    thr_sel = select(thr, node)                       # [n]
-                    dir_sel = select(dirv, node) if missing else None
+                    form = plan.route_forms[level - 1]
+                    if form == "tables":
+                        select = in_route(per_class(
+                            partial(table_select, n_entries=n_prev)))
+                        feat_sel = select(feat, node)                 # [n]
+                        thr_sel = select(thr, node)                   # [n]
+                        dir_sel = select(dirv, node) if missing else None
+                    else:
+                        # ONE packed word a node, looked up once a row
+                        lookup = (chain_select if form == "chain"
+                                  else table_select)
+                        word = in_route(split_word.pack)(feat, thr, dirv)
+                        word_sel = in_route(per_class(partial(
+                            lookup, n_entries=n_prev)))(word, node)   # [n]
+                        feat_sel, thr_sel, dir_sel = in_route(
+                            split_word.unpack)(word_sel)
                     if n_blk:
                         lefts, nodes2 = [], []
                         for sl in rows:

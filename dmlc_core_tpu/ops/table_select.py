@@ -21,19 +21,67 @@ alike (``scripts/sweep_table_select.py``):
     entries         4     16    32    64    128    256    512
     one piece       1.31  1.56  0.90  1.33  21.12  19.49  21.09
     pieces of 64    1.30  1.57  0.89  1.32   3.21   6.28  12.04
+    chain           0.35  0.35  0.37  0.64   1.21   5.54   9.63
+
+The last row is :func:`chain_select`, a static chain of selects: one
+elementwise pass, no ``[n, N]`` intermediate, no reduce.  It is the
+fastest form alone and it is NOT the form of every lookup, for two
+reasons that each became a constant here.
+
+* What it is fused INTO.  The tail of ``grow_tree`` (the last descend's
+  lookups, then the leaf value's) takes a chain into one fusion with
+  every table entry a scalar operand of its own, 361 of them at depth 8,
+  and ``leaf`` reads 39 ms for 12.9 (PR 46).  ``route`` (the level
+  loop: each row's node's split at every level below the root) hands its
+  result straight to the descend over the ``[F, n]`` bins and the chain
+  costs what the table says: a level's two or three lookups 2.1-2.7 ms
+  as pieces, 0.15-0.27 as chains (ledger, PR 54: ``round.nonhist_ms``
+  20.44 -> 11.18 at depth 6, 34.65 -> 23.05 at depth 8, 24M rows).  So
+  only ``route`` chains.
+* What it costs to TRACE.  Every select of a chain is an equation of the
+  round program's jaxpr, traced and lowered again at every warm start
+  (the compile cache is keyed on the lowered module): about 11 ms an
+  entry on the benchmark's host (ledger, PR 54: 62 / 254 / 381 entries a
+  tree cost +1.69 / +3.95 / +5.30 s of ``setup.compile_trace_lower_s``).
+  The saving is per ROW, ~2.1 ms a level at 24M rows whatever the
+  level's size, and the cost is per ENTRY, doubling every level.  Hence
+  :data:`CHAIN_MAX_ENTRIES` and :data:`CHAIN_MIN_ROWS`, and hence ONE
+  table a level: ``route`` packs a node's feature, threshold and
+  direction into one int32 (:class:`SplitWord`), looks the word up once
+  and unpacks it per row with shifts and masks.
+
+:func:`route_form` picks a level's form from those two static shapes.
 """
 
 import functools
 import operator
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-__all__ = ["ROW_MAJOR_MAX", "table_select"]
+from dmlc_core_tpu.base.logging import CHECK
+
+__all__ = ["CHAIN_MAX_ENTRIES", "CHAIN_MIN_ROWS", "ROW_MAJOR_MAX",
+           "SplitWord", "chain_select", "route_form", "table_select"]
 
 #: entries a piece: the last size at which the compiler keeps the rows
 #: of one compare-and-sum on the lanes (the table above)
 ROW_MAJOR_MAX = 64
+
+#: the largest table ``route`` looks up as a chain.  A level's chain
+#: saves ~2.1 ms a round at 24M rows whatever its size and costs ~11 ms
+#: of warm trace + lower an entry, and the entries double every level:
+#: a depth-8 tree's L7 (64 entries) would be half of all its chained
+#: entries for 1.8 of 11.8 ms (ledger and traces, PR 54)
+CHAIN_MAX_ENTRIES = 32
+
+#: the fewest rows a device at which ``route`` packs and chains.  The
+#: saving is per row (24M rows: 9.3-11.6 ms of a 254-613 ms round;
+#: 1.18M rows: 1.8 ms of a 1021 ms round, for 5.3 s of set-up; 0.4M and
+#: 1.25M rows: nothing — ledger, PR 54) and the cost per entry: below
+#: 2^21 rows the level loop traces the unpacked lookups it always has
+CHAIN_MIN_ROWS = 1 << 21
 
 
 def _row_major(table, node, n_entries):
@@ -59,3 +107,70 @@ def table_select(table: jax.Array, node: jax.Array,
         _row_major(table[lo:lo + ROW_MAJOR_MAX], node - lo,
                    min(ROW_MAJOR_MAX, n_entries - lo))
         for lo in range(0, n_entries, ROW_MAJOR_MAX)))
+
+
+def chain_select(table: jax.Array, node: jax.Array,
+                 n_entries: int) -> jax.Array:
+    """:func:`table_select`'s answer for an INTEGER table, bit for bit,
+    as a static chain of selects: ``acc = select(node == k, table[k],
+    acc)`` for ``k`` in ``0..n_entries-1`` from an ``acc`` of zeros — one
+    elementwise pass that fuses into whatever consumes it.  Outside
+    ``0..n_entries-1`` no select fires and the zero stays.  Said in
+    ``lax``'s own words: every entry is traced again at every warm
+    start, and ``jnp.where`` and ``table[k]`` cost twice the tracing for
+    the same program (PR 54)."""
+    acc = jnp.zeros(node.shape, table.dtype)
+    for k in range(n_entries):
+        entry = jax.lax.index_in_dim(table, k, keepdims=False)
+        acc = jax.lax.select(node == k,
+                             jnp.broadcast_to(entry, node.shape), acc)
+    return acc
+
+
+def route_form(n_prev: int, rows_per_device: int) -> str:
+    """How ``route`` reads each row's split at a level whose parents
+    number ``n_prev``, from the two shapes the program can see:
+
+    * ``"tables"`` below :data:`CHAIN_MIN_ROWS` rows a device: feature,
+      threshold and direction each through :func:`table_select`;
+    * ``"chain"``: ONE :class:`SplitWord` a node through
+      :func:`chain_select`, up to :data:`CHAIN_MAX_ENTRIES` parents;
+    * ``"pieces"``: the same word through :func:`table_select` past
+      them (one compare-and-sum where there were two or three)."""
+    if rows_per_device < CHAIN_MIN_ROWS:
+        return "tables"
+    return "chain" if n_prev <= CHAIN_MAX_ENTRIES else "pieces"
+
+
+class SplitWord(NamedTuple):
+    """Where a node's split sits in one non-negative int32:
+    ``(feat << (thr_bits + dir_bits)) | (thr << dir_bits) | dir``."""
+    thr_bits: int
+    dir_bits: int
+
+    @classmethod
+    def of(cls, n_features: int, n_bins: int, missing: bool) -> "SplitWord":
+        """The layout for features ``0..n_features-1``, thresholds
+        ``0..n_bins-1`` and, under ``missing``, a direction bit."""
+        word = cls((n_bins - 1).bit_length(), int(missing))
+        bits = (n_features - 1).bit_length() + word.thr_bits + word.dir_bits
+        CHECK(bits <= 31,
+              f"a split of {n_features} features x {n_bins} bins"
+              f"{' and a direction' if missing else ''} takes {bits} bits:"
+              f" one int32 word holds 31")
+        return word
+
+    def pack(self, feat: jax.Array, thr: jax.Array,
+             dirv: Optional[jax.Array] = None) -> jax.Array:
+        word = ((feat << (self.thr_bits + self.dir_bits))
+                | (thr << self.dir_bits))
+        return word | dirv if self.dir_bits else word
+
+    def unpack(self, word: jax.Array
+               ) -> Tuple[jax.Array, jax.Array, Optional[jax.Array]]:
+        """``(feat, thr, dir)`` of a packed word, ``dir`` ``None`` where
+        the word has no direction bit; word 0 (a padding row's) is all
+        zeros."""
+        feat = word >> (self.thr_bits + self.dir_bits)
+        thr = (word >> self.dir_bits) & ((1 << self.thr_bits) - 1)
+        return feat, thr, (word & 1) if self.dir_bits else None

@@ -188,9 +188,12 @@ def test_round_plan_record_is_the_plans_json_view():
     assert set(m.round_plan) == {
         "hist_method", "missing", "pallas_interpret", "grow_policy",
         "bin_layout", "hist_features", "hist_feature_blocks",
-        "hist_node_blocks", "hist_class_blocks", "hist_blocks",
-        "mesh_devices",
+        "hist_node_blocks", "hist_class_blocks", "route_lookups",
+        "hist_blocks", "mesh_devices",
         "num_class", "trees_per_round", "margin_layout"}
+    # a plan made without rows is a small fit's: route's unpacked tables
+    assert m.round_plan["route_lookups"] == {
+        "form": "pieces", "packed": False, "chained_entries": 0}
     assert (m.round_plan["num_class"], m.round_plan["trees_per_round"],
             m.round_plan["margin_layout"]) == (1, 1, "[n]")
     assert m.round_plan["missing"] is False
