@@ -2021,11 +2021,15 @@ def main() -> None:
     # the depth-wise build budget (16 expansions vs 2^(depth-1)=32
     # builds at depth 6).  setdefault so an operator can still A/B any
     # lever off (DMLC_BIN_PACK=0 etc.); the exact setting ships in the
-    # record's config.levers block either way.
+    # record's config.levers block either way.  The growth policy and
+    # the leaf budget are hyperparameters of the model
+    # (``HistGBTParam.grow_policy`` / ``max_leaves``): BENCH_GROW_POLICY
+    # and BENCH_MAX_LEAVES are this script's way to say them.
     os.environ.setdefault("DMLC_BIN_PACK", "1")
     os.environ.setdefault("DMLC_FEATURE_BUNDLE", "1")
-    os.environ.setdefault("DMLC_GROW_POLICY", "lossguide")
-    os.environ.setdefault("DMLC_MAX_LEAVES", str(max(1 << (depth - 2), 4)))
+    grow_policy = os.environ.get("BENCH_GROW_POLICY", "lossguide")
+    max_leaves = int(os.environ.get("BENCH_MAX_LEAVES",
+                                    max(1 << (depth - 2), 4)))
 
     if os.environ.get("BENCH_FORCE_CPU"):
         # self-test hook: N virtual CPU devices, asked for explicitly
@@ -2067,9 +2071,8 @@ def main() -> None:
                         "bin_pack": os.environ["DMLC_BIN_PACK"] == "1",
                         "feature_bundle":
                             os.environ["DMLC_FEATURE_BUNDLE"] == "1",
-                        "grow_policy": os.environ["DMLC_GROW_POLICY"],
-                        "max_leaves":
-                            int(os.environ["DMLC_MAX_LEAVES"] or 0),
+                        "grow_policy": grow_policy,
+                        "max_leaves": max_leaves,
                     }}
 
     # chips=N mode (ISSUE 7): BENCH_CHIPS pins the data-mesh width (0 /
@@ -2091,6 +2094,8 @@ def main() -> None:
         max_depth=depth,
         n_bins=n_bins,
         learning_rate=0.1,
+        grow_policy=grow_policy,
+        max_leaves=max_leaves,
         mesh=mesh,
     )
     # cold-start overlap, bench half: the round-program compile (or its
@@ -2237,7 +2242,7 @@ def main() -> None:
         1.0 / (value * n_chips), peak, n_chips,
         layout=model._bin_layout,
         grow_policy=model.round_plan["grow_policy"],
-        max_leaves=int(os.environ.get("DMLC_MAX_LEAVES", "0") or 0)))
+        max_leaves=model.round_plan.get("max_leaves", 0)))
     official["round_plan"] = model.round_plan
     EV["official"] = official
     EV["runs"] = runs
@@ -2275,6 +2280,8 @@ def main() -> None:
                 yb = (mb > 0).astype(np.float32)
                 model1 = HistGBT(n_trees=rounds, max_depth=depth,
                                  n_bins=n_bins, learning_rate=0.1,
+                                 grow_policy=grow_policy,
+                                 max_leaves=max_leaves,
                                  mesh=local_mesh(1))
                 dd1 = model1.make_device_data(
                     Xb, yb, cuts=np.asarray(model.cuts))

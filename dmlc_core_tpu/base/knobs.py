@@ -179,16 +179,6 @@ declare("DMLC_HIST_BLOCKS", 0,
         "reduction with N fixed row blocks (rounded up to a power of "
         "two >= the data-axis size): trees become bit-identical across "
         "mesh shapes; 0 keeps the faster plain psum.", "gbt")
-declare("DMLC_GROW_POLICY", "depthwise",
-        "'lossguide' grows each tree leaf-wise: a gain-priority queue "
-        "expands the best open leaf, building ONE histogram per "
-        "expansion (sibling subtraction covers the other child) instead "
-        "of a whole level at a time; tree structure is identical to "
-        "depthwise when the leaf budget is unlimited.", "gbt")
-declare("DMLC_MAX_LEAVES", 0,
-        "Leaf budget per tree under DMLC_GROW_POLICY=lossguide (0 = "
-        "unlimited, i.e. up to 2^max_depth); the queue stops after "
-        "max_leaves - 1 profitable expansions.", "gbt")
 declare("DMLC_BIN_PACK", "0",
         "1 packs narrow features two-per-byte (int4) in the transposed "
         "bin matrix: features whose OCCUPIED bin count is <= 16 are "

@@ -156,23 +156,6 @@ def _hist_blocks(data_size: int) -> int:
     return c
 
 
-def _grow_policy() -> str:
-    """Tree growth policy (``DMLC_GROW_POLICY``): ``depthwise`` (default,
-    the bit-parity-pinned complete-tree engine) or ``lossguide``
-    (LightGBM-style leaf-wise growth: expand the open leaf with the best
-    gain, building ONE histogram per expansion + sibling subtraction)."""
-    v = os.environ.get("DMLC_GROW_POLICY", "depthwise")
-    CHECK(v in ("depthwise", "lossguide"),
-          f"DMLC_GROW_POLICY must be 'depthwise' or 'lossguide', got {v!r}")
-    return v
-
-
-def _max_leaves() -> int:
-    """``DMLC_MAX_LEAVES``: leaf budget for lossguide growth (0 = full
-    2^max_depth, i.e. no budget beyond the depth cap)."""
-    return get_env("DMLC_MAX_LEAVES", 0, int)
-
-
 def _bin_pack_requested() -> bool:
     """``DMLC_BIN_PACK=1``: pack two ≤16-bin features per byte (int4) in
     the transposed bin matrix (ops.binlayout), halving HBM bin traffic
@@ -210,7 +193,9 @@ class _RoundPlan(NamedTuple):
     missing: bool
     pallas_interpret: bool
     grow_policy: str
-    #: loss-guide leaf budget (0 = the depth cap alone)
+    #: leaves of a loss-guide tree: ``max_leaves`` and ``2^max_depth``,
+    #: whichever is set and smaller (0 under depth-wise growth); a tree is
+    #: a node list of ``2 * max_leaves - 1`` entries
     max_leaves: int
     layout: Optional[_bl.BinLayout]
     hist_blocks: int
@@ -268,6 +253,10 @@ class _RoundPlan(NamedTuple):
             "margin_layout": "[num_class, n]" if self.num_class > 1
                              else "[n]",
             **(self.rank.describe() if self.rank is not None else {}),
+            # a loss-guide tree: one single-node build an expansion
+            **({"max_leaves": self.max_leaves,
+                "expansions": self.max_leaves - 1}
+               if self.grow_policy == "lossguide" else {}),
         }
 
 
@@ -603,7 +592,21 @@ class HistGBTParam(Parameter):
     """Hyperparameters (XGBoost-compatible names where they exist)."""
 
     n_trees = field(int, default=100, lower_bound=1, description="boosting rounds")
-    max_depth = field(int, default=6, lower_bound=1, upper_bound=12)
+    max_depth = field(int, default=6, lower_bound=0, upper_bound=12,
+                      description="tree depth; 0 = no depth cap, under "
+                                  "grow_policy='lossguide' with a "
+                                  "max_leaves budget only")
+    grow_policy = field(str, default="depthwise",
+                        enum=["depthwise", "lossguide"],
+                        description="depthwise: complete trees, a level at "
+                                    "a time; lossguide: leaf-wise, the open "
+                                    "leaf of highest gain next (LightGBM's "
+                                    "policy), one histogram build an "
+                                    "expansion")
+    max_leaves = field(int, default=0, lower_bound=0,
+                       description="leaf budget of a lossguide tree (0 = "
+                                   "the depth cap alone, 2^max_depth); "
+                                   "ignored by depthwise growth")
     n_bins = field(int, default=256, lower_bound=2, upper_bound=256,
                    description="feature quantization bins (max_bin)")
     learning_rate = field(float, default=0.3, lower_bound=0.0, description="eta")
@@ -1221,7 +1224,7 @@ class HistGBT(_ExternalMemoryEngine):
             # later chunks keep computing — so these in-order arrival
             # timestamps give per-chunk durations for free (see
             # ``last_chunk_times`` doc in __init__).
-            k = int(trees_k["leaf"].shape[0])
+            k = int(trees_k["feat"].shape[0])
             with span("dmlc.fit.fetch_chunk", trees=k):
                 t_np = jax.tree.map(np.asarray, trees_k)
             fetched += k
@@ -2470,7 +2473,12 @@ class HistGBT(_ExternalMemoryEngine):
         det_blocks = _hist_blocks(dsize)
         sync_bins = layout.sync_bins if layout is not None else p.n_bins
         mat_rows = layout.phys_rows if layout is not None else n_features
-        lossguide = _grow_policy() == "lossguide"
+        lossguide = p.grow_policy == "lossguide"
+        leaves = self._lossguide_leaves() if lossguide else 0
+        CHECK(lossguide or depth >= 1,
+              "max_depth=0 (no depth cap) needs grow_policy='lossguide' "
+              "and a max_leaves budget; depthwise growth needs "
+              "max_depth >= 1")
         builds = [1] if lossguide else (
             [1] + [1 << (lv - 1) for lv in range(1, depth)])
         packed = layout is not None and bool(layout.pairs)
@@ -2500,7 +2508,7 @@ class HistGBT(_ExternalMemoryEngine):
             missing=self._missing,
             pallas_interpret=pallas_interpret(),
             grow_policy="lossguide" if lossguide else "depthwise",
-            max_leaves=_max_leaves() if lossguide else 0,
+            max_leaves=leaves,
             layout=layout,
             hist_blocks=det_blocks,
             mesh_devices=dsize,
@@ -2514,7 +2522,35 @@ class HistGBT(_ExternalMemoryEngine):
                 for m, nb in zip(methods, builds)),
             route_forms=route, route_word=route_word)
         self.round_plan = plan.describe()
+        if lossguide:
+            # rows a device hands each build: all of them today, the other
+            # leaves masked out.  A record of the shapes, not of the plan
+            # (one jitted program serves every row count)
+            self.round_plan["hist_rows_per_build"] = n_rows // dsize
         return plan
+
+    def _lossguide_leaves(self) -> int:
+        """Leaves of a loss-guide tree, and what the policy refuses —
+        said where the plan is made, before anything is traced."""
+        p = self.param
+        CHECK(not self._missing,
+              "grow_policy='lossguide' with NaN/missing features is not "
+              "supported yet — impute, or use depthwise")
+        CHECK(not any(int(v) for v in p.monotone_constraints),
+              "grow_policy='lossguide' with monotone_constraints is not "
+              "supported (bound propagation is level-order) — use "
+              "depthwise")
+        CHECK(p.max_leaves or p.max_depth,
+              "grow_policy='lossguide' needs a bound: max_leaves, "
+              "max_depth, or both (max_depth=0 and max_leaves=0 leave "
+              "the tree unbounded)")
+        caps = [v for v in (p.max_leaves,
+                            (1 << p.max_depth) if p.max_depth else 0) if v]
+        leaves = min(caps)
+        CHECK(leaves >= 2,
+              f"lossguide needs >= 2 leaves (max_depth={p.max_depth}, "
+              f"max_leaves={p.max_leaves})")
+        return leaves
 
     def _round_fn_cache_key(self, plan: _RoundPlan, n_rounds: int):
         """Everything baked into the traced round program as a constant:
@@ -2561,7 +2597,7 @@ class HistGBT(_ExternalMemoryEngine):
         mcw = p.min_child_weight
         methods = plan.hist_method
         obj = self._obj
-        n_leaf = 1 << depth
+        n_leaf = 1 << depth            # (depth-wise's; loss-guide: node lists)
         half = max(n_leaf >> 1, 1)
 
         mono_arr = None
@@ -2610,35 +2646,13 @@ class HistGBT(_ExternalMemoryEngine):
         layout = plan.layout
         lossguide = plan.grow_policy == "lossguide"
         if lossguide:
-            CHECK(not missing,
-                  "DMLC_GROW_POLICY=lossguide with NaN/missing features "
-                  "is not supported yet — impute, or use depthwise")
-            CHECK(mono_arr is None,
-                  "DMLC_GROW_POLICY=lossguide with monotone_constraints "
-                  "is not supported (bound propagation is level-order) "
-                  "— use depthwise")
-            max_leaves = plan.max_leaves
-            CHECK(max_leaves >= 0, "DMLC_MAX_LEAVES must be >= 0")
-            L_leaves = min(max_leaves, n_leaf) if max_leaves else n_leaf
-            CHECK(L_leaves >= 2,
-                  f"lossguide needs >= 2 leaves (max_depth={depth}, "
-                  f"DMLC_MAX_LEAVES={max_leaves})")
+            L_leaves = plan.max_leaves
             # the open-leaf histogram pool is the policy's working set:
             # L·2·F·B f32 — refuse silently absurd configs up front
             CHECK(L_leaves * 2 * n_features * B * 4 <= (256 << 20),
                   f"lossguide histogram pool would exceed 256 MB "
                   f"({L_leaves} leaves x {n_features} features x {B} "
-                  f"bins) — lower DMLC_MAX_LEAVES or max_depth")
-            # heap-id space: root 1, children 2i/2i+1; ids with level >
-            # depth are never created (only level < depth leaves expand)
-            NH = 1 << (depth + 1)
-            level_np = np.zeros(NH, np.int32)
-            pos_np = np.zeros(NH, np.int32)
-            for i in range(1, NH):
-                lvl = i.bit_length() - 1
-                level_np[i] = lvl
-                # leaf position = leftmost depth-level descendant
-                pos_np[i] = (i - (1 << lvl)) << (depth - lvl)
+                  f"bins) — lower max_leaves or max_depth")
 
         def row_blocks(n_local):
             """The row blocks a shard's rows are cut into in
@@ -2897,22 +2911,29 @@ class HistGBT(_ExternalMemoryEngine):
                     table_select, n_entries=n_leaf))(leaf, node)
 
         def grow_tree_lossguide(bins_tl, g, h, feat_mask):
-            """One LEAF-WISE tree on (g, h) → (tree arrays, margin delta).
+            """One LEAF-WISE tree on (g, h) → (node list, margin delta).
 
-            LightGBM lossguide: a gain-priority queue over open leaves;
-            each of the ``L_leaves - 1`` expansions splits the open leaf
-            with the best candidate gain, builds ONE histogram (the left
-            child over only that leaf's rows) and derives the right
-            sibling by subtraction from the parent's pooled histogram.
-            Per round that is ``L_leaves`` node-histogram builds against
-            depthwise's ``2^(depth-1)`` — the win when the leaf budget
-            is far under the full tree.  Trees are emitted in the SAME
-            complete-binary-tree arrays depthwise uses (unexpanded heap
-            slots carry the depthwise degenerate encoding feat=0,
-            thr=B-1, gain=0, leaf −0.0), so save_model, predict and
-            every downstream consumer are layout-unchanged.  With an
-            unbounded budget the split STRUCTURE (feat/thr/gain) is
-            bit-identical to depthwise — pinned by
+            XGBoost's ``grow_policy=lossguide`` (LightGBM's growth): a
+            gain-priority queue over the open leaves; each of the
+            ``L_leaves - 1`` expansions splits the open leaf of highest
+            candidate gain (ties: the LOWEST node id, ``argmax``'s first),
+            builds ONE histogram — the left child, every other row masked
+            out — and takes the right sibling by subtraction from the
+            parent's pooled histogram.  A leaf is split only while its
+            gain > ``gamma`` and both children hold ``min_child_weight``
+            of hessian (``best_split``'s own gates), so once no open leaf
+            qualifies every later expansion is a no-op and the tree has
+            fewer leaves than its budget.
+
+            The tree is a NODE LIST of ``2 * L_leaves - 1`` entries, of
+            any depth: node 0 is the root and expansion ``k`` makes nodes
+            ``2k+1`` (left) and ``2k+2`` (right).  ``feat`` / ``thr`` /
+            ``gain`` hold a split node's split (a leaf's and an unused
+            entry's are the degenerate 0 / B-1 / 0), ``left`` / ``right``
+            its children (-1: none), ``value`` a leaf's weight times eta
+            (0 elsewhere).  A depth cap (``max_depth`` > 0) is a test on
+            a node's depth.  With a budget of ``2^max_depth`` leaves the
+            split STRUCTURE is depth-wise's, bit for bit — pinned by
             tests/test_lossguide.py; leaf values agree to f32 rounding
             (subtracted vs freshly-built deepest-level histograms).
 
@@ -2929,6 +2950,11 @@ class HistGBT(_ExternalMemoryEngine):
             n_local = int(bins_tl.shape[1])
             rows = row_blocks(n_local)
             n_blk = len(rows)
+            M = 2 * L_leaves - 1                  # the node list's entries
+            # the expansion's device phases (doc/observability.md)
+            in_pick, in_hist, in_settle = (
+                jax.named_scope(f"dmlc.round.expand.{phase}")
+                for phase in ("pick", "hist", "settle"))
 
             def build_one(node_build):
                 """Histogram of the single node whose rows have
@@ -2954,8 +2980,6 @@ class HistGBT(_ExternalMemoryEngine):
                 tot = jnp.cumsum(ev, axis=-1)[..., 0, -1]    # [2, N]
                 return f_, t_, gn_, tot[0], tot[1]
 
-            levels = jnp.asarray(level_np)
-            poss = jnp.asarray(pos_np)
             tabs = (_bl.layout_tables(layout) if layout is not None
                     else None)
 
@@ -2991,143 +3015,123 @@ class HistGBT(_ExternalMemoryEngine):
                 return v
 
             def open_root(root):
-                """The queue with the root alone in it."""
+                """The queue with the root (node 0, pool slot 0) alone in
+                it."""
                 f0, t0_, g0, tg0, th0 = eval_nodes(root)
-                open_ = jnp.zeros(NH, bool).at[1].set(True)
-                leaf_g = jnp.zeros(NH, jnp.float32).at[1].set(tg0[0])
-                leaf_h = jnp.zeros(NH, jnp.float32).at[1].set(th0[0])
-                cand_feat = jnp.zeros(NH, jnp.int32).at[1].set(f0[0])
-                cand_thr = jnp.full(NH, B - 1, jnp.int32).at[1].set(t0_[0])
-                cand_gain = jnp.full(NH, -jnp.inf,
-                                     jnp.float32).at[1].set(g0[0])
-                rec_feat = jnp.zeros(NH, jnp.int32)
-                rec_thr = jnp.full(NH, B - 1, jnp.int32)
-                rec_gain = jnp.zeros(NH, jnp.float32)
-                pool = jnp.zeros((L_leaves,) + root[:, 0].shape,
-                                 jnp.float32).at[0].set(root[:, 0])
-                pool_id = jnp.zeros(L_leaves, jnp.int32).at[0].set(1)
-                return (open_, leaf_g, leaf_h, cand_feat, cand_thr,
-                        cand_gain, pool, pool_id, rec_feat, rec_thr,
-                        rec_gain)
+                zi = jnp.zeros(M, jnp.int32)
+                zf = jnp.zeros(M, jnp.float32)
+                return {
+                    "open": jnp.zeros(M, bool).at[0].set(True),
+                    "leaf_g": zf.at[0].set(tg0[0]),
+                    "leaf_h": zf.at[0].set(th0[0]),
+                    "cand_feat": zi.at[0].set(f0[0]),
+                    "cand_thr": jnp.full(M, B - 1, jnp.int32).at[0].set(
+                        t0_[0]),
+                    "cand_gain": jnp.full(M, -jnp.inf, jnp.float32).at[
+                        0].set(g0[0]),
+                    "depth": zi,
+                    # a node's pool slot: its parent's for a left child,
+                    # slot k+1 for expansion k's right child
+                    "slot": zi,
+                    "pool": jnp.zeros((L_leaves,) + root[:, 0].shape,
+                                      jnp.float32).at[0].set(root[:, 0]),
+                    "feat": zi, "thr": jnp.full(M, B - 1, jnp.int32),
+                    "gain": zf, "left": jnp.full(M, -1, jnp.int32),
+                    "right": jnp.full(M, -1, jnp.int32),
+                }
 
-            def pick(node, state):
-                """The open leaf to expand and its rows sent down: the
-                new node ids, the left child's rows to build, and what
-                :func:`settle` needs of the choice."""
-                (open_, _, _, cand_feat, cand_thr, cand_gain, _, pool_id,
-                 rec_feat, rec_thr, rec_gain) = state
-                # priority queue: best candidate gain among open leaves
-                # that can still grow.  A real split always has recorded
-                # gain > gamma (best_split's own split_ok gate), so the
-                # > gamma test is exactly depthwise's expansion rule.
-                gains = jnp.where(open_ & (levels < depth), cand_gain,
-                                  -jnp.inf)
-                hc = jnp.argmax(gains).astype(jnp.int32)
+            def pick(node, st, k):
+                """Expansion ``k``: the open leaf to split and its rows
+                sent down to nodes ``2k+1`` / ``2k+2``; the left child's
+                rows to build, and what :func:`settle` needs of the
+                choice."""
+                # priority queue: best candidate gain among the open
+                # leaves.  A real split always has recorded gain > gamma
+                # (best_split's own split_ok gate), so the > gamma test
+                # is exactly depthwise's expansion rule.
+                gains = jnp.where(st["open"], st["cand_gain"], -jnp.inf)
+                hc = jnp.argmax(gains).astype(jnp.int32)  # ties: lowest id
                 ok = gains[hc] > gamma
-                fsel = cand_feat[hc]
-                tsel = cand_thr[hc]
-                hc_eff = jnp.where(ok, hc, NH)
-                rec_feat = rec_feat.at[hc_eff].set(fsel, mode="drop")
-                rec_thr = rec_thr.at[hc_eff].set(tsel, mode="drop")
-                rec_gain = rec_gain.at[hc_eff].set(cand_gain[hc],
-                                                   mode="drop")
-                mine = node == hc
-                slot = jnp.argmax(pool_id == hc)
+                fsel = st["cand_feat"][hc]
+                tsel = st["cand_thr"][hc]
+                lc, rc = 2 * k + 1, 2 * k + 2
                 # descend the expanded leaf's rows on (fsel, tsel)
                 v = row_bins_of(fsel)
                 go_right = v > tsel
-                node = jnp.where(ok & mine,
-                                 2 * node + go_right.astype(jnp.int32),
-                                 node)
+                mine = ok & (node == hc)
+                node = jnp.where(mine, jnp.where(go_right, rc, lc), node)
                 # ONE build: left child only; right = parent − left
-                node_build = jnp.where(ok & mine & ~go_right, 0, -1)
-                return (node, node_build,
-                        (hc, ok, hc_eff, slot, rec_feat, rec_thr, rec_gain))
+                node_build = jnp.where(mine & ~go_right, 0, -1)
+                return node, node_build, (hc, ok, fsel, tsel, lc, rc)
 
-            def settle(state, picked, left):
+            def settle(st, picked, left, k):
                 """The expansion's two children into the queue and the
                 pool, from the built left child ``left`` [2, 1, S, Bs]."""
-                (open_, leaf_g, leaf_h, cand_feat, cand_thr, cand_gain,
-                 pool, pool_id, _, _, _) = state
-                hc, ok, hc_eff, slot, rec_feat, rec_thr, rec_gain = picked
+                hc, ok, fsel, tsel, lc, rc = picked
                 left = left[:, 0]                         # [2, S, Bs]
-                right = pool[slot] - left
+                slot = st["slot"][hc]
+                right = st["pool"][slot] - left
                 f2, t2, g2, tg2, th2 = eval_nodes(
                     jnp.stack([left, right], axis=1))
-                # children at the depth cap never expand
-                g2 = jnp.where(levels[2 * hc] < depth, g2, -jnp.inf)
-                lc = jnp.where(ok, 2 * hc, NH)
-                rc = jnp.where(ok, 2 * hc + 1, NH)
-                open_ = open_.at[hc_eff].set(False, mode="drop")
-                open_ = open_.at[lc].set(True, mode="drop")
-                open_ = open_.at[rc].set(True, mode="drop")
-                leaf_g = leaf_g.at[lc].set(tg2[0], mode="drop")
-                leaf_g = leaf_g.at[rc].set(tg2[1], mode="drop")
-                leaf_h = leaf_h.at[lc].set(th2[0], mode="drop")
-                leaf_h = leaf_h.at[rc].set(th2[1], mode="drop")
-                cand_feat = cand_feat.at[lc].set(f2[0], mode="drop") \
-                                     .at[rc].set(f2[1], mode="drop")
-                cand_thr = cand_thr.at[lc].set(t2[0], mode="drop") \
-                                   .at[rc].set(t2[1], mode="drop")
-                cand_gain = cand_gain.at[lc].set(g2[0], mode="drop") \
-                                     .at[rc].set(g2[1], mode="drop")
-                # pool bookkeeping: parent slot → left child; first free
-                # slot (searched BEFORE the parent overwrite) → right
-                free = jnp.argmax(pool_id == 0)
-                slot_eff = jnp.where(ok, slot, L_leaves)
-                free_eff = jnp.where(ok, free, L_leaves)
-                pool = pool.at[slot_eff].set(left, mode="drop")
-                pool = pool.at[free_eff].set(right, mode="drop")
-                pool_id = pool_id.at[slot_eff].set(2 * hc, mode="drop")
-                pool_id = pool_id.at[free_eff].set(2 * hc + 1,
-                                                   mode="drop")
-                return (open_, leaf_g, leaf_h, cand_feat, cand_thr,
-                        cand_gain, pool, pool_id, rec_feat, rec_thr,
-                        rec_gain)
+                child_depth = st["depth"][hc] + 1
+                if depth:                # children at the cap never expand
+                    g2 = jnp.where(child_depth < depth, g2, -jnp.inf)
+                # a skipped expansion (ok False) writes past the end
+                hc_, lc_, rc_ = (jnp.where(ok, i, M) for i in (hc, lc, rc))
+                st = dict(st)
 
-            def expand(carry, _):
-                node, state = carry
-                node, node_build, picked = per_class(pick)(node, state)
-                left = build_one(node_build)
-                return (node, per_class(settle)(state, picked, left)), None
+                def put(name, at, value):
+                    st[name] = st[name].at[at].set(value, mode="drop")
 
-            def finish(node, state):
-                """The tree in depthwise's arrays and each row's delta."""
-                (open_, leaf_g, leaf_h, _, _, _, _, _, rec_feat, rec_thr,
-                 rec_gain) = state
-                # leaf table in depthwise's positional layout: every slot
-                # an open leaf doesn't own is a depthwise empty leaf,
-                # whose value is exactly −0.0 (−(+0)/(0+λ)·η)
-                w_all = (-_maybe_l1(leaf_g, alpha) / (leaf_h + lam)) * eta
-                pos_eff = jnp.where(open_, poss, n_leaf)
-                leaf = jnp.full(n_leaf, -0.0,
-                                jnp.float32).at[pos_eff].set(w_all,
-                                                             mode="drop")
-                tree = {
-                    "feat": jnp.stack([
-                        jnp.pad(rec_feat[1 << lv:1 << (lv + 1)],
-                                (0, half - (1 << lv)))
-                        for lv in range(depth)]),
-                    "thr": jnp.stack([
-                        jnp.pad(rec_thr[1 << lv:1 << (lv + 1)],
-                                (0, half - (1 << lv)))
-                        for lv in range(depth)]),
-                    "gain": jnp.stack([
-                        jnp.pad(rec_gain[1 << lv:1 << (lv + 1)],
-                                (0, half - (1 << lv)))
-                        for lv in range(depth)]),
-                    "leaf": leaf,                            # [n_leaf]
-                }
-                delta = table_select(jnp.where(open_, w_all, 0.0), node, NH)
-                return tree, delta
+                put("open", hc_, False)
+                put("feat", hc_, fsel)
+                put("thr", hc_, tsel)
+                put("gain", hc_, st["cand_gain"][hc])
+                put("left", hc_, lc)
+                put("right", hc_, rc)
+                for j, at in enumerate((lc_, rc_)):
+                    put("open", at, True)
+                    put("leaf_g", at, tg2[j])
+                    put("leaf_h", at, th2[j])
+                    put("cand_feat", at, f2[j])
+                    put("cand_thr", at, t2[j])
+                    put("cand_gain", at, g2[j])
+                    put("depth", at, child_depth)
+                # the parent's slot → the left child, slot k+1 → the right
+                put("slot", lc_, slot)
+                put("slot", rc_, k + 1)
+                put("pool", jnp.where(ok, slot, L_leaves), left)
+                put("pool", jnp.where(ok, k + 1, L_leaves), right)
+                return st
+
+            def expand(carry, k):
+                node, st = carry
+                node, node_build, picked = in_pick(per_class(
+                    partial(pick, k=k)))(node, st)
+                left = in_hist(build_one)(node_build)
+                st = in_settle(per_class(partial(settle, k=k)))(
+                    st, picked, left)
+                return (node, st), None
+
+            def finish(node, st):
+                """The node list and each row's delta."""
+                w_all = (-_maybe_l1(st["leaf_g"], alpha)
+                         / (st["leaf_h"] + lam)) * eta
+                value = jnp.where(st["open"], w_all, 0.0)   # open = a leaf
+                tree = {k: st[k] for k in ("feat", "thr", "gain", "left",
+                                           "right")}
+                tree["value"] = value
+                return tree, table_select(value, node, M)
 
             # ---- root ----
-            node = jnp.ones(g.shape, jnp.int32)          # heap ids
-            state = per_class(open_root)(
-                build_one(jnp.zeros(g.shape, jnp.int32)))
-            (node, state), _ = jax.lax.scan(expand, (node, state), None,
-                                            length=L_leaves - 1)
-            return per_class(finish)(node, state)
+            with jax.named_scope("dmlc.round.root"):
+                node = jnp.zeros(g.shape, jnp.int32)     # node-list ids
+                st = per_class(open_root)(build_one(node))
+            (node, st), _ = jax.lax.scan(
+                expand, (node, st),
+                jnp.arange(L_leaves - 1, dtype=jnp.int32))
+            with jax.named_scope("dmlc.round.leaf"):
+                return per_class(finish)(node, st)
 
         grow = grow_tree_lossguide if lossguide else grow_tree
 
@@ -3329,15 +3333,18 @@ class HistGBT(_ExternalMemoryEngine):
         Returns int32 ``[n, T]`` (multiclass: ``[n, T, K]``) of leaf
         positions in ``[0, 2^max_depth)`` — the index within each
         depth-complete tree's leaf layer (XGBoost's global node ids for
-        a complete tree are ``leaf + 2^depth − 1``).  The classic use is
-        GBDT feature embeddings (leaf one-hots into a linear model)."""
+        a complete tree are ``leaf + 2^depth − 1``); for loss-guide
+        trees, the leaf's id in the tree's node list (``[0, 2L-1)``).
+        The classic use is GBDT feature embeddings (leaf one-hots into a
+        linear model)."""
         CHECK(self.cuts is not None, "predict before fit")
         CHECK(len(self.trees) > 0, "no trees trained")
         depth = self.param.max_depth
         use = self._resolve_trees(n_trees)
         # exact-count stack (not the padded chunks): the output is
         # [n, T] leaf ids, so padded no-op trees would widen it
-        keys = ("feat", "thr") + (("dir",) if "dir" in use[0] else ())
+        keys = tuple(k for k in _forest_keys(use)
+                     if k not in ("leaf", "value"))
         stacked = {k: jnp.asarray(np.stack([t[k] for t in use]))
                    for k in keys}
         X = np.ascontiguousarray(X, dtype=np.float32)
@@ -3352,7 +3359,9 @@ class HistGBT(_ExternalMemoryEngine):
         for lo in range(0, len(X), self._PREDICT_BATCH):
             bins = self._bin_matrix(
                 jnp.asarray(X[lo:lo + self._PREDICT_BATCH]))
-            if stacked["feat"].ndim == 4:   # multiclass [T, K, depth, half]
+            if "left" in stacked:           # node lists: [T, (K,) M]
+                outs.append(np.asarray(_node_list_leaves(bins, stacked)))
+            elif stacked["feat"].ndim == 4:  # multiclass [T, K, depth, half]
                 cols = [_leaf_indices(
                             bins, stacked["feat"][:, c],
                             stacked["thr"][:, c], depth,
@@ -3442,8 +3451,7 @@ class HistGBT(_ExternalMemoryEngine):
         more so that a prefix and the whole forest can take turns.
         Concurrent callers each build what they miss and publish with
         one assignment.  ``engine`` labels the counter."""
-        keys = ("feat", "thr", "leaf") + (
-            ("dir",) if "dir" in trees[0] else ())
+        keys = _forest_keys(trees)
         kept = self._forest_chunks
         chunks: List[_ForestChunk] = []
         with span("dmlc.predict.stack", trees=len(trees)) as sp:
@@ -3551,9 +3559,11 @@ class HistGBT(_ExternalMemoryEngine):
         tree, one node per line) — the debugging/inspection surface of
         ``Booster.dump_model``.
 
-        Node ids follow the complete-binary-tree layout these depth-wise
-        trees actually have: node ``n`` of level ``ℓ`` is id
-        ``2^ℓ−1+n`` with children ``2^(ℓ+1)−1+2n`` / ``+2n+1``; the leaf
+        A loss-guide tree prints the ids of its node list (root 0,
+        expansion ``k``'s children ``2k+1`` / ``2k+2``).  A depth-wise
+        tree's node ids follow the complete-binary-tree layout it has:
+        node ``n`` of level ``ℓ`` is id ``2^ℓ−1+n``
+        with children ``2^(ℓ+1)−1+2n`` / ``+2n+1``; the leaf
         layer sits at level ``max_depth``.  Split conditions print the
         REAL feature threshold (``cuts[f][thr]`` — bins are internal),
         as ``[f<N>≤x]`` with yes=left.  Degenerate nodes (no profitable
@@ -3606,9 +3616,40 @@ class HistGBT(_ExternalMemoryEngine):
             for i, v in enumerate(np.asarray(leaf_t)):
                 lines.append(f"\t{base + i}:leaf={float(v):.6g}")
 
+        def dump_nodes(tree):
+            """A loss-guide tree: the node list's own ids, the nodes the
+            root reaches in id order (unused entries are skipped)."""
+            feat_t, thr_t, left_t, right_t, value_t = (
+                np.asarray(tree[k]) for k in ("feat", "thr", "left",
+                                              "right", "value"))
+            reach, todo = set(), [0]
+            while todo:
+                i = todo.pop()
+                reach.add(i)
+                if left_t[i] > 0:
+                    todo += [int(left_t[i]), int(right_t[i])]
+            for i in sorted(reach):
+                if left_t[i] <= 0:
+                    lines.append(f"\t{i}:leaf={float(value_t[i]):.6g}")
+                    continue
+                f, t = int(feat_t[i]), int(thr_t[i])
+                stat = (f",gain={float(tree['gain'][i]):.6g}"
+                        if with_stats and "gain" in tree else "")
+                lines.append(f"\t{i}:[{fname(f)}<{cuts[f][t]:.6g}] "
+                             f"yes={int(left_t[i])},no={int(right_t[i])}"
+                             f"{stat}")
+
         for ti, tree in enumerate(self.trees):
             feat_t = np.asarray(tree["feat"])
-            if feat_t.ndim == 3:            # multiclass [K, depth, half]
+            if "left" in tree:              # node list [(K,) M]
+                if feat_t.ndim == 2:
+                    for c in range(feat_t.shape[0]):
+                        lines.append(f"booster[{ti}] class[{c}]:")
+                        dump_nodes({k: v[c] for k, v in tree.items()})
+                else:
+                    lines.append(f"booster[{ti}]:")
+                    dump_nodes(tree)
+            elif feat_t.ndim == 3:          # multiclass [K, depth, half]
                 for c in range(feat_t.shape[0]):
                     lines.append(f"booster[{ti}] class[{c}]:")
                     dump_one(tree["feat"][c], tree["thr"][c],
@@ -3648,6 +3689,11 @@ class HistGBT(_ExternalMemoryEngine):
             thr_t = np.asarray(tree["thr"])
             gain_t = (np.asarray(tree["gain"])
                       if importance_type == "gain" else None)
+            if "left" in tree:              # node list: the split nodes
+                split = np.asarray(tree["left"]) > 0
+                np.add.at(out, feat_t[split],
+                          gain_t[split] if gain_t is not None else 1)
+                continue
             if feat_t.ndim == 2:            # single-output: [depth, half]
                 feat_t, thr_t = feat_t[None], thr_t[None]
                 gain_t = None if gain_t is None else gain_t[None]
@@ -3705,6 +3751,70 @@ def _put_chunk(part: List[Dict[str, np.ndarray]], keys: Tuple[str, ...]
             v = np.concatenate([v, np.zeros((pad,) + v.shape[1:], v.dtype)])
         arrays[k] = jnp.asarray(v)
     return arrays
+
+
+def _forest_keys(trees: List[Dict[str, np.ndarray]]) -> Tuple[str, ...]:
+    """The tables a device forest of ``trees`` carries: depth-wise trees'
+    level arrays, or loss-guide trees' node lists.  One forest, one
+    form."""
+    node_lists = "left" in trees[0]
+    CHECK(all(("left" in t) == node_lists for t in trees),
+          "the ensemble mixes depth-wise trees and loss-guide node lists "
+          "(a fit continued under another grow_policy): they cannot be "
+          "scored as one forest")
+    if node_lists:
+        return ("feat", "thr", "left", "right", "value")
+    return ("feat", "thr", "leaf") + (("dir",) if "dir" in trees[0] else ())
+
+
+def _walk_node_list(bins, tree):
+    """Each row's leaf in ONE node list (``left`` > 0 marks a split
+    node: the root is nobody's child, so an all-zero padding tree stays
+    at node 0, whose value is 0), by following children until no row
+    moves — a tree of any depth, one program.  Plain gathers: no cell
+    times this walker yet (PERF.md section 7)."""
+    feat, thr, left, right = (tree[k] for k in ("feat", "thr", "left",
+                                                "right"))
+
+    def step(node):
+        row_bin = jnp.take_along_axis(
+            bins, feat[node][:, None], axis=1)[:, 0].astype(jnp.int32)
+        to_left = left[node]
+        nxt = jnp.where(row_bin > thr[node], right[node], to_left)
+        return jnp.where(to_left > 0, nxt, node)
+
+    return jax.lax.while_loop(lambda node: jnp.any(left[node] > 0), step,
+                              jnp.zeros(bins.shape[0], jnp.int32))
+
+
+@jax.jit
+@jax.named_scope("dmlc.descend.nodes")
+def _add_node_lists(bins, forest, margin):
+    """``margin`` ([n], multiclass class-major [K, n]) plus the leaf
+    values of a forest of node lists [T, (K,) M], tree after tree in
+    float32: the summation order of the updates that built the
+    margins."""
+    def one_class(trees, margin):
+        def one_tree(margin, tree):
+            return margin + tree["value"][_walk_node_list(bins, tree)], None
+
+        return jax.lax.scan(one_tree, margin, trees)[0]
+
+    if forest["feat"].ndim == 3:       # multiclass: [T, K, M]
+        by_class = jax.tree.map(lambda a: jnp.moveaxis(a, 1, 0), forest)
+        return jax.lax.map(lambda a: one_class(*a), (by_class, margin))
+    return one_class(forest, margin)
+
+
+@jax.jit
+@jax.named_scope("dmlc.descend.nodes")
+def _node_list_leaves(bins, forest):
+    """Per-tree leaf node ids [n, T] (multiclass [n, T, K]) of a forest
+    of node lists (predict_leaf)."""
+    walk = partial(_walk_node_list, bins)
+    if forest["feat"].ndim == 3:
+        walk = jax.vmap(walk)
+    return jnp.moveaxis(jax.lax.map(walk, forest), -1, 0)
 
 
 #: row-trees one block of the descent walks at once: each of a block's
@@ -3864,6 +3974,8 @@ def _add_trees(bins, forest, margin, depth: int, miss_bin: int):
     :func:`_predict_trees` with the margin as its ``init``, for several
     classes ONE descent program walked K times (``lax.map`` over the
     class axis)."""
+    if "left" in forest:               # loss-guide trees: node lists
+        return _add_node_lists(bins, forest, margin)
     dirs = forest.get("dir")
     if forest["feat"].ndim == 4:       # multiclass: [T, K, depth, half]
         by_class = jax.tree.map(
@@ -3905,7 +4017,8 @@ def _predict_slab(x, cuts, chunks, depth: int, miss_bin: int,
     with jax.named_scope("dmlc.descend"):
         forest = {k: jnp.concatenate([chunk[k] for chunk in chunks])
                   for k in chunks[0]}
-    n_out = forest["leaf"].shape[1:-1]     # () or, multiclass, (K,)
+    # () or, multiclass, (K,): [T, (K,) n_leaf], node lists [T, (K,) M]
+    n_out = forest["value" if "left" in forest else "leaf"].shape[1:-1]
     margin = _add_trees(
         bins, forest,
         jnp.full(n_out + x.shape[:1], base_score, jnp.float32),

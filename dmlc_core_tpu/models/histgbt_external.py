@@ -447,6 +447,10 @@ class _ExternalMemoryEngine:
 
         p = self.param
         obj = self._obj
+        CHECK(p.grow_policy == "depthwise" and p.max_depth >= 1,
+              "the external-memory engine grows depth-wise trees only: "
+              "grow_policy='lossguide' (and max_depth=0) is the in-core "
+              "engine's (fit / fit_device)")
         B, depth, K_cls = p.n_bins, p.max_depth, p.num_class
         n_leaf = 1 << depth
         half = max(n_leaf >> 1, 1)

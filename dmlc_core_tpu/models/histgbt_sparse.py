@@ -205,6 +205,9 @@ class SparseHistGBT:
         CHECK(p.colsample_bytree >= 1.0,
               "SparseHistGBT: colsample_bytree not supported (v1) — "
               "a silently ignored knob would train a different model")
+        CHECK(p.grow_policy == "depthwise" and p.max_depth >= 1,
+              "SparseHistGBT grows depth-wise trees only "
+              "(grow_policy='lossguide' is HistGBT's)")
         # the field bound is inclusive; 0.0 would silently train
         # all-degenerate trees (same guard as the dense engine)
         CHECK(p.subsample > 0.0, "subsample must be > 0")

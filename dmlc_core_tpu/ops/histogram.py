@@ -71,11 +71,13 @@ def leaves_built_per_round(depth: int, grow_policy: str = "depthwise",
     """Histogram BUILDS one boosting round pays (sibling subtraction
     derives the rest for free).  Depth-wise: the root plus every level's
     left children — ``2^(depth-1)``.  Loss-guide builds only for the
-    expanded leaf: the root plus one per expansion — ``max_leaves``
-    total, independent of depth.  Feeds bench.py's
-    ``kernel.leaves_built_per_round`` regression field."""
+    expanded leaf: the root plus one per expansion — the tree's leaves:
+    ``max_leaves`` and ``2^depth``, whichever is set (not 0) and smaller.
+    Feeds bench.py's ``kernel.leaves_built_per_round`` regression
+    field."""
     if grow_policy == "lossguide":
-        return min(max_leaves, 1 << depth) if max_leaves else 1 << depth
+        return min(v for v in (max_leaves, (1 << depth) if depth else 0)
+                   if v)
     return 1 if depth <= 1 else 1 << (depth - 1)
 
 

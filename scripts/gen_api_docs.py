@@ -80,6 +80,14 @@ def render_knob_doc() -> str:
         "The *default* column is what the reading call site applies "
         "when the variable is unset; boolean-ish knobs accept "
         "`0`/`1`.",
+        "",
+        "A model's hyperparameters are not knobs: they are fields of "
+        "its `Parameter` (`HistGBTParam`: `max_depth`, `learning_rate`, "
+        "`min_child_weight`, ... and, since PR 56, the growth policy "
+        "`grow_policy` = `depthwise` | `lossguide` and the leaf budget "
+        "`max_leaves`, where `max_depth=0` under `lossguide` means no "
+        "depth cap), given to the constructor, saved with the model "
+        "and listed in the [API pages](api/README.md).",
     ]
     by_group = {}
     for k in knobs.all_knobs():

@@ -115,3 +115,46 @@ def test_every_call_of_a_build_cut_on_features_states_a_limit(monkeypatch):
     assert limits(44, 4) == [H._NESTED_BLOCKS_VMEM] * 3   # feature blocks
     assert limits(44, 16) == [H._NESTED_BLOCKS_VMEM] * 12
     assert H._NESTED_BLOCKS_VMEM > 16 << 20
+
+
+def test_the_leaf_wise_round_program_compiles(chip_mesh, monkeypatch):
+    """ISSUE 56: what no TPU compiler had seen — a scan of 254 expansions
+    with the 255-slot histogram pool in its carry, a ``dynamic_slice`` of
+    one feature's row of the ``[F, n]`` bins an expansion, the node list's
+    one lookup of 509 entries — at the leaf-wise cell's width and budget
+    (the rows cut: they change no program but its sizes)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    monkeypatch.setattr(H, "pallas_interpret", lambda: False)
+    monkeypatch.setattr(G, "pallas_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(G, "_ROUND_FN_CACHE", {})
+    n, F = 4 * H._TILE_ROWS + 640, 28        # not a multiple of the tile
+    model = HistGBT(n_trees=5, mesh=chip_mesh, grow_policy="lossguide",
+                    max_leaves=255, max_depth=0, n_bins=256,
+                    learning_rate=0.1, min_child_weight=100.0)
+    plan = model._round_plan(F, n)
+    assert model.round_plan["hist_method"] == ["pallas"]
+    assert (model.round_plan["max_leaves"], model.round_plan["expansions"],
+            model.round_plan["hist_rows_per_build"]) == (255, 254, n)
+    mat = NamedSharding(chip_mesh, P(None, "data"))
+    row = NamedSharding(chip_mesh, P("data"))
+    args = (jax.ShapeDtypeStruct((F, n), np.uint8, sharding=mat),) + tuple(
+        jax.ShapeDtypeStruct((n,), np.float32, sharding=row)
+        for _ in range(3))
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        compiled = model._build_round_fn(plan, 5).lower(*args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+    text = compiled.as_text()
+    # two kernel calls in the program: the root's and the expansion's
+    assert text.count("tpu_custom_call") >= 2
+    for scope in ("dmlc.round.root", "dmlc.round.expand.pick",
+                  "dmlc.round.expand.hist", "dmlc.round.expand.settle"):
+        assert scope in text, scope
+    # five trees of 509 entries come back
+    assert "s32[5,509]" in text and "f32[5,509]" in text

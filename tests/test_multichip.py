@@ -408,7 +408,7 @@ class TestOneChipOracle:
         # mirrors the per-block deterministic reduction
         monkeypatch.setenv("DMLC_HIST_BLOCKS", "8")
         monkeypatch.setenv("DMLC_BIN_PACK", "1")
-        monkeypatch.setenv("DMLC_GROW_POLICY", "lossguide")
+        lg = dict(KW, grow_policy="lossguide")
         rng = np.random.default_rng(5)
         n = 1003
         X = rng.normal(size=(n, 7)).astype(np.float32)
@@ -417,9 +417,9 @@ class TestOneChipOracle:
         y = (X[:, 0] + X[:, 2] > 0.5).astype(np.float32)
         cuts = compute_cuts(X, KW["n_bins"])
         devs = np.array(jax.devices())
-        m1 = HistGBT(mesh=Mesh(devs[:1], ("data",)), **KW)
+        m1 = HistGBT(mesh=Mesh(devs[:1], ("data",)), **lg)
         m1.fit(X, y, cuts=cuts)
-        m8 = HistGBT(mesh=Mesh(devs[:8], ("data",)), **KW)
+        m8 = HistGBT(mesh=Mesh(devs[:8], ("data",)), **lg)
         m8.fit(X, y, cuts=cuts)
         assert m1._bin_layout is not None
         assert m1._bin_layout == m8._bin_layout   # identical layout
@@ -570,10 +570,9 @@ class TestPsumTraffic:
                         if s["labels"].get("engine") == "incore")
                     if m else 0.0)
 
-        monkeypatch.setenv("DMLC_GROW_POLICY", "lossguide")
-        monkeypatch.setenv("DMLC_MAX_LEAVES", "4")
         before = psum_total()
-        m8 = HistGBT(mesh=local_mesh(8), **KW)
+        m8 = HistGBT(mesh=local_mesh(8), grow_policy="lossguide",
+                     max_leaves=4, **KW)
         m8.fit(X, y)
         expect = KW["n_trees"] * hist_psum_bytes_per_round(
             KW["max_depth"], X.shape[1], KW["n_bins"],

@@ -59,13 +59,14 @@ def _model(env, monkeypatch, layout=None, **kw):
 _LEVERS = {
     "DMLC_HIST_BLOCKS": (dict(env={}), dict(env={"DMLC_HIST_BLOCKS": "2"}),
                          "hist_blocks"),
-    "DMLC_GROW_POLICY": (dict(env={}),
-                         dict(env={"DMLC_GROW_POLICY": "lossguide"}),
+    # (two hyperparameters on the Parameter since ISSUE 56)
+    "grow_policy": (dict(env={}),
+                         dict(env={}, grow_policy="lossguide"),
                          "grow_policy"),
-    "DMLC_MAX_LEAVES": (
-        dict(env={"DMLC_GROW_POLICY": "lossguide"}),
-        dict(env={"DMLC_GROW_POLICY": "lossguide", "DMLC_MAX_LEAVES": "4"}),
-        None),
+    "max_leaves": (
+        dict(env={}, grow_policy="lossguide"),
+        dict(env={}, grow_policy="lossguide", max_leaves=4),
+        "max_leaves"),
     "packed_layout": (dict(env={}), dict(env={}, layout="packed"),
                       "bin_layout"),
     "hist_method": (dict(env={}, hist_method="segment"),
@@ -102,7 +103,6 @@ def test_cache_key_follows_the_plan(lever, monkeypatch):
     assert len(hg._ROUND_FN_CACHE) == before + 1
     # and a lever set AFTER the plan was resolved moves nothing
     monkeypatch.setenv("DMLC_HIST_BLOCKS", "4")
-    monkeypatch.setenv("DMLC_GROW_POLICY", "lossguide")
     assert a._round_fn_cache_key(plan_a, 2) == key
     assert a._build_round_fn(plan_a, 2) is fn
 
@@ -150,8 +150,7 @@ class _NoLevers(dict):
 
 @pytest.mark.parametrize("policy", ["depthwise", "lossguide"])
 def test_no_environment_read_once_the_plan_is_resolved(policy, monkeypatch):
-    m, plan = _model({"DMLC_GROW_POLICY": policy, "DMLC_MAX_LEAVES": "5"},
-                     monkeypatch)
+    m, plan = _model({}, monkeypatch, grow_policy=policy, max_leaves=5)
     assert plan.grow_policy == policy
     assert plan.max_leaves == (5 if policy == "lossguide" else 0)
     key_before = m._round_fn_cache_key(plan, 2)
@@ -159,8 +158,7 @@ def test_no_environment_read_once_the_plan_is_resolved(policy, monkeypatch):
     monkeypatch.setattr(os, "environ", _NoLevers(os.environ))
     with pytest.raises(AssertionError, match="DMLC_HIST_BLOCKS"):
         m._round_plan(F)                            # the guard does bite
-    for helper in ("_hist_blocks", "_max_leaves", "_grow_policy",
-                   "get_env"):
+    for helper in ("_hist_blocks", "get_env"):
         def refuse(*a, _h=helper, **k):
             raise AssertionError(f"{_h} called after _round_plan returned")
         monkeypatch.setattr(hg, helper, refuse)
@@ -191,6 +189,18 @@ def test_round_plan_record_is_the_plans_json_view():
         "hist_node_blocks", "hist_class_blocks", "route_lookups",
         "hist_blocks", "mesh_devices",
         "num_class", "trees_per_round", "margin_layout"}
+    # a loss-guide plan says its leaves, expansions and rows a build too
+    lg = HistGBT(mesh=local_mesh(1), grow_policy="lossguide", max_leaves=5,
+                 **KW)
+    lg_plan = lg._round_plan(F, 640)
+    # (the rows a build is handed are a record of the shapes: the plan,
+    # hence the program's key, is the same at every row count)
+    assert lg_plan == lg._round_plan(F, 1280)
+    lg._round_plan(F, 640)
+    assert set(lg.round_plan) - set(m.round_plan) == {
+        "max_leaves", "expansions", "hist_rows_per_build"}
+    assert (lg.round_plan["max_leaves"], lg.round_plan["expansions"],
+            lg.round_plan["hist_rows_per_build"]) == (5, 4, 640)
     # a plan made without rows is a small fit's: route's unpacked tables
     assert m.round_plan["route_lookups"] == {
         "form": "pieces", "packed": False, "chained_entries": 0}
@@ -211,13 +221,15 @@ def test_round_plan_record_is_the_plans_json_view():
 
 _DELETED = ["DMLC_TPU_FUSED_" + "DESCEND", "DMLC_HIST_" + "QUANT",
             "DMLC_COLDSTART_" + "OVERLAP", "DMLC_SHARDED_" + "INGEST",
-            "DMLC_WARMUP_" + "EXEC", "DMLC_FUSED_" + "ROUND"]
+            "DMLC_WARMUP_" + "EXEC", "DMLC_FUSED_" + "ROUND",
+            # hyperparameters, on the Parameter since ISSUE 56
+            "DMLC_GROW_" + "POLICY", "DMLC_MAX_" + "LEAVES"]
 
 
 @pytest.mark.parametrize("name", _DELETED)
 def test_deleted_lever_is_gone(name, monkeypatch):
     assert name not in knobs.names()
-    assert len(knobs.names()) == 99
+    assert len(knobs.names()) == 97
     # set, it is any undeclared name: the plan and the key do not move
     m, plan = _model({}, monkeypatch, hist_method="pallas")
     m1, plan1 = _model({name: "1"}, monkeypatch, hist_method="pallas")

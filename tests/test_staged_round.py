@@ -63,8 +63,9 @@ def _wide_xy(F, n=1302, seed=11):
     return X, y
 
 
-def _tree_bytes(method, X, y):
-    m = HistGBT(mesh=local_mesh(1), hist_method=method, **MODEL_KW)
+def _tree_bytes(method, X, y, **kw):
+    m = HistGBT(mesh=local_mesh(1), hist_method=method,
+                **dict(MODEL_KW, **kw))
     m.fit(X, y)
     assert set(m.round_plan["hist_method"]) == {method}
     (tree,) = m.trees
@@ -73,23 +74,23 @@ def _tree_bytes(method, X, y):
 
 class TestPallasFitEqualsSegmentFit:
     # every lever the staged round composes with; lossguide rides
-    # DMLC_MAX_LEAVES so the expansion loop (not the level loop) is hit
+    # max_leaves so the expansion loop (not the level loop) is hit
     CASES = [
         ("depthwise_plain", {}, _narrow_xy),
         ("depthwise_pack", {"DMLC_BIN_PACK": "1"}, _narrow_xy),
         ("depthwise_bundle", {"DMLC_FEATURE_BUNDLE": "1"}, _bundle_xy),
-        ("lossguide_plain", {"DMLC_GROW_POLICY": "lossguide",
-                             "DMLC_MAX_LEAVES": "6"}, _narrow_xy),
-        ("lossguide_pack", {"DMLC_GROW_POLICY": "lossguide",
-                            "DMLC_MAX_LEAVES": "6",
+        ("lossguide_plain", {"grow_policy": "lossguide",
+                             "max_leaves": 6}, _narrow_xy),
+        ("lossguide_pack", {"grow_policy": "lossguide",
+                            "max_leaves": 6,
                             "DMLC_BIN_PACK": "1"}, _narrow_xy),
-        ("lossguide_bundle", {"DMLC_GROW_POLICY": "lossguide",
-                              "DMLC_MAX_LEAVES": "6",
+        ("lossguide_bundle", {"grow_policy": "lossguide",
+                              "max_leaves": 6,
                               "DMLC_FEATURE_BUNDLE": "1"}, _bundle_xy),
         ("depthwise_28_features", {}, lambda: _wide_xy(28)),
         ("depthwise_31_features", {}, lambda: _wide_xy(31)),
-        ("lossguide_28_features", {"DMLC_GROW_POLICY": "lossguide",
-                                   "DMLC_MAX_LEAVES": "6"},
+        ("lossguide_28_features", {"grow_policy": "lossguide",
+                                   "max_leaves": 6},
          lambda: _wide_xy(28)),
     ]
 
@@ -98,10 +99,12 @@ class TestPallasFitEqualsSegmentFit:
     def test_pallas_tree_is_the_segment_tree(self, name, env, mk,
                                              monkeypatch):
         X, y = mk()
-        for k, v in env.items():
-            monkeypatch.setenv(k, v)
-        want, _ = _tree_bytes("segment", X, y)
-        got, m = _tree_bytes("pallas", X, y)
+        # DMLC_* names are the environment's, the others the Parameter's
+        kw = {k: v for k, v in env.items() if not k.startswith("DMLC_")}
+        for k in env.keys() - kw.keys():
+            monkeypatch.setenv(k, env[k])
+        want, _ = _tree_bytes("segment", X, y, **kw)
+        got, m = _tree_bytes("pallas", X, y, **kw)
         assert got == want
         assert np.asarray(m.trees[0]["gain"]).any()     # a grown tree
         if "DMLC_BIN_PACK" in env or "DMLC_FEATURE_BUNDLE" in env:

@@ -203,15 +203,17 @@ def test_a_deep_fit_grows_the_trees_of_plain_indexing(depth, nan_share,
                           _bits(plain.predict(X[:256])))
 
 
-def test_a_lossguide_fit_at_depth_6_looks_128_entries_up(monkeypatch):
-    monkeypatch.setenv("DMLC_GROW_POLICY", "lossguide")
+def test_a_lossguide_fit_at_depth_6_looks_127_entries_up(monkeypatch):
+    """The 64 leaves of a depth-6 budget are a node list of 127 entries:
+    the one lookup of a loss-guide tree, in two pieces."""
     X, y = _data()
-    ours = _fit(6, X, y)
+    ours = _fit(6, X, y, grow_policy="lossguide")
     assert ours.round_plan["grow_policy"] == "lossguide"
     seen = _plain_lookups(monkeypatch)
-    plain = _fit(6, X, y)
-    assert 128 in seen
-    _same_fit(ours, plain, ["feat", "thr", "gain", "leaf"])
+    plain = _fit(6, X, y, grow_policy="lossguide")
+    assert seen and set(seen) == {127}
+    _same_fit(ours, plain, ["feat", "thr", "gain", "left", "right",
+                            "value"])
 
 
 # ----------------------------------------------------------------------
