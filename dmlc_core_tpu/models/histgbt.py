@@ -35,6 +35,7 @@ import os
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 from functools import lru_cache, partial
+from itertools import pairwise
 from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
@@ -61,9 +62,13 @@ from dmlc_core_tpu.ops.histogram import (build_histogram,
                                          hist_feature_dots,
                                          hist_node_blocks,
                                          hist_psum_bytes_per_round,
+                                         hist_tile_rows,
                                          pallas_interpret,
+                                         recluster_points,
+                                         recluster_rows,
                                          resolve_hist_method,
-                                         select_feature_bins)
+                                         select_feature_bins,
+                                         tile_aligned, tile_liveness)
 from dmlc_core_tpu.ops.quantile import (apply_bins, apply_bins_missing,
                                         apply_bins_t, compute_cuts,
                                         mesh_nan_scan, nan_scan)
@@ -217,6 +222,11 @@ class _RoundPlan(NamedTuple):
     #: where a node's split sits in the ONE int32 the packed forms look
     #: up; ``None`` where every level reads the unpacked tables
     route_word: Optional[SplitWord] = None
+    #: the expansions before which a loss-guide tree re-orders a device's
+    #: rows by leaf, after which its builds skip the tiles that hold none
+    #: of their node (``ops.recluster_points``); ``()``: one scan, every
+    #: build over all rows
+    recluster_at: Tuple[int, ...] = ()
 
     def describe(self) -> Dict[str, Any]:
         """The JSON-serialisable record left on ``HistGBT.round_plan``."""
@@ -255,7 +265,8 @@ class _RoundPlan(NamedTuple):
             **(self.rank.describe() if self.rank is not None else {}),
             # a loss-guide tree: one single-node build an expansion
             **({"max_leaves": self.max_leaves,
-                "expansions": self.max_leaves - 1}
+                "expansions": self.max_leaves - 1,
+                "recluster_at": list(self.recluster_at)}
                if self.grow_policy == "lossguide" else {}),
         }
 
@@ -1202,6 +1213,7 @@ class HistGBT(_ExternalMemoryEngine):
 
         t0 = get_time()
         chunks: List[Any] = []
+        hist_tiles = None
         done = 0
         while done < p.n_trees:
             fn = kfn if p.n_trees - done >= K else rem_fn
@@ -1209,6 +1221,9 @@ class HistGBT(_ExternalMemoryEngine):
             with span("dmlc.fit.dispatch", rounds=k_now,
                       first_round=round_offset + done):
                 preds, trees_k = run(fn, preds, done)
+            # a clustered leaf-wise tree says how many tiles its builds
+            # computed: a record beside the node list, not part of it
+            hist_tiles = trees_k.pop("hist_tiles", None)
             chunks.append(trees_k)        # stacked [k, ...] device arrays
             done += k_now
             if eval_every and done % eval_every == 0:
@@ -1248,6 +1263,12 @@ class HistGBT(_ExternalMemoryEngine):
                 chunk_callback(*self.last_chunk_times[-1])
             self.trees.extend(
                 {key: t_np[key][i] for key in t_np} for i in range(k))
+        if hist_tiles is not None:
+            # rows a device's kernels computed per build of the LAST tree
+            builds = int(np.sum(self.trees[-1]["left"] >= 0)) + 1
+            self.round_plan["hist_rows_per_build"] = int(
+                int(np.asarray(hist_tiles)[-1]) * hist_tile_rows()
+                // (plan.mesh_devices * builds))
         with span("dmlc.fit.sync"):
             np.asarray(preds[:1])         # real sync before stopping timer
         self.last_fit_seconds = get_time() - t0
@@ -2520,12 +2541,24 @@ class HistGBT(_ExternalMemoryEngine):
                                   _class_batches(p.num_class)[1],
                                   whole=packed)
                 for m, nb in zip(methods, builds)),
-            route_forms=route, route_word=route_word)
+            route_forms=route, route_word=route_word,
+            # rows clustered by leaf + tile-skipping builds: ONE tree a
+            # round (K trees would need K orderings of the matrix) on the
+            # plain matrix through the Pallas kernel, rows free to move
+            # (deterministic blocks fold FIXED row ranges), and enough
+            # leaves and rows a device, on few enough features, to pay
+            # for the sort
+            recluster_at=(
+                recluster_points(leaves, n_rows // dsize, n_features)
+                if lossguide and methods[0] == "pallas"
+                and p.num_class <= 1 and layout is None and not det_blocks
+                else ()))
         self.round_plan = plan.describe()
         if lossguide:
-            # rows a device hands each build: all of them today, the other
-            # leaves masked out.  A record of the shapes, not of the plan
-            # (one jitted program serves every row count)
+            # rows a device hands each build: the bound before a fit (all
+            # of them, the other leaves masked out) — a fit that clusters
+            # its rows writes what its last tree's kernels computed
+            # (``_boost_rounds``).  A record, not of the plan
             self.round_plan["hist_rows_per_build"] = n_rows // dsize
         return plan
 
@@ -2645,6 +2678,7 @@ class HistGBT(_ExternalMemoryEngine):
         # untouched.  None traces the exact seed program.
         layout = plan.layout
         lossguide = plan.grow_policy == "lossguide"
+        recluster_at = plan.recluster_at
         if lossguide:
             L_leaves = plan.max_leaves
             # the open-leaf histogram pool is the policy's working set:
@@ -2945,31 +2979,52 @@ class HistGBT(_ExternalMemoryEngine):
             A CLASS axis (``g`` / ``h`` ``[K, n]``): the K trees expand
             in step — each class its own queue, leaf and split, the
             pieces batched (``per_class``) — and an expansion's K
-            builds are ONE ``build_histogram`` of K classes."""
+            builds are ONE ``build_histogram`` of K classes.
+
+            CLUSTERED rows (``plan.recluster_at``, where
+            ``HistGBT._round_plan`` engages it): the matrix and the row
+            vectors are made tile-aligned ONCE (``ops.tile_aligned``),
+            the expansion scan is cut at the plan's points, and at each
+            the device's rows are re-ordered by the leaf they sit in
+            (``recluster``); every build says which tiles hold a row of
+            its node (``ops.tile_liveness``) and the kernel skips the
+            others, so a build after the point costs what its leaf's
+            cluster costs.  The queue, the order of expansions, the
+            built (left) child, the gates and each node's rows are what
+            they were: only the order in which a histogram's float32
+            tile sums are added changes.  The per-row delta comes back
+            in input order.  The tree carries ``hist_tiles``, the tiles
+            its builds computed, summed over the devices, which
+            ``_boost_rounds`` takes out again."""
             per_class = _per_class(g)
             n_local = int(bins_tl.shape[1])
             rows = row_blocks(n_local)
             n_blk = len(rows)
             M = 2 * L_leaves - 1                  # the node list's entries
             # the expansion's device phases (doc/observability.md)
-            in_pick, in_hist, in_settle = (
+            in_pick, in_hist, in_settle, in_recluster = (
                 jax.named_scope(f"dmlc.round.expand.{phase}")
-                for phase in ("pick", "hist", "settle"))
+                for phase in ("pick", "hist", "settle", "recluster"))
+            clustered = bool(recluster_at)
 
-            def build_one(node_build):
+            def build_one(data, node_build, tile_live=None):
                 """Histogram of the single node whose rows have
-                ``node_build == 0`` (everything else -1), synced."""
+                ``node_build == 0`` (everything else -1) over the rows
+                ``data`` = (bins, g, h), synced."""
+                bins_x, g_x, h_x = data
                 if n_blk:
                     hh = _tree_fold([
                         build_histogram(
-                            bins_tl[sl], node_build[sl], g[sl], h[sl],
+                            bins_x[sl], node_build[sl], g_x[sl], h_x[sl],
                             1, B, methods[0], transposed=True,
                             layout=layout)
                         for sl in rows])
                 else:
-                    hh = build_histogram(bins_tl, node_build, g, h, 1, B,
-                                         methods[0], transposed=True,
-                                         layout=layout)
+                    # clustered: tile-aligned operands, dead tiles skipped
+                    how = ({"tile_live": tile_live, "n_features": n_features}
+                           if clustered else {"layout": layout})
+                    hh = build_histogram(bins_x, node_build, g_x, h_x, 1, B,
+                                         methods[0], transposed=True, **how)
                 return hist_sync(hh, n_blk)      # [(K,) 2, 1, S, Bs]
 
             def eval_nodes(hist_st):
@@ -2983,16 +3038,16 @@ class HistGBT(_ExternalMemoryEngine):
             tabs = (_bl.layout_tables(layout) if layout is not None
                     else None)
 
-            def row_bins_of(fsel):
+            def row_bins_of(bins_x, fsel):
                 """Bins of ONE (traced-scalar) original feature for every
                 local row — the expansion descend's read."""
                 if layout is None:
-                    row = jax.lax.dynamic_slice_in_dim(bins_tl, fsel, 1, 0)
+                    row = jax.lax.dynamic_slice_in_dim(bins_x, fsel, 1, 0)
                     return row[0].astype(jnp.int32)
                 src_f = jnp.asarray(tabs["src"][tabs["owner"]])
                 nib_f = jnp.asarray(tabs["nib"][tabs["owner"]])
                 row = jax.lax.dynamic_slice_in_dim(
-                    bins_tl, src_f[fsel], 1, 0)[0].astype(jnp.int32)
+                    bins_x, src_f[fsel], 1, 0)[0].astype(jnp.int32)
                 nb = nib_f[fsel]
                 v = jnp.where(nb == 1, row >> 4,
                               jnp.where(nb == 0, row & 15, row))
@@ -3040,11 +3095,11 @@ class HistGBT(_ExternalMemoryEngine):
                     "right": jnp.full(M, -1, jnp.int32),
                 }
 
-            def pick(node, st, k):
+            def pick(node, st, k, bins_x):
                 """Expansion ``k``: the open leaf to split and its rows
                 sent down to nodes ``2k+1`` / ``2k+2``; the left child's
-                rows to build, and what :func:`settle` needs of the
-                choice."""
+                rows to build (clustered: and the tiles that hold one),
+                and what :func:`settle` needs of the choice."""
                 # priority queue: best candidate gain among the open
                 # leaves.  A real split always has recorded gain > gamma
                 # (best_split's own split_ok gate), so the > gamma test
@@ -3056,13 +3111,14 @@ class HistGBT(_ExternalMemoryEngine):
                 tsel = st["cand_thr"][hc]
                 lc, rc = 2 * k + 1, 2 * k + 2
                 # descend the expanded leaf's rows on (fsel, tsel)
-                v = row_bins_of(fsel)
+                v = row_bins_of(bins_x, fsel)
                 go_right = v > tsel
                 mine = ok & (node == hc)
                 node = jnp.where(mine, jnp.where(go_right, rc, lc), node)
                 # ONE build: left child only; right = parent − left
                 node_build = jnp.where(mine & ~go_right, 0, -1)
-                return node, node_build, (hc, ok, fsel, tsel, lc, rc)
+                live = tile_liveness(node_build) if clustered else None
+                return node, node_build, live, (hc, ok, fsel, tsel, lc, rc)
 
             def settle(st, picked, left, k):
                 """The expansion's two children into the queue and the
@@ -3104,14 +3160,42 @@ class HistGBT(_ExternalMemoryEngine):
                 put("pool", jnp.where(ok, k + 1, L_leaves), right)
                 return st
 
-            def expand(carry, k):
-                node, st = carry
-                node, node_build, picked = in_pick(per_class(
-                    partial(pick, k=k)))(node, st)
-                left = in_hist(build_one)(node_build)
-                st = in_settle(per_class(partial(settle, k=k)))(
-                    st, picked, left)
-                return (node, st), None
+            def expander(data):
+                """The expansion scan's body over the rows ``data``."""
+                def expand(carry, k):
+                    node, st, tiles = carry
+                    node, node_build, live, picked = in_pick(per_class(
+                        partial(pick, k=k, bins_x=data[0])))(node, st)
+                    left = in_hist(build_one)(data, node_build, live)
+                    st = in_settle(per_class(partial(settle, k=k)))(
+                        st, picked, left)
+                    if clustered:
+                        tiles = in_pick(jnp.add)(tiles, live.sum())
+                    return (node, st, tiles), None
+                return expand
+
+            def recluster(data, node, st, order, at):
+                """The device's rows re-ordered by the node they sit in
+                after ``at`` expansions (ids ``0 .. 2·at``; the id order
+                is the clusters' order), each open leaf's rows by the
+                side they take under its recorded candidate split — the
+                split it gets if it is ever expanded, so its children's
+                rows come out contiguous too.  Stable: equal keys keep
+                their order, and two fits order alike.  ``order`` is
+                each row's position in the input."""
+                bins_x, g_x, h_x = data
+                n_ids = 2 * at + 1
+                feat_sel = table_select(st["cand_feat"][:n_ids], node, n_ids)
+                thr_sel = table_select(st["cand_thr"][:n_ids], node, n_ids)
+                side = select_feature_bins(bins_x, feat_sel) > thr_sel
+                last = jnp.iinfo(jnp.int32).max          # the pad rows'
+                key = jnp.where(node >= 0, 2 * node + side, last)
+                if order is None:
+                    order = jnp.arange(node.shape[0], dtype=jnp.int32)
+                key, bins_x, g_x, h_x, order = recluster_rows(
+                    key, bins_x, n_features, g_x, h_x, order)
+                node = jnp.where(key == last, -1, key >> 1)
+                return (bins_x, g_x, h_x), node, order
 
             def finish(node, st):
                 """The node list and each row's delta."""
@@ -3126,12 +3210,34 @@ class HistGBT(_ExternalMemoryEngine):
             # ---- root ----
             with jax.named_scope("dmlc.round.root"):
                 node = jnp.zeros(g.shape, jnp.int32)     # node-list ids
-                st = per_class(open_root)(build_one(node))
-            (node, st), _ = jax.lax.scan(
-                expand, (node, st),
-                jnp.arange(L_leaves - 1, dtype=jnp.int32))
+                data, live, tiles = (bins_tl, g, h), None, None
+                if clustered:
+                    bins_x, node, g_x, h_x = tile_aligned(bins_tl, node,
+                                                          g, h)
+                    data, live = (bins_x, g_x, h_x), tile_liveness(node)
+                    tiles = live.sum()
+                st = per_class(open_root)(build_one(data, node, live))
+            # the expansions, cut where the rows re-cluster (nowhere: one
+            # scan over the rows as they came)
+            order = None
+            for start, stop in pairwise((0,) + recluster_at
+                                        + (L_leaves - 1,)):
+                if start:
+                    data, node, order = in_recluster(recluster)(
+                        data, node, st, order, start)
+                (node, st, tiles), _ = jax.lax.scan(
+                    expander(data), (node, st, tiles),
+                    jnp.arange(start, stop, dtype=jnp.int32))
+            if clustered:
+                # each row's node back where the row came from
+                node = in_recluster(lambda o, nd: jax.lax.sort(
+                    (o, nd), num_keys=1, is_stable=False)[1][:n_local])(
+                        order, node)
             with jax.named_scope("dmlc.round.leaf"):
-                return per_class(finish)(node, st)
+                tree, delta = per_class(finish)(node, st)
+                if clustered:
+                    tree["hist_tiles"] = jax.lax.psum(tiles, "data")
+                return tree, delta
 
         grow = grow_tree_lossguide if lossguide else grow_tree
 

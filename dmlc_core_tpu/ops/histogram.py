@@ -63,7 +63,9 @@ __all__ = ["build_histogram", "descend_histogram",
            "reference_histogram", "hist_psum_bytes_per_round",
            "bins_bytes_per_round", "leaves_built_per_round",
            "hist_feature_dots", "hist_feature_blocks",
-           "hist_node_blocks", "hist_class_blocks"]
+           "hist_node_blocks", "hist_class_blocks",
+           "RECLUSTER_MIN_ROWS", "recluster_points", "recluster_rows",
+           "hist_tile_rows", "tile_aligned", "tile_liveness"]
 
 
 def leaves_built_per_round(depth: int, grow_policy: str = "depthwise",
@@ -446,6 +448,8 @@ def build_histogram(
     *,
     transposed: bool = False,
     layout=None,
+    tile_live: jax.Array = None,
+    n_features: int = 0,
 ) -> jax.Array:
     """Return ``hist[2, n_nodes, F, n_bins]`` — plane 0 Σgrad, plane 1 Σhess.
 
@@ -472,7 +476,26 @@ def build_histogram(
     win); ``segment`` unpacks to the storage matrix first (exact
     integer nibble extraction, so cell values stay bit-identical to an
     unpacked build — the cross-method parity contract).
+
+    ``tile_live`` (``s32[grid]``, :func:`tile_liveness`) makes the
+    TILE-SKIPPING build of the Pallas kernel: the operands come
+    tile-aligned from :func:`tile_aligned` — ``bins`` ``[Fp, n_pad]`` of
+    which ``n_features`` rows are real, ``node_id`` / ``grad`` /
+    ``hess`` ``[n_pad]`` — nothing is padded again, and a tile whose
+    entry is 0 (none of its rows has ``node_id >= 0``) is neither
+    fetched nor computed (:func:`_hist_pallas_skip`).  The result is
+    ``[2, n_nodes, n_features, n_bins]``.  Without it every call traces
+    what it always traced.
     """
+    if tile_live is not None:
+        CHECK(transposed and layout is None and node_id.ndim == 1
+              and method == "pallas",
+              "tile_live= is the Pallas build of one tree over the plain "
+              "transposed matrix")
+        return _hist_pallas_blocks(bins, node_id, grad, hess, n_nodes,
+                                   n_bins, transposed=True,
+                                   tile_live=tile_live,
+                                   n_features=n_features or bins.shape[0])
     if layout is not None:
         CHECK(transposed, "layout= requires the transposed [F, n] matrix")
         n_bins = layout.sync_bins
@@ -547,7 +570,8 @@ def _hist_class_blocks(bins, node_id, grad, hess, n_nodes, n_bins, *,
 
 
 def _hist_pallas_blocks(bins, node_id, grad, hess, n_nodes, n_bins, *,
-                        transposed, layout=None):
+                        transposed, layout=None, tile_live=None,
+                        n_features=0):
     """:func:`_hist_pallas` over the node blocks of
     :func:`hist_node_blocks` and, inside each, the feature blocks of
     :func:`hist_feature_blocks`.  One block of each (every shape the
@@ -570,26 +594,33 @@ def _hist_pallas_blocks(bins, node_id, grad, hess, n_nodes, n_bins, *,
     nibble-packed layout: cut on nodes like any other build, never on
     features.  A class axis (``node_id`` ``[Kb, n]``: a stacked call
     of :func:`_hist_class_blocks`) rides through: the maps are
-    elementwise and the joins count their axes from the end."""
-    rows = bins.shape[0] if transposed else bins.shape[1]
+    elementwise and the joins count their axes from the end.
+    ``tile_live`` / ``n_features`` (a tile-skipping build,
+    :func:`build_histogram`) ride through to every call: a tile without
+    a row of the build has none of any node block's."""
+    rows = n_features or (bins.shape[0] if transposed else bins.shape[1])
+    skip = ({} if tile_live is None
+            else {"tile_live": tile_live, "n_features": n_features})
     blocks = hist_node_blocks(n_bins, rows, n_nodes,
                               jnp.dtype(bins.dtype).itemsize,
                               whole=layout is not None)
     if len(blocks) == 1:
         return _hist_pallas_fblocks(bins, node_id, grad, hess, n_nodes,
                                     n_bins, transposed=transposed,
-                                    layout=layout)
+                                    layout=layout, **skip)
     in_nblock = jax.named_scope("dmlc.hist.nblock")
     own = in_nblock(lambda lo, hi: jnp.where(
         (node_id >= lo) & (node_id < hi), node_id - lo, -1))
     return in_nblock(jnp.concatenate)(
         [_hist_pallas_fblocks(bins, own(lo, hi), grad, hess, hi - lo,
-                              n_bins, transposed=transposed, layout=layout)
+                              n_bins, transposed=transposed, layout=layout,
+                              **skip)
          for lo, hi in pairwise(accumulate(blocks, initial=0))], axis=-3)
 
 
 def _hist_pallas_fblocks(bins, node_id, grad, hess, n_nodes, n_bins, *,
-                         transposed, layout=None):
+                         transposed, layout=None, tile_live=None,
+                         n_features=0):
     """One node block of :func:`_hist_pallas_blocks` over its feature
     blocks.  One block (every shape the whole-matrix budgets admit, and
     every packed ``layout``) is the plain call and traces nothing else.
@@ -601,24 +632,33 @@ def _hist_pallas_fblocks(bins, node_id, grad, hess, n_nodes, n_bins, *,
     scope ``dmlc.hist.fblock``.  Every call of a build cut on features
     states ``_NESTED_BLOCKS_VMEM`` for itself (a stacked call keeps its
     ``_STACKED_VMEM``): the limit is stated, not used — the kernel is
-    the same kernel."""
+    the same kernel.  A tile-skipping build (``tile_live``: the matrix
+    comes with ``Fp`` rows, ``n_features`` of them real) cuts its slabs
+    to whole groups of 8 rows — the last block takes the pad rows with
+    it — and every block's call takes the same liveness vector."""
     if layout is not None:
         return _hist_pallas(bins, node_id, grad, hess, n_nodes, n_bins,
                             transposed=True, layout=layout)
-    F = bins.shape[0] if transposed else bins.shape[1]
+    F = n_features or (bins.shape[0] if transposed else bins.shape[1])
     blocks = hist_feature_blocks(
         n_bins, F, n_nodes, jnp.dtype(bins.dtype).itemsize,
         n_class=1 if node_id.ndim == 1 else node_id.shape[0])
+    if tile_live is not None:
+        def call(slab, fb, **kw):
+            return _hist_pallas_skip(slab, node_id, grad, hess, tile_live,
+                                     n_nodes, n_bins, fb, **kw)
+    else:
+        def call(slab, fb, **kw):
+            return _hist_pallas(slab, node_id, grad, hess, n_nodes, n_bins,
+                                transposed=transposed, **kw)
     if len(blocks) == 1:
-        return _hist_pallas(bins, node_id, grad, hess, n_nodes, n_bins,
-                            transposed=transposed)
+        return call(bins, F)
     in_fblock = jax.named_scope("dmlc.hist.fblock")
-    slab = in_fblock(lambda lo, hi: (bins[lo:hi] if transposed
-                                     else bins[:, lo:hi]))
+    slab = in_fblock(lambda lo, hi: (
+        bins[lo:-(-hi // 8) * 8] if tile_live is not None
+        else bins[lo:hi] if transposed else bins[:, lo:hi]))
     return in_fblock(jnp.concatenate)(
-        [_hist_pallas(slab(lo, hi), node_id, grad, hess, n_nodes, n_bins,
-                      transposed=transposed,
-                      vmem_limit_bytes=_NESTED_BLOCKS_VMEM)
+        [call(slab(lo, hi), hi - lo, vmem_limit_bytes=_NESTED_BLOCKS_VMEM)
          for lo, hi in pairwise(accumulate(blocks, initial=0))], axis=-2)
 
 
@@ -696,6 +736,33 @@ def _hist_pallas_kernel(bins_ref, node_ref, g_ref, h_ref, out_ref,
     _accum_hist(bins_ref, out_ref, node, g, h,
                 n_nodes=n_nodes, hi=hi, lo=lo, n_rows=n_rows,
                 n_pack_groups=n_pack_groups)
+
+
+def _hist_pallas_skip_kernel(live_ref, src_ref, bins_ref, node_ref, g_ref,
+                             h_ref, out_ref, *, n_nodes, hi, lo, n_rows):
+    """:func:`_hist_pallas_kernel` of one class over tile-aligned
+    operands (:func:`_hist_pallas_skip`), with two scalar-prefetch
+    vectors a grid step reads before its blocks are fetched:
+    ``live_ref[i]`` — whether tile ``i`` holds a
+    row of the build — gates the accumulation, and ``src_ref[i]`` is the
+    tile whose blocks step ``i`` names (its own where it is live, else
+    the live tile before it: the pipeline fetches no block whose index
+    did not change, so a dead step moves no bytes).  A live tile adds
+    what it always added, in the same tile order; a dead one held rows
+    of ``node < 0`` alone and added exact zeros."""
+    del src_ref                              # the index maps read it
+    i = pl.program_id(0)
+
+    @pl.when(i == 0)
+    def _():
+        out_ref[:] = jnp.zeros_like(out_ref)
+
+    @pl.when(live_ref[i] != 0)
+    def _():
+        _accum_hist(bins_ref, out_ref, [node_ref[:].astype(jnp.int32)],
+                    [g_ref[:].astype(jnp.bfloat16)],
+                    [h_ref[:].astype(jnp.bfloat16)],
+                    n_nodes=n_nodes, hi=hi, lo=lo, n_rows=n_rows)
 
 
 def _accum_hist(bins_ref, out_ref, node, g, h, *, n_nodes, hi, lo, n_rows,
@@ -868,7 +935,8 @@ def _hist_pallas(bins, node_id, grad, hess, n_nodes, n_bins,
     -major as the round program holds them, no re-layout — the out
     block ``[F, Kb·A, lo]`` at the stack's ``lo`` (:func:`_lo_stacked`),
     the result ``[Kb, 2, N, F, B]``.  ``[n]`` is the call of one class
-    it always was, the same program."""
+    it always was, the same program.  (The TILE-SKIPPING call is a
+    program of its own, :func:`_hist_pallas_skip`.)"""
     if transposed:
         F, n = bins.shape
     else:
@@ -934,6 +1002,158 @@ def _hist_pallas(bins, node_id, grad, hess, n_nodes, n_bins,
         # bin pads
         return out.transpose(*range(1, out.ndim - 1), 0,
                              out.ndim - 1)[..., :n_bins]
+
+
+@partial(jax.jit, static_argnums=(5, 6, 7, 8))
+def _hist_pallas_skip(bins, node_id, grad, hess, tile_live, n_nodes, n_bins,
+                      n_features, vmem_limit_bytes=0):
+    """The TILE-SKIPPING call: :func:`_hist_pallas` of one class over
+    operands the caller made tile-aligned (:func:`tile_aligned` —
+    ``bins`` ``[Fp, n_pad]`` with ``n_features`` real rows, the row
+    vectors ``[n_pad]``, pad rows ``node = -1``), so ``dmlc.hist.pad``
+    copies nothing; the tile is ``n_pad / len(tile_live)`` rows.  The
+    grid still has a step a tile, but a step whose ``tile_live`` is 0
+    names the blocks of the live tile before it (of the first live tile
+    where none came before) and skips the accumulation: it costs a grid
+    step, not a fetch of the tile and its dots.  The kernel keeps its
+    name, ``dmlc_hist``.  ``[2, N, n_features, B]``, every live tile's
+    sums what the plain call adds for it, in the same order."""
+    Fp, n_pad = bins.shape
+    grid = tile_live.shape[0]
+    tile_rows = n_pad // grid
+    CHECK(Fp % 8 == 0 and 0 <= Fp - n_features < 8
+          and grid * tile_rows == n_pad and node_id.ndim == 1,
+          "a tile-skipping call takes one class of tile-aligned operands")
+    lo = min(_lo_factor(n_nodes, n_bins), n_bins)
+    hi = -(-n_bins // lo)
+    A = 2 * n_nodes * hi
+    with jax.named_scope("dmlc.hist.pad"):
+        # where each step's blocks come from: its own tile, or the last
+        # live one at or before it (the first live one where none is)
+        last = jax.lax.cummax(jnp.where(
+            tile_live != 0, jnp.arange(grid, dtype=jnp.int32), -1))
+        src = jnp.where(last >= 0, last,
+                        jnp.argmax(tile_live != 0).astype(jnp.int32))
+    rows = pl.BlockSpec((1, tile_rows), lambda i, live, src: (0, src[i]))
+    out = pl.pallas_call(
+        partial(_hist_pallas_skip_kernel, n_nodes=n_nodes, hi=hi, lo=lo,
+                n_rows=n_features),
+        out_shape=jax.ShapeDtypeStruct((Fp, A, lo), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(grid,),
+            in_specs=[pl.BlockSpec((Fp, tile_rows),
+                                   lambda i, live, src: (0, src[i])),
+                      rows, rows, rows],
+            out_specs=pl.BlockSpec((Fp, A, lo),
+                                   lambda i, live, src: (0, 0, 0)),
+        ),
+        interpret=pallas_interpret(),
+        name="dmlc_hist",
+        **({"compiler_params": pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes)} if vmem_limit_bytes else {}),
+    )(tile_live, src, bins, node_id.reshape(1, n_pad),
+      grad.reshape(1, n_pad), hess.reshape(1, n_pad))
+    with jax.named_scope("dmlc.hist.unpack"):
+        out = out[:n_features].reshape(n_features, 2, n_nodes, hi * lo)
+        return out.transpose(1, 2, 0, 3)[..., :n_bins]
+
+
+#: rows a device from which a leaf-wise tree re-clusters its rows
+#: (:func:`recluster_points`).  The re-cluster costs a sort of every row
+#: and saves, per later build, the tiles that hold none of the node's
+#: rows: a cluster has to span many tiles of ``_TILE_ROWS`` rows for
+#: that to be most of them.  2^21 rows a device are 128 tiles (the size
+#: from which ``ops.table_select`` takes its per-row savings too);
+#: measured at 24M rows only (PERF.md section 6, PR 57)
+RECLUSTER_MIN_ROWS = 1 << 21
+#: expansions a re-cluster has to be followed by to be taken: its sort,
+#: side pass and way back to input order are ~0.44 s at 24M x 28 on v5e,
+#: what ~22 later expansions save (a build of all rows 20.6 ms against
+#: ~2 of a cluster's tiles: PERF.md section 5, PR 57), and its program
+#: compiles for minutes; half as much again keeps a margin
+_RECLUSTER_COST_BUILDS = 32
+
+
+#: the widest matrix a tree re-clusters: its rows ride through the sort
+#: four feature rows a ``uint32`` operand (:func:`recluster_rows`), and
+#: a sort COMPILES for ~15 s an operand at 24M rows (PERF.md section 5,
+#: PR 57): 16 words, four minutes
+_RECLUSTER_MAX_FEATURES = 64
+
+
+def recluster_points(max_leaves: int, rows_per_device: int,
+                     n_features: int) -> tuple[int, ...]:
+    """The expansions BEFORE which a leaf-wise tree of ``max_leaves``
+    re-orders a device's rows by the leaf they sit in, from the shapes
+    the program can see; ``()`` where it never does (today's one scan
+    over all rows).  One point, ``s = sqrt(max_leaves - 1) / 2``,
+    rounded: the ``s`` builds before it sweep all rows and each of the
+    ``max_leaves - 1 - s`` after it the tiles of its leaf's cluster, a
+    share of the rows that falls with ``s``, so the cost has a minimum
+    in ``s`` that grows like a root of the budget.  At 255 leaves on
+    24M x 28 (v5e, seconds a round; PERF.md section 5, PR 57)::
+
+        s             8       16      32      (8, 64)
+        a round     1.623   1.682   1.845     1.777     (6.893 unclustered)
+
+    two points re-sort for less than the second sort costs."""
+    s = max(int((max_leaves - 1) ** 0.5 / 2 + 0.5), 1)
+    if (rows_per_device < RECLUSTER_MIN_ROWS
+            or n_features > _RECLUSTER_MAX_FEATURES
+            or max_leaves - 1 - s <= _RECLUSTER_COST_BUILDS):
+        return ()
+    return (s,)
+
+
+def tile_aligned(bins_t, node_id, grad, hess):
+    """The operands of a tile-skipping build (:func:`build_histogram`'s
+    ``tile_live``), made once: the ``[F, n]`` matrix padded to
+    ``[Fp, n_pad]`` (features to whole groups of 8, rows to whole tiles
+    of ``_TILE_ROWS``) and the row vectors to ``[n_pad]``, pad rows
+    ``node = -1`` — what :func:`_hist_pallas` pads on every call."""
+    F, n = bins_t.shape
+    pad = (-n) % _TILE_ROWS
+    with jax.named_scope("dmlc.hist.pad"):
+        return (jnp.pad(bins_t, ((0, (-F) % 8), (0, pad))),
+                jnp.pad(node_id, (0, pad), constant_values=-1),
+                jnp.pad(grad, (0, pad)), jnp.pad(hess, (0, pad)))
+
+
+def hist_tile_rows() -> int:
+    """Rows of one tile of the Pallas kernels' row grid (what a count of
+    computed tiles is multiplied by)."""
+    return _TILE_ROWS
+
+
+def recluster_rows(key, bins_t, n_features, *rows_):
+    """The tile-aligned rows of one device in the STABLE order of
+    ``key`` (``s32[n_pad]``): ``(key, bins_t, *rows_)`` re-ordered alike.
+    ONE ``lax.sort`` of one key with everything else as operands — the
+    ``[Fp, n_pad]`` uint8 matrix rides as ``ceil(n_features / 4)``
+    uint32 words a row, four feature rows a word (a sort's price is its
+    operands: PERF.md section 5, PR 57); no per-row gather.  The pad
+    features' rows come back zero."""
+    Fp, n = bins_t.shape
+    n_words = -(-n_features // 4)
+    # row by row: a ``reshape`` of the uint8 matrix to ``[W, 4, n]``
+    # does the same and compiles for minutes at 24M rows
+    rows = [bins_t[i].astype(jnp.uint32) for i in range(4 * n_words)]
+    words = [rows[i] | (rows[i + 1] << 8) | (rows[i + 2] << 16)
+             | (rows[i + 3] << 24) for i in range(0, 4 * n_words, 4)]
+    out = jax.lax.sort((key, *words, *rows_), num_keys=1, is_stable=True)
+    rows = [((w >> s) & 255).astype(jnp.uint8)
+            for w in out[1:1 + n_words] for s in (0, 8, 16, 24)]
+    rows += [jnp.zeros_like(rows[0])] * (Fp - len(rows))
+    return (out[0], jnp.stack(rows), *out[1 + n_words:])
+
+
+def tile_liveness(node_build):
+    """``s32[grid]``: 1 for each tile of ``_TILE_ROWS`` rows of a
+    tile-aligned ``node_build`` that holds a row of the build
+    (``>= 0``)."""
+    return (node_build >= 0).reshape(-1, _TILE_ROWS).any(axis=1).astype(
+        jnp.int32)
 
 
 def descend_histogram(

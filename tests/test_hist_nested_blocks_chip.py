@@ -13,6 +13,7 @@ since PR 51 every call of a build cut on features does.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -117,26 +118,37 @@ def test_every_call_of_a_build_cut_on_features_states_a_limit(monkeypatch):
     assert H._NESTED_BLOCKS_VMEM > 16 << 20
 
 
-def test_the_leaf_wise_round_program_compiles(chip_mesh, monkeypatch):
+@pytest.mark.parametrize("rows,points", [
+    (4 * H._TILE_ROWS + 640, []),     # few rows: today's one scan
+    (24_000_000, [8]),                # the leaf-wise cell's: clustered
+])
+def test_the_leaf_wise_round_program_compiles(chip_mesh, monkeypatch, rows,
+                                              points):
     """ISSUE 56: what no TPU compiler had seen — a scan of 254 expansions
     with the 255-slot histogram pool in its carry, a ``dynamic_slice`` of
     one feature's row of the ``[F, n]`` bins an expansion, the node list's
     one lookup of 509 entries — at the leaf-wise cell's width and budget
-    (the rows cut: they change no program but its sizes)."""
+    (the rows cut: they change no program but its sizes).  ISSUE 57: at
+    the cell's own 24M rows the plan CLUSTERS the rows — the operands
+    padded once, two scans around the re-cluster's sort, every build the
+    scalar-prefetch kernel — and that program compiles and fits the
+    chip too."""
     from jax.experimental.compilation_cache import compilation_cache as cc
 
     monkeypatch.setattr(H, "pallas_interpret", lambda: False)
     monkeypatch.setattr(G, "pallas_interpret", lambda: False)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(G, "_ROUND_FN_CACHE", {})
-    n, F = 4 * H._TILE_ROWS + 640, 28        # not a multiple of the tile
+    n, F = rows, 28                          # not a multiple of the tile
     model = HistGBT(n_trees=5, mesh=chip_mesh, grow_policy="lossguide",
                     max_leaves=255, max_depth=0, n_bins=256,
                     learning_rate=0.1, min_child_weight=100.0)
     plan = model._round_plan(F, n)
     assert model.round_plan["hist_method"] == ["pallas"]
+    # (rows a build is handed: before a fit the bound, all of them)
     assert (model.round_plan["max_leaves"], model.round_plan["expansions"],
-            model.round_plan["hist_rows_per_build"]) == (255, 254, n)
+            model.round_plan["hist_rows_per_build"],
+            model.round_plan["recluster_at"]) == (255, 254, n, points)
     mat = NamedSharding(chip_mesh, P(None, "data"))
     row = NamedSharding(chip_mesh, P("data"))
     args = (jax.ShapeDtypeStruct((F, n), np.uint8, sharding=mat),) + tuple(
@@ -151,10 +163,22 @@ def test_the_leaf_wise_round_program_compiles(chip_mesh, monkeypatch):
         jax.config.update("jax_enable_compilation_cache", was)
         cc.reset_cache()
     text = compiled.as_text()
-    # two kernel calls in the program: the root's and the expansion's
-    assert text.count("tpu_custom_call") >= 2
+    # the kernel calls of the program: the root's and one a scan
+    assert text.count("tpu_custom_call") >= 2 + len(points)
     for scope in ("dmlc.round.root", "dmlc.round.expand.pick",
                   "dmlc.round.expand.hist", "dmlc.round.expand.settle"):
         assert scope in text, scope
     # five trees of 509 entries come back
     assert "s32[5,509]" in text and "f32[5,509]" in text
+    if points:
+        # the re-cluster and the way back sort; nothing pads the matrix
+        # inside a scan (the one pad of the tree is the root's)
+        assert "dmlc.round.expand.recluster" in text
+        assert text.count(" sort(") >= 2
+        assert "dmlc.round.root/dmlc.hist.pad/jit(_pad)/pad" in text
+        assert not re.search(
+            r'dmlc\.round\.expand\.hist/[^"]*dmlc\.hist\.pad/jit\(_pad\)', text)
+        mem = compiled.memory_analysis()
+        # the sorted copies and the sort's temporaries beside the handle:
+        # well inside a 16 GB chip
+        assert mem.temp_size_in_bytes < 8 << 30

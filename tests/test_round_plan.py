@@ -198,9 +198,12 @@ def test_round_plan_record_is_the_plans_json_view():
     assert lg_plan == lg._round_plan(F, 1280)
     lg._round_plan(F, 640)
     assert set(lg.round_plan) - set(m.round_plan) == {
-        "max_leaves", "expansions", "hist_rows_per_build"}
+        "max_leaves", "expansions", "hist_rows_per_build", "recluster_at"}
+    # (before a fit the rows a build is handed are the bound: all of
+    # them; a tree this small never re-clusters its rows, ISSUE 57)
     assert (lg.round_plan["max_leaves"], lg.round_plan["expansions"],
-            lg.round_plan["hist_rows_per_build"]) == (5, 4, 640)
+            lg.round_plan["hist_rows_per_build"],
+            lg.round_plan["recluster_at"]) == (5, 4, 640, [])
     # a plan made without rows is a small fit's: route's unpacked tables
     assert m.round_plan["route_lookups"] == {
         "form": "pieces", "packed": False, "chained_entries": 0}
