@@ -11,8 +11,9 @@ vectorized over [nodes, features, bins] on device.
 
 from __future__ import annotations
 
+import contextlib
 from functools import partial
-from typing import Optional
+from typing import Optional, Sequence
 
 import numpy as np
 
@@ -131,7 +132,10 @@ def _maybe_l1(G, alpha: float):
 def _make_best_split(B: int, lam: float, gamma: float, mcw: float,
                      with_child_sums: bool = False,
                      mono: Optional[np.ndarray] = None,
-                     missing: bool = False, alpha: float = 0.0):
+                     missing: bool = False, alpha: float = 0.0,
+                     cat_bins: Optional[Sequence[int]] = None,
+                     max_cat_to_onehot: int = 4,
+                     max_cat_threshold: int = 64):
     """Greedy per-node split chooser over a gradient histogram.
 
     hist [2,N,F,B] → (feat [N], thr [N], split_gain [N]); degenerate
@@ -172,16 +176,106 @@ def _make_best_split(B: int, lam: float, gamma: float, mcw: float,
     as ``dir`` (1 = missing left), returned between thr and gain.
     Degenerate nodes keep thr = B-1 / dir = 1: every row, missing
     included, goes left.
+
+    ``cat_bins`` ([F] ints, ``c_f`` > 0 for a CATEGORICAL feature: the
+    bins ``0..c_f-1`` of its category→bin table that hold a training
+    row, ``ops.quantile.cat_tables``'s ``used``; 0 for a numeric one;
+    exclusive with ``mono`` and ``missing``) makes such a feature's
+    split a PARTITION of its bins (LightGBM's and XGBoost's rule, Fisher
+    1958): per node the ``c_f`` bins are ordered by ``G_k / (H_k +
+    lambda)``, ascending and stable (a bin that is empty in the node
+    sits at 0 among the others, ties by bin id), and the candidates are
+    the ``c_f - 1`` cuts of that order that leave at most
+    ``max_cat_threshold`` bins on one side — a prefix of 1..T bins from
+    either end; the SET is that side, and it goes LEFT.  A feature of
+    ``c_f <= max_cat_to_onehot`` bins offers each single bin against the
+    rest instead.  Candidate ``j`` of a categorical feature stands where
+    threshold ``j`` of a numeric one does — the gain formula, ``gamma``,
+    ``min_child_weight``, ``reg_alpha``, the feature mask and the ONE
+    ``argmax`` over ``[F x (B-1)]`` (ties to the lower flat index) are
+    shared, with its left sums the set's.  ``best_split`` then returns,
+    after ``thr``, the chosen split's left set ``[N, B]`` (bool) for
+    EVERY node — a numeric split's is its bins ``<= thr``, a degenerate
+    node's all bins — and a categorical node's ``thr`` is its set's
+    size less one (never ``B-1``: that marks the degenerate node).  Its
+    ``scope=`` keyword names the device scope of the sort and the scan
+    (``dmlc.round.L<d>.split.cat``).
     """
     CHECK(mono is None or not missing,
           "monotone constraints are not supported with missing=True "
           "(the constrained-gain branch has no missing-direction form)")
+    cat = cat_bins is not None and any(int(c) > 0 for c in cat_bins)
+    if cat:
+        CHECK(mono is None and not missing,
+              "categorical features have no constrained or "
+              "missing-direction scan")
+        c_all = np.asarray([int(c) for c in cat_bins], np.int64)
+        cat_idx = np.flatnonzero(c_all > 0)
+        c_f = c_all[cat_idx][:, None]                # [Fc, 1]
+        T = int(max_cat_threshold)
+        slot = np.arange(B - 1)[None, :]             # candidate j: m = j+1
+        one_hot = np.broadcast_to(c_f <= max_cat_to_onehot,
+                                  (len(cat_idx), B - 1))
+        low = np.broadcast_to(slot + 1 <= T, one_hot.shape)
+        valid_cat = np.where(
+            one_hot, (slot < c_f) & (c_f >= 2),
+            (slot + 1 <= c_f - 1) & (low | (c_f - (slot + 1) <= T)))
+        valid_all = np.ones((len(c_all), B - 1), bool)
+        valid_all[cat_idx] = valid_cat
+        # per feature, for the chosen split: its place among the
+        # categorical ones, its bins, whether it offers single bins
+        cat_pos = np.zeros(len(c_all), np.int32)
+        cat_pos[cat_idx] = np.arange(len(cat_idx))
+        used_bins = np.arange(B)[None, :] < c_f      # [Fc, B]
 
-    def best_split(hist, feat_mask=None, bounds=None):
+    def cat_left_sums(g, h):
+        """Per node and categorical feature the bins' order and, at
+        candidate ``j``, the left SET's sums (slot ``B-1``: the node's)."""
+        gc, hc = g[:, cat_idx], h[:, cat_idx]        # [N, Fc, B]
+        key = jnp.where(used_bins[None], gc / (hc + lam), jnp.inf)
+        order = jnp.argsort(key, axis=-1, stable=True)
+
+        def set_sums(v):
+            vs = jnp.take_along_axis(v, order, axis=-1)
+            cs = jnp.cumsum(vs, axis=-1)
+            tot = cs[..., -1:]
+            left = jnp.where(one_hot[None], vs[..., :-1],
+                             jnp.where(low[None], cs[..., :-1],
+                                       tot - cs[..., :-1]))
+            return jnp.concatenate([left, tot], axis=-1)
+
+        return order, set_sums(gc), set_sums(hc)
+
+    def cat_left_set(order, feat, thr, split_ok):
+        """The chosen split's left set [N, B] and its recorded ``thr``."""
+        pos = jnp.asarray(cat_pos)[feat]             # [N]
+        is_cat = jnp.asarray(c_all > 0)[feat] & split_ok
+        c_n = jnp.asarray(c_all.astype(np.int32))[feat][:, None]
+        hot = c_n <= max_cat_to_onehot
+        place = jnp.arange(B, dtype=jnp.int32)[None, :]
+        j = thr[:, None]
+        in_set = jnp.where(hot, place == j,
+                           jnp.where(j + 1 <= T, place <= j,
+                                     (place > j) & (place < c_n)))
+        order_n = jnp.take_along_axis(order, pos[:, None, None],
+                                      axis=1)[:, 0]  # [N, B]
+        member = jnp.zeros(in_set.shape, bool).at[
+            jnp.arange(in_set.shape[0])[:, None], order_n].set(in_set)
+        size = jnp.sum(member, axis=1, dtype=jnp.int32)
+        return (jnp.where(is_cat[:, None], member, place <= thr[:, None]),
+                jnp.where(is_cat, size - 1, thr))
+
+    def best_split(hist, feat_mask=None, bounds=None,
+                   scope=contextlib.nullcontext):
         g = hist[0]
         h = hist[1]
         cg = jnp.cumsum(g, axis=-1)                  # [N,F,B] left-incl. sums
         ch = jnp.cumsum(h, axis=-1)
+        if cat:
+            with scope():
+                order, cg_cat, ch_cat = cat_left_sums(g, h)
+                cg = cg.at[:, cat_idx].set(cg_cat)
+                ch = ch.at[:, cat_idx].set(ch_cat)
         gl = cg[..., :-1]                            # [N,F,B-1] left: bin ≤ b
         hl = ch[..., :-1]
         gt = cg[..., -1:]                            # [N,F,1]
@@ -248,6 +342,8 @@ def _make_best_split(B: int, lam: float, gamma: float, mcw: float,
             gain = jnp.where(ok, gain, -jnp.inf)
         if feat_mask is not None:                    # colsample: [F] bool
             gain = jnp.where(feat_mask[None, :, None], gain, -jnp.inf)
+        if cat:                          # the cuts a categorical feature has
+            gain = jnp.where(valid_all[None], gain, -jnp.inf)
         flat = gain.reshape(gain.shape[0], -1)       # [N, F*(B-1)]
         best = jnp.argmax(flat, axis=1)
         best_gain = jnp.take_along_axis(flat, best[:, None], axis=1)[:, 0]
@@ -264,12 +360,18 @@ def _make_best_split(B: int, lam: float, gamma: float, mcw: float,
         # XGBoost's reported split gain (0 for degenerate nodes) — kept in
         # the tree arrays so importance_type="gain" costs nothing extra
         split_gain = jnp.where(split_ok, 0.5 * best_gain, 0.0)
+        slot_thr = thr                   # (a set's sums stand at its slot)
+        if cat:
+            with scope():
+                left_set, thr = cat_left_set(order, feat, slot_thr, split_ok)
+            if not with_child_sums:
+                return feat, thr, left_set, split_gain
         if not with_child_sums:
             return ((feat, thr, dirv, split_gain) if missing
                     else (feat, thr, split_gain))
         N, F = g.shape[0], g.shape[1]
         n_idx = jnp.arange(N, dtype=jnp.int32)
-        flat_idx = (n_idx * F + feat) * B + thr
+        flat_idx = (n_idx * F + feat) * B + slot_thr
         lg = cg.reshape(-1)[flat_idx]                # left-child sums [N]
         lh = ch.reshape(-1)[flat_idx]
         if missing:
@@ -286,6 +388,8 @@ def _make_best_split(B: int, lam: float, gamma: float, mcw: float,
         child_h = jnp.stack([lh, th_ - lh], axis=1).reshape(2 * N)
         if missing:
             return feat, thr, dirv, split_gain, child_g, child_h
+        if cat:
+            return feat, thr, left_set, split_gain, child_g, child_h
         return feat, thr, split_gain, child_g, child_h
 
     return best_split

@@ -69,11 +69,16 @@ from dmlc_core_tpu.ops.histogram import (build_histogram,
                                          resolve_hist_method,
                                          select_feature_bins,
                                          tile_aligned, tile_liveness)
-from dmlc_core_tpu.ops.quantile import (apply_bins, apply_bins_missing,
-                                        apply_bins_t, compute_cuts,
-                                        mesh_nan_scan, nan_scan)
-from dmlc_core_tpu.ops.table_select import (SplitWord, chain_select,
-                                            route_form, table_select)
+from dmlc_core_tpu.ops.quantile import (CAT_MAX_CODE, apply_bins,
+                                        apply_bins_missing, apply_bins_t,
+                                        cat_bins_used, cat_scan, cat_tables,
+                                        compute_cuts, mesh_nan_scan,
+                                        nan_scan)
+from dmlc_core_tpu.ops.table_select import (SET_FLAT_MAX, SET_WORD_BITS,
+                                            SplitWord,
+                                            chain_select, route_form,
+                                            set_select, set_words,
+                                            table_select)
 from dmlc_core_tpu.parallel.mesh import device_count, local_mesh
 from dmlc_core_tpu.models.gbt_objectives import (  # noqa: F401  (re-exports:
     # scripts/tests import these via models.histgbt — keep the names)
@@ -227,6 +232,16 @@ class _RoundPlan(NamedTuple):
     #: of their node (``ops.recluster_points``); ``()``: one scan, every
     #: build over all rows
     recluster_at: Tuple[int, ...] = ()
+    #: per feature the bins of a CATEGORICAL column's table that can hold
+    #: a training row (0: numeric), ``()`` where no column is categorical
+    #: — the split scan's static shapes, and, through the largest, the
+    #: words of a node's set that ``route`` looks up
+    cat_bins: Tuple[int, ...] = ()
+
+    @property
+    def cat_words(self) -> int:
+        """Words of 32 bins that ``route`` reads of a node's set."""
+        return -(-max(self.cat_bins, default=0) // SET_WORD_BITS)
 
     def describe(self) -> Dict[str, Any]:
         """The JSON-serialisable record left on ``HistGBT.round_plan``."""
@@ -268,6 +283,15 @@ class _RoundPlan(NamedTuple):
                 "expansions": self.max_leaves - 1,
                 "recluster_at": list(self.recluster_at)}
                if self.grow_policy == "lossguide" else {}),
+            # categorical columns: how many, their widest table, and how a
+            # node's set is held and looked up
+            **({"cat_features": sum(c > 0 for c in self.cat_bins),
+                "cat_bins_max": max(self.cat_bins),
+                "cat_set_form": {"form": "bit_words",
+                                 "words": self.cat_words,
+                                 "flat_up_to_nodes":
+                                     SET_FLAT_MAX // self.cat_words}}
+               if self.cat_bins else {}),
         }
 
 
@@ -410,13 +434,28 @@ def _put_matrix(X: np.ndarray,
 
 
 @lru_cache(maxsize=32)
-def _bin_chunk_fn(mesh: Mesh, miss_bin: Optional[int]):
+def _bin_chunk_fn(mesh: Mesh, miss_bin: Optional[int],
+                  cat: Optional[tuple] = None):
     """Jitted per-(mesh, mode) chunk binning: digitize a row-sharded
     f32 slab against the cuts, feature-major as the count produces it —
     the streamed ingest's per-chunk kernel (cuts ride as a traced arg so
-    one program serves every fit on the mesh)."""
-    return jax.jit(lambda xc, cuts: apply_bins_t(xc, cuts, miss_bin=miss_bin),
-                   out_shardings=NamedSharding(mesh, P(None, "data")))
+    one program serves every fit on the mesh).  ``cat``: the categorical
+    columns, binned by their tables (None: no such column)."""
+    return jax.jit(
+        lambda xc, cuts: apply_bins_t(xc, cuts, miss_bin=miss_bin, cat=cat),
+        out_shardings=NamedSharding(mesh, P(None, "data")))
+
+
+@lru_cache(maxsize=32)
+def _take_columns_fn(columns: Tuple[int, ...], mesh: Optional[Mesh]):
+    """Jitted ``x[:, columns]`` as column slices laid side by side (a
+    gather would go an element at a time), in a mesh's row shards where
+    ``x`` lies in them: the numeric columns of a table that has
+    categorical ones, for the cut sort."""
+    return jax.jit(
+        lambda x: jnp.stack([x[:, f] for f in columns], axis=1),
+        out_shardings=(None if mesh is None
+                       else NamedSharding(mesh, P("data", None))))
 
 
 @lru_cache(maxsize=64)
@@ -662,6 +701,19 @@ class HistGBTParam(Parameter):
     hist_method = field(str, default="auto",
                         enum=["auto", "segment", "pallas"],
                         description="histogram engine (ops.histogram)")
+    feature_types = field(list, default=(),
+                          description="per-feature 'q' (numeric) or 'c' "
+                                      "(categorical: whole-number codes "
+                                      ">= 0, split by partition); empty = "
+                                      "all numeric (XGBoost's DMatrix "
+                                      "feature_types)")
+    max_cat_to_onehot = field(int, default=4, lower_bound=1,
+                              description="a categorical feature of at "
+                                          "most this many bins offers each "
+                                          "single bin against the rest")
+    max_cat_threshold = field(int, default=64, lower_bound=1,
+                              description="most bins on the smaller side "
+                                          "of a categorical partition")
 
 
 class HistGBT(_ExternalMemoryEngine):
@@ -705,7 +757,15 @@ class HistGBT(_ExternalMemoryEngine):
         # first to wire the cache): see _set_cache_options on why the
         # scopes in the programs depend on it
         _cc.configure()
-        self.cuts: Optional[jax.Array] = None          # [F, n_bins-1]
+        #: [F, n_bins-1]: a numeric feature's cut points; a CATEGORICAL
+        #: feature's (``feature_types``) category→bin table — the code
+        #: of bin k at k, -1 past the named bins (ops.quantile.cat_tables)
+        self.cuts: Optional[jax.Array] = None
+        CHECK(all(t in ("q", "c") for t in self.param.feature_types),
+              f"feature_types holds 'q' (numeric) and 'c' (categorical), "
+              f"got {list(self.param.feature_types)}")
+        #: (cuts, :meth:`_cat_bins` of them): read off the tables once
+        self._cat_bins_of: Optional[tuple] = None
         #: NaN-as-missing mode (XGBoost learned default direction),
         #: auto-detected from the training data: bin n_bins-1 is
         #: reserved for NaN, trees carry a per-node "dir" array, and
@@ -1329,12 +1389,40 @@ class HistGBT(_ExternalMemoryEngine):
         :func:`fold_scale_pos_weight`."""
         return fold_scale_pos_weight(self.param, y, weight)
 
+    def _cat_flags(self, n_features: Optional[int] = None
+                   ) -> Optional[Tuple[bool, ...]]:
+        """Per feature whether it is categorical (``feature_types`` says
+        ``"c"``), or None where no feature is: the ``cat=`` of every
+        binning call, and the gate of everything categorical."""
+        types = self.param.feature_types
+        if "c" not in types:
+            return None
+        if n_features is not None:
+            CHECK_EQ(len(types), n_features,
+                     "feature_types length must equal n_features")
+        return tuple(t == "c" for t in types)
+
+    def _cat_bins(self) -> Tuple[int, ...]:
+        """Per feature the bins a categorical column's rows can hold (0:
+        numeric), off the model's tables — ``()`` where no feature is
+        categorical.  Host: the first call of a fresh model waits for
+        the programs that make the cuts."""
+        cat = self._cat_flags()
+        if cat is None:
+            return ()
+        kept = self._cat_bins_of
+        if kept is None or kept[0] is not self.cuts:
+            kept = self._cat_bins_of = (
+                self.cuts, cat_bins_used(np.asarray(self.cuts), cat))
+        return kept[1]
+
     def _bin_matrix(self, x) -> jax.Array:
         """Digitize against the model's cuts, honoring missing mode
-        (NaN → reserved bin ``n_bins-1``)."""
+        (NaN → reserved bin ``n_bins-1``) and the categorical columns'
+        tables."""
         if self._missing:
             return apply_bins_missing(x, self.cuts, self._miss_bin())
-        return apply_bins(x, self.cuts)
+        return apply_bins(x, self.cuts, cat=self._cat_flags())
 
     def _check_nan_allowed(self, X: np.ndarray, where: str) -> None:
         """A non-missing model given NaN must fail loudly — plain
@@ -1448,6 +1536,9 @@ class HistGBT(_ExternalMemoryEngine):
         the compile cannot start before ingest."""
         if _bin_pack_requested() or _feature_bundle_requested():
             return False
+        if self._cat_flags() is not None:
+            # the categorical columns' tables say how many bins each uses
+            return False
         if self.param.objective.startswith("rank:"):
             # the group table's width buckets shape the gradient stage:
             # known only once make_device_data(qid=) has seen the queries
@@ -1492,7 +1583,7 @@ class HistGBT(_ExternalMemoryEngine):
         cut sort is waiting for, and a 24M × 28 ingest takes 8–12 s
         instead of 4.5 (PERF.md §5–6, PR 28).
         """
-        fn = _bin_chunk_fn(self.mesh, self._nan_bin())
+        fn = _bin_chunk_fn(self.mesh, self._nan_bin(), self._cat_flags())
         if resident is not None:
             with span("dmlc.ingest.stream", slabs=1):
                 with span("dmlc.ingest.bin_dispatch"):
@@ -1644,7 +1735,8 @@ class HistGBT(_ExternalMemoryEngine):
         # a committed f32 piece pins the jit (and its uint8 output) to
         # that piece's device: each chip bins exactly its own row slice
         bin_fn = (None if host_bin
-                  else partial(apply_bins_t, miss_bin=self._nan_bin()))
+                  else partial(apply_bins_t, miss_bin=self._nan_bin(),
+                               cat=self._cat_flags()))
         # a copy a chip (the cuts of a mesh's first ingest are committed
         # to the whole mesh until _stage_device_data has fetched them)
         cuts_dev = (None if host_bin
@@ -1787,6 +1879,11 @@ class HistGBT(_ExternalMemoryEngine):
         from dmlc_core_tpu.ops.quantile import SketchAccumulator
 
         p = self.param
+        CHECK(self._cat_flags() is None,
+              "make_device_data_iter: streamed ingest does not support "
+              "categorical features (feature_types) — the page sketch "
+              "makes cut points, not category→bin tables; use "
+              "make_device_data")
         CHECK(not self._missing,
               "make_device_data_iter: streamed ingest does not support "
               "missing mode (NaN bin) — impute, or fit in-core")
@@ -1985,6 +2082,11 @@ class HistGBT(_ExternalMemoryEngine):
                       f"(objective is {p.objective!r})")
             n, F = X.shape
             sp.set(features=F)
+            cat = self._cat_flags(F)
+            CHECK(cat is None or not _host_bin_requested(),
+                  "DMLC_TPU_BIN_BACKEND=cpu bins against cut points only: "
+                  "a categorical column (feature_types) is binned on the "
+                  "device")
             self._settle_num_class(y)
             weight = self._fold_scale_pos_weight(y, weight)
             # the NaN scan reads the matrix where it lies: one about to
@@ -1996,6 +2098,14 @@ class HistGBT(_ExternalMemoryEngine):
                           on="host"):
                     missing_share = self._settle_missing_mode(
                         *self._nan_facts_host(X), cuts)
+                if cat is not None:
+                    # (where the matrix is put whole the device tells:
+                    # _cuts_and_tables)
+                    codes = X[:, np.flatnonzero(cat)]
+                    self._check_codes(
+                        int(np.count_nonzero((codes < 0)
+                                             | (codes != np.floor(codes)))),
+                        float(codes.max(initial=0.0)))
         # explicit cuts always win (a caller injecting boundaries must
         # not be silently overridden by leftovers from an earlier or
         # failed fit); existing self.cuts are kept only when nothing is
@@ -2056,12 +2166,17 @@ class HistGBT(_ExternalMemoryEngine):
                     facts = self._nan_facts_device(
                         x_cuts, n, self.mesh if sharded else None)
                 missing_share = self._settle_missing_mode(*facts, cuts)
-                self.cuts = compute_cuts(
-                    x_cuts, p.n_bins - 1 if self._missing else p.n_bins,
-                    weight=weight,
-                    allgather_fn=self._maybe_allgather(),
-                    missing=self._missing,
-                    mesh=self.mesh if sharded else None, n_rows=n)
+                if cat is None:
+                    self.cuts = compute_cuts(
+                        x_cuts, p.n_bins - 1 if self._missing else p.n_bins,
+                        weight=weight,
+                        allgather_fn=self._maybe_allgather(),
+                        missing=self._missing,
+                        mesh=self.mesh if sharded else None, n_rows=n)
+                else:
+                    self.cuts = self._cuts_and_tables(
+                        x_cuts, cat, weight, n,
+                        self.mesh if sharded else None)
                 del x_cuts
         sp.set(missing=int(self._missing), missing_share=missing_share,
                nan_scan=scan,
@@ -2188,6 +2303,49 @@ class HistGBT(_ExternalMemoryEngine):
         return out
 
     @staticmethod
+    def _check_codes(bad: int, top: float) -> None:
+        """Hold a table's categorical columns to codes: ``bad`` of their
+        values are negative, fractional or NaN, ``top`` is the largest."""
+        CHECK(bad == 0,
+              f"a categorical column (feature_types 'c') holds whole-number "
+              f"codes >= 0 in float32: {bad} values are negative, "
+              f"fractional or NaN")
+        CHECK(top < CAT_MAX_CODE,
+              f"a categorical column holds codes 0..{CAT_MAX_CODE - 1}, "
+              f"got {top:g}: that many levels are ids, not categories")
+
+    def _cuts_and_tables(self, x_cuts: jax.Array, cat: Tuple[bool, ...],
+                         weight, n: int, mesh: Optional[Mesh]) -> jax.Array:
+        """The cut matrix of a table with categorical columns, from the
+        matrix ``x_cuts`` as it lies on the device for the cut sort (with
+        ``mesh``: in row shards, ``n`` rows of data): a category→bin
+        table for every ``cat`` column, from its codes' counts over ALL
+        rows (``ops.quantile.cat_tables``; host span
+        ``dmlc.ingest.cats``, device scope ``dmlc.cats``), and quantile
+        cuts for the others, whose sort runs over those columns alone.
+        The codes are held to what a code is first: ONE scan where the
+        matrix lies, two ``[Fc]`` vectors back."""
+        p = self.param
+        CHECK(self._maybe_allgather() is None,
+              "categorical columns (feature_types) in a job of several "
+              "worker processes are not supported: the codes' counts are "
+              "summed over one process's mesh")
+        with span("dmlc.ingest.cats", columns=sum(cat)):
+            bad, top = jax.device_get(cat_scan(x_cuts, cat, mesh))
+            self._check_codes(int(bad.sum()), float(top.max()))
+            tables = cat_tables(x_cuts, cat, int(top.max()) + 1, p.n_bins,
+                                n, mesh)
+        cat_idx = np.flatnonzero(cat)
+        num_idx = np.flatnonzero(~np.asarray(cat))
+        cuts = jnp.zeros((len(cat), p.n_bins - 1), jnp.float32
+                         ).at[cat_idx].set(tables)
+        if len(num_idx):
+            cuts = cuts.at[num_idx].set(compute_cuts(
+                _take_columns_fn(tuple(map(int, num_idx)), mesh)(x_cuts),
+                p.n_bins, weight=weight, mesh=mesh, n_rows=n))
+        return cuts
+
+    @staticmethod
     def _nan_facts_host(X: np.ndarray):
         """The three facts :meth:`_settle_missing_mode` decides from, read
         off ``X`` ON THE HOST: the remainder.  A first ingest (no
@@ -2250,6 +2408,11 @@ class HistGBT(_ExternalMemoryEngine):
             # program than its peers (histogram psum divergence)
             has_nan = bool(coll.allreduce(
                 np.asarray([has_nan], np.int32), op="max")[0])
+        CHECK(not has_nan or self._cat_flags() is None,
+              "X contains NaN and feature_types names a categorical "
+              "column: the missing mode has no set scan (a NaN code is no "
+              "category, and the learned direction of a numeric column's "
+              "NaN has no partition form) — impute, or give NaN a code")
         if has_nan and self.cuts is None and cuts is None:
             CHECK(p.n_bins >= 3,
                   "NaN features need n_bins >= 3 (one bin is reserved "
@@ -2496,6 +2659,26 @@ class HistGBT(_ExternalMemoryEngine):
         mat_rows = layout.phys_rows if layout is not None else n_features
         lossguide = p.grow_policy == "lossguide"
         leaves = self._lossguide_leaves() if lossguide else 0
+        cat_bins = self._cat_bins() if self._cat_flags(n_features) else ()
+        if cat_bins:
+            # what a set-valued split has no form for yet, said where the
+            # plan is made, before anything is traced (PERF.md section 7)
+            CHECK(not lossguide,
+                  "grow_policy='lossguide' with categorical features "
+                  "(feature_types) is not supported: the expansion descend "
+                  "routes by threshold — use depthwise")
+            CHECK(not any(int(v) for v in p.monotone_constraints),
+                  "monotone_constraints with categorical features "
+                  "(feature_types) are not supported: a partition has no "
+                  "order for the bound to follow")
+            CHECK(not self._missing,
+                  "categorical features (feature_types) in missing mode "
+                  "are not supported: the set scan has no missing "
+                  "direction")
+            CHECK(layout is None,
+                  "DMLC_BIN_PACK / DMLC_FEATURE_BUNDLE with categorical "
+                  "features (feature_types) are not supported: a packed "
+                  "or bundled bin has no place in a node's set")
         CHECK(lossguide or depth >= 1,
               "max_depth=0 (no depth cap) needs grow_policy='lossguide' "
               "and a max_leaves budget; depthwise growth needs "
@@ -2552,7 +2735,8 @@ class HistGBT(_ExternalMemoryEngine):
                 recluster_points(leaves, n_rows // dsize, n_features)
                 if lossguide and methods[0] == "pallas"
                 and p.num_class <= 1 and layout is None and not det_blocks
-                else ()))
+                else ()),
+            cat_bins=cat_bins)
         self.round_plan = plan.describe()
         if lossguide:
             # rows a device hands each build: the bound before a fit (all
@@ -2608,7 +2792,9 @@ class HistGBT(_ExternalMemoryEngine):
         return (self.mesh, n_rounds, p.max_depth, p.n_bins,
                 p.learning_rate, p.reg_lambda, p.reg_alpha, p.gamma,
                 p.min_child_weight, obj_key, mono, p.subsample,
-                p.colsample_bytree, plan)
+                p.colsample_bytree, plan,
+                (p.max_cat_to_onehot, p.max_cat_threshold)
+                if plan.cat_bins else None)
 
     def _build_round_fn(self, plan: _RoundPlan, n_rounds: int = 1):
         """Jitted shard_map program running ``n_rounds`` boosting rounds
@@ -2652,12 +2838,40 @@ class HistGBT(_ExternalMemoryEngine):
                   "monotone_constraints with reg_alpha is not supported "
                   "(the constrained gain evaluation would need the L1 "
                   "term at the clipped weights) — drop one of the two")
+        # categorical columns: a split is a partition of a column's bins,
+        # a row goes by membership of its bin in its node's set
+        cat_bins = plan.cat_bins
+        cat_how = ({"cat_bins": cat_bins,
+                    "max_cat_to_onehot": p.max_cat_to_onehot,
+                    "max_cat_threshold": p.max_cat_threshold}
+                   if cat_bins else {})
         best_split = _make_best_split(B, lam, gamma, mcw, mono=mono_arr,
-                                      missing=missing, alpha=alpha)
+                                      missing=missing, alpha=alpha,
+                                      **cat_how)
         best_split_leaf = _make_best_split(B, lam, gamma, mcw,
                                            with_child_sums=True,
                                            mono=mono_arr, missing=missing,
-                                           alpha=alpha)
+                                           alpha=alpha, **cat_how)
+        if cat_bins:
+            # per feature: is it categorical, and the bins it uses
+            cat_flag = np.asarray(cat_bins) > 0
+            cat_used = (np.arange(B)[None, :]
+                        < np.asarray(cat_bins)[:, None])
+
+            def route_sets(feat, thr, left_set):
+                """What ``route`` reads of a level's splits: the
+                threshold — ``B-1``, "every bin left", where the node
+                splits a categorical feature — and the node's RIGHT set
+                as bit words, the feature's bins outside the left set
+                (empty where the node splits a numeric feature, or none):
+                a row goes right if its bin is past the one OR in the
+                other."""
+                is_cat = jnp.asarray(cat_flag)[feat]
+                right = (is_cat[:, None] & jnp.asarray(cat_used)[feat]
+                         & ~left_set)
+                return (jnp.where(is_cat, B - 1, thr),
+                        set_words(right)[:, :plan.cat_words])
+
         # snapshot EVERY param the traced closure reads: the program is
         # cached process-wide under the key above, and a later retrace
         # (new input shape) must not see live mutations of some other
@@ -2798,7 +3012,8 @@ class HistGBT(_ExternalMemoryEngine):
                     jnp.stack([lo_r, up_r], 1)], axis=1
                 ).reshape(2 * n_nodes, 2)
 
-            def leaf_tail(feat, thr, dirv, node, gsum, hsum, bounds):
+            def leaf_tail(feat, thr, dirv, node, gsum, hsum, bounds,
+                          right_words=None):
                 """The leaf values and each row's leaf: the final descend
                 (the loop's levels advanced node only up to level
                 depth-1); shared gather-free feature select."""
@@ -2807,6 +3022,10 @@ class HistGBT(_ExternalMemoryEngine):
                 row_bin = select_feature_bins(bins_tl, feat_sel,
                                               layout=layout)             # [n]
                 go_right = row_bin > thr_sel
+                if right_words is not None:
+                    with jax.named_scope("dmlc.round.leaf.route.cat"):
+                        go_right = go_right | set_select(
+                            right_words, node, row_bin, 1 << (depth - 1))
                 if missing:
                     dir_sel = table_select(dirv, node, 1 << (depth - 1))
                     go_right = jnp.where(row_bin == B - 1, dir_sel == 0,
@@ -2821,9 +3040,12 @@ class HistGBT(_ExternalMemoryEngine):
             thrs = []
             gains = []
             dirs = []                                # missing mode only
+            cats = []                      # categorical columns only
             gsum = hsum = None
             prev_hist = None
-            feat = thr = dirv = None
+            # thr_rt, right_words: what route reads of a level's splits
+            # (thr itself, and nothing, where no column is categorical)
+            feat = thr = thr_rt = dirv = right_words = None
             bounds = None
             if mono_arr is not None:
                 bounds = jnp.stack([jnp.full(1, -jnp.inf, jnp.float32),
@@ -2840,6 +3062,13 @@ class HistGBT(_ExternalMemoryEngine):
                 in_route, in_hist, in_sync, in_split = (
                     jax.named_scope(f"dmlc.round.L{level}.{phase}")
                     for phase in ("route", "hist", "sync", "split"))
+                # inside them, the categorical columns' own: the set
+                # lookup of .route, the sort and the scan of .split
+                in_route_cat = jax.named_scope(
+                    f"dmlc.round.L{level}.route.cat")
+                split_how = ({"scope": partial(
+                    jax.named_scope, f"dmlc.round.L{level}.split.cat")}
+                    if cat_bins else {})
                 if level == 0:
                     if n_blk:
                         hist = in_hist(_tree_fold)([
@@ -2860,17 +3089,27 @@ class HistGBT(_ExternalMemoryEngine):
                         select = in_route(per_class(
                             partial(table_select, n_entries=n_prev)))
                         feat_sel = select(feat, node)                 # [n]
-                        thr_sel = select(thr, node)                   # [n]
+                        thr_sel = select(thr_rt, node)                # [n]
                         dir_sel = select(dirv, node) if missing else None
                     else:
                         # ONE packed word a node, looked up once a row
                         lookup = (chain_select if form == "chain"
                                   else table_select)
-                        word = in_route(split_word.pack)(feat, thr, dirv)
+                        word = in_route(split_word.pack)(feat, thr_rt, dirv)
                         word_sel = in_route(per_class(partial(
                             lookup, n_entries=n_prev)))(word, node)   # [n]
                         feat_sel, thr_sel, dir_sel = in_route(
                             split_word.unpack)(word_sel)
+                    go_right = None
+                    if cat_bins:
+                        # the descend's own select, then membership of the
+                        # row's bin in its node's right set
+                        row_bin = in_hist(per_class(partial(
+                            select_feature_bins, bins_tl)))(feat_sel)
+                        go_right = in_route_cat(per_class(
+                            lambda ws, nd, rb, ts: (rb > ts) | set_select(
+                                ws, nd, rb, n_prev)))(
+                                    right_words, node, row_bin, thr_sel)
                     if n_blk:
                         lefts, nodes2 = [], []
                         for sl in rows:
@@ -2881,7 +3120,9 @@ class HistGBT(_ExternalMemoryEngine):
                                 dir_sel=(None if dir_sel is None
                                          else dir_sel[sl]),
                                 miss_bin=B - 1 if missing else None,
-                                layout=layout)
+                                layout=layout,
+                                go_right=(None if go_right is None
+                                          else go_right[sl]))
                             lefts.append(l_j)
                             nodes2.append(nd_j)
                         left = in_hist(_tree_fold)(lefts)
@@ -2892,7 +3133,7 @@ class HistGBT(_ExternalMemoryEngine):
                             n_prev, B, methods[level],
                             dir_sel=dir_sel,
                             miss_bin=B - 1 if missing else None,
-                            layout=layout)
+                            layout=layout, go_right=go_right)
                     left = in_sync(hist_sync)(left, n_blk)
                     hist = in_hist(per_class(with_siblings))(prev_hist,
                                                              left)
@@ -2904,20 +3145,33 @@ class HistGBT(_ExternalMemoryEngine):
                     _bl.unbundle_hist, layout=layout, n_bins=B)))(hist)
                 if mono_arr is not None or level == depth - 1:
                     split = in_split(per_class(
-                        lambda hh, bb: best_split_leaf(hh, feat_mask, bb)))
+                        lambda hh, bb: best_split_leaf(hh, feat_mask, bb,
+                                                       **split_how)))
                     if missing:
                         feat, thr, dirv, gn, cg_, ch_ = split(hist, bounds)
+                    elif cat_bins:
+                        feat, thr, left_set, gn, cg_, ch_ = split(hist,
+                                                                  bounds)
                     else:
                         feat, thr, gn, cg_, ch_ = split(hist, bounds)
                     if level == depth - 1:
                         gsum, hsum = cg_, ch_
                 else:
                     split = in_split(per_class(
-                        lambda hh: best_split(hh, feat_mask)))
+                        lambda hh: best_split(hh, feat_mask, **split_how)))
                     if missing:
                         feat, thr, dirv, gn = split(hist)
+                    elif cat_bins:
+                        feat, thr, left_set, gn = split(hist)
                     else:
                         feat, thr, gn = split(hist)
+                thr_rt = thr
+                if cat_bins:
+                    thr_rt, right_words = in_split(per_class(route_sets))(
+                        feat, thr, left_set)
+                    cats.append(in_split(per_class(lambda m: jnp.pad(
+                        set_words(m), ((0, half - n_nodes), (0, 0)))))(
+                            left_set))
                 # pad per-level arrays to a common width for stacking
                 pad_n = per_class(
                     lambda a: jnp.pad(a, (0, half - n_nodes)))
@@ -2931,7 +3185,8 @@ class HistGBT(_ExternalMemoryEngine):
                                                      feat, thr)
             with jax.named_scope("dmlc.round.leaf"):
                 leaf, node = per_class(leaf_tail)(
-                    feat, thr, dirv, node, gsum, hsum, bounds)
+                    feat, thr_rt, dirv, node, gsum, hsum, bounds,
+                    right_words)
                 # the levels' tables side by side: [(K,) depth, half]
                 tree = {
                     "feat": jnp.stack(feats, axis=-2),
@@ -2941,6 +3196,10 @@ class HistGBT(_ExternalMemoryEngine):
                 }
                 if missing:
                     tree["dir"] = jnp.stack(dirs, axis=-2)
+                if cat_bins:
+                    # every node's LEFT set as bit words (a numeric
+                    # split's: its bins <= thr): [(K,) depth, half, B/32]
+                    tree["cats"] = jnp.stack(cats, axis=-3)
                 return tree, per_class(partial(
                     table_select, n_entries=n_leaf))(leaf, node)
 
@@ -3353,6 +3612,9 @@ class HistGBT(_ExternalMemoryEngine):
         if len(X) == 0:
             return self._no_rows(transform)
         miss_bin = self._miss_bin()
+        # (no categorical column: the call, and its program, as they were)
+        flags = self._cat_flags()
+        cat = () if flags is None else (flags,)
         outs = []
         for lo in range(0, len(X), self._PREDICT_BATCH):
             t_b = get_time()
@@ -3362,7 +3624,7 @@ class HistGBT(_ExternalMemoryEngine):
             with span("dmlc.predict.dispatch", programs=1):
                 out_d = _predict_slab(xb_d, self.cuts, stacked,
                                       p.max_depth, miss_bin, p.base_score,
-                                      transform)
+                                      transform, *cat)
                 del xb_d
             op.set(programs=op.counts["programs"] + 1)
             with span("dmlc.predict.fetch", bytes=out_d.nbytes):
@@ -3460,7 +3722,7 @@ class HistGBT(_ExternalMemoryEngine):
                      if self.param.num_class > 1 else (0, len(use)))
             return np.zeros(shape, np.int32)
         miss = self._miss_bin()
-        dirs = stacked.get("dir")
+        dirs, cats = stacked.get("dir"), stacked.get("cats")
         outs = []
         for lo in range(0, len(X), self._PREDICT_BATCH):
             bins = self._bin_matrix(
@@ -3472,13 +3734,13 @@ class HistGBT(_ExternalMemoryEngine):
                             bins, stacked["feat"][:, c],
                             stacked["thr"][:, c], depth,
                             dirs[:, c] if dirs is not None else None,
-                            miss)
+                            miss, cats[:, c] if cats is not None else None)
                         for c in range(stacked["feat"].shape[1])]
                 outs.append(np.stack([np.asarray(c) for c in cols], axis=2))
             else:
                 outs.append(np.asarray(
                     _leaf_indices(bins, stacked["feat"], stacked["thr"],
-                                  depth, dirs, miss)))
+                                  depth, dirs, miss, cats)))
         return np.concatenate(outs) if len(outs) > 1 else outs[0]
 
     def predict_proba(self, X: np.ndarray,
@@ -3672,7 +3934,10 @@ class HistGBT(_ExternalMemoryEngine):
         with children ``2^(ℓ+1)−1+2n`` / ``+2n+1``; the leaf
         layer sits at level ``max_depth``.  Split conditions print the
         REAL feature threshold (``cuts[f][thr]`` — bins are internal),
-        as ``[f<N>≤x]`` with yes=left.  Degenerate nodes (no profitable
+        as ``[f<N>≤x]`` with yes=left; a split of a categorical feature
+        prints the codes of its left set, ``[f<N>:{3,17,other}]``
+        (``other``: the bin of the rare and the unseen levels).
+        Degenerate nodes (no profitable
         split: every row goes left) print as ``passthrough``.
         ``with_stats`` appends each real split's stored gain;
         ``feature_names`` replaces the ``f<N>`` placeholders (XGBoost's
@@ -3685,9 +3950,19 @@ class HistGBT(_ExternalMemoryEngine):
         def fname(f: int) -> str:
             return feature_names[f] if feature_names is not None else f"f{f}"
         B = self.param.n_bins
+        cat = self._cat_flags() or (False,) * cuts.shape[0]
         lines: List[str] = []
 
-        def dump_one(feat_t, thr_t, gain_t, leaf_t, dir_t=None):
+        def set_codes(f: int, words) -> str:
+            """The codes of feature ``f``'s bins in the set ``words``."""
+            bins_in = [b for b in range(B)
+                       if (int(words[b >> 5]) >> (b & 31)) & 1]
+            return ",".join(
+                "other" if b >= cuts.shape[1] else f"{int(cuts[f][b])}"
+                for b in bins_in if b >= cuts.shape[1] or cuts[f][b] >= 0)
+
+        def dump_one(feat_t, thr_t, gain_t, leaf_t, dir_t=None,
+                     cats_t=None):
             feat_t = np.asarray(feat_t)
             thr_t = np.asarray(thr_t)
             gain_t = None if gain_t is None else np.asarray(gain_t)
@@ -3713,7 +3988,10 @@ class HistGBT(_ExternalMemoryEngine):
                         stat = f",gain={float(gain_t[level][nid]):.6g}"
                     # missing mode's top value threshold (t == #cuts) is
                     # a missingness-only split: every finite value left
-                    cond = (f"{fname(f)}<{cuts[f][t]:.6g}"
+                    cond = (f"{fname(f)}:{{"
+                            f"{set_codes(f, cats_t[level][nid])}}}"
+                            if cat[f] else
+                            f"{fname(f)}<{cuts[f][t]:.6g}"
                             if t < cuts.shape[1] else f"{fname(f)}<inf")
                     lines.append(
                         f"\t{gid}:[{cond}] "
@@ -3761,11 +4039,12 @@ class HistGBT(_ExternalMemoryEngine):
                     dump_one(tree["feat"][c], tree["thr"][c],
                              tree["gain"][c] if "gain" in tree else None,
                              tree["leaf"][c],
-                             tree["dir"][c] if "dir" in tree else None)
+                             tree["dir"][c] if "dir" in tree else None,
+                             tree["cats"][c] if "cats" in tree else None)
             else:
                 lines.append(f"booster[{ti}]:")
                 dump_one(tree["feat"], tree["thr"], tree.get("gain"),
-                         tree["leaf"], tree.get("dir"))
+                         tree["leaf"], tree.get("dir"), tree.get("cats"))
         return "\n".join(lines) + "\n"
 
     def feature_importances(self, importance_type: str = "weight"
@@ -3870,7 +4149,11 @@ def _forest_keys(trees: List[Dict[str, np.ndarray]]) -> Tuple[str, ...]:
           "scored as one forest")
     if node_lists:
         return ("feat", "thr", "left", "right", "value")
-    return ("feat", "thr", "leaf") + (("dir",) if "dir" in trees[0] else ())
+    CHECK(all(("cats" in t) == ("cats" in trees[0]) for t in trees),
+          "the ensemble mixes trees with and without categorical sets (a "
+          "fit continued under other feature_types)")
+    return (("feat", "thr", "leaf") + (("dir",) if "dir" in trees[0] else ())
+            + (("cats",) if "cats" in trees[0] else ()))
 
 
 def _walk_node_list(bins, tree):
@@ -3966,14 +4249,19 @@ def _select(entries, idx):
                    axis=(1, 2))
 
 
-def _descend(bins_t, feats, thrs, dirs, depth: int, miss_bin: int):
+def _descend(bins_t, feats, thrs, dirs, depth: int, miss_bin: int,
+             cats=None):
     """Leaf position [trees, rows/128, 128] of a block's rows in a
     block's trees, level by level and gather-free: at each level select
     the row's node's feature, then the row's bin of that feature, and
     route right on bin > thr; missing rows (bin == miss_bin; only
     produced in missing mode) follow the node's learned direction
     (1 = left).  ``bins_t`` is [F, rows/128, 128] int32, the tables
-    [trees, depth, half]."""
+    [trees, depth, half].  ``cats`` [trees, depth, half, W] (a model with
+    categorical columns) holds EVERY node's left set as bit words — a
+    numeric split's is its bins <= thr — and a row goes left iff its bin
+    is in its node's: word ``bin // 32`` of the node, selected among the
+    level's ``W x nodes``, then the bit."""
     node = jnp.zeros(feats.shape[:1] + bins_t.shape[1:], jnp.int32)
     for level in range(depth):
         def of_node(table):
@@ -3981,7 +4269,14 @@ def _descend(bins_t, feats, thrs, dirs, depth: int, miss_bin: int):
             return _select(table[:, level, :1 << level][:, :, None, None],
                            node)
         row_bin = _select(bins_t[None], of_node(feats))
-        go_right = row_bin > of_node(thrs)
+        if cats is not None:
+            n_words = cats.shape[-1]
+            words = cats[:, level, :1 << level].reshape(cats.shape[0], -1)
+            word = _select(words[:, :, None, None],
+                           node * n_words + (row_bin >> 5))
+            go_right = ((word >> (row_bin & 31)) & 1) == 0
+        else:
+            go_right = row_bin > of_node(thrs)
         if dirs is not None:
             go_right = jnp.where(row_bin == miss_bin, of_node(dirs) == 0,
                                  go_right)
@@ -4034,13 +4329,14 @@ def _tree_blocks(a, tree_blocks: int):
 @jax.named_scope("dmlc.descend")
 def _predict_trees(bins, feats, thrs, leaves, depth: int,
                    base_score: float = 0.0, init=None,
-                   dirs=None, miss_bin: int = -1):
+                   dirs=None, miss_bin: int = -1, cats=None):
     """Sum leaf values over trees: a dense, gather-free descent
     (:func:`_descend`) over blocks of rows × blocks of trees.
 
     ``init`` carries margins from already-applied trees (the incremental
     validation path); otherwise margins start at ``base_score``.
-    ``dirs``/``miss_bin`` enable missing-mode routing.  A row's answer
+    ``dirs``/``miss_bin`` enable missing-mode routing, ``cats`` (every
+    node's left set) routing by membership.  A row's answer
     depends on neither the block sizes nor the other rows of the call.
     """
     n = bins.shape[0]
@@ -4048,17 +4344,17 @@ def _predict_trees(bins, feats, thrs, leaves, depth: int,
         init = jnp.full(n, base_score, jnp.float32)
     row_blocks, rows, tree_blocks = _descend_blocks(n, feats.shape[0],
                                                    bins.shape[1])
-    # (a tree map skips dirs=None)
+    # (a tree map skips dirs=None and cats=None)
     trees = jax.tree.map(partial(_tree_blocks, tree_blocks=tree_blocks),
-                         (feats, thrs, dirs, leaves))
+                         (feats, thrs, dirs, leaves, cats))
 
     def row_block(block):
         bins_t, margin = block
         bins_t = bins_t.astype(jnp.int32)
 
         def tree_block(margin, tree):
-            feat, thr, dirv, leaf = tree
-            node = _descend(bins_t, feat, thr, dirv, depth, miss_bin)
+            feat, thr, dirv, leaf, sets = tree
+            node = _descend(bins_t, feat, thr, dirv, depth, miss_bin, sets)
             vals = _select(leaf[:, :, None, None], node)
             # in tree order, in float32: the summation order of the
             # incremental updates that built the margins
@@ -4082,25 +4378,26 @@ def _add_trees(bins, forest, margin, depth: int, miss_bin: int):
     class axis)."""
     if "left" in forest:               # loss-guide trees: node lists
         return _add_node_lists(bins, forest, margin)
-    dirs = forest.get("dir")
+    dirs, cats = forest.get("dir"), forest.get("cats")
     if forest["feat"].ndim == 4:       # multiclass: [T, K, depth, half]
         by_class = jax.tree.map(
             lambda a: jnp.moveaxis(a, 1, 0),
-            (forest["feat"], forest["thr"], forest["leaf"], dirs))
+            (forest["feat"], forest["thr"], forest["leaf"], dirs, cats))
 
         def one_class(args):
-            (feat, thr, leaf, dirv), init = args
+            (feat, thr, leaf, dirv, sets), init = args
             return _predict_trees(bins, feat, thr, leaf, depth, 0.0, init,
-                                  dirv, miss_bin)
+                                  dirv, miss_bin, sets)
 
         return jax.lax.map(one_class, (by_class, margin))
     return _predict_trees(bins, forest["feat"], forest["thr"],
-                          forest["leaf"], depth, 0.0, margin, dirs, miss_bin)
+                          forest["leaf"], depth, 0.0, margin, dirs, miss_bin,
+                          cats)
 
 
-@partial(jax.jit, static_argnums=(3, 4, 5, 6))
+@partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
 def _predict_slab(x, cuts, chunks, depth: int, miss_bin: int,
-                  base_score: float, transform):
+                  base_score: float, transform, cat=None):
     """One slab of ``predict`` as ONE program: bin ``x`` [n, F] (float32,
     on the device) against ``cuts``, start the margins at ``base_score``
     (a constant of the program), add the forest's leaf values and apply
@@ -4115,8 +4412,11 @@ def _predict_slab(x, cuts, chunks, depth: int, miss_bin: int,
     shape, the NUMBER of chunks, whether they carry ``dir``, and the
     statics: a forest that grows inside a chunk, or a prefix with as
     many chunks, compiles nothing; one that crosses a ``_TREE_CHUNK``
-    mark compiles once per slab shape."""
-    if miss_bin < 0:
+    mark compiles once per slab shape.  ``cat``: the categorical
+    columns, binned by their tables (None: no such column)."""
+    if cat is not None:
+        bins = apply_bins(x, cuts, cat=cat)
+    elif miss_bin < 0:
         bins = apply_bins(x, cuts)
     else:
         bins = apply_bins_missing(x, cuts, miss_bin)
@@ -4135,7 +4435,7 @@ def _predict_slab(x, cuts, chunks, depth: int, miss_bin: int,
 @partial(jax.jit, static_argnums=(3, 5))
 @jax.named_scope("dmlc.descend")
 def _leaf_indices(bins, feats, thrs, depth: int, dirs=None,
-                  miss_bin: int = -1):
+                  miss_bin: int = -1, cats=None):
     """Per-tree leaf assignment [n, T] (predict_leaf); the same
     :func:`_descend` as _predict_trees, collecting the final node instead
     of summing leaf values."""
@@ -4143,12 +4443,13 @@ def _leaf_indices(bins, feats, thrs, depth: int, dirs=None,
     row_blocks, rows, tree_blocks = _descend_blocks(n, n_trees,
                                                    bins.shape[1])
     trees = jax.tree.map(partial(_tree_blocks, tree_blocks=tree_blocks),
-                         (feats, thrs, dirs))
+                         (feats, thrs, dirs, cats))
 
     def row_block(bins_t):
         bins_t = bins_t.astype(jnp.int32)
         nodes = jax.lax.map(
-            lambda tree: _descend(bins_t, *tree, depth, miss_bin), trees)
+            lambda tree: _descend(bins_t, *tree[:3], depth, miss_bin,
+                                  tree[3]), trees)
         return nodes.reshape((-1,) + nodes.shape[2:])[:n_trees]
 
     return _rows_of_blocks(

@@ -211,6 +211,10 @@ class _ExternalMemoryEngine:
         CHECK(not (p.monotone_constraints
                    and any(int(v) for v in p.monotone_constraints)),
               "fit_external: monotone_constraints not supported — use fit()")
+        CHECK("c" not in p.feature_types,
+              "fit_external: categorical features (feature_types) are the "
+              "in-core engine's — the page sketch has no category→bin "
+              "table; use fit() / make_device_data()")
         CHECK(not p.objective.startswith("rank:"),
               f"fit_external: {p.objective} needs the grouped in-core "
               "layout — use fit(X, y, qid=...)")

@@ -202,6 +202,10 @@ class SparseHistGBT:
         CHECK(not (p.monotone_constraints
                    and any(int(v) for v in p.monotone_constraints)),
               "SparseHistGBT: monotone constraints not supported")
+        CHECK("c" not in p.feature_types,
+              "SparseHistGBT: categorical features (feature_types) not "
+              "supported — its splits are thresholds on the CSR cuts; "
+              "use HistGBT")
         CHECK(p.colsample_bytree >= 1.0,
               "SparseHistGBT: colsample_bytree not supported (v1) — "
               "a silently ignored knob would train a different model")

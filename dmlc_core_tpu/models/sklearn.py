@@ -7,7 +7,9 @@ GBLinear the same ergonomic surface — ``fit(X, y)`` / ``predict`` /
 pipeline code written against ``XGBClassifier``/``XGBRegressor``/
 ``XGBRanker`` ports by changing the import.  ``booster='gbtree'``
 selects hist-GBT, ``'gblinear'`` the linear booster, matching
-XGBoost's knob.
+XGBoost's knob.  Every other native hyperparameter passes through by
+name — ``feature_types=["c", "q", ...]``, ``max_cat_to_onehot``,
+``max_cat_threshold`` for categorical columns among them.
 
 No sklearn import is required (duck-typed estimator contract), but the
 wrappers satisfy ``sklearn.base.BaseEstimator`` conventions (params in

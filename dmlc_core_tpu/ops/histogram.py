@@ -1169,6 +1169,7 @@ def descend_histogram(
     dir_sel: jax.Array = None,  # [n] learned missing direction (1=left)
     miss_bin: int = None,       # bin index reserved for NaN rows
     layout=None,                # BinLayout: bins_t is the physical matrix
+    go_right: jax.Array = None,  # [n] the caller has routed the rows
 ):
     """Advance rows one level down the tree and build the new level's
     LEFT-child histograms, in two passes over the bin matrix: an XLA
@@ -1180,12 +1181,17 @@ def descend_histogram(
     ``[K, n]``: the level of a multiclass round's K trees) the descend
     is each class's own and the build ONE :func:`build_histogram` of K
     classes; ``left_hist`` and ``new_node`` lead with K.
+    ``go_right`` given, the caller has read each row's bin and decided
+    its side (a categorical split goes by membership in a set, which a
+    threshold cannot say) and the descend here only applies it.
     Replaces rabit's per-level hist allreduce prep (SURVEY.md §2e
     data-parallel row)."""
     valid = node_id >= 0
-    select = partial(select_feature_bins, bins_t, layout=layout)
-    row_bin = (select if node_id.ndim == 1 else jax.vmap(select))(feat_sel)
-    go_right = row_bin > thr_sel
+    if go_right is None:
+        select = partial(select_feature_bins, bins_t, layout=layout)
+        row_bin = (select if node_id.ndim == 1
+                   else jax.vmap(select))(feat_sel)
+        go_right = row_bin > thr_sel
     if dir_sel is not None:
         # learned missing direction: NaN rows (bin == miss_bin) follow
         # their node's dir bit (1 = left) instead of the threshold

@@ -39,6 +39,12 @@ property-checks this bound against adversarial distributions
 Device phases (doc/observability.md): everything that computes cuts is
 traced under ``jax.named_scope("dmlc.cuts")``, digitizing under
 ``"dmlc.bin"`` — one name for ingest and predict, it is one function.
+
+A CATEGORICAL column has no cuts: its values are codes, its row of the
+``[F, n_bins-1]`` cut matrix is its category→bin TABLE (the code of bin
+``k`` at position ``k``, -1 past the last named bin: :func:`cat_tables`,
+device scope ``dmlc.cats``) and it is binned by lookup
+(:func:`apply_bins_t` with ``cat=``, ``dmlc.bin.cat``).
 """
 
 from __future__ import annotations
@@ -57,7 +63,8 @@ from dmlc_core_tpu.base.parameter import get_env
 
 __all__ = ["local_summary", "mesh_summary", "merge_summaries", "compute_cuts",
            "apply_bins", "apply_bins_t", "apply_bins_missing",
-           "SketchAccumulator", "nan_scan", "mesh_nan_scan"]
+           "SketchAccumulator", "nan_scan", "mesh_nan_scan",
+           "CAT_MAX_CODE", "cat_scan", "cat_tables", "cat_bins_used"]
 
 
 def _key_sort_quantiles(x: jax.Array, qs: jax.Array) -> jax.Array:
@@ -509,10 +516,11 @@ class SketchAccumulator:
 _CUT_FOLD = 32
 
 
-@partial(jax.jit, static_argnames=("miss_bin",))
+@partial(jax.jit, static_argnames=("miss_bin", "cat"))
 @jax.named_scope("dmlc.bin")
 def apply_bins_t(x: jax.Array, cuts: jax.Array,
-                 miss_bin: Optional[int] = None) -> jax.Array:
+                 miss_bin: Optional[int] = None,
+                 cat: Optional[tuple] = None) -> jax.Array:
     """Digitize ``x`` [n, F] by per-feature ``cuts`` [F, n_bins-1] →
     integer bins FEATURE-MAJOR [F, n]: the layout the round program
     reads, and the one the count is cheapest in.
@@ -543,6 +551,15 @@ def apply_bins_t(x: jax.Array, cuts: jax.Array,
     the caller reserves its top bin; without it NaN would alias the top
     VALUE bin and score garbage.
 
+    ``cat`` (a bool per feature; None or all False: no such column, and
+    the program is the one it always was) marks the CATEGORICAL columns,
+    whose row of ``cuts`` is a category→bin table (:func:`cat_tables`):
+    the bin is the position of the value in that row, and ``n_cuts`` —
+    the last bin, "other" — for a value the row lacks (a level rarer than
+    the named ones, or one unseen when the table was made).  The same
+    compare of every value with every entry, summed over the entry axis;
+    device scope ``dmlc.bin.cat``.
+
     dtype: uint8 when bins fit (largest bin < 256: ``n_bins`` ≤ 256, the
     XGBoost max_bin default) — the bin matrix is the largest resident
     training array and the narrow dtype quarters its HBM footprint under
@@ -561,16 +578,32 @@ def apply_bins_t(x: jax.Array, cuts: jax.Array,
     if miss_bin is not None:
         out = jnp.where(jnp.isnan(xt), miss_bin, out)
         top = miss_bin
+    if cat is not None and any(cat):
+        with jax.named_scope("dmlc.bin.cat"):
+            # position + 1 of the one entry that equals the value, 0 where
+            # none does; a pad (-1, and the fold's) is NaN: equal to nothing
+            table = jnp.pad(jnp.where(cuts >= 0, cuts, jnp.nan),
+                            ((0, 0), (n_pad, 0)), constant_values=jnp.nan
+                            ).reshape(F, -1, _CUT_FOLD)
+            place = jnp.arange(1 - n_pad, C + 1, dtype=jnp.int32
+                               ).reshape(1, -1, _CUT_FOLD, 1)
+            hit = jnp.sum(jnp.where(
+                xt[:, None, None, :] == table[:, :, :, None], place, 0),
+                axis=(1, 2), dtype=jnp.int32)
+            out = jnp.where(jnp.asarray(cat)[:, None],
+                            jnp.where(hit > 0, hit - 1, C), out)
     return out.astype(jnp.uint8 if top < 256 else jnp.int32)
 
 
-@jax.jit
+@partial(jax.jit, static_argnames=("cat",))
 @jax.named_scope("dmlc.bin")
-def apply_bins(x: jax.Array, cuts: jax.Array) -> jax.Array:
+def apply_bins(x: jax.Array, cuts: jax.Array,
+               cat: Optional[tuple] = None) -> jax.Array:
     """:func:`apply_bins_t` row-major: ``x`` [n, F] → bins [n, F]
-    (bin = #cuts ≤ value, so bins ∈ [0, n_bins-1]; NaN → ``n_bins-1``).
+    (bin = #cuts ≤ value, so bins ∈ [0, n_bins-1]; NaN → ``n_bins-1``;
+    a ``cat`` column by its table).
     """
-    return apply_bins_t(x, cuts).T
+    return apply_bins_t(x, cuts, cat=cat).T
 
 
 @partial(jax.jit, static_argnames=("miss_bin",))
@@ -631,3 +664,124 @@ def mesh_nan_scan(x: jax.Array, n_rows: int, mesh: Mesh) -> tuple:
     always finite — so they come off the finite count and a column
     whose data holds no finite value still reads so."""
     return _mesh_nan_scan_fn(mesh, x.shape[0] - n_rows)(x)
+
+
+# -- categorical columns: codes, counts, category→bin tables ----------------
+
+#: codes a categorical column may hold: whole numbers ``0 .. CAT_MAX_CODE
+#: - 1``.  The counts are a compare of every row with every code (one
+#: fusion, no ``[n, K]`` buffer: the binning's own form), so the bound is
+#: on work, not on memory; a column of more levels than this is an id,
+#: not a category
+CAT_MAX_CODE = 1 << 16
+
+
+def _cat_index(cat: tuple) -> np.ndarray:
+    return np.flatnonzero(np.asarray(cat, bool))
+
+
+def _on_mesh(per_chip, mesh: Optional[Mesh], scope: str):
+    """``per_chip(x)`` as a program under device scope ``scope``: on the
+    matrix as it lies on one device, or on every chip's row shard of a
+    mesh (``per_chip`` then reduces over ``data`` itself)."""
+    if mesh is not None:
+        per_chip = shard_map(per_chip, mesh=mesh, in_specs=(P("data", None),),
+                             out_specs=P(), check_vma=False)
+    return jax.jit(jax.named_scope(scope)(per_chip))
+
+
+@lru_cache(maxsize=32)
+def _cat_scan_fn(cat: tuple, mesh: Optional[Mesh]):
+    idx = _cat_index(cat)
+
+    def per_chip(x):
+        # every column, as nan_scan reads them (ONE fusion at HBM's rate;
+        # a gather of the categorical ones goes an element at a time),
+        # then the [F] results cut to the categorical columns.  NaN is
+        # not its own floor: it counts as bad too
+        bad = jnp.sum((x != jnp.floor(x)) | (x < 0), axis=0,
+                      dtype=jnp.int32)[idx]
+        top = jnp.max(x, axis=0, initial=-jnp.inf)[idx]
+        if mesh is not None:
+            bad, top = jax.lax.psum(bad, "data"), jax.lax.pmax(top, "data")
+        return bad, top
+
+    return _on_mesh(per_chip, mesh, "dmlc.cats")
+
+
+def cat_scan(x: jax.Array, cat: tuple, mesh: Optional[Mesh] = None) -> tuple:
+    """What a table's categorical columns have to be told before anything
+    is made of them, from ONE pass over ``x`` [n, F] where it lies (with
+    ``mesh``: in row shards, one ``psum``): per ``cat`` column the number
+    of values that are no code — negative, fractional or NaN — and the
+    largest value.  ``(bad [Fc] int32, top [Fc] float32)``."""
+    return _cat_scan_fn(tuple(map(bool, cat)), mesh)(x)
+
+
+@lru_cache(maxsize=32)
+def _cat_tables_fn(cat: tuple, n_codes: int, n_named: int, n_pad: int,
+                   mesh: Optional[Mesh]):
+    idx = _cat_index(cat)
+    folds = -(-n_codes // _CUT_FOLD)
+
+    def per_chip(x):
+        # (column slices side by side, not a gather)
+        codes = jnp.stack([x[:, f] for f in idx], axis=0)      # [Fc, n]
+        # counts[f, k] = rows of column f that hold code k: one compare
+        # of every row with every code, summed over the rows (the code
+        # axis folded as the cut axis is, see _CUT_FOLD)
+        code = jnp.arange(folds * _CUT_FOLD, dtype=x.dtype
+                          ).reshape(1, folds, _CUT_FOLD, 1)
+        counts = jnp.sum(codes[:, None, None, :] == code, axis=3,
+                         dtype=jnp.int32).reshape(len(idx), -1)[:, :n_codes]
+        if mesh is not None:
+            counts = jax.lax.psum(counts, "data")
+        # a mesh's pad rows are zeros at the tail: never code 0's rows
+        counts = counts.at[:, 0].add(-n_pad)
+        # falling count, ties to the lower code (the sort is stable)
+        order = jnp.argsort(-counts, axis=1, stable=True)
+        seen = jnp.take_along_axis(counts, order, axis=1) > 0
+        named = jnp.where(seen, order, -1)[:, :n_named]
+        named = jnp.pad(named, ((0, 0), (0, n_named - named.shape[1])),
+                        constant_values=-1)
+        return named.astype(x.dtype)
+
+    return _on_mesh(per_chip, mesh, "dmlc.cats")
+
+
+def cat_tables(x: jax.Array, cat: tuple, n_codes: int, n_bins: int,
+               n_rows: Optional[int] = None,
+               mesh: Optional[Mesh] = None) -> jax.Array:
+    """The category→bin tables of the ``cat`` columns of ``x`` [n, F]
+    (codes ``0 .. n_codes-1``, as :func:`cat_scan` found them), LightGBM's
+    ``BinMapper`` rule: the codes by FALLING COUNT over all rows, ties to
+    the lower code, take bins ``0, 1, 2, ...``; at most ``n_bins - 1``
+    are named and every rarer level shares the last bin, ``n_bins - 1``
+    ("other"), with every code unseen here.
+
+    Returns the tables ``[Fc, n_bins-1]``: the code of bin ``k`` at ``k``
+    and -1 past the named bins — each a row of the cut matrix, see
+    :func:`apply_bins_t`.  With ``mesh``, ``x``
+    lies there in row shards, its first ``n_rows`` rows the data and the
+    rest zeros: the chips count their own rows and ONE ``psum`` adds the
+    counts up before the tables are made, alike on every chip."""
+    CHECK(0 < n_codes <= CAT_MAX_CODE,
+          f"a categorical column holds codes 0..{CAT_MAX_CODE - 1}, "
+          f"got {n_codes - 1}")
+    n_pad = 0 if n_rows is None else x.shape[0] - n_rows
+    return _cat_tables_fn(tuple(map(bool, cat)), n_codes, n_bins - 1, n_pad,
+                          mesh)(x)
+
+
+def cat_bins_used(cuts: np.ndarray, cat: tuple) -> tuple:
+    """Per feature the bins ``0..c-1`` its training rows can hold, read
+    off the cut matrix (host): a categorical column's named bins, and
+    ALL ``n_bins`` where every one of them is taken (so that "other" is
+    counted whether or not a level was left over); 0 for a numeric
+    column.  What the split scan is told (``_make_best_split``'s
+    ``cat_bins``)."""
+    cuts = np.asarray(cuts)
+    named = (cuts >= 0).sum(axis=1)
+    return tuple(
+        0 if not is_cat else int(c) if c < cuts.shape[1] else int(c) + 1
+        for is_cat, c in zip(cat, named))

@@ -63,7 +63,8 @@ import jax.numpy as jnp
 from dmlc_core_tpu.base.logging import CHECK
 
 __all__ = ["CHAIN_MAX_ENTRIES", "CHAIN_MIN_ROWS", "ROW_MAJOR_MAX",
-           "SplitWord", "chain_select", "route_form", "table_select"]
+           "SET_FLAT_MAX", "SET_WORD_BITS", "SplitWord", "chain_select", "route_form",
+           "set_words", "set_select", "table_select"]
 
 #: entries a piece: the last size at which the compiler keeps the rows
 #: of one compare-and-sum on the lanes (the table above)
@@ -125,6 +126,61 @@ def chain_select(table: jax.Array, node: jax.Array,
         acc = jax.lax.select(node == k,
                              jnp.broadcast_to(entry, node.shape), acc)
     return acc
+
+
+#: bins a word of a node's SET holds (a categorical split sends a row by
+#: membership of its bin in its node's set: :func:`set_select`)
+SET_WORD_BITS = 32
+
+
+def set_words(member: jax.Array) -> jax.Array:
+    """A set of bins ``member`` [..., n_bins] (bool) as bit words
+    [..., ceil(n_bins / 32)] int32: bin ``b`` is bit ``b % 32`` of word
+    ``b // 32``."""
+    pad = -member.shape[-1] % SET_WORD_BITS
+    bits = jnp.pad(member, ((0, 0),) * (member.ndim - 1) + ((0, pad),)
+                   ).reshape(member.shape[:-1] + (-1, SET_WORD_BITS))
+    weight = jnp.uint32(1) << jnp.arange(SET_WORD_BITS, dtype=jnp.uint32)
+    return jax.lax.bitcast_convert_type(
+        jnp.sum(jnp.where(bits, weight, jnp.uint32(0)), axis=-1,
+                dtype=jnp.uint32), jnp.int32)
+
+
+#: the largest set table (words x nodes) looked up as ONE table keyed
+#: ``words * node + bin // 32``; past it each word is looked up by the
+#: node alone.  ms a lookup at 115M rows, 8 words (PERF.md section 6, PR
+#: 58; ``scripts/sweep_cat_route.py``):
+#:
+#:     nodes            4      16     64     128
+#:     one table        8.2    20.2   72.5   141.0
+#:     a word at a time 23.0   28.5   64.9   129.1
+SET_FLAT_MAX = 2 * ROW_MAJOR_MAX
+
+
+def set_select(words: jax.Array, node: jax.Array, row_bin: jax.Array,
+               n_entries: int) -> jax.Array:
+    """Is ``row_bin`` in ``node``'s set?  ``words`` [n_entries, W] are
+    the nodes' sets as :func:`set_words` makes them; a bin past the last
+    word, and a row whose ``node`` is outside ``0..n_entries-1``, is in
+    no set.  Gather-free, in the form the table's size picks: up to
+    :data:`SET_FLAT_MAX` words in all ONE :func:`table_select` keyed
+    ``W * node + bin // 32``; past it word ``j`` of the row's node for
+    each of the W words (W lookups of ``n_entries``), the row's own
+    picked by a chain of W selects.  Then the bit, by a shift.  The
+    per-row cost of a categorical split: ``W x n_entries`` select-adds a
+    row a level, where a threshold's packed word costs ``n_entries``."""
+    n_words = words.shape[1]
+    at = row_bin >> 5
+    if n_entries * n_words <= SET_FLAT_MAX:
+        # (a pad row's -1 keys below 0 by itself)
+        key = jnp.where(at < n_words, node * n_words + at, -1)
+        word = table_select(words.reshape(-1), key, n_entries * n_words)
+    else:
+        word = jnp.zeros(node.shape, jnp.int32)
+        for j in range(n_words):
+            word = jnp.where(
+                at == j, table_select(words[:, j], node, n_entries), word)
+    return ((word >> (row_bin & 31)) & 1) == 1
 
 
 def route_form(n_prev: int, rows_per_device: int) -> str:

@@ -86,7 +86,9 @@ def render_knob_doc() -> str:
         "`min_child_weight`, ... and, since PR 56, the growth policy "
         "`grow_policy` = `depthwise` | `lossguide` and the leaf budget "
         "`max_leaves`, where `max_depth=0` under `lossguide` means no "
-        "depth cap), given to the constructor, saved with the model "
+        "depth cap; since PR 58 `feature_types`, a list of `\"q\"` / "
+        "`\"c\"`, `max_cat_to_onehot` and `max_cat_threshold` for "
+        "categorical columns), given to the constructor, saved with the model "
         "and listed in the [API pages](api/README.md).",
     ]
     by_group = {}
